@@ -9,25 +9,14 @@ pattern NFAs advance as vectorized transitions.  See SURVEY.md.
 """
 import os
 
-# XLA:CPU's new fusion emitters (jaxlib 0.9.0) miscompile some of our jitted
+# XLA:CPU's fusion emitters (jaxlib 0.9.0) miscompile some of our jitted
 # pattern steps (LLVM IR verifier failure in fusion_compiler.cc — e.g. a
 # 2-column (long,int) partitioned NFA step) and compile slower than the
-# legacy emitters.  Best-effort opt-out before the backend initializes; a
-# no-op for TPU and for processes that already compiled something.
-# VERSION-GATED: older jaxlibs (< 0.9) don't know the flag, and XLA
-# hard-aborts the process on unknown XLA_FLAGS — the opt-out must only be
-# injected where the flag exists.
-def _jaxlib_has_fusion_emitters() -> bool:
-    try:
-        import jaxlib
-        major, minor = (int(x) for x in jaxlib.__version__.split(".")[:2])
-        return (major, minor) >= (0, 9)
-    except Exception:  # noqa: BLE001 — never block import on a probe
-        return False
-
-
-if "--xla_cpu_use_fusion_emitters" not in os.environ.get("XLA_FLAGS", "") \
-        and _jaxlib_has_fusion_emitters():
+# legacy emitters.  Opt out before the backend initializes.  The flag is a
+# CPU-backend option that every XLA build of this installation parses
+# (libtpu included — checked on the v5e, PERF.md "PR 21"), so it is safe to
+# carry in XLA_FLAGS whichever backend ends up in use.
+if "--xla_cpu_use_fusion_emitters" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                " --xla_cpu_use_fusion_emitters=false")
 

@@ -94,8 +94,8 @@ select JL.sym as s, JL.price as p, JR.qty as q
 insert into JOut;
 """
 
-# single-key block-NFA sequence (VERDICT §9 shape 2; bench
-# sequence_within / _mc_block_nfa)
+# single-key block-NFA sequence (bench sequence_within /
+# _mc_block_nfa)
 SEQUENCE_QL = """
 @app:playback
 define stream S (symbol long, price float, volume int);

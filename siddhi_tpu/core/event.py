@@ -163,6 +163,14 @@ def dtype_of(attr_type: str):
     return _DTYPES[attr_type.upper()]
 
 
+def typed_full(shape, value, dtype):
+    """`jnp.full` with the fill typed on the HOST.  State is allocated
+    eagerly, and under jax_enable_x64 a bare Python float fill (the 0.0 /
+    NaN defaults below) reaches the device as an f64 scalar — a type the
+    TPU does not have."""
+    return jnp.full(shape, np.asarray(value, dtype))
+
+
 def default_value(attr_type: str):
     t = attr_type.upper()
     if t in ("STRING", "OBJECT"):
@@ -322,7 +330,7 @@ class EventBatch:
     @staticmethod
     def empty(schema: Schema, capacity: int) -> "EventBatch":
         cols = tuple(
-            jnp.full((capacity,), default_value(t), dtype=d)
+            typed_full((capacity,), default_value(t), d)
             for t, d in zip(schema.types, schema.dtypes)
         )
         return EventBatch(

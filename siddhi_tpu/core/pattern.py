@@ -291,7 +291,7 @@ class PatternExec:
             # leaves the partner's StreamEvent null; e1[i] out of range
             # returns null)
             cols = tuple(
-                jnp.full((P, D, K), ev.null_value(t), dtype=d)
+                ev.typed_full((P, D, K), ev.null_value(t), d)
                 for t, d in zip(schema.types, schema.dtypes))
             # ts plane -1 == unfilled: fill-depth tests use >= 0, so a
             # legitimate playback event at timestamp 0 still counts

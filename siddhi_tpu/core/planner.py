@@ -25,7 +25,7 @@ from ..query_api.query import (
 )
 from . import event as ev
 from .executor import CompileError, Scope, compile_expression
-from .steputil import jit_step, pcast, shard_map
+from .steputil import jit_step, pmin_i64
 from .keyslots import SlotAllocator
 from .selector import SelectorExec
 from .window import NoWindow, Rows, WindowProcessor, create_window
@@ -176,17 +176,17 @@ def _shard_plain_step(step, mesh, sel, wproc, group_slots: int,
 
     def local(state, ts, kind, valid, cols, gslot, now, in_tabs, pslots):
         dev = lax.axis_index("shard")
-        ts = pcast(ts, ("shard",), to="varying")
-        kind = pcast(kind, ("shard",), to="varying")
-        valid = pcast(valid, ("shard",), to="varying")
-        cols = tuple(pcast(c, ("shard",), to="varying") for c in cols)
-        gslot = pcast(gslot, ("shard",), to="varying")
+        ts = lax.pcast(ts, ("shard",), to="varying")
+        kind = lax.pcast(kind, ("shard",), to="varying")
+        valid = lax.pcast(valid, ("shard",), to="varying")
+        cols = tuple(lax.pcast(c, ("shard",), to="varying") for c in cols)
+        gslot = lax.pcast(gslot, ("shard",), to="varying")
         in_tabs = jax.tree.map(
-            lambda x: pcast(x, ("shard",), to="varying"), in_tabs)
+            lambda x: lax.pcast(x, ("shard",), to="varying"), in_tabs)
         wstate, astate = state
         old_w = wstate
         wstate = jax.tree.map(
-            lambda x: pcast(x, ("shard",), to="varying"), wstate)
+            lambda x: lax.pcast(x, ("shard",), to="varying"), wstate)
         # round-robin ownership (slot % n): sequential slot allocation
         # would park every early group on device 0 under a block split —
         # same layout as the pattern path, device column = (s%n)*blk + s//n
@@ -203,16 +203,16 @@ def _shard_plain_step(step, mesh, sel, wproc, group_slots: int,
         okind = _merge_rows(ovalid, okind)
         ocols = tuple(_merge_rows(ovalid, c) for c in ocols)
         ovalid = lax.psum(ovalid.astype(jnp.int32), "shard") > 0
-        wake = lax.pmin(wake, "shard")
+        wake = pmin_i64(wake, "shard")
         # NoWindow's state is the additive seq counter: re-replicate as
         # old + sum of per-device deltas (pattern-path recipe)
         wstate = jax.tree.map(
             lambda old, new: old + lax.psum(
-                new - pcast(old, ("shard",), to="varying"), "shard"),
+                new - lax.pcast(old, ("shard",), to="varying"), "shard"),
             old_w, wstate)
         return (wstate, astate), (ots, okind, ovalid, ocols), wake
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=((wspec, sspec), rspec, rspec, rspec, rspec, rspec, P(),
                   rspec, rspec),
@@ -246,7 +246,7 @@ def _shard_keyed_step(kstep, mesh, K: int, owner=None):
         is_bool = old.dtype == jnp.bool_
         oi = old.astype(jnp.int32) if is_bool else old
         ni = new.astype(jnp.int32) if is_bool else new
-        oi_v = pcast(oi, ("shard",), to="varying")
+        oi_v = lax.pcast(oi, ("shard",), to="varying")
         changed = ni != oi_v
         merged = oi + lax.psum(
             jnp.where(changed, ni - oi_v, jnp.zeros_like(ni)), "shard")
@@ -255,7 +255,7 @@ def _shard_keyed_step(kstep, mesh, K: int, owner=None):
     def local(state, ts, kind, valid, cols, gslot, key_idx, sel_idx, now,
               in_tabs):
         dev = lax.axis_index("shard")
-        vary = lambda x: pcast(x, ("shard",), to="varying")  # noqa: E731
+        vary = lambda x: lax.pcast(x, ("shard",), to="varying")  # noqa: E731
         ts, kind, valid, gslot = vary(ts), vary(kind), vary(valid), \
             vary(gslot)
         cols = tuple(vary(c) for c in cols)
@@ -275,13 +275,13 @@ def _shard_keyed_step(kstep, mesh, K: int, owner=None):
         okind = _merge_rows(ovalid, okind)
         ocols = tuple(_merge_rows(ovalid, c) for c in ocols)
         ovalid = lax.psum(ovalid.astype(jnp.int32), "shard") > 0
-        wake = lax.pmin(wake, "shard")
+        wake = pmin_i64(wake, "shard")
         astate = jax.tree.map(dmerge, old_a, astate)
         return (wslab, astate), (ots, okind, ovalid, ocols), wake
 
     wspec = P("shard")
     rspec = P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=((wspec, rspec), rspec, rspec, rspec, rspec, rspec, rspec,
                   rspec, P(), rspec),

@@ -88,7 +88,7 @@ class TableRuntime:
         self.index_stats = {"indexed": 0, "dense": 0}
         # device state
         self.cols = tuple(
-            jnp.full((capacity,), ev.default_value(t), dtype=d)
+            ev.typed_full((capacity,), ev.default_value(t), d)
             for t, d in zip(schema.types, schema.dtypes))
         self.ts = jnp.zeros((capacity,), jnp.int64)
         self.valid = jnp.zeros((capacity,), jnp.bool_)

@@ -66,7 +66,7 @@ class Buffer(NamedTuple):
 
 def empty_buffer(schema: ev.Schema, capacity: int) -> Buffer:
     cols = tuple(
-        jnp.full((capacity,), ev.default_value(t), dtype=d)
+        ev.typed_full((capacity,), ev.default_value(t), d)
         for t, d in zip(schema.types, schema.dtypes)
     )
     big = jnp.full((capacity,), BIG_SEQ, jnp.int64)

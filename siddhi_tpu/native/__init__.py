@@ -1,12 +1,18 @@
 """ctypes loader for the native host-staging library.
 
-Compiles `staging.c` with the system gcc on first import (cached as
-`_staging_<mtime>.so` next to the source); falls back to None so callers
-keep the pure-numpy path when no toolchain is available.
+Compiles `staging.c` with the system gcc on first import, cached next to
+the source as `_staging_<sha256 of staging.c>.so` — keyed on the source's
+CONTENT, so a binary built from any other version of the file (a copied
+working tree keeps ignored `.so` files but not mtimes) can never be picked
+up.  `LIB` is None when no toolchain is available and callers keep the
+pure-numpy path; that path is an order of magnitude slower, so the failure
+is logged at ERROR and measuring entry points (`chip_smoke.py`) refuse to
+run without the library.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -19,7 +25,8 @@ _src = os.path.join(_dir, "staging.c")
 def _build():
     if not os.path.exists(_src):
         return None
-    tag = int(os.stat(_src).st_mtime)
+    with open(_src, "rb") as fh:
+        tag = hashlib.sha256(fh.read()).hexdigest()[:16]
     so = os.path.join(_dir, f"_staging_{tag}.so")
     if not os.path.exists(so):
         now = time.time()
@@ -48,15 +55,16 @@ def _build():
                 pass
             # a concurrent importer may have published the .so meanwhile
             if not os.path.exists(so):
-                logging.getLogger("siddhi_tpu").warning(
-                    "native staging build failed (%s); using numpy fallback",
-                    exc)
+                logging.getLogger("siddhi_tpu").error(
+                    "native staging build failed (%s); using the slow numpy "
+                    "fallback", exc)
                 return None
     try:
         return ctypes.CDLL(so)
     except OSError as exc:
-        logging.getLogger("siddhi_tpu").warning(
-            "native staging load failed (%s); using numpy fallback", exc)
+        logging.getLogger("siddhi_tpu").error(
+            "native staging load failed (%s); using the slow numpy "
+            "fallback", exc)
         return None
 
 

@@ -7,9 +7,9 @@ switchable OFF/BASIC/DETAIL — SiddhiAppRuntimeImpl.setStatisticsLevel
 :859-895) plus log4j TRACE-level event tracing.
 
 TPU design (how): a JAX/XLA deployment has two failure modes the reference
-never had — *tail latency* dominated by device dispatch + tunnel roundtrips,
-and *silent XLA recompilation* (a re-trace stalls a query for seconds on CPU
-and minutes through a remote TPU tunnel).  This package therefore records
+never had — *tail latency* dominated by device dispatch + blocking fetches,
+and *silent XLA recompilation* (a re-trace stalls a query for seconds, tens
+of seconds for a large step on the TPU).  This package therefore records
 
 - fixed-bucket log2 latency **histograms** (p50/p95/p99/max) instead of
   avg/max scalars (`histogram.py`),

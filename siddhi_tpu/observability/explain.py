@@ -223,14 +223,20 @@ def step_cost(fn, cache: Optional[Dict] = None,
         with RECOMPILES.suppress():
             lowered = fn.lower(*specs)
         ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
+        compiled = None
+        if ca is None or deep or collectives:
+            with RECOMPILES.suppress():
+                compiled = lowered.compile()
+        if ca is None:
+            # XLA:TPU analyses compiled executables only (the lowering's
+            # cost_analysis() is None there), so on the TPU even a shallow
+            # EXPLAIN compiles — through the persistent cache when the
+            # step has already run
+            ca = compiled.cost_analysis()
         for k in _COST_KEYS:
             if k in ca:
                 out[k.replace(" ", "_")] = float(ca[k])
         if deep or collectives:
-            with RECOMPILES.suppress():
-                compiled = lowered.compile()
             ma = compiled.memory_analysis()
             arg = int(getattr(ma, "argument_size_in_bytes", 0))
             outb = int(getattr(ma, "output_size_in_bytes", 0))
@@ -294,6 +300,22 @@ def _steps_of(qr, kind: str) -> List[Tuple[str, Any]]:
                 getattr(mg, "_fused_cache", {}).items():
             steps.append((f"merged_fused_step[{fkind}]", fn))
     return steps
+
+
+def compiled_steps(qr) -> List[Tuple[str, Any, Any]]:
+    """(role, jitted fn, argspecs) for every XLA program on the query's
+    hot path, the serving ring's append/read pair included.  `argspecs`
+    is the signature the program last traced at (`fn.lower(*argspecs)`
+    reproduces what ran) or None when it has not run yet.  Served as
+    `SiddhiAppRuntime.compiled_steps` — what a script outside the
+    package walks instead of the planner's attributes."""
+    steps = _steps_of(qr, _runtime_kind(qr))
+    ring = qr.__dict__.get("_serve_ring")
+    if ring is not None:
+        steps = steps + ring.programs()
+    return [(role, fn,
+             (getattr(fn, "_siddhi_argspec", None) or {}).get("argspecs"))
+            for role, fn in steps]
 
 
 # ---------------------------------------------------------------------------

@@ -5,8 +5,8 @@ consumers host-side with its Disruptor-backed async StreamJunction
 (CORE/stream/StreamJunction.java:276) — a producer never blocks on a
 consumer; it writes into a preallocated ring and moves on.
 
-TPU design (how): every perf round since r04 shows the chip doing
-~0.2 ms of work per dispatch while the host round-trip costs 73-95 ms,
+TPU design (how): at small batches the device step is short next to
+the blocking emission fetch (shares not measured on the current chip),
 and @pipeline/@fuse only *amortize* the blocking `device_get` — the
 depth-k drain still makes a periodic fetch burst structural.  This
 module does the Disruptor decoupling *across the PCIe boundary*: a
@@ -63,23 +63,30 @@ def _aval_key(out) -> Tuple:
 
 
 def _alloc_like(x, slots: int):
-    """[S, ...] zeros for one output leaf.  Sharded leaves keep their
-    NamedSharding with a replicated slot axis: each mesh device holds
-    its own segment of every ring slot (per-shard rings — the drain
-    transfers each shard's buffer independently)."""
+    """([S, ...] zeros for one output leaf, placement fell back?).
+    Sharded leaves keep their NamedSharding with a replicated slot axis:
+    each mesh device holds its own segment of every ring slot (per-shard
+    rings — the drain transfers each shard's buffer independently).  A
+    refused sharded placement leaves the leaf on the default device —
+    the whole ring on chip 0 — which still delivers but is not the
+    layout asked for, so the caller counts it and it is logged at ERROR
+    (`facts()["placement_fallbacks"]`; chip_smoke.py requires zero)."""
     z = jnp.zeros((slots,) + tuple(x.shape), x.dtype)
     sh = getattr(x, "sharding", None)
     spec = getattr(sh, "spec", None)
     mesh = getattr(sh, "mesh", None)
-    if spec is not None and mesh is not None and \
-            any(p is not None for p in tuple(spec)):
-        try:
-            from jax.sharding import NamedSharding, PartitionSpec
-            z = jax.device_put(
-                z, NamedSharding(mesh, PartitionSpec(None, *tuple(spec))))
-        except Exception:  # noqa: BLE001 — fall back to default placement
-            pass
-    return z
+    if spec is None or mesh is None or \
+            all(p is None for p in tuple(spec)):
+        return z, False
+    from jax.sharding import NamedSharding, PartitionSpec
+    try:
+        return jax.device_put(
+            z, NamedSharding(mesh, PartitionSpec(None, *tuple(spec)))), False
+    except Exception:  # noqa: BLE001 — delivery continues, unsharded
+        log.exception(
+            "emission ring leaf %s%s could not be placed with spec %s; "
+            "left on the default device", x.dtype, tuple(z.shape), spec)
+        return z, True
 
 
 class _Generation:
@@ -89,7 +96,7 @@ class _Generation:
     delivery."""
 
     __slots__ = ("state", "slots", "head", "tail", "count", "key",
-                 "out_len", "_set", "_read")
+                 "out_len", "placement_fallbacks", "_set", "_read")
 
     def __init__(self, out, slots: int, owner: str):
         from ..core.steputil import jit_step
@@ -99,7 +106,10 @@ class _Generation:
         self.count = 0         # occupied slots
         self.key = _aval_key(out)
         self.out_len = len(out)
-        self.state = jax.tree.map(lambda x: _alloc_like(x, slots), out)
+        leaves, treedef = jax.tree.flatten(out)
+        placed = [_alloc_like(x, slots) for x in leaves]
+        self.state = jax.tree.unflatten(treedef, [z for z, _ in placed])
+        self.placement_fallbacks = sum(fell for _, fell in placed)
 
         def _set(state, o, i):
             return jax.tree.map(
@@ -169,6 +179,14 @@ class EmissionRing:
         self.appends_total = 0
         self.grows_total = 0
         self.generation = 0
+        self.placement_fallbacks = 0
+
+    def _open_generation(self, out, slots: int) -> "_Generation":
+        gen = _Generation(out, slots, self.qr.name)
+        self._gens.append(gen)
+        self.generation += 1
+        self.placement_fallbacks += gen.placement_fallbacks
+        return gen
 
     # -- producer edge (query lock held; never fetches) ---------------------
     def append(self, out, now: int, ingest_ns=None, trace=None) -> None:
@@ -179,9 +197,7 @@ class EmissionRing:
                 # output signature changed (emission-cap replan): seal
                 # the old generation — it keeps draining FIFO — and
                 # open a fresh buffer at the configured capacity
-                gen = _Generation(out, self.capacity, self.qr.name)
-                self._gens.append(gen)
-                self.generation += 1
+                gen = self._open_generation(out, self.capacity)
             if gen.count >= gen.slots:
                 gen = self._make_room(gen, out)
             gen.append(out)
@@ -224,9 +240,7 @@ class EmissionRing:
             if stats.enabled:
                 stats.counter_inc(f"{self.qr.name}.ring_grows")
             self.grows_total += 1
-            gen = _Generation(out, new_cap, self.qr.name)
-            self._gens.append(gen)
-            self.generation += 1
+            gen = self._open_generation(out, new_cap)
             grown = True
         if grown:
             return gen
@@ -281,6 +295,17 @@ class EmissionRing:
         observability/memory.py counts the ring under `serve_ring`)."""
         return [g.state for g in self._gens]
 
+    def programs(self) -> List[Tuple[str, Any]]:
+        """(role, jitted fn) of every live generation's append/read pair
+        — the ring's share of `runtime.compiled_steps`."""
+        with self._cond:
+            gens = list(self._gens)
+        out: List[Tuple[str, Any]] = []
+        for gi, gen in enumerate(gens):
+            out += [(f"ring_append[{gi}]", gen._set),
+                    (f"ring_read[{gi}]", gen._read)]
+        return out
+
     def facts(self) -> Dict[str, Any]:
         """EXPLAIN / healthz node for this ring."""
         return {
@@ -290,5 +315,6 @@ class EmissionRing:
             "appends_total": self.appends_total,
             "overflow_grows": self.grows_total,
             "generation": self.generation,
+            "placement_fallbacks": self.placement_fallbacks,
             "nbytes": self.nbytes(),
         }

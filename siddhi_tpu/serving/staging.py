@@ -3,8 +3,8 @@
 In the blocking path a batch's host->device transfer starts inside
 `process_staged` (StagedBatch.to_device), AFTER the junction has
 waited on the query lock and resolved group slots — the upload
-serializes behind host staging work, and on a remote accelerator its
-tunnel latency lands in the send path.
+serializes behind host staging work, and its latency lands in the send
+path.
 
 The stager moves the upload to the junction's ACCEPT edge: the moment
 a staged batch enters dispatch (sync path) or the @async ingress queue
@@ -23,12 +23,14 @@ staging instead of accumulating transfers.
 from __future__ import annotations
 
 import collections
+import logging
 import threading
 
 import jax
 import numpy as np
 
 jnp = jax.numpy
+log = logging.getLogger("siddhi_tpu")
 
 
 class DoubleBufferedStager:
@@ -43,13 +45,16 @@ class DoubleBufferedStager:
         self._inflight = collections.deque(maxlen=self.depth)
         self.staged_total = 0
         self.adopted_total = 0
+        self.fallback_total = 0
 
     def stage(self, staged, schema) -> None:
         """Start the non-blocking upload of one StagedBatch's arrays and
         attach them for `to_device` adoption.  Idempotent per batch; a
         failure leaves the batch unstaged (to_device transfers as
         before) — staging is an overlap optimization, never a
-        correctness dependency."""
+        correctness dependency — but the lost overlap is counted
+        (`facts()["fallback_total"]`) and logged, so a measurement can
+        refuse to run on the downgraded path."""
         if getattr(staged, "dev", None) is not None:
             return
         try:
@@ -61,6 +66,10 @@ class DoubleBufferedStager:
                                jnp.asarray(staged.kind),
                                jnp.asarray(staged.valid), cols)
         except Exception:  # noqa: BLE001 — fall back to in-path transfer
+            with self._lock:
+                self.fallback_total += 1
+            log.exception("accept-edge H2D staging failed; the batch "
+                          "transfers in the dispatch path instead")
             return
         staged.dev = (schema, batch)
         with self._lock:
@@ -78,4 +87,5 @@ class DoubleBufferedStager:
                 "in_flight": len(self._inflight),
                 "staged_total": self.staged_total,
                 "adopted_total": self.adopted_total,
+                "fallback_total": self.fallback_total,
             }
