@@ -90,8 +90,8 @@ fuse-smoke:
 
 # boots a sample app, then asserts the whole introspection surface:
 # GET /explain carries XLA cost analysis, /healthz reports live+ready,
-# /trace.json parses as Chrome trace-event JSON, and the
-# siddhi_state_bytes family scrapes (observability v2 layer)
+# /trace/<query> serves DETAIL traces in the runtime's span names, and
+# the siddhi_state_bytes family scrapes (observability v2 layer)
 explain-smoke:
 	$(CPU_ENV) $(PY) samples/explain_smoke.py
 
@@ -139,7 +139,7 @@ serve-smoke:
 
 # phase-level hot-path profiler in <60 s: all 8 taxonomy phases recorded
 # for a @serve query, cross-thread trace handoff (drain spans share the
-# dispatch trace id; /trace.json drain track + flow arrows), sampled
+# dispatch trace id on the drain track), sampled
 # deep-mode overhead < 5%, and every surface (/metrics families,
 # phase_report, EXPLAIN phases) touching zero device state (README
 # "Phase profiling"); plus the quick per-phase budget A-B

@@ -1,9 +1,9 @@
 """Explain/introspection smoke test: boot a sample app behind the REST
 service, push traffic, then assert the full introspection surface works —
 `GET /explain` returns an operator tree with XLA cost analysis,
-`GET /healthz` distinguishes readiness from liveness, `GET /trace.json`
-parses as Chrome trace-event JSON, and the `siddhi_state_bytes` family
-scrapes.  Run via `make explain-smoke` (CI/tooling hook of the
+`GET /healthz` distinguishes readiness from liveness, `GET /trace/<query>`
+serves DETAIL batch traces in the runtime's span names, and the
+`siddhi_state_bytes` family scrapes.  Run via `make explain-smoke` (CI/tooling hook of the
 observability v2 layer; see README "Observability")."""
 import json
 import re
@@ -70,14 +70,12 @@ def main() -> int:
         assert _get(base, "/healthz/ready").status == 200
         assert _get(base, "/healthz/live").status == 200
 
-        # 3. /trace.json: valid Chrome trace-event JSON
-        doc = json.loads(_get(base, "/trace.json").read().decode())
-        evs = doc["traceEvents"]
-        assert evs, "no trace events"
-        for e in evs:
-            assert {"ph", "name", "pid", "tid"} <= set(e), e
-        ts = [e["ts"] for e in evs if e["ph"] != "M"]
-        assert ts == sorted(ts), "non-monotonic trace ts"
+        # 3. /trace/<query>: DETAIL batch traces, spans named by the
+        # runtime's span taxonomy (observability/phases.py)
+        evs = json.loads(_get(base, "/trace/vwap").read().decode())["traces"]
+        assert evs, "no DETAIL traces"
+        stages = {s["stage"] for t in evs for s in t["spans"]}
+        assert {"stage", "h2d", "dispatch"} <= stages, stages
 
         # 4. /metrics: the state-bytes family scrapes with components
         text = _get(base, "/metrics").read().decode()
@@ -86,7 +84,7 @@ def main() -> int:
                       r'query="vwap",component="window"\} (\d+)', text)
         assert m and int(m.group(1)) > 0, "state bytes gauge missing"
 
-        print(f"explain-smoke OK: {len(evs)} trace events, "
+        print(f"explain-smoke OK: {len(evs)} DETAIL traces, "
               f"vwap window state {m.group(1)} bytes, "
               f"healthz live+ready")
         return 0

@@ -7,8 +7,8 @@ End-to-end assertions over the phase-attribution surface in <30 s:
    is nonzero for a @serve query under sampled deep mode — one trace
    spans the dispatch thread AND the drainer thread;
 2. the drainer's delivery spans carry the SAME trace id as the
-   dispatch-side spans (cross-thread handoff/adopt), and /trace.json
-   renders them on a "drain" track linked by flow events;
+   dispatch-side spans (cross-thread handoff/adopt), tagged with the
+   "drain" track;
 3. the sampled deep mode's overhead stays bounded (< 20% of per-send
    p50 on a worst-case near-zero-work query — the only
    block_until_ready it ever takes is the every-Nth fence), and the
@@ -82,23 +82,17 @@ def main():
           f"accounted={node['accounted']}, "
           f"sampled={node['sampled_dispatches']}")
 
-    # 2. cross-thread trace: drain spans share the dispatch trace id,
-    # /trace.json links the two tracks with flow events
+    # 2. cross-thread trace: drain spans share the dispatch trace id
     traces = rt.trace_dump("q", 16)
     linked = [t for t in traces
               if any(s.get("track") == "drain" for s in t["spans"])
               and any(s.get("track") is None for s in t["spans"])]
     assert linked, "no trace spans both the dispatch and drainer threads"
-    from siddhi_tpu.observability.chrome_trace import chrome_trace
-    evs = chrome_trace(manager.runtimes)["traceEvents"]
-    starts = {e["id"] for e in evs if e["ph"] == "s"}
-    finishes = {e["id"] for e in evs if e["ph"] == "f"}
-    assert starts & finishes, "no flow arrow pairs in /trace.json"
-    drain_tids = {e["tid"] for e in evs
-                  if e["ph"] == "X" and e["tid"] >= 10 ** 9}
-    assert drain_tids, "no drain track in /trace.json"
-    print(f"trace: {len(linked)} cross-thread traces, "
-          f"{len(starts & finishes)} flow arrows onto the drain track")
+    drained = {s["stage"] for t in linked for s in t["spans"]
+               if s.get("track") == "drain"}
+    assert {"fetch", "demux", "sink"} <= drained, drained
+    print(f"trace: {len(linked)} cross-thread traces, drain-side spans "
+          f"{sorted(drained)}")
 
     # 4. (before shutdown) surfaces agree and never touch the device
     import jax

@@ -501,7 +501,7 @@ class AggregationRuntime:
                 vals.append(v)
             return keep, jnp.stack(vals) if vals else jnp.zeros((0,) + ts.shape)
 
-        self._step = jit_step(step, owner=f"agg:{adef.id}")
+        self._step = jit_step(step, owner=f"agg:{adef.id}", role="agg_step")
 
         # device merge: one scatter per base row into the duration slab
         kinds = tuple(b.kind for b in self.base)
@@ -523,7 +523,7 @@ class AggregationRuntime:
             return jnp.stack(rows)
 
         self._merge = jit_step(merge, owner=f"agg:{adef.id}",
-                               donate_argnums=(0,))
+                               role="agg_merge", donate_argnums=(0,))
 
     # -- construction ---------------------------------------------------------
     def _decompose(self, selector, scope: Scope) -> None:

@@ -366,15 +366,17 @@ class StagedBatch:
         # replayed verbatim by drains/dispatch (core/runtime.py
         # JoinQueryRuntime._join_key_probe)
         self.jprobe = None
-        # (schema, EventBatch) prestaged by the serving double-buffer
-        # (serving/staging.py): the H2D transfer started at the junction
-        # accept edge; to_device adopts it instead of re-transferring
+        # (schema, EventBatch, stager) prestaged by the serving
+        # double-buffer (serving/staging.py): the H2D transfer started at
+        # the junction accept edge; to_device adopts it instead of
+        # re-transferring, and tells the stager it did
         self.dev = None
 
     def to_device(self, schema: Schema) -> EventBatch:
         dev = self.dev
         if dev is not None and (dev[0] is schema or
                                 dev[0].dtypes == schema.dtypes):
+            dev[2].adopted()
             return dev[1]
         cols = tuple(jnp.asarray(c).astype(d)
                      for c, d in zip(self.cols, schema.dtypes))

@@ -42,7 +42,7 @@ from typing import Callable, Dict, List, Tuple
 import jax
 import numpy as np
 
-from ..observability import tracing as _tracing
+from ..observability import phases as _phases
 from . import event as ev
 from .steputil import fuse_step
 
@@ -186,11 +186,7 @@ class FuseBuffer:
         stats = qr.app.stats
         k = len(items)
         t0 = time.perf_counter_ns() if stats.enabled else 0
-        if _tracing.active() is None:
-            _DISPATCH[self.kind](qr, items)
-        else:
-            with _tracing.span("fused_step", query=qr.name, k=k):
-                _DISPATCH[self.kind](qr, items)
+        _DISPATCH[self.kind](qr, items)
         if stats.enabled:
             n = sum(int(a[-2].n) for a in items)
             stats.fused_dispatch(qr.name, k, n,
@@ -231,7 +227,7 @@ def _fused_fn(qr, kind: str, body: Callable) -> Callable:
     if ent is not None and ent[0] is body:
         return ent[1]
     adapter = _ADAPTERS[kind](body)
-    fn = fuse_step(adapter, owner=f"fused:{qr.name}")
+    fn = fuse_step(adapter, owner=f"fused:{qr.name}", role=f"fused_{kind}")
     cache[key] = (body, fn)
     return fn
 
@@ -292,23 +288,31 @@ def _now_stack(items) -> jax.Array:
     return jnp.asarray(np.asarray([a[-1] for a in items], np.int64))
 
 
+def _stack_nbytes(stack, *more) -> int:
+    """Host bytes of one fused upload: the [K, B] stack plus whatever
+    rides with it."""
+    return _phases.nbytes(stack.ts, stack.kind, stack.valid, *stack.cols,
+                          *more)
+
+
 def _dispatch_plain(qr, items) -> None:
-    from . import runtime as _rt
     p = qr.planned
+    st, k = qr.app.stats, len(items)
     prep = [qr._slots_for_batch(staged, now) for staged, now in items]
-    stack = ev.StackedBatch([staged for staged, _ in items])
-    batch = stack.to_device(p.in_schema)
-    gslot_k = jnp.asarray(np.stack([np.asarray(g) for g, _ in prep]))
-    pslots_k = tuple(
-        jnp.asarray(np.stack([np.asarray(ps[j]) for _, ps in prep]))
-        for j in range(len(p.pair_allocs)))
-    xs = (batch.ts, batch.kind, batch.valid, batch.cols, gslot_k,
-          _now_stack(items), pslots_k)
+    with _phases.phase(st, qr.name, "stage", k):
+        stack = ev.StackedBatch([staged for staged, _ in items])
+        gslot_np = np.stack([np.asarray(g) for g, _ in prep])
+        pslots_np = [np.stack([np.asarray(ps[j]) for _, ps in prep])
+                     for j in range(len(p.pair_allocs))]
+    with _phases.phase(st, qr.name, "h2d", k,
+                       bytes=_stack_nbytes(stack, gslot_np, *pslots_np)):
+        batch = stack.to_device(p.in_schema)
+        xs = (batch.ts, batch.kind, batch.valid, batch.cols,
+              jnp.asarray(gslot_np), _now_stack(items),
+              tuple(jnp.asarray(a) for a in pslots_np))
     const = qr.app.in_probe_tables(p.in_deps)
     fn = _fused_fn(qr, "plain", p.raw_step)
-    _st, outs = _rt._step_phase(
-        qr, lambda: fn(qr.state, xs, const), mult=len(items))
-    _rt._rebind_state(qr, _st, mult=len(items))
+    qr.state, outs = _phases.dispatch(qr, fn, qr.state, xs, const, mult=k)
     _deliver_fused(qr, outs, [now for _, now in items])
 
 
@@ -318,35 +322,37 @@ def _prepare_pattern(qr, items) -> Tuple[Callable, Tuple, Tuple]:
     device-resident inputs and zero emission fetches."""
     from . import runtime as _rt
     p = qr.planned
+    st, k = qr.app.stats, len(items)
     stream_id = items[0][0]
     B = items[0][1].ts.shape[0]
-    sels = []
-    for _, staged, _ in items:
-        if staged.valid.all():
-            sels.append(_rt._identity_sel(B))
-        else:
-            sels.append(np.where(staged.valid,
-                                 np.arange(B, dtype=np.int32),
-                                 -1)[None, :])
-    stack = ev.StackedBatch([staged for _, staged, _ in items])
-    # the sequential pattern path ships raw staged columns (np_dtype
-    # already matches the device dtypes) — mirror it exactly
-    cols_k = tuple(jnp.asarray(c) for c in stack.cols)
-    k = len(items)
-    xs = (cols_k, jnp.asarray(stack.ts), jnp.asarray(np.stack(sels)),
-          jnp.asarray(np.zeros((k, 1), np.int32)), _now_stack(items))
+    with _phases.phase(st, qr.name, "stage", k):
+        sels = []
+        for _, staged, _ in items:
+            if staged.valid.all():
+                sels.append(_rt._identity_sel(B))
+            else:
+                sels.append(np.where(staged.valid,
+                                     np.arange(B, dtype=np.int32),
+                                     -1)[None, :])
+        sel_np = np.stack(sels)
+        stack = ev.StackedBatch([staged for _, staged, _ in items])
+    with _phases.phase(st, qr.name, "h2d", k,
+                       bytes=_stack_nbytes(stack, sel_np)):
+        # the sequential pattern path ships raw staged columns (np_dtype
+        # already matches the device dtypes) — mirror it exactly
+        cols_k = tuple(jnp.asarray(c) for c in stack.cols)
+        xs = (cols_k, jnp.asarray(stack.ts), jnp.asarray(sel_np),
+              jnp.asarray(np.zeros((k, 1), np.int32)), _now_stack(items))
     return (_fused_fn(qr, "pattern", p.step_bodies[stream_id]), xs,
             qr._in_tabs())
 
 
 def _dispatch_pattern(qr, items) -> None:
-    from . import runtime as _rt
     if getattr(qr.planned, "mesh", None) is not None:
         return _dispatch_pattern_sharded(qr, items)
     fn, xs, const = _prepare_pattern(qr, items)
-    _st, outs = _rt._step_phase(
-        qr, lambda: fn(qr.state, xs, const), mult=len(items))
-    _rt._rebind_state(qr, _st, mult=len(items))
+    qr.state, outs = _phases.dispatch(qr, fn, qr.state, xs, const,
+                                      mult=len(items))
     _deliver_fused(qr, outs, [now for _, _, now in items])
 
 
@@ -366,23 +372,24 @@ def _dispatch_pattern_sharded(qr, items) -> None:
     Kb = max(ki.shape[1] for ki, _ in preps)
     E = max(s.shape[2] for _, s in preps)
     block = qr.shard_router.block
-    k = len(items)
-    key_k = np.full((k, n, Kb), block, np.int32)
-    sel_k = np.full((k, n, Kb, E), -1, np.int32)
-    for i, (ki, s) in enumerate(preps):
-        key_k[i, :, :ki.shape[1]] = ki
-        sel_k[i, :, :s.shape[1], :s.shape[2]] = s
-    stack = ev.StackedBatch([staged for _, staged, _ in items])
-    xs = (tuple(jnp.asarray(c) for c in stack.cols),
-          jnp.asarray(stack.ts),
-          jnp.asarray(sel_k.reshape(k, n * Kb, E)),
-          jnp.asarray(key_k.reshape(k, n * Kb)),
-          _now_stack(items))
-    from . import runtime as _rt
-    fn = p.shard_fused_steps[stream_id]
-    _st, outs = _rt._step_phase(
-        qr, lambda: fn(qr.state, xs, qr._in_tabs()), mult=len(items))
-    _rt._rebind_state(qr, _st, mult=len(items))
+    st, k = qr.app.stats, len(items)
+    with _phases.phase(st, qr.name, "stage", k):
+        key_k = np.full((k, n, Kb), block, np.int32)
+        sel_k = np.full((k, n, Kb, E), -1, np.int32)
+        for i, (ki, s) in enumerate(preps):
+            key_k[i, :, :ki.shape[1]] = ki
+            sel_k[i, :, :s.shape[1], :s.shape[2]] = s
+        stack = ev.StackedBatch([staged for _, staged, _ in items])
+    with _phases.phase(st, qr.name, "h2d", k,
+                       bytes=_stack_nbytes(stack, sel_k, key_k)):
+        xs = (tuple(jnp.asarray(c) for c in stack.cols),
+              jnp.asarray(stack.ts),
+              jnp.asarray(sel_k.reshape(k, n * Kb, E)),
+              jnp.asarray(key_k.reshape(k, n * Kb)),
+              _now_stack(items))
+    qr.state, outs = _phases.dispatch(
+        qr, p.shard_fused_steps[stream_id], qr.state, xs, qr._in_tabs(),
+        mult=k)
     _deliver_fused(qr, outs, [now for _, _, now in items])
 
 
@@ -391,40 +398,47 @@ def _dispatch_join(qr, items) -> None:
     is_left = items[0][0]
     side = p.left if is_left else p.right
     body = p.raw_left if is_left else p.raw_right
+    st, k = qr.app.stats, len(items)
     gs = [qr._join_slots(is_left, staged) for _, staged, _ in items]
-    stack = ev.StackedBatch([staged for _, staged, _ in items])
-    batch = stack.to_device(side.schema)
-    xs = [batch.ts, batch.kind, batch.valid, batch.cols,
-          jnp.asarray(np.stack([np.asarray(g) for g in gs]))]
-    if p.fastpath == "bucket":
-        # probes were bound (and the retention mirror fed) at offer
-        # time, so the stack replays them verbatim
-        xs.append(jnp.asarray(np.stack(
-            [np.asarray(qr._join_key_probe(is_left, staged))
-             for _, staged, _ in items])))
-    elif p.fastpath == "table":
-        # candidates resolve against the table at DISPATCH time — the
-        # same moment `const` snapshots its columns below
-        probes = [qr._table_probe(staged) for _, staged, _ in items]
-        w = max(c.shape[1] for c, _ in probes)
-        b = probes[0][0].shape[0]
-        cand_k = np.full((len(probes), b, w), -1, np.int32)
-        ok_k = np.zeros((len(probes), b, w), np.bool_)
-        for i, (c, o) in enumerate(probes):
-            cand_k[i, :, :c.shape[1]] = c
-            ok_k[i, :, :o.shape[1]] = o
-        xs.append((jnp.asarray(cand_k), jnp.asarray(ok_k)))
-    xs.append(_now_stack(items))
+    with _phases.phase(st, qr.name, "stage", k):
+        stack = ev.StackedBatch([staged for _, staged, _ in items])
+        host = [np.stack([np.asarray(g) for g in gs])]
+        if p.fastpath == "bucket":
+            # probes were bound (and the retention mirror fed) at offer
+            # time, so the stack replays them verbatim
+            host.append(np.stack(
+                [np.asarray(qr._join_key_probe(is_left, staged))
+                 for _, staged, _ in items]))
+        elif p.fastpath == "table":
+            # candidates resolve against the table at DISPATCH time — the
+            # same moment `const` snapshots its columns below
+            probes = [qr._table_probe(staged) for _, staged, _ in items]
+            w = max(c.shape[1] for c, _ in probes)
+            b = probes[0][0].shape[0]
+            cand_k = np.full((len(probes), b, w), -1, np.int32)
+            ok_k = np.zeros((len(probes), b, w), np.bool_)
+            for i, (c, o) in enumerate(probes):
+                cand_k[i, :, :c.shape[1]] = c
+                ok_k[i, :, :o.shape[1]] = o
+            host += [cand_k, ok_k]
+    with _phases.phase(st, qr.name, "h2d", k,
+                       bytes=_stack_nbytes(stack, *host)):
+        batch = stack.to_device(side.schema)
+        xs = [batch.ts, batch.kind, batch.valid, batch.cols,
+              jnp.asarray(host[0])]
+        if p.fastpath == "bucket":
+            xs.append(jnp.asarray(host[1]))
+        elif p.fastpath == "table":
+            xs.append((jnp.asarray(host[1]), jnp.asarray(host[2])))
+        xs.append(_now_stack(items))
     # table/aggregation other-side snapshot is taken ONCE at dispatch:
     # under @fuse the per-batch read-your-writes of a concurrently
     # updated table relaxes to dispatch granularity (stream other-sides
     # live in the carry and stay exact)
     const = qr._other_table(is_left)
     fn = _fused_fn(qr, "join", body)
-    from . import runtime as _rt
-    _st, outs = _rt._step_phase(
-        qr, lambda: fn(qr.state, tuple(xs), const), mult=len(items))
-    _rt._rebind_state(qr, _st, mult=len(items))
+    qr.state, outs = _phases.dispatch(qr, fn, qr.state, tuple(xs), const,
+                                      mult=k)
     _deliver_fused(qr, outs, [now for _, _, now in items])
 
 
@@ -435,32 +449,32 @@ def _dispatch_merged(qr, items) -> None:
     from . import runtime as _rt
     stats = qr.app.stats
     t0 = time.perf_counter_ns() if stats.enabled else 0
+    K = len(items)
+    gname = f"merged:{qr.group}"
     preps = [qr._prep(staged, now) for staged, now in items]
-    stack = ev.StackedBatch([staged for staged, _ in items])
-    batch = stack.to_device(qr.in_schema)
-    n_units = len(qr.units)
-    gslots_k = tuple(
-        jnp.asarray(np.stack([np.asarray(p[0][u]) for p in preps]))
-        for u in range(n_units))
-    pslots_k = tuple(
-        tuple(jnp.asarray(np.stack([np.asarray(p[1][i][j])
-                                    for p in preps]))
-              for j in range(len(qr.members[i].planned.pair_allocs)))
-        for i in range(len(qr.members)))
-    xs = (batch.ts, batch.kind, batch.valid, batch.cols, gslots_k,
-          _now_stack(items), pslots_k)
+    with _phases.phase(stats, gname, "stage", K):
+        stack = ev.StackedBatch([staged for staged, _ in items])
+        gslots_np = [np.stack([np.asarray(p[0][u]) for p in preps])
+                     for u in range(len(qr.units))]
+        pslots_np = [
+            [np.stack([np.asarray(p[1][i][j]) for p in preps])
+             for j in range(len(qr.members[i].planned.pair_allocs))]
+            for i in range(len(qr.members))]
+    with _phases.phase(
+            stats, gname, "h2d", K, bytes=_stack_nbytes(
+                stack, *gslots_np, *(a for ps in pslots_np for a in ps))):
+        batch = stack.to_device(qr.in_schema)
+        xs = (batch.ts, batch.kind, batch.valid, batch.cols,
+              tuple(jnp.asarray(a) for a in gslots_np), _now_stack(items),
+              tuple(tuple(jnp.asarray(a) for a in ps) for ps in pslots_np))
     fn = _fused_fn(qr, "merged", qr.raw_body)
-    _st, outs = _rt._step_phase(
-        qr, lambda: fn(qr._state, xs, qr._in_tabs()),
-        name=f"merged:{qr.group}", mult=len(items))
-    _rt._rebind_state(qr, _st, mult=len(items),
-                      name=f"merged:{qr.group}", attr="_state")
+    qr._state, outs = _phases.dispatch(
+        qr, fn, qr._state, xs, qr._in_tabs(), name=gname, mult=K)
     if stats.enabled:
         stats.counter_inc(f"merged.{qr.group}.dispatches")
         stats.counter_inc(f"merged.{qr.group}.member_batches",
                           len(qr.members) * len(items))
     ingests = qr.__dict__.pop("_fused_ingests", None)
-    K = len(items)
     if ingests is None or len(ingests) != K:
         ingests = [None] * K
     consumers = [i for i, m in enumerate(qr.members)
@@ -472,11 +486,8 @@ def _dispatch_merged(qr, items) -> None:
     if consumers and not deferred:
         # ONE fetch for every consumed member's whole [K, ...] block;
         # per-batch views below are then numpy slices
-        tf = time.perf_counter_ns()
-        host = jax.device_get([outs[i] for i in consumers])
-        if stats.enabled:
-            stats.phases.add(f"merged:{qr.group}", "d2h_drain",
-                             time.perf_counter_ns() - tf)
+        host = _phases.fetch(stats, gname, "rows",
+                             [outs[i] for i in consumers])
         outs = list(outs)
         for i, h in zip(consumers, host):
             outs[i] = h
@@ -535,32 +546,14 @@ def _deliver_fused(qr, outs, nows: List[int]) -> None:
     if len(outs) == 6:
         # ONE fetch for the combined [K, 2] header (join headers are
         # [K, 2] vectors themselves; still one fetch)
-        tf = time.perf_counter_ns()
-        h0, h1 = jax.device_get((outs[0], outs[1]))
-        if _st.enabled:
-            _st.phases.add(qr.name, "d2h_drain",
-                           time.perf_counter_ns() - tf)
-        need_rows = bool(qr.callbacks) or \
-            getattr(qr, "table_op", None) is not None or \
-            getattr(qr, "rate_limiter", None) is not None or \
+        h0, h1 = _phases.fetch(_st, qr.name, "header",
+                               (outs[0], outs[1]))
+        # _emit_output_sync_impl's own test: a junction nobody reads
+        # must not force a bulk fetch
+        need_rows = bool(qr.callbacks) or _rt._target_live(qr) or \
             getattr(qr.planned, "emits_uuid", False)
-        tgt = qr.planned.output_target
-        if not need_rows and tgt:
-            # mirror _emit_output_sync_impl's target-live check: a dead
-            # downstream junction must not force a bulk fetch
-            app = qr.app
-            if tgt in getattr(app, "named_windows", {}) or \
-                    tgt in getattr(app, "tables", {}):
-                need_rows = True
-            else:
-                j = app.junctions.get(tgt)
-                need_rows = j is not None and bool(
-                    j.queries or j.stream_callbacks or app.stats.enabled)
-        tf = time.perf_counter_ns()
-        bulk = jax.device_get(outs[2:]) if need_rows else outs[2:]
-        if need_rows and _st.enabled:
-            _st.phases.add(qr.name, "d2h_drain",
-                           time.perf_counter_ns() - tf)
+        bulk = _phases.fetch(_st, qr.name, "rows", outs[2:]) \
+            if need_rows else outs[2:]
         for i in range(K):
             out_i = (h0[i], h1[i], bulk[0][i], bulk[1][i], bulk[2][i],
                      tuple(c[i] for c in bulk[3]))
@@ -573,11 +566,8 @@ def _deliver_fused(qr, outs, nows: List[int]) -> None:
     else:
         # plain outputs are window-capacity bounded and always ship
         # whole on the sequential path too: ONE fetch for the block
-        tf = time.perf_counter_ns()
-        ots, okind, ovalid, ocols = jax.device_get(outs)
-        if _st.enabled:
-            _st.phases.add(qr.name, "d2h_drain",
-                           time.perf_counter_ns() - tf)
+        ots, okind, ovalid, ocols = _phases.fetch(_st, qr.name, "rows",
+                                                  outs)
         for i in range(K):
             out_i = (ots[i], okind[i], ovalid[i],
                      tuple(c[i] for c in ocols))

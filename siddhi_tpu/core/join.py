@@ -668,9 +668,11 @@ def plan_join_query(
     if not right.is_table and raw_right is None:
         raw_right = _make_feed_only(right, False, mesh, fp_mode)
     if raw_left is not None:
-        step_left = jit_step(raw_left, owner=name, donate_argnums=(0,))
+        step_left = jit_step(raw_left, owner=name, role="join_left",
+                             donate_argnums=(0,))
     if raw_right is not None:
-        step_right = jit_step(raw_right, owner=name, donate_argnums=(0,))
+        step_right = jit_step(raw_right, owner=name, role="join_right",
+                              donate_argnums=(0,))
 
     def init_state():
         wl = left.window.init_state() if left.window else ()

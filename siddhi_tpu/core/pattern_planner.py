@@ -310,7 +310,11 @@ def plan_pattern_query(
                 return st, emit
 
             xs = (tuple(c.T for c in cols), ts.T, valid.T)   # scan over E
-            sub, emits = lax.scan(body, sub, xs)
+            # named scopes are op-name metadata only (a profiler trace
+            # reads the section off each device op): the compiled program
+            # is the same with and without them
+            with jax.named_scope("nfa_advance"):
+                sub, emits = lax.scan(body, sub, xs)
 
             nb32, nb64, nscal = packer.pack(sub)
             if dense:
@@ -359,23 +363,29 @@ def plan_pattern_query(
         block_bodies = {sid: make_block_step(
             spec, pexec, sel, schemas, packer, sid, compact_rows)
             for sid in spec.stream_ids}
-        steps = {sid: jit_step(b, owner=name, donate_argnums=(0, 1))
+        steps = {sid: jit_step(b, owner=name, role="pattern_block",
+                               donate_argnums=(0, 1))
                  for sid, b in block_bodies.items()}
         steps_w = {sid: jit_step(wire_ts(b), owner=name,
+                                 role="pattern_block_w",
                                  donate_argnums=(0, 1))
                    for sid, b in block_bodies.items()}
         step_bodies = block_bodies
     elif mesh is None:
-        steps = {sid: jit_step(body, owner=name, donate_argnums=(0, 1))
+        steps = {sid: jit_step(body, owner=name, role="pattern_step",
+                               donate_argnums=(0, 1))
                  for sid, body in raw_steps.items()}
         steps_w = {sid: jit_step(wire_ts(body), owner=name,
+                                 role="pattern_step_w",
                                  donate_argnums=(0, 1))
                    for sid, body in raw_steps.items()}
         dense_steps = {sid: jit_step(make_step(sid, dense=True), owner=name,
+                                     role="pattern_dense",
                                      donate_argnums=(0, 1))
                        for sid in spec.stream_ids}
         dense_steps_w = {sid: jit_step(wire_ts(make_step(sid, dense=True)),
-                                       owner=name, donate_argnums=(0, 1))
+                                       owner=name, role="pattern_dense_w",
+                                       donate_argnums=(0, 1))
                          for sid in spec.stream_ids}
         step_bodies = raw_steps
     else:
@@ -418,7 +428,7 @@ def plan_pattern_query(
                 jnp.any(nb64 != b64, axis=0)
             return (nb32, nb64, nscalars), sel_state, out, wake, changed
 
-        timer_step = jit_step(tstep, owner=name,
+        timer_step = jit_step(tstep, owner=name, role="pattern_timer",
                               donate_argnums=(0, 1))
 
     def init_state(K: int):
@@ -552,7 +562,8 @@ def _shard_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
         _shard_local(body), mesh=mesh,
         in_specs=(pspec, sspec, rspec, rspec, bspec, bspec, P(), P()),
         out_specs=(pspec, sspec, (P(), P(), bspec, bspec, bspec, bspec), P()))
-    return jit_step(sharded, owner=owner, donate_argnums=(0, 1))
+    return jit_step(sharded, owner=owner, role="pattern_step_sharded",
+                    donate_argnums=(0, 1))
 
 
 def _shard_fused_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
@@ -587,7 +598,8 @@ def _shard_fused_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
         in_specs=((pspec, sspec), (P(), P(), bspec2, bspec2, P()), P()),
         out_specs=((pspec, sspec),
                    (P(), P(), bspec2, bspec2, bspec2, bspec2)))
-    return jit_step(sharded, owner=owner, donate_argnums=(0,))
+    return jit_step(sharded, owner=owner, role="fused_pattern_sharded",
+                    donate_argnums=(0,))
 
 
 def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
@@ -653,25 +665,28 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
         gslot=gslot,
         cols=(),
     )
-    sel_state, out = sel.process(sel_state, rows, env)
+    with jax.named_scope("selector"):
+        sel_state, out = sel.process(sel_state, rows, env)
 
     ots, okind, ovalid, ocols = out
     R = min(compact_rows, EP)
     if R < EP:
-        v2 = ovalid.reshape(EP, K)
-        rank = jnp.cumsum(v2.astype(jnp.int32), axis=0) - 1
-        keep_oh = jnp.logical_and(
-            jnp.arange(R, dtype=jnp.int32)[:, None, None] == rank[None],
-            v2[None])                          # [R,EP,K]
-        cmask = jnp.any(keep_oh, axis=1)       # [R,K]
-        n_valid = jnp.sum(cmask.astype(jnp.int64))
-        n_dropped = jnp.sum(v2.astype(jnp.int64)) - n_valid
+        with jax.named_scope("emission_compaction"):
+            v2 = ovalid.reshape(EP, K)
+            rank = jnp.cumsum(v2.astype(jnp.int32), axis=0) - 1
+            keep_oh = jnp.logical_and(
+                jnp.arange(R, dtype=jnp.int32)[:, None, None] == rank[None],
+                v2[None])                          # [R,EP,K]
+            cmask = jnp.any(keep_oh, axis=1)       # [R,K]
+            n_valid = jnp.sum(cmask.astype(jnp.int64))
+            n_dropped = jnp.sum(v2.astype(jnp.int64)) - n_valid
 
-        def cmp(x):                            # [B] -> [R*K]
-            return oh_take(x.reshape(EP, K)[None], keep_oh, 1).reshape(R * K)
+            def cmp(x):                            # [B] -> [R*K]
+                return oh_take(x.reshape(EP, K)[None], keep_oh,
+                               1).reshape(R * K)
 
-        out = (cmp(ots), cmp(okind), cmask.reshape(R * K),
-               tuple(cmp(c) for c in ocols))
+            out = (cmp(ots), cmp(okind), cmask.reshape(R * K),
+                   tuple(cmp(c) for c in ocols))
     else:
         n_valid = jnp.sum(ovalid.astype(jnp.int64))
         n_dropped = jnp.zeros((), jnp.int64)

@@ -217,7 +217,8 @@ def _shard_plain_step(step, mesh, sel, wproc, group_slots: int,
         in_specs=((wspec, sspec), rspec, rspec, rspec, rspec, rspec, P(),
                   rspec, rspec),
         out_specs=((wspec, sspec), (P(), P(), P(), P()), P()))
-    return jit_step(sharded, owner=owner, donate_argnums=(0,))
+    return jit_step(sharded, owner=owner, role="plain_step_sharded",
+                    donate_argnums=(0,))
 
 
 def _shard_keyed_step(kstep, mesh, K: int, owner=None):
@@ -286,7 +287,8 @@ def _shard_keyed_step(kstep, mesh, K: int, owner=None):
         in_specs=((wspec, rspec), rspec, rspec, rspec, rspec, rspec, rspec,
                   rspec, P(), rspec),
         out_specs=((wspec, rspec), (P(), P(), P(), P()), P()))
-    return jit_step(sharded, owner=owner, donate_argnums=(0,))
+    return jit_step(sharded, owner=owner, role="keyed_step_sharded",
+                    donate_argnums=(0,))
 
 
 def plan_single_query(
@@ -600,7 +602,8 @@ def plan_single_query(
             step_fn = _shard_keyed_step(kstep, mesh, K, owner=name)
             keyed_mesh = mesh
         else:
-            step_fn = jit_step(kstep, owner=name, donate_argnums=(0,))
+            step_fn = jit_step(kstep, owner=name, role="keyed_step",
+                               donate_argnums=(0,))
             keyed_mesh = None
 
         def init_state():
@@ -625,7 +628,8 @@ def plan_single_query(
                                         allocator.capacity, owner=name)
             plain_mesh = mesh
         else:
-            step_fn = jit_step(step, owner=name, donate_argnums=(0,))
+            step_fn = jit_step(step, owner=name, role="plain_step",
+                               donate_argnums=(0,))
             plain_mesh = None
             raw_step = step
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import heapq
+import itertools
 import logging
 import pickle
 import threading
@@ -45,19 +46,10 @@ _NO_WAKEUP_INT = int(NO_WAKEUP)
 # StreamJunction.sendEvent :147 and QuerySelector.process :77)
 _trace_log = logging.getLogger("siddhi_tpu.trace")
 
-# shared no-op context for span sites on the OFF/BASIC hot path (nullcontext
+# shared no-op context for the DETAIL `query` span at OFF/BASIC (nullcontext
 # enter/exit is stateless, so ONE instance serves every thread without
 # allocating per batch)
 _NULL_CM = contextlib.nullcontext()
-
-
-def _maybe_span(stage: str, **meta):
-    """A `tracing.span` when a DETAIL pipeline trace is active on this
-    thread, else the shared no-op context — one thread-local read at
-    OFF/BASIC, zero allocation."""
-    if _tracing.active() is None:
-        return _NULL_CM
-    return _tracing.span(stage, **meta)
 
 
 def _sub_name(sub, default: str) -> str:
@@ -66,55 +58,12 @@ def _sub_name(sub, default: str) -> str:
     return getattr(getattr(sub, "_qr", sub), "name", default)
 
 
-def _step_phase(qr, fn, name=None, mult=1):
-    """Run one jitted step call, recording its wall as the
-    `dispatch_submit` phase (async dispatch: the call returns at SUBMIT,
-    so this wall says nothing about device time).  Every
-    `profile.sample.every` dispatches per query the deep mode fences the
-    returned pytree with `block_until_ready` and records the fence wall
-    as `device_compute` — the only block the profiler ever takes, and
-    never on the steady (unsampled) path.  `mult` is the number of
-    source batches one dispatch serves (a @fuse stack of K): each of the
-    K batches' `<q>:e2e` sample contains this full wall, so the phase
-    charges it K times to keep sum(phases) tracking sum(e2e) — the
-    attribution rule documented in observability/phases.py."""
-    st = qr.app.stats
-    if not st.enabled:
-        return fn()
-    qname = name or qr.name
-    ph = st.phases
-    t0 = time.perf_counter_ns()
-    res = fn()
-    t1 = time.perf_counter_ns()
-    ph.add(qname, "dispatch_submit", (t1 - t0) * mult)
-    every = _phases.sample_every(qr.app)
-    if every and ph.should_sample(qname, every):
-        jax.block_until_ready(res)
-        ph.add(qname, "device_compute",
-               (time.perf_counter_ns() - t1) * mult)
-    return res
-
-
-def _rebind_state(qr, v, mult=1, name=None, attr="state"):
-    """Rebind a query's device state to the step's returned pytree,
-    timing the rebind as `device_compute`.  Under async dispatch this
-    plain assignment is where the device wall surfaces on the host:
-    dropping the previous generation's buffers — live inputs of the
-    step still executing — blocks in the XLA client until that step
-    retires them.  No fence or fetch is added; the wait is inherent to
-    the rebind, so always-on mode stays zero-sync while still
-    accounting the compute wall each batch's e2e sample contains.
-    (When the sampled deep mode fenced this dispatch the buffers are
-    already retired and this records ~0 — the two never double-count.)
-    `mult`: batches served by one fused dispatch, as in _step_phase."""
-    st = qr.app.stats
-    if not st.enabled:
-        setattr(qr, attr, v)
-        return
-    t0 = time.perf_counter_ns()
-    setattr(qr, attr, v)
-    st.phases.add(name or qr.name, "device_compute",
-                  (time.perf_counter_ns() - t0) * mult)
+def _staged_nbytes(staged) -> int:
+    """Bytes `staged.to_device` will upload: nothing when the serving
+    stager already did at the accept edge."""
+    if staged.dev is not None:
+        return 0
+    return _phases.nbytes(staged.ts, staged.kind, staged.valid, *staged.cols)
 
 
 def current_millis() -> int:
@@ -285,11 +234,22 @@ class InputHandler:
 
     def send(self, data, timestamp: Optional[int] = None) -> None:
         """Accepts one event's data list/tuple, an Event, or a list of those."""
-        self._runtime._gate_wait()     # entry valve, see _gate_wait
-        events = self._to_events(data, timestamp)
-        if not self._admitted(len(events)):
-            return                     # shed at the edge (counted)
-        self._runtime._route(self.stream_id, events)
+        with self._send_span(None) as span:
+            self._runtime._gate_wait()     # entry valve, see _gate_wait
+            events = self._to_events(data, timestamp)
+            span.set_metadata(events=len(events))
+            if not self._admitted(len(events)):
+                return                     # shed at the edge (counted)
+            self._runtime._route(self.stream_id, events)
+
+    def _send_span(self, n: Optional[int]):
+        """`siddhi:send` over the whole call, under the next number of the
+        junction's send sequence: every span this send causes, on
+        whatever thread, carries it as `batch` (observability/phases.py)."""
+        rt = self._runtime
+        j = rt.junctions.get(self.stream_id)
+        return _phases.send(rt.stats, self.stream_id,
+                            next(j._batch_seq) if j is not None else 0, n)
 
     def _to_events(self, data, timestamp) -> List[ev.Event]:
         now = timestamp if timestamp is not None \
@@ -314,10 +274,12 @@ class InputHandler:
         device link).  This matches the reference's InputHandler.send
         (Object[] ownership transfers, InputHandler.java:70); pass a copy
         if you need to keep writing into the array."""
-        self._runtime._gate_wait()     # entry valve, see _gate_wait
-        if not self._admitted(len(cols[0]) if cols else 0):
-            return                     # shed at the edge (counted)
-        self._runtime._route_columns(self.stream_id, cols, timestamps)
+        n = len(cols[0]) if cols else 0
+        with self._send_span(n):
+            self._runtime._gate_wait()     # entry valve, see _gate_wait
+            if not self._admitted(n):
+                return                     # shed at the edge (counted)
+            self._runtime._route_columns(self.stream_id, cols, timestamps)
 
 
 class _MeshResolved:
@@ -403,17 +365,25 @@ class QueryRuntime(_MeshResolved):
         shared by the sequential path and fused dispatch (core/fusion.py)."""
         p = self.planned
         valid = staged.valid
-        if p.group_by_positions and p.slot_allocator is not None:
-            gslot = p.slot_allocator.slots_for(
-                [staged.cols[i] for i in p.group_by_positions], valid)
-            _stateobs_feed_slots(self, p.slot_allocator, gslot)
+        st = self.app.stats
+        grouped = bool(p.group_by_positions) and p.slot_allocator is not None
+        if not grouped and not p.pair_allocs:
+            gslot, pslots = _zero_slots(staged.ts.shape[0]), ()
         else:
-            gslot = _zero_slots(staged.ts.shape[0])
-        if self._touch is not None:
-            self._touch(gslot, now)
-        # distinctCount: (group, value) -> pair refcount slots
-        pslots = tuple(alloc.slots_for([gslot, staged.cols[pos]], valid)
-                       for alloc, pos in p.pair_allocs)
+            with _phases.phase(st, self.name, "route_keys"):
+                gslot = p.slot_allocator.slots_for(
+                    [staged.cols[i] for i in p.group_by_positions],
+                    valid) if grouped else _zero_slots(staged.ts.shape[0])
+                # distinctCount: (group, value) -> pair refcount slots
+                pslots = tuple(
+                    alloc.slots_for([gslot, staged.cols[pos]], valid)
+                    for alloc, pos in p.pair_allocs)
+        if grouped or self._touch is not None:
+            with _phases.phase(st, self.name, "obs_feed"):
+                if grouped:
+                    _stateobs_feed_slots(self, p.slot_allocator, gslot)
+                if self._touch is not None:
+                    self._touch(gslot, now)
         return gslot, pslots
 
     def process_staged(self, staged: ev.StagedBatch, now: int) -> None:
@@ -427,35 +397,40 @@ class QueryRuntime(_MeshResolved):
         fb = self._fuse
         if fb is not None and fb.offer((staged, now), staged, None):
             return
+        st = self.app.stats
         if p.partition_key_fn is not None:
             # range partition: derived key column; rows matching no range
             # are excluded from the query entirely
-            kcols, kvalid = p.partition_key_fn(staged)
-            valid = staged.valid & kvalid
-            if p.slot_allocator is not None:
-                key_cols = list(kcols) + [staged.cols[i]
-                                          for i in p.group_by_positions]
-                gslot = p.slot_allocator.slots_for(key_cols, valid)
-            else:
-                gslot = _zero_slots(staged.ts.shape[0])
-            staged = ev.StagedBatch(staged.ts, staged.kind, valid,
-                                    staged.cols, staged.n)
+            with _phases.phase(st, self.name, "route_keys"):
+                kcols, kvalid = p.partition_key_fn(staged)
+                valid = staged.valid & kvalid
+                if p.slot_allocator is not None:
+                    key_cols = list(kcols) + [staged.cols[i]
+                                              for i in p.group_by_positions]
+                    gslot = p.slot_allocator.slots_for(key_cols, valid)
+                else:
+                    gslot = _zero_slots(staged.ts.shape[0])
+                staged = ev.StagedBatch(staged.ts, staged.kind, valid,
+                                        staged.cols, staged.n)
+                pslots = tuple(
+                    alloc.slots_for([gslot, staged.cols[pos]], valid)
+                    for alloc, pos in p.pair_allocs)
             if self._touch is not None:
-                self._touch(gslot, now)
-            pslots = tuple(alloc.slots_for([gslot, staged.cols[pos]], valid)
-                           for alloc, pos in p.pair_allocs)
+                with _phases.phase(st, self.name, "obs_feed"):
+                    self._touch(gslot, now)
         else:
             gslot, pslots = self._slots_for_batch(staged, now)
-        pslots = tuple(jax.numpy.asarray(s) for s in pslots)
-        batch = staged.to_device(p.in_schema)
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_staged_nbytes(staged) +
+                           _phases.nbytes(gslot, *pslots)):
+            pslots = tuple(jax.numpy.asarray(s) for s in pslots)
+            batch = staged.to_device(p.in_schema)
+            gslot_d = jax.numpy.asarray(gslot)
+            now_d = jax.numpy.asarray(now, jax.numpy.int64)
         in_tabs = self.app.in_probe_tables(p.in_deps)
-        with _maybe_span("step", query=self.name, kind="window"):
-            _st, out, wake = _step_phase(self, lambda: p.step(
-                self.state, batch.ts, batch.kind, batch.valid, batch.cols,
-                jax.numpy.asarray(gslot),
-                jax.numpy.asarray(now, jax.numpy.int64),
-                in_tabs, pslots))
-        _rebind_state(self, _st)
+        self.state, out, wake = _phases.dispatch(
+            self, p.step, self.state, batch.ts, batch.kind, batch.valid,
+            batch.cols, gslot_d, now_d, in_tabs, pslots)
         # sampled window-fill probe: dispatch-only; its scalar rides the
         # delivery fetch in _deliver_output (observability/stateobs.py)
         _stateobs.arm_fill_probe(self)
@@ -475,49 +450,56 @@ class QueryRuntime(_MeshResolved):
         and the window state slab advances under vmap (planner.kstep)."""
         p = self.planned
         valid = staged.valid
-        kcols: List[np.ndarray] = []
+        st = self.app.stats
         if all_keys:
             # timer tick: advance EVERY key's window; each key sees the
             # TIMER row (staged row 0) so flush-on-timer windows
             # (cron/timeBatch) fire per key, and `now` drives time expiry.
             # The partition key fn is NOT applied: a TIMER row's zeroed
             # columns would fail every range condition and kill the row.
+            # Timer ticks carry no data rows: no group slots to resolve.
             key_idx = np.arange(p.key_capacity, dtype=np.int32)
             sel = np.zeros((p.key_capacity, 1), np.int32)
-        elif p.partition_key_fn is not None:
-            kcols, kvalid = p.partition_key_fn(staged)
-            valid = valid & kvalid
-            kcols = list(kcols)
-        else:
-            kcols = [staged.cols[i] for i in p.window_key_positions]
-        if not all_keys:
-            _, key_idx, sel = p.window_key_allocator.slots_and_group(
-                kcols, valid, pad=p.key_capacity)
-            _stateobs_feed_group(self, p.window_key_allocator, key_idx,
-                                 sel, p.key_capacity)
-        if self._touch is not None and not all_keys:
-            self._touch(key_idx, now)
-        if p.slot_allocator is not None and not all_keys:
-            if p.partition_key_fn is not None:
-                gk = kcols + [staged.cols[i] for i in p.group_by_positions]
-            else:
-                gk = [staged.cols[i] for i in p.group_by_positions]
-            gslot = p.slot_allocator.slots_for(gk, valid)
-            if self._touch_group is not None:
-                self._touch_group(gslot, now)
-        else:
-            # timer ticks carry no data rows: no group slots to resolve
             gslot = _zero_slots(staged.ts.shape[0])
-        batch = ev.StagedBatch(staged.ts, staged.kind, valid, staged.cols,
-                               staged.n).to_device(p.in_schema)
+        else:
+            gslot = None
+            with _phases.phase(st, self.name, "route_keys"):
+                if p.partition_key_fn is not None:
+                    kcols, kvalid = p.partition_key_fn(staged)
+                    valid = valid & kvalid
+                    kcols = list(kcols)
+                else:
+                    kcols = [staged.cols[i] for i in p.window_key_positions]
+                _, key_idx, sel = p.window_key_allocator.slots_and_group(
+                    kcols, valid, pad=p.key_capacity)
+                if p.slot_allocator is not None:
+                    gk = [staged.cols[i] for i in p.group_by_positions]
+                    if p.partition_key_fn is not None:
+                        gk = kcols + gk
+                    gslot = p.slot_allocator.slots_for(gk, valid)
+            with _phases.phase(st, self.name, "obs_feed"):
+                _stateobs_feed_group(self, p.window_key_allocator, key_idx,
+                                     sel, p.key_capacity)
+                if self._touch is not None:
+                    self._touch(key_idx, now)
+                if gslot is not None and self._touch_group is not None:
+                    self._touch_group(gslot, now)
+            if gslot is None:
+                gslot = _zero_slots(staged.ts.shape[0])
+        staged = ev.StagedBatch(staged.ts, staged.kind, valid, staged.cols,
+                                staged.n)
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_staged_nbytes(staged) +
+                           _phases.nbytes(gslot, key_idx, sel)):
+            batch = staged.to_device(p.in_schema)
+            gslot_d = jax.numpy.asarray(gslot)
+            key_d = jax.numpy.asarray(key_idx)
+            sel_d = jax.numpy.asarray(sel)
+            now_d = jax.numpy.asarray(now, jax.numpy.int64)
         in_tabs = self.app.in_probe_tables(p.in_deps)
-        with _maybe_span("step", query=self.name, kind="keyed-window"):
-            _st, out, wake = _step_phase(self, lambda: p.step(
-                self.state, batch.ts, batch.kind, batch.valid, batch.cols,
-                jax.numpy.asarray(gslot), jax.numpy.asarray(key_idx),
-                jax.numpy.asarray(sel),
-                jax.numpy.asarray(now, jax.numpy.int64), in_tabs))
-        _rebind_state(self, _st)
+        self.state, out, wake = _phases.dispatch(
+            self, p.step, self.state, batch.ts, batch.kind, batch.valid,
+            batch.cols, gslot_d, key_d, sel_d, now_d, in_tabs)
         wake_arg = None
         if p.needs_timer:
             if getattr(p.window, "host_scheduled", False):
@@ -634,7 +616,8 @@ class PatternQueryRuntime(_MeshResolved):
         the allocator's bindings are unchanged since the block was last
         resolved (`version`) and the keys compare equal, the C pass and
         group fill are pure functions of the block and replay from cache
-        (~30ms -> ~0.2ms per 131k-key send: 16% of flagship wall time)."""
+        (~30ms -> ~0.2ms per 131k-key send: 16% of flagship wall time).
+        Returns (key_idx, sel, memo hit)."""
         alloc = self.slot_allocator
         keys = key_cols[0] if len(key_cols) == 1 else None
         cacheable = (keys is not None and keys.dtype.kind in "iu" and
@@ -644,7 +627,7 @@ class PatternQueryRuntime(_MeshResolved):
             ent = self._block_cache.get(blk)
             if ent is not None and ent[0] == alloc.version and \
                     np.array_equal(keys, ent[3]):
-                return ent[1], ent[2]
+                return ent[1], ent[2], True
         _, key_idx, sel = alloc.slots_and_group(key_cols, valid,
                                                 pad=p.key_capacity)
         if cacheable:
@@ -652,7 +635,7 @@ class PatternQueryRuntime(_MeshResolved):
                 self._block_cache.clear()
             self._block_cache[blk] = (alloc.version, key_idx, sel,
                                       keys.copy())
-        return key_idx, sel
+        return key_idx, sel, False
 
     def process_staged(self, stream_id: str, staged: ev.StagedBatch,
                        now: int) -> None:
@@ -668,88 +651,62 @@ class PatternQueryRuntime(_MeshResolved):
         if self.shard_router is not None:
             self._process_sharded(stream_id, staged, now)
             return
-        # host prep wall (uploads, ts-wire fit check, key->slot routing)
-        # charges to stage_host right before the step — without it the
-        # pattern path's per-batch routing work lands in `other` and the
-        # flagship phase budget can't account its e2e (phases.py)
-        _prep0 = time.perf_counter_ns() if self.app.stats.enabled else None
-        raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
-        # ts-delta wire: ship (base scalar, i32 delta) instead of a fresh
-        # i64 column when the batch's span fits i32 (PERF.md lever 1);
-        # falls back to the plain i64 step otherwise
-        ts_wire = None
-        if p.steps_w is not None and staged.n:
-            # fit-check over the REAL rows only: a partial bucket's zero
-            # padding vs an epoch base would always fail it.  Padding
-            # rows (valid=False) reconstruct to `base` on device — their
-            # values are never read through a valid selection.
-            tsn = staged.ts[:staged.n]
-            base = tsn[0]
-            dmax = int(tsn.max()) - int(base)
-            dmin = int(tsn.min()) - int(base)
-            if dmax < 2**31 and dmin >= -(2**31):
-                delta32 = np.zeros(staged.ts.shape, np.int32)
-                delta32[:staged.n] = tsn - base
-                ts_wire = (jax.numpy.asarray(base, jax.numpy.int64),
-                           jax.numpy.asarray(delta32))
-        raw_ts = jax.numpy.asarray(staged.ts) if ts_wire is None else None
-        if p.partition_positions:
-            kf = (p.partition_key_fns or {}).get(stream_id)
-            if kf is not None:
-                key_cols, kvalid = kf(staged)
-                valid = staged.valid & kvalid
-            else:
-                pos = p.partition_positions[stream_id]
-                key_cols = [staged.cols[i] for i in pos]
-                valid = staged.valid
-            key_idx_np, sel = self._grouped_slots(key_cols, valid, p)
-            _stateobs_feed_group(self, self.slot_allocator, key_idx_np,
-                                 sel, p.key_capacity)
-            if self._touch is not None:
-                self._touch(key_idx_np, now)
-            sel_d = jax.numpy.asarray(sel)
-            # contiguous-slot fast path: dynamic-slice state access instead
-            # of row-serialized gather/scatter (see dense_steps)
-            Kb = key_idx_np.shape[0]
-            nuniq = int((key_idx_np < p.key_capacity).sum())
-            if self._dirty is not None and nuniq:
-                self._dirty[key_idx_np[:nuniq]] = True
-            # nuniq >= 2: the Kb=1 dense specialization trips an XLA:CPU
-            # fused-dynamic-slice codegen bug (RET_CHECK llvm_module), and a
-            # 1-row gather is as fast as a 1-row slice anyway
-            if (p.dense_steps is not None and nuniq > 1 and
-                    int(key_idx_np[0]) + Kb <= p.key_capacity and
-                    int(key_idx_np[nuniq - 1]) ==
-                    int(key_idx_np[0]) + nuniq - 1):
-                if self._dirty is not None:
-                    # the dense step also time-ticks slots beyond nuniq
-                    self._dirty[int(key_idx_np[0]):
-                                int(key_idx_np[0]) + Kb] = True
-                pstate, sel_state = self.state
-                key_lo = jax.numpy.asarray(int(key_idx_np[0]),
-                                           jax.numpy.int32)
-                now_d = jax.numpy.asarray(now, jax.numpy.int64)
-                if _prep0 is not None:
-                    self.app.stats.phases.add(
-                        self.name, "stage_host",
-                        time.perf_counter_ns() - _prep0)
-                if ts_wire is not None:
-                    pstate, sel_state, out, wake = _step_phase(
-                        self, lambda: p.dense_steps_w[stream_id](
-                            pstate, sel_state, raw_cols, ts_wire[0],
-                            ts_wire[1], sel_d, key_lo, now_d,
-                            self._in_tabs()))
+        st = self.app.stats
+        # the columns go up FIRST, as they always did: the upload is
+        # asynchronous, so the ~8 MB of a 524,288-event send cross the
+        # link while the host routes keys below (uploaded after host prep
+        # the transfer would sit on the critical path).  Then host prep,
+        # each part under its own span — key -> slot routing and the
+        # ts-wire build (route_keys), the observatory and liveness feeds
+        # (obs_feed) — then what prep produced goes up (h2d again), then
+        # the step (dispatch): no span's clock holds another's work
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_phases.nbytes(*staged.cols)):
+            raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
+        dense = False
+        key_idx_np = sel_np = delta32 = base = None
+        with _phases.phase(st, self.name, "route_keys") as sp:
+            # ts-delta wire: ship (base scalar, i32 delta) instead of a
+            # fresh i64 column when the batch's span fits i32 (PERF.md
+            # lever 1); falls back to the plain i64 step otherwise
+            if p.steps_w is not None and staged.n:
+                # fit-check over the REAL rows only: a partial bucket's
+                # zero padding vs an epoch base would always fail it.
+                # Padding rows (valid=False) reconstruct to `base` on
+                # device — their values are never read through a valid
+                # selection.
+                tsn = staged.ts[:staged.n]
+                base = tsn[0]
+                dmax = int(tsn.max()) - int(base)
+                dmin = int(tsn.min()) - int(base)
+                if dmax < 2**31 and dmin >= -(2**31):
+                    delta32 = np.zeros(staged.ts.shape, np.int32)
+                    delta32[:staged.n] = tsn - base
+            if p.partition_positions:
+                kf = (p.partition_key_fns or {}).get(stream_id)
+                if kf is not None:
+                    key_cols, kvalid = kf(staged)
+                    valid = staged.valid & kvalid
                 else:
-                    pstate, sel_state, out, wake = _step_phase(
-                        self, lambda: p.dense_steps[stream_id](
-                            pstate, sel_state, raw_cols, raw_ts, sel_d,
-                            key_lo, now_d, self._in_tabs()))
-                _rebind_state(self, (pstate, sel_state))
-                _emit_output(self, out, now, wake=self._wake_arg(wake))
-                return
-            key_idx = jax.numpy.asarray(key_idx_np)
-        else:
-            if staged.valid.all():
+                    pos = p.partition_positions[stream_id]
+                    key_cols = [staged.cols[i] for i in pos]
+                    valid = staged.valid
+                key_idx_np, sel_np, hit = self._grouped_slots(
+                    key_cols, valid, p)
+                Kb = key_idx_np.shape[0]
+                nuniq = int((key_idx_np < p.key_capacity).sum())
+                sp.set_metadata(keys=nuniq, memo_hit=int(hit))
+                # contiguous-slot fast path: dynamic-slice state access
+                # instead of row-serialized gather/scatter (see
+                # dense_steps).  nuniq >= 2: the Kb=1 dense specialization
+                # trips an XLA:CPU fused-dynamic-slice codegen bug
+                # (RET_CHECK llvm_module), and a 1-row gather is as fast
+                # as a 1-row slice anyway
+                dense = (p.dense_steps is not None and nuniq > 1 and
+                         int(key_idx_np[0]) + Kb <= p.key_capacity and
+                         int(key_idx_np[nuniq - 1]) ==
+                         int(key_idx_np[0]) + nuniq - 1)
+            elif staged.valid.all():
                 # full bucket: the identity selection is a constant per
                 # capacity — cached read-only so repeat sends dedupe
                 sel_np = _identity_sel(B)
@@ -757,26 +714,44 @@ class PatternQueryRuntime(_MeshResolved):
                 sel_np = np.where(staged.valid,
                                   np.arange(B, dtype=np.int32),
                                   -1)[None, :]
-            sel_d = jax.numpy.asarray(sel_np)
-            key_idx = jax.numpy.asarray(np.zeros((1,), np.int32))
-        pstate, sel_state = self.state
-        now_d = jax.numpy.asarray(now, jax.numpy.int64)
-        if _prep0 is not None:
-            self.app.stats.phases.add(self.name, "stage_host",
-                                      time.perf_counter_ns() - _prep0)
-        with _maybe_span("step", query=self.name, kind="pattern"):
-            if ts_wire is not None:
-                pstate, sel_state, out, wake = _step_phase(
-                    self, lambda: p.steps_w[stream_id](
-                        pstate, sel_state, raw_cols, ts_wire[0],
-                        ts_wire[1], sel_d, key_idx, now_d,
-                        self._in_tabs()))
+        if p.partition_positions:
+            with _phases.phase(st, self.name, "obs_feed"):
+                _stateobs_feed_group(self, self.slot_allocator, key_idx_np,
+                                     sel_np, p.key_capacity)
+                if self._touch is not None:
+                    self._touch(key_idx_np, now)
+                if self._dirty is not None and nuniq:
+                    self._dirty[key_idx_np[:nuniq]] = True
+                    if dense:
+                        # the dense step also time-ticks slots beyond nuniq
+                        self._dirty[int(key_idx_np[0]):
+                                    int(key_idx_np[0]) + Kb] = True
+        ts_np = staged.ts if delta32 is None else delta32
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_phases.nbytes(ts_np, sel_np)):
+            if delta32 is None:
+                ts_args = (jax.numpy.asarray(staged.ts),)
             else:
-                pstate, sel_state, out, wake = _step_phase(
-                    self, lambda: p.steps[stream_id](
-                        pstate, sel_state, raw_cols, raw_ts, sel_d,
-                        key_idx, now_d, self._in_tabs()))
-        _rebind_state(self, (pstate, sel_state))
+                ts_args = (jax.numpy.asarray(base, jax.numpy.int64),
+                           jax.numpy.asarray(delta32))
+            sel_d = jax.numpy.asarray(sel_np)
+            if dense:
+                key_d = jax.numpy.asarray(int(key_idx_np[0]),
+                                          jax.numpy.int32)
+            elif key_idx_np is not None:
+                key_d = jax.numpy.asarray(key_idx_np)
+            else:
+                key_d = jax.numpy.asarray(np.zeros((1,), np.int32))
+            now_d = jax.numpy.asarray(now, jax.numpy.int64)
+        if dense:
+            steps = p.dense_steps if delta32 is None else p.dense_steps_w
+        else:
+            steps = p.steps if delta32 is None else p.steps_w
+        pstate, sel_state = self.state
+        pstate, sel_state, out, wake = _phases.dispatch(
+            self, steps[stream_id], pstate, sel_state, raw_cols, *ts_args,
+            sel_d, key_d, now_d, self._in_tabs())
+        self.state = (pstate, sel_state)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def _shard_prep(self, stream_id: str, staged: ev.StagedBatch,
@@ -788,43 +763,30 @@ class PatternQueryRuntime(_MeshResolved):
         sequential sharded path and fused dispatch (core/fusion.py)."""
         p = self.planned
         router = self.shard_router
-        kf = (p.partition_key_fns or {}).get(stream_id)
-        if kf is not None:
-            key_cols, kvalid = kf(staged)
-            valid = staged.valid & kvalid
-        else:
-            pos = p.partition_positions[stream_id]
-            key_cols = [staged.cols[i] for i in pos]
-            valid = staged.valid
-        t0 = time.perf_counter_ns()
-        slots = self.slot_allocator.slots_for(key_cols, valid)
-        _stateobs_feed_slots(self, self.slot_allocator, slots)
-        if self._touch is not None:
-            self._touch(slots, now)
-        if self._dirty is not None:
-            live = slots[slots >= 0]
-            if live.size:
-                # global state column of slot s under the shard layout
-                self._dirty[router.state_row(live)] = True
-        key_idx, sel, counts = router.group(slots, staged.valid)
-        t1 = time.perf_counter_ns()
-        stats = self.app.stats
-        if stats.enabled:
-            stats.shard_events(self.name, counts)
-            # the [n, Kb, E] regroup is host staging work: it belongs to
-            # the stage_host phase even though it runs post-publish
-            stats.phases.add(self.name, "stage_host", t1 - t0)
-            tr = _tracing.active()
-            if tr is not None:
-                # per-shard sub-spans over the regroup wall: the even
-                # time split is nominal, but the per-shard event counts
-                # are real — trace viewers read the skew off the meta
-                n_sh = max(1, len(counts))
-                for d, c in enumerate(counts):
-                    tr.add_span(
-                        f"shard{d}", t0 + (t1 - t0) * d // n_sh,
-                        t0 + (t1 - t0) * (d + 1) // n_sh,
-                        {"query": self.name, "events": int(c)})
+        st = self.app.stats
+        with _phases.phase(st, self.name, "route_keys"):
+            kf = (p.partition_key_fns or {}).get(stream_id)
+            if kf is not None:
+                key_cols, kvalid = kf(staged)
+                valid = staged.valid & kvalid
+            else:
+                pos = p.partition_positions[stream_id]
+                key_cols = [staged.cols[i] for i in pos]
+                valid = staged.valid
+            slots = self.slot_allocator.slots_for(key_cols, valid)
+            # the [n, Kb, E] regroup is host staging work too
+            key_idx, sel, counts = router.group(slots, staged.valid)
+        with _phases.phase(st, self.name, "obs_feed"):
+            _stateobs_feed_slots(self, self.slot_allocator, slots)
+            if self._touch is not None:
+                self._touch(slots, now)
+            if self._dirty is not None:
+                live = slots[slots >= 0]
+                if live.size:
+                    # global state column of slot s under the shard layout
+                    self._dirty[router.state_row(live)] = True
+            if st.enabled:
+                st.shard_events(self.name, counts)
         return key_idx, sel
 
     def _process_sharded(self, stream_id: str, staged: ev.StagedBatch,
@@ -834,18 +796,19 @@ class PatternQueryRuntime(_MeshResolved):
         p = self.planned
         key_idx, sel = self._shard_prep(stream_id, staged, now)
         flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
+        with _phases.phase(self.app.stats, self.name, "h2d",
+                           bytes=_phases.nbytes(staged.ts, sel, key_idx,
+                                            *staged.cols)):
+            raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
+            ts_d = jax.numpy.asarray(staged.ts)
+            sel_d = jax.numpy.asarray(flat(sel))
+            key_d = jax.numpy.asarray(flat(key_idx))
+            now_d = jax.numpy.asarray(now, jax.numpy.int64)
         pstate, sel_state = self.state
-        with _maybe_span("step", query=self.name, kind="sharded-pattern"):
-            pstate, sel_state, out, wake = _step_phase(
-                self, lambda: p.steps[stream_id](
-                    pstate, sel_state,
-                    tuple(jax.numpy.asarray(c) for c in staged.cols),
-                    jax.numpy.asarray(staged.ts),
-                    jax.numpy.asarray(flat(sel)),
-                    jax.numpy.asarray(flat(key_idx)),
-                    jax.numpy.asarray(now, jax.numpy.int64),
-                    self._in_tabs()))
-        _rebind_state(self, (pstate, sel_state))
+        pstate, sel_state, out, wake = _phases.dispatch(
+            self, p.steps[stream_id], pstate, sel_state, raw_cols, ts_d,
+            sel_d, key_d, now_d, self._in_tabs())
+        self.state = (pstate, sel_state)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def on_timer(self, now: int) -> None:
@@ -853,16 +816,17 @@ class PatternQueryRuntime(_MeshResolved):
         if p.timer_step is None:
             return
         pstate, sel_state = self.state
-        pstate, sel_state, out, wake, changed = p.timer_step(
-            pstate, sel_state, jax.numpy.asarray(now, jax.numpy.int64),
-            self._in_tabs())
+        pstate, sel_state, out, wake, changed = _phases.dispatch(
+            self, p.timer_step, pstate, sel_state,
+            jax.numpy.asarray(now, jax.numpy.int64), self._in_tabs())
         self.state = (pstate, sel_state)
         if self._dirty is not None:
             # timer-driven expiry/absent firing mutates key NFA state;
             # without marking, incremental snapshots miss those changes and
             # a restore resurrects expired pending states.  The device
             # reports exactly which keys changed.
-            self._dirty |= np.asarray(jax.device_get(changed))
+            self._dirty |= np.asarray(_phases.fetch(
+                self.app.stats, self.name, "rows", changed))
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def _wake_arg(self, wake):
@@ -876,24 +840,31 @@ class PatternQueryRuntime(_MeshResolved):
             self.app._scheduler.notify_at(w, self)
 
 
-def _has_consumers(qr) -> bool:
-    """Anything downstream that would read this output?  Checked BEFORE any
-    device->host transfer so unconsumed outputs cost zero D2H traffic."""
-    if qr.callbacks or qr.batch_callbacks:
-        return True
+def _target_live(qr) -> bool:
+    """Does anything need this output as host rows — a table op, a rate
+    limiter, or a READER of its output target (a named window, a table,
+    a junction with a subscribing query or stream callback)?  Statistics
+    are not a reader: the output stream's throughput is counted from the
+    emission header (_emit_output_sync_impl), and nothing is fetched,
+    sorted or unpacked for a junction nobody reads."""
     if getattr(qr, "table_op", None) is not None or \
             getattr(qr, "rate_limiter", None) is not None:
         return True
-    p = qr.planned
-    if p.output_target:
-        app = qr.app
-        if p.output_target in getattr(app, "named_windows", {}) or \
-                p.output_target in getattr(app, "tables", {}):
-            return True
-        j = app.junctions.get(p.output_target)
-        return j is not None and bool(
-            j.queries or j.stream_callbacks or app.stats.enabled)
-    return False
+    tgt = qr.planned.output_target
+    if not tgt:
+        return False
+    app = qr.app
+    if tgt in getattr(app, "named_windows", {}) or \
+            tgt in getattr(app, "tables", {}):
+        return True
+    j = app.junctions.get(tgt)
+    return j is not None and bool(j.queries or j.stream_callbacks)
+
+
+def _has_consumers(qr) -> bool:
+    """Anything downstream that would read this output?  Checked BEFORE any
+    device->host transfer so unconsumed outputs cost zero D2H traffic."""
+    return bool(qr.callbacks or qr.batch_callbacks) or _target_live(qr)
 
 
 def _emit_output(qr, out, now: int, wake=None) -> None:
@@ -926,13 +897,13 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
         # @pipeline: a deferred wake scalar would stall expiry), and
         # serving takes precedence over @async/@pipeline below.
         from ..serving import ring_append
-        # handoff(): arm + carry the dispatch thread's trace so the
-        # drainer's delivery spans join it (None when tracing is off)
-        ring_append(qr, out, now, ingest_ns, _tracing.handoff())
+        # handoff(): carry the send's batch number and (armed) DETAIL
+        # trace so the drainer's delivery spans join them
+        ring_append(qr, out, now, ingest_ns, _phases.handoff())
         return
     if getattr(qr, "async_emit", False) and qr.app._drainer is not None:
         qr.app._drainer.enqueue(qr, out, now, wake, ingest_ns,
-                                _tracing.handoff())
+                                _phases.handoff())
         return
     depth = int(getattr(qr, "pipeline_emit", 0) or 0)
     if depth and wake is None and \
@@ -944,7 +915,7 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
         dq = getattr(qr, "_pending_emit", None)
         if dq is None:
             dq = qr._pending_emit = collections.deque()
-        dq.append((out, now, None, ingest_ns, _tracing.handoff()))
+        dq.append((out, now, None, ingest_ns, _phases.handoff()))
         if len(dq) > depth:
             if depth == 1:
                 # exactly-one-deep contract: each send delivers its
@@ -968,27 +939,24 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
 def _deliver_output(qr, out, now: int, wake, ingest_ns=None,
                     trace=None) -> None:
     """Blocking device->host fetch + delivery of one emission.  `trace`
-    is a handed-off BatchTrace for deferred (@pipeline) deliveries whose
+    is the handoff token of a deferred (@pipeline) delivery whose
     originating dispatch has moved on — delivery spans adopt it."""
-    t0 = time.perf_counter_ns()
-    # sampled window-fill probe rides THIS fetch (same device_get call:
-    # the never-fetch guard counts calls, and this adds none)
-    probe = _stateobs.take_fill_probe(qr)
-    if len(out) == 6:
-        header, wake_h, fills = jax.device_get(
-            ((out[0], out[1]), wake, probe))
-    else:
-        out, wake_h, fills = jax.device_get((out, wake, probe))
-        header = None
-    st = qr.app.stats
-    if st.enabled:
-        st.phases.add(qr.name, "d2h_drain",
-                      time.perf_counter_ns() - t0)
-    if fills is not None:
-        _stateobs.record_fill(qr, fills)
-    if wake_h is not None:
-        qr._apply_wake(int(wake_h))
-    with _tracing.adopt(trace):
+    with _phases.adopt(trace):
+        # sampled window-fill probe rides THIS fetch (same device_get call:
+        # the never-fetch guard counts calls, and this adds none)
+        probe = _stateobs.take_fill_probe(qr)
+        st = qr.app.stats
+        if len(out) == 6:
+            header, wake_h, fills = _phases.fetch(
+                st, qr.name, "header", ((out[0], out[1]), wake, probe))
+        else:
+            out, wake_h, fills = _phases.fetch(
+                st, qr.name, "rows", (out, wake, probe))
+            header = None
+        if fills is not None:
+            _stateobs.record_fill(qr, fills)
+        if wake_h is not None:
+            qr._apply_wake(int(wake_h))
         _emit_output_sync(qr, out, now, header=header, ingest_ns=ingest_ns)
 
 
@@ -998,23 +966,18 @@ def _deliver_many(qr, items) -> None:
     if len(items) == 1:
         _deliver_output(qr, *items[0])
         return
-    t0 = time.perf_counter_ns()
-    fetched = jax.device_get([
-        (out[0], out[1]) if len(out) == 6 else out
-        for out, _, _, _, _ in items])
-    fetch_ns = time.perf_counter_ns() - t0
     st = qr.app.stats
+    # latency attribution: the batched fetch wall charges to every item
+    # it served (`mult`), and the serialized wait behind predecessors'
+    # deliveries is queue residency — both are inside each item's e2e
+    # sample (see phases.py)
+    fetched = _phases.fetch(st, qr.name, "header", [
+        (out[0], out[1]) if len(out) == 6 else out
+        for out, _, _, _, _ in items], mult=len(items))
     loop_t0 = time.perf_counter_ns()
     for (out, now, _, t_in, trace), fetch_h in zip(items, fetched):
-        if st.enabled:
-            # latency attribution: the batched fetch wall charges to
-            # every item it served, and the serialized wait behind
-            # predecessors' deliveries is queue residency — both are
-            # inside each item's e2e sample (see phases.py)
-            st.phases.add(qr.name, "d2h_drain", fetch_ns)
-            st.phases.add(qr.name, "ring_wait",
-                          time.perf_counter_ns() - loop_t0)
-        with _tracing.adopt(trace):
+        _phases.waited(st, qr.name, loop_t0)
+        with _phases.adopt(trace):
             if len(out) == 6:
                 _emit_output_sync(qr, out, now, header=fetch_h,
                                   ingest_ns=t_in)
@@ -1053,9 +1016,13 @@ class _LazyBatchPayload(dict):
     _LAZY = ("ts", "kind", "valid", "cols")
     _COUNTS = ("n_valid", "n_current", "n_expired", "n_dropped")
 
-    def __init__(self, names, ots, okind, ovalid, ocols, counts=None):
+    def __init__(self, names, ots, okind, ovalid, ocols, counts=None,
+                 qr=None):
         super().__init__()
         self._names = names
+        # whose `fetch` spans the lazy pulls are (None: unattributed)
+        self._stats = qr.app.stats if qr is not None else None
+        self._qname = qr.name if qr is not None else None
         self._ots, self._okind = ots, okind
         self._ovalid, self._ocols = ovalid, ocols
         if counts:
@@ -1064,14 +1031,16 @@ class _LazyBatchPayload(dict):
 
     def __missing__(self, k):
         if k in ("ts", "kind", "valid"):
-            ts, kind, valid = jax.device_get(
+            ts, kind, valid = _phases.fetch(
+                self._stats, self._qname, "rows",
                 (self._ots, self._okind, self._ovalid))
             dict.__setitem__(self, "ts", ts)
             dict.__setitem__(self, "kind", kind)
             dict.__setitem__(self, "valid", valid)
             return dict.__getitem__(self, k)
         if k == "cols":
-            cols = jax.device_get(self._ocols)
+            cols = _phases.fetch(self._stats, self._qname, "rows",
+                                 self._ocols)
             v = dict(zip(self._names, cols))
             dict.__setitem__(self, k, v)
             return v
@@ -1125,18 +1094,14 @@ class _LazyBatchPayload(dict):
 
 def _emit_output_sync(qr, out, now: int, header=None,
                       ingest_ns=None) -> None:
-    """Emission with an `emit` span when a pipeline trace is active on
-    this thread — which now includes drainer threads: deferred deliveries
-    carry the dispatch side's handed-off trace and run under
-    `tracing.adopt`, so their spans (tagged track="drain") join the
-    originating trace.  `ingest_ns` (send-acceptance perf_counter_ns)
-    closes the `<query>:e2e` histogram here — after callbacks, downstream
-    routing, and the synchronous sink publish they trigger."""
+    """Emission on whichever thread delivers it — drainer threads run
+    this under `phases.adopt`, so its spans carry the originating send's
+    batch and join its DETAIL trace.  `ingest_ns` (send-acceptance
+    perf_counter_ns) closes the `<query>:e2e` histogram here — after
+    callbacks, downstream routing, and the synchronous sink publish they
+    trigger."""
     try:
-        if _tracing.active() is None:
-            return _emit_output_sync_impl(qr, out, now, header)
-        with _tracing.span("emit", query=qr.name):
-            return _emit_output_sync_impl(qr, out, now, header)
+        return _emit_output_sync_impl(qr, out, now, header)
     finally:
         if ingest_ns is not None:
             st = qr.app.stats
@@ -1171,40 +1136,33 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
     Pattern outputs (len-6) may still hold DEVICE arrays here; only the
     count header has been fetched.  Bulk rows transfer lazily through the
     payload / the event-delivery path below.  Plain outputs (len-4) arrive
-    fully fetched (they are bounded by the window batch capacity)."""
-    p = qr.planned
-    target_live = getattr(qr, "table_op", None) is not None or \
-        getattr(qr, "rate_limiter", None) is not None
-    if p.output_target and not target_live:
-        app = qr.app
-        if p.output_target in getattr(app, "named_windows", {}) or \
-                p.output_target in getattr(app, "tables", {}):
-            target_live = True
-        else:
-            j = app.junctions.get(p.output_target)
-            target_live = j is not None and bool(
-                j.queries or j.stream_callbacks or app.stats.enabled)
+    fully fetched (they are bounded by the window batch capacity).
+
+    One `demux` span covers the delivery; the device fetches paid here
+    (`fetch`) and the consumer-facing work (`sink`) nest in it, so its
+    self time is the rest — header decode, unpack, ts-order restore."""
+    target_live = _target_live(qr)
     if not (qr.callbacks or qr.batch_callbacks or target_live):
         return
     if qr.app.stats.detail:
         # reference: log4j TRACE at QuerySelector.process :77
         _trace_log.debug("query %s: emitting output batch @ %d",
                          qr.name, now)
+    with _phases.phase(qr.app.stats, qr.name, "demux") as span:
+        _demux_and_deliver(qr, out, now, header, target_live, span)
+
+
+def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
+                       span) -> None:
+    p = qr.planned
+    _st = qr.app.stats
     counts = None
     overflow_exc = None
-    # phase split of this delivery: device fetches paid here (`d2h_drain`),
-    # consumer-facing work (`sink`), and everything else — header decode,
-    # unpack, ts-order restore — as `demux`
-    _st = qr.app.stats
-    _ph_t0 = time.perf_counter_ns() if _st.enabled else None
-    _sink_ns = 0
-    _fetch_ns = 0
     if len(out) == 6:
         n_valid, n_dropped, ots, okind, ovalid, ocols = out
         if header is None:
-            _tf = time.perf_counter_ns()
-            header = jax.device_get((n_valid, n_dropped))
-            _fetch_ns += time.perf_counter_ns() - _tf
+            header = _phases.fetch(_st, qr.name, "header",
+                                   (n_valid, n_dropped))
         h0 = np.asarray(header[0])
         nd = int(header[1])
         if h0.ndim:
@@ -1215,7 +1173,6 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
         if nd:
             # dropped-row counter BEFORE the growth attempt: even when the
             # cap grows for the next batch, THIS batch lost nd rows
-            _st = qr.app.stats
             if _st.enabled:
                 _st.counter_inc(f"{qr.name}.dropped", nd)
             what = ("join result rows exceeded the emission"
@@ -1255,10 +1212,11 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
         # the sizing ledger persists for @emit pre-sizing
         _cap = getattr(qr.planned, "compact_rows", None)
         if _cap is not None and _stateobs.obs_enabled(qr.app):
-            qr.app.stats.stateobs.observe(
-                qr.name, "emission_cap", nv + nd, _cap,
-                growable=not getattr(qr.planned, "emit_explicit", True),
-                config_key="@emit(rows='N')")
+            with _phases.phase(_st, qr.name, "obs_feed"):
+                _st.stateobs.observe(
+                    qr.name, "emission_cap", nv + nd, _cap,
+                    growable=not getattr(qr.planned, "emit_explicit", True),
+                    config_key="@emit(rows='N')")
     try:
         if len(out) == 6:
             if nv == 0:
@@ -1270,7 +1228,7 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
             if not ovalid_np.any():
                 return
             rows_out = int(ovalid_np.sum())
-        _st = qr.app.stats
+        span.set_metadata(rows=rows_out)
         if _st.enabled and rows_out:
             # per-tenant events_out/emitted_bytes accounting: row count is
             # already host-side (header / staged valid plane) and the byte
@@ -1282,10 +1240,8 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
             # callbacks, batch payloads, downstream routing, table writes)
             # observes the same id per row
             if len(out) == 6:
-                _tf = time.perf_counter_ns()
-                ots, okind, ovalid, ocols = jax.device_get(
-                    (ots, okind, ovalid, ocols))
-                _fetch_ns += time.perf_counter_ns() - _tf
+                ots, okind, ovalid, ocols = _phases.fetch(
+                    _st, qr.name, "rows", (ots, okind, ovalid, ocols))
             changed = ev.materialize_uuid_sentinels(
                 p.out_schema, np.asarray(ovalid), ocols)
             if changed:
@@ -1295,22 +1251,26 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
                 ocols = tuple(oc)
         if qr.batch_callbacks:
             payload = _LazyBatchPayload(p.out_schema.names, ots, okind,
-                                        ovalid, ocols, counts)
-            _ts = time.perf_counter_ns()
-            for bcb in qr.batch_callbacks:
-                bcb(now, payload)
-            _sink_ns += time.perf_counter_ns() - _ts
+                                        ovalid, ocols, counts, qr)
+            with _phases.phase(_st, qr.name, "sink"):
+                for bcb in qr.batch_callbacks:
+                    bcb(now, payload)
         if not qr.callbacks and not target_live:
+            if _st.enabled and p.output_target in qr.app.junctions:
+                # an output stream nobody reads as events: its throughput
+                # is counted from numbers already on the host (a routed
+                # emission is counted by the junction's publish) — nothing
+                # is fetched, sorted or unpacked for the statistics' sake
+                _st.stream_in(p.output_target, _routed_rows(
+                    p, rows_out, counts, okind, ovalid))
             return
         if len(out) == 6:
             # pattern outputs are compacted [R,K] rank-major on device;
             # fetch them now and restore timestamp order for event delivery
             # with a host-side stable sort of just the valid rows
             # (O(matches), runs on the drainer thread)
-            _tf = time.perf_counter_ns()
-            ts_np, okind, ovalid_np, ocols = jax.device_get(
-                (ots, okind, ovalid, ocols))
-            _fetch_ns += time.perf_counter_ns() - _tf
+            ts_np, okind, ovalid_np, ocols = _phases.fetch(
+                _st, qr.name, "rows", (ots, okind, ovalid, ocols))
             idxv = np.nonzero(ovalid_np)[0]
             order = idxv[np.argsort(ts_np[idxv], kind="stable")]
             ots = ts_np[order]
@@ -1322,35 +1282,37 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
                           want_kinds=(ev.CURRENT, ev.EXPIRED))
         if not pairs:
             return
-        if getattr(qr, "table_op", None) is not None:
-            _ts = time.perf_counter_ns()
-            current = [e for k, e in pairs if k == ev.CURRENT]
-            expired = [e for k, e in pairs if k == ev.EXPIRED]
-            for cb in qr.callbacks:
-                cb(now, current or None, expired or None)
-            _apply_table_op(qr, ots, okind, ovalid, ocols, now)
-            _sink_ns += time.perf_counter_ns() - _ts
-            return
-        limiter = getattr(qr, "rate_limiter", None)
-        if limiter is not None:
-            _ts = time.perf_counter_ns()
-            limiter.process(pairs, now)
-            _sink_ns += time.perf_counter_ns() - _ts
-            return
-        _ts = time.perf_counter_ns()
-        _deliver_pairs(qr, pairs, now)
-        _sink_ns += time.perf_counter_ns() - _ts
+        with _phases.phase(_st, qr.name, "sink"):
+            if getattr(qr, "table_op", None) is not None:
+                current = [e for k, e in pairs if k == ev.CURRENT]
+                expired = [e for k, e in pairs if k == ev.EXPIRED]
+                for cb in qr.callbacks:
+                    cb(now, current or None, expired or None)
+                _apply_table_op(qr, ots, okind, ovalid, ocols, now)
+                return
+            limiter = getattr(qr, "rate_limiter", None)
+            if limiter is not None:
+                limiter.process(pairs, now)
+                return
+            _deliver_pairs(qr, pairs, now)
     finally:
-        if _ph_t0 is not None:
-            _ph = _st.phases
-            if _sink_ns:
-                _ph.add(qr.name, "sink", _sink_ns)
-            if _fetch_ns:
-                _ph.add(qr.name, "d2h_drain", _fetch_ns)
-            _ph.add(qr.name, "demux",
-                    time.perf_counter_ns() - _ph_t0 - _sink_ns - _fetch_ns)
         if overflow_exc is not None:
             raise overflow_exc
+
+
+def _routed_rows(p, rows_out: int, counts, okind, ovalid) -> int:
+    """How many of an emission's rows `_deliver_pairs` would route to the
+    output target (`insert [current|expired|all] events into`), from
+    numbers already on the host: the header's counts for compacted
+    outputs, the fetched kind plane for plain ones."""
+    sel = p.output_event_type
+    if sel not in ("CURRENT_EVENTS", "EXPIRED_EVENTS"):
+        return rows_out
+    if counts is not None:
+        return counts["n_current" if sel == "CURRENT_EVENTS"
+                      else "n_expired"]
+    want = ev.CURRENT if sel == "CURRENT_EVENTS" else ev.EXPIRED
+    return int((np.asarray(ovalid) & (np.asarray(okind) == want)).sum())
 
 
 def _aggregation_view(agg, per: str, within) -> Tuple:
@@ -1518,23 +1480,27 @@ class JoinQueryRuntime(_MeshResolved):
             return cached
         from .join import _norm_key_cols
         p = self.planned
-        kvalid = staged.valid & (staged.kind == ev.CURRENT)
-        pos = p.key_left if is_left else p.key_right
-        slots = self._jk.track(
-            is_left, _norm_key_cols(staged.cols, pos, p.key_dtypes),
-            kvalid)
-        need = self._jk.needed_k()
-        if need > p.lane_k:
-            self._grow_lane_k(need)
-        out = np.where(kvalid, slots, -1).astype(np.int32)
+        st = self.app.stats
+        with _phases.phase(st, self.name, "route_keys"):
+            kvalid = staged.valid & (staged.kind == ev.CURRENT)
+            pos = p.key_left if is_left else p.key_right
+            slots = self._jk.track(
+                is_left, _norm_key_cols(staged.cols, pos, p.key_dtypes),
+                kvalid)
+            need = self._jk.needed_k()
+            if need > p.lane_k:
+                self._grow_lane_k(need)
+            out = np.where(kvalid, slots, -1).astype(np.int32)
         if _stateobs.obs_enabled(self.app):
             # lane demand is a running bucket-occupancy max the tracker
             # already mirrors host-side; push it so the HWM survives
             # window expiry shrinking the live lanes back down
-            self.app.stats.stateobs.observe(
-                self.name, "join_lane", need, self.planned.lane_k,
-                growable=True, config_key="auto (lane grows via replan)")
-            _stateobs_feed_slots(self, p.join_key_allocator, out)
+            with _phases.phase(st, self.name, "obs_feed"):
+                st.stateobs.observe(
+                    self.name, "join_lane", need, self.planned.lane_k,
+                    growable=True,
+                    config_key="auto (lane grows via replan)")
+                _stateobs_feed_slots(self, p.join_key_allocator, out)
         cache[key] = out
         return out
 
@@ -1648,8 +1614,9 @@ class JoinQueryRuntime(_MeshResolved):
         gpos = p.gl_pos if is_left else p.gr_pos
         if galloc is None:
             return _zero_slots(staged.ts.shape[0])
-        gvalid = staged.valid & (staged.kind != ev.TIMER)
-        return galloc.slots_for([staged.cols[i] for i in gpos], gvalid)
+        with _phases.phase(self.app.stats, self.name, "route_keys"):
+            gvalid = staged.valid & (staged.kind != ev.TIMER)
+            return galloc.slots_for([staged.cols[i] for i in gpos], gvalid)
 
     def process_staged(self, is_left: bool, staged: ev.StagedBatch,
                        now: int) -> None:
@@ -1669,20 +1636,27 @@ class JoinQueryRuntime(_MeshResolved):
                                        is_left):
             return
         gslot = self._join_slots(is_left, staged)
-        batch = staged.to_device(side.schema)
-        args = [self.state, batch.ts, batch.kind, batch.valid, batch.cols,
-                jax.numpy.asarray(gslot)]
+        st = self.app.stats
+        extra = ()
         if p.fastpath == "bucket":
-            args.append(jax.numpy.asarray(probe))
+            extra = (probe,)
         elif p.fastpath == "table":
-            cand, ok = self._table_probe(staged)
-            args.append((jax.numpy.asarray(cand), jax.numpy.asarray(ok)))
-        args += [self._other_table(is_left),
-                 jax.numpy.asarray(now, jax.numpy.int64)]
-        with _maybe_span("step", query=self.name, kind="join"):
-            _st, out, wake = _step_phase(
-                self, lambda: step(*args))
-        _rebind_state(self, _st)
+            with _phases.phase(st, self.name, "route_keys"):
+                extra = self._table_probe(staged)
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_staged_nbytes(staged) +
+                           _phases.nbytes(gslot, *extra)):
+            batch = staged.to_device(side.schema)
+            args = [self.state, batch.ts, batch.kind, batch.valid,
+                    batch.cols, jax.numpy.asarray(gslot)]
+            if p.fastpath == "bucket":
+                args.append(jax.numpy.asarray(probe))
+            elif p.fastpath == "table":
+                args.append((jax.numpy.asarray(extra[0]),
+                             jax.numpy.asarray(extra[1])))
+            args += [self._other_table(is_left),
+                     jax.numpy.asarray(now, jax.numpy.int64)]
+        self.state, out, wake = _phases.dispatch(self, step, *args)
         _emit_output(self, out, now,
                      wake=wake if p.needs_timer else None)
 
@@ -1786,7 +1760,8 @@ class NamedWindowRuntime:
         # (_other_table) without holding _qlock through their own step —
         # donation would let a concurrent ingest delete the buffers a
         # join just captured
-        self._step = jit_step(step, owner=f"window:{wdef.id}")
+        self._step = jit_step(step, owner=f"window:{wdef.id}",
+                              role="window_step")
         self.state = jax.tree.map(
             lambda x: jax.numpy.array(x, copy=True), wproc.init_state())
 
@@ -1795,10 +1770,13 @@ class NamedWindowRuntime:
         return self.definition.id
 
     def process_staged(self, staged: ev.StagedBatch, now: int) -> None:
-        batch = staged.to_device(self.schema)
-        self.state, out, wake = self._step(
-            self.state, batch.ts, batch.kind, batch.valid, batch.cols,
-            jax.numpy.asarray(now, jax.numpy.int64))
+        with _phases.phase(self.app.stats, self.name, "h2d",
+                           bytes=_staged_nbytes(staged)):
+            batch = staged.to_device(self.schema)
+            now_d = jax.numpy.asarray(now, jax.numpy.int64)
+        self.state, out, wake = _phases.dispatch(
+            self, self._step, self.state, batch.ts, batch.kind,
+            batch.valid, batch.cols, now_d)
         self._fanout(out, now)
         if self.needs_timer:
             w = int(wake)
@@ -1864,6 +1842,10 @@ class StreamJunction:
         self.app = app
         self.queries: List[QueryRuntime] = []
         self.stream_callbacks: List[Callable] = []
+        # per-junction send sequence: the `batch` every span of one send
+        # carries, on whatever thread it runs (observability/phases.py)
+        self._batch_seq = itertools.count(1)
+        self._sub_names_memo: Optional[Tuple[str, ...]] = None
         # @async(buffer.size, workers): bounded ingress queue + worker
         # threads (the reference's Disruptor ring,
         # StreamJunction.java:276-313).  None => synchronous dispatch.
@@ -1903,35 +1885,43 @@ class StreamJunction:
             t.start()
             self._async_workers.append(t)
 
+    def sub_names(self) -> Tuple[str, ...]:
+        """Names of the subscribing queries: a junction-level span (stage,
+        accept-edge upload) charges each of them, as each one's e2e
+        sample contains it (see phases.py).  Memoized: subscriptions
+        change at wiring time only (subscribe_query, the merge pass)."""
+        names = self._sub_names_memo
+        if names is None:
+            names = self._sub_names_memo = tuple(
+                _sub_name(q, self.stream_id) for q in self.queries)
+        return names
+
     def _serve_stage(self, staged) -> None:
         """Double-buffered H2D staging (serving/staging.py): when any
         subscriber runs the serving loop, the batch's device upload
         starts HERE at the accept edge — batch N+1's transfer overlaps
-        batch N's compute (and, on the @async path, the queue wait)."""
+        batch N's compute (and, on the @async path, the queue wait).
+        Idempotent: a batch staged at enqueue is skipped at dispatch."""
         on = getattr(self, "_serve_staging", None)
         if on is None:
             # memoized on first dispatch: wiring is complete by then
             on = self._serve_staging = any(
                 getattr(getattr(q, "_qr", q), "serve_emit", False)
                 for q in self.queries)
-        if on and self.app is not None:
+        if on and self.app is not None and staged.dev is None:
             st = getattr(self.app, "_serve_stager", None)
             if st is not None:
-                st.stage(staged, self.schema)
+                with _phases.phase(self.app.stats, self.sub_names(), "h2d",
+                                   bytes=_staged_nbytes(staged)):
+                    st.stage(staged, self.schema)
 
     def enqueue(self, tag: str, payload, now: int) -> None:
         q = self._async_q
         stats = self.app.stats if self.app is not None else None
         if tag == "staged":
-            s0 = time.perf_counter_ns()
+            # @async accept-edge upload: paid here, not in
+            # dispatch_staged's idempotent re-call
             self._serve_stage(payload)
-            if stats is not None and stats.enabled:
-                # @async accept-edge upload: the h2d wall is paid here,
-                # not in dispatch_staged's idempotent re-call
-                h2d_ns = time.perf_counter_ns() - s0
-                ph = stats.phases
-                for sub in self.queries:
-                    ph.add(_sub_name(sub, self.stream_id), "h2d", h2d_ns)
         # ingest stamp taken BEFORE the queue put: the `<query>:e2e`
         # histogram must include @async queue wait, not start at dispatch
         t_in = time.perf_counter_ns() \
@@ -1942,14 +1932,17 @@ class StreamJunction:
             else:
                 self.publish(payload, now, ingest_ns=t_in)
             return
+        # the send's batch number rides the queue beside the stamp, so
+        # the worker's spans carry it
+        item = (tag, payload, now, t_in, _phases.current_batch())
         if self._async_policy == "shed":
             import queue as _queue
             try:
-                q.put_nowait((tag, payload, now, t_in))
+                q.put_nowait(item)
             except _queue.Full:
                 self._shed_async(tag, payload)
             return
-        q.put((tag, payload, now, t_in))
+        q.put(item)
 
     def _shed_async(self, tag: str, payload) -> None:
         """@async(queue.policy='shed') full-queue drop: loud and counted
@@ -1970,14 +1963,15 @@ class StreamJunction:
 
     def _drain_async(self) -> None:
         while True:
-            tag, payload, now, t_in = self._async_q.get()
+            tag, payload, now, t_in, batch = self._async_q.get()
             try:
                 if tag == "stop":
                     return
-                if tag == "staged":
-                    self.dispatch_staged(payload, now, ingest_ns=t_in)
-                else:
-                    self.publish(payload, now, ingest_ns=t_in)
+                with _phases.batch_scope(batch):
+                    if tag == "staged":
+                        self.dispatch_staged(payload, now, ingest_ns=t_in)
+                    else:
+                        self.publish(payload, now, ingest_ns=t_in)
             except Exception:  # noqa: BLE001 — worker must survive
                 import traceback
                 traceback.print_exc()
@@ -2010,7 +2004,7 @@ class StreamJunction:
             return
         self._async_q.join()
         for _ in self._async_workers:
-            self._async_q.put(("stop", None, 0, None))
+            self._async_q.put(("stop", None, 0, None, 0))
         for t in self._async_workers:
             t.join(timeout=2.0)
         self._async_workers.clear()
@@ -2018,6 +2012,7 @@ class StreamJunction:
 
     def subscribe_query(self, q: QueryRuntime) -> None:
         self.queries.append(q)
+        self._sub_names_memo = None
 
     def subscribe_callback(self, cb: Callable) -> None:
         self.stream_callbacks.append(cb)
@@ -2079,9 +2074,7 @@ class StreamJunction:
         """Run every subscribed query over a staged batch, serialized per
         QUERY (not per app) so queries on different streams — or workers of
         different streams — process concurrently."""
-        s0 = time.perf_counter_ns()
-        self._serve_stage(staged)   # idempotent (skips if prestaged)
-        s1 = time.perf_counter_ns()
+        self._serve_stage(staged)
         stats = self.app.stats if self.app is not None else None
         if stats is None or not stats.enabled:
             for q in self.queries:
@@ -2092,10 +2085,6 @@ class StreamJunction:
             return
         if ingest_ns is None:
             ingest_ns = time.perf_counter_ns()   # synchronous send path
-        if s1 > s0:
-            ph = stats.phases
-            for q in self.queries:
-                ph.add(_sub_name(q, self.stream_id), "h2d", s1 - s0)
         stats.stream_in(self.stream_id, staged.n)
         tr = stats.tracer.start(self.stream_id, staged.n) \
             if stats.detail else None
@@ -2122,10 +2111,13 @@ class StreamJunction:
                 ingest_ns=None) -> None:
         stats = self.app.stats if self.app is not None else None
         if stats is None or not stats.enabled:
-            for cb in self.stream_callbacks:
-                cb(events)
+            if self.stream_callbacks:
+                with _phases.phase(stats, None, "sink"):
+                    for cb in self.stream_callbacks:
+                        cb(events)
             if self.queries:
-                staged = ev.pack_np(self.schema, events)
+                with _phases.phase(stats, self.sub_names(), "stage"):
+                    staged = ev.pack_np(self.schema, events)
                 self._serve_stage(staged)
                 for q in self.queries:
                     try:
@@ -2145,23 +2137,16 @@ class StreamJunction:
                 self.stream_id, len(events), len(self.queries), now)
         j0 = time.perf_counter_ns()
         try:
-            for cb in self.stream_callbacks:
-                cb(events)
+            if self.stream_callbacks:
+                with _phases.phase(stats, None, "sink"):
+                    for cb in self.stream_callbacks:
+                        cb(events)
             if self.queries:
-                s0 = time.perf_counter_ns()
-                with (_tracing.span("ingest", stream=self.stream_id)
-                      if tr is not None else _NULL_CM):
+                # pack and upload walls charge to every subscriber, as
+                # their e2e does (see phases.py)
+                with _phases.phase(stats, self.sub_names(), "stage"):
                     staged = ev.pack_np(self.schema, events)
-                s1 = time.perf_counter_ns()
                 self._serve_stage(staged)
-                s2 = time.perf_counter_ns()
-                # per-query latency attribution (see phases.py): pack and
-                # upload walls charge to every subscriber, as their e2e does
-                ph = stats.phases
-                for q in self.queries:
-                    qn = _sub_name(q, self.stream_id)
-                    ph.add(qn, "stage_host", s1 - s0)
-                    ph.add(qn, "h2d", s2 - s1)
                 for q in self.queries:
                     try:
                         self._dispatch_one(q, staged, now, stats,
@@ -2505,36 +2490,34 @@ class _EmissionDrainer:
                     break
             # one roundtrip for ALL queued outputs: pattern outs (len 6)
             # contribute only their 16-byte count header; plain outs are
-            # window-capacity bounded and ship whole
-            t_fetch = time.perf_counter_ns()
+            # window-capacity bounded and ship whole.  Latency
+            # attribution: the batched fetch charges to every item it
+            # served, and a later item's serialized wait behind its
+            # predecessors' deliveries counts as queue residency — both
+            # inside its e2e sample (see phases.py).  The drainer is one
+            # app's, so one statistics manager; the span carries the
+            # first item's batch
+            st = items[0][0].app.stats
             try:
-                fetched = jax.device_get([
-                    ((out[0], out[1]), wake) if len(out) == 6
-                    else (out, wake)
-                    for _, out, _, wake, _, _ in items])
+                with _phases.adopt(items[0][5]):
+                    fetched = _phases.fetch(
+                        st, tuple(it[0].name for it in items), "header", [
+                            ((out[0], out[1]), wake) if len(out) == 6
+                            else (out, wake)
+                            for _, out, _, wake, _, _ in items])
             except Exception:  # noqa: BLE001 — drainer must survive
                 traceback.print_exc()
                 fetched = [(None, None)] * len(items)
-            fetch_ns = time.perf_counter_ns() - t_fetch
             loop_t0 = time.perf_counter_ns()
             for (qr, out, now, _, t_in, trace), (fetch_h, wake_h) in \
                     zip(items, fetched):
                 try:
-                    st = qr.app.stats
-                    if st.enabled:
-                        # latency attribution: the batched fetch charges
-                        # to every item it served, and a later item's
-                        # serialized wait behind its predecessors'
-                        # deliveries counts as queue residency — both
-                        # inside its e2e sample (see phases.py)
-                        st.phases.add(qr.name, "d2h_drain", fetch_ns)
-                        st.phases.add(qr.name, "ring_wait",
-                                      time.perf_counter_ns() - loop_t0)
+                    _phases.waited(st, qr.name, loop_t0)
                     if wake_h is not None:
                         qr._apply_wake(int(wake_h))
                     if fetch_h is None:
                         continue
-                    with _tracing.adopt(trace):
+                    with _phases.adopt(trace):
                         if len(out) == 6:
                             _emit_output_sync(qr, out, now, header=fetch_h,
                                               ingest_ns=t_in)
@@ -2590,13 +2573,29 @@ class _Scheduler:
         try:
             while self._heap and self._heap[0][0] <= now:
                 ts, _, q = heapq.heappop(self._heap)
-                lk = getattr(q, "_qlock", None)
-                if lk is None:
-                    lk = q.__dict__.setdefault("_qlock", threading.RLock())
-                with lk:
-                    q.on_timer(ts)
+                self._fire(q, ts)
         finally:
             self._draining = False
+
+    def pending(self) -> int:
+        """Timers on the heap right now (the `siddhi_timers_pending`
+        gauge).  `notify_at` pushes without looking for an entry the
+        target already has, so this is where a pile-up shows."""
+        return len(self._heap)
+
+    def _fire(self, q, ts: int) -> None:
+        """One timer step under the target's query lock, inside a `timer`
+        span carrying the heap's depth.  Targets without a query lock get
+        their own (NOT the app lock — a timer target holding the app lock
+        while taking query locks downstream could deadlock against a
+        worker emitting into a named window)."""
+        lk = getattr(q, "_qlock", None)
+        if lk is None:
+            lk = q.__dict__.setdefault("_qlock", threading.RLock())
+        name = _sub_name(q, getattr(q, "stream_id", "timer"))
+        with _phases.phase(self.app.stats, name, "timer",
+                           pending=len(self._heap)), lk:
+            q.on_timer(ts)
 
     def stop(self):
         with self._cv:
@@ -2626,17 +2625,8 @@ class _Scheduler:
                     continue
                 heapq.heappop(self._heap)
             try:
-                # serialize against the target's ingestion workers; targets
-                # without a query lock get their own (NOT the app lock — a
-                # timer target holding the app lock while taking query
-                # locks downstream could deadlock against a worker emitting
-                # into a named window)
-                lk = getattr(q, "_qlock", None)
-                if lk is None:
-                    lk = q.__dict__.setdefault(
-                        "_qlock", threading.RLock())
-                with lk:
-                    q.on_timer(max(ts, self.app.timestamp_millis()))
+                # serialized against the target's ingestion workers
+                self._fire(q, max(ts, self.app.timestamp_millis()))
             except Exception:  # noqa: BLE001 - scheduler must survive
                 import traceback
                 traceback.print_exc()
@@ -3752,10 +3742,28 @@ class SiddhiAppRuntime:
         junction = self.junctions.get(stream_id)
         if junction is None:
             raise DefinitionNotExistError(f"undefined stream {stream_id!r}")
-        pack_t0 = time.perf_counter_ns()
+        with _phases.phase(self.stats, junction.sub_names(), "stage"):
+            staged = self._stage_columns(junction.schema, cols, timestamps)
+        n = staged.n
+        ts = staged.ts
+        if self.playback and n:
+            with self._lock:   # vs the idle-advance thread's bump
+                self._playback_time = max(self._playback_time,
+                                          int(ts[:n].max()))
+                self._playback_last_wall = current_millis()
+        now = self.timestamp_millis()
+        if self.playback:
+            with self._lock:
+                self._scheduler.drain_playback(now)
+        elif junction._async_q is not None:
+            junction.enqueue("staged", staged, now)
+            return
+        junction.dispatch_staged(staged, now)
+
+    def _stage_columns(self, schema, cols, timestamps) -> ev.StagedBatch:
+        """Columnar pad/adopt staging of one send_columns call."""
         n = len(cols[0])
         cap = ev.bucket_size(max(n, 1))
-        schema = junction.schema
         if timestamps is None:
             ts0 = self.timestamp_millis()
             ts = np.full((cap,), ts0, np.int64)
@@ -3788,27 +3796,7 @@ class SiddhiAppRuntime:
             a = np.zeros((cap,), d)
             a[:n] = c
             padded.append(a)
-        staged = ev.StagedBatch(ts, kind, valid, padded, n)
-        if self.stats.enabled and junction.queries:
-            # columnar pad/adopt staging: stage_host for every subscriber
-            # (pack_np-path sends get the same charge inside publish)
-            pack_ns = time.perf_counter_ns() - pack_t0
-            ph = self.stats.phases
-            for sub in junction.queries:
-                ph.add(_sub_name(sub, stream_id), "stage_host", pack_ns)
-        if self.playback and n:
-            with self._lock:   # vs the idle-advance thread's bump
-                self._playback_time = max(self._playback_time,
-                                          int(ts[:n].max()))
-                self._playback_last_wall = current_millis()
-        now = self.timestamp_millis()
-        if self.playback:
-            with self._lock:
-                self._scheduler.drain_playback(now)
-        elif junction._async_q is not None:
-            junction.enqueue("staged", staged, now)
-            return
-        junction.dispatch_staged(staged, now)
+        return ev.StagedBatch(ts, kind, valid, padded, n)
 
     def _route(self, stream_id: str, events: List[ev.Event]) -> None:
         if stream_id in self.named_windows:
@@ -3900,6 +3888,10 @@ class SiddhiAppRuntime:
             return d.depth()
         except Exception:  # noqa: BLE001 — metrics must not throw
             return 0
+
+    def timers_pending(self) -> int:
+        """Timers on the scheduler's heap (siddhi_timers_pending)."""
+        return self._scheduler.pending()
 
     def serve_rings(self) -> Dict[str, "object"]:
         """{query: EmissionRing} for every runtime that has opened a

@@ -61,7 +61,7 @@ def strongify(tree):
     return jax.tree.map(_strong_leaf, tree)
 
 
-def fuse_step(body, owner=None):
+def fuse_step(body, owner=None, role=None):
     """K query steps in ONE device dispatch: `body(carry, x, const) ->
     (carry', y)` becomes a jitted `fused(carry, xs, const) -> (carry',
     ys)` running `lax.scan` over the leading [K] axis of every `xs` leaf.
@@ -86,10 +86,10 @@ def fuse_step(body, owner=None):
             return strongify(c2), y
         return jax.lax.scan(scan_body, carry, xs)
 
-    return jit_step(fused, owner=owner, donate_argnums=(0,))
+    return jit_step(fused, owner=owner, role=role, donate_argnums=(0,))
 
 
-def jit_step(fn, owner=None, **jit_kwargs):
+def jit_step(fn, owner=None, role=None, **jit_kwargs):
     """`jax.jit` with compile-signature-stable outputs: every returned
     leaf is strong-typed, so feeding returned state back into the step
     can never re-trace.  Drop-in for `jax.jit(fn, donate_argnums=...)`.
@@ -97,12 +97,18 @@ def jit_step(fn, owner=None, **jit_kwargs):
     `owner` labels this step for recompile accounting: the wrapped body
     only executes while jax is TRACING a new signature, so recording there
     counts exactly the compile events — with the triggering abstract
-    shapes — at zero steady-state cost (observability/recompile.py).  A
-    DETAIL-level pipeline trace active on the tracing thread additionally
-    gets a `compile` span, making a recompile-stalled batch self-evident
-    in its trace dump."""
+    shapes — at zero steady-state cost (observability/recompile.py).  The
+    trace runs inside a `siddhi:compile` span (observability/phases.py),
+    so a recompile-stalled batch is self-evident in a profiler capture
+    and in a DETAIL trace dump.
+
+    `role` names the traced function, so the XLA module — and every
+    device op of it in a profiler trace — is `jit_<role>`:
+    `pattern_dense_w`, `plain_step`, `join_left`, ...  Roles, not query
+    names: a bounded set, the same across apps, so a trace reduction
+    finds a step after a refactor."""
     from ..observability.recompile import RECOMPILES
-    from ..observability import tracing
+    from ..observability.phases import phase
     label = owner or getattr(fn, "__qualname__", None) or "step"
     # last-traced argument avals, captured for EXPLAIN: observability/
     # explain.py re-lowers the jitted step from these ShapeDtypeStructs to
@@ -132,15 +138,15 @@ def jit_step(fn, owner=None, **jit_kwargs):
                                                    x.aval.dtype), args)
             except Exception:  # noqa: BLE001 — accounting must not break
                 pass           # a trace (e.g. non-array leaves)
-            tr = tracing.active()
-            if tr is None:
-                return strongify(fn(*args, **kwargs))
-            with tracing.span("compile", owner=label):
+            with phase(None, None, "compile", owner=label):
                 return strongify(fn(*args, **kwargs))
 
+    if role is not None:
+        wrapped.__name__ = wrapped.__qualname__ = role
     jitted = jax.jit(wrapped, **jit_kwargs)
     try:
         jitted._siddhi_owner = label
+        jitted._siddhi_role = wrapped.__name__
         jitted._siddhi_argspec = spec_holder
     except Exception:  # noqa: BLE001 — attribute support is best-effort
         pass

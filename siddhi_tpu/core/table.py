@@ -97,9 +97,11 @@ class TableRuntime:
 
         self._jit_write = jit_step(self._write_impl,
                                    owner=f"table:{definition.id}",
+                                   role="table_write",
                                    donate_argnums=(0, 1, 2))
         self._jit_masked_delete = jit_step(self._masked_delete_impl,
                                           owner=f"table:{definition.id}",
+                                          role="table_delete",
                                           donate_argnums=(0,))
 
     # -- row-slot resolution ---------------------------------------------------

@@ -28,9 +28,11 @@ and the v2 introspection layer (where the time and memory actually go):
 - **state-memory accounting**: nbytes per device-state component from
   shape/dtype metadata only, exported as `siddhi_state_bytes`
   (`memory.py`),
-- **Perfetto export**: the pipeline-trace ring buffer as Chrome
-  trace-event JSON (`GET /trace.json`) + guarded `jax.profiler`
-  start/stop (`chrome_trace.py`),
+- **one span primitive** at every hot-path boundary (`phases.phase`):
+  a `jax.profiler.TraceAnnotation` on the profiler's clock — a capture
+  (`POST /profiler/start|stop`) holds the runtime's `siddhi:*` spans
+  beside the device's ops — that also feeds the phase profiler and the
+  DETAIL trace ring when statistics are on,
 - **health probes**: readiness vs. liveness, per-stream last-event age
   and backlog, sliding-window drop/recompile rates (`health.py`),
 
@@ -64,8 +66,7 @@ and the soak-telemetry layer (metrics over TIME, not just at scrape):
   submit from device compute, and cross-thread trace handoff/adoption
   so one pipeline trace spans ingest -> dispatch -> drain -> sink
   (`phases.py`; surfaced as `siddhi_phase_seconds_total`,
-  `GET /siddhi-apps/<app>/phases`, EXPLAIN, and a drain track with
-  flow arrows in `/trace.json`).
+  `GET /siddhi-apps/<app>/phases` and EXPLAIN).
 
 Everything is allocation-free on the hot path when statistics are OFF: each
 hook sits behind a single `enabled`/`active()` check, and every scrape/
@@ -82,8 +83,6 @@ from .stateobs import (STRUCTURES, KeyHotness,            # noqa: F401
 from .exposition import render_prometheus                 # noqa: F401
 from .explain import explain_app, explain_query           # noqa: F401
 from .memory import component_bytes, total_bytes          # noqa: F401
-from .chrome_trace import (chrome_trace, profiler_status,  # noqa: F401
-                           start_profiler, stop_profiler)
 from .health import app_health, healthz, liveness, readiness  # noqa: F401
 from .timeseries import (Series, SeriesStore,                 # noqa: F401
                          TimeSeriesSampler, tenant_account)
@@ -95,7 +94,6 @@ __all__ = [
     "PHASES", "PhaseProfiler", "phase_report",
     "STRUCTURES", "KeyHotness", "StateObservatory", "state_report",
     "explain_app", "explain_query", "component_bytes", "total_bytes",
-    "chrome_trace", "start_profiler", "stop_profiler", "profiler_status",
     "app_health", "healthz", "liveness", "readiness",
     "Series", "SeriesStore", "TimeSeriesSampler", "tenant_account",
     "SLOEngine", "SLORule", "default_rules",

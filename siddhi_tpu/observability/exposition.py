@@ -114,6 +114,14 @@ def render_prometheus(runtimes: Dict) -> str:
     d_dep = fam("siddhi_drainer_queue_depth", "gauge",
                 "Device outputs sitting in the async emission drainer "
                 "queue right now")
+    t_pend = fam("siddhi_timers_pending", "gauge",
+                 "Timers on the app scheduler's heap right now (every "
+                 "wake-up is pushed without looking for one the query "
+                 "already has, so a pile-up shows here)")
+    w_full = fam("siddhi_window_slab_full_total", "counter",
+                 "Sampled fill probes that found a window slab full, per "
+                 "query: past this point a time window drops its OLDEST "
+                 "rows unexpired (size it with @capacity(window='N'))")
     e_rows = fam("siddhi_emitted_rows_total", "counter",
                  "Output rows delivered per query (callbacks, downstream "
                  "routing, sinks) — per-tenant events_out accounting")
@@ -256,6 +264,9 @@ def render_prometheus(runtimes: Dict) -> str:
         for name, n in sorted(snap["counters"].items()):
             if name.endswith(".dropped"):
                 ctr.sample(n, app=app_name, query=name[:-len(".dropped")])
+            elif name.endswith(".window_full"):
+                w_full.sample(n, app=app_name,
+                              query=name[:-len(".window_full")])
             elif name.endswith(".cap_growths"):
                 grow.sample(n, app=app_name,
                             query=name[:-len(".cap_growths")])
@@ -328,6 +339,8 @@ def render_prometheus(runtimes: Dict) -> str:
                 q_dep.sample(n, app=app_name, stream=sid)
         if hasattr(rt, "drainer_depth"):
             d_dep.sample(rt.drainer_depth(), app=app_name)
+        if hasattr(rt, "timers_pending"):
+            t_pend.sample(rt.timers_pending(), app=app_name)
         # serving-loop gauges: ring occupancy per query + drainer
         # backlog (host-side deque length reads — never a fetch)
         if hasattr(rt, "ring_occupancies"):

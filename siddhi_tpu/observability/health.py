@@ -323,6 +323,8 @@ def app_health(rt, now_ms: Optional[int] = None) -> Dict:
         "buffered_emissions": rt.buffered_emissions(),
         "drainer_queue_depth": rt.drainer_depth()
         if hasattr(rt, "drainer_depth") else 0,
+        "timers_pending": rt.timers_pending()
+        if hasattr(rt, "timers_pending") else 0,
         "rates_window_s": window_s,
         "dropped_per_s": round(_rate(rt, "dropped", drops), 6),
         "cap_growths_per_s": round(_rate(rt, "cap_growths", growths), 6),

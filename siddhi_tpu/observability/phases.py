@@ -1,35 +1,51 @@
-"""Phase-level hot-path profiler for the serving pipeline.
+"""Phase-level hot-path profiler, and the ONE span primitive that feeds it.
 
 Reference (what): the reference's DETAIL statistics level leaves per-event
 breadcrumbs (StreamJunction.sendEvent :147, QuerySelector.process :77);
 every open perf question here is instead a per-PHASE budget question —
 which slice of the batch pipeline (host staging, H2D upload, dispatch
 submit, device compute, ring residency, D2H drain, demux, sink fan-out)
-owns the wall time.  TPU design (how): an always-on accumulator of
-per-(query, phase) nanosecond counters fed exclusively from HOST clocks
-at the existing hot-path boundaries — zero device fetches and zero
-`block_until_ready` on the steady path, so it can stay on in production
-(the Google-Wide-Profiling posture: continuous, cheap, always there).
+owns the wall time.  TPU design (how): every hot-path boundary is ONE
+`with phase(stats, query, name, ...)` call site, and that one call feeds
+three readers:
+
+- always: a `jax.profiler.TraceAnnotation("siddhi:<name>", q=, batch=,
+  ...)` — a TraceMe enter/exit (about a microsecond) when no profiler
+  session is live, and when one is, a host span on the SAME timeline as
+  the device's `XLA Ops`, which is what says why the chip was idle.
+  There is no switch: the profiler session is the switch;
+- statistics BASIC and up: the span's SELF time (its wall minus the spans
+  nested in it on the same thread) into the per-(query, phase)
+  `PhaseProfiler` behind `/metrics`, `/phases` and `phase_report()`;
+- DETAIL with a batch trace active: a span on the `PipelineTracer` ring.
+
+Host clocks only — zero device fetches and zero `block_until_ready` on
+the steady path.  The instrument never changes what the program does.
 
 The async-dispatch blind spot: a jitted step call returns at SUBMIT, so
-the host-side `dispatch_submit` wall says nothing about device time —
-that is paid later inside whichever `device_get` drains the output
-(`d2h_drain`).  The sampled deep mode (`profile.sample.every=N`) fences
-every Nth dispatch per query with `block_until_ready` to split the two:
-the fence wall is `device_compute`, and the sampled-dispatch counter
-(`siddhi_phase_dispatches_sampled_total`) says how much of the traffic
-paid for that visibility.
+the `dispatch` span says nothing about device time — that is paid later
+inside whichever `fetch` drains the output.  Device time is the profiler
+trace's; the sampled deep mode (`profile.sample.every=N`) fences every
+Nth dispatch per query with `block_until_ready` and books the fence wall
+as `device_compute`.
 
-Phase taxonomy (one batch, ingest -> sink):
+Spans (`siddhi:<name>`) and the scrape phase each feeds:
 
-  stage_host       host staging: pack_np + the sharded [n,Kb,E] regroup
-  h2d              explicit device upload (serving/staging.py)
-  dispatch_submit  jitted step call wall (async dispatch: submit only)
-  device_compute   sampled only: block_until_ready fence after submit
-  ring_wait        emission-ring residency (append -> take)
-  d2h_drain        device->host output fetch (blocking or drainer-side)
-  demux            header decode / unpack / ts restore in emission sync
-  sink             callbacks + downstream routing + sink publish
+  send        whole InputHandler.send / send_columns call   (no phase:
+              its self time is what no child span covers)
+  stage       pad/adopt or pack_np into a StagedBatch        stage_host
+  route_keys  key -> slot routing, grouping, ts-wire build   stage_host
+  obs_feed    state observatory feed, liveness, dirty marks  stage_host
+  h2d         every host->device upload (host wall)          h2d
+  dispatch    the jitted step call (submit only)             dispatch_submit
+  fetch       every device_get on a delivery path            d2h_drain
+  demux       header decode, ts-order restore, unpack        demux
+  sink        callbacks, table op, rate limit, re-publish    sink
+  compile     jit_step's body while tracing a new signature  (none)
+  timer       the scheduler firing a timer step              (none)
+
+`ring_wait` (emission-ring / drainer-queue residency, append -> take) is
+a difference of two stamps on two threads, not a span: `waited()`.
 
 Counters are per-query LATENCY attribution, not wall-clock utilization:
 a batched drainer fetch serving three queries charges its full wall to
@@ -40,12 +56,29 @@ remainder surfaces as `other` in `runtime.phase_report()`.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
+
+from . import tracing as _tracing
+from .memory import tree_nbytes
 
 # canonical order — every surface (report, /metrics, /timeseries, PERF
 # tables) lists phases in pipeline order, not dict order
 PHASES = ("stage_host", "h2d", "dispatch_submit", "device_compute",
           "ring_wait", "d2h_drain", "demux", "sink")
+
+# span name -> the scrape phase its self time feeds.  `stage_host` is the
+# sum of three spans, which snapshot()/phase_report() list beneath it as
+# `parts`.  `send`, `compile` and `timer` feed no phase.
+SPAN_PHASE = {"stage": "stage_host", "route_keys": "stage_host",
+              "obs_feed": "stage_host", "h2d": "h2d",
+              "dispatch": "dispatch_submit", "fetch": "d2h_drain",
+              "demux": "demux", "sink": "sink"}
+STAGE_PARTS = ("stage", "route_keys", "obs_feed")
+SPAN_PREFIX = "siddhi:"
 
 
 class PhaseProfiler:
@@ -58,15 +91,18 @@ class PhaseProfiler:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._ns: Dict[tuple, int] = {}        # (query, phase) -> total ns
-        self._count: Dict[tuple, int] = {}     # (query, phase) -> samples
+        # (query, phase, part) -> total ns / samples; part None = the
+        # phase itself, else one of the spans that sum to it
+        self._ns: Dict[tuple, int] = {}
+        self._count: Dict[tuple, int] = {}
         self._dispatches: Dict[str, int] = {}  # query -> dispatch counter
         self._sampled: Dict[str, int] = {}     # query -> fenced dispatches
 
-    def add(self, query: str, phase: str, ns: int) -> None:
+    def add(self, query: str, phase: str, ns: int,
+            part: Optional[str] = None) -> None:
         if ns <= 0:
             return
-        key = (query, phase)
+        key = (query, phase, part)
         with self._lock:
             self._ns[key] = self._ns.get(key, 0) + int(ns)
             self._count[key] = self._count.get(key, 0) + 1
@@ -87,19 +123,37 @@ class PhaseProfiler:
         return True
 
     def snapshot(self) -> Dict:
-        """{"queries": {q: {phase: {"ns", "count"}}}, "sampled": {q: n}}
-        — phases in canonical order; shallow int copies, scrape-safe."""
+        """{"queries": {q: {phase: {"ns", "count"[, "parts"]}}},
+        "sampled": {q: n}} — phases in canonical order; shallow int
+        copies, scrape-safe.  A phase fed by several spans (`stage_host`)
+        sums their ns, lists each under `parts`, and counts batches: its
+        `stage` part's samples (one a staged batch), or the most-sampled
+        part's where a query never sees one (timer-fired steps)."""
         with self._lock:
             ns = dict(self._ns)
             count = dict(self._count)
             sampled = dict(self._sampled)
         queries: Dict[str, Dict] = {}
-        for (q, p), total in ns.items():
-            queries.setdefault(q, {})[p] = {"ns": total,
-                                            "count": count.get((q, p), 0)}
-        for q in queries:
-            queries[q] = {p: queries[q][p] for p in PHASES
-                          if p in queries[q]}
+        for (q, p, part), total in ns.items():
+            ent = queries.setdefault(q, {}).setdefault(
+                p, {"ns": 0, "count": 0})
+            ent["ns"] += total
+            n = count.get((q, p, part), 0)
+            if part is None:
+                ent["count"] += n
+            else:
+                ent.setdefault("parts", {})[part] = {"ns": total,
+                                                     "count": n}
+        for q, phases in queries.items():
+            for ent in phases.values():
+                parts = ent.get("parts")
+                if parts:
+                    ent["count"] += parts["stage"]["count"] \
+                        if "stage" in parts \
+                        else max(v["count"] for v in parts.values())
+                    ent["parts"] = {k: parts[k] for k in STAGE_PARTS
+                                    if k in parts}
+            queries[q] = {p: phases[p] for p in PHASES if p in phases}
         return {"queries": queries, "sampled": sampled}
 
     def reset(self) -> None:
@@ -108,6 +162,218 @@ class PhaseProfiler:
             self._count.clear()
             self._dispatches.clear()
             self._sampled.clear()
+
+
+# ---------------------------------------------------------------------------
+# the span primitive
+# ---------------------------------------------------------------------------
+
+_tls = threading.local()
+
+
+def current_batch() -> int:
+    """The send this thread is working for: the per-junction sequence
+    number `siddhi:send` opened with, carried across thread handoffs by
+    `handoff()` / `adopt()` and, over the @async ingress queue, beside
+    the staged batch.  0 = none (a timer firing, a flush)."""
+    return getattr(_tls, "batch", 0)
+
+
+class batch_scope:
+    """Make `batch` the thread's current send for the scope of a
+    dispatch or a delivery (an @async ingress worker picking a
+    StagedBatch off its queue, a drainer delivering a handed-off
+    emission).  0 keeps whatever is current."""
+
+    __slots__ = ("batch", "prev")
+
+    def __init__(self, batch: int):
+        self.batch = batch
+
+    def __enter__(self):
+        self.prev = getattr(_tls, "batch", 0)
+        if self.batch:
+            _tls.batch = self.batch
+        return self
+
+    def __exit__(self, *exc):
+        _tls.batch = self.prev
+        return False
+
+
+def handoff():
+    """Token for a cross-thread delivery (the @async drainer queue, the
+    @pipeline deque, the serving ring): the DETAIL trace armed for
+    concurrent appends (tracing.handoff) and the send's batch number.
+    The drain side wraps its delivery in `adopt(token)`."""
+    return (_tracing.handoff(), getattr(_tls, "batch", 0))
+
+
+class adopt:
+    """Deliver under a handed-off token: spans opened inside carry the
+    originating send's `batch`, and DETAIL spans join its trace on the
+    `drain` track (tracing.adopt)."""
+
+    __slots__ = ("token", "scope", "tr")
+
+    def __init__(self, token):
+        self.token = token
+
+    def __enter__(self):
+        trace, batch = self.token if self.token is not None else (None, 0)
+        self.scope = batch_scope(batch)
+        self.scope.__enter__()
+        self.tr = _tracing.adopt(trace)
+        self.tr.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.tr.__exit__(*exc)
+        return self.scope.__exit__(*exc)
+
+
+class _Timed:
+    """A span with statistics on: the TraceAnnotation plus a host clock.
+    On exit its SELF time (wall minus the `_Timed` spans nested in it on
+    this thread) goes to the PhaseProfiler under the span's scrape phase,
+    once per query it is charged to and `mult` times (a fused dispatch
+    serves `mult` batches, and each batch's e2e sample contains the whole
+    wall); a DETAIL batch trace active on the thread gets the span too."""
+
+    __slots__ = ("ann", "stats", "queries", "name", "mult", "meta",
+                 "t0", "kids", "prev")
+
+    def __init__(self, ann, stats, queries, name, mult, meta):
+        self.ann, self.stats, self.queries = ann, stats, queries
+        self.name, self.mult, self.meta = name, mult, meta
+
+    def set_metadata(self, **kw):
+        self.ann.set_metadata(**kw)
+        self.meta.update(kw)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.prev = getattr(_tls, "open", None)
+        _tls.open = self
+        self.kids = 0
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        _tls.open = self.prev
+        wall = t1 - self.t0
+        if self.prev is not None:
+            self.prev.kids += wall
+        phase_name = SPAN_PHASE.get(self.name)
+        if phase_name is not None and self.queries and \
+                self.stats is not None and self.stats.enabled:
+            own = (wall - self.kids) * self.mult
+            part = self.name if phase_name == "stage_host" else None
+            add = self.stats.phases.add
+            for q in self.queries:
+                add(q, phase_name, own, part)
+        tr = _tracing.active()
+        if tr is not None:
+            meta = self.meta
+            if self.queries:
+                meta = dict(meta, query=self.queries[0])
+            tr.add_span(self.name, self.t0, t1, meta,
+                        getattr(_tracing._tls, "track", None))
+        return self.ann.__exit__(*exc)
+
+
+def phase(stats, query, name: str, mult: int = 1, **meta):
+    """THE span primitive: `with phase(stats, query, "fetch", what="header")`.
+
+    `query` is the query the time is charged to, a tuple of them (a
+    junction-level span charges every subscriber, as each one's e2e
+    sample contains it), or None.  With statistics OFF (`stats` None
+    counts as OFF) and no DETAIL trace on the thread this returns the bare
+    `jax.profiler.TraceAnnotation` — nothing else runs; stats known only
+    at the end go on with `.set_metadata(rows=n)` either way."""
+    queries = (query,) if isinstance(query, str) else (query or ())
+    ann = TraceAnnotation(
+        SPAN_PREFIX + name, q=queries[0] if queries else "",
+        batch=getattr(_tls, "batch", 0), **meta)
+    if (stats is None or not stats.enabled) and _tracing.active() is None:
+        return ann
+    return _Timed(ann, stats, queries, name, mult, meta)
+
+
+class send:
+    """`siddhi:send` over one InputHandler call, under `batch` — the next
+    number of the junction's send sequence, which every span the send
+    causes then carries (batch_scope + phase entered as one)."""
+
+    __slots__ = ("scope", "stats", "stream", "events", "span")
+
+    def __init__(self, stats, stream: str, batch: int,
+                 events: Optional[int]):
+        self.scope = batch_scope(batch)
+        self.stats, self.stream, self.events = stats, stream, events
+
+    def __enter__(self):
+        self.scope.__enter__()
+        meta = {} if self.events is None else {"events": self.events}
+        self.span = phase(self.stats, None, "send", stream=self.stream,
+                          **meta)
+        return self.span.__enter__()
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        return self.scope.__exit__(*exc)
+
+
+def dispatch(qr, step, *args, name: Optional[str] = None, mult: int = 1):
+    """Call one jitted step inside a `dispatch` span (async dispatch: the
+    call returns at SUBMIT, so the span says nothing about device time).
+    Every `profile.sample.every` dispatches per query the deep mode
+    fences the returned pytree with `block_until_ready` and books the
+    fence wall as `device_compute` — the only block the profiler ever
+    takes, and never on the steady (unsampled) path.  The span's `step`
+    is the jit role (`jit_<role>` is the XLA module's name in the same
+    trace); `mult` as in `_Timed`."""
+    st = qr.app.stats
+    qname = name or qr.name
+    with phase(st, qname, "dispatch", mult,
+               step=getattr(step, "_siddhi_role", "step")):
+        res = step(*args)
+    if st.enabled:
+        every = sample_every(qr.app)
+        if every and st.phases.should_sample(qname, every):
+            t1 = time.perf_counter_ns()
+            jax.block_until_ready(res)
+            st.phases.add(qname, "device_compute",
+                          (time.perf_counter_ns() - t1) * mult)
+    return res
+
+
+def nbytes(*arrays) -> int:
+    """Bytes of the host arrays an `h2d` span is about to upload (the hot
+    path's plain sum; `memory.tree_nbytes` walks a pytree)."""
+    return sum(int(getattr(a, "nbytes", 0)) for a in arrays)
+
+
+def fetch(stats, query, what: str, tree, mult: int = 1):
+    """THE device->host fetch of every delivery path: `jax.device_get`
+    inside a `fetch` span (`what` = header | rows | ring).  In blocking
+    delivery the first fetch after a dispatch also holds the wait for
+    the step.  `bytes` is summed only while somebody records it."""
+    with phase(stats, query, "fetch", mult, what=what) as sp:
+        out = jax.device_get(tree)
+        if TraceAnnotation.is_enabled() or _tracing.active() is not None:
+            sp.set_metadata(bytes=tree_nbytes(out))
+    return out
+
+
+def waited(stats, query: str, since_ns: int, extra_ns: int = 0) -> None:
+    """Book `ring_wait`: how long an emission sat between the dispatch
+    side's handoff and this delivery (ring or drainer-queue residency,
+    plus the serialized wait behind its predecessors' deliveries)."""
+    if stats.enabled:
+        stats.phases.add(query, "ring_wait",
+                         extra_ns + time.perf_counter_ns() - since_ns)
 
 
 def sample_every(rt) -> int:
@@ -148,6 +414,12 @@ def phase_report(rt) -> Dict:
                 "count": v["count"],
                 "share": round(v["ns"] / base, 4) if base else 0.0}
             for p, v in phases.items()}
+        for p, v in phases.items():
+            if "parts" in v:
+                entry[p]["parts"] = {
+                    k: {"seconds": round(pv["ns"] / 1e9, 6),
+                        "count": pv["count"]}
+                    for k, pv in v["parts"].items()}
         other_ns = max(0, e2e - total_ns) if e2e > 0 else 0
         queries[q] = {
             "phases": entry,

@@ -473,7 +473,8 @@ def _probe_fn():
             return jax.numpy.stack(
                 [jax.numpy.sum(a.astype(jax.numpy.int32)) for a in ls])
 
-        _PROBE_FN = jit_step(_probe, owner="stateobs:fill_probe")
+        _PROBE_FN = jit_step(_probe, owner="stateobs:fill_probe",
+                             role="fill_probe")
     return _PROBE_FN
 
 
@@ -524,7 +525,13 @@ def record_fill(qr, fills) -> None:
         return
     caps = qr.__dict__.get("_stateobs_probe_caps") or []
     try:
-        fill = int(np.asarray(fills).sum())
+        per_buffer = np.asarray(fills)
+        if any(int(f) >= c for f, c in zip(per_buffer, caps)):
+            # a full slab: a time window past this point drops its OLDEST
+            # rows without expiring them out of the aggregates — counted
+            # beside the emission drop counters (sampled, so a floor)
+            qr.app.stats.counter_inc(f"{qr.name}.window_full")
+        fill = int(per_buffer.sum())
         cap = int(sum(caps)) or 1
         qr.app.stats.stateobs.observe(
             qr.name, "window_fill", fill, cap, growable=False,

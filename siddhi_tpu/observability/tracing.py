@@ -18,7 +18,9 @@ lock, paid only once armed) and carries the returned token on whatever
 queue crosses the thread boundary (@async drainer items, serving-ring
 generations); the drain side wraps its delivery in `adopt(token)`, so one
 trace spans ingest -> dispatch -> drain -> sink and the delivery-side
-spans carry `track="drain"` for the Chrome-trace drainer track.
+spans carry `track="drain"`.  The runtime's hot path reaches this module
+through the span primitive (`phases.phase`, `phases.handoff/adopt`), which
+feeds a DETAIL trace the same spans a profiler capture holds.
 Everything is a no-op (one thread-local read) when no trace is active.
 """
 from __future__ import annotations
@@ -193,7 +195,7 @@ def adopt(token: Optional[BatchTrace], track: str = "drain"):
     """Make a handed-off trace the thread's active trace for the scope of
     one delivery: spans recorded inside (emit, sink, nested re-ingestion
     dispatches) attach to the ORIGINATING trace, tagged with `track` for
-    the Chrome-trace drainer lane.  With a None token this is the plain
+    the drainer's lane.  With a None token this is the plain
     no-op path.  Nested dispatch under adoption behaves exactly like
     same-thread nesting: PipelineTracer.start() sees the adopted trace
     and returns None, so the inner hop's spans join the outer story

@@ -50,6 +50,7 @@ from ..core import event as ev
 from ..core import plan_facts
 from ..core.steputil import jit_step
 from ..core.window import NO_WAKEUP
+from ..observability import phases as _phases
 
 jnp = jax.numpy
 log = logging.getLogger("siddhi_tpu")
@@ -123,7 +124,7 @@ class MergedGroupRuntime:
             m._qlock = self._qlock
         self.raw_body = self._build_body()
         self._step = jit_step(self.raw_body, owner=self.name,
-                              donate_argnums=(0,))
+                              role="merged_step", donate_argnums=(0,))
         # @fuse(batches=K) on every member: the MERGED dispatch owns the
         # stack (kind 'merged' in core/fusion.py); members drop theirs
         self._fuse = None
@@ -241,9 +242,9 @@ class MergedGroupRuntime:
         for mode, idxs in self.units:
             lead = self.members[idxs[0]]
             g, ps = lead._slots_for_batch(staged, now)
-            gslots.append(jnp.asarray(g))
+            gslots.append(g)
             if mode == "solo" and ps:
-                pslots[idxs[0]] = tuple(jnp.asarray(s) for s in ps)
+                pslots[idxs[0]] = tuple(ps)
         return tuple(gslots), tuple(pslots)
 
     def _in_tabs(self) -> Tuple:
@@ -261,16 +262,21 @@ class MergedGroupRuntime:
         self._dispatch(staged, now)
 
     def _dispatch(self, staged: ev.StagedBatch, now: int) -> None:
-        from ..core.runtime import _maybe_span
+        from ..core.runtime import _staged_nbytes
         stats = self.app.stats
         t0 = time.perf_counter_ns() if stats.enabled else 0
         gslots, pslots = self._prep(staged, now)
-        batch = staged.to_device(self.in_schema)
-        with _maybe_span("step", query=self.name, kind="merged"):
-            self._state, outs, _wake = self._step(
-                self._state, batch.ts, batch.kind, batch.valid,
-                batch.cols, gslots,
-                jnp.asarray(now, jnp.int64), self._in_tabs(), pslots)
+        with _phases.phase(stats, self.name, "h2d",
+                           bytes=_staged_nbytes(staged)):
+            batch = staged.to_device(self.in_schema)
+            gslots = tuple(jnp.asarray(g) for g in gslots)
+            pslots = tuple(tuple(jnp.asarray(s) for s in ps)
+                           for ps in pslots)
+            now_d = jnp.asarray(now, jnp.int64)
+        self._state, outs, _wake = _phases.dispatch(
+            self, self._step, self._state, batch.ts, batch.kind,
+            batch.valid, batch.cols, gslots, now_d, self._in_tabs(),
+            pslots)
         if stats.enabled:
             stats.counter_inc(f"merged.{self.group}.dispatches")
             stats.counter_inc(f"merged.{self.group}.member_batches",
@@ -304,7 +310,8 @@ class MergedGroupRuntime:
                      if _rt._has_consumers(m)]
         hosted: Dict[int, List] = {}
         if consumers and not deferred:
-            flat = jax.device_get(
+            flat = _phases.fetch(
+                stats, self.name, "rows",
                 [[b[0][i] for b in batches] for i in consumers])
             hosted = dict(zip(consumers, flat))
         elif consumers:
@@ -408,6 +415,7 @@ def apply_merge(rt) -> None:
         for _name, qr in members:
             qs.remove(qr)
         qs.insert(pos, mg)
+        junction._sub_names_memo = None
         log.info("multi-query merge: %s merges %d queries on %r "
                  "(%d shared unit(s))", mg.name, len(members),
                  g["stream"],

@@ -22,10 +22,6 @@ Endpoints (JSON in/out):
                                                traces touching <query>
                                                (searched across apps)
   GET    /siddhi-apps/<name>/trace/<query>  -> same, one app
-  GET    /trace.json                        -> the trace ring as Chrome
-                                               trace-event JSON — opens
-                                               directly in Perfetto /
-                                               chrome://tracing
   GET    /siddhi-apps/<name>/explain/<query> -> EXPLAIN: operator tree +
                                                per-step XLA cost analysis,
                                                state bytes, fusion
@@ -74,7 +70,10 @@ Endpoints (JSON in/out):
                                                unless config property
                                                metrics.sampler.enabled=false)
   POST   /profiler/start  body={"log_dir"?} -> start a guarded jax.profiler
-                                               session (409 if running)
+                                               session (409 if running): the
+                                               capture holds the runtime's
+                                               `siddhi:*` spans and the
+                                               device's ops on one clock
   POST   /profiler/stop                     -> stop it (409 if not running)
   GET    /siddhi-apps/<name>/admission      -> admission-control report:
                                                overload policy, quota
@@ -104,6 +103,45 @@ from typing import Optional
 
 from .core.runtime import SiddhiManager
 from .exceptions import SiddhiError
+
+
+# ---------------------------------------------------------------------------
+# jax.profiler guard: explicit start/stop, one session at a time.  A capture
+# holds the runtime's own `siddhi:*` spans (observability/phases.py) beside
+# the device's XLA ops, on one clock.
+# ---------------------------------------------------------------------------
+
+_prof_lock = threading.Lock()
+_prof_dir: Optional[str] = None
+
+
+def start_profiler(log_dir: str = "/tmp/siddhi_tpu_profile") -> dict:
+    """Start a jax.profiler trace session.  Returns {started, log_dir} or
+    raises RuntimeError when a session is already active (the profiler is
+    process-global — two sessions would corrupt each other's capture)."""
+    global _prof_dir
+    with _prof_lock:
+        if _prof_dir is not None:
+            raise RuntimeError(
+                f"profiler already running (log_dir={_prof_dir!r}); "
+                f"POST /profiler/stop first")
+        import jax
+        jax.profiler.start_trace(log_dir)
+        _prof_dir = log_dir
+    return {"started": True, "log_dir": log_dir}
+
+
+def stop_profiler() -> dict:
+    """Stop the active jax.profiler session; raises RuntimeError when
+    none is running."""
+    global _prof_dir
+    with _prof_lock:
+        if _prof_dir is None:
+            raise RuntimeError("no profiler session running")
+        import jax
+        d, _prof_dir = _prof_dir, None
+        jax.profiler.stop_trace()
+    return {"stopped": True, "log_dir": d}
 
 
 def _qparam(query_str: str, name: str) -> Optional[str]:
@@ -164,14 +202,6 @@ class SiddhiRestService:
                         else:
                             code, payload = _health.healthz(svc.manager)
                         self._json(code, payload)
-                    elif parts == ["trace.json"]:
-                        # Chrome trace-event JSON of the pipeline-trace
-                        # ring — loads directly in Perfetto
-                        from .observability.chrome_trace import \
-                            chrome_trace
-                        q = _qparam(query_str, "query")
-                        self._json(200, chrome_trace(
-                            svc.manager.runtimes, q))
                     elif len(parts) == 4 and parts[0] == "siddhi-apps" \
                             and parts[2] == "explain":
                         rt = svc.manager.runtimes.get(parts[1])
@@ -283,10 +313,8 @@ class SiddhiRestService:
                 try:
                     parts = [p for p in self.path.split("/") if p]
                     if len(parts) == 2 and parts[0] == "profiler":
-                        # guarded jax.profiler session for device-level
-                        # deep dives; one at a time, never implicit
-                        from .observability.chrome_trace import (
-                            start_profiler, stop_profiler)
+                        # guarded jax.profiler session; one at a time,
+                        # never implicit
                         try:
                             if parts[1] == "start":
                                 req = json.loads(self._body() or b"{}")

@@ -82,6 +82,7 @@ def ring_append(qr, out, now: int, ingest_ns=None, trace=None) -> None:
     """Producer edge of the serving loop: dispatch the ring append and
     return — zero host<->device synchronization (core/runtime.py
     `_emit_output` routes here for serve-enabled runtimes).  `trace` is
-    the dispatch thread's handed-off BatchTrace (tracing.handoff): it
-    rides the ring so the drainer's delivery spans join the trace."""
+    the dispatch thread's handoff token (phases.handoff): it rides the
+    ring so the drainer's delivery spans carry the send's batch number
+    and join its DETAIL trace."""
     ensure_ring(qr).append(out, now, ingest_ns, trace)

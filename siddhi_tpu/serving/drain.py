@@ -30,7 +30,6 @@ import threading
 import time
 from typing import List
 
-import jax
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -147,37 +146,36 @@ class ServingDrainer:
     def _deliver(self, items) -> None:
         import traceback
         from ..core.runtime import _emit_output_sync
-        from ..observability import tracing as _tracing
+        from ..observability import phases as _phases
         # phase accounting: each item's ring residency (append -> take,
         # stamped by ring.take) plus this cycle's batched fetch wall —
-        # charged per item, exactly as each item's e2e sample counts it
-        t_fetch = time.perf_counter_ns()
+        # charged per item, exactly as each item's e2e sample counts it.
         # ONE blocking fetch for every segment taken this cycle: len-6
-        # outs contribute the 16-byte header, len-4 outs ship whole
+        # outs contribute the 16-byte header, len-4 outs ship whole; the
+        # span carries the batch of the first send it serves
+        st = self.app.stats
         try:
-            fetched = jax.device_get([
-                (out[0], out[1]) if len(out) == 6 else out
-                for _, out, _, _, _, _ in items])
+            with _phases.adopt(items[0][4]):
+                fetched = _phases.fetch(
+                    st, tuple(it[0].name for it in items), "ring", [
+                        (out[0], out[1]) if len(out) == 6 else out
+                        for _, out, _, _, _, _ in items])
         except Exception:  # noqa: BLE001 — drainer must survive
             traceback.print_exc()
             fetched = [None] * len(items)
-        fetch_ns = time.perf_counter_ns() - t_fetch
         per_q = {}
         loop_t0 = time.perf_counter_ns()
         for (qr, out, now, t_in, trace, wait_ns), fetch_h in \
                 zip(items, fetched):
-            ph = qr.app.stats.phases
             # in-batch wait: deliveries run serially, so a later item's
             # e2e contains every predecessor's demux/sink wall — that
             # residency is drainer wait, charged here so the phase sum
             # keeps tracking e2e (attribution rule in phases.py)
-            ph.add(qr.name, "ring_wait",
-                   wait_ns + (time.perf_counter_ns() - loop_t0))
-            ph.add(qr.name, "d2h_drain", fetch_ns)
+            _phases.waited(st, qr.name, loop_t0, wait_ns)
             try:
                 if fetch_h is None:
                     continue
-                with _tracing.adopt(trace):
+                with _phases.adopt(trace):
                     if len(out) == 6:
                         _emit_output_sync(qr, out, now, header=fetch_h,
                                           ingest_ns=t_in)

@@ -125,8 +125,9 @@ class _Generation:
         # signature.  The buffer is donated — XLA updates the ring in
         # place instead of copying S slots per append.
         self._set = jit_step(_set, owner=f"serve:{owner}",
-                             donate_argnums=(0,))
-        self._read = jit_step(_read, owner=f"serve:{owner}:read")
+                             role="ring_append", donate_argnums=(0,))
+        self._read = jit_step(_read, owner=f"serve:{owner}:read",
+                              role="ring_read")
 
     def append(self, out) -> int:
         slot = self.head
@@ -171,8 +172,9 @@ class EmissionRing:
         self._gens: List[_Generation] = []
         # (generation, now, ingest_ns, trace_token, append_ns) in send
         # order, across generations: the token is the dispatch thread's
-        # handed-off BatchTrace (observability/tracing.handoff) so the
-        # drainer's delivery spans join the originating trace; append_ns
+        # handoff (observability/phases.handoff: the send's batch number
+        # and its DETAIL trace) so the drainer's delivery spans carry the
+        # one and join the other; append_ns
         # stamps ring entry for the `ring_wait` phase (take - append)
         self._meta: "list" = []
         self._on_highwater = on_highwater

@@ -71,12 +71,16 @@ class DoubleBufferedStager:
             log.exception("accept-edge H2D staging failed; the batch "
                           "transfers in the dispatch path instead")
             return
-        staged.dev = (schema, batch)
+        # the stager rides along so `to_device` can count the adoption
+        staged.dev = (schema, batch, self)
         with self._lock:
             self._inflight.append(batch)
             self.staged_total += 1
 
     def adopted(self) -> None:
+        """A step took the prestaged arrays instead of re-uploading
+        (StagedBatch.to_device): staged_total - adopted_total uploads
+        were made twice, or for nothing."""
         with self._lock:
             self.adopted_total += 1
 

@@ -321,6 +321,9 @@ def test_growth_denied_flips_shedding_instead_of_growing(manager):
 
 def test_growth_allowed_under_ceiling(manager):
     rt = manager.create_siddhi_app_runtime(GROW_QL)
+    # a subscriber: statistics alone are not a reader, and an emission
+    # nobody reads is not fetched — header and overflow count included
+    rt.add_batch_callback("q", lambda ts, b: None)
     rt.start()
     adm, clock = _fake_controller(rt)
     adm.max_state_bytes = float(1 << 30)

@@ -1,7 +1,9 @@
 """Observability v2: query EXPLAIN (operator tree + XLA cost analysis),
-state-memory gauges, Chrome trace-event export, /healthz readiness vs
+state-memory gauges, a profiler capture over REST, /healthz readiness vs
 liveness, and the no-device-touch scrape invariant (see ISSUE 3)."""
+import glob
 import json
+import os
 import re
 import urllib.error
 import urllib.request
@@ -12,7 +14,6 @@ import jax
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.observability import RECOMPILES, render_prometheus
-from siddhi_tpu.observability.chrome_trace import chrome_trace
 from siddhi_tpu.observability.health import SlidingRate, app_health
 
 
@@ -266,44 +267,9 @@ def test_scrape_and_probe_never_touch_device(manager, monkeypatch):
     assert rt.state_memory()["wq"]["window"] > 0
 
 
-# -- Chrome trace-event export ------------------------------------------------
+# -- profiler capture + explain over REST ---------------------------------------
 
-def _valid_trace_events(doc):
-    assert "traceEvents" in doc
-    evs = doc["traceEvents"]
-    assert evs, "no trace events exported"
-    for e in evs:
-        assert {"ph", "name", "pid", "tid"} <= set(e), e
-        if e["ph"] == "X":
-            assert "ts" in e and "dur" in e
-            assert e["dur"] >= 0
-    ts = [e["ts"] for e in evs if e["ph"] != "M"]
-    assert ts == sorted(ts), "trace-event ts must be monotonic"
-    # process metadata names each app's track group
-    assert any(e["ph"] == "M" and e["name"] == "process_name"
-               for e in evs)
-    return evs
-
-
-def test_chrome_trace_golden_shape(manager):
-    _boot(manager, """
-    @app:name('TraceApp')
-    @app:statistics('DETAIL')
-    define stream S (sym string, v int);
-    @info(name='q') from S[v > 0] select sym, v insert into Out;
-    """, [("S", [["a", i] for i in range(4)]),
-          ("S", [["b", i] for i in range(4)])])
-    doc = chrome_trace(manager.runtimes)
-    evs = _valid_trace_events(doc)
-    # round-trips through strict JSON
-    evs2 = json.loads(json.dumps(doc))["traceEvents"]
-    assert len(evs2) == len(evs)
-    names = {e["name"] for e in evs}
-    assert any(n.startswith("dispatch") for n in names)
-    assert "query" in names and "step" in names
-
-
-def test_trace_json_endpoint(manager):
+def test_trace_json_endpoint(manager, tmp_path):
     from siddhi_tpu.service import SiddhiRestService
     svc = SiddhiRestService().start()
     try:
@@ -319,9 +285,38 @@ def test_trace_json_endpoint(manager):
         urllib.request.urlopen(urllib.request.Request(
             f"{base}/siddhi-apps/TJ/streams/S", data=body, method="POST"))
         svc.manager.runtimes["TJ"].flush()
-        doc = json.loads(urllib.request.urlopen(
-            f"{base}/trace.json").read().decode())
-        _valid_trace_events(doc)
+        # /trace.json went with the DETAIL ring's Chrome export: a profiler
+        # capture holds the runtime's own spans and the device's ops on
+        # one clock
+        with pytest.raises(urllib.error.HTTPError) as gone:
+            urllib.request.urlopen(f"{base}/trace.json")
+        assert gone.value.code == 404
+        log_dir = str(tmp_path / "prof")
+        started = json.loads(urllib.request.urlopen(urllib.request.Request(
+            f"{base}/profiler/start", method="POST",
+            data=json.dumps({"log_dir": log_dir}).encode())).read())
+        assert started == {"started": True, "log_dir": log_dir}
+        with pytest.raises(urllib.error.HTTPError) as twice:
+            urllib.request.urlopen(urllib.request.Request(
+                f"{base}/profiler/start", method="POST", data=b"{}"))
+        assert twice.value.code == 409
+        for _ in range(2):
+            urllib.request.urlopen(urllib.request.Request(
+                f"{base}/siddhi-apps/TJ/streams/S", data=body,
+                method="POST"))
+        svc.manager.runtimes["TJ"].flush()
+        stopped = json.loads(urllib.request.urlopen(urllib.request.Request(
+            f"{base}/profiler/stop", method="POST", data=b"")).read())
+        assert stopped == {"stopped": True, "log_dir": log_dir}
+        (capture,) = glob.glob(os.path.join(
+            log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        names = [e.name for plane in
+                 jax.profiler.ProfileData.from_file(capture).planes
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("siddhi:")]
+        # the REST stream endpoint sends event by event: 2 posts x 4 events
+        assert names.count("siddhi:send") == 8
+        assert {"siddhi:stage", "siddhi:dispatch"} <= set(names)
         # explain endpoint returns the same report as the API
         rep = json.loads(urllib.request.urlopen(
             f"{base}/siddhi-apps/TJ/explain/q").read().decode())

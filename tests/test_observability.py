@@ -176,7 +176,7 @@ def test_detail_trace_spans(manager):
     tr = traces[0]
     assert tr["stream"] == "S" and tr["events"] == 2
     stages = [s["stage"] for s in tr["spans"]]
-    assert "query" in stages and "step" in stages
+    assert "query" in stages and "dispatch" in stages
     qspan = next(s for s in tr["spans"] if s["stage"] == "query")
     assert qspan["query"] == "tq"
     assert qspan["duration_us"] >= 0

@@ -1,0 +1,582 @@
+"""The span primitive (observability/phases.py `phase`) at the runtime's
+hot-path boundaries: what a jax.profiler capture holds (a CPU session
+writes TraceAnnotations to the host plane too), what the phase profiler
+gets from the same call sites, that neither changes what the program does,
+that every jitted step's XLA module carries its role — and the counters
+that ride along (adopted uploads, pending timers, full window slabs)."""
+import contextlib
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core import event as ev
+from siddhi_tpu.observability import phases as ph
+
+PATTERN_QL = """
+@app:name('SpanApp')
+@app:playback
+{annotations}
+define stream T (key long, price float, stage int);
+partition with (key of T)
+begin
+  @capacity(keys='4096', slots='4') @emit(rows='2') {query_annotations}
+  @info(name='q')
+  from every e1=T[stage == 1] -> e2=T[stage == 2 and price >= e1.price]
+  select e1.key as k, e1.price as p1, e2.price as p2 insert into Matches;
+end;
+"""
+N_KEYS = 1024       # >= 1024 contiguous keys: block memo and dense step
+
+
+def pattern_ql(annotations="", query_annotations=""):
+    return PATTERN_QL.format(annotations=annotations,
+                             query_annotations=query_annotations)
+
+
+def send_pattern(rt, i, n_keys=N_KEYS):
+    """One send completing one match per key: both stages of every key."""
+    keys = np.repeat(np.arange(n_keys, dtype=np.int64), 2)
+    stage = np.tile(np.array([1, 2], np.int32), n_keys)
+    price = np.full(2 * n_keys, 1.0 + i, np.float32)
+    ts = np.full(2 * n_keys, 1000 + 10 * i, np.int64) + stage
+    rt.get_input_handler("T").send_columns([keys, price, stage],
+                                           timestamps=ts)
+
+
+@contextlib.contextmanager
+def profiler_session(tmp_path):
+    """Everything inside is captured; yields a function that, called after
+    the block, returns the capture's `siddhi:*` events as dicts."""
+    log_dir = str(tmp_path / "capture")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+
+    def events():
+        (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                         "*.xplane.pb"))
+        out = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for t, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("siddhi:"):
+                        out.append(dict(
+                            dict(e.stats), name=e.name[len("siddhi:"):],
+                            thread=t, start=e.start_ns,
+                            end=e.start_ns + e.duration_ns))
+        return out
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+
+
+def capture(tmp_path, ql, n_sends=3, batch_cb=True):
+    """Deploy, warm one send outside the capture, run `n_sends` inside it;
+    returns (events, rows delivered per send)."""
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(ql)
+        rows = []
+        if batch_cb:
+            rt.add_batch_callback(
+                "q", lambda ts, b: rows.append(int(np.sum(b["valid"]))))
+        rt.start()
+        send_pattern(rt, 0)
+        rt.flush()
+        del rows[:]
+        with profiler_session(tmp_path) as events:
+            for i in range(1, n_sends + 1):
+                send_pattern(rt, i)
+            rt.flush()
+    finally:
+        m.shutdown()
+    return events(), rows
+
+
+# -- (a) what a capture of the blocking path holds ----------------------------
+
+def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
+    evs, rows = capture(tmp_path, pattern_ql())
+    assert rows == [N_KEYS] * 3
+    sends = [e for e in evs if e["name"] == "send"]
+    assert len(sends) == 3
+    assert [s["events"] for s in sends] == [2 * N_KEYS] * 3
+    assert len({s["batch"] for s in sends}) == 3 and \
+        all(s["batch"] > 0 for s in sends)
+    taken = ("stage", "route_keys", "obs_feed", "h2d", "dispatch", "fetch",
+             "demux", "sink")
+    for s in sends:
+        inside = [e for e in evs if e is not s and e["thread"] == s["thread"]
+                  and s["start"] <= e["start"] and e["end"] <= s["end"]]
+        names = [e["name"] for e in inside]
+        for name in taken:
+            assert name in names, (name, names)
+        # one send, one identifier, on every span it caused
+        assert {e["batch"] for e in inside} == {s["batch"]}
+        # query-level spans name their query
+        assert {e["q"] for e in inside if e["name"] != "stage"} == {"q"}
+        by = {n: [e for e in inside if e["name"] == n] for n in taken}
+        assert len(by["dispatch"]) == 1
+        assert by["dispatch"][0]["step"] == "pattern_dense_w"
+        assert by["route_keys"][0]["keys"] == N_KEYS
+        assert by["route_keys"][0]["memo_hit"] == 1
+        # the columns go up before host prep (the transfer overlaps it),
+        # what prep produced after it
+        assert len(by["h2d"]) == 2
+        assert by["h2d"][0]["bytes"] == 2 * N_KEYS * (8 + 4 + 4)
+        assert by["h2d"][0]["end"] <= by["route_keys"][0]["start"]
+        assert by["h2d"][1]["start"] >= by["obs_feed"][0]["end"]
+        kinds = sorted(e["what"] for e in by["fetch"])
+        assert kinds == ["header", "rows"]        # payload: `valid` only
+        assert all(e["bytes"] > 0 for e in by["fetch"])
+        assert by["demux"][0]["rows"] == N_KEYS
+        # pipeline order on the thread
+        order = [by[n][0]["start"] for n in
+                 ("stage", "h2d", "route_keys", "obs_feed", "dispatch",
+                  "demux")]
+        assert order == sorted(order)
+        # the emission side's observatory feed and the subscriber sit
+        # inside demux
+        d = by["demux"][0]
+        assert d["start"] <= by["sink"][0]["start"] and \
+            by["sink"][0]["end"] <= d["end"]
+    # nothing of the runtime ran outside a send
+    assert all(any(s["start"] <= e["start"] and e["end"] <= s["end"]
+                   for s in sends) for e in evs)
+
+
+# -- (b) the served path: delivery on the drainer thread ------------------------
+
+def test_serve_drainer_spans_carry_the_batch_of_their_send(tmp_path):
+    evs, rows = capture(tmp_path, pattern_ql(query_annotations="@serve"))
+    assert rows == [N_KEYS] * 3
+    sends = {s["batch"]: s for s in evs if s["name"] == "send"}
+    assert len(sends) == 3
+    sender = {s["thread"] for s in sends.values()}
+    assert len(sender) == 1
+    drained = [e for e in evs if e["thread"] not in sender]
+    assert {"fetch", "demux", "sink"} <= {e["name"] for e in drained}
+    ring = [e for e in evs if e["name"] == "fetch" and e["what"] == "ring"]
+    assert ring and all(e["batch"] in sends for e in ring)
+    # every send is delivered once, under its own batch, whichever thread
+    # drains it (the drainer's, or the flush's for what is left)
+    for name in ("demux", "sink"):
+        got = sorted(e["batch"] for e in evs if e["name"] == name)
+        assert got == sorted(sends), (name, got)
+    # inside a send nothing is fetched: the ring append is dispatch-only
+    assert not [e for e in evs if e["name"] == "fetch" and any(
+        e["thread"] == s["thread"] and s["start"] <= e["start"] <= s["end"]
+        for s in sends.values())]
+    # the columns go up twice a send: the stager's whole batch at the
+    # accept edge, then the pattern path's own two uploads (it never
+    # adopts: ROADMAP A4)
+    for b, s in sends.items():
+        assert len([e for e in evs if e["name"] == "h2d"
+                    and e["batch"] == b]) == 3
+
+
+# -- (c) the spans add no sync and, at OFF, feed nothing ------------------------
+
+class NullSpan:
+    """What the primitive is without jax.profiler.TraceAnnotation."""
+
+    def __init__(self, *a, **k):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **k):
+        pass
+
+    @staticmethod
+    def is_enabled():
+        return False
+
+
+def count_syncs(monkeypatch, ql, n=3, batch_cb=True, event_cb=False,
+                null_spans=False):
+    """(device_get calls, block_until_ready calls, phase snapshot,
+    statistics report, rows delivered) over n sends after one warm send."""
+    gets, blocks, rows = [0], [0], []
+    real_get, real_block = jax.device_get, jax.block_until_ready
+
+    def g(*a, **k):
+        gets[0] += 1
+        return real_get(*a, **k)
+
+    def b(*a, **k):
+        blocks[0] += 1
+        return real_block(*a, **k)
+
+    m = SiddhiManager()
+    try:
+        if null_spans:
+            monkeypatch.setattr(ph, "TraceAnnotation", NullSpan)
+        rt = m.create_siddhi_app_runtime(ql)
+        if batch_cb:
+            rt.add_batch_callback(
+                "q", lambda ts, bt: rows.append(int(bt["n_valid"])))
+        if event_cb:
+            rt.add_callback("q", lambda ts, cur, exp: None)
+        rt.start()
+        send_pattern(rt, 0)
+        rt.flush()
+        del rows[:]
+        monkeypatch.setattr(jax, "device_get", g)
+        monkeypatch.setattr(jax, "block_until_ready", b)
+        for i in range(1, n + 1):
+            send_pattern(rt, i)
+        rt.flush()
+        monkeypatch.setattr(jax, "device_get", real_get)
+        monkeypatch.setattr(jax, "block_until_ready", real_block)
+        snap = rt.stats.phases.snapshot()
+        report = rt.statistics()
+        phase_report = rt.phase_report()
+    finally:
+        monkeypatch.undo()
+        m.shutdown()
+    return gets[0], blocks[0], snap, report, rows, phase_report
+
+
+def test_off_spans_add_no_sync_and_feed_no_profiler(monkeypatch):
+    g, b, snap, _, rows, _ = count_syncs(monkeypatch, pattern_ql())
+    g0, b0, snap0, _, rows0, _ = count_syncs(monkeypatch, pattern_ql(),
+                                             null_spans=True)
+    assert rows == rows0 == [N_KEYS] * 3
+    assert (g, b) == (g0, b0)
+    assert g == 3 and b == 0          # one header fetch a send, no fence
+    assert snap == snap0 == {"queries": {}, "sampled": {}}
+
+
+# -- (d) BASIC does what OFF does -----------------------------------------------
+
+def test_basic_with_a_batch_callback_fetches_and_builds_what_off_does(
+        monkeypatch):
+    """Statistics are not a reader.  On the parent an output stream nobody
+    subscribed to counted as live once statistics were on: every send
+    fetched the whole compacted payload a second time, sorted it and
+    unpacked it into one Event a row, for a junction without a reader."""
+    g_off, b_off, _, _, rows_off, _ = count_syncs(monkeypatch, pattern_ql())
+
+    def boom(*a, **k):
+        raise AssertionError("an Event was built for a stream nobody reads")
+
+    monkeypatch.setattr(ev, "unpack", boom)
+    g_on, b_on, _, report, rows_on, _ = count_syncs(
+        monkeypatch, pattern_ql("@app:statistics('BASIC')"))
+    assert rows_on == rows_off == [N_KEYS] * 3
+    assert (g_on, b_on) == (g_off, b_off)
+    # the unread output stream's throughput still counts, off the header
+    assert report["streams"]["Matches"]["events"] == 4 * N_KEYS
+    assert report["streams"]["T"]["events"] == 4 * 2 * N_KEYS
+
+
+def test_basic_counts_a_routed_output_stream_once(monkeypatch):
+    """With an event callback the rows are routed into the junction, which
+    counts them in publish: the header path must not count them too."""
+    _, _, _, report, _, _ = count_syncs(
+        monkeypatch, pattern_ql("@app:statistics('BASIC')"),
+        batch_cb=False, event_cb=True)
+    assert report["streams"]["Matches"]["events"] == 4 * N_KEYS
+
+
+# -- (e) the scrape's phases, from the same call sites --------------------------
+
+def test_basic_phase_report_counts_each_phase_once_per_send(monkeypatch):
+    *_, rep = count_syncs(monkeypatch,
+                          pattern_ql("@app:statistics('BASIC')"), n=3)
+    node = rep["queries"]["q"]["phases"]
+    sends = 4                               # the warm send counts too
+    for name in ("stage_host", "dispatch_submit", "demux", "sink"):
+        assert node[name]["count"] == sends, (name, node[name])
+        assert node[name]["seconds"] > 0
+    # A2: the uploads are booked as h2d, not as staging — the columns
+    # before host prep, what prep produced after it
+    assert node["h2d"]["count"] == 2 * sends and node["h2d"]["seconds"] > 0
+    # a counting subscriber costs the header fetch and nothing else
+    assert node["d2h_drain"]["count"] == sends
+    parts = node["stage_host"]["parts"]
+    assert list(parts) == ["stage", "route_keys", "obs_feed"]
+    assert sum(p["seconds"] for p in parts.values()) == pytest.approx(
+        node["stage_host"]["seconds"], abs=1e-5)
+    assert parts["stage"]["count"] == parts["route_keys"]["count"] == sends
+    # the observatory feeds twice a send — key hotness before the step,
+    # emission-cap demand at delivery — and stage_host still counts sends
+    assert parts["obs_feed"]["count"] == 2 * sends
+    # nothing books device time by guessing any more
+    assert "device_compute" not in node
+    assert rep["queries"]["q"]["accounted"] > 0.5
+
+
+def test_self_time_is_the_span_minus_its_children_on_the_thread():
+    class Stats:
+        enabled = True
+        phases = ph.PhaseProfiler()
+
+    st = Stats()
+    import time
+    with ph.phase(st, "q", "demux"):
+        time.sleep(0.002)
+        with ph.phase(st, "q", "fetch", what="rows"):
+            time.sleep(0.006)
+        with ph.phase(st, ("q", "r"), "sink", mult=2):
+            time.sleep(0.004)
+    snap = st.phases.snapshot()["queries"]
+    q = snap["q"]
+    assert q["d2h_drain"]["ns"] >= 6e6 and q["sink"]["ns"] >= 2 * 4e6
+    # demux's own: its wall (>= 12 ms) minus fetch and sink (once each)
+    assert 2e6 <= q["demux"]["ns"] < 6e6
+    assert snap["r"]["sink"]["ns"] == q["sink"]["ns"]
+    assert [v["count"] for v in q.values()] == [1, 1, 1]
+
+
+# -- (f) every jitted step's XLA module is jit_<role> ---------------------------
+
+PLANNER_APPS = {
+    "plain": ("""define stream S (k long, v float);
+        @info(name='q') from S[v > 0.0] select k, v insert into Out;""",
+              {"plain_step"}),
+    "keyed": ("""define stream S (k long, v float);
+        partition with (k of S) begin
+        @info(name='q') from S#window.length(4)
+        select k, sum(v) as s insert into Out; end;""", {"keyed_step"}),
+    "join": ("""define stream S (k long, v float);
+        define stream R (k long, w float);
+        @info(name='q') from S#window.length(8) join R#window.length(8)
+        on S.k == R.k select S.k as k, v, w insert into Out;""",
+             {"join_left", "join_right"}),
+    "pattern": ("""define stream S (k long, v float);
+        partition with (k of S) begin
+        @capacity(keys='64', slots='4') @info(name='q')
+        from every e1=S[v == 1.0] -> e2=S[v == 2.0]
+        select e1.k as k insert into Out; end;""",
+                {"pattern_step_w", "pattern_dense_w"}),
+    "pattern_timer": ("""define stream S (k long, v float);
+        partition with (k of S) begin
+        @capacity(keys='64', slots='4') @info(name='q')
+        from every e1=S[v == 1.0] -> not S[v == 2.0] for 1 sec
+        select e1.k as k insert into Out; end;""", {"pattern_timer"}),
+    "block": ("""define stream S (k long, v float);
+        @info(name='q') from every e1=S[v == 1.0] -> e2=S[v == 2.0]
+        select e1.k as k insert into Out;""", {"pattern_block_w"}),
+    "fused": ("""define stream S (k long, v float);
+        @fuse(batches='2') @info(name='q')
+        from S[v > 0.0] select k, v insert into Out;""", {"fused_plain"}),
+    "merged": ("""define stream S (k long, v float);
+        @info(name='q') from S[v > 0.0] select k, v insert into Out;
+        @info(name='q2') from S[v > 1.0] select k, v insert into Out2;""",
+               {"merged_step"}),
+    "served": ("""define stream S (k long, v float);
+        @serve @info(name='q')
+        from S[v > 0.0] select k, v insert into Out;""",
+               {"plain_step", "ring_append", "ring_read"}),
+}
+
+
+@pytest.mark.parametrize("planner", sorted(PLANNER_APPS))
+def test_every_jitted_step_lowers_to_its_role(planner, manager):
+    ql, want = PLANNER_APPS[planner]
+    rt = manager.create_siddhi_app_runtime(
+        "@app:name('Roles')\n@app:playback\n" + ql)
+    for q in rt.query_runtimes:
+        rt.add_callback(q, lambda ts, cur, exp: None)
+    rt.start()
+    for stream in ("S", "R") if planner == "join" else ("S",):
+        h = rt.get_input_handler(stream)
+        # contiguous keys (dense step), then gappy ones (gather/scatter)
+        for i, keys in enumerate((np.arange(8), np.array([1, 5, 9, 40]))):
+            keys = keys.astype(np.int64)
+            n = keys.shape[0]
+            h.send_columns([keys, np.full(n, 1.0, np.float32)],
+                           timestamps=np.full(n, 1000 + i, np.int64))
+            h.send_columns([keys, np.full(n, 2.0, np.float32)],
+                           timestamps=np.full(n, 1010 + i, np.int64))
+    h.send_columns([np.arange(8, dtype=np.int64),
+                    np.full(8, 3.0, np.float32)],
+                   timestamps=np.full(8, 9000, np.int64))   # fires timers
+    rt.flush()
+    modules = set()
+    for q in rt.query_runtimes:
+        for _role, fn, argspecs in rt.compiled_steps(q):
+            if argspecs is None:
+                continue                      # never ran: nothing to name
+            text = fn.lower(*argspecs).as_text()
+            name = text.split("module @", 1)[1].split(" ", 1)[0]
+            assert name == "jit_" + fn._siddhi_role, (name, _role)
+            modules.add(fn._siddhi_role)
+    assert want <= modules, (want, modules)
+    assert "wrapped" not in modules
+
+
+def test_named_scopes_leave_the_compiled_pattern_step_as_it_was(manager,
+                                                                monkeypatch):
+    """jax.named_scope is op-name metadata: XLA's cost analysis of the
+    pattern step is the same with the scopes and without them."""
+    def costs(scoped):
+        if not scoped:
+            monkeypatch.setattr(jax, "named_scope",
+                                lambda name: contextlib.nullcontext())
+        m = SiddhiManager()
+        try:
+            rt = m.create_siddhi_app_runtime(pattern_ql())
+            rt.add_batch_callback("q", lambda ts, b: None)
+            rt.start()
+            send_pattern(rt, 0)
+            rt.flush()
+            out = {}
+            for _role, fn, argspecs in rt.compiled_steps("q"):
+                if argspecs is not None:
+                    text = fn.lower(*argspecs).as_text()
+                    ca = fn.lower(*argspecs).compile().cost_analysis()
+                    out[fn._siddhi_role] = (
+                        ca.get("flops"), ca.get("bytes accessed"),
+                        "nfa_advance" in fn.lower(*argspecs).as_text(
+                            debug_info=True), len(text))
+        finally:
+            m.shutdown()
+            monkeypatch.undo()
+        return out
+
+    with_scopes, without = costs(True), costs(False)
+    assert set(with_scopes) == set(without) == {"pattern_dense_w"}
+    flops, nbytes, named, _ = with_scopes["pattern_dense_w"]
+    assert named and not without["pattern_dense_w"][2]
+    assert (flops, nbytes) == without["pattern_dense_w"][:2]
+
+
+# -- counters that count, and two that were missing -----------------------------
+
+def test_adopted_total_counts_the_uploads_a_step_took(manager):
+    rt = manager.create_siddhi_app_runtime("""
+    @app:name('Adopt')
+    define stream S (k long, v float);
+    @serve @info(name='q') from S[v > 0.0] select k, v insert into Out;
+    """)
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.start()
+    h = rt.get_input_handler("S")
+    for i in range(4):
+        h.send_columns([np.arange(64, dtype=np.int64),
+                        np.full(64, 2.0, np.float32)],
+                       timestamps=np.full(64, 1000 + i, np.int64))
+    rt.flush()
+    facts = rt.serve_staging_facts()
+    assert facts["staged_total"] == 4
+    assert facts["adopted_total"] == 4       # read 0 whatever happened
+    assert facts["fallback_total"] == 0
+
+
+def test_pattern_path_never_adopts_its_prestaged_upload(manager):
+    """ROADMAP A4, pinned: under @serve the pattern path uploads its own
+    columns and lets the stager's copy go to waste."""
+    rt = manager.create_siddhi_app_runtime(
+        pattern_ql(query_annotations="@serve"))
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.start()
+    for i in range(3):
+        send_pattern(rt, i)
+    rt.flush()
+    facts = rt.serve_staging_facts()
+    assert facts["staged_total"] == 3 and facts["adopted_total"] == 0
+
+
+TIMEWINDOW_QL = """
+@app:name('Timers')
+@app:playback
+define stream S (sym long, v float);
+@capacity(window='{window}') @info(name='q')
+from S#window.time(1 sec) select sym, sum(v) as s, count() as n
+group by sym insert into Out;
+"""
+
+
+def dispatches_per_send(rt, n_sends, events=64):
+    """`dispatch` spans of query q per send, from the DETAIL-free side:
+    the recompile-free dispatch counter the profiler keeps at BASIC."""
+    h = rt.get_input_handler("S")
+    per_send, pending = [], []
+    for i in range(n_sends):
+        before = rt.stats.phases.snapshot()["queries"].get("q", {}).get(
+            "dispatch_submit", {"count": 0})["count"]
+        h.send_columns([np.arange(events, dtype=np.int64) % 8,
+                        np.full(events, 1.0, np.float32)],
+                       timestamps=np.full(events, 1000 + 600 * i, np.int64))
+        after = rt.stats.phases.snapshot()["queries"]["q"][
+            "dispatch_submit"]["count"]
+        per_send.append(after - before)
+        pending.append(rt.timers_pending())
+    return per_send, pending
+
+
+def test_timers_pile_up_under_playback_with_a_time_window(manager):
+    """Pins today's growth (PERF.md section 7): `notify_at` pushes a
+    wake-up without looking for one the query already has, every step
+    that leaves live rows pushes one, every timer that fires pushes the
+    next — so under @app:playback each send runs one more timer step than
+    the last.  The program PR that keeps one timer per query moves these
+    numbers to 1, 2, 2, 2, ... and the gauge to 1."""
+    rt = manager.create_siddhi_app_runtime(
+        "@app:statistics('BASIC')\n" + TIMEWINDOW_QL.format(window=4096))
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.start()
+    per_send, pending = dispatches_per_send(rt, 8)
+    assert per_send[:5] == [1, 1, 3, 4, 5]
+    assert per_send == sorted(per_send) and per_send[-1] >= 8
+    assert pending == sorted(pending) and pending[-1] > pending[1] >= 1
+    from siddhi_tpu.observability import render_prometheus
+    from siddhi_tpu.observability.health import app_health
+    text = render_prometheus(manager.runtimes)
+    assert f'siddhi_timers_pending{{app="Timers"}} {pending[-1]}' in text
+    assert app_health(rt)["timers_pending"] == pending[-1]
+
+
+def test_timer_span_carries_the_heap_depth(manager, tmp_path):
+    rt = manager.create_siddhi_app_runtime(TIMEWINDOW_QL.format(window=4096))
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.start()
+    with profiler_session(tmp_path) as events:
+        h = rt.get_input_handler("S")
+        for i in range(4):
+            h.send_columns([np.arange(16, dtype=np.int64) % 8,
+                            np.full(16, 1.0, np.float32)],
+                           timestamps=np.full(16, 1000 + 600 * i, np.int64))
+    timers = [e for e in events() if e["name"] == "timer"]
+    assert timers and all(e["q"] == "q" for e in timers)
+    assert max(e["pending"] for e in timers) >= 1
+    # a timer step is a dispatch like any other, nested in its timer span
+    for t in timers:
+        assert [e for e in events() if e["name"] == "dispatch"
+                and t["start"] <= e["start"] and e["end"] <= t["end"]]
+
+
+def test_full_window_slab_is_counted(manager):
+    """A `window.time` slab that fills up drops its oldest rows unexpired
+    (verify skill, round 5): the sampled fill probe now counts each time
+    it finds one full, beside the emission drop counters."""
+    from siddhi_tpu.observability import render_prometheus
+    from siddhi_tpu.utils.config import InMemoryConfigManager
+    manager.set_config_manager(InMemoryConfigManager(
+        {"state.obs.sample.every": "1"}))
+    rt = manager.create_siddhi_app_runtime(TIMEWINDOW_QL.format(window=1024))
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.start()
+    h = rt.get_input_handler("S")
+    for i in range(4):          # 2,048 rows alive at once, a 1,024-row slab
+        h.send_columns([np.arange(512, dtype=np.int64) % 8,
+                        np.full(512, 1.0, np.float32)],
+                       timestamps=np.full(512, 1000 + i, np.int64))
+    rt.flush()
+    counters = rt.stats.exposition_snapshot()["counters"]
+    assert counters.get("q.window_full", 0) >= 1
+    assert 'siddhi_window_slab_full_total{app="Timers",query="q"}' in \
+        render_prometheus(manager.runtimes)
