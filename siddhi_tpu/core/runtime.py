@@ -162,10 +162,12 @@ def _allocator_of(qr):
 _STATEOBS_ONE = np.ones(1, np.int64)
 
 
-def _stateobs_feed_slots(qr, alloc, slots) -> None:
+def _stateobs_feed_slots(qr, alloc, slots, span) -> None:
     """Fold one batch's resolved key slots (per-event slot ids, -1 =
     invalid) into the app's key-hotness tracker — host numpy only; a
-    disabled observatory costs one memoized dict read."""
+    disabled observatory costs one memoized dict read.  `span` is the
+    `obs_feed` span the call runs under: it gets `keys`, how many were
+    fed."""
     if not _stateobs.obs_enabled(qr.app):
         return
     live = slots[slots >= 0]
@@ -177,13 +179,15 @@ def _stateobs_feed_slots(qr, alloc, slots) -> None:
         keys, counts = live, _STATEOBS_ONE
     else:
         keys, counts = np.unique(live, return_counts=True)
+    span.set_metadata(keys=keys.size)
     qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity, keys, counts)
 
 
-def _stateobs_feed_group(qr, alloc, key_idx, sel, pad) -> None:
+def _stateobs_feed_group(qr, alloc, key_idx, sel, pad, span) -> None:
     """Fold one grouped batch's key set into the hotness tracker: the
     per-key row counts fall out of the already-computed [Kb, E] group
-    selection (`(sel >= 0).sum(axis=1)`) — no extra np.unique pass."""
+    selection (`(sel >= 0).sum(axis=1)`) — no extra np.unique pass.
+    `span` (the `obs_feed` span around the call) gets `keys`."""
     if not _stateobs.obs_enabled(qr.app):
         return
     keys = np.asarray(key_idx)
@@ -191,8 +195,10 @@ def _stateobs_feed_group(qr, alloc, key_idx, sel, pad) -> None:
     if not live.any():
         return
     counts = (np.asarray(sel) >= 0).sum(axis=1)
+    keys = keys[live]
+    span.set_metadata(keys=keys.size)
     qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity,
-                                    keys[live], counts[live])
+                                    keys, counts[live])
 
 
 def _wrap_stream_callback(cb) -> Callable[[List[ev.Event]], None]:
@@ -379,9 +385,9 @@ class QueryRuntime(_MeshResolved):
                     alloc.slots_for([gslot, staged.cols[pos]], valid)
                     for alloc, pos in p.pair_allocs)
         if grouped or self._touch is not None:
-            with _phases.phase(st, self.name, "obs_feed"):
+            with _phases.phase(st, self.name, "obs_feed") as sp:
                 if grouped:
-                    _stateobs_feed_slots(self, p.slot_allocator, gslot)
+                    _stateobs_feed_slots(self, p.slot_allocator, gslot, sp)
                 if self._touch is not None:
                     self._touch(gslot, now)
         return gslot, pslots
@@ -477,9 +483,9 @@ class QueryRuntime(_MeshResolved):
                     if p.partition_key_fn is not None:
                         gk = kcols + gk
                     gslot = p.slot_allocator.slots_for(gk, valid)
-            with _phases.phase(st, self.name, "obs_feed"):
+            with _phases.phase(st, self.name, "obs_feed") as sp:
                 _stateobs_feed_group(self, p.window_key_allocator, key_idx,
-                                     sel, p.key_capacity)
+                                     sel, p.key_capacity, sp)
                 if self._touch is not None:
                     self._touch(key_idx, now)
                 if gslot is not None and self._touch_group is not None:
@@ -715,9 +721,9 @@ class PatternQueryRuntime(_MeshResolved):
                                   np.arange(B, dtype=np.int32),
                                   -1)[None, :]
         if p.partition_positions:
-            with _phases.phase(st, self.name, "obs_feed"):
+            with _phases.phase(st, self.name, "obs_feed") as sp:
                 _stateobs_feed_group(self, self.slot_allocator, key_idx_np,
-                                     sel_np, p.key_capacity)
+                                     sel_np, p.key_capacity, sp)
                 if self._touch is not None:
                     self._touch(key_idx_np, now)
                 if self._dirty is not None and nuniq:
@@ -776,8 +782,8 @@ class PatternQueryRuntime(_MeshResolved):
             slots = self.slot_allocator.slots_for(key_cols, valid)
             # the [n, Kb, E] regroup is host staging work too
             key_idx, sel, counts = router.group(slots, staged.valid)
-        with _phases.phase(st, self.name, "obs_feed"):
-            _stateobs_feed_slots(self, self.slot_allocator, slots)
+        with _phases.phase(st, self.name, "obs_feed") as sp:
+            _stateobs_feed_slots(self, self.slot_allocator, slots, sp)
             if self._touch is not None:
                 self._touch(slots, now)
             if self._dirty is not None:
@@ -1495,12 +1501,12 @@ class JoinQueryRuntime(_MeshResolved):
             # lane demand is a running bucket-occupancy max the tracker
             # already mirrors host-side; push it so the HWM survives
             # window expiry shrinking the live lanes back down
-            with _phases.phase(st, self.name, "obs_feed"):
+            with _phases.phase(st, self.name, "obs_feed") as sp:
                 st.stateobs.observe(
                     self.name, "join_lane", need, self.planned.lane_k,
                     growable=True,
                     config_key="auto (lane grows via replan)")
-                _stateobs_feed_slots(self, p.join_key_allocator, out)
+                _stateobs_feed_slots(self, p.join_key_allocator, out, sp)
         cache[key] = out
         return out
 

@@ -26,7 +26,13 @@ Key hotness: staging already computes per-batch key sets (slot ids +
 per-key row counts) to group events; the observatory folds them into a
 count-min sketch (bounded memory, one-sided overestimates) plus a
 space-saving top-K (the heavy hitters) plus an exact distinct bitmap
-(slots are dense ints below the allocator capacity).  The derived
+(slots are dense ints below the allocator capacity).  The feed is on
+the served path of every keyed query at every statistics level, so a
+batch is folded in WHOLE: a few O(n) numpy passes, and of the batch's
+keys only those already tracked and the `_TOPK` heaviest others ever
+reach the top-K table's Python dict (`KeyHotness`: the rule and the
+guarantees it keeps).  Totals, distinct and the sketch stay exact sums —
+nothing is sampled.  The derived
 `hot_share` — the share of keyed traffic landing in the hottest 1% of
 keys — is the measured input ROADMAP item 4's tiered key state needs.
 
@@ -49,6 +55,7 @@ threshold, default 0.9).
 """
 from __future__ import annotations
 
+import heapq
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -64,17 +71,52 @@ STRUCTURES = ("window_keys", "group_slots", "pattern_keys", "pair_slots",
 # per tracked query — error bound e*total/1024 per estimate, one-sided
 _CMS_DEPTH = 4
 _CMS_WIDTH = 1024
-# odd multipliers for the per-row multiply-shift hashes (keys are dense
-# non-negative slot ints, so multiply-shift mixes them well enough)
+# odd multipliers of the per-row hashes (keys are dense non-negative slot
+# ints)
 _CMS_MULT = (0x9E3779B1, 0x85EBCA77, 0xC2B2AE35, 0x27D4EB2F)
+# the row hash is `(key + 1) * mult % 2**31 % _CMS_WIDTH`.  The width is a
+# power of two dividing 2**31, so that is the low bits of the product,
+# which only the low bits of `key + 1` reach: row d's counter for a key is
+# a fixed permutation (the mults are odd) of ONE shared bucket,
+# `(key + 1) & _CMS_MASK`.  A batch is therefore summed per bucket once,
+# and `_CMS_FROM[d, j]` names the bucket whose sum row d's counter j takes.
+# It also means the four rows split the keys the same way, so `estimate`
+# is as sharp as one row: a hash on the product's HIGH bits would cure
+# that and change every stored value, so it is not done in passing
+_CMS_MASK = _CMS_WIDTH - 1
+_CMS_FROM = np.stack([np.argsort((np.arange(_CMS_WIDTH) * m) & _CMS_MASK)
+                      for m in _CMS_MULT])
 _TOPK = 64
 
 
 class KeyHotness:
     """Per-query key-traffic tracker: count-min sketch + space-saving
     top-K + exact distinct bitmap.  Fed from staging's already-computed
-    per-batch key sets (slot ids + per-key row counts) — numpy only,
-    never a device array."""
+    per-batch key sets (DISTINCT slot ids + exact per-key row counts) —
+    numpy only, never a device array.
+
+    `total`, the distinct bitmap and the count-min rows are exact sums of
+    what was fed.  The space-saving table takes a whole batch at once
+    (`_ss_merge`): a batch is already an exact summary, so tracked keys
+    in it add their counts, and of the untracked ones only the `_TOPK`
+    largest go through the take-over rule (lightest first, so the
+    heaviest are the last to come and the ones that stay) — the rest
+    cannot end in the table and never touch it.  One `update` costs O(n)
+    numpy passes and at most 2 * `_TOPK` Python steps, whatever n is.
+    Whichever branch ran (one key, up to `_TOPK` keys one by one, or the
+    merge), the table keeps:
+
+    (a) a tracked key's count is never below its true count;
+    (b) with the table full, an untracked key's true count is at most
+        the table's minimum — so every key with more than
+        `total / _TOPK` rows is tracked;
+    (c) the table's counts sum to at most `total`;
+    (d) below `_TOPK` distinct keys the table is exact.
+
+    The merge raises the minimum once per key it takes over (at most
+    `_TOPK` a batch), not once per untracked key of the batch as a
+    key-by-key feed would, so wide uniform traffic inflates the floor
+    far less and `hot_share` reads tighter."""
 
     __slots__ = ("_cms", "_seen", "_ss", "total")
 
@@ -87,26 +129,31 @@ class KeyHotness:
     def update(self, keys, counts) -> None:
         keys = np.asarray(keys, np.int64).ravel()
         counts = np.asarray(counts, np.int64).ravel()
-        if keys.size == 1:
-            # scalar fast path: single-key batches dominate small sends
-            # and vectorized numpy overhead (~10x) would tax every one
-            self._update_one(int(keys[0]), int(counts[0]))
+        if keys.size < 2:
+            # scalar path: single-key batches dominate interactive and
+            # test sends, and even the few numpy calls below cost more
+            # than the whole of `_update_one`
+            if keys.size:
+                self._update_one(int(keys[0]), int(counts[0]))
             return
-        live = (keys >= 0) & (counts > 0)
-        if not live.any():
-            return
-        keys, counts = keys[live], counts[live]
+        if int(keys.min()) < 0 or int(counts.min()) <= 0:
+            live = (keys >= 0) & (counts > 0)
+            if not live.any():
+                return
+            keys, counts = keys[live], counts[live]
         self.total += int(counts.sum())
         # exact distinct: slots are dense ints < allocator capacity
-        inb = keys < self._seen.shape[0]
-        if inb.any():
-            self._seen[keys[inb]] = True
-        # CMS rows: vectorized multiply-shift hash + scatter-add
-        for d in range(_CMS_DEPTH):
-            h = ((keys + 1) * _CMS_MULT[d]) % (2 ** 31) % _CMS_WIDTH
-            np.add.at(self._cms[d], h, counts)
-        for k, c in zip(keys.tolist(), counts.tolist()):
-            self._ss_feed(k, c)
+        seen = self._seen
+        if int(keys.max()) < seen.shape[0]:
+            seen[keys] = True
+        else:
+            seen[keys[keys < seen.shape[0]]] = True
+        # CMS rows: one scatter-add into the shared buckets, then each
+        # row takes the 1024 sums through its permutation (_CMS_FROM)
+        buckets = np.zeros(_CMS_WIDTH, np.int64)
+        np.add.at(buckets, (keys + 1) & _CMS_MASK, counts)
+        self._cms += buckets[_CMS_FROM]
+        self._ss_merge(keys, counts)
 
     def _update_one(self, k: int, c: int) -> None:
         if k < 0 or c <= 0:
@@ -117,8 +164,51 @@ class KeyHotness:
         kk = k + 1
         cms = self._cms
         for d in range(_CMS_DEPTH):
-            cms[d, (kk * _CMS_MULT[d]) % (2 ** 31) % _CMS_WIDTH] += c
+            cms[d, (kk * _CMS_MULT[d]) & _CMS_MASK] += c
         self._ss_feed(k, c)
+
+    def _ss_merge(self, keys: np.ndarray, counts: np.ndarray) -> None:
+        """Fold one batch (distinct live keys, exact counts) into the
+        space-saving table."""
+        ss = self._ss
+        if keys.size > _TOPK:
+            if ss:
+                # the tracked keys of the batch add their counts in place
+                tracked = np.sort(np.fromiter(ss, np.int64, len(ss)))
+                at = np.searchsorted(tracked, keys)
+                at[at == tracked.size] = 0
+                hit = tracked[at] == keys
+                if hit.any():
+                    for k, c in zip(keys[hit].tolist(),
+                                    counts[hit].tolist()):
+                        ss[k] += c
+                    miss = ~hit
+                    keys, counts = keys[miss], counts[miss]
+            if keys.size > _TOPK:
+                # an untracked key with _TOPK others of its batch at or
+                # above it cannot end in the table: their take-overs
+                # alone lift the minimum past the old floor + its count
+                top = np.argpartition(counts, keys.size - _TOPK)[-_TOPK:]
+                keys, counts = keys[top], counts[top]
+        late = []
+        for k, c in zip(keys.tolist(), counts.tolist()):
+            if k in ss:
+                ss[k] += c
+            elif len(ss) < _TOPK:
+                ss[k] = c
+            else:
+                late.append((c, k))
+        if late:
+            # take-overs, lightest first: the heaviest of the batch come
+            # last and are the ones still standing at the end
+            late.sort()
+            heap = [(c, k) for k, c in ss.items()]
+            heapq.heapify(heap)
+            for c, k in late:
+                floor, victim = heap[0]
+                heapq.heapreplace(heap, (floor + c, k))
+                del ss[victim]
+                ss[k] = floor + c
 
     def _ss_feed(self, k: int, c: int) -> None:
         # space-saving: exact for tracked keys; an untracked key takes
@@ -139,10 +229,9 @@ class KeyHotness:
 
     def estimate(self, key: int) -> int:
         """CMS point estimate — never underestimates the true count."""
-        k = np.int64(key)
-        return int(min(
-            self._cms[d][((k + 1) * _CMS_MULT[d]) % (2 ** 31) % _CMS_WIDTH]
-            for d in range(_CMS_DEPTH)))
+        kk = int(key) + 1
+        return int(min(self._cms[d, (kk * _CMS_MULT[d]) & _CMS_MASK]
+                       for d in range(_CMS_DEPTH)))
 
     def top(self, n: int = 10) -> List[Tuple[int, int]]:
         """Heavy hitters with tightened counts: the space-saving count

@@ -126,6 +126,7 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
         assert by["dispatch"][0]["step"] == "pattern_dense_w"
         assert by["route_keys"][0]["keys"] == N_KEYS
         assert by["route_keys"][0]["memo_hit"] == 1
+        assert by["obs_feed"][0]["keys"] == N_KEYS
         # the columns go up before host prep (the transfer overlaps it),
         # what prep produced after it
         assert len(by["h2d"]) == 2

@@ -123,6 +123,160 @@ def test_key_hotness_space_saving_overestimates_in_place():
     assert 4000 in tracked and 1 <= tracked[4000] <= 4
 
 
+# -- KeyHotness: the batch merge against a per-key reference ------------------
+
+_SPACE = 1 << 20
+# batches a case: enough that the table fills, takes over and is hit again
+_BATCHES = {1: 48, 2: 48, 63: 12, 64: 12, 65: 12, 4096: 6, 131072: 2}
+
+
+def _stream(kind, width, batches, rng):
+    """`batches` batches of exactly `width` distinct keys with their row
+    counts, as staging hands them over."""
+    for i in range(batches):
+        if kind == "sweep":
+            keys = (np.arange(width, dtype=np.int64) + i * width) % _SPACE
+            counts = np.full(width, 4, np.int64)
+        elif kind == "uniform":
+            keys = rng.choice(_SPACE, width, replace=False).astype(np.int64)
+            counts = rng.integers(1, 5, width)
+        else:   # zipf(1.3) events; the hot ranks come back in every batch
+            keys, counts = np.unique(
+                np.minimum(rng.zipf(1.3, 4 * width) - 1, _SPACE - 1),
+                return_counts=True)
+            keys, counts = keys[:width], counts[:width]
+            spare = np.setdiff1d(rng.choice(_SPACE, 2 * width), keys)
+            spare = spare[:width - keys.size]
+            keys = np.concatenate([keys, spare]).astype(np.int64)
+            counts = np.concatenate([counts, np.ones(spare.size, np.int64)])
+            order = rng.permutation(width)      # first-touch order, unsorted
+            keys, counts = keys[order], counts[order]
+        assert keys.size == width == np.unique(keys).size
+        yield keys, counts
+
+
+@pytest.mark.parametrize("kind", ["uniform", "zipf", "sweep"])
+@pytest.mark.parametrize("width", sorted(_BATCHES))
+def test_key_hotness_batch_merge_keeps_invariants_and_exact_sums(kind, width):
+    h = KeyHotness(_SPACE)
+    true = {}                                   # key -> rows really fed
+    rows = [[0] * so_mod._CMS_WIDTH for _ in range(so_mod._CMS_DEPTH)]
+    rng = np.random.default_rng(width * 3 + len(kind))
+    for keys, counts in _stream(kind, width, _BATCHES[width], rng):
+        h.update(keys, counts)
+        for k, c in zip(keys.tolist(), counts.tolist()):
+            true[k] = true.get(k, 0) + c
+            for d, mult in enumerate(so_mod._CMS_MULT):
+                rows[d][(k + 1) * mult % 2 ** 31 % so_mod._CMS_WIDTH] += c
+        total, ss = sum(true.values()), h._ss
+        # exact counters stay exact, bit for bit
+        assert h.total == total
+        assert h.distinct == len(true)
+        assert np.array_equal(h._cms, np.array(rows, np.int64))
+        assert len(ss) == min(len(true), so_mod._TOPK)
+        # (a) a tracked count never underestimates
+        assert all(c >= true[k] for k, c in ss.items())
+        # (b) nothing untracked is above the table's minimum
+        if len(true) > so_mod._TOPK:
+            floor = min(ss.values())
+            assert max(c for k, c in true.items() if k not in ss) <= floor
+            assert all(k in ss for k, c in true.items()
+                       if c > total / so_mod._TOPK)
+        # (c) the table never holds more than was fed
+        assert sum(ss.values()) <= total
+        # (d) exact while every key fits
+        if len(true) <= so_mod._TOPK:
+            assert ss == true
+        assert all(h.estimate(k) >= true[k] for k in list(true)[:32])
+
+
+class _CountingDict(dict):
+    """A space-saving table that counts how often it is touched."""
+
+    def __init__(self, *a):
+        super().__init__(*a)
+        self.steps = self.drops = self.reads = 0
+
+    def __setitem__(self, k, v):
+        self.steps += 1
+        super().__setitem__(k, v)
+
+    def __delitem__(self, k):
+        self.drops += 1
+        super().__delitem__(k)
+
+    def pop(self, *a):
+        self.drops += 1
+        return super().pop(*a)
+
+    def __getitem__(self, k):
+        self.reads += 1
+        return super().__getitem__(k)
+
+    def get(self, *a):
+        self.reads += 1
+        return super().get(*a)
+
+    def __contains__(self, k):
+        self.reads += 1
+        return super().__contains__(k)
+
+
+def test_key_hotness_wide_batch_touches_table_a_bounded_number_of_times():
+    """The cost guard, without a clock: 131,072 keys a batch, the table
+    full and every tracked key in the batch.  Every per-key step ends in
+    one write of the table, and there are at most two per table entry
+    (one add in place, one take-over) — never one per key of the batch."""
+    width, topk = 131072, so_mod._TOPK
+    h = KeyHotness(_SPACE)
+    keys = np.arange(width, dtype=np.int64)
+    h.update(keys, np.full(width, 4, np.int64))
+    assert len(h._ss) == topk
+    h._ss = table = _CountingDict(h._ss)
+    h.update(keys[::-1], np.arange(1, width + 1, dtype=np.int64))
+    assert topk <= table.steps <= 2 * topk
+    assert table.drops <= topk and table.reads <= 4 * topk
+    assert h.total == 4 * width + width * (width + 1) // 2
+    assert len(table) == topk
+    assert all(c >= 4 + width - k for k, c in table.items())
+
+
+def test_wide_pattern_sends_feed_exact_totals_and_span_carries_keys(
+        manager, tmp_path):
+    """Runtime level: sends wider than the space-saving table go through
+    the batch merge; the report's exact counters equal what was fed, and
+    each send's `siddhi:obs_feed` span says how many keys it fed."""
+    width = 3 * so_mod._TOPK
+    rt = manager.create_siddhi_app_runtime(
+        PATTERN_QL.replace("keys='16'", "keys='1024'"))
+    rt.start()
+    h = rt.get_input_handler("T")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for i in range(3):      # windows of keys sliding by half a send
+            keys = np.repeat(np.arange(width, dtype=np.int64)
+                             + i * width // 2, 2)
+            h.send_columns([keys, np.full(2 * width, 1.0 + i, np.float32),
+                            np.tile(np.array([1, 2], np.int32), width)],
+                           timestamps=np.full(2 * width, 1000 + i, np.int64))
+        rt.flush()
+    finally:
+        jax.profiler.stop_trace()
+    hot = rt.state_report()["hotness"]["q"]
+    assert hot["total"] == 3 * 2 * width
+    assert hot["distinct"] == 2 * width
+    (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
+    fed = [dict(e.stats).get("keys")
+           for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+           for line in plane.lines for e in line.events
+           if e.name == "siddhi:obs_feed"]
+    # the emission-cap demand is an `obs_feed` span too, and feeds no keys
+    assert [k for k in fed if k is not None] == [width] * 3
+
+
 # -- StateObservatory: accumulator arithmetic --------------------------------
 
 def test_observe_tracks_high_water_and_capacity_refresh():
