@@ -1,0 +1,179 @@
+"""pattern_32m: traffic, plain reference and comparison.
+
+The deployment is `app.siddhi` beside this file: per partition key,
+    every e1[v==1] -> e2[v==2, p>=e1.p] -> e3[v==3] -> e4[v==4, p>=e3.p]
+selecting (e1.key, e1.price, e2.price, e4.price), with the key space
+sharded over `shards` chips by the app's own `@app:mesh`.  This is the
+configuration's own copy of pattern_1m's yardstick — same generator, same
+reference, same comparison — with two differences: `send_keys` sweeps the
+traffic's `active_keys` instead of the whole key space, and `least_bytes`
+is ONE chip's share of a send.  Everything here is numpy and imports nothing
+of siddhi_tpu: it is the yardstick the program is held to, so it must not
+move when the program does; how the keys are sharded is the program's
+business, and the reference knows nothing of it.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from benchmarks.harness.numeric import to_bf16
+
+STAGES = 4
+# bytes one event needs on the wire: long key, f32 price, i32 volume, long
+# timestamp; and one result row: long key, 3 x f32 price, long timestamp
+EVENT_BYTES = 8 + 4 + 4 + 8
+ROW_BYTES = 8 + 3 * 4 + 8
+
+
+def plan(seed: int, traffic: dict, sizes: dict) -> dict:
+    """What is drawn once per run: for `key_order: permuted` a seeded
+    permutation of the whole key space, so that a key comes round again
+    only after every other key has."""
+    out = {"n_keys": int(sizes["n_keys"])}
+    if traffic["key_order"] == "permuted":
+        out["perm"] = np.random.default_rng([seed, 0x9e37]).permutation(
+            out["n_keys"]).astype(np.int64)
+    return out
+
+
+def send_keys(i: int, traffic: dict, plan_: dict) -> np.ndarray:
+    """The distinct keys of the i-th send of this traffic: blocks of
+    `keys_per_send` over the ACTIVE range — keys 0 ... `active_keys` - 1 of
+    the `n_keys` the deployment holds (the whole key space where the traffic
+    names no `active_keys`)."""
+    kb = int(traffic["keys_per_send"])
+    active = int(traffic.get("active_keys", plan_["n_keys"]))
+    if not kb <= active <= plan_["n_keys"]:
+        raise ValueError(f"active_keys {active} must lie between "
+                         f"keys_per_send {kb} and n_keys {plan_['n_keys']}")
+    blocks = active // kb
+    lo = (i % blocks) * kb
+    if traffic["key_order"] == "contiguous_sweep":
+        return np.arange(lo, lo + kb, dtype=np.int64)
+    if traffic["key_order"] == "permuted":
+        return plan_["perm"][lo:lo + kb]
+    raise ValueError(f"unknown key_order {traffic['key_order']!r}")
+
+
+def make_send(rng, i: int, traffic: dict, plan_: dict, clock_ms: int) -> dict:
+    """One send: each key gets its 4 stages in arrival order, prices seeded
+    so that each key completes exactly one match (p2 >= p1, p4 >= p3) with a
+    payload that differs per key and per send."""
+    k = send_keys(i, traffic, plan_)
+    kb = k.shape[0]
+    r = rng.random((kb, STAGES), np.float32)
+    price = np.stack([r[:, 0], r[:, 0] + r[:, 1],
+                      r[:, 2], r[:, 2] + r[:, 3]], 1)
+    return {
+        "cols": [np.repeat(k, STAGES),
+                 np.ascontiguousarray(price.reshape(-1)),
+                 np.tile(np.arange(1, STAGES + 1, dtype=np.int32), kb)],
+        "ts": clock_ms + np.tile(np.arange(STAGES, dtype=np.int64), kb),
+        "events": kb * STAGES,
+    }
+
+
+def events_per_send(traffic: dict) -> int:
+    return int(traffic["keys_per_send"]) * STAGES
+
+
+def clock_step_ms(traffic: dict) -> int:
+    return 10
+
+
+def expected_rows(send: dict) -> int:
+    """Every key of a send completes exactly one match."""
+    return send["events"] // STAGES
+
+
+def reference(sends: list, plan_: dict) -> list:
+    """Plain per-key evaluation of the pattern over each send (4
+    consecutive events per key; no partial match is alive at the start of a
+    send, because every earlier visit of a key completed its match and
+    `every` re-arms only e1).  Rows in key order."""
+    out = []
+    for s in sends:
+        keys, price, vol = s["cols"]
+        k = keys.reshape(-1, STAGES)
+        p = price.reshape(-1, STAGES)
+        v = vol.reshape(-1, STAGES)
+        if not bool((k == k[:, :1]).all()):
+            raise ValueError("reference expects 4 consecutive rows per key")
+        hit = ((v == np.arange(1, STAGES + 1)).all(1) &
+               (p[:, 1] >= p[:, 0]) & (p[:, 3] >= p[:, 2]))
+        rows = {"k": k[hit, 0], "p1": p[hit, 0], "p2": p[hit, 1],
+                "p4": p[hit, 3]}
+        out.append(canonical(rows))
+    return out
+
+
+def canonical(rows: dict) -> dict:
+    """Rows of one send in the order the comparison uses (by key: a key
+    matches once per send, and the program emits rank-major, not in
+    arrival order)."""
+    order = np.argsort(rows["k"], kind="stable")
+    return {n: a[order] for n, a in rows.items()}
+
+
+class Attribution:
+    """Result row -> the send that completes it, by content: a `key ->
+    send` array written when a send is issued.  Holds whichever thread
+    delivers, as long as a key's result is delivered before the key is sent
+    again (a whole pass over the key space later)."""
+
+    def __init__(self, plan_: dict):
+        self.key2send = np.full(plan_["n_keys"], -1, np.int64)
+
+    def on_issue(self, sid: int, send: dict) -> None:
+        self.key2send[send["cols"][0][::STAGES]] = sid
+
+    def attribute(self, rows: dict) -> np.ndarray:
+        k = rows["k"]
+        ok = (k >= 0) & (k < self.key2send.shape[0])
+        sids = np.full(k.shape[0], -1, np.int64)
+        sids[ok] = self.key2send[k[ok]]
+        return sids
+
+
+# each number compared, with its limit: all are exact comparisons (keys and
+# prices are carried through the NFA captures, never computed), so 0
+LIMITS = {"rows_missing": 0, "rows_unexpected": 0, "rows_differing": 0}
+
+
+def compare(got: dict, want: dict) -> dict:
+    """One send's delivered rows (canonical order) against the reference's:
+    {number: value}, each held to LIMITS."""
+    n_got, n_want = got["k"].shape[0], want["k"].shape[0]
+    missing = int(np.setdiff1d(want["k"], got["k"]).shape[0])
+    unexpected = n_got - (n_want - missing)
+    differing = 0
+    if n_got == n_want and missing == 0:
+        bad = np.zeros(n_want, bool)
+        for n in want:
+            bad |= got[n] != want[n]
+        differing = int(bad.sum())
+    return {"rows_missing": missing, "rows_unexpected": max(unexpected, 0),
+            "rows_differing": differing}
+
+
+def control_rows(want: dict) -> dict:
+    """What the nearest lower precision would deliver: the reference's rows
+    with the f32 payload carried as bfloat16."""
+    return {n: (to_bf16(a) if a.dtype == np.float32 else a)
+            for n, a in want.items()}
+
+
+def least_bytes(traffic: dict, sizes: dict, config: dict) -> int:
+    """Bytes the ALGORITHM needs to move through ONE chip's HBM for one
+    send, from shapes: each touched key's NFA state row read and written,
+    the batch columns in, the matched rows out — the whole send's bytes
+    divided by `shards`, because a key's state lives on one chip and a
+    uniform sweep gives every chip a quarter of each send.  One chip's
+    share, not the host's total: `step_roofline`'s reader divides by the
+    MEAN busy time per device plane and by one chip's peak bytes per second,
+    so the whole send's bytes there would count the work four times.  Not
+    what today's program moves (it uploads the columns to every chip)."""
+    kb = int(traffic["keys_per_send"])
+    whole = (2 * kb * int(config["state_bytes_per_key"]) +
+             kb * STAGES * EVENT_BYTES + kb * ROW_BYTES)
+    return whole // int(sizes["shards"])

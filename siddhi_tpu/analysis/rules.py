@@ -384,14 +384,24 @@ def _float_partition_key(ctx: AnalysisContext) -> Iterator[Finding]:
 
 def _mesh_devices(ctx: AnalysisContext) -> int:
     """Deploy-target mesh size: the live runtime's mesh when analyzing a
-    runtime, else LintConfig.mesh_devices (CLI --mesh-size), else 0 =
-    unknown (PART002 stays silent — mesh size is a deploy property)."""
+    runtime, else the app's own `@app:mesh(shards='N')`, else
+    LintConfig.mesh_devices (CLI --mesh-size), else 0 = unknown (PART002
+    stays silent — without the annotation mesh size is a deploy
+    property)."""
     rt = ctx.runtime
     if rt is not None:
         from ..sharding import shard_count
         n = shard_count(rt)
         if n > 1:
             return n
+    from ..core.plan_facts import mesh_shards
+    from ..exceptions import SiddhiAppValidationError
+    try:
+        n = mesh_shards(ctx.app)
+    except SiddhiAppValidationError:   # a malformed count: deploy's error
+        n = None
+    if n is not None:
+        return n
     return int(getattr(ctx.config, "mesh_devices", 0) or 0)
 
 

@@ -9,6 +9,7 @@ sequential-per-key NFA advance.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -133,7 +134,11 @@ class PlannedPatternQuery:
     output_event_type: str
     steps: Dict[str, Callable]          # stream_id -> jitted step
     timer_step: Optional[Callable]
-    init_state: Callable                # (K) -> (pattern_state, sel_state)
+    # (K) -> ((b32, b64, scalars), sel_state): the one jitted init, placed
+    # by the plan's mesh (_init_program)
+    init_state: Callable
+    # () -> ([W32, 1], [W64, 1], scalars): one fresh key's packed column
+    init_columns: Callable
     key_capacity: int
     slots: int
     partition_positions: Optional[Dict[str, List[int]]] = None
@@ -389,13 +394,12 @@ def plan_pattern_query(
                          for sid in spec.stream_ids}
         step_bodies = raw_steps
     else:
-        steps = {sid: _shard_step(body, mesh, packer, pexec, sel,
-                                  owner=name)
+        steps = {sid: _shard_step(body, mesh, packer, sel, owner=name)
                  for sid, body in raw_steps.items()}
         # @fuse over the mesh: scan-of-K-batches inside the shard_map
         # (fusion._dispatch_pattern routes stacks here)
         shard_fused_steps = {
-            sid: _shard_fused_step(body, mesh, packer, pexec, sel,
+            sid: _shard_fused_step(body, mesh, packer, sel,
                                    owner=f"fused:{name}")
             for sid, body in raw_steps.items()}
 
@@ -431,8 +435,8 @@ def plan_pattern_query(
         timer_step = jit_step(tstep, owner=name, role="pattern_timer",
                               donate_argnums=(0, 1))
 
-    def init_state(K: int):
-        return packer.pack(pexec.init_state(K)), sel.init_state()
+    init_state = _init_program(packer, pexec, sel, mesh)
+    init_columns = functools.partial(_init_columns, packer, pexec)
 
     return PlannedPatternQuery(
         name=name, spec=spec, exec=pexec,
@@ -446,6 +450,7 @@ def plan_pattern_query(
         steps=steps, dense_steps=dense_steps,
         steps_w=steps_w, dense_steps_w=dense_steps_w,
         timer_step=timer_step, init_state=init_state,
+        init_columns=init_columns,
         key_capacity=key_capacity, slots=slots,
         partition_positions=partition_positions,
         partition_key_fns=partition_key_fns,
@@ -485,23 +490,61 @@ def _used_refs(query: Query, spec: PatternSpec) -> set:
     return used
 
 
-def _shard_specs(packer: "StatePacker", pexec: PatternExec,
-                 sel: SelectorExec):
+def _shard_specs(packer: "StatePacker", sel: SelectorExec):
     """(pattern-state spec, selector-state spec) for the sharded pattern
     layouts — blobs are [W, K] with the key (shard) axis at axis 1;
-    selector slabs shard axis 0; scalars replicate."""
+    selector slabs shard axis 0; scalars replicate.  Read from shapes
+    alone (`eval_shape`): nothing is allocated to learn a layout."""
     from jax.sharding import PartitionSpec as P
 
-    ex_packed = packer.pack(pexec.init_state(2))
-    ex_s = sel.init_state()
-
     def leaf_spec(x):
-        return P() if getattr(x, "ndim", 0) == 0 else P("shard")
+        return P() if x.ndim == 0 else P("shard")
 
     pspec = (P(None, "shard"), P(None, "shard"),
-             tuple(P() for _ in ex_packed[2]))
-    sspec = jax.tree.map(leaf_spec, ex_s)
+             tuple(P() for _ in packer.scalars))
+    sspec = jax.tree.map(leaf_spec, jax.eval_shape(sel.init_state))
     return pspec, sspec
+
+
+def _init_columns(packer: "StatePacker", pexec: PatternExec):
+    """([W32, 1], [W64, 1], scalars): the packed state of ONE fresh key —
+    what `_init_program` broadcasts along the key axis and what the
+    partition purger writes back over a recycled key's column."""
+    return packer.pack(pexec.init_state(1))
+
+
+def _init_program(packer: "StatePacker", pexec: PatternExec,
+                  sel: SelectorExec, mesh):
+    """The ONE state-init path, mesh or no mesh: `init_state(K)` ->
+    ((b32, b64, scalars), selector state) from one jitted program whose
+    `out_shardings` are the NamedShardings of `_shard_specs` (none
+    without a mesh: the default device).
+
+    Every NFA leaf starts key-uniform (`PatternExec.init_state` fills
+    each with one value — the purger's reset column rests on the same
+    fact), so a blob is its one-key column broadcast along the key axis:
+    XLA writes each [W, K/n] share where it lives, in place.  No chip
+    ever holds a per-leaf slab, a concatenated second copy or another
+    chip's share — at 33,554,432 keys the whole state is 17.4 GB and no
+    single chip could.  Every call returns buffers of its own, so two
+    runtimes of one plan (or a regrown one) never alias each other and
+    each may donate its state to its steps; nothing needs copying
+    afterwards."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def pattern_init(K: int):
+        c32, c64, scalars = _init_columns(packer, pexec)
+        return ((jnp.broadcast_to(c32, (packer.w32, K)),
+                 jnp.broadcast_to(c64, (packer.w64, K)), scalars),
+                sel.init_state())
+
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            _shard_specs(packer, sel),
+            is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(pattern_init, static_argnums=0, out_shardings=shardings)
 
 
 def _shard_local(body):
@@ -538,8 +581,7 @@ def _shard_local(body):
     return local
 
 
-def _shard_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
-                sel: SelectorExec,
+def _shard_step(body, mesh, packer: "StatePacker", sel: SelectorExec,
                 owner=None):
     """Shard the pattern step over the mesh 'shard' axis.
 
@@ -555,7 +597,7 @@ def _shard_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
     """
     from jax.sharding import PartitionSpec as P
 
-    pspec, sspec = _shard_specs(packer, pexec, sel)
+    pspec, sspec = _shard_specs(packer, sel)
     bspec = P("shard")    # sharded inputs: [n*Kb, ...] on axis 0
     rspec = P()           # raw event columns [B]: replicated to all shards
     sharded = jax.shard_map(
@@ -566,7 +608,7 @@ def _shard_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
                     donate_argnums=(0, 1))
 
 
-def _shard_fused_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
+def _shard_fused_step(body, mesh, packer: "StatePacker",
                       sel: SelectorExec, owner=None):
     """@fuse(batches=K) over the MESH: one shard_map dispatch whose local
     body is a lax.scan over K stacked batches — per-dispatch overhead
@@ -579,7 +621,7 @@ def _shard_fused_step(body, mesh, packer: "StatePacker", pexec: PatternExec,
     from jax.sharding import PartitionSpec as P
     from .steputil import strongify
 
-    pspec, sspec = _shard_specs(packer, pexec, sel)
+    pspec, sspec = _shard_specs(packer, sel)
     local = _shard_local(body)
     bspec2 = P(None, "shard")   # stacked sharded inputs: [K, n*Kb, ...]
 

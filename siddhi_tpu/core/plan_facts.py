@@ -607,6 +607,28 @@ def fuse_depth(app, q) -> int:
     return max(1, int(k))
 
 
+def mesh_shards(app) -> Optional[int]:
+    """@app:mesh(shards='N'): the shard-mesh size the app asks for in its
+    own text, or None when it says nothing.  Shared by the deploy path
+    (`SiddhiManager.create_siddhi_app_runtime` builds the mesh from it)
+    and lint PART002; a value that is not a whole number >= 1 is a
+    deploy error, not a default."""
+    ann = app.get_annotation("app:mesh")
+    if ann is None:
+        return None
+    raw = ann.element("shards", ann.element(None))
+    try:
+        n = int(str(raw))
+    except (TypeError, ValueError):
+        n = 0
+    if n < 1:
+        from ..exceptions import SiddhiAppValidationError
+        raise SiddhiAppValidationError(
+            f"@app:mesh(shards={raw!r}): shards must be a whole number "
+            f">= 1")
+    return n
+
+
 def serve_enabled(app, q) -> bool:
     """@serve on the query, any input stream definition, or @app:serve —
     the device-resident serving loop (siddhi_tpu/serving): emissions

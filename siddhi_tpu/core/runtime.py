@@ -24,7 +24,8 @@ import jax
 import numpy as np
 
 from ..exceptions import (CannotRestoreStateError, DefinitionNotExistError,
-                          MatchOverflowError, QueryNotExistError)
+                          MatchOverflowError, QueryNotExistError,
+                          SiddhiAppValidationError)
 from ..observability import tracing as _tracing
 from ..observability import phases as _phases
 from ..observability import stateobs as _stateobs
@@ -543,9 +544,17 @@ class PatternQueryRuntime(_MeshResolved):
                  slot_allocator=None):
         self.planned = planned
         self.app = app
-        self.state = jax.tree.map(
-            lambda x: jax.numpy.array(x, copy=True),
-            planned.init_state(planned.key_capacity))
+        # the plan's one jitted init writes the state where it lives
+        # (each chip its own [W, K/n] share under a mesh) and returns
+        # buffers no other runtime of this plan holds, so the steps may
+        # donate them: nothing is copied afterwards
+        with _phases.phase(app.stats, planned.name, "state_init") as sp:
+            # waited for: deploy is not the hot path, and a state that
+            # does not fit fails here, not at the first send
+            self.state = jax.block_until_ready(
+                planned.init_state(planned.key_capacity))
+            sp.set_metadata(bytes=_phases.tree_nbytes(self.state),
+                            shards=_sharding.shard_count(planned))
         self.callbacks: List[Callable] = []
         self.batch_callbacks: List[Callable] = []
         self.next_wakeup: int = _NO_WAKEUP_INT
@@ -780,8 +789,13 @@ class PatternQueryRuntime(_MeshResolved):
                 key_cols = [staged.cols[i] for i in pos]
                 valid = staged.valid
             slots = self.slot_allocator.slots_for(key_cols, valid)
-            # the [n, Kb, E] regroup is host staging work too
-            key_idx, sel, counts = router.group(slots, staged.valid)
+            # the [n, Kb, E] regroup is host staging work too, under its
+            # own span: route_keys' self time is slot resolution alone
+            with _phases.phase(st, self.name, "shard_group",
+                               shards=router.n_shards) as sp:
+                key_idx, sel, counts = router.group(slots, staged.valid)
+                sp.set_metadata(rows=key_idx.size,
+                                keys=int((key_idx < router.block).sum()))
         with _phases.phase(st, self.name, "obs_feed") as sp:
             _stateobs_feed_slots(self, self.slot_allocator, slots, sp)
             if self._touch is not None:
@@ -804,7 +818,8 @@ class PatternQueryRuntime(_MeshResolved):
         flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
         with _phases.phase(self.app.stats, self.name, "h2d",
                            bytes=_phases.nbytes(staged.ts, sel, key_idx,
-                                            *staged.cols)):
+                                                *staged.cols),
+                           shards=self.shard_router.n_shards):
             raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
             ts_d = jax.numpy.asarray(staged.ts)
             sel_d = jax.numpy.asarray(flat(sel))
@@ -2233,7 +2248,7 @@ class _PartitionPurger:
         for qr in runtimes:
             if isinstance(qr, PatternQueryRuntime):
                 qr._touch = self._make_touch(self._seen_shared)
-                (b32i, b64i, _), _ = qr.planned.init_state(1)
+                b32i, b64i, _ = qr.planned.init_columns()
                 self._init_cols[id(qr)] = (jax.numpy.asarray(b32i),
                                            jax.numpy.asarray(b64i))
                 continue
@@ -4352,6 +4367,40 @@ class SiddhiAppRuntime:
             self.stats.stateobs.adopt_ledger(sizing)
 
 
+def _resolve_mesh(app: SiddhiApp, mesh):
+    """The mesh an app deploys on: the `mesh=` argument, or the one its
+    own text asks for with `@app:mesh(shards='N')` — the first N of
+    `jax.devices()` on a 'shard' axis, so a tenant who deploys by text
+    alone (REST, a file) can shard.  `shards='1'` is the unsharded
+    runtime (None).  Fewer devices than asked for is a deploy error that
+    names both numbers, never a quiet one-chip deployment; so is an
+    explicit mesh whose size disagrees with the annotation (one that
+    agrees wins, devices and all)."""
+    from .plan_facts import mesh_shards
+    n = mesh_shards(app)
+    if n is None:
+        return mesh
+    if mesh is not None:
+        have = _sharding.shard_count(mesh)
+        if have != n:
+            raise SiddhiAppValidationError(
+                f"@app:mesh(shards='{n}') disagrees with the mesh= "
+                f"argument, which has {have} device(s): drop one of them "
+                f"or make them agree")
+        return mesh
+    if n == 1:
+        return None
+    devs = jax.devices()
+    if len(devs) < n:
+        raise SiddhiAppValidationError(
+            f"@app:mesh(shards='{n}') asks for {n} devices and jax has "
+            f"{len(devs)} ({devs[0].platform}); deploy on a host with at "
+            f"least {n}, or on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n}")
+    from jax.sharding import Mesh
+    return Mesh(np.array(devs[:n]), ("shard",))
+
+
 class SiddhiManager:
     """reference: CORE/SiddhiManager.java:49"""
 
@@ -4473,6 +4522,7 @@ class SiddhiManager:
         # runtime is constructed — a denial provably precedes any
         # planning, tracing, or device allocation (core/admission.py)
         from .admission import check_deploy
+        mesh = _resolve_mesh(app, mesh)
         check_deploy(app, self, mesh=mesh)
         runtime = SiddhiAppRuntime(app, self, mesh=mesh)
         self.runtimes[runtime.name] = runtime

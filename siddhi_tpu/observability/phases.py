@@ -35,6 +35,8 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
               its self time is what no child span covers)
   stage       pad/adopt or pack_np into a StagedBatch        stage_host
   route_keys  key -> slot routing, grouping, ts-wire build   stage_host
+  shard_group the router's [n, Kb, E] regroup of a sharded
+              send, nested in route_keys                     stage_host
   obs_feed    state observatory feed, liveness, dirty marks  stage_host
   h2d         every host->device upload (host wall)          h2d
   dispatch    the jitted step call (submit only)             dispatch_submit
@@ -43,6 +45,8 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
   sink        callbacks, table op, rate limit, re-publish    sink
   compile     jit_step's body while tracing a new signature  (none)
   timer       the scheduler firing a timer step              (none)
+  state_init  a pattern runtime's one jitted state init, at
+              deploy (`bytes`, `shards`)                     (none)
 
 `ring_wait` (emission-ring / drainer-queue residency, append -> take) is
 a difference of two stamps on two threads, not a span: `waited()`.
@@ -71,13 +75,14 @@ PHASES = ("stage_host", "h2d", "dispatch_submit", "device_compute",
           "ring_wait", "d2h_drain", "demux", "sink")
 
 # span name -> the scrape phase its self time feeds.  `stage_host` is the
-# sum of three spans, which snapshot()/phase_report() list beneath it as
-# `parts`.  `send`, `compile` and `timer` feed no phase.
+# sum of four spans, which snapshot()/phase_report() list beneath it as
+# `parts`.  `send`, `compile`, `timer` and `state_init` feed no phase.
 SPAN_PHASE = {"stage": "stage_host", "route_keys": "stage_host",
+              "shard_group": "stage_host",
               "obs_feed": "stage_host", "h2d": "h2d",
               "dispatch": "dispatch_submit", "fetch": "d2h_drain",
               "demux": "demux", "sink": "sink"}
-STAGE_PARTS = ("stage", "route_keys", "obs_feed")
+STAGE_PARTS = ("stage", "route_keys", "shard_group", "obs_feed")
 SPAN_PREFIX = "siddhi:"
 
 
