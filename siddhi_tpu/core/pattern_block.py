@@ -286,14 +286,14 @@ def make_block_step(spec: PatternSpec, pexec: PatternExec, sel: SelectorExec,
             return ncarry, (comp_valid, comp_idx, comp_ts, caps_t)
 
         # ---- unpack state, chunk the block, scan ---------------------------
-        b32, b64, scalars = packed
+        b32, lo64, hi64, scalars = packed
         B = raw_ts.shape[0]
         csel = jnp.clip(sel_idx[0], 0, B - 1)                     # [E]
         cols = tuple(c[csel].astype(d)
                      for c, d in zip(raw_cols, schema.dtypes))
         ts = raw_ts[csel]
         valid = sel_idx[0] >= 0
-        st = packer.unpack(b32, b64, scalars)
+        st = packer.unpack(b32, lo64, hi64, scalars)
         E = ts.shape[0]
         W = min(CHUNK, E)
         C = (E + W - 1) // W
@@ -332,7 +332,7 @@ def make_block_step(spec: PatternSpec, pexec: PatternExec, sel: SelectorExec,
             start_ts=uq(fstart), entry_ts=uq(fentry),
             seed_on=uq(fseed_on), done=uq(fdone), dropped=fdropped,
             caps=ncapd)
-        nb32, nb64, nscal = packer.pack(nst)
+        nb32, nlo, nhi, nscal = packer.pack(nst)
 
         # ---- emission: order completions by arrival, run the selector ------
         comp_valid, comp_idx, comp_ts, caps_stack = comps    # [C,T] / nested
@@ -376,6 +376,6 @@ def make_block_step(spec: PatternSpec, pexec: PatternExec, sel: SelectorExec,
             n_dropped = jnp.zeros((), jnp.int64)
         out = (n_valid, n_dropped) + out
         wake = jnp.asarray(NO_WAKEUP, jnp.int64)
-        return (nb32, nb64, nscal), sel_state, out, wake
+        return (nb32, nlo, nhi, nscal), sel_state, out, wake
 
     return step

@@ -14,6 +14,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..query_api.definition import StreamDefinition
@@ -34,15 +35,39 @@ _FORCE_SCAN = False
 
 class StatePacker:
     """Pack a per-key state pytree (array leaves with leading K axis) into
-    two blobs: one i32 (i32/f32-bitcast/bool) and one i64, stored [W, K]
-    (key axis MINOR).
+    three arrays stored [W, K] (key axis MINOR): `b32`, one i32 blob
+    (i32 / f32-bitcast / bool leaves), and the i64 leaves as two SEPARATE
+    u32 planes, `lo64` (low words) and `hi64` (high words), rows in the
+    order the leaves come.  The packed state is
+    `(b32, lo64, hi64, scalars)`; `scalars` are the 0-d leaves.
 
-    Why two blobs: XLA:TPU scatter has a large per-op cost, roughly
+    Why blobs at all: XLA:TPU scatter has a large per-op cost, roughly
     independent of row width (an earlier remote-chip reading was ~7 ms for
     32k rows; not measured on the current chip).  The
     NFA state has ~24 leaf arrays; scattering each per batch dominated the
-    step.  Packing reduces the per-batch key-state update to 2 gathers + 2
-    scatters.
+    step.  Packing reduces the per-batch key-state update to one gather
+    and one scatter per array.
+
+    Why three arrays and not an i32 blob plus an i64 blob: the TPU has no
+    64-bit integers.  XLA's X64 rewrite turns every s64 array INSIDE a
+    program into a (low u32, high u32) pair, but an s64 array that is a
+    PARAMETER or a RESULT of the program is converted at the boundary,
+    all of it, on every call: `X64SplitLow` + `X64SplitHigh` of the
+    argument, `X64Combine` into the result.  The state is a donated
+    argument and a result of every step, so that cost went with the keys
+    RESIDENT, not the keys a send touches: 29.6 of the 76.7 ms of a
+    sharded step over `s64[40, 8388608]` a chip (PERF.md section 6, PR 26
+    trace), and a temporary the size of the blob.  As two u32 planes the
+    state crosses the boundary as it lies; 64-bit values exist on the
+    device only for the `[W64, Kb]` rows a step gathered or sliced
+    (`unpack` joins them, `pack` splits the result).  Nothing narrows:
+    the join and the split are bit-exact over the whole i64 range.
+
+    Why two planes and not one stacked `[2*W64, K]` / `[2, W64, K]` u32
+    array: compiled for v5e at 1,048,576 keys the stacked forms cost a
+    whole-blob layout copy per step (537 MB / 1.08 GB of temporaries)
+    where two planes cost none (ISSUE 27's compile table;
+    tests/test_state_planes.py repeats it).
 
     Why [W, K] and not [K, W]: with keys leading, XLA:TPU layout assignment
     picked a key-major {0,1} layout for the [K, W] blobs, so every per-key
@@ -51,6 +76,10 @@ class StatePacker:
     With keys minor, per-key access rides the
     tiled minor axis and batch key indices arrive sorted (keyslots group
     ascending), so gather/scatter granules are dense.
+
+    On the host and on disk (snapshots) the two planes are ONE int64
+    array `b64 [W64, K]`, as before the planes existed: `to_host` /
+    `from_host` convert at that boundary.
     """
 
     def __init__(self, example):
@@ -98,11 +127,17 @@ class StatePacker:
             jnp.zeros((0, K), jnp.int32)
         b64 = jnp.concatenate(parts64, axis=0) if parts64 else \
             jnp.zeros((0, K), jnp.int64)
-        return b32, b64, tuple(scal)
+        # both halves are in u32's range before the convert, so it is
+        # exact on every backend (no reliance on a wrapping narrow)
+        lo64 = (b64 & 0xFFFFFFFF).astype(jnp.uint32)
+        hi64 = lax.shift_right_logical(
+            b64, jnp.asarray(32, jnp.int64)).astype(jnp.uint32)
+        return b32, lo64, hi64, tuple(scal)
 
-    def unpack(self, b32, b64, scalars):
+    def unpack(self, b32, lo64, hi64, scalars):
         leaves = []
         K = b32.shape[1]
+        b64 = (hi64.astype(jnp.int64) << 32) | lo64.astype(jnp.int64)
         for kind, dtype, head, off, width in self.recs:
             if kind == "scalar":
                 leaves.append(scalars[off])
@@ -122,6 +157,39 @@ class StatePacker:
             leaves.append(leaf)
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
+    @staticmethod
+    def join_host(lo64, hi64) -> np.ndarray:
+        """Two u32 planes (or any same-shaped columns of them) -> the
+        int64 array the host and every snapshot hold."""
+        lo64 = np.asarray(lo64)
+        pair = np.empty(lo64.shape + (2,), "<u4")
+        pair[..., 0] = lo64
+        pair[..., 1] = np.asarray(hi64)
+        return pair.view("<i8")[..., 0]
+
+    @staticmethod
+    def split_host(b64):
+        """The host's int64 array -> (low words, high words), u32 each."""
+        pair = np.ascontiguousarray(b64, "<i8").view("<u4").reshape(
+            np.shape(b64) + (2,))
+        return (np.ascontiguousarray(pair[..., 0]),
+                np.ascontiguousarray(pair[..., 1]))
+
+    @classmethod
+    def to_host(cls, packed):
+        """Device packed state -> the host / on-disk form
+        `(b32, b64, scalars)`, numpy, `b64` int64."""
+        b32, lo64, hi64, scalars = packed
+        return (np.asarray(b32), cls.join_host(lo64, hi64),
+                tuple(np.asarray(s) for s in scalars))
+
+    @classmethod
+    def from_host(cls, host):
+        """The host form -> the packed state's arrays (numpy; the caller
+        places them)."""
+        b32, b64, scalars = host
+        return (np.asarray(b32),) + cls.split_host(b64) + (tuple(scalars),)
+
 
 @dataclasses.dataclass
 class PlannedPatternQuery:
@@ -134,10 +202,11 @@ class PlannedPatternQuery:
     output_event_type: str
     steps: Dict[str, Callable]          # stream_id -> jitted step
     timer_step: Optional[Callable]
-    # (K) -> ((b32, b64, scalars), sel_state): the one jitted init, placed
-    # by the plan's mesh (_init_program)
+    # (K) -> ((b32, lo64, hi64, scalars), sel_state): the one jitted init,
+    # placed by the plan's mesh (_init_program)
     init_state: Callable
-    # () -> ([W32, 1], [W64, 1], scalars): one fresh key's packed column
+    # () -> ([W32, 1], [W64, 1], [W64, 1], scalars): one fresh key's
+    # packed column
     init_columns: Callable
     key_capacity: int
     slots: int
@@ -281,7 +350,7 @@ def plan_pattern_query(
             # holds batch indices (-1 = padding).  The [Kb,E] gather happens
             # here on device (~60us) so the host ships ~40% fewer bytes and
             # never copies event payloads.
-            b32, b64, scalars = packed
+            *arrays, scalars = packed      # b32, lo64, hi64: each [W, K]
             B = raw_ts.shape[0]
             csel = jnp.clip(sel_idx, 0, B - 1)
             cols = tuple(c[csel].astype(d)
@@ -296,15 +365,14 @@ def plan_pattern_query(
                 key_lo = jnp.asarray(key_ref, jnp.int32)
                 z = jnp.asarray(0, jnp.int32)
                 key_idx = key_lo + jnp.arange(Kb, dtype=jnp.int32)
-                sub32 = lax.dynamic_slice(b32, (z, key_lo),
-                                          (packer.w32, Kb))
-                sub64 = lax.dynamic_slice(b64, (z, key_lo),
-                                          (packer.w64, Kb))
+                subs = [lax.dynamic_slice(a, (z, key_lo), (a.shape[0], Kb))
+                        for a in arrays]
             else:
-                # generic path: 2 gathers riding the minor (key) axis
+                # generic path: gathers riding the minor (key) axis
                 key_idx = key_ref
-                sub32, sub64 = b32[:, key_idx], b64[:, key_idx]
-            sub = packer.unpack(sub32, sub64, scalars)
+                subs = [a[:, key_idx] for a in arrays]
+            # 64-bit values exist from here on, for these Kb keys only
+            sub = packer.unpack(*subs, scalars)
 
             def body(carry, xs):
                 st = carry
@@ -321,21 +389,19 @@ def plan_pattern_query(
             with jax.named_scope("nfa_advance"):
                 sub, emits = lax.scan(body, sub, xs)
 
-            nb32, nb64, nscal = packer.pack(sub)
+            *news, nscal = packer.pack(sub)
             if dense:
-                z = jnp.asarray(0, jnp.int32)
-                key_lo = jnp.asarray(key_ref, jnp.int32)
-                b32 = lax.dynamic_update_slice(b32, nb32, (z, key_lo))
-                b64 = lax.dynamic_update_slice(b64, nb64, (z, key_lo))
+                arrays = [lax.dynamic_update_slice(a, n, (z, key_lo))
+                          for a, n in zip(arrays, news)]
             else:
                 # out-of-bounds (padding) rows are dropped by scatter
-                b32 = b32.at[:, key_idx].set(nb32, mode="drop")
-                b64 = b64.at[:, key_idx].set(nb64, mode="drop")
+                arrays = [a.at[:, key_idx].set(n, mode="drop")
+                          for a, n in zip(arrays, news)]
 
             sel_state, out, wake = _emit_matches(
                 pexec, sel, spec, emits, ord_, sel_state, sub, now,
                 key_idx=key_idx, compact_rows=compact_rows)
-            return (b32, b64, nscal), sel_state, out, wake
+            return (*arrays, nscal), sel_state, out, wake
 
         return step
 
@@ -409,8 +475,7 @@ def plan_pattern_query(
         schema0 = schemas[any_sid]
 
         def tstep(packed, sel_state, now, in_tabs=()):
-            b32, b64, scalars = packed
-            pstate = packer.unpack(b32, b64, scalars)
+            pstate = packer.unpack(*packed)
             K = pstate.active.shape[-1]
             zero_cols = tuple(
                 jnp.full((K,), ev.default_value(t), dtype=d)
@@ -424,13 +489,14 @@ def plan_pattern_query(
             ord_ = jnp.zeros((K, 1), jnp.int64)
             sel_state, out, wake = _emit_matches(
                 pexec, sel, spec, emits, ord_, sel_state, st, now)
-            nb32, nb64, nscalars = packer.pack(st)
+            npacked = packer.pack(st)
             # per-key changed mask so the host marks ONLY mutated keys dirty
             # (a full-slab dirty would turn every incremental snapshot after
-            # a timer fire into a full one)
-            changed = jnp.any(nb32 != b32, axis=0) | \
-                jnp.any(nb64 != b64, axis=0)
-            return (nb32, nb64, nscalars), sel_state, out, wake, changed
+            # a timer fire into a full one); the planes compare as they lie
+            changed = functools.reduce(jnp.logical_or, (
+                jnp.any(n != o, axis=0)
+                for n, o in zip(npacked[:-1], packed[:-1])))
+            return npacked, sel_state, out, wake, changed
 
         timer_step = jit_step(tstep, owner=name, role="pattern_timer",
                               donate_argnums=(0, 1))
@@ -492,22 +558,23 @@ def _used_refs(query: Query, spec: PatternSpec) -> set:
 
 def _shard_specs(packer: "StatePacker", sel: SelectorExec):
     """(pattern-state spec, selector-state spec) for the sharded pattern
-    layouts — blobs are [W, K] with the key (shard) axis at axis 1;
-    selector slabs shard axis 0; scalars replicate.  Read from shapes
+    layouts — the i32 blob and the two 64-bit planes are [W, K] with the
+    key (shard) axis at axis 1; selector slabs shard axis 0; scalars
+    replicate.  Read from shapes
     alone (`eval_shape`): nothing is allocated to learn a layout."""
     from jax.sharding import PartitionSpec as P
 
     def leaf_spec(x):
         return P() if x.ndim == 0 else P("shard")
 
-    pspec = (P(None, "shard"), P(None, "shard"),
-             tuple(P() for _ in packer.scalars))
+    pspec = (P(None, "shard"),) * 3 + (tuple(P() for _ in packer.scalars),)
     sspec = jax.tree.map(leaf_spec, jax.eval_shape(sel.init_state))
     return pspec, sspec
 
 
 def _init_columns(packer: "StatePacker", pexec: PatternExec):
-    """([W32, 1], [W64, 1], scalars): the packed state of ONE fresh key —
+    """([W32, 1], [W64, 1], [W64, 1], scalars): the packed state of ONE
+    fresh key —
     what `_init_program` broadcasts along the key axis and what the
     partition purger writes back over a recycled key's column."""
     return packer.pack(pexec.init_state(1))
@@ -516,13 +583,14 @@ def _init_columns(packer: "StatePacker", pexec: PatternExec):
 def _init_program(packer: "StatePacker", pexec: PatternExec,
                   sel: SelectorExec, mesh):
     """The ONE state-init path, mesh or no mesh: `init_state(K)` ->
-    ((b32, b64, scalars), selector state) from one jitted program whose
+    ((b32, lo64, hi64, scalars), selector state) from one jitted program whose
     `out_shardings` are the NamedShardings of `_shard_specs` (none
     without a mesh: the default device).
 
     Every NFA leaf starts key-uniform (`PatternExec.init_state` fills
     each with one value — the purger's reset column rests on the same
-    fact), so a blob is its one-key column broadcast along the key axis:
+    fact), so a blob or plane is its one-key column broadcast along the
+    key axis:
     XLA writes each [W, K/n] share where it lives, in place.  No chip
     ever holds a per-leaf slab, a concatenated second copy or another
     chip's share — at 33,554,432 keys the whole state is 17.4 GB and no
@@ -533,10 +601,9 @@ def _init_program(packer: "StatePacker", pexec: PatternExec,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def pattern_init(K: int):
-        c32, c64, scalars = _init_columns(packer, pexec)
-        return ((jnp.broadcast_to(c32, (packer.w32, K)),
-                 jnp.broadcast_to(c64, (packer.w64, K)), scalars),
-                sel.init_state())
+        *cols, scalars = _init_columns(packer, pexec)
+        return ((*(jnp.broadcast_to(c, (c.shape[0], K)) for c in cols),
+                 scalars), sel.init_state())
 
     shardings = None
     if mesh is not None:
@@ -556,7 +623,7 @@ def _shard_local(body):
 
     def local(packed, sel_state, raw_cols, raw_ts, sel_idx, key_idx, now,
               in_tabs=()):
-        b32, b64, scalars = packed
+        *arrays, scalars = packed
         old_scalars = scalars
         # replicated scalar counters become device-varying inside; mark them
         scalars = tuple(lax.pcast(s, ("shard",), to="varying")
@@ -566,17 +633,17 @@ def _shard_local(body):
         raw_ts = lax.pcast(raw_ts, ("shard",), to="varying")
         in_tabs = jax.tree.map(
             lambda x: lax.pcast(x, ("shard",), to="varying"), in_tabs)
-        ps, ss, out, wake = body((b32, b64, scalars), sel_state, raw_cols,
+        ps, ss, out, wake = body((*arrays, scalars), sel_state, raw_cols,
                                  raw_ts, sel_idx, key_idx, now, in_tabs)
         out = (lax.psum(out[0], "shard"), lax.psum(out[1], "shard")) + out[2:]
-        nb32, nb64, nscal = ps
+        *narrays, nscal = ps
         # re-replicate scalar counters: old + psum(local delta)
         nscal = tuple(
             old + lax.psum(new - lax.pcast(old, ("shard",), to="varying"),
                            "shard")
             for old, new in zip(old_scalars, nscal))
         wake = pmin_i64(wake, "shard")
-        return (nb32, nb64, nscal), ss, out, wake
+        return (*narrays, nscal), ss, out, wake
 
     return local
 

@@ -35,6 +35,7 @@ from ..query_api.query import Partition, Query, SingleInputStream
 from . import event as ev
 from .executor import CompileError
 from .keyslots import SlotAllocator
+from .pattern_planner import StatePacker
 from .planner import PlannedQuery, plan_single_query
 from .window import NO_WAKEUP
 from .steputil import jit_step
@@ -147,6 +148,27 @@ def _rebucket_for(qr, old_layout, host_state):
         return host_state
     return _sharding.rebucket_state(host_state, old_layout, new_layout,
                                     qr.planned)
+
+
+def _host_state(qr):
+    """A query's state as a snapshot carries it: numpy leaves, and a
+    pattern's two 64-bit planes joined into the one int64 `b64` array
+    (StatePacker.to_host), so the host / on-disk format is the same
+    whatever the device layout."""
+    if isinstance(qr, PatternQueryRuntime):
+        packed, sel_state = qr.state
+        return (StatePacker.to_host(packed),
+                jax.tree.map(lambda x: np.asarray(x), sel_state))
+    return jax.tree.map(lambda x: np.asarray(x), qr.state)
+
+
+def _device_state(qr, host_state):
+    """Inverse of `_host_state`: a snapshot's (re-bucketed) host state
+    as device arrays in the runtime's own layout."""
+    if isinstance(qr, PatternQueryRuntime):
+        packed, sel_state = host_state
+        host_state = (StatePacker.from_host(packed), sel_state)
+    return jax.tree.map(lambda x: jax.numpy.asarray(x), host_state)
 
 
 def _allocator_of(qr):
@@ -2248,9 +2270,9 @@ class _PartitionPurger:
         for qr in runtimes:
             if isinstance(qr, PatternQueryRuntime):
                 qr._touch = self._make_touch(self._seen_shared)
-                b32i, b64i, _ = qr.planned.init_columns()
-                self._init_cols[id(qr)] = (jax.numpy.asarray(b32i),
-                                           jax.numpy.asarray(b64i))
+                self._init_cols[id(qr)] = tuple(
+                    jax.numpy.asarray(c)
+                    for c in qr.planned.init_columns()[:3])
                 continue
             if not hasattr(qr, "_touch"):
                 # join runtimes have no liveness hook: purging their group
@@ -2343,17 +2365,16 @@ class _PartitionPurger:
         return masked_fill(arr, mask, init, key_axis)
 
     def _reset_pattern_keys(self, qr, idx: np.ndarray) -> None:
-        (b32, b64, scalars), sel_state = qr.state
-        init32, init64 = self._init_cols[id(qr)]
+        (*arrays, scalars), sel_state = qr.state   # b32, lo64, hi64
         router = qr.shard_router
         if router is not None:
             # the sharded path routes allocator slot s to state column
             # router.state_row(s) (keys round-robin over devices,
             # _process_sharded) — the reset must hit the same columns
             idx = router.state_row(idx)
-        mask = self._key_mask(idx, b32.shape[1])
-        b32 = self._masked_fill(b32, mask, init32, key_axis=1)
-        b64 = self._masked_fill(b64, mask, init64, key_axis=1)
+        mask = self._key_mask(idx, arrays[0].shape[1])
+        arrays = [self._masked_fill(a, mask, init, key_axis=1)
+                  for a, init in zip(arrays, self._init_cols[id(qr)])]
         # selector accumulators (per-key sums etc.) key on the same shared
         # slots — same [K] axis, same mask: a recycled slot must NOT leak
         # the purged key's aggregates into whatever key comes next
@@ -2362,7 +2383,7 @@ class _PartitionPurger:
             a if s.slot_src is not None
             else self._masked_fill(a, mask, s.init)
             for a, s in zip(sel_state, specs))
-        qr.state = ((b32, b64, scalars), sel_state)
+        qr.state = ((*arrays, scalars), sel_state)
         if qr._dirty is not None:
             qr._dirty[idx] = True
 
@@ -4097,7 +4118,7 @@ class SiddhiAppRuntime:
         with self._quiesce():
             states = {}
             for name, qr in self.query_runtimes.items():
-                host_state = jax.tree.map(lambda x: np.asarray(x), qr.state)
+                host_state = _host_state(qr)
                 alloc = _allocator_of(qr)
                 alloc2 = getattr(qr.planned, "slot_allocator2", None)
                 jk = getattr(qr.planned, "join_key_allocator", None)
@@ -4160,12 +4181,16 @@ class SiddhiAppRuntime:
                 if dirty is not None and isinstance(qr.state, tuple) and \
                         len(qr.state) == 2 and isinstance(qr.state[0], tuple):
                     idx = np.nonzero(dirty)[0]
-                    b32, b64, scalars = qr.state[0]
+                    b32, lo64, hi64, scalars = qr.state[0]
                     deltas[name] = {
                         "kind": "keyed",
                         "slots": idx,
                         "b32": np.asarray(b32)[:, idx],
-                        "b64": np.asarray(b64)[:, idx],
+                        # the delta's format is the host's: one int64
+                        # array, joined from the planes' dirty columns
+                        "b64": StatePacker.join_host(
+                            np.asarray(lo64)[:, idx],
+                            np.asarray(hi64)[:, idx]),
                         "scalars": [np.asarray(s) for s in scalars],
                         "sel_state": jax.tree.map(
                             lambda x: np.asarray(x), qr.state[1]),
@@ -4180,8 +4205,7 @@ class SiddhiAppRuntime:
                     jk = getattr(qr.planned, "join_key_allocator", None)
                     deltas[name] = {
                         "kind": "full",
-                        "state": jax.tree.map(
-                            lambda x: np.asarray(x), qr.state),
+                        "state": _host_state(qr),
                         "slots": alloc.snapshot()
                         if alloc is not None else None,
                         "slots2": alloc2.snapshot()
@@ -4223,7 +4247,11 @@ class SiddhiAppRuntime:
                     continue
                 alloc = _allocator_of(qr)
                 if d["kind"] == "keyed":
-                    (b32, b64, scalars), _ = qr.state
+                    arrays = qr.state[0][:3]       # b32, lo64, hi64
+                    # the delta carries the host format: its int64
+                    # columns split into the planes' columns here
+                    cols = (np.asarray(d["b32"]),) + \
+                        StatePacker.split_host(d["b64"])
                     # incremental deltas index by state ROW: remap rows
                     # (and the full selector tree riding along) when the
                     # snapshot was cut under a different mesh size
@@ -4236,48 +4264,38 @@ class SiddhiAppRuntime:
                             d_slots, old_l, new_l)
                         sel_host = _sharding.rebucket_selector(
                             sel_host, old_l, new_l, qr.planned)
-                    sharded = len(getattr(
-                        b32, "sharding", None).device_set) > 1 \
-                        if getattr(b32, "sharding", None) is not None else \
-                        False
-                    if sharded:
+                    sharding = getattr(arrays[0], "sharding", None)
+                    if sharding is not None and \
+                            len(sharding.device_set) > 1:
                         # host-context scatters into sharded slabs drop
                         # remote-shard columns (core/shardsafe.py): go
                         # through a dense masked where instead
                         from .shardsafe import key_mask, masked_fill
-                        slots = d_slots
-                        K = b32.shape[1]
-                        mask = key_mask(slots, K)
-                        up32 = np.zeros(b32.shape, np.asarray(
-                            d["b32"]).dtype)
-                        up32[:, slots] = d["b32"]
-                        up64 = np.zeros(b64.shape, np.asarray(
-                            d["b64"]).dtype)
-                        up64[:, slots] = d["b64"]
-                        b32 = masked_fill(b32, mask,
-                                          jax.numpy.asarray(up32),
-                                          key_axis=1)
-                        b64 = masked_fill(b64, mask,
-                                          jax.numpy.asarray(up64),
-                                          key_axis=1)
+                        mask = key_mask(d_slots, arrays[0].shape[1])
+
+                        def put(arr, c):
+                            up = np.zeros(arr.shape, c.dtype)
+                            up[:, d_slots] = c
+                            return masked_fill(arr, mask,
+                                               jax.numpy.asarray(up),
+                                               key_axis=1)
                     else:
                         idx = jax.numpy.asarray(d_slots)
-                        b32 = b32.at[:, idx].set(
-                            jax.numpy.asarray(d["b32"]))
-                        b64 = b64.at[:, idx].set(
-                            jax.numpy.asarray(d["b64"]))
+
+                        def put(arr, c):
+                            return arr.at[:, idx].set(jax.numpy.asarray(c))
+                    arrays = tuple(put(a, c) for a, c in zip(arrays, cols))
                     scalars = tuple(jax.numpy.asarray(s)
                                     for s in d["scalars"])
                     sel_state = jax.tree.map(lambda x: jax.numpy.asarray(x),
                                              sel_host)
-                    qr.state = ((b32, b64, scalars), sel_state)
+                    qr.state = ((*arrays, scalars), sel_state)
                     if alloc is not None:
                         alloc.apply_journal(d["journal"])
                 else:
                     host_state = _rebucket_for(qr, d.get("layout"),
                                                d["state"])
-                    restored = jax.tree.map(
-                        lambda x: jax.numpy.asarray(x), host_state)
+                    restored = _device_state(qr, host_state)
                     qr.state = qr.place_state(restored) \
                         if hasattr(qr, "place_state") else restored
                     if d["slots"] is not None and alloc is not None:
@@ -4312,8 +4330,7 @@ class SiddhiAppRuntime:
                     continue
                 host_state = _rebucket_for(qr, data.get("layout"),
                                            data["state"])
-                restored = jax.tree.map(
-                    lambda x: jax.numpy.asarray(x), host_state)
+                restored = _device_state(qr, host_state)
                 qr.state = qr.place_state(restored) \
                     if hasattr(qr, "place_state") else restored
                 alloc = _allocator_of(qr)

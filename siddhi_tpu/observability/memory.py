@@ -53,7 +53,9 @@ def tree_nbytes(tree) -> int:
 def _kind_components(qr) -> Dict[str, int]:
     """Split a query runtime's state tuple into named components.  The
     state layouts are (window, selector) for planned single queries,
-    ((b32, b64, scalars), selector) for patterns, and the join's
+    ((b32, lo64, hi64, scalars), selector) for patterns (the NFA's i64
+    leaves as two u32 planes on the device: the same bytes as the one
+    int64 `b64` blob a host snapshot holds), and the join's
     (left window, right window, selector...) tuple; anything that doesn't
     match falls back to positional names so the total always adds up."""
     mg = getattr(qr, "_merged", None)

@@ -17,9 +17,14 @@ the slot->row order moves.
 
 Three state families carry a key-ordered axis:
 
-- **pattern** (partitioned NFA): packed blobs `b32/b64 [W, K]` (key axis
-  1) plus selector accumulator slabs `[K, ...]` (key axis 0 — sharded
-  patterns shard the selector with the same layout, see
+- **pattern** (partitioned NFA): the HOST form of the packed state,
+  `b32 [W32, K]` int32 and `b64 [W64, K]` int64 (key axis 1) — on the
+  device the 64-bit part is two u32 planes (`StatePacker`:
+  `(b32, lo64, hi64, scalars)`), joined into `b64` before a snapshot
+  reaches this module and split again after it (`StatePacker.to_host` /
+  `from_host`), so the on-disk format and this permutation know nothing
+  of the planes — plus selector accumulator slabs `[K, ...]` (key axis
+  0 — sharded patterns shard the selector with the same layout, see
   pattern_planner._shard_step's sspec);
 - **plain** (windowless partitioned group-by): selector slabs
   `[G, ...]` over the group-slot space;

@@ -126,7 +126,7 @@ def test_every_key_axis_leaf_is_a_quarter_per_device(config, model):
         assert len(shard_shapes) == 4
         for ss in shard_shapes:
             assert ss[axis] * 4 == K, (shape, ss)
-    assert keyed >= 2                        # the b32 and b64 blobs
+    assert keyed >= 3                        # b32 and the two 64-bit planes
 
 
 def test_explain_and_lint_see_the_annotation_mesh(config, model):

@@ -71,8 +71,7 @@ def test_compiled_out_shardings_are_the_shard_specs(mesh4):
             assert g.is_equivalent_to(w, leaf.ndim), (leaf.shape, g, w)
             assert leaf.sharding.is_equivalent_to(w, leaf.ndim)
         # the blobs really are split: a quarter of the key axis a device
-        b32, b64 = qr.state[0][0], qr.state[0][1]
-        for blob in (b32, b64):
+        for blob in qr.state[0][:3]:         # b32 and the two 64-bit planes
             assert {s.data.shape for s in blob.addressable_shards} == \
                 {(blob.shape[0], p.key_capacity // 4)}
     finally:
@@ -140,11 +139,12 @@ def test_init_equals_the_per_leaf_pack_it_replaced():
         p = qr.planned
         small = 64
         packer = pattern_planner.StatePacker(p.exec.init_state(1))
-        want32, want64, want_s = packer.pack(p.exec.init_state(small))
-        (b32, b64, scal), sel_state = p.init_state(small)
-        np.testing.assert_array_equal(np.asarray(b32), np.asarray(want32))
-        np.testing.assert_array_equal(np.asarray(b64), np.asarray(want64))
-        assert b32.dtype == want32.dtype and b64.dtype == want64.dtype
+        *want, want_s = packer.pack(p.exec.init_state(small))
+        (*got, scal), sel_state = p.init_state(small)
+        assert len(got) == len(want) == 3    # b32, low words, high words
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            assert g.dtype == w.dtype
         for a, b in zip(scal, want_s):
             assert np.asarray(a) == np.asarray(b) and a.dtype == b.dtype
         for a, b in zip(sel_state, p.selector_exec.init_state()):

@@ -7,6 +7,7 @@ that ride along (adopted uploads, pending timers, full window slabs)."""
 import contextlib
 import glob
 import os
+import time
 
 import jax
 import numpy as np
@@ -76,9 +77,12 @@ def profiler_session(tmp_path):
         jax.profiler.stop_trace()
 
 
-def capture(tmp_path, ql, n_sends=3, batch_cb=True):
+def capture(tmp_path, ql, n_sends=3, batch_cb=True, await_delivery=False):
     """Deploy, warm one send outside the capture, run `n_sends` inside it;
-    returns (events, rows delivered per send)."""
+    returns (events, rows delivered per send).  `await_delivery` waits
+    (bounded) for each send's rows before the next: under `@serve` that
+    gives the drainer thread its turn — on a loaded machine the final
+    flush() otherwise sometimes delivers all three itself."""
     m = SiddhiManager()
     try:
         rt = m.create_siddhi_app_runtime(ql)
@@ -93,6 +97,10 @@ def capture(tmp_path, ql, n_sends=3, batch_cb=True):
         with profiler_session(tmp_path) as events:
             for i in range(1, n_sends + 1):
                 send_pattern(rt, i)
+                deadline = time.monotonic() + 10.0
+                while await_delivery and len(rows) < i and \
+                        time.monotonic() < deadline:
+                    time.sleep(0.002)
             rt.flush()
     finally:
         m.shutdown()
@@ -155,7 +163,8 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
 # -- (b) the served path: delivery on the drainer thread ------------------------
 
 def test_serve_drainer_spans_carry_the_batch_of_their_send(tmp_path):
-    evs, rows = capture(tmp_path, pattern_ql(query_annotations="@serve"))
+    evs, rows = capture(tmp_path, pattern_ql(query_annotations="@serve"),
+                        await_delivery=True)
     assert rows == [N_KEYS] * 3
     sends = {s["batch"]: s for s in evs if s["name"] == "send"}
     assert len(sends) == 3
