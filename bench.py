@@ -920,8 +920,7 @@ def run_state_profile(quick=False, out_path=None):
 def run_join_compare(B=1 << 10, n_batches=8, out_path=None):
     """--mode join_compare: the windowed_join corpus shape with the
     equi-join fast path ON vs OFF (full [R,C] grid), plus the
-    cost_analysis bytes-accessed delta for the same two plans — the
-    ROADMAP item-2 A-B artifact (JOIN_r10.json)."""
+    cost_analysis bytes-accessed delta for the same two plans."""
     from siddhi_tpu.core import join as joinmod
 
     results = {}
@@ -1560,9 +1559,9 @@ def run_soak(seconds: int = 60, apps: int = 2, chaos: bool = False,
     (observability/timeseries.py, observability/slo.py).  With --chaos,
     utils/chaos.py kills each tenant's sink transport mid-run (publish
     attempts 40-60 fail) and the retry policy must redeliver with zero
-    loss.  With --out, writes the long-run artifact (the committed
-    SOAK_r07.json is one): per-second series, per-tenant accounting, p99 trajectories, and a
-    machine-checked SLO verdict.  Exit contract: rc 0 only when the
+    loss.  With --out, writes the long-run artifact: per-second series,
+    per-tenant accounting, p99 trajectories, and a machine-checked SLO
+    verdict.  Exit contract: rc 0 only when the
     final verdict is `ok` AND zero events were silently dropped."""
     import threading as _threading
 
@@ -1753,8 +1752,7 @@ def run_soak_noisy(seconds: int = 30, out_path=None,
     deliberately abusive tenant that (a) over-offers into a shed-policy
     rate limit, (b) recompile-storms by hot deploy/undeploy churn, and
     (c) attempts an over-ceiling deploy — while the admission layer
-    sheds, penalizes, and denies.  With --out, writes the artifact (the
-    committed SOAK_r08.json is one).
+    sheds, penalizes, and denies.  With --out, writes the artifact.
 
     Exit contract (rc 1 on violation):
       - victim co-run step p99 within 25% of its solo baseline

@@ -252,9 +252,9 @@ def run_flagship(smoke: Smoke, rec: dict, tag: str, serve: bool = False,
                  mesh=None):
     """Warm sweep, one checked sweep, then one checked GAPPY send.  At
     this size every block of a sweep is slot-contiguous and takes the
-    dense-slice step (dense_step_w); the gappy send — every other key of
+    dense-slice step (dense_step); the gappy send — every other key of
     the first two blocks — takes the other program real traffic runs, the
-    gather/scatter step (step_w).  Returns the checked rows (sweep then
+    gather/scatter step (step).  Returns the checked rows (sweep then
     gappy send, each sorted by key)."""
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.analysis.corpus import FLAGSHIP_QL_TEMPLATE

@@ -24,7 +24,7 @@ no trace (lowering happens in the consumer, under RECOMPILES.suppress).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -103,8 +103,8 @@ def _plain_specs(qr) -> Dict[str, Tuple]:
 
 def _pattern_specs(qr) -> Dict[str, Tuple]:
     """PatternQueryRuntime.process_staged argument layouts, one entry
-    per compiled step variant (plain / ts-delta wire / dense slice /
-    sharded / timer)."""
+    per compiled step variant (gather / dense slice / sharded / timer);
+    timestamps ride the wire of `core.event.encode_ts`."""
     from ..core.plan_facts import BATCH_CAPACITY
     p = qr.planned
     B = BATCH_CAPACITY
@@ -123,24 +123,15 @@ def _pattern_specs(qr) -> Dict[str, Tuple]:
     for sid in p.spec.stream_ids:
         schema = p.in_schemas[sid]
         raw_cols = _staging_cols(schema, B)
-        raw_ts = _sds((B,), np.int64)
-        out[f"step[{sid}]"] = (pstate, sel_state, raw_cols, raw_ts,
+        # the steady specialisation: (base i64 scalar, delta i32 [B])
+        ts = (_sds((), np.int64), _sds((B,), np.int32))
+        out[f"step[{sid}]"] = (pstate, sel_state, raw_cols, *ts,
                                sel, key_idx, now, in_tabs)
-        if p.steps_w is not None and sid in p.steps_w:
-            # ts-delta wire twin: (base scalar i64, delta i32 column)
-            out[f"step_w[{sid}]"] = (
-                pstate, sel_state, raw_cols, _sds((), np.int64),
-                _sds((B,), np.int32), sel, key_idx, now, in_tabs)
         if p.dense_steps is not None and sid in p.dense_steps:
             # contiguous-slot fast path takes a scalar key_lo
             out[f"dense_step[{sid}]"] = (
-                pstate, sel_state, raw_cols, raw_ts, sel,
+                pstate, sel_state, raw_cols, *ts, sel,
                 _sds((), np.int32), now, in_tabs)
-        if p.dense_steps_w is not None and sid in p.dense_steps_w:
-            out[f"dense_step_w[{sid}]"] = (
-                pstate, sel_state, raw_cols, _sds((), np.int64),
-                _sds((B,), np.int32), sel, _sds((), np.int32), now,
-                in_tabs)
     if p.timer_step is not None:
         out["timer_step"] = (pstate, sel_state, now, in_tabs)
     return out
@@ -226,44 +217,13 @@ def spec_for_role(qr, kind: str, role: str) -> Optional[Tuple]:
 
 def primary_roles(qr, kind: str) -> List[str]:
     """The steady-state hot-path program per batch: what ONE dispatch
-    of real traffic runs (ts-delta wire twin when it exists — that is
-    what steady traffic traces), summed across pattern streams / join
-    sides by the auditor's totals."""
+    of real traffic runs, summed across pattern streams / join sides by
+    the auditor's totals."""
     p = qr.planned
     if kind == "pattern":
-        roles = []
-        for sid in p.spec.stream_ids:
-            if p.steps_w is not None and sid in p.steps_w:
-                roles.append(f"step_w[{sid}]")
-            else:
-                roles.append(f"step[{sid}]")
-        return roles
+        return [f"step[{sid}]" for sid in p.spec.stream_ids]
     if kind == "join":
         return [r for r, s in (("step[left]", p.step_left),
                                ("step[right]", p.step_right))
                 if s is not None]
     return ["step"]
-
-
-def step_for_role(qr, kind: str, role: str) -> Optional[Any]:
-    """The jitted fn a role names (same mapping _steps_of renders)."""
-    p = qr.planned
-    if role == "step" and kind not in ("pattern",):
-        return getattr(p, "step", None)
-    if role == "timer_step":
-        return getattr(p, "timer_step", None)
-    if role == "step[left]":
-        return getattr(p, "step_left", None)
-    if role == "step[right]":
-        return getattr(p, "step_right", None)
-    if "[" in role and role.endswith("]"):
-        base, sid = role[:-1].split("[", 1)
-        d = {"step": getattr(p, "steps", None),
-             "step_w": getattr(p, "steps_w", None),
-             "dense_step": getattr(p, "dense_steps", None),
-             "dense_step_w": getattr(p, "dense_steps_w", None),
-             "shard_fused_step": getattr(p, "shard_fused_steps", None),
-             }.get(base)
-        if isinstance(d, dict):
-            return d.get(sid)
-    return None

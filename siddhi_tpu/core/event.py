@@ -384,6 +384,35 @@ class StagedBatch:
                           jnp.asarray(self.valid), cols)
 
 
+def encode_ts(ts: np.ndarray, n: int) -> Tuple[np.int64, np.ndarray]:
+    """The timestamp wire of the sequential pattern programs: a staged
+    `i64 [B]` column (`n` real rows first, padding after) ->
+    `(base, delta)`, `base` the first real row's timestamp and `delta
+    [B]` each real row's distance from it.  `delta` is int32 whenever the
+    real rows' distances fit — every batch that spans under 2**31 ms
+    (24.8 days) — so the timestamp plane's upload halves; int64 when they
+    do not, and the same jitted step then specialises on that dtype.
+    Padding rows (and an empty batch) encode as zeros: they decode to
+    `base`, and no valid selection reads them."""
+    if not n:
+        return np.int64(0), np.zeros(ts.shape, np.int32)
+    real = ts[:n]
+    base = real[0]
+    fits = int(real.max()) - int(base) < 2**31 and \
+        int(real.min()) - int(base) >= -(2**31)
+    # no i64 temporary: one buffered pass writes the narrowed distances
+    delta = np.empty(ts.shape, np.int32 if fits else np.int64)
+    np.subtract(real, base, out=delta[:n], casting="unsafe")
+    delta[n:] = 0
+    return base, delta
+
+
+def decode_ts(base, delta):
+    """`encode_ts`'s pair -> the `i64 [B]` timestamp column, on the
+    device, inside the step that reads it."""
+    return jnp.asarray(base, jnp.int64) + delta.astype(jnp.int64)
+
+
 class StackedBatch:
     """K same-capacity staged micro-batches stacked into [K, B] host
     arrays for ONE fused device dispatch (core/fusion.py): one

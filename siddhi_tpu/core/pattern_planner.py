@@ -200,7 +200,10 @@ class PlannedPatternQuery:
     out_schema: ev.Schema
     output_target: str
     output_event_type: str
-    steps: Dict[str, Callable]          # stream_id -> jitted step
+    # stream_id -> jitted step.  Every sequential step (these, the dense
+    # ones, the sharded one) takes its timestamps on the wire of
+    # core/event.py: (base i64 scalar, delta i32 [B]), see _jit_sequential
+    steps: Dict[str, Callable]
     timer_step: Optional[Callable]
     # (K) -> ((b32, lo64, hi64, scalars), sel_state): the one jitted init,
     # placed by the plan's mesh (_init_program)
@@ -218,10 +221,6 @@ class PlannedPatternQuery:
     # gather/scatter on TPU is row-serialized (~0.3us/row; 131k-key batch =
     # ~90ms), a contiguous slice is DMA-speed
     dense_steps: Optional[Dict[str, Callable]] = None
-    # ts-delta wire variants (base i64 scalar + delta i32 [B] instead of a
-    # fresh i64 [B] ts column); None when unavailable (sharded path)
-    steps_w: Optional[Dict[str, Callable]] = None
-    dense_steps_w: Optional[Dict[str, Callable]] = None
     # False when the per-key emission cap is an implicit default: overflow
     # then raises instead of dropping rows (@emit(rows=N) opts into capping)
     emit_explicit: bool = True
@@ -266,7 +265,6 @@ class PlannedPatternQuery:
             "partitioned": bool(self.partition_positions),
             "out_columns": list(self.out_schema.names),
             # per-batch step specializations the runtime can dispatch to
-            "ts_delta_wire": self.steps_w is not None,
             "dense_slot_fast_path": self.dense_steps is not None,
             "timer_step": self.timer_step is not None,
         }
@@ -407,23 +405,7 @@ def plan_pattern_query(
 
     raw_steps = {sid: make_step(sid) for sid in spec.stream_ids}
 
-    def wire_ts(body):
-        """ts-delta wire variant: the host ships (base i64 scalar,
-        delta i32 [B]) instead of a fresh 8-byte-per-event timestamp
-        column — the timestamp plane's H2D bytes halve (every send is
-        a real transfer of the whole batch).  The i64 column
-        reconstructs on device inside the same jit."""
-        def wrapped(packed, sel_state, raw_cols, ts_base, ts_delta,
-                    sel_idx, key_ref, now, in_tabs=()):
-            raw_ts = jnp.asarray(ts_base, jnp.int64) + \
-                ts_delta.astype(jnp.int64)
-            return body(packed, sel_state, raw_cols, raw_ts, sel_idx,
-                        key_ref, now, in_tabs)
-        return wrapped
-
     dense_steps = None
-    steps_w = None
-    dense_steps_w = None
     step_bodies = None
     shard_fused_steps = None
     if mesh is None and partition_positions is None and \
@@ -431,34 +413,18 @@ def plan_pattern_query(
         # single-key simple chain: the sequential E-tick scan degrades to
         # interpreter speed (round-4: 776 ev/s); the block path advances a
         # whole chunk in S-1 vectorized stages — see pattern_block.py
-        block_bodies = {sid: make_block_step(
+        step_bodies = {sid: make_block_step(
             spec, pexec, sel, schemas, packer, sid, compact_rows)
             for sid in spec.stream_ids}
-        steps = {sid: jit_step(b, owner=name, role="pattern_block",
-                               donate_argnums=(0, 1))
-                 for sid, b in block_bodies.items()}
-        steps_w = {sid: jit_step(wire_ts(b), owner=name,
-                                 role="pattern_block_w",
-                                 donate_argnums=(0, 1))
-                   for sid, b in block_bodies.items()}
-        step_bodies = block_bodies
+        steps = {sid: _jit_sequential(b, name, "pattern_block")
+                 for sid, b in step_bodies.items()}
     elif mesh is None:
-        steps = {sid: jit_step(body, owner=name, role="pattern_step",
-                               donate_argnums=(0, 1))
-                 for sid, body in raw_steps.items()}
-        steps_w = {sid: jit_step(wire_ts(body), owner=name,
-                                 role="pattern_step_w",
-                                 donate_argnums=(0, 1))
-                   for sid, body in raw_steps.items()}
-        dense_steps = {sid: jit_step(make_step(sid, dense=True), owner=name,
-                                     role="pattern_dense",
-                                     donate_argnums=(0, 1))
-                       for sid in spec.stream_ids}
-        dense_steps_w = {sid: jit_step(wire_ts(make_step(sid, dense=True)),
-                                       owner=name, role="pattern_dense_w",
-                                       donate_argnums=(0, 1))
-                         for sid in spec.stream_ids}
         step_bodies = raw_steps
+        steps = {sid: _jit_sequential(body, name, "pattern_step")
+                 for sid, body in raw_steps.items()}
+        dense_steps = {sid: _jit_sequential(make_step(sid, dense=True),
+                                            name, "pattern_dense")
+                       for sid in spec.stream_ids}
     else:
         steps = {sid: _shard_step(body, mesh, packer, sel, owner=name)
                  for sid, body in raw_steps.items()}
@@ -514,7 +480,6 @@ def plan_pattern_query(
                            query.output_stream.output_event_type
                            else "CURRENT_EVENTS"),
         steps=steps, dense_steps=dense_steps,
-        steps_w=steps_w, dense_steps_w=dense_steps_w,
         timer_step=timer_step, init_state=init_state,
         init_columns=init_columns,
         key_capacity=key_capacity, slots=slots,
@@ -524,6 +489,23 @@ def plan_pattern_query(
         selector_exec=sel, emits_uuid=pexec.scope.uses_uuid,
         compact_rows=compact_rows, step_bodies=step_bodies,
         shard_fused_steps=shard_fused_steps)
+
+
+def _jit_sequential(body, owner, role):
+    """The jitted form of a sequential step body, one-chip or
+    shard_map'd: where the body takes `raw_ts`, an `i64 [B]` column, the
+    program takes `(ts_base, ts_delta)`, the timestamp wire the host ships
+    (`ev.encode_ts`), and decodes it here, once, inside the same jit.
+    `ts_delta` is int32 on every batch spanning under 2**31 ms; a wider
+    batch sends int64 and this same callable specialises on it (one
+    compile, counted under `owner` like any other).  The bodies keep
+    `raw_ts`: @fuse scans them over a stacked `i64 [K, B]` (fusion.py)."""
+    def step(packed, sel_state, raw_cols, ts_base, ts_delta, sel_idx,
+             key_ref, now, in_tabs=()):
+        return body(packed, sel_state, raw_cols,
+                    ev.decode_ts(ts_base, ts_delta), sel_idx, key_ref, now,
+                    in_tabs)
+    return jit_step(step, owner=owner, role=role, donate_argnums=(0, 1))
 
 
 def _first_schema(spec: PatternSpec, schemas) -> ev.Schema:
@@ -671,8 +653,7 @@ def _shard_step(body, mesh, packer: "StatePacker", sel: SelectorExec,
         _shard_local(body), mesh=mesh,
         in_specs=(pspec, sspec, rspec, rspec, bspec, bspec, P(), P()),
         out_specs=(pspec, sspec, (P(), P(), bspec, bspec, bspec, bspec), P()))
-    return jit_step(sharded, owner=owner, role="pattern_step_sharded",
-                    donate_argnums=(0, 1))
+    return _jit_sequential(sharded, owner, "pattern_step_sharded")
 
 
 def _shard_fused_step(body, mesh, packer: "StatePacker",

@@ -701,33 +701,11 @@ class PatternQueryRuntime(_MeshResolved):
                            bytes=_phases.nbytes(*staged.cols)):
             raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
         dense = False
-        key_idx_np = sel_np = delta32 = base = None
+        key_idx_np = sel_np = None
         with _phases.phase(st, self.name, "route_keys") as sp:
-            # ts-delta wire: ship (base scalar, i32 delta) instead of a
-            # fresh i64 column when the batch's span fits i32 (PERF.md
-            # lever 1); falls back to the plain i64 step otherwise
-            if p.steps_w is not None and staged.n:
-                # fit-check over the REAL rows only: a partial bucket's
-                # zero padding vs an epoch base would always fail it.
-                # Padding rows (valid=False) reconstruct to `base` on
-                # device — their values are never read through a valid
-                # selection.
-                tsn = staged.ts[:staged.n]
-                base = tsn[0]
-                dmax = int(tsn.max()) - int(base)
-                dmin = int(tsn.min()) - int(base)
-                if dmax < 2**31 and dmin >= -(2**31):
-                    delta32 = np.zeros(staged.ts.shape, np.int32)
-                    delta32[:staged.n] = tsn - base
+            ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
             if p.partition_positions:
-                kf = (p.partition_key_fns or {}).get(stream_id)
-                if kf is not None:
-                    key_cols, kvalid = kf(staged)
-                    valid = staged.valid & kvalid
-                else:
-                    pos = p.partition_positions[stream_id]
-                    key_cols = [staged.cols[i] for i in pos]
-                    valid = staged.valid
+                key_cols, valid = self._partition_keys(stream_id, staged)
                 key_idx_np, sel_np, hit = self._grouped_slots(
                     key_cols, valid, p)
                 Kb = key_idx_np.shape[0]
@@ -763,14 +741,11 @@ class PatternQueryRuntime(_MeshResolved):
                         # the dense step also time-ticks slots beyond nuniq
                         self._dirty[int(key_idx_np[0]):
                                     int(key_idx_np[0]) + Kb] = True
-        ts_np = staged.ts if delta32 is None else delta32
         with _phases.phase(st, self.name, "h2d",
-                           bytes=_phases.nbytes(ts_np, sel_np)):
-            if delta32 is None:
-                ts_args = (jax.numpy.asarray(staged.ts),)
-            else:
-                ts_args = (jax.numpy.asarray(base, jax.numpy.int64),
-                           jax.numpy.asarray(delta32))
+                           bytes=_phases.nbytes(ts_delta, sel_np)):
+            # the base rides the step call as the numpy scalar it is: an
+            # upload call of its own costs as much as the delta's
+            ts_d = (ts_base, jax.numpy.asarray(ts_delta))
             sel_d = jax.numpy.asarray(sel_np)
             if dense:
                 key_d = jax.numpy.asarray(int(key_idx_np[0]),
@@ -780,14 +755,28 @@ class PatternQueryRuntime(_MeshResolved):
             else:
                 key_d = jax.numpy.asarray(np.zeros((1,), np.int32))
             now_d = jax.numpy.asarray(now, jax.numpy.int64)
-        if dense:
-            steps = p.dense_steps if delta32 is None else p.dense_steps_w
-        else:
-            steps = p.steps if delta32 is None else p.steps_w
+        steps = p.dense_steps if dense else p.steps
+        self._step_and_emit(steps[stream_id], now, raw_cols, *ts_d, sel_d,
+                            key_d, now_d)
+
+    def _partition_keys(self, stream_id: str, staged: ev.StagedBatch):
+        """(key columns, row validity) of a partitioned batch: the
+        stream's range-partition function where it has one, else its key
+        columns by position."""
+        p = self.planned
+        kf = (p.partition_key_fns or {}).get(stream_id)
+        if kf is not None:
+            key_cols, kvalid = kf(staged)
+            return key_cols, staged.valid & kvalid
+        return ([staged.cols[i] for i in p.partition_positions[stream_id]],
+                staged.valid)
+
+    def _step_and_emit(self, step, now: int, *batch_args) -> None:
+        """Dispatch one sequential step on the state, rebind what it
+        returns (the state was donated) and hand its emission on."""
         pstate, sel_state = self.state
         pstate, sel_state, out, wake = _phases.dispatch(
-            self, steps[stream_id], pstate, sel_state, raw_cols, *ts_args,
-            sel_d, key_d, now_d, self._in_tabs())
+            self, step, pstate, sel_state, *batch_args, self._in_tabs())
         self.state = (pstate, sel_state)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
@@ -802,14 +791,7 @@ class PatternQueryRuntime(_MeshResolved):
         router = self.shard_router
         st = self.app.stats
         with _phases.phase(st, self.name, "route_keys"):
-            kf = (p.partition_key_fns or {}).get(stream_id)
-            if kf is not None:
-                key_cols, kvalid = kf(staged)
-                valid = staged.valid & kvalid
-            else:
-                pos = p.partition_positions[stream_id]
-                key_cols = [staged.cols[i] for i in pos]
-                valid = staged.valid
+            key_cols, valid = self._partition_keys(stream_id, staged)
             slots = self.slot_allocator.slots_for(key_cols, valid)
             # the [n, Kb, E] regroup is host staging work too, under its
             # own span: route_keys' self time is slot resolution alone
@@ -835,24 +817,25 @@ class PatternQueryRuntime(_MeshResolved):
                          now: int) -> None:
         """Multi-chip path: route each key to its shard (slot % n), build the
         stacked [n*Kb, E] layout, run the shard_map step."""
-        p = self.planned
+        st = self.app.stats
+        # the ts-wire build is host prep, booked where process_staged
+        # books it; _shard_prep's own route_keys span is slot resolution
+        # (fused dispatch shares it and ships a stacked i64 ts)
+        with _phases.phase(st, self.name, "route_keys"):
+            ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
         key_idx, sel = self._shard_prep(stream_id, staged, now)
         flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
-        with _phases.phase(self.app.stats, self.name, "h2d",
-                           bytes=_phases.nbytes(staged.ts, sel, key_idx,
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_phases.nbytes(ts_delta, sel, key_idx,
                                                 *staged.cols),
                            shards=self.shard_router.n_shards):
             raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
-            ts_d = jax.numpy.asarray(staged.ts)
+            ts_d = (ts_base, jax.numpy.asarray(ts_delta))
             sel_d = jax.numpy.asarray(flat(sel))
             key_d = jax.numpy.asarray(flat(key_idx))
             now_d = jax.numpy.asarray(now, jax.numpy.int64)
-        pstate, sel_state = self.state
-        pstate, sel_state, out, wake = _phases.dispatch(
-            self, p.steps[stream_id], pstate, sel_state, raw_cols, ts_d,
-            sel_d, key_d, now_d, self._in_tabs())
-        self.state = (pstate, sel_state)
-        _emit_output(self, out, now, wake=self._wake_arg(wake))
+        self._step_and_emit(self.planned.steps[stream_id], now, raw_cols,
+                            *ts_d, sel_d, key_d, now_d)
 
     def on_timer(self, now: int) -> None:
         p = self.planned

@@ -104,7 +104,7 @@ def jit_step(fn, owner=None, role=None, **jit_kwargs):
 
     `role` names the traced function, so the XLA module — and every
     device op of it in a profiler trace — is `jit_<role>`:
-    `pattern_dense_w`, `plain_step`, `join_left`, ...  Roles, not query
+    `pattern_dense`, `plain_step`, `join_left`, ...  Roles, not query
     names: a bounded set, the same across apps, so a trace reduction
     finds a step after a refactor."""
     from ..observability.recompile import RECOMPILES

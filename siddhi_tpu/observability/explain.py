@@ -267,15 +267,11 @@ def _steps_of(qr, kind: str) -> List[Tuple[str, Any]]:
     p = qr.planned
     steps: List[Tuple[str, Any]] = []
     if kind == "pattern":
-        # each variant is its own XLA program: the plain per-stream step,
-        # the ts-delta wire twin (steps_w — what steady-state traffic
-        # actually runs), and the contiguous-slot dense specialization
-        for role, d in (("step", p.steps), ("step_w", p.steps_w),
-                        ("dense_step", getattr(p, "dense_steps", None)),
-                        ("dense_step_w",
-                         getattr(p, "dense_steps_w", None)),
-                        ("shard_fused_step",
-                         getattr(p, "shard_fused_steps", None))):
+        # each variant is its own XLA program: the per-stream step
+        # (gather/scatter, block or sharded), the contiguous-slot dense
+        # specialization, and the mesh's @fuse step
+        for role, d in (("step", p.steps), ("dense_step", p.dense_steps),
+                        ("shard_fused_step", p.shard_fused_steps)):
             for sid, fn in (d or {}).items():
                 steps.append((f"{role}[{sid}]", fn))
         if p.timer_step is not None:

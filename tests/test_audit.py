@@ -164,8 +164,9 @@ def test_fingerprint_shape(tiny_current):
     fp = tiny_current["samples/pattern"]["queries"]["seq"]
     assert fp["kind"] == "pattern"
     assert fp["dispatch_programs"] == 1
-    # plain step + ts-delta wire twin at minimum
-    assert fp["recompile_signature_arity"] >= 2
+    # one program a role: a non-partitioned chain has the block step alone
+    assert fp["recompile_signature_arity"] == 1
+    assert set(fp["steps"]) == {"step[P]"}
     assert fp["emission"] == {"cap_rows": 64, "cap_explicit": True}
     assert fp["fusion"]["eligible"] is True
     assert fp["state"]["total_bytes"] > 0

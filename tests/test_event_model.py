@@ -100,3 +100,39 @@ def test_staged_batch_padding():
     cap = ev.bucket_size(3)
     assert staged.valid.shape[0] == cap
     assert staged.valid[:3].all() and not staged.valid[3:].any()
+
+
+T0 = 1_760_000_000_000      # epoch milliseconds
+TS_WIRE = {
+    # name: (real rows' timestamps, the delta's dtype)
+    "same_ms": ([T0] * 5, np.int32),
+    "ascending": ([T0, T0 + 1, T0 + 7, T0 + 60_000], np.int32),
+    "negative_deltas": ([T0, T0 - 3, T0 + 2, T0 - 86_400_000], np.int32),
+    "full_bucket": (list(T0 + np.arange(8)), np.int32),
+    "span_2_31_minus_1": ([T0, T0 + 2**31 - 1], np.int32),
+    "span_2_31": ([T0, T0 + 2**31], np.int64),
+    "back_2_31": ([T0, T0 - 2**31], np.int32),
+    "back_2_31_plus_1": ([T0, T0 - 2**31 - 1], np.int64),
+    "epoch_zero_and_now": ([0, T0], np.int64),
+    "empty": ([], np.int32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TS_WIRE))
+def test_ts_wire_round_trip(name):
+    """`encode_ts` -> `decode_ts` is the staged column on every real row,
+    padding decodes to the base, and the delta narrows exactly when every
+    real row's distance from the first fits int32."""
+    real, dtype = TS_WIRE[name]
+    n = len(real)
+    ts = np.zeros(ev.bucket_size(max(n, 1)), np.int64)   # zero padding
+    ts[:n] = real
+    base, delta = ev.encode_ts(ts, n)
+    assert delta.dtype == dtype and delta.shape == ts.shape
+    assert np.asarray(base).dtype == np.int64 and np.ndim(base) == 0
+    assert not delta[n:].any()
+    back = np.asarray(ev.decode_ts(base, delta))
+    assert back.dtype == np.int64
+    np.testing.assert_array_equal(back[:n], ts[:n])
+    np.testing.assert_array_equal(back[n:], int(base))
+    assert int(base) == (real[0] if n else 0)

@@ -133,7 +133,9 @@ def test_explain_pattern_query(manager):
     assert rep["emission"]["cap_rows"] is None
     assert rep["plan"]["nfa_states"] >= 2
     assert rep["plan"]["partitioned"] is False
-    assert rep["plan"]["ts_delta_wire"] is True
+    # one program a role: the (block) step that ran, no twin beside it
+    assert set(rep["steps"]) == {"step[S]"}
+    assert rep["steps"]["step[S]"]["available"]
 
 
 def test_explain_fusion_exclusion_reason(manager):

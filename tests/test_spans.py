@@ -131,7 +131,7 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
         assert {e["q"] for e in inside if e["name"] != "stage"} == {"q"}
         by = {n: [e for e in inside if e["name"] == n] for n in taken}
         assert len(by["dispatch"]) == 1
-        assert by["dispatch"][0]["step"] == "pattern_dense_w"
+        assert by["dispatch"][0]["step"] == "pattern_dense"
         assert by["route_keys"][0]["keys"] == N_KEYS
         assert by["route_keys"][0]["memo_hit"] == 1
         assert by["obs_feed"][0]["keys"] == N_KEYS
@@ -370,7 +370,7 @@ PLANNER_APPS = {
         @capacity(keys='64', slots='4') @info(name='q')
         from every e1=S[v == 1.0] -> e2=S[v == 2.0]
         select e1.k as k insert into Out; end;""",
-                {"pattern_step_w", "pattern_dense_w"}),
+                {"pattern_step", "pattern_dense"}),
     "pattern_timer": ("""define stream S (k long, v float);
         partition with (k of S) begin
         @capacity(keys='64', slots='4') @info(name='q')
@@ -378,7 +378,7 @@ PLANNER_APPS = {
         select e1.k as k insert into Out; end;""", {"pattern_timer"}),
     "block": ("""define stream S (k long, v float);
         @info(name='q') from every e1=S[v == 1.0] -> e2=S[v == 2.0]
-        select e1.k as k insert into Out;""", {"pattern_block_w"}),
+        select e1.k as k insert into Out;""", {"pattern_block"}),
     "fused": ("""define stream S (k long, v float);
         @fuse(batches='2') @info(name='q')
         from S[v > 0.0] select k, v insert into Out;""", {"fused_plain"}),
@@ -458,10 +458,10 @@ def test_named_scopes_leave_the_compiled_pattern_step_as_it_was(manager,
         return out
 
     with_scopes, without = costs(True), costs(False)
-    assert set(with_scopes) == set(without) == {"pattern_dense_w"}
-    flops, nbytes, named, _ = with_scopes["pattern_dense_w"]
-    assert named and not without["pattern_dense_w"][2]
-    assert (flops, nbytes) == without["pattern_dense_w"][:2]
+    assert set(with_scopes) == set(without) == {"pattern_dense"}
+    flops, nbytes, named, _ = with_scopes["pattern_dense"]
+    assert named and not without["pattern_dense"][2]
+    assert (flops, nbytes) == without["pattern_dense"][:2]
 
 
 # -- counters that count, and two that were missing -----------------------------

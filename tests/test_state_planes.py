@@ -3,7 +3,10 @@
 array: bit-exact pack/unpack, no 64-bit key-axis argument or result on any
 pattern step, the planes sharded as `_shard_specs` says, purge and
 incremental persistence on timestamps past 2**32, a parent-written
-snapshot restoring unchanged, and a no-chip compile guard for v5e:2x2."""
+snapshot restoring unchanged, and a no-chip compile guard for v5e:2x2.
+The event timestamps cross it on the wire of `core.event.encode_ts`: an
+i64 scalar and an i32 `[B]` on every sequential step, the same callable
+taking an i64 `[B]` delta for a batch that spans 2**31 ms or more."""
 import collections
 import functools
 import json
@@ -18,10 +21,12 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core import event as ev
 from siddhi_tpu.core import pattern_planner
 from siddhi_tpu.core.pattern_planner import StatePacker
 from siddhi_tpu.core.window import NO_WAKEUP
 from siddhi_tpu.observability.explain import compiled_steps
+from siddhi_tpu.observability.recompile import RECOMPILES
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T0 = 1_760_000_000_000          # epoch milliseconds: high word non-zero
@@ -166,10 +171,8 @@ def send(rt, batches, flush_each=True):
 
 
 def drive_partitioned(rt):
-    send(rt, [cols(range(8), 10.0, 1, T0),                  # dense_w
-              cols([1, 5, 20], 10.0, 1, T0 + 1),            # gather, ts wire
-              cols(range(8, 16), 10.0, 1, [T0] * 7 + [T0 + 2**33]),  # dense
-              cols([2, 40], 10.0, 1, [T0 + 2**33, T0])])    # gather, i64 ts
+    send(rt, [cols(range(8), 10.0, 1, T0),                  # dense
+              cols([1, 5, 20], 10.0, 1, T0 + 1)])           # gather
 
 
 def drive_absent(rt):
@@ -178,8 +181,8 @@ def drive_absent(rt):
 
 
 def drive_block(rt):
-    send(rt, [cols([0] * 4, 10.0, 1, T0 + np.arange(4)),           # ts wire
-              cols([0] * 4, 10.0, 2, [T0 + 9] * 3 + [T0 + 2**33])])  # i64 ts
+    send(rt, [cols([0] * 4, 10.0, 1, T0 + np.arange(4)),
+              cols([0] * 4, 10.0, 2, T0 + 9)])
 
 
 def drive_fused(rt):
@@ -191,11 +194,10 @@ def drive_fused(rt):
 
 VARIANTS = {
     # name: (app text, needs mesh, drive, roles that must have run)
-    "gather_dense_wire": (PART_QL % (KEYS, ""), False, drive_partitioned,
-                          {"step[T]", "step_w[T]", "dense_step[T]",
-                           "dense_step_w[T]"}),
+    "gather_dense": (PART_QL % (KEYS, ""), False, drive_partitioned,
+                     {"step[T]", "dense_step[T]"}),
     "timer": (ABSENT_QL, False, drive_absent, {"timer_step"}),
-    "block": (BLOCK_QL % "", False, drive_block, {"step[T]", "step_w[T]"}),
+    "block": (BLOCK_QL % "", False, drive_block, {"step[T]"}),
     # one-chip @fuse takes the non-partitioned (block) pattern only
     "fused_block": (BLOCK_QL % "@fuse(batches='4')", False, drive_fused,
                     {"fused_step[pattern]"}),
@@ -238,11 +240,119 @@ def test_no_64_bit_key_axis_array_crosses_a_jit_boundary(variant, request):
                     (kcap in x.shape if kcap > 1 else
                      x.ndim == 2 and x.shape[-1] == 1)]
             assert not wide, (role, wide)
+            if role.startswith(("step[", "dense_step[")):
+                # steady traffic's timestamps: (base, delta), no i64 [B]
+                ts = [(x.shape, str(x.dtype)) for x in specs[3:5]]
+                B = specs[2][0].shape[0]
+                assert ts == [((), "int64"), ((B,), "int32")], (role, ts)
         # ... and what the runtime holds between steps is the same
         b32, lo64, hi64, _scalars = qr.state[0]
         assert (b32.dtype, lo64.dtype, hi64.dtype) == \
             (jnp.int32, jnp.uint32, jnp.uint32)
         assert lo64.shape == hi64.shape and lo64.shape[1] == kcap
+    finally:
+        m.shutdown()
+
+
+# -- the ts wire's wide fall-back is the same callable ----------------------
+
+WIDE = T0 + 2**33          # 99 days after T0: no i32 delta reaches it
+GAPPY = [cols([1, 5, 20], 10.0, 1, T0),
+         cols([1, 5, 20], 99.0, 2, [T0 + 5, T0 + 7, WIDE]),
+         cols([1, 5, 20], 10.0, 1, WIDE + 10),
+         cols([1, 5, 20], 99.0, 2, WIDE + 20)]
+# name: (app text, needs mesh, the plan's step table, warm-up sends,
+#        [narrow, WIDE, narrow, narrow] of one shape)
+FALLBACK = {
+    # keys 0..7 bind slots 0..7; {1, 5, 20} then sit on slots 1, 5, 8
+    "gather": (PART_QL % (KEYS, ""), False, "steps",
+               [cols(range(8), 10.0, 1, T0 - 20_000)],
+               GAPPY),
+    "dense": (PART_QL % (KEYS, ""), False, "dense_steps", [],
+              [cols(range(8), 10.0, 1, T0),
+               cols(range(8), 99.0, 2, [T0 + 5] * 7 + [WIDE]),
+               cols(range(8), 10.0, 1, WIDE + 10),
+               cols(range(8), 99.0, 2, WIDE + 20)]),
+    "block": (BLOCK_QL % "", False, "steps", [],
+              [cols([0] * 4, 10.0, 1, T0 + np.arange(4)),
+               cols([0] * 4, 10.0, 2, [T0 + 9] * 3 + [WIDE]),
+               cols([0] * 4, 10.0, 1, WIDE + 10 + np.arange(4)),
+               cols([0] * 4, 10.0, 2, WIDE + 20)]),
+    "sharded": (PART_QL % (KEYS, ""), True, "steps", [],
+                GAPPY),
+}
+
+
+def row_by_row(batches):
+    """The same rows, one a send: every batch spans 0 ms."""
+    return [([x[i:i + 1] for x in c], ts[i:i + 1])
+            for c, ts in batches for i in range(len(ts))]
+
+
+def delta_dtype(fn):
+    """The ts delta's dtype in the specialisation `fn` traced last."""
+    return str(fn._siddhi_argspec["argspecs"][4].dtype)
+
+
+@pytest.mark.parametrize("path", sorted(FALLBACK))
+def test_wide_batch_goes_through_the_same_step(path, request):
+    text, sharded, table, warm, batches = FALLBACK[path]
+    mesh = request.getfixturevalue("mesh4") if sharded else None
+    m, rt, got, errors = deploy(text, mesh)
+    m2, rt2, want, errors2 = deploy(text, mesh)
+    try:
+        send(rt, warm + batches[:1])
+        qr = rt.query_runtimes["q"]
+        fn = getattr(qr.planned, table)["T"]
+        owner, role = fn._siddhi_owner, fn._siddhi_role
+        assert owner == "q" and delta_dtype(fn) == "int32"
+        compiles, cached = RECOMPILES.count(owner), fn._cache_size()
+        send(rt, batches[1:2])                 # spans 2**33 ms
+        # one more specialisation of THAT callable, under its owner
+        assert getattr(qr.planned, table)["T"] is fn
+        assert (fn._siddhi_owner, fn._siddhi_role) == (owner, role)
+        assert delta_dtype(fn) == "int64"
+        assert RECOMPILES.count(owner) == compiles + 1
+        assert fn._cache_size() == cached + 1
+        send(rt, batches[2:])                  # narrow again: nothing new
+        assert RECOMPILES.count(owner) == compiles + 1
+        assert fn._cache_size() == cached + 1
+        send(rt2, row_by_row(warm + batches))
+        assert not errors and not errors2
+        assert sorted(got) == sorted(want)
+        # block: 4 + 4 pairs; keyed: the WIDE e2 is past `within`, all match
+        # in the second round
+        n_keys = len(batches[0][1])
+        assert len(got) == (8 if path == "block" else 2 * n_keys - 1)
+        assert max(r[0] for r in got) == WIDE + 20
+    finally:
+        m.shutdown()
+        m2.shutdown()
+
+
+@pytest.mark.parametrize("path", ["gather", "block", "sharded"])
+def test_empty_batch_runs_the_wire_step(path, request):
+    """No rows: zeros on the wire, through the step a one-key batch
+    already compiled (a dense slice needs two keys: no empty batch takes
+    it)."""
+    text, sharded, table, warm, _ = FALLBACK[path]
+    mesh = request.getfixturevalue("mesh4") if sharded else None
+    m, rt, got, errors = deploy(text, mesh)
+    try:
+        one, ts = cols([2], 10.0, 1, T0)
+        send(rt, warm + [(one, ts)])
+        qr = rt.query_runtimes["q"]
+        fn = getattr(qr.planned, table)["T"]
+        compiles, cached = RECOMPILES.count("q"), fn._cache_size()
+        B = ev.bucket_size(1)
+        empty = ev.StagedBatch(
+            np.zeros(B, np.int64), np.zeros(B, np.int32),
+            np.zeros(B, np.bool_), [np.zeros(B, x.dtype) for x in one], 0)
+        qr.process_staged("T", empty, T0 + 1)
+        rt.flush()
+        assert not errors and not got
+        assert delta_dtype(fn) == "int32"
+        assert (RECOMPILES.count("q"), fn._cache_size()) == (compiles, cached)
     finally:
         m.shutdown()
 
@@ -480,15 +590,14 @@ def flagship_plan():
     m.shutdown()
 
 
-def step_args(p, kcap, B, Kb, E, place_state, rep, batch, dense, wire):
+def step_args(p, kcap, B, Kb, E, place_state, rep, batch, dense):
     def sds(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
     packed, sel = jax.tree.map(
         place_state, jax.eval_shape(lambda: p.init_state.__wrapped__(kcap)))
     raw_cols = (sds((B,), np.int64, rep), sds((B,), np.float32, rep),
                 sds((B,), np.int32, rep))
-    ts = (sds((), np.int64, rep), sds((B,), np.int32, rep)) if wire \
-        else (sds((B,), np.int64, rep),)
+    ts = (sds((), np.int64, rep), sds((B,), np.int32, rep))
     key_ref = sds((), np.int32, rep) if dense else sds((Kb,), np.int32, batch)
     return (packed, sel, raw_cols, *ts, sds((Kb, E), np.int32, batch),
             key_ref, sds((), np.int64, rep), ())
@@ -519,12 +628,9 @@ def test_the_guard_sees_a_whole_blob_pass():
 # [.., 131072] work temporaries (202,139,136 B, by hand, PR 27) would
 # hide a stray whole-plane copy (167,772,160 B)
 GUARD = {
-    "gather": dict(kcap=1048576, chips=1, B=8192, Kb=2048, dense=False,
-                   wire=True),
-    "dense": dict(kcap=1048576, chips=1, B=65536, Kb=16384, dense=True,
-                  wire=True),
-    "sharded": dict(kcap=33554432, chips=4, B=524288, Kb=32768, dense=False,
-                    wire=False),
+    "gather": dict(kcap=1048576, chips=1, B=8192, Kb=2048, dense=False),
+    "dense": dict(kcap=1048576, chips=1, B=65536, Kb=16384, dense=True),
+    "sharded": dict(kcap=33554432, chips=4, B=524288, Kb=32768, dense=False),
 }
 
 
@@ -538,8 +644,7 @@ def test_v5e_compile_has_no_whole_blob_x64_pass(step, topo, flagship_plan):
 
         def place(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
-        role = ("dense_steps_w" if g["dense"] else "steps_w")
-        fn = getattr(p, role)["TradeStream"]
+        fn = (p.dense_steps if g["dense"] else p.steps)["TradeStream"]
         n_rows = g["Kb"]
     else:
         mesh = Mesh(np.array(topo.devices), ("shard",))
@@ -560,7 +665,7 @@ def test_v5e_compile_has_no_whole_blob_x64_pass(step, topo, flagship_plan):
         fn = p.steps["TradeStream"]
         n_rows = g["Kb"] * g["chips"]
     args = step_args(p, g["kcap"], g["B"], n_rows, 4, place, rep, batch,
-                     g["dense"], g["wire"])
+                     g["dense"])
     compiled = fn.lower(*args).compile()
     per_chip = g["kcap"] // g["chips"]
     assert x64_boundary_ops(compiled.as_text(), per_chip) == []
