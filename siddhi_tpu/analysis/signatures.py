@@ -103,8 +103,8 @@ def _plain_specs(qr) -> Dict[str, Tuple]:
 
 def _pattern_specs(qr) -> Dict[str, Tuple]:
     """PatternQueryRuntime.process_staged argument layouts, one entry
-    per compiled step variant (gather / dense slice / sharded / timer);
-    timestamps ride the wire of `core.event.encode_ts`."""
+    per compiled step variant (scan / dense slice / block / sharded /
+    timer); timestamps ride the wire of `core.event.encode_ts`."""
     from ..core.plan_facts import BATCH_CAPACITY
     p = qr.planned
     B = BATCH_CAPACITY
@@ -119,12 +119,16 @@ def _pattern_specs(qr) -> Dict[str, Tuple]:
         G, E = 1, B
     key_idx = _sds((G,), np.int32)
     sel = _sds((G, E), np.int32)
+    # the one-chip scan programs take the columns and the ts delta in
+    # `sel`'s order, flat [G * E] (runtime._group_columns); the block
+    # step and the sharded one take the staged [B] batch
+    n = G * E if p.grouped_input else B
     out: Dict[str, Tuple] = {}
     for sid in p.spec.stream_ids:
         schema = p.in_schemas[sid]
-        raw_cols = _staging_cols(schema, B)
-        # the steady specialisation: (base i64 scalar, delta i32 [B])
-        ts = (_sds((), np.int64), _sds((B,), np.int32))
+        raw_cols = _staging_cols(schema, n)
+        # the steady specialisation: (base i64 scalar, delta i32 [n])
+        ts = (_sds((), np.int64), _sds((n,), np.int32))
         out[f"step[{sid}]"] = (pstate, sel_state, raw_cols, *ts,
                                sel, key_idx, now, in_tabs)
         if p.dense_steps is not None and sid in p.dense_steps:

@@ -202,7 +202,10 @@ class PlannedPatternQuery:
     output_event_type: str
     # stream_id -> jitted step.  Every sequential step (these, the dense
     # ones, the sharded one) takes its timestamps on the wire of
-    # core/event.py: (base i64 scalar, delta i32 [B]), see _jit_sequential
+    # core/event.py: (base i64 scalar, delta i32 [B]), see _jit_sequential.
+    # Off the mesh these and the dense ones take the columns and the delta
+    # GROUPED by the host, flat [Kb * E]; the block step and the sharded
+    # one take the staged [B] batch
     steps: Dict[str, Callable]
     timer_step: Optional[Callable]
     # (K) -> ((b32, lo64, hi64, scalars), sel_state): the one jitted init,
@@ -214,13 +217,19 @@ class PlannedPatternQuery:
     key_capacity: int
     slots: int
     partition_positions: Optional[Dict[str, List[int]]] = None
-    raw_steps: Optional[Dict[str, Callable]] = None   # unjitted bodies
+    # un-jitted scan bodies over the staged [B] batch (they gather the
+    # [Kb, E] layout on the device: _gathering)
+    raw_steps: Optional[Dict[str, Callable]] = None
     mesh: Any = None
     # contiguous-slot fast path: takes a scalar key_lo instead of key_idx and
     # reads/writes the state slab with dynamic slices — generic row
     # gather/scatter on TPU is row-serialized (~0.3us/row; 131k-key batch =
     # ~90ms), a contiguous slice is DMA-speed
     dense_steps: Optional[Dict[str, Callable]] = None
+    # `steps` / `dense_steps` are the one-chip scan programs: the host
+    # hands them the columns already in the per-key [Kb, E] order
+    # (runtime._group_columns) and they gather nothing
+    grouped_input: bool = False
     # False when the per-key emission cap is an implicit default: overflow
     # then raises instead of dropping rows (@emit(rows=N) opts into capping)
     emit_explicit: bool = True
@@ -342,20 +351,16 @@ def plan_pattern_query(
     def make_step(stream_id: str, dense: bool = False):
         schema = schemas[stream_id]
 
-        def step(packed, sel_state, raw_cols, raw_ts, sel_idx, key_ref, now,
+        def step(packed, sel_state, cols, ts, sel_idx, key_ref, now,
                  in_tabs=()):
-            # raw_cols/raw_ts are the UNGROUPED batch [B]; sel_idx [Kb,E]
-            # holds batch indices (-1 = padding).  The [Kb,E] gather happens
-            # here on device (~60us) so the host ships ~40% fewer bytes and
-            # never copies event payloads.
+            # cols/ts are the batch GROUPED per key, [Kb, E]: a key's
+            # events along E in arrival order.  sel_idx [Kb, E] holds each
+            # cell's batch index (-1 = padding; a padding cell carries
+            # row 0's values, which no valid selection reads).
             *arrays, scalars = packed      # b32, lo64, hi64: each [W, K]
-            B = raw_ts.shape[0]
-            csel = jnp.clip(sel_idx, 0, B - 1)
-            cols = tuple(c[csel].astype(d)
-                         for c, d in zip(raw_cols, schema.dtypes))
-            ts = raw_ts[csel]
+            cols = tuple(c.astype(d) for c, d in zip(cols, schema.dtypes))
             valid = sel_idx >= 0
-            ord_ = csel.astype(jnp.int64)
+            ord_ = jnp.maximum(sel_idx, 0).astype(jnp.int64)
             Kb = ts.shape[0]
             if dense:
                 # key_ref is a scalar key_lo: the batch's slots are the
@@ -403,11 +408,16 @@ def plan_pattern_query(
 
         return step
 
-    raw_steps = {sid: make_step(sid) for sid in spec.stream_ids}
+    # raw_steps gather on the device: what ships an UNGROUPED batch — the
+    # mesh steps, the @fuse stacks — runs these.  The one-chip sequential
+    # programs take the body itself: the host has grouped (_jit_sequential)
+    bodies = {sid: make_step(sid) for sid in spec.stream_ids}
+    raw_steps = {sid: _gathering(body) for sid, body in bodies.items()}
 
     dense_steps = None
     step_bodies = None
     shard_fused_steps = None
+    grouped_input = False
     if mesh is None and partition_positions is None and \
             block_eligible(spec) and not _FORCE_SCAN:
         # single-key simple chain: the sequential E-tick scan degrades to
@@ -420,10 +430,13 @@ def plan_pattern_query(
                  for sid, b in step_bodies.items()}
     elif mesh is None:
         step_bodies = raw_steps
-        steps = {sid: _jit_sequential(body, name, "pattern_step")
-                 for sid, body in raw_steps.items()}
+        grouped_input = True
+        steps = {sid: _jit_sequential(body, name, "pattern_step",
+                                      grouped=True)
+                 for sid, body in bodies.items()}
         dense_steps = {sid: _jit_sequential(make_step(sid, dense=True),
-                                            name, "pattern_dense")
+                                            name, "pattern_dense",
+                                            grouped=True)
                        for sid in spec.stream_ids}
     else:
         steps = {sid: _shard_step(body, mesh, packer, sel, owner=name)
@@ -485,13 +498,28 @@ def plan_pattern_query(
         key_capacity=key_capacity, slots=slots,
         partition_positions=partition_positions,
         partition_key_fns=partition_key_fns,
-        raw_steps=raw_steps, mesh=mesh, emit_explicit=emit_explicit,
+        raw_steps=raw_steps, mesh=mesh,
+        grouped_input=grouped_input, emit_explicit=emit_explicit,
         selector_exec=sel, emits_uuid=pexec.scope.uses_uuid,
         compact_rows=compact_rows, step_bodies=step_bodies,
         shard_fused_steps=shard_fused_steps)
 
 
-def _jit_sequential(body, owner, role):
+def _gathering(body):
+    """A sequential step body for callers that ship the UNGROUPED batch
+    `[B]`: the `[Kb, E]` gather by `sel_idx` happens here, on the device
+    (~7 ns an element and column on the v5e: 3.7 ms a column at 524,288).
+    The mesh steps keep it — a shard's grouped layout is half padding and
+    their host is the busier side — and so do the @fuse stacks."""
+    def step(packed, sel_state, raw_cols, raw_ts, sel_idx, key_ref, now,
+             in_tabs=()):
+        csel = jnp.clip(sel_idx, 0, raw_ts.shape[0] - 1)
+        return body(packed, sel_state, tuple(c[csel] for c in raw_cols),
+                    raw_ts[csel], sel_idx, key_ref, now, in_tabs)
+    return step
+
+
+def _jit_sequential(body, owner, role, grouped=False):
     """The jitted form of a sequential step body, one-chip or
     shard_map'd: where the body takes `raw_ts`, an `i64 [B]` column, the
     program takes `(ts_base, ts_delta)`, the timestamp wire the host ships
@@ -499,11 +527,20 @@ def _jit_sequential(body, owner, role):
     `ts_delta` is int32 on every batch spanning under 2**31 ms; a wider
     batch sends int64 and this same callable specialises on it (one
     compile, counted under `owner` like any other).  The bodies keep
-    `raw_ts`: @fuse scans them over a stacked `i64 [K, B]` (fusion.py)."""
+    `raw_ts`: @fuse scans them over a stacked `i64 [K, B]` (fusion.py).
+
+    `grouped`: the host has put the columns and the delta in the per-key
+    order already (`PatternQueryRuntime.process_staged`) and ships each
+    as the flat `[Kb * E]` buffer; the program reshapes to `sel_idx`'s
+    `[Kb, E]` and gathers nothing.  Flat, because a 2-D host array with a
+    minor dimension of E meets the TPU's 128-lane tiling at upload."""
     def step(packed, sel_state, raw_cols, ts_base, ts_delta, sel_idx,
              key_ref, now, in_tabs=()):
-        return body(packed, sel_state, raw_cols,
-                    ev.decode_ts(ts_base, ts_delta), sel_idx, key_ref, now,
+        ts = ev.decode_ts(ts_base, ts_delta)
+        if grouped:
+            raw_cols = tuple(c.reshape(sel_idx.shape) for c in raw_cols)
+            ts = ts.reshape(sel_idx.shape)
+        return body(packed, sel_state, raw_cols, ts, sel_idx, key_ref, now,
                     in_tabs)
     return jit_step(step, owner=owner, role=role, donate_argnums=(0, 1))
 

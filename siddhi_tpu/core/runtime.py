@@ -591,7 +591,7 @@ class PatternQueryRuntime(_MeshResolved):
         # with a larger emission cap (adaptive overflow growth)
         self._replan = None
         # steady-state block memo for _grouped_slots: (k0, n) ->
-        # (allocator version, key_idx, sel, keys copy)
+        # (allocator version, key_idx, sel, keys copy, sel is the identity)
         self._block_cache: Dict = {}
         # @fuse(batches=K): stack buffer for scan-fused dispatch, or None
         self._fuse = None
@@ -654,7 +654,11 @@ class PatternQueryRuntime(_MeshResolved):
         resolved (`version`) and the keys compare equal, the C pass and
         group fill are pure functions of the block and replay from cache
         (~30ms -> ~0.2ms per 131k-key send: 16% of flagship wall time).
-        Returns (key_idx, sel, memo hit)."""
+        Returns (key_idx, sel, memo hit, identity): `identity` says that
+        `sel` lists the batch's rows 0 .. B-1 in order, so the grouped
+        columns ARE the staged ones (_group_columns).  It is decided
+        here, where `sel` is made, and kept with the memo entry: a memo
+        hit pays no O(B) pass for it."""
         alloc = self.slot_allocator
         keys = key_cols[0] if len(key_cols) == 1 else None
         cacheable = (keys is not None and keys.dtype.kind in "iu" and
@@ -664,15 +668,16 @@ class PatternQueryRuntime(_MeshResolved):
             ent = self._block_cache.get(blk)
             if ent is not None and ent[0] == alloc.version and \
                     np.array_equal(keys, ent[3]):
-                return ent[1], ent[2], True
+                return ent[1], ent[2], True, ent[4]
         _, key_idx, sel = alloc.slots_and_group(key_cols, valid,
                                                 pad=p.key_capacity)
+        ident = _is_identity_sel(sel, valid.shape[0])
         if cacheable:
             if len(self._block_cache) >= 64:
                 self._block_cache.clear()
             self._block_cache[blk] = (alloc.version, key_idx, sel,
-                                      keys.copy())
-        return key_idx, sel, False
+                                      keys.copy(), ident)
+        return key_idx, sel, False, ident
 
     def process_staged(self, stream_id: str, staged: ev.StagedBatch,
                        now: int) -> None:
@@ -689,24 +694,21 @@ class PatternQueryRuntime(_MeshResolved):
             self._process_sharded(stream_id, staged, now)
             return
         st = self.app.stats
-        # the columns go up FIRST, as they always did: the upload is
-        # asynchronous, so the ~8 MB of a 524,288-event send cross the
-        # link while the host routes keys below (uploaded after host prep
-        # the transfer would sit on the critical path).  Then host prep,
-        # each part under its own span — key -> slot routing and the
-        # ts-wire build (route_keys), the observatory and liveness feeds
-        # (obs_feed) — then what prep produced goes up (h2d again), then
-        # the step (dispatch): no span's clock holds another's work
-        with _phases.phase(st, self.name, "h2d",
-                           bytes=_phases.nbytes(*staged.cols)):
-            raw_cols = tuple(jax.numpy.asarray(c) for c in staged.cols)
+        # host prep, each part under its own span — key -> slot routing,
+        # the ts-wire build and, for the programs that take them so
+        # (p.grouped_input), the columns put in the per-key order
+        # (route_keys) — then the columns go up, BEFORE the observatory
+        # and liveness feeds (obs_feed): the upload is asynchronous, so
+        # the ~8 MB of a 524,288-event send cross the link under them.
+        # Then what else prep produced goes up (h2d again), then the step
+        # (dispatch): no span's clock holds another's work
         dense = False
-        key_idx_np = sel_np = None
+        key_idx_np = None
         with _phases.phase(st, self.name, "route_keys") as sp:
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
             if p.partition_positions:
                 key_cols, valid = self._partition_keys(stream_id, staged)
-                key_idx_np, sel_np, hit = self._grouped_slots(
+                key_idx_np, sel_np, hit, ident = self._grouped_slots(
                     key_cols, valid, p)
                 Kb = key_idx_np.shape[0]
                 nuniq = int((key_idx_np < p.key_capacity).sum())
@@ -724,11 +726,19 @@ class PatternQueryRuntime(_MeshResolved):
             elif staged.valid.all():
                 # full bucket: the identity selection is a constant per
                 # capacity — cached read-only so repeat sends dedupe
-                sel_np = _identity_sel(B)
+                sel_np, ident = _identity_sel(B), True
             else:
-                sel_np = np.where(staged.valid,
-                                  np.arange(B, dtype=np.int32),
-                                  -1)[None, :]
+                sel_np, ident = np.where(staged.valid,
+                                         np.arange(B, dtype=np.int32),
+                                         -1)[None, :], False
+            cols = staged.cols
+            if p.grouped_input:
+                cols, ts_delta = _group_columns(sel_np, ident, cols,
+                                                ts_delta)
+                sp.set_metadata(grouped="view" if ident else "take")
+        with _phases.phase(st, self.name, "h2d",
+                           bytes=_phases.nbytes(*cols)):
+            cols_d = tuple(jax.numpy.asarray(c) for c in cols)
         if p.partition_positions:
             with _phases.phase(st, self.name, "obs_feed") as sp:
                 _stateobs_feed_group(self, self.slot_allocator, key_idx_np,
@@ -756,7 +766,7 @@ class PatternQueryRuntime(_MeshResolved):
                 key_d = jax.numpy.asarray(np.zeros((1,), np.int32))
             now_d = jax.numpy.asarray(now, jax.numpy.int64)
         steps = p.dense_steps if dense else p.steps
-        self._step_and_emit(steps[stream_id], now, raw_cols, *ts_d, sel_d,
+        self._step_and_emit(steps[stream_id], now, cols_d, *ts_d, sel_d,
                             key_d, now_d)
 
     def _partition_keys(self, stream_id: str, staged: ev.StagedBatch):
@@ -2427,6 +2437,30 @@ def _identity_sel(cap: int) -> np.ndarray:
         s.setflags(write=False)
         _IDENTITY_SEL[cap] = s
     return s
+
+
+def _is_identity_sel(sel: np.ndarray, B: int) -> bool:
+    """Does the grouping `sel` [Kb, E] list the batch's rows 0 .. B-1 in
+    order, each once (so nothing is padding)?  Two O(1) rejections, then
+    one pass (~0.4 ms at 524,288)."""
+    return (sel.size == B and int(sel[0, 0]) == 0 and
+            int(sel[-1, -1]) == B - 1 and
+            np.array_equal(sel.reshape(-1), _identity_sel(B)[0]))
+
+
+def _group_columns(sel: np.ndarray, identity: bool, cols, ts_delta):
+    """The staged columns and the ts delta in the per-key order of `sel`
+    [Kb, E], each as the flat `[Kb * E]` buffer the grouped programs
+    reshape (pattern_planner._jit_sequential): the values a device gather
+    by the clipped `sel` gives, a padding cell carrying row 0's.  The
+    delta is gathered as the narrow wire it is; the i64 timestamp forms
+    on the device.  Where `sel` is the identity the grouped buffers ARE
+    the staged ones: no copy."""
+    if identity:
+        return cols, ts_delta
+    idx = sel.reshape(-1)
+    return ([c.take(idx, mode="clip") for c in cols],
+            ts_delta.take(idx, mode="clip"))
 
 
 def _full_bucket_planes(cap: int) -> Tuple[np.ndarray, np.ndarray]:

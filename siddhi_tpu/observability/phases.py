@@ -34,7 +34,9 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
   send        whole InputHandler.send / send_columns call   (no phase:
               its self time is what no child span covers)
   stage       pad/adopt or pack_np into a StagedBatch        stage_host
-  route_keys  key -> slot routing, grouping, ts-wire build   stage_host
+  route_keys  key -> slot routing, grouping, ts-wire build;
+              `grouped` = view | take: the one-chip pattern
+              path's columns put in the per-key order        stage_host
   shard_group the router's [n, Kb, E] regroup of a sharded
               send, nested in route_keys                     stage_host
   obs_feed    state observatory feed, liveness, dirty marks  stage_host
@@ -92,7 +94,8 @@ class PhaseProfiler:
     hot-path entry — a dict upsert under a short lock, no allocation
     beyond the first sample of a (query, phase) pair."""
 
-    __slots__ = ("_lock", "_ns", "_count", "_dispatches", "_sampled")
+    __slots__ = ("_lock", "_ns", "_count", "_grouped", "_dispatches",
+                 "_sampled")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -100,17 +103,24 @@ class PhaseProfiler:
         # phase itself, else one of the spans that sum to it
         self._ns: Dict[tuple, int] = {}
         self._count: Dict[tuple, int] = {}
+        # (query, phase, part) -> {"view" | "take": samples}: how the
+        # pattern path's route_keys span grouped the columns
+        self._grouped: Dict[tuple, Dict[str, int]] = {}
         self._dispatches: Dict[str, int] = {}  # query -> dispatch counter
         self._sampled: Dict[str, int] = {}     # query -> fenced dispatches
 
     def add(self, query: str, phase: str, ns: int,
-            part: Optional[str] = None) -> None:
+            part: Optional[str] = None,
+            grouped: Optional[str] = None) -> None:
         if ns <= 0:
             return
         key = (query, phase, part)
         with self._lock:
             self._ns[key] = self._ns.get(key, 0) + int(ns)
             self._count[key] = self._count.get(key, 0) + 1
+            if grouped is not None:
+                tally = self._grouped.setdefault(key, {})
+                tally[grouped] = tally.get(grouped, 0) + 1
 
     def should_sample(self, query: str, every: int) -> bool:
         """Per-query dispatch modulus for the deep mode: True on every
@@ -133,10 +143,13 @@ class PhaseProfiler:
         copies, scrape-safe.  A phase fed by several spans (`stage_host`)
         sums their ns, lists each under `parts`, and counts batches: its
         `stage` part's samples (one a staged batch), or the most-sampled
-        part's where a query never sees one (timer-fired steps)."""
+        part's where a query never sees one (timer-fired steps).  The
+        `route_keys` part of a pattern query also lists `grouped`:
+        {"view": n, "take": n}, its spans counted by that stat."""
         with self._lock:
             ns = dict(self._ns)
             count = dict(self._count)
+            grouped = {k: dict(v) for k, v in self._grouped.items()}
             sampled = dict(self._sampled)
         queries: Dict[str, Dict] = {}
         for (q, p, part), total in ns.items():
@@ -149,6 +162,8 @@ class PhaseProfiler:
             else:
                 ent.setdefault("parts", {})[part] = {"ns": total,
                                                      "count": n}
+                if (q, p, part) in grouped:
+                    ent["parts"][part]["grouped"] = grouped[(q, p, part)]
         for q, phases in queries.items():
             for ent in phases.values():
                 parts = ent.get("parts")
@@ -165,6 +180,7 @@ class PhaseProfiler:
         with self._lock:
             self._ns.clear()
             self._count.clear()
+            self._grouped.clear()
             self._dispatches.clear()
             self._sampled.clear()
 
@@ -277,7 +293,7 @@ class _Timed:
             part = self.name if phase_name == "stage_host" else None
             add = self.stats.phases.add
             for q in self.queries:
-                add(q, phase_name, own, part)
+                add(q, phase_name, own, part, self.meta.get("grouped"))
         tr = _tracing.active()
         if tr is not None:
             meta = self.meta
@@ -423,7 +439,7 @@ def phase_report(rt) -> Dict:
             if "parts" in v:
                 entry[p]["parts"] = {
                     k: {"seconds": round(pv["ns"] / 1e9, 6),
-                        "count": pv["count"]}
+                        **{f: x for f, x in pv.items() if f != "ns"}}
                     for k, pv in v["parts"].items()}
         other_ns = max(0, e2e - total_ns) if e2e > 0 else 0
         queries[q] = {

@@ -135,11 +135,16 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
         assert by["route_keys"][0]["keys"] == N_KEYS
         assert by["route_keys"][0]["memo_hit"] == 1
         assert by["obs_feed"][0]["keys"] == N_KEYS
-        # the columns go up before host prep (the transfer overlaps it),
-        # what prep produced after it
+        # 1,024 keys take the 4,096-key bucket: the host groups the
+        # columns into its [4096, 2] cells by a take (an identity `sel`
+        # would read "view": tests/test_grouped_columns.py)
+        assert by["route_keys"][0]["grouped"] == "take"
+        # the grouped columns go up between routing and the observatory
+        # feed (the transfer overlaps it), what else prep produced after
         assert len(by["h2d"]) == 2
-        assert by["h2d"][0]["bytes"] == 2 * N_KEYS * (8 + 4 + 4)
-        assert by["h2d"][0]["end"] <= by["route_keys"][0]["start"]
+        assert by["h2d"][0]["bytes"] == 2 * 4096 * (8 + 4 + 4)
+        assert by["route_keys"][0]["end"] <= by["h2d"][0]["start"]
+        assert by["h2d"][0]["end"] <= by["obs_feed"][0]["start"]
         assert by["h2d"][1]["start"] >= by["obs_feed"][0]["end"]
         kinds = sorted(e["what"] for e in by["fetch"])
         assert kinds == ["header", "rows"]        # payload: `valid` only
@@ -147,7 +152,7 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
         assert by["demux"][0]["rows"] == N_KEYS
         # pipeline order on the thread
         order = [by[n][0]["start"] for n in
-                 ("stage", "h2d", "route_keys", "obs_feed", "dispatch",
+                 ("stage", "route_keys", "h2d", "obs_feed", "dispatch",
                   "demux")]
         assert order == sorted(order)
         # the emission side's observatory feed and the subscriber sit
@@ -310,8 +315,8 @@ def test_basic_phase_report_counts_each_phase_once_per_send(monkeypatch):
     for name in ("stage_host", "dispatch_submit", "demux", "sink"):
         assert node[name]["count"] == sends, (name, node[name])
         assert node[name]["seconds"] > 0
-    # A2: the uploads are booked as h2d, not as staging — the columns
-    # before host prep, what prep produced after it
+    # A2: the uploads are booked as h2d, not as staging — the grouped
+    # columns after routing, what else prep produced after the feeds
     assert node["h2d"]["count"] == 2 * sends and node["h2d"]["seconds"] > 0
     # a counting subscriber costs the header fetch and nothing else
     assert node["d2h_drain"]["count"] == sends
@@ -320,6 +325,8 @@ def test_basic_phase_report_counts_each_phase_once_per_send(monkeypatch):
     assert sum(p["seconds"] for p in parts.values()) == pytest.approx(
         node["stage_host"]["seconds"], abs=1e-5)
     assert parts["stage"]["count"] == parts["route_keys"]["count"] == sends
+    # how each send's columns were put in the per-key order
+    assert parts["route_keys"]["grouped"] == {"take": sends}
     # the observatory feeds twice a send — key hotness before the step,
     # emission-cap demand at delivery — and stage_host still counts sends
     assert parts["obs_feed"]["count"] == 2 * sends

@@ -634,39 +634,55 @@ GUARD = {
 }
 
 
+@pytest.fixture(scope="module")
+def guard_compiled(topo, flagship_plan):
+    """step name -> (plan, compiled program) at GUARD's shapes, each
+    compiled once for the guards below."""
+    done = {}
+
+    def compiled(step):
+        if step in done:
+            return done[step]
+        g = GUARD[step]
+        if g["chips"] == 1:
+            one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+            rep = batch = one
+            p = flagship_plan(g["kcap"])
+
+            def place(x):
+                return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+            fn = (p.dense_steps if g["dense"] else p.steps)["TradeStream"]
+            n_rows = g["Kb"]
+        else:
+            mesh = Mesh(np.array(topo.devices), ("shard",))
+            rep = NamedSharding(mesh, P())
+            batch = NamedSharding(mesh, P("shard"))
+            p = flagship_plan(g["kcap"], mesh)
+            specs = pattern_planner._shard_specs(
+                StatePacker(p.exec.init_state(1)), p.selector_exec)
+            shapes = jax.eval_shape(
+                lambda: p.init_state.__wrapped__(g["kcap"]))
+            placed = jax.tree.map(
+                lambda x, s: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+                shapes, specs)
+            flat = iter(jax.tree.leaves(placed))
+
+            def place(_x):
+                return next(flat)
+            fn = p.steps["TradeStream"]
+            n_rows = g["Kb"] * g["chips"]
+        args = step_args(p, g["kcap"], g["B"], n_rows, 4, place, rep, batch,
+                         g["dense"])
+        done[step] = (p, fn.lower(*args).compile())
+        return done[step]
+    return compiled
+
+
 @pytest.mark.parametrize("step", sorted(GUARD))
-def test_v5e_compile_has_no_whole_blob_x64_pass(step, topo, flagship_plan):
+def test_v5e_compile_has_no_whole_blob_x64_pass(step, guard_compiled):
     g = GUARD[step]
-    if g["chips"] == 1:
-        one = jax.sharding.SingleDeviceSharding(topo.devices[0])
-        rep = batch = one
-        p = flagship_plan(g["kcap"])
-
-        def place(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
-        fn = (p.dense_steps if g["dense"] else p.steps)["TradeStream"]
-        n_rows = g["Kb"]
-    else:
-        mesh = Mesh(np.array(topo.devices), ("shard",))
-        rep = NamedSharding(mesh, P())
-        batch = NamedSharding(mesh, P("shard"))
-        p = flagship_plan(g["kcap"], mesh)
-        specs = pattern_planner._shard_specs(
-            StatePacker(p.exec.init_state(1)), p.selector_exec)
-        shapes = jax.eval_shape(lambda: p.init_state.__wrapped__(g["kcap"]))
-        placed = jax.tree.map(
-            lambda x, s: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
-            shapes, specs)
-        flat = iter(jax.tree.leaves(placed))
-
-        def place(_x):
-            return next(flat)
-        fn = p.steps["TradeStream"]
-        n_rows = g["Kb"] * g["chips"]
-    args = step_args(p, g["kcap"], g["B"], n_rows, 4, place, rep, batch,
-                     g["dense"])
-    compiled = fn.lower(*args).compile()
+    p, compiled = guard_compiled(step)
     per_chip = g["kcap"] // g["chips"]
     assert x64_boundary_ops(compiled.as_text(), per_chip) == []
     w64 = StatePacker(p.exec.init_state(1)).w64
@@ -674,3 +690,25 @@ def test_v5e_compile_has_no_whole_blob_x64_pass(step, topo, flagship_plan):
     assert w64 == 40 and plane in (167772160, 1342177280)
     # under ONE plane's bytes: no copy of the resident state, whole or half
     assert compiled.memory_analysis().temp_size_in_bytes < plane
+
+
+# the one-chip programs take the event columns grouped by the host (PR 29)
+# and reshape them: the dense step gathers nothing, the scan step only its
+# keys' rows of the three state planes.  The sharded step still gathers
+# the replicated [B] columns on each chip: three state planes + key (two
+# u32 halves), price, stage and the decoded i64 timestamp (two more) — its
+# count is the guard that the mesh program did not change
+GATHERS = {
+    "dense": [],
+    "gather": ["s32[50,2048]", "u32[40,2048]", "u32[40,2048]"],
+    "sharded": ["s32[50,32768]", "u32[40,32768]", "u32[40,32768]"] +
+               ["[32768,4]"] * 6,
+}
+
+
+@pytest.mark.parametrize("step", sorted(GUARD))
+def test_v5e_compile_gathers_only_what_the_step_must(step, guard_compiled):
+    _p, compiled = guard_compiled(step)
+    found = re.findall(r"= (\S+?)\{\S* gather\(", compiled.as_text())
+    assert len(found) == len(GATHERS[step]), found
+    assert all(want in got for want, got in zip(GATHERS[step], found)), found
