@@ -142,7 +142,7 @@ class SlotAllocator:
         """Vectorized lookup/insert: key_cols are 1-D arrays of equal length.
         Returns int32 slot ids (-1 for invalid rows; with lookup_only also
         -1 for unknown keys, and nothing is allocated)."""
-        out, _ = self._slots(key_cols, valid, lookup_only, group=False,
+        out, _ = self._slots(key_cols, valid, lookup_only, group=None,
                              pad=0)
         return out
 
@@ -156,11 +156,28 @@ class SlotAllocator:
             v = np.ones(slots.shape[0], bool) if valid is None else valid
             key_idx, sel, _ = group_events_by_key(slots, v, pad=pad)
             return slots, key_idx, sel
-        out, grouped = self._slots(key_cols, valid, False, group=True,
-                                   pad=pad)
+        out, grouped = self._slots(key_cols, valid, False,
+                                   group=_fill_groups, pad=pad)
         return out, grouped[0], grouped[1]
 
-    def _slots(self, key_cols, valid, lookup_only, group: bool, pad: int):
+    def slots_and_tiers(self, key_cols: Sequence[np.ndarray],
+                        valid: Optional[np.ndarray], pad: int):
+        """`slots_and_group` with the layout sized by the batch's events:
+        (slots, [(key_idx, sel), ...], the hottest key's count), the keys
+        split by their count into a few [Kb, E] rectangles
+        (`tier_events_by_key`) — one, and `slots_and_group`'s own, unless
+        the keys' counts are far apart."""
+        if LIB is None:
+            slots = self.slots_for(key_cols, valid)
+            v = np.ones(slots.shape[0], bool) if valid is None else valid
+            return (slots,) + tier_events_by_key(slots, v, pad=pad)
+        out, grouped = self._slots(key_cols, valid, False,
+                                   group=_fill_tiers, pad=pad)
+        return (out,) + grouped
+
+    def _slots(self, key_cols, valid, lookup_only, group, pad: int):
+        """`group`: None, or the fill (`_fill_groups` | `_fill_tiers`)
+        the fused count pass feeds."""
         n = len(key_cols[0])
         if n == 0:
             return np.empty((0,), np.int32), None
@@ -215,9 +232,9 @@ class SlotAllocator:
                             f"slot capacity {self.capacity} exhausted for "
                             f"{self.name!r}; raise via @capacity annotation")
                     if group:
-                        grouped = _fill_groups(out, live, n, cnt, rank,
-                                               touched, int(gmeta[0]),
-                                               int(gmeta[1]), pad)
+                        grouped = group(out, live, n, cnt, rank,
+                                        touched, int(gmeta[0]),
+                                        int(gmeta[1]), pad)
                 finally:
                     if group:
                         _group_scratch_lock.release()
@@ -491,6 +508,133 @@ def _fill_groups(slots, live, n, cnt, rank, touched, nu, maxc, pad):
     return key_idx, sel
 
 
+def _count_and_fill(slots, valid, pad, fill):
+    """The standalone C count pass over already-resolved `slots`, then
+    `fill` (`_fill_groups` | `_fill_tiers`): (what `fill` returns, the
+    number of distinct slots)."""
+    n = slots.shape[0]
+    slots = np.ascontiguousarray(slots, np.int32)
+    live = np.ascontiguousarray(valid, np.uint8)
+    with _group_scratch_lock:
+        cnt, rank, touched = _scratch(
+            max(pad, int(slots.max(initial=0)) + 1))
+        maxc = np.zeros(1, np.int64)
+        nu = int(LIB.sg_group_count(
+            ptr(slots, ctypes.c_int32), ptr(live, ctypes.c_uint8), n,
+            ptr(cnt, ctypes.c_int32), ptr(touched, ctypes.c_int32),
+            ptr(maxc, ctypes.c_int64)))
+        return fill(slots, live, n, cnt, rank, touched, nu, int(maxc[0]),
+                    pad), nu
+
+
+def _tier_plan(counts, nu: int, maxc: int):
+    """How a batch whose `nu` keys have the per-key counts `counts()` (asked
+    for only once the cheap tests have passed) is tiered:
+    None for the one rectangle, else [(hi, Kb, E)] of the non-empty count
+    classes, ascending (class i: the keys with _TIER_CUTS[i-1] < count <=
+    _TIER_CUTS[i], the last one open above; hi = the class's own largest
+    count, which tells the classes apart as well as the cut does).
+
+    The one rectangle stays unless it has over twice the tiers' cells
+    and over _TIER_MIN_CELLS: keys that all have one count are one class,
+    counts a factor of two or three apart are not worth a dispatch a
+    class, and neither is a rectangle whose columns are under a
+    megabyte."""
+    one = _bucket(nu, _KB_BUCKETS) * _bucket(maxc, _E_BUCKETS)
+    if maxc <= _TIER_CUTS[0] or one <= _TIER_MIN_CELLS:
+        return None
+    counts = counts()
+    cls = np.searchsorted(_TIER_CUTS, counts, side="left")
+    plan = []
+    for i in range(len(_TIER_CUTS) + 1):
+        mine = counts[cls == i]
+        if mine.size:
+            hi = int(mine.max())
+            plan.append((hi, _bucket(mine.size, _KB_BUCKETS),
+                         _bucket(hi, _E_BUCKETS)))
+    if len(plan) < 2 or one <= 2 * sum(kb * e for _, kb, e in plan):
+        return None
+    return plan
+
+
+def _tier_buffers(plan):
+    """The rectangles of a tier plan, end to end in one key_idx and one sel
+    buffer: ([(key_idx view, sel view)], key_idx buffer, sel buffer, row
+    offsets, cell offsets)."""
+    key_off = np.zeros(len(plan) + 1, np.int64)
+    sel_off = np.zeros(len(plan) + 1, np.int64)
+    for t, (_, kb, e) in enumerate(plan):
+        key_off[t + 1] = key_off[t] + kb
+        sel_off[t + 1] = sel_off[t] + kb * e
+    key_all = np.empty(int(key_off[-1]), np.int32)
+    sel_all = np.empty(int(sel_off[-1]), np.int32)
+    tiers = [(key_all[key_off[t]:key_off[t + 1]],
+              sel_all[sel_off[t]:sel_off[t + 1]].reshape(kb, e))
+             for t, (_, kb, e) in enumerate(plan)]
+    return tiers, key_all, sel_all, key_off, sel_off
+
+
+def _fill_tiers(slots, live, n, cnt, rank, touched, nu, maxc, pad):
+    """`_fill_groups`, tiered: ([(key_idx, sel), ...], maxc) — the one
+    rectangle of `_fill_groups` where `_tier_plan` keeps it."""
+    plan = None if nu == 0 else \
+        _tier_plan(lambda: cnt[touched[:nu]], nu, maxc)
+    if plan is None:
+        return [_fill_groups(slots, live, n, cnt, rank, touched, nu, maxc,
+                             pad)], maxc
+    tiers, key_all, sel_all, key_off, sel_off = _tier_buffers(plan)
+    hi = np.array([h for h, _, _ in plan], np.int32)
+    kbs = np.array([kb for _, kb, _ in plan], np.int64)
+    es = np.array([e for _, _, e in plan], np.int64)
+    LIB.sg_group_fill_tiers(
+        ptr(slots, ctypes.c_int32),
+        None if live is None else ptr(live, ctypes.c_uint8), n,
+        ptr(cnt, ctypes.c_int32), ptr(rank, ctypes.c_int32),
+        ptr(touched, ctypes.c_int32), nu,
+        ptr(hi, ctypes.c_int32), len(plan),
+        ptr(kbs, ctypes.c_int64), ptr(es, ctypes.c_int64),
+        ptr(key_off, ctypes.c_int64), ptr(sel_off, ctypes.c_int64), pad,
+        ptr(key_all, ctypes.c_int32), ptr(sel_all, ctypes.c_int32))
+    return tiers, maxc
+
+
+def tier_events_by_key(slots: np.ndarray, valid: np.ndarray,
+                       pad: int = 2**30):
+    """`group_events_by_key` with the layout sized by the batch's events,
+    not by `distinct keys x hottest key's count`: ([(key_idx [Kb],
+    sel [Kb, E]), ...], the hottest key's count), the keys split by
+    their count (`_tier_plan`) into rectangles of their own, classes
+    ascending.  Each rectangle keeps the layout contract of the one
+    (slots ascending, a key's events along E in batch order, -1 / `pad`
+    padding); a key is in exactly one.  A batch whose keys all have one
+    count — and any batch the one rectangle serves within a factor of
+    two — gives that one rectangle."""
+    if LIB is not None and pad < 2**30:
+        return _count_and_fill(slots, valid, pad, _fill_tiers)[0]
+    idx = np.nonzero(valid & (slots >= 0))[0]
+    plan, maxc = None, 0
+    if idx.size:
+        uniq, counts = np.unique(slots[idx], return_counts=True)
+        maxc = int(counts.max())
+        plan = _tier_plan(lambda: counts, len(uniq), maxc)
+    if plan is None:
+        return [group_events_by_key(slots, valid, pad)[:2]], maxc
+    tiers, key_all, sel_all, _, _ = _tier_buffers(plan)
+    key_all[:] = pad
+    sel_all[:] = -1
+    lo = 0
+    for (hi, _, _), (key_idx, sel) in zip(plan, tiers):
+        keys = uniq[(counts > lo) & (counts <= hi)]
+        mine = idx[np.isin(slots[idx], keys)]
+        order = np.argsort(slots[mine], kind="stable")
+        row = np.searchsorted(keys, slots[mine][order])
+        first = np.searchsorted(row, row, side="left")
+        key_idx[:len(keys)] = keys
+        sel[row, np.arange(len(row)) - first] = mine[order]
+        lo = hi
+    return tiers, maxc
+
+
 def group_events_by_key(slots: np.ndarray, valid: np.ndarray,
                         pad: int = 2**30):
     """Arrange a batch into the per-key [Kb, E] device layout.
@@ -505,20 +649,9 @@ def group_events_by_key(slots: np.ndarray, valid: np.ndarray,
     no-op there) and the scatter-back DROPS them as out-of-bounds — a pad row
     must never alias a live key's slot, or its stale state would clobber it."""
     if LIB is not None and pad < 2**30:
-        n = slots.shape[0]
-        slots = np.ascontiguousarray(slots, np.int32)
-        live = np.ascontiguousarray(valid, np.uint8)
-        with _group_scratch_lock:
-            cnt, rank, touched = _scratch(
-                max(pad, int(slots.max(initial=0)) + 1))
-            maxc = np.zeros(1, np.int64)
-            nu = LIB.sg_group_count(
-                ptr(slots, ctypes.c_int32), ptr(live, ctypes.c_uint8), n,
-                ptr(cnt, ctypes.c_int32), ptr(touched, ctypes.c_int32),
-                ptr(maxc, ctypes.c_int64))
-            key_idx, sel = _fill_groups(slots, live, n, cnt, rank, touched,
-                                        int(nu), int(maxc[0]), pad)
-        if int(nu) == 0:
+        (key_idx, sel), nu = _count_and_fill(slots, valid, pad,
+                                             _fill_groups)
+        if nu == 0:
             return key_idx, sel, np.zeros((1, 1), np.bool_)
         return key_idx, sel, sel >= 0
     vmask = valid & (slots >= 0)
@@ -555,4 +688,23 @@ def _bucket(n: int, buckets) -> int:
 
 _KB_BUCKETS = (1, 8, 64, 512, 4096, 16384, 65536, 131072,
                262144, 524288, 1048576)
-_E_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096, 16384)
+# powers of two up to 16,384: a key's E is under twice its count
+_E_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096,
+              8192, 16384)
+# per-key count classes of a tiered grouping (`_tier_plan`): at most 4
+# events of a batch, at most 32, more.  Fixed cuts, not cuts fitted to
+# each batch's histogram: every distinct [Kb, E] is a compiled program,
+# and cuts that follow the batch would meet new shapes for as long as the
+# traffic runs.  Two cuts a factor 8 apart, as the Kb buckets are, hold
+# whatever the skew (tests/test_keyslots_direct.py walks exponents 0.5 to
+# 3 and key spaces of 4,096 to 16 M):
+#   the scan's ticks, the sum of the E, stay under 36 + twice the
+#   hottest key's count;
+#   the two lower classes pay at most 4 and 32 cells a key, times the Kb
+#   bucket's padding;
+#   the open top class pays `its keys' Kb bucket x the hottest key's E`
+#   — the known remainder: 20 keys of 33 to 1,500 events are a [64, 2048]
+#   rectangle, ~36 cells an event of theirs.
+# 4 is the E of a key that brings one visit of the 4-stage flagship
+_TIER_CUTS = (4, 32)
+_TIER_MIN_CELLS = 1 << 16

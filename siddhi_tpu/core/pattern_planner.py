@@ -230,6 +230,10 @@ class PlannedPatternQuery:
     # hands them the columns already in the per-key [Kb, E] order
     # (runtime._group_columns) and they gather nothing
     grouped_input: bool = False
+    # ((emission, wake) of each tier) -> (emission, wake): the tiers of a
+    # skewed send (runtime process_staged) leave as the one emission of
+    # the send (_merge_emissions); None where sends are never tiered
+    merge_emissions: Optional[Callable] = None
     # False when the per-key emission cap is an implicit default: overflow
     # then raises instead of dropping rows (@emit(rows=N) opts into capping)
     emit_explicit: bool = True
@@ -275,6 +279,11 @@ class PlannedPatternQuery:
             "out_columns": list(self.out_schema.names),
             # per-batch step specializations the runtime can dispatch to
             "dense_slot_fast_path": self.dense_steps is not None,
+            # a partitioned send whose keys' event counts are far apart
+            # is laid out as tiers, each a dispatch of the same step,
+            # their emissions merged into the send's one (runtime
+            # process_staged; keyslots._tier_plan has the rule)
+            "tiered_send_layout": self.merge_emissions is not None,
             "timer_step": self.timer_step is not None,
         }
         d["emission_cap_rows"] = plan_facts.render_cap(self.compact_rows)
@@ -418,6 +427,7 @@ def plan_pattern_query(
     step_bodies = None
     shard_fused_steps = None
     grouped_input = False
+    merge_emissions = None
     if mesh is None and partition_positions is None and \
             block_eligible(spec) and not _FORCE_SCAN:
         # single-key simple chain: the sequential E-tick scan degrades to
@@ -438,6 +448,9 @@ def plan_pattern_query(
                                             name, "pattern_dense",
                                             grouped=True)
                        for sid in spec.stream_ids}
+        if partition_positions is not None:
+            merge_emissions = jit_step(_merge_emissions, owner=name,
+                                       role="pattern_merge")
     else:
         steps = {sid: _shard_step(body, mesh, packer, sel, owner=name)
                  for sid, body in raw_steps.items()}
@@ -499,10 +512,25 @@ def plan_pattern_query(
         partition_positions=partition_positions,
         partition_key_fns=partition_key_fns,
         raw_steps=raw_steps, mesh=mesh,
-        grouped_input=grouped_input, emit_explicit=emit_explicit,
-        selector_exec=sel, emits_uuid=pexec.scope.uses_uuid,
+        grouped_input=grouped_input, merge_emissions=merge_emissions,
+        emit_explicit=emit_explicit, selector_exec=sel,
+        emits_uuid=pexec.scope.uses_uuid,
         compact_rows=compact_rows, step_bodies=step_bodies,
         shard_fused_steps=shard_fused_steps)
+
+
+def _merge_emissions(parts):
+    """The `_emit_matches` results of a send's tiers, `((out, wake), ...)`,
+    as the one result of the send: the counts summed, the tiers' rows end
+    to end (each tier's stay rank-major; delivery restores the timestamp
+    order over all of them with the sort it already has), the earliest
+    wake.  A copy of the tiers' row slots on the device, so the send
+    costs one header fetch and one payload, as a one-rectangle send."""
+    outs = [out for out, _ in parts]
+    rows = jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                        *[out[2:] for out in outs])
+    out = (sum(out[0] for out in outs), sum(out[1] for out in outs)) + rows
+    return out, functools.reduce(jnp.minimum, [wake for _, wake in parts])
 
 
 def _gathering(body):

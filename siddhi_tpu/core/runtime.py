@@ -206,22 +206,27 @@ def _stateobs_feed_slots(qr, alloc, slots, span) -> None:
     qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity, keys, counts)
 
 
-def _stateobs_feed_group(qr, alloc, key_idx, sel, pad, span) -> None:
+def _stateobs_feed_group(qr, alloc, groups, pad, span) -> None:
     """Fold one grouped batch's key set into the hotness tracker: the
     per-key row counts fall out of the already-computed [Kb, E] group
-    selection (`(sel >= 0).sum(axis=1)`) — no extra np.unique pass.
-    `span` (the `obs_feed` span around the call) gets `keys`."""
+    selections (`(sel >= 0).sum(axis=1)`) — no extra np.unique pass.
+    `groups` is the batch's (key_idx, sel) rectangles — one, or the
+    tiers of a skewed send, whose keys are disjoint: one feed either
+    way.  `span` (the `obs_feed` span around the call) gets `keys`."""
     if not _stateobs.obs_enabled(qr.app):
         return
-    keys = np.asarray(key_idx)
-    live = keys < pad
-    if not live.any():
+    keys, counts = [], []
+    for key_idx, sel in groups:
+        key_idx = np.asarray(key_idx)
+        live = key_idx < pad
+        keys.append(key_idx[live])
+        counts.append((np.asarray(sel) >= 0).sum(axis=1)[live])
+    keys = keys[0] if len(keys) == 1 else np.concatenate(keys)
+    if not keys.size:
         return
-    counts = (np.asarray(sel) >= 0).sum(axis=1)
-    keys = keys[live]
+    counts = counts[0] if len(counts) == 1 else np.concatenate(counts)
     span.set_metadata(keys=keys.size)
-    qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity,
-                                    keys, counts[live])
+    qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity, keys, counts)
 
 
 def _wrap_stream_callback(cb) -> Callable[[List[ev.Event]], None]:
@@ -507,8 +512,8 @@ class QueryRuntime(_MeshResolved):
                         gk = kcols + gk
                     gslot = p.slot_allocator.slots_for(gk, valid)
             with _phases.phase(st, self.name, "obs_feed") as sp:
-                _stateobs_feed_group(self, p.window_key_allocator, key_idx,
-                                     sel, p.key_capacity, sp)
+                _stateobs_feed_group(self, p.window_key_allocator,
+                                     [(key_idx, sel)], p.key_capacity, sp)
                 if self._touch is not None:
                     self._touch(key_idx, now)
                 if gslot is not None and self._touch_group is not None:
@@ -654,11 +659,14 @@ class PatternQueryRuntime(_MeshResolved):
         resolved (`version`) and the keys compare equal, the C pass and
         group fill are pure functions of the block and replay from cache
         (~30ms -> ~0.2ms per 131k-key send: 16% of flagship wall time).
-        Returns (key_idx, sel, memo hit, identity): `identity` says that
-        `sel` lists the batch's rows 0 .. B-1 in order, so the grouped
-        columns ARE the staged ones (_group_columns).  It is decided
-        here, where `sel` is made, and kept with the memo entry: a memo
-        hit pays no O(B) pass for it."""
+        Returns (tiers, hottest key's count, memo hit): `tiers` is the
+        batch's layout as [(key_idx, sel, identity)] — one rectangle, or
+        the few a skewed batch is split into by per-key count
+        (keyslots._tier_plan), their keys disjoint.  `identity` says
+        that `sel` lists the batch's rows 0 .. B-1 in order, so the
+        grouped columns ARE the staged ones (_group_columns).  It is
+        decided here, where `sel` is made, and kept with the memo entry:
+        a memo hit pays no O(B) pass for it."""
         alloc = self.slot_allocator
         keys = key_cols[0] if len(key_cols) == 1 else None
         cacheable = (keys is not None and keys.dtype.kind in "iu" and
@@ -668,16 +676,18 @@ class PatternQueryRuntime(_MeshResolved):
             ent = self._block_cache.get(blk)
             if ent is not None and ent[0] == alloc.version and \
                     np.array_equal(keys, ent[3]):
-                return ent[1], ent[2], True, ent[4]
-        _, key_idx, sel = alloc.slots_and_group(key_cols, valid,
-                                                pad=p.key_capacity)
-        ident = _is_identity_sel(sel, valid.shape[0])
+                return ent[1], ent[2], True
+        _, groups, max_e = alloc.slots_and_tiers(key_cols, valid,
+                                                 pad=p.key_capacity)
+        tiers = [(key_idx, sel, len(groups) == 1 and
+                  _is_identity_sel(sel, valid.shape[0]))
+                 for key_idx, sel in groups]
         if cacheable:
             if len(self._block_cache) >= 64:
                 self._block_cache.clear()
-            self._block_cache[blk] = (alloc.version, key_idx, sel,
-                                      keys.copy(), ident)
-        return key_idx, sel, False, ident
+            self._block_cache[blk] = (alloc.version, tiers, max_e,
+                                      keys.copy())
+        return tiers, max_e, False
 
     def process_staged(self, stream_id: str, staged: ev.StagedBatch,
                        now: int) -> None:
@@ -694,6 +704,7 @@ class PatternQueryRuntime(_MeshResolved):
             self._process_sharded(stream_id, staged, now)
             return
         st = self.app.stats
+        cap = p.key_capacity
         # host prep, each part under its own span — key -> slot routing,
         # the ts-wire build and, for the programs that take them so
         # (p.grouped_input), the columns put in the per-key order
@@ -701,73 +712,114 @@ class PatternQueryRuntime(_MeshResolved):
         # and liveness feeds (obs_feed): the upload is asynchronous, so
         # the ~8 MB of a 524,288-event send cross the link under them.
         # Then what else prep produced goes up (h2d again), then the step
-        # (dispatch): no span's clock holds another's work
-        dense = False
-        key_idx_np = None
+        # (dispatch): no span's clock holds another's work.  A send whose
+        # keys' counts are far apart is laid out as a few [Kb, E] tiers
+        # (_grouped_slots), each its own upload and dispatch of the same
+        # step, the tier of the hottest keys first: its scan is the send's
+        # longest piece of device work, and it runs under the host's prep
+        # of the others.  The tiers' emissions leave as ONE emission
+        # (planned.merge_emissions): one header, one payload, and the
+        # timestamp order of delivery holds over all of the send's keys
         with _phases.phase(st, self.name, "route_keys") as sp:
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
             if p.partition_positions:
                 key_cols, valid = self._partition_keys(stream_id, staged)
-                key_idx_np, sel_np, hit, ident = self._grouped_slots(
-                    key_cols, valid, p)
-                Kb = key_idx_np.shape[0]
-                nuniq = int((key_idx_np < p.key_capacity).sum())
-                sp.set_metadata(keys=nuniq, memo_hit=int(hit))
+                tiers, max_e, hit = self._grouped_slots(key_cols, valid, p)
+                nuniq = [int((k < cap).sum()) for k, _, _ in tiers]
+                sp.set_metadata(
+                    keys=sum(nuniq), memo_hit=int(hit), tiers=len(tiers),
+                    cells=sum(sel.size for _, sel, _ in tiers),
+                    ticks=sum(sel.shape[1] for _, sel, _ in tiers),
+                    max_e=max_e)
+            elif staged.valid.all():
+                # full bucket: the identity selection is a constant per
+                # capacity — cached read-only so repeat sends dedupe
+                tiers, nuniq = [(None, _identity_sel(B), True)], [0]
+            else:
+                tiers, nuniq = [(None, np.where(
+                    staged.valid, np.arange(B, dtype=np.int32),
+                    -1)[None, :], False)], [0]
+            grouped = [(staged.cols, ts_delta)] * len(tiers)
+            if p.grouped_input:
+                grouped = [_group_columns(sel, ident, staged.cols, ts_delta)
+                           for _, sel, ident in tiers]
+                sp.set_metadata(
+                    grouped="view" if tiers[0][2] else "take")
+        outs, now_d, fed = [], None, not p.partition_positions
+        try:
+            for t in reversed(range(len(tiers))):
+                key_idx_np, sel_np, _ = tiers[t]
+                cols, delta = grouped[t]
                 # contiguous-slot fast path: dynamic-slice state access
                 # instead of row-serialized gather/scatter (see
                 # dense_steps).  nuniq >= 2: the Kb=1 dense specialization
                 # trips an XLA:CPU fused-dynamic-slice codegen bug
                 # (RET_CHECK llvm_module), and a 1-row gather is as fast
                 # as a 1-row slice anyway
-                dense = (p.dense_steps is not None and nuniq > 1 and
-                         int(key_idx_np[0]) + Kb <= p.key_capacity and
-                         int(key_idx_np[nuniq - 1]) ==
-                         int(key_idx_np[0]) + nuniq - 1)
-            elif staged.valid.all():
-                # full bucket: the identity selection is a constant per
-                # capacity — cached read-only so repeat sends dedupe
-                sel_np, ident = _identity_sel(B), True
-            else:
-                sel_np, ident = np.where(staged.valid,
-                                         np.arange(B, dtype=np.int32),
-                                         -1)[None, :], False
-            cols = staged.cols
-            if p.grouped_input:
-                cols, ts_delta = _group_columns(sel_np, ident, cols,
-                                                ts_delta)
-                sp.set_metadata(grouped="view" if ident else "take")
-        with _phases.phase(st, self.name, "h2d",
-                           bytes=_phases.nbytes(*cols)):
-            cols_d = tuple(jax.numpy.asarray(c) for c in cols)
-        if p.partition_positions:
-            with _phases.phase(st, self.name, "obs_feed") as sp:
-                _stateobs_feed_group(self, self.slot_allocator, key_idx_np,
-                                     sel_np, p.key_capacity, sp)
-                if self._touch is not None:
-                    self._touch(key_idx_np, now)
-                if self._dirty is not None and nuniq:
-                    self._dirty[key_idx_np[:nuniq]] = True
-                    if dense:
+                n, Kb = nuniq[t], sel_np.shape[0]
+                dense = (p.dense_steps is not None and n > 1 and
+                         int(key_idx_np[0]) + Kb <= cap and
+                         int(key_idx_np[n - 1]) ==
+                         int(key_idx_np[0]) + n - 1)
+                with _phases.tier_scope(t if len(tiers) > 1 else None):
+                    with _phases.phase(st, self.name, "h2d",
+                                       bytes=_phases.nbytes(*cols)):
+                        cols_d = tuple(jax.numpy.asarray(c) for c in cols)
+                    if not fed:
+                        self._feed_observers(tiers, nuniq, now)
+                        fed = True
+                    if dense and self._dirty is not None:
                         # the dense step also time-ticks slots beyond nuniq
                         self._dirty[int(key_idx_np[0]):
                                     int(key_idx_np[0]) + Kb] = True
-        with _phases.phase(st, self.name, "h2d",
-                           bytes=_phases.nbytes(ts_delta, sel_np)):
-            # the base rides the step call as the numpy scalar it is: an
-            # upload call of its own costs as much as the delta's
-            ts_d = (ts_base, jax.numpy.asarray(ts_delta))
-            sel_d = jax.numpy.asarray(sel_np)
-            if dense:
-                key_d = jax.numpy.asarray(int(key_idx_np[0]),
-                                          jax.numpy.int32)
-            elif key_idx_np is not None:
-                key_d = jax.numpy.asarray(key_idx_np)
-            else:
-                key_d = jax.numpy.asarray(np.zeros((1,), np.int32))
-            now_d = jax.numpy.asarray(now, jax.numpy.int64)
-        steps = p.dense_steps if dense else p.steps
-        self._step_and_emit(steps[stream_id], now, cols_d, *ts_d, sel_d,
-                            key_d, now_d)
+                    with _phases.phase(st, self.name, "h2d",
+                                       bytes=_phases.nbytes(delta, sel_np)):
+                        # the base rides the step call as the numpy scalar
+                        # it is: an upload call of its own costs as much
+                        # as the delta's
+                        ts_d = (ts_base, jax.numpy.asarray(delta))
+                        sel_d = jax.numpy.asarray(sel_np)
+                        if dense:
+                            key_d = jax.numpy.asarray(int(key_idx_np[0]),
+                                                      jax.numpy.int32)
+                        elif key_idx_np is not None:
+                            key_d = jax.numpy.asarray(key_idx_np)
+                        else:
+                            key_d = jax.numpy.asarray(
+                                np.zeros((1,), np.int32))
+                        if now_d is None:   # one upload serves every tier
+                            now_d = jax.numpy.asarray(now, jax.numpy.int64)
+                    steps = p.dense_steps if dense else p.steps
+                    outs.append(self._step(steps[stream_id], cols_d, *ts_d,
+                                           sel_d, key_d, now_d))
+            # the tiers' emissions as the send's one: a dispatch of its
+            # own (pattern_planner._merge_emissions), tiny beside a step
+            out, wake = outs[0] if len(outs) == 1 else _phases.dispatch(
+                self, p.merge_emissions, tuple(outs))
+        except Exception:
+            # a tier that was dispatched has advanced its keys' state: what
+            # it matched is delivered before the error is, so no match is
+            # consumed and lost (the junction reports the send as failed;
+            # the tiers after the failing one were not applied)
+            for out, wake in outs:
+                _emit_output(self, out, now, wake=self._wake_arg(wake))
+            raise
+        _emit_output(self, out, now, wake=self._wake_arg(wake))
+
+    def _feed_observers(self, tiers, nuniq, now: int) -> None:
+        """What watches a partitioned send's keys, under one `obs_feed`
+        span: the key-hotness feed, the purger's liveness touch, the
+        snapshot's dirty marks — once for all of the send's tiers."""
+        with _phases.phase(self.app.stats, self.name, "obs_feed") as sp:
+            _stateobs_feed_group(
+                self, self.slot_allocator,
+                [(key_idx, sel) for key_idx, sel, _ in tiers],
+                self.planned.key_capacity, sp)
+            for (key_idx, _, _), n in zip(tiers, nuniq):
+                if self._touch is not None:
+                    self._touch(key_idx, now)
+                if self._dirty is not None and n:
+                    self._dirty[key_idx[:n]] = True
 
     def _partition_keys(self, stream_id: str, staged: ev.StagedBatch):
         """(key columns, row validity) of a partitioned batch: the
@@ -781,13 +833,18 @@ class PatternQueryRuntime(_MeshResolved):
         return ([staged.cols[i] for i in p.partition_positions[stream_id]],
                 staged.valid)
 
-    def _step_and_emit(self, step, now: int, *batch_args) -> None:
-        """Dispatch one sequential step on the state, rebind what it
-        returns (the state was donated) and hand its emission on."""
+    def _step(self, step, *batch_args):
+        """Dispatch one sequential step on the state and rebind what it
+        returns (the state was donated); its (emission, wake)."""
         pstate, sel_state = self.state
         pstate, sel_state, out, wake = _phases.dispatch(
             self, step, pstate, sel_state, *batch_args, self._in_tabs())
         self.state = (pstate, sel_state)
+        return out, wake
+
+    def _step_and_emit(self, step, now: int, *batch_args) -> None:
+        """One sequential step, its emission handed on."""
+        out, wake = self._step(step, *batch_args)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def _shard_prep(self, stream_id: str, staged: ev.StagedBatch,

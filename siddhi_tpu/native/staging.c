@@ -225,3 +225,48 @@ int32_t sg_group_fill(const int32_t *slots, const uint8_t *valid, int64_t n,
     return (n_uniq > 0 &&
             touched[n_uniq - 1] == touched[0] + (int32_t)(n_uniq - 1)) ? 1 : 0;
 }
+
+/* Tiered fill: the touched keys split by their count into n_tiers classes
+ * — class t holds the keys with count <= hi[t] that no earlier class took
+ * (hi ascending; the last class takes the rest) — each class its own
+ * [Kb[t], E[t]] rectangle laid as sg_group_fill lays the one: slots
+ * ascending, a key's events along E in batch order.  key_idx and sel are
+ * the classes' buffers end to end, class t's rows from key_off[t] and
+ * its cells from sel_off[t].  A send whose hottest key has a thousand
+ * events and whose other keys have one is then laid out in cells of the
+ * order of its events, not keys x hottest count.  Leaves cnt clean. */
+void sg_group_fill_tiers(const int32_t *slots, const uint8_t *valid,
+                         int64_t n, int32_t *cnt, int32_t *rank,
+                         int32_t *touched, int64_t n_uniq,
+                         const int32_t *hi, int64_t n_tiers,
+                         const int64_t *Kb, const int64_t *E,
+                         const int64_t *key_off, const int64_t *sel_off,
+                         int32_t pad, int32_t *key_idx, int32_t *sel) {
+    uint32_t *tmp = (uint32_t *)malloc((size_t)n_uniq * 4);
+    radix_sort_u32((uint32_t *)touched, n_uniq, tmp);
+    free(tmp);
+    const int64_t last = n_tiers - 1;
+    int64_t rows = key_off[last] + Kb[last];
+    int64_t cells = sel_off[last] + Kb[last] * E[last];
+    for (int64_t g = 0; g < rows; g++) key_idx[g] = pad;
+    memset(sel, 0xFF, (size_t)cells * 4);
+    int64_t fill[16] = {0};
+    for (int64_t k = 0; k < n_uniq; k++) {
+        int32_t s = touched[k];
+        int64_t t = 0;
+        while (t < last && cnt[s] > hi[t]) t++;
+        int64_t g = key_off[t] + fill[t]++;
+        key_idx[g] = s;
+        rank[s] = (int32_t)g;
+        cnt[s] = 0;                               /* reuse as within-counter */
+    }
+    for (int64_t i = 0; i < n; i++) {
+        int32_t s = slots[i];
+        if (s < 0 || (valid && !valid[i])) continue;
+        int64_t g = rank[s], t = 0;
+        while (t < last && g >= key_off[t + 1]) t++;
+        sel[sel_off[t] + (g - key_off[t]) * E[t] + cnt[s]++] = (int32_t)i;
+    }
+    for (int64_t k = 0; k < n_uniq; k++)
+        cnt[touched[k]] = 0;                      /* leave cnt clean */
+}

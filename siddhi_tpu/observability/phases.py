@@ -36,12 +36,17 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
   stage       pad/adopt or pack_np into a StagedBatch        stage_host
   route_keys  key -> slot routing, grouping, ts-wire build;
               `grouped` = view | take: the one-chip pattern
-              path's columns put in the per-key order        stage_host
+              path's columns put in the per-key order;
+              `tiers`, `cells`, `max_e`, `ticks`: the send's
+              [Kb, E] layout (LAYOUT_STATS)                  stage_host
   shard_group the router's [n, Kb, E] regroup of a sharded
               send, nested in route_keys                     stage_host
   obs_feed    state observatory feed, liveness, dirty marks  stage_host
   h2d         every host->device upload (host wall)          h2d
-  dispatch    the jitted step call (submit only)             dispatch_submit
+  dispatch    the jitted step call (submit only); `tier`
+              on it and on its uploads, where a send was
+              laid out as several tiers (tier_scope) — their
+              emissions leave as one (`pattern_merge`)       dispatch_submit
   fetch       every device_get on a delivery path            d2h_drain
   demux       header decode, ts-order restore, unpack        demux
   sink        callbacks, table op, rate limit, re-publish    sink
@@ -85,6 +90,10 @@ SPAN_PHASE = {"stage": "stage_host", "route_keys": "stage_host",
               "dispatch": "dispatch_submit", "fetch": "d2h_drain",
               "demux": "demux", "sink": "sink"}
 STAGE_PARTS = ("stage", "route_keys", "shard_group", "obs_feed")
+# what `route_keys` says of a partitioned pattern send's device layout:
+# the [Kb, E] rectangles it was split into, their cells, the hottest
+# key's events, the sum of the rectangles' E (the scan ticks it costs)
+LAYOUT_STATS = ("tiers", "cells", "max_e", "ticks")
 SPAN_PREFIX = "siddhi:"
 
 
@@ -94,8 +103,8 @@ class PhaseProfiler:
     hot-path entry — a dict upsert under a short lock, no allocation
     beyond the first sample of a (query, phase) pair."""
 
-    __slots__ = ("_lock", "_ns", "_count", "_grouped", "_dispatches",
-                 "_sampled")
+    __slots__ = ("_lock", "_ns", "_count", "_grouped", "_layout",
+                 "_dispatches", "_sampled")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -106,21 +115,28 @@ class PhaseProfiler:
         # (query, phase, part) -> {"view" | "take": samples}: how the
         # pattern path's route_keys span grouped the columns
         self._grouped: Dict[tuple, Dict[str, int]] = {}
+        # (query, phase, part) -> sums of the same span's LAYOUT_STATS
+        self._layout: Dict[tuple, Dict[str, int]] = {}
         self._dispatches: Dict[str, int] = {}  # query -> dispatch counter
         self._sampled: Dict[str, int] = {}     # query -> fenced dispatches
 
     def add(self, query: str, phase: str, ns: int,
-            part: Optional[str] = None,
-            grouped: Optional[str] = None) -> None:
+            part: Optional[str] = None, meta: Optional[Dict] = None) -> None:
+        """`meta`: the span's stats; `grouped` is tallied by value and the
+        LAYOUT_STATS are summed (both are `route_keys`'s)."""
         if ns <= 0:
             return
         key = (query, phase, part)
         with self._lock:
             self._ns[key] = self._ns.get(key, 0) + int(ns)
             self._count[key] = self._count.get(key, 0) + 1
-            if grouped is not None:
+            if meta and "grouped" in meta:
                 tally = self._grouped.setdefault(key, {})
-                tally[grouped] = tally.get(grouped, 0) + 1
+                tally[meta["grouped"]] = tally.get(meta["grouped"], 0) + 1
+            if meta and "cells" in meta:
+                sums = self._layout.setdefault(key, {})
+                for stat in LAYOUT_STATS:
+                    sums[stat] = sums.get(stat, 0) + int(meta.get(stat, 0))
 
     def should_sample(self, query: str, every: int) -> bool:
         """Per-query dispatch modulus for the deep mode: True on every
@@ -145,11 +161,13 @@ class PhaseProfiler:
         `stage` part's samples (one a staged batch), or the most-sampled
         part's where a query never sees one (timer-fired steps).  The
         `route_keys` part of a pattern query also lists `grouped`:
-        {"view": n, "take": n}, its spans counted by that stat."""
+        {"view": n, "take": n}, its spans counted by that stat, and
+        `layout`: the sums of its spans' LAYOUT_STATS."""
         with self._lock:
             ns = dict(self._ns)
             count = dict(self._count)
             grouped = {k: dict(v) for k, v in self._grouped.items()}
+            layout = {k: dict(v) for k, v in self._layout.items()}
             sampled = dict(self._sampled)
         queries: Dict[str, Dict] = {}
         for (q, p, part), total in ns.items():
@@ -164,6 +182,8 @@ class PhaseProfiler:
                                                      "count": n}
                 if (q, p, part) in grouped:
                     ent["parts"][part]["grouped"] = grouped[(q, p, part)]
+                if (q, p, part) in layout:
+                    ent["parts"][part]["layout"] = layout[(q, p, part)]
         for q, phases in queries.items():
             for ent in phases.values():
                 parts = ent.get("parts")
@@ -181,6 +201,7 @@ class PhaseProfiler:
             self._ns.clear()
             self._count.clear()
             self._grouped.clear()
+            self._layout.clear()
             self._dispatches.clear()
             self._sampled.clear()
 
@@ -219,6 +240,28 @@ class batch_scope:
 
     def __exit__(self, *exc):
         _tls.batch = self.prev
+        return False
+
+
+class tier_scope:
+    """The spans a thread opens inside carry `tier=<i>`: the i-th [Kb, E]
+    tier of a send the pattern path laid out as several (runtime
+    `PatternQueryRuntime.process_staged`), so its uploads and its dispatch
+    can be told apart (the send's emission is one, and carries none).
+    None — a send of one rectangle — adds nothing."""
+
+    __slots__ = ("tier", "prev")
+
+    def __init__(self, tier: Optional[int]):
+        self.tier = tier
+
+    def __enter__(self):
+        self.prev = getattr(_tls, "tier", None)
+        _tls.tier = self.tier
+        return self
+
+    def __exit__(self, *exc):
+        _tls.tier = self.prev
         return False
 
 
@@ -293,7 +336,7 @@ class _Timed:
             part = self.name if phase_name == "stage_host" else None
             add = self.stats.phases.add
             for q in self.queries:
-                add(q, phase_name, own, part, self.meta.get("grouped"))
+                add(q, phase_name, own, part, self.meta)
         tr = _tracing.active()
         if tr is not None:
             meta = self.meta
@@ -314,6 +357,9 @@ def phase(stats, query, name: str, mult: int = 1, **meta):
     `jax.profiler.TraceAnnotation` — nothing else runs; stats known only
     at the end go on with `.set_metadata(rows=n)` either way."""
     queries = (query,) if isinstance(query, str) else (query or ())
+    tier = getattr(_tls, "tier", None)
+    if tier is not None:
+        meta["tier"] = tier
     ann = TraceAnnotation(
         SPAN_PREFIX + name, q=queries[0] if queries else "",
         batch=getattr(_tls, "batch", 0), **meta)

@@ -319,7 +319,7 @@ def test_identity_block_copies_nothing_and_uploads_what_the_parent_did(
         assert not errors
         assert [(s["memo_hit"], s["grouped"]) for s in stats] == \
             [(0, "view"), (1, "view"), (1, "view")]
-        assert next(iter(qr._block_cache.values()))[4] is True
+        assert next(iter(qr._block_cache.values()))[1][0][2] is True
         # the grouped buffers ARE the staged ones
         identity, (cols, delta), (staged, staged_delta) = grouped_log[-1]
         assert identity and delta is staged_delta
@@ -368,3 +368,158 @@ def test_is_identity_sel_rejects_near_misses():
     # a padding cell carries row 0's value: the clipped gather's
     np.testing.assert_array_equal(d.reshape(16, 8)[:, 4:], 0)
     np.testing.assert_array_equal(d.reshape(16, 8)[:, :4], ident)
+
+
+# -- a skewed send: tiers against the one rectangle ---------------------------
+
+def zipf_send(seed, ts, n=1024):
+    """1,024 events whose keys follow Zipf(1.2) over the 2,048 bound keys
+    (one key ~230 times, hundreds once), stages alternating per key."""
+    rng = np.random.default_rng(seed)
+    cdf = np.cumsum(np.arange(1, KEYS + 1, dtype=np.float64) ** -1.2)
+    k = PERM[np.searchsorted(cdf / cdf[-1], rng.random(n), side="right")]
+    order = np.argsort(k, kind="stable")
+    within = np.empty(n, np.int64)
+    _, first, counts = np.unique(k[order], return_index=True,
+                                 return_counts=True)
+    within[order] = np.arange(n) - np.repeat(first, counts)
+    return rows(k, 1 + within % 2, ts + np.arange(n) // 256,
+                price=rng.random(n).astype(np.float32))
+
+
+def test_a_tiered_send_equals_the_one_rectangle_send(monkeypatch):
+    """The same skewed sends through two runtimes, one laying each out as
+    tiers (three dispatches of one step), one as the single [Kb, E]
+    rectangle: the same events delivered, the state planes equal bit for
+    bit, and the tiers' spans say what they are."""
+    text = PART_QL.replace("@emit(rows='4')", "@emit(rows='128')") % \
+        "@app:statistics('BASIC')"
+    warm = [rows(range(KEYS), 0, T0 - 10)]
+    sends = [zipf_send(s, T0 + 10 * s) for s in range(3)]
+    calls = {}
+    for mode in ("tiers", "rectangle"):
+        if mode == "rectangle":
+            from siddhi_tpu.core import keyslots
+            monkeypatch.setattr(keyslots, "_TIER_MIN_CELLS", 1 << 40)
+        m, rt, qr, got, errors, log = deploy(text, gathering=False)
+        try:
+            drive(rt, qr, warm)
+            del log[:], got[:]
+            drive(rt, qr, sends)
+            assert not errors, errors[:1]
+            node = rt.phase_report()["queries"]["q"]["phases"]["stage_host"]
+            calls[mode] = (len(log), sorted(got),
+                           jax.device_get(qr.state),
+                           node["parts"]["route_keys"]["layout"],
+                           rt.statistics().get("counters", {}))
+        finally:
+            m.shutdown()
+    n_t, got_t, state_t, lay_t, ctr_t = calls["tiers"]
+    n_r, got_r, state_r, lay_r, ctr_r = calls["rectangle"]
+    assert (n_t, n_r) == (9, 3)                  # three tiers a send
+    assert got_t == got_r and len(got_t) > 500
+    assert ctr_t.get("q.dropped", 0) == ctr_r.get("q.dropped", 0) == 0
+    for x, y in zip(jax.tree.leaves(state_t), jax.tree.leaves(state_r)):
+        np.testing.assert_array_equal(x, y)
+    # warm-up send + three sends; the rectangle is [512, 256] a send
+    assert lay_r["tiers"] == 4 and lay_t["tiers"] == 1 + 9
+    assert lay_r["cells"] - lay_t["cells"] > 3 * 100000
+    assert lay_t["max_e"] == lay_r["max_e"] > 3 * 128
+    assert lay_t["ticks"] <= 1 + 2 * lay_t["max_e"]
+
+
+def test_a_tiered_send_is_delivered_as_one_emission_in_timestamp_order(
+        monkeypatch):
+    """A skewed send's tiers leave as ONE emission: one header and one
+    payload fetch pair, one batch callback, one event callback whose rows
+    are in timestamp order over ALL of the send's keys — the sequence the
+    one-rectangle send delivers, not its multiset alone."""
+    text = PART_QL.replace("@emit(rows='4')", "@emit(rows='128')") % ""
+    warm = [rows(range(KEYS), 0, T0 - 10)]
+    sends = []
+    for s in range(3):
+        c, ts = zipf_send(s, T0 + 5000 * s)
+        sends.append((c, T0 + 5000 * s + np.arange(ts.size)))
+    seen = {}
+    for mode in ("tiers", "rectangle"):
+        if mode == "rectangle":
+            from siddhi_tpu.core import keyslots
+            monkeypatch.setattr(keyslots, "_TIER_MIN_CELLS", 1 << 40)
+        m, rt, qr, got, errors, log = deploy(text, gathering=False)
+        calls, batches, fetches = [], [], []
+        rt.add_callback("q", lambda ts, i, o: calls.append(
+            [(int(e.timestamp), *[float(x) for x in e.data])
+             for e in (i or [])]))
+        rt.add_batch_callback("q", lambda now, payload: batches.append(
+            int(payload["n_valid"])))
+        real = rtm._phases.fetch
+        monkeypatch.setattr(
+            rtm._phases, "fetch",
+            lambda st, q, what, tree, mult=1: fetches.append(what) or
+            real(st, q, what, tree, mult))
+        try:
+            drive(rt, qr, warm)
+            del log[:], got[:], calls[:], batches[:], fetches[:]
+            drive(rt, qr, sends)
+            assert not errors, errors[:1]
+            seen[mode] = (len(log), calls, batches, fetches)
+        finally:
+            monkeypatch.setattr(rtm._phases, "fetch", real)
+            m.shutdown()
+    steps, calls, batches, fetches = seen["tiers"]
+    assert steps == 9 and seen["rectangle"][0] == 3
+    # one emission a send: one event callback, one batch callback, and
+    # the header + the rows of the event delivery, as a rectangle's
+    assert len(calls) == len(batches) == 3
+    assert fetches == seen["rectangle"][3] == ["header", "rows"] * 3
+    assert batches == [len(c) for c in calls] == seen["rectangle"][2]
+    for call in calls:
+        stamps = [row[0] for row in call]
+        assert stamps == sorted(stamps)
+        # an event may release several partials: equal stamps, one key
+        assert len({row[:2] for row in call}) == len(set(stamps))
+        assert len({row[1] for row in call}) > 50       # over many keys
+    assert calls == seen["rectangle"][1]
+
+
+def test_a_tier_that_fails_loses_no_match_of_the_tiers_before_it():
+    """A send is dispatched tier by tier, the hottest keys first.  Where a
+    later tier's step raises, the earlier tiers have advanced their keys'
+    state: what they matched is delivered before the error is reported,
+    the failing tier's keys and the ones after it are untouched, and the
+    next send runs as if nothing had happened to them."""
+    text = PART_QL.replace("@emit(rows='4')", "@emit(rows='128')") % ""
+    warm = [rows(range(KEYS), 0, T0 - 10)]
+    send = zipf_send(0, T0)
+    # what every key's events match by themselves (a key is in one tier)
+    m, rt, qr, want, errors, log = deploy(text, gathering=False)
+    try:
+        drive(rt, qr, warm + [send])
+        assert not errors and len(log) == 1 + 3
+        hot_rows = int(log[1][2][0])      # the hot tier is dispatched first
+    finally:
+        m.shutdown()
+    m, rt, qr, got, errors, log = deploy(text, gathering=False)
+    try:
+        drive(rt, qr, warm)
+        del log[:]
+        p, n = qr.planned, [0]
+
+        def second_fails(fn):
+            def call(*args):
+                n[0] += 1
+                if n[0] == 2:
+                    raise RuntimeError("tier 2 of 3 cannot run")
+                return fn(*args)
+            call._siddhi_role = fn._siddhi_role
+            return call
+        qr.planned = dataclasses.replace(
+            p, steps={s: second_fails(f) for s, f in p.steps.items()})
+        drive(rt, qr, [send])
+        assert len(errors) == 1 and "tier 2 of 3" in str(errors[0])
+        assert len(log) == 1 and 0 < hot_rows == len(got) < len(want)
+        assert set(got) <= set(want)
+        hot_keys = {row[1] for row in got}
+        assert not hot_keys & {row[1] for row in set(want) - set(got)}
+    finally:
+        m.shutdown()

@@ -259,3 +259,144 @@ def test_apply_journal_rebind_wins(backend):
                        lookup_only=True)[0] == 0
     assert a.slots_for([np.array([1], np.int64)],
                        lookup_only=True)[0] == -1
+
+
+# -- tiered grouping: a skewed batch as a few [Kb, E] rectangles -----------
+
+def _zipf_slots(seed, n=8192, n_slots=1 << 16, exponent=1.2):
+    """A batch whose keys follow Zipf: one slot with ~n/5 events, most
+    with one; about 2 % of the rows invalid."""
+    rng = np.random.default_rng(seed)
+    cdf = np.cumsum(np.arange(1, n_slots + 1, dtype=np.float64) ** -exponent)
+    ranks = np.searchsorted(cdf / cdf[-1], rng.random(n), side="right")
+    return (rng.permutation(n_slots)[ranks].astype(np.int32),
+            rng.random(n) > 0.02)
+
+
+def _rows_by_key(groups, pad):
+    """{slot: its batch rows in order} over (key_idx, sel) rectangles; a
+    key in two rectangles, or unsorted within one, fails here."""
+    out = {}
+    for key_idx, sel in groups:
+        live = key_idx[key_idx < pad]
+        assert (np.diff(live) > 0).all()
+        assert (key_idx[live.size:] == pad).all()
+        assert (sel[live.size:] == -1).all()
+        for i, k in enumerate(live.tolist()):
+            assert k not in out
+            row = sel[i]
+            n = int((row >= 0).sum())
+            assert (row[n:] == -1).all()
+            out[k] = row[:n].tolist()
+    return out
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_tiered_grouping_equals_the_one_rectangle_row_for_row(backend, seed):
+    slots, valid = _zipf_slots(seed)
+    pad = 1 << 16
+    tiers, maxc = ks.tier_events_by_key(slots, valid, pad=pad)
+    key_idx, sel, _ = group_events_by_key(slots, valid, pad=pad)
+    assert len(tiers) == 3
+    assert _rows_by_key(tiers, pad) == _rows_by_key([(key_idx, sel)], pad)
+    counts = (sel >= 0).sum(1)
+    assert maxc == counts.max() > 1024
+    # layout cells of the order of the events, not keys x hottest count;
+    # ticks (the sum of the E) under twice the hottest key's count
+    cells = sum(s.size for _, s in tiers)
+    assert cells <= 32 * slots.size < sel.size
+    assert sum(s.shape[1] for _, s in tiers) <= 2 * maxc
+    # classes by count: at most 4, at most 32, the rest
+    for (lo, hi), (k, s) in zip(((0, 4), (4, 32), (32, maxc)), tiers):
+        c = (s >= 0).sum(1)[k < pad]
+        assert lo < c.min() and c.max() <= hi
+
+
+# (exponent, key space): the layout's promises at skews and hot-set sizes
+# other than the Zipf(1.2) cell's — `cells an event` is what the fixed
+# cuts leave, the open top class's padding included (keyslots._TIER_CUTS)
+_SKEWS = {(0.5, 1 << 12): 5, (0.8, 1 << 12): 8, (0.8, 1 << 17): 12,
+          (1.0, 1 << 17): 12, (1.0, 1 << 24): 14, (1.5, 1 << 12): 35,
+          (1.5, 1 << 24): 33, (2.0, 1 << 17): 65, (3.0, 1 << 12): 9}
+
+
+@pytest.mark.parametrize("exponent,n_slots", sorted(_SKEWS))
+def test_tiers_hold_their_promises_at_other_skews(backend, exponent,
+                                                  n_slots):
+    slots, valid = _zipf_slots(7, n_slots=n_slots, exponent=exponent)
+    tiers, maxc = ks.tier_events_by_key(slots, valid, pad=n_slots)
+    key_idx, sel, _ = group_events_by_key(slots, valid, pad=n_slots)
+    assert _rows_by_key(tiers, n_slots) == \
+        _rows_by_key([(key_idx, sel)], n_slots)
+    assert maxc == (sel >= 0).sum(1).max()
+    cells = sum(s.size for _, s in tiers)
+    assert 2 <= len(tiers) <= 3 and 2 * cells < sel.size
+    assert cells / slots.size <= _SKEWS[exponent, n_slots]
+    assert sum(s.shape[1] for _, s in tiers) <= 36 + 2 * maxc
+    for k, s in tiers:                  # the lower classes: E at most 32
+        c = (s >= 0).sum(1)[k < n_slots]
+        assert s.shape[1] < 2 * c.max()
+        assert s.shape[1] <= 32 or s is tiers[-1][1]
+
+
+def test_tiers_native_equals_numpy(monkeypatch):
+    if ks.LIB is None:
+        pytest.skip("native staging library unavailable")
+    for seed in (4, 5):
+        slots, valid = _zipf_slots(seed)
+        nat, maxc = ks.tier_events_by_key(slots, valid, pad=1 << 16)
+        with monkeypatch.context() as mp:
+            mp.setattr(ks, "LIB", None)
+            py, maxc_py = ks.tier_events_by_key(slots, valid, pad=1 << 16)
+        assert maxc == maxc_py and len(nat) == len(py) == 3
+        for (k1, s1), (k2, s2) in zip(nat, py):
+            assert k1.dtype == k2.dtype == s1.dtype == s2.dtype == np.int32
+            np.testing.assert_array_equal(k1, k2)
+            np.testing.assert_array_equal(s1, s2)
+
+
+@pytest.mark.parametrize("times", [1, 4, 16])
+def test_a_uniform_batch_is_one_tier_identical_to_the_rectangle(backend,
+                                                                times):
+    """Keys that all have one count: one rectangle, the very [Kb, E] and
+    cells `group_events_by_key` gives."""
+    slots = np.repeat(np.arange(100, 100 + 2048, dtype=np.int32), times)
+    valid = np.ones(slots.size, np.bool_)
+    tiers, maxc = ks.tier_events_by_key(slots, valid, pad=1 << 16)
+    key_idx, sel, _ = group_events_by_key(slots, valid, pad=1 << 16)
+    assert len(tiers) == 1 and maxc == times
+    np.testing.assert_array_equal(tiers[0][0], key_idx)
+    np.testing.assert_array_equal(tiers[0][1], sel)
+    assert sel.shape == (4096, times)
+
+
+def test_a_small_or_mildly_skewed_batch_keeps_the_one_rectangle(backend):
+    # far-apart counts, but a rectangle of 8 x 8 cells: not worth a tier
+    slots = np.array([3] * 6 + [5] * 2, np.int32)
+    tiers, maxc = ks.tier_events_by_key(slots, np.ones(8, np.bool_), pad=64)
+    assert len(tiers) == 1 and tiers[0][1].shape == (8, 8) and maxc == 6
+    # a large one whose counts are a factor of two apart: tiers would
+    # have over half its cells
+    slots = np.concatenate([
+        np.repeat(np.arange(30000, dtype=np.int32), 4),
+        np.repeat(np.arange(30000, 60000, dtype=np.int32), 8)])
+    tiers, _ = ks.tier_events_by_key(slots, np.ones(slots.size, np.bool_),
+                                     pad=1 << 16)
+    assert len(tiers) == 1 and tiers[0][1].shape == (65536, 8)
+    # nothing valid at all
+    tiers, maxc = ks.tier_events_by_key(np.array([1, 2], np.int32),
+                                        np.zeros(2, np.bool_), pad=8)
+    assert len(tiers) == 1 and maxc == 0 and (tiers[0][1] == -1).all()
+
+
+def test_slots_and_tiers_fused_matches_two_pass(backend):
+    a = SlotAllocator(1 << 16, "t")
+    slots, valid = _zipf_slots(6)
+    keys = slots.astype(np.int64) * 7 + 3
+    got, tiers, maxc = a.slots_and_tiers([keys], valid, pad=1 << 16)
+    two, maxc2 = ks.tier_events_by_key(got, valid, pad=1 << 16)
+    assert maxc == maxc2 and len(tiers) == len(two) == 3
+    assert _rows_by_key(tiers, 1 << 16) == _rows_by_key(two, 1 << 16)
+    # the scratch is left clean: a second, uniform batch groups as ever
+    again, t2, _ = a.slots_and_tiers([keys[:64]], None, pad=1 << 16)
+    assert len(t2) == 1 and (again == got[:64])[valid[:64]].all()

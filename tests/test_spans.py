@@ -335,26 +335,81 @@ def test_basic_phase_report_counts_each_phase_once_per_send(monkeypatch):
     assert rep["queries"]["q"]["accounted"] > 0.5
 
 
-def test_self_time_is_the_span_minus_its_children_on_the_thread():
+class _DrivenClock:
+    """`phases.time`, its clock driven by the test: a loaded machine
+    cannot stretch a span."""
+
+    def __init__(self):
+        self.ns = 1_000_000_000
+
+    def perf_counter_ns(self):
+        return self.ns
+
+    def pass_ms(self, ms):
+        self.ns += int(ms * 1e6)
+
+
+def _stats():
     class Stats:
         enabled = True
         phases = ph.PhaseProfiler()
+    return Stats()
 
-    st = Stats()
-    import time
+
+def test_self_time_is_the_span_minus_its_children_on_the_thread(monkeypatch):
+    clock = _DrivenClock()
+    monkeypatch.setattr(ph, "time", clock)
+    st = _stats()
     with ph.phase(st, "q", "demux"):
-        time.sleep(0.002)
+        clock.pass_ms(2)
         with ph.phase(st, "q", "fetch", what="rows"):
-            time.sleep(0.006)
+            clock.pass_ms(6)
         with ph.phase(st, ("q", "r"), "sink", mult=2):
-            time.sleep(0.004)
+            clock.pass_ms(4)
     snap = st.phases.snapshot()["queries"]
     q = snap["q"]
-    assert q["d2h_drain"]["ns"] >= 6e6 and q["sink"]["ns"] >= 2 * 4e6
-    # demux's own: its wall (>= 12 ms) minus fetch and sink (once each)
-    assert 2e6 <= q["demux"]["ns"] < 6e6
+    # fetch's wall is its own; sink's counts `mult` times, for each of
+    # the queries it is charged to
+    assert q["d2h_drain"]["ns"] == 6e6 and q["sink"]["ns"] == 2 * 4e6
+    # demux's own: its wall (12 ms) minus fetch and sink (once each)
+    assert q["demux"]["ns"] == 2e6
     assert snap["r"]["sink"]["ns"] == q["sink"]["ns"]
     assert [v["count"] for v in q.values()] == [1, 1, 1]
+
+
+def test_route_keys_layout_stats_are_summed_and_tiers_are_named(
+        monkeypatch):
+    """`route_keys`' `tiers` / `cells` / `max_e` / `ticks` are summed under
+    stage_host's `route_keys` part (a span without them adds nothing), and
+    inside a `tier_scope` every span carries `tier`."""
+    clock = _DrivenClock()
+    monkeypatch.setattr(ph, "time", clock)
+    st = _stats()
+    for tiers, cells, max_e, ticks in ((1, 8192, 4, 4),
+                                       (3, 163840, 1514, 2084)):
+        with ph.phase(st, "q", "route_keys") as sp:
+            clock.pass_ms(1)
+            sp.set_metadata(keys=7, tiers=tiers, cells=cells, max_e=max_e,
+                            ticks=ticks, grouped="take")
+    with ph.phase(st, "q", "route_keys") as sp:      # the sharded path's
+        clock.pass_ms(1)
+        sp.set_metadata(keys=7)
+    part = st.phases.snapshot()["queries"]["q"]["stage_host"]["parts"][
+        "route_keys"]
+    assert part["count"] == 3 and part["ns"] == 3e6
+    assert part["layout"] == {"tiers": 4, "cells": 172032, "max_e": 1518,
+                              "ticks": 2088}
+    assert part["grouped"] == {"take": 2}
+    assert set(part["layout"]) == set(ph.LAYOUT_STATS)
+    # tier_scope: the metadata of what opens inside, and nothing outside
+    with ph.tier_scope(2):
+        with ph.phase(st, "q", "dispatch", step="pattern_step") as sp:
+            assert sp.meta["tier"] == 2
+        with ph.tier_scope(None):
+            with ph.phase(st, "q", "fetch", what="header") as sp:
+                assert "tier" not in sp.meta
+    with ph.phase(st, "q", "dispatch", step="pattern_step") as sp:
+        assert "tier" not in sp.meta
 
 
 # -- (f) every jitted step's XLA module is jit_<role> ---------------------------
