@@ -33,6 +33,20 @@ from .steputil import jit_step, pmin_i64
 _FORCE_SCAN = False
 
 
+def split64(x):
+    """(low words, high words) of an int64 array, as u32.  Both halves
+    are in u32's range before the convert, so it is exact on every
+    backend (no reliance on a wrapping narrow)."""
+    return ((x & 0xFFFFFFFF).astype(jnp.uint32),
+            lax.shift_right_logical(
+                x, jnp.asarray(32, jnp.int64)).astype(jnp.uint32))
+
+
+def join64(lo, hi):
+    """The int64 array of `split64`'s two planes."""
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+
+
 class StatePacker:
     """Pack a per-key state pytree (array leaves with leading K axis) into
     three arrays stored [W, K] (key axis MINOR): `b32`, one i32 blob
@@ -127,17 +141,13 @@ class StatePacker:
             jnp.zeros((0, K), jnp.int32)
         b64 = jnp.concatenate(parts64, axis=0) if parts64 else \
             jnp.zeros((0, K), jnp.int64)
-        # both halves are in u32's range before the convert, so it is
-        # exact on every backend (no reliance on a wrapping narrow)
-        lo64 = (b64 & 0xFFFFFFFF).astype(jnp.uint32)
-        hi64 = lax.shift_right_logical(
-            b64, jnp.asarray(32, jnp.int64)).astype(jnp.uint32)
+        lo64, hi64 = split64(b64)
         return b32, lo64, hi64, tuple(scal)
 
     def unpack(self, b32, lo64, hi64, scalars):
         leaves = []
         K = b32.shape[1]
-        b64 = (hi64.astype(jnp.int64) << 32) | lo64.astype(jnp.int64)
+        b64 = join64(lo64, hi64)
         for kind, dtype, head, off, width in self.recs:
             if kind == "scalar":
                 leaves.append(scalars[off])
@@ -230,10 +240,11 @@ class PlannedPatternQuery:
     # hands them the columns already in the per-key [Kb, E] order
     # (runtime._group_columns) and they gather nothing
     grouped_input: bool = False
-    # ((emission, wake) of each tier) -> (emission, wake): the tiers of a
-    # skewed send (runtime process_staged) leave as the one emission of
-    # the send (_merge_emissions); None where sends are never tiered
-    merge_emissions: Optional[Callable] = None
+    # the sequential scan programs (`steps`, `dense_steps`, the sharded
+    # step) hand their emission over as a BandedEmission: rank bands on
+    # the u32 wire, so delivery fetches the ranks a send used and no
+    # 64-bit array.  True wherever there is a key axis to cut ranks over
+    banded_emission: bool = False
     # False when the per-key emission cap is an implicit default: overflow
     # then raises instead of dropping rows (@emit(rows=N) opts into capping)
     emit_explicit: bool = True
@@ -281,9 +292,11 @@ class PlannedPatternQuery:
             "dense_slot_fast_path": self.dense_steps is not None,
             # a partitioned send whose keys' event counts are far apart
             # is laid out as tiers, each a dispatch of the same step,
-            # their emissions merged into the send's one (runtime
+            # their rank bands the send's one emission (runtime
             # process_staged; keyslots._tier_plan has the rule)
-            "tiered_send_layout": self.merge_emissions is not None,
+            "tiered_send_layout": bool(self.grouped_input and
+                                       self.partition_positions),
+            "banded_emission": self.banded_emission,
             "timer_step": self.timer_step is not None,
         }
         d["emission_cap_rows"] = plan_facts.render_cap(self.compact_rows)
@@ -357,7 +370,8 @@ def plan_pattern_query(
 
     packer = StatePacker(pexec.init_state(1))
 
-    def make_step(stream_id: str, dense: bool = False):
+    def make_step(stream_id: str, dense: bool = False,
+                  banded: bool = False):
         schema = schemas[stream_id]
 
         def step(packed, sel_state, cols, ts, sel_idx, key_ref, now,
@@ -412,22 +426,23 @@ def plan_pattern_query(
 
             sel_state, out, wake = _emit_matches(
                 pexec, sel, spec, emits, ord_, sel_state, sub, now,
-                key_idx=key_idx, compact_rows=compact_rows)
+                key_idx=key_idx, compact_rows=compact_rows, banded=banded)
             return (*arrays, nscal), sel_state, out, wake
 
         return step
 
     # raw_steps gather on the device: what ships an UNGROUPED batch — the
-    # mesh steps, the @fuse stacks — runs these.  The one-chip sequential
-    # programs take the body itself: the host has grouped (_jit_sequential)
-    bodies = {sid: make_step(sid) for sid in spec.stream_ids}
-    raw_steps = {sid: _gathering(body) for sid, body in bodies.items()}
+    # mesh steps, the @fuse stacks — runs these, and they keep the flat
+    # emission (a @fuse stack is sliced per batch).  The sequential
+    # programs — one-chip (the host has grouped: _jit_sequential) and
+    # sharded — hand over rank bands wherever there is a key axis
+    raw_steps = {sid: _gathering(make_step(sid)) for sid in spec.stream_ids}
+    banded = partition_positions is not None
 
     dense_steps = None
     step_bodies = None
     shard_fused_steps = None
     grouped_input = False
-    merge_emissions = None
     if mesh is None and partition_positions is None and \
             block_eligible(spec) and not _FORCE_SCAN:
         # single-key simple chain: the sequential E-tick scan degrades to
@@ -441,19 +456,16 @@ def plan_pattern_query(
     elif mesh is None:
         step_bodies = raw_steps
         grouped_input = True
-        steps = {sid: _jit_sequential(body, name, "pattern_step",
-                                      grouped=True)
-                 for sid, body in bodies.items()}
-        dense_steps = {sid: _jit_sequential(make_step(sid, dense=True),
-                                            name, "pattern_dense",
-                                            grouped=True)
-                       for sid in spec.stream_ids}
-        if partition_positions is not None:
-            merge_emissions = jit_step(_merge_emissions, owner=name,
-                                       role="pattern_merge")
+        steps = {sid: _jit_sequential(make_step(sid, banded=banded), name,
+                                      "pattern_step", grouped=True)
+                 for sid in spec.stream_ids}
+        dense_steps = {sid: _jit_sequential(
+            make_step(sid, dense=True, banded=banded), name,
+            "pattern_dense", grouped=True) for sid in spec.stream_ids}
     else:
-        steps = {sid: _shard_step(body, mesh, packer, sel, owner=name)
-                 for sid, body in raw_steps.items()}
+        steps = {sid: _shard_step(
+            _gathering(make_step(sid, banded=banded)), mesh, packer, sel,
+            owner=name, banded=banded) for sid in spec.stream_ids}
         # @fuse over the mesh: scan-of-K-batches inside the shard_map
         # (fusion._dispatch_pattern routes stacks here)
         shard_fused_steps = {
@@ -512,38 +524,36 @@ def plan_pattern_query(
         partition_positions=partition_positions,
         partition_key_fns=partition_key_fns,
         raw_steps=raw_steps, mesh=mesh,
-        grouped_input=grouped_input, merge_emissions=merge_emissions,
+        grouped_input=grouped_input,
+        banded_emission=banded,
         emit_explicit=emit_explicit, selector_exec=sel,
         emits_uuid=pexec.scope.uses_uuid,
         compact_rows=compact_rows, step_bodies=step_bodies,
         shard_fused_steps=shard_fused_steps)
 
 
-def _merge_emissions(parts):
-    """The `_emit_matches` results of a send's tiers, `((out, wake), ...)`,
-    as the one result of the send: the counts summed, the tiers' rows end
-    to end (each tier's stay rank-major; delivery restores the timestamp
-    order over all of them with the sort it already has), the earliest
-    wake.  A copy of the tiers' row slots on the device, so the send
-    costs one header fetch and one payload, as a one-rectangle send."""
-    outs = [out for out, _ in parts]
-    rows = jax.tree.map(lambda *xs: jnp.concatenate(xs),
-                        *[out[2:] for out in outs])
-    out = (sum(out[0] for out in outs), sum(out[1] for out in outs)) + rows
-    return out, functools.reduce(jnp.minimum, [wake for _, wake in parts])
-
-
 def _gathering(body):
     """A sequential step body for callers that ship the UNGROUPED batch
-    `[B]`: the `[Kb, E]` gather by `sel_idx` happens here, on the device
-    (~7 ns an element and column on the v5e: 3.7 ms a column at 524,288).
+    `[B]`: the `[Kb, E]` gather by `sel_idx` happens here, on the device.
     The mesh steps keep it — a shard's grouped layout is half padding and
-    their host is the busier side — and so do the @fuse stacks."""
+    their host is the busier side — and so do the @fuse stacks.
+
+    ONE gather for all the columns and the timestamp: their u32 planes
+    (`_planes`) stacked `[P, B]` and gathered along B.  The v5e gathers by
+    the SLICE, not by the element: six planes a slice cost 0.88 ms at
+    262,144 slices where six gathers of one element cost 1.87 ms EACH —
+    and 5.3-7.2 ms each once XLA's memory-space assignment put their
+    results in HBM, which a change to the program's OUTPUTS was enough
+    to cause (PERF.md, PR 31)."""
     def step(packed, sel_state, raw_cols, raw_ts, sel_idx, key_ref, now,
              in_tabs=()):
+        arrays = (*raw_cols, raw_ts)
         csel = jnp.clip(sel_idx, 0, raw_ts.shape[0] - 1)
-        return body(packed, sel_state, tuple(c[csel] for c in raw_cols),
-                    raw_ts[csel], sel_idx, key_ref, now, in_tabs)
+        planes = iter(jnp.stack(
+            [pl for a in arrays for pl in _planes(a)])[:, csel])
+        *cols, ts = (_from_planes(planes, a.dtype) for a in arrays)
+        return body(packed, sel_state, tuple(cols), ts, sel_idx, key_ref,
+                    now, in_tabs)
     return step
 
 
@@ -682,7 +692,15 @@ def _shard_local(body):
             lambda x: lax.pcast(x, ("shard",), to="varying"), in_tabs)
         ps, ss, out, wake = body((*arrays, scalars), sel_state, raw_cols,
                                  raw_ts, sel_idx, key_idx, now, in_tabs)
-        out = (lax.psum(out[0], "shard"), lax.psum(out[1], "shard")) + out[2:]
+        if isinstance(out, BandedEmission):
+            # a plain pair out of the shard_map (its out_specs name the
+            # header's scalars and the bands apart); _shard_step re-wraps
+            (n_valid, n_dropped, ranks_used), bands = out.tiers[0]
+            out = ((lax.psum(n_valid, "shard"), lax.psum(n_dropped, "shard"),
+                    lax.pmax(ranks_used, "shard")), bands)
+        else:
+            out = (lax.psum(out[0], "shard"),
+                   lax.psum(out[1], "shard")) + out[2:]
         *narrays, nscal = ps
         # re-replicate scalar counters: old + psum(local delta)
         nscal = tuple(
@@ -696,7 +714,7 @@ def _shard_local(body):
 
 
 def _shard_step(body, mesh, packer: "StatePacker", sel: SelectorExec,
-                owner=None):
+                owner=None, banded: bool = False):
     """Shard the pattern step over the mesh 'shard' axis.
 
     Design (scaling-book style): partition keys are the shard axis — each
@@ -708,17 +726,31 @@ def _shard_step(body, mesh, packer: "StatePacker", sel: SelectorExec,
     (psum) ride the ICI.  This replaces the reference's
     thread-per-Disruptor scale-up (CORE/stream/StreamJunction.java:296)
     with SPMD scale-out.
+
+    `banded`: the body hands over rank bands (`_emit_matches`); a band's
+    buffer is one more `bspec` output — every shard's planes of its own
+    [rb, Kb] ranks, shard after shard — and `ranks_used` one more
+    replicated scalar, the `pmax` beside the header's `psum`.
     """
     from jax.sharding import PartitionSpec as P
 
     pspec, sspec = _shard_specs(packer, sel)
     bspec = P("shard")    # sharded inputs: [n*Kb, ...] on axis 0
     rspec = P()           # raw event columns [B]: replicated to all shards
+    ospec = ((P(), P(), P()), bspec) if banded else \
+        (P(), P(), bspec, bspec, bspec, bspec)
     sharded = jax.shard_map(
         _shard_local(body), mesh=mesh,
         in_specs=(pspec, sspec, rspec, rspec, bspec, bspec, P(), P()),
-        out_specs=(pspec, sspec, (P(), P(), bspec, bspec, bspec, bspec), P()))
-    return _jit_sequential(sharded, owner, "pattern_step_sharded")
+        out_specs=(pspec, sspec, ospec, P()))
+    if not banded:
+        return _jit_sequential(sharded, owner, "pattern_step_sharded")
+    n = int(mesh.devices.size)
+
+    def step(*args):
+        ps, ss, tier, wake = sharded(*args)
+        return ps, ss, BandedEmission((tier,), shards=n), wake
+    return _jit_sequential(step, owner, "pattern_step_sharded")
 
 
 def _shard_fused_step(body, mesh, packer: "StatePacker",
@@ -757,9 +789,206 @@ def _shard_fused_step(body, mesh, packer: "StatePacker",
                     donate_argnums=(0,))
 
 
+def band_edges(R: int) -> tuple:
+    """Rank edges of a banded emission of `R` ranks: (0, 1, 4, 16, 64,
+    256, R) cut off at R — band b holds ranks [edges[b], edges[b + 1]).
+    Fixed at plan time and a factor 4 apart: a buffer costs ~75 us in a
+    `device_get` whatever it holds (PERF.md, PR 31), so a finer cut would
+    pay more in buffers than it saves in slots."""
+    edges, e = [0], 1
+    while e < R:
+        edges.append(e)
+        e *= 4
+    return (*edges, R)
+
+
+def _planes(x) -> list:
+    """The u32 planes of one row array (the emission wire's, and the
+    stacked column gather's, `_gathering`): a 64-bit
+    array as (low words, high words) — XLA:TPU keeps it so anyway, and a
+    `device_get` of an 8-byte dtype costs ten times a 4-byte one's (6.8
+    against 0.67 ms at 262,144 elements: PERF.md, PR 31) — a 4-byte one
+    bit for bit, a bool as 0 / 1."""
+    if x.dtype.itemsize == 8:      # int64: the device has no other
+        return list(split64(x))
+    if x.dtype.itemsize == 4:
+        return [lax.bitcast_convert_type(x, jnp.uint32)]
+    return [x.astype(jnp.uint32)]
+
+
+def _from_planes(planes, dtype):
+    """`_planes` undone on the device: the next plane(s) of the iterator
+    as one array of `dtype`."""
+    if np.dtype(dtype).itemsize == 8:
+        return join64(next(planes), next(planes))
+    if np.dtype(dtype).itemsize == 4:
+        return lax.bitcast_convert_type(next(planes), dtype)
+    return next(planes).astype(dtype)
+
+
+def unpack_planes(bufs, dtypes, shards: int = 1) -> list:
+    """Host side of `_planes`: `bufs` are the fetched u32 buffers of the
+    bands delivery took (each: every shard's planes of `dtypes`' arrays
+    over the shard's slots of the band, shard after shard), the result
+    one array a dtype over all of their slots, the bands end to end.
+    Every word is written once, where it ends up."""
+    words = [2 if np.dtype(d).itemsize == 8 else 1 for d in dtypes]
+    sizes = [b.size // sum(words) for b in bufs]
+    outs = [np.empty(sum(sizes), d) for d in dtypes]
+    at = 0
+    for buf, m in zip(bufs, sizes):
+        pl = buf.reshape(shards, sum(words), m // shards)
+        p = 0
+        for o, w in zip(outs, words):
+            dst = o[at:at + m]
+            if dst.dtype == np.bool_:
+                np.not_equal(pl[:, p], 0, out=dst.reshape(shards, -1))
+            else:
+                dst = dst.view(np.uint32).reshape(shards, -1, w)
+                for i in range(w):          # little-endian: low word first
+                    dst[:, :, i] = pl[:, p + i]
+            p += w
+        at += m
+    return outs
+
+
+# what rides the head buffer of a band: ts, then kind with valid in bit 31
+HEAD_DTYPES = (np.int64, np.uint32)
+_VALID_BIT = 31
+
+
+@jax.tree_util.register_pytree_node_class
+class BandedEmission:
+    """A pattern send's emission as RANK BANDS: what the sequential scan
+    programs hand over wherever there is a key axis (`_emit_matches`,
+    `banded`), a type of its own so that no delivery path mistakes it for
+    the flat 6-tuple the other emitters keep.
+
+    `tiers`: one `(header, bands)` a `[Kb, E]` tier of the send (one for
+    a one-rectangle send; the runtime puts a tiered send's end to end,
+    `joined`).  `header` = (n_valid i64, n_dropped i64, ranks_used i32):
+    `ranks_used` is the highest per-key row count of the tier, clipped to
+    its R — a key with c rows fills ranks 0 .. c-1, so no row sits at or
+    above it.  `bands[b]` = (head, cols): two u32 buffers holding the
+    planes (`_planes`) of ranks [edges[b], edges[b + 1]) x K slots,
+    rank-major — `head` the timestamp's two planes and `kind | valid <<
+    31`, `cols` the output columns' planes in schema order.  Band 0 is
+    one rank, so a buffer's size says how many ranks it holds.
+    `shards`: under a mesh a buffer holds every shard's planes, shard
+    after shard, each over its own Kb keys.
+
+    Delivery fetches the headers, then only the bands below `ranks_used`
+    (`used`), and decodes them on the host (`unpack_planes`)."""
+
+    __slots__ = ("tiers", "shards")
+
+    def __init__(self, tiers, shards: int = 1):
+        self.tiers = tuple(tiers)
+        self.shards = shards
+
+    def tree_flatten(self):
+        return (self.tiers,), self.shards
+
+    @classmethod
+    def tree_unflatten(cls, shards, children):
+        return cls(children[0], shards)
+
+    @staticmethod
+    def joined(emissions) -> "BandedEmission":
+        """The emissions of a send's tiers as the send's one."""
+        return BandedEmission(
+            tuple(t for e in emissions for t in e.tiers), emissions[0].shards)
+
+    @property
+    def headers(self):
+        """What delivery fetches first: ((n_valid, n_dropped, ranks_used),
+        ...), one a tier."""
+        return tuple(h for h, _ in self.tiers)
+
+    def used(self, ranks_used):
+        """(bands to fetch, ranks they hold, ranks of all bands): of
+        each tier the bands that start below its `ranks_used`, in tier
+        order."""
+        take, ranks, cap = [], 0, 0
+        for (_, bands), ru in zip(self.tiers, ranks_used):
+            lo = 0
+            for head, cols in bands:
+                rb = head.size // bands[0][0].size
+                if lo < ru:
+                    take.append((head, cols))
+                    ranks += rb
+                lo += rb
+            cap += lo
+        return take, ranks, cap
+
+
+def compact_emission(out, EP: int, K: int, compact_rows: int,
+                     band_types=None):
+    """The selector's output rows over the [EP, K] grid — (ts, kind,
+    valid, cols), each [EP * K] — as the step's emission: per key the
+    first R = min(compact_rows, EP) valid rows in grid order (rank r of
+    key k), the rest counted as dropped.
+
+    Flat (`band_types` None): (n_valid, n_dropped, ts, kind, valid, cols)
+    over [R * K] slots, rank r of key k at r * K + k; where R == EP the
+    grid as it is.  Banded (`band_types`: the output columns' attribute
+    types): a `BandedEmission` of one tier — always rank-major (where R
+    == EP too: the same contraction, R x EP x K cells), cut into
+    `band_edges(R)`, on the u32 wire, `ranks_used` in its header.  R, and
+    with it what is dropped, is the same in both."""
+    ots, okind, ovalid, ocols = out
+    banded = band_types is not None
+    R = min(compact_rows, EP)
+    if R < EP or banded:
+        with jax.named_scope("emission_compaction"):
+            v2 = ovalid.reshape(EP, K)
+            rank = jnp.cumsum(v2.astype(jnp.int32), axis=0) - 1
+            keep_oh = jnp.logical_and(
+                jnp.arange(R, dtype=jnp.int32)[:, None, None] == rank[None],
+                v2[None])                          # [R,EP,K]
+            cmask = jnp.any(keep_oh, axis=1)       # [R,K]
+            n_valid = jnp.sum(cmask.astype(jnp.int64))
+            n_dropped = jnp.sum(v2.astype(jnp.int64)) - n_valid
+
+            def cmp(x):                            # [B] -> [R,K]
+                return oh_take(x.reshape(EP, K)[None], keep_oh, 1)
+
+            if not banded:
+                out = (cmp(ots).reshape(R * K), cmp(okind).reshape(R * K),
+                       cmask.reshape(R * K),
+                       tuple(cmp(c).reshape(R * K) for c in ocols))
+    else:
+        n_valid = jnp.sum(ovalid.astype(jnp.int64))
+        n_dropped = jnp.zeros((), jnp.int64)
+    if not banded:
+        # leading scalars: valid-row count (drainer skips empty outputs
+        # with one 16-byte read) and overflow count (rows beyond R
+        # matches/key/batch)
+        return (n_valid, n_dropped) + out
+    with jax.named_scope("emission_bands"):
+        kind_valid = cmp(okind).astype(jnp.uint32) | \
+            (cmask.astype(jnp.uint32) << _VALID_BIT)
+        head = _planes(cmp(ots)) + [kind_valid]
+        # the columns in the out schema's own dtypes: what the host
+        # decodes the planes by (runtime._EmissionRows)
+        body = [pl for c, t in zip(ocols, band_types)
+                for pl in _planes(cmp(c).astype(ev.dtype_of(t)))]
+
+        def band(planes, lo, hi):                  # [R,K] each -> u32 wire
+            return jnp.concatenate(
+                [pl[lo:hi].reshape((hi - lo) * K) for pl in planes])
+
+        edges = band_edges(R)
+        bands = tuple((band(head, lo, hi), band(body, lo, hi))
+                      for lo, hi in zip(edges, edges[1:]))
+        # int32 throughout: XLA:TPU lowers no 64-bit pmax (the mesh's)
+        ranks_used = jnp.max(jnp.sum(cmask, axis=0, dtype=jnp.int32))
+    return BandedEmission((((n_valid, n_dropped, ranks_used), bands),))
+
+
 def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
                   emits, ord_, sel_state, pstate, now, key_idx=None,
-                  compact_rows: int = 8):
+                  compact_rows: int = 8, banded: bool = False):
     """Flatten scan emissions [E,P+1,K] into selector Rows + env, then
     compact the selector's OUTPUT rows per key.
 
@@ -769,7 +998,8 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
     searchsorted/sort compaction costs ~80ms at 131k keys: TPU lowers both
     to serialized gathers; compacting the ~25 capture arrays instead of the
     ~7 output arrays costs GBs of HBM traffic).  Valid rows beyond R
-    matches per key per batch are counted in the out[1] dropped scalar."""
+    matches per key per batch are counted in the out[1] dropped scalar
+    (`compact_emission`; `banded`: as a `BandedEmission`)."""
     mask = emits["mask"]                       # [E,P+1,K]
     E, P1, K = mask.shape
     EP = E * P1
@@ -823,31 +1053,8 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
     with jax.named_scope("selector"):
         sel_state, out = sel.process(sel_state, rows, env)
 
-    ots, okind, ovalid, ocols = out
-    R = min(compact_rows, EP)
-    if R < EP:
-        with jax.named_scope("emission_compaction"):
-            v2 = ovalid.reshape(EP, K)
-            rank = jnp.cumsum(v2.astype(jnp.int32), axis=0) - 1
-            keep_oh = jnp.logical_and(
-                jnp.arange(R, dtype=jnp.int32)[:, None, None] == rank[None],
-                v2[None])                          # [R,EP,K]
-            cmask = jnp.any(keep_oh, axis=1)       # [R,K]
-            n_valid = jnp.sum(cmask.astype(jnp.int64))
-            n_dropped = jnp.sum(v2.astype(jnp.int64)) - n_valid
-
-            def cmp(x):                            # [B] -> [R*K]
-                return oh_take(x.reshape(EP, K)[None], keep_oh,
-                               1).reshape(R * K)
-
-            out = (cmp(ots), cmp(okind), cmask.reshape(R * K),
-                   tuple(cmp(c) for c in ocols))
-    else:
-        n_valid = jnp.sum(ovalid.astype(jnp.int64))
-        n_dropped = jnp.zeros((), jnp.int64)
-    # leading scalars: valid-row count (drainer skips empty outputs with one
-    # 16-byte read) and overflow count (rows beyond R matches/key/batch)
-    out = (n_valid, n_dropped) + out
+    out = compact_emission(out, EP, K, compact_rows,
+                           sel.out_types if banded else None)
 
     # next wakeup: earliest absent deadline (standalone `not X for t` atoms
     # and timed absent sides of logical pairs whose wait hasn't elapsed)

@@ -35,7 +35,8 @@ from ..query_api.query import Partition, Query, SingleInputStream
 from . import event as ev
 from .executor import CompileError
 from .keyslots import SlotAllocator
-from .pattern_planner import StatePacker
+from .pattern_planner import (HEAD_DTYPES, BandedEmission, StatePacker,
+                              unpack_planes)
 from .planner import PlannedQuery, plan_single_query
 from .window import NO_WAKEUP
 from .steputil import jit_step
@@ -718,7 +719,8 @@ class PatternQueryRuntime(_MeshResolved):
         # step, the tier of the hottest keys first: its scan is the send's
         # longest piece of device work, and it runs under the host's prep
         # of the others.  The tiers' emissions leave as ONE emission
-        # (planned.merge_emissions): one header, one payload, and the
+        # (BandedEmission.joined: nothing is dispatched for it): one
+        # header fetch, one payload of the bands the tiers used, and the
         # timestamp order of delivery holds over all of the send's keys
         with _phases.phase(st, self.name, "route_keys") as sp:
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
@@ -792,10 +794,6 @@ class PatternQueryRuntime(_MeshResolved):
                     steps = p.dense_steps if dense else p.steps
                     outs.append(self._step(steps[stream_id], cols_d, *ts_d,
                                            sel_d, key_d, now_d))
-            # the tiers' emissions as the send's one: a dispatch of its
-            # own (pattern_planner._merge_emissions), tiny beside a step
-            out, wake = outs[0] if len(outs) == 1 else _phases.dispatch(
-                self, p.merge_emissions, tuple(outs))
         except Exception:
             # a tier that was dispatched has advanced its keys' state: what
             # it matched is delivered before the error is, so no match is
@@ -804,6 +802,12 @@ class PatternQueryRuntime(_MeshResolved):
             for out, wake in outs:
                 _emit_output(self, out, now, wake=self._wake_arg(wake))
             raise
+        out, wake = outs[0]
+        if len(outs) > 1:
+            # the tiers' emissions as the send's one; the wakes ride the
+            # header fetch together and the earliest is applied
+            out = BandedEmission.joined([o for o, _ in outs])
+            wake = tuple(w for _, w in outs)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def _feed_observers(self, tiers, nuniq, now: int) -> None:
@@ -960,6 +964,37 @@ def _has_consumers(qr) -> bool:
     return bool(qr.callbacks or qr.batch_callbacks) or _target_live(qr)
 
 
+def _earliest(wake) -> int:
+    """A wake as `_apply_wake` takes it: the scalar, or the earliest of
+    a tiered send's (one a tier)."""
+    return int(np.min(wake))
+
+
+def _headed(out) -> bool:
+    """Does this emission lead with a count header — a banded pattern
+    emission, or the flat 6-tuple of the other compacting emitters (joins,
+    @fuse stacks, the block and timer steps)?  Its rows then stay on the
+    device until somebody reads them; a plain 4-tuple ships whole."""
+    return isinstance(out, BandedEmission) or len(out) == 6
+
+
+def _header_of(out):
+    """What a delivery path fetches first of an emission: the header of a
+    headed one (a banded one's carries `ranks_used` a tier), all of a
+    plain one."""
+    if isinstance(out, BandedEmission):
+        return out.headers
+    return (out[0], out[1]) if len(out) == 6 else out
+
+
+def _emit_fetched(qr, out, fetched, now: int, ingest_ns=None) -> None:
+    """Deliver an emission of which `_header_of(out)` has been fetched."""
+    if _headed(out):
+        _emit_output_sync(qr, out, now, header=fetched, ingest_ns=ingest_ns)
+    else:
+        _emit_output_sync(qr, fetched, now, ingest_ns=ingest_ns)
+
+
 def _emit_output(qr, out, now: int, wake=None) -> None:
     """Emission entry: async mode (@async) defers the device->host sync to a
     background drainer thread so the producer keeps dispatching device work
@@ -973,7 +1008,7 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
     in one roundtrip and applied before delivery."""
     if not _has_consumers(qr):
         if wake is not None:
-            qr._apply_wake(int(wake))
+            qr._apply_wake(_earliest(wake))
         return
     # ingest stamp (perf_counter_ns at send acceptance, stashed by the
     # junction under the query lock): rides every deferred-delivery queue
@@ -1038,19 +1073,14 @@ def _deliver_output(qr, out, now: int, wake, ingest_ns=None,
         # sampled window-fill probe rides THIS fetch (same device_get call:
         # the never-fetch guard counts calls, and this adds none)
         probe = _stateobs.take_fill_probe(qr)
-        st = qr.app.stats
-        if len(out) == 6:
-            header, wake_h, fills = _phases.fetch(
-                st, qr.name, "header", ((out[0], out[1]), wake, probe))
-        else:
-            out, wake_h, fills = _phases.fetch(
-                st, qr.name, "rows", (out, wake, probe))
-            header = None
+        fetched, wake_h, fills = _phases.fetch(
+            qr.app.stats, qr.name, "header" if _headed(out) else "rows",
+            (_header_of(out), wake, probe))
         if fills is not None:
             _stateobs.record_fill(qr, fills)
         if wake_h is not None:
-            qr._apply_wake(int(wake_h))
-        _emit_output_sync(qr, out, now, header=header, ingest_ns=ingest_ns)
+            qr._apply_wake(_earliest(wake_h))
+        _emit_fetched(qr, out, fetched, now, ingest_ns)
 
 
 def _deliver_many(qr, items) -> None:
@@ -1065,17 +1095,12 @@ def _deliver_many(qr, items) -> None:
     # deliveries is queue residency — both are inside each item's e2e
     # sample (see phases.py)
     fetched = _phases.fetch(st, qr.name, "header", [
-        (out[0], out[1]) if len(out) == 6 else out
-        for out, _, _, _, _ in items], mult=len(items))
+        _header_of(out) for out, _, _, _, _ in items], mult=len(items))
     loop_t0 = time.perf_counter_ns()
     for (out, now, _, t_in, trace), fetch_h in zip(items, fetched):
         _phases.waited(st, qr.name, loop_t0)
         with _phases.adopt(trace):
-            if len(out) == 6:
-                _emit_output_sync(qr, out, now, header=fetch_h,
-                                  ingest_ns=t_in)
-            else:
-                _emit_output_sync(qr, fetch_h, now, ingest_ns=t_in)
+            _emit_fetched(qr, out, fetch_h, now, t_in)
 
 
 def _drain_pending_emit(qr) -> None:
@@ -1095,6 +1120,70 @@ def _drain_pending_emit(qr) -> None:
         _deliver_many(qr, items)
 
 
+class _EmissionRows:
+    """The rows of one emission — ts, kind, valid, cols — wherever they
+    are, and THE way every consumer gets them onto the host: the batch
+    payload's lazy pulls, `Event` delivery, the UUID sentinels.
+
+    A flat emission's four members are fetched as they are (a plain
+    output's are host arrays already and pass through `device_get`
+    untouched).  Of a banded one (`BandedEmission`) only the bands below
+    each tier's `ranks_used` are fetched — u32 buffers, decoded here
+    (`unpack_planes`) into arrays of `ranks fetched x K` slots, the tiers
+    in order, rank-major within a tier, under the same `valid` mask
+    contract; their `fetch` spans carry `ranks` and `ranks_cap`."""
+
+    __slots__ = ("stats", "qname", "flat", "bands", "meta", "dtypes",
+                 "shards")
+
+    def __init__(self, qr, out, ranks_used=None):
+        self.stats, self.qname = qr.app.stats, qr.name
+        if isinstance(out, BandedEmission):
+            self.flat = None
+            self.bands, ranks, cap = out.used(ranks_used)
+            self.meta = {"ranks": ranks, "ranks_cap": cap}
+            self.dtypes = qr.planned.out_schema.dtypes
+            self.shards = out.shards
+        else:
+            self.flat, self.meta = tuple(out[-4:]), {}
+
+    def replace(self, ts, kind, valid, cols):
+        """Host arrays in the rows' place (the UUID sentinels' new ids)."""
+        self.flat, self.meta = (ts, kind, valid, cols), {}
+
+    def _fetch(self, tree):
+        return _phases.fetch(self.stats, self.qname, "rows", tree,
+                             **self.meta)
+
+    def _head(self, bufs):
+        ts, kv = unpack_planes(bufs, HEAD_DTYPES, self.shards)
+        return (ts, (kv & 0x7FFFFFFF).astype(np.int32),
+                (kv >> 31).astype(np.bool_))
+
+    def _cols(self, bufs):
+        return tuple(unpack_planes(bufs, self.dtypes, self.shards))
+
+    def head(self):
+        """(ts, kind, valid) in one roundtrip."""
+        if self.flat is not None:
+            return self._fetch(self.flat[:3])
+        return self._head(self._fetch([h for h, _ in self.bands]))
+
+    def cols(self):
+        """The output columns, in another."""
+        if self.flat is not None:
+            return self._fetch(self.flat[3])
+        return self._cols(self._fetch([c for _, c in self.bands]))
+
+    def all(self):
+        """(ts, kind, valid, cols) in one."""
+        if self.flat is not None:
+            return self._fetch(self.flat)
+        got = self._fetch(self.bands)
+        return (*self._head([h for h, _ in got]),
+                self._cols([c for _, c in got]))
+
+
 class _LazyBatchPayload(dict):
     """Batch-callback payload materializing device->host pulls on access.
 
@@ -1109,32 +1198,23 @@ class _LazyBatchPayload(dict):
     _LAZY = ("ts", "kind", "valid", "cols")
     _COUNTS = ("n_valid", "n_current", "n_expired", "n_dropped")
 
-    def __init__(self, names, ots, okind, ovalid, ocols, counts=None,
-                 qr=None):
+    def __init__(self, names, rows: _EmissionRows, counts=None):
         super().__init__()
         self._names = names
-        # whose `fetch` spans the lazy pulls are (None: unattributed)
-        self._stats = qr.app.stats if qr is not None else None
-        self._qname = qr.name if qr is not None else None
-        self._ots, self._okind = ots, okind
-        self._ovalid, self._ocols = ovalid, ocols
+        self._rows = rows
         if counts:
             for k, v in counts.items():
                 dict.__setitem__(self, k, v)
 
     def __missing__(self, k):
         if k in ("ts", "kind", "valid"):
-            ts, kind, valid = _phases.fetch(
-                self._stats, self._qname, "rows",
-                (self._ots, self._okind, self._ovalid))
+            ts, kind, valid = self._rows.head()
             dict.__setitem__(self, "ts", ts)
             dict.__setitem__(self, "kind", kind)
             dict.__setitem__(self, "valid", valid)
             return dict.__getitem__(self, k)
         if k == "cols":
-            cols = _phases.fetch(self._stats, self._qname, "rows",
-                                 self._ocols)
-            v = dict(zip(self._names, cols))
+            v = dict(zip(self._names, self._rows.cols()))
             dict.__setitem__(self, k, v)
             return v
         if k == "n_valid":
@@ -1226,10 +1306,12 @@ def _emit_output_sync_impl(qr, out, now: int, header=None) -> None:
     scalars ride the header fetch), then unpack to host events only if
     someone needs them (Event callbacks or downstream routing).
 
-    Pattern outputs (len-6) may still hold DEVICE arrays here; only the
-    count header has been fetched.  Bulk rows transfer lazily through the
-    payload / the event-delivery path below.  Plain outputs (len-4) arrive
-    fully fetched (they are bounded by the window batch capacity).
+    Headed outputs (`_headed`: a banded pattern emission, a flat
+    6-tuple) may still hold DEVICE arrays here; only the count header has
+    been fetched.  Bulk rows transfer lazily through `_EmissionRows` —
+    the payload's pulls, the event-delivery path below.  Plain outputs
+    (len-4) arrive fully fetched (they are bounded by the window batch
+    capacity).
 
     One `demux` span covers the delivery; the device fetches paid here
     (`fetch`) and the consumer-facing work (`sink`) nest in it, so its
@@ -1251,18 +1333,25 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
     _st = qr.app.stats
     counts = None
     overflow_exc = None
-    if len(out) == 6:
-        n_valid, n_dropped, ots, okind, ovalid, ocols = out
+    headed = _headed(out)
+    ranks_used = None
+    if headed:
         if header is None:
-            header = _phases.fetch(_st, qr.name, "header",
-                                   (n_valid, n_dropped))
-        h0 = np.asarray(header[0])
-        nd = int(header[1])
-        if h0.ndim:
-            # join header vector [n_valid, n_current] (see join.py)
-            nv, ncur = int(h0[0]), int(h0[1])
+            header = _phases.fetch(_st, qr.name, "header", _header_of(out))
+        if isinstance(out, BandedEmission):
+            # one (n_valid, n_dropped, ranks_used) a tier of the send
+            nv = sum(int(h[0]) for h in header)
+            nd = sum(int(h[1]) for h in header)
+            ncur = None
+            ranks_used = [int(h[2]) for h in header]
         else:
-            nv, ncur = int(h0), None
+            h0 = np.asarray(header[0])
+            nd = int(header[1])
+            if h0.ndim:
+                # join header vector [n_valid, n_current] (see join.py)
+                nv, ncur = int(h0[0]), int(h0[1])
+            else:
+                nv, ncur = int(h0), None
         if nd:
             # dropped-row counter BEFORE the growth attempt: even when the
             # cap grows for the next batch, THIS batch lost nd rows
@@ -1311,16 +1400,16 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
                     growable=not getattr(qr.planned, "emit_explicit", True),
                     config_key="@emit(rows='N')")
     try:
-        if len(out) == 6:
+        if headed:
             if nv == 0:
                 return
             rows_out = nv
         else:
-            ots, okind, ovalid, ocols = out
-            ovalid_np = np.asarray(ovalid)
+            ovalid_np = np.asarray(out[2])
             if not ovalid_np.any():
                 return
             rows_out = int(ovalid_np.sum())
+        rows = _EmissionRows(qr, out, ranks_used)
         span.set_metadata(rows=rows_out)
         if _st.enabled and rows_out:
             # per-tenant events_out/emitted_bytes accounting: row count is
@@ -1332,19 +1421,15 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             # emission boundary, so every consumer of this emission (event
             # callbacks, batch payloads, downstream routing, table writes)
             # observes the same id per row
-            if len(out) == 6:
-                ots, okind, ovalid, ocols = _phases.fetch(
-                    _st, qr.name, "rows", (ots, okind, ovalid, ocols))
+            ots, okind, ovalid, ocols = rows.all() if headed else rows.flat
             changed = ev.materialize_uuid_sentinels(
                 p.out_schema, np.asarray(ovalid), ocols)
-            if changed:
-                oc = list(ocols)
-                for pos, col in changed:
-                    oc[pos] = col
-                ocols = tuple(oc)
+            oc = list(ocols)
+            for pos, col in changed or ():
+                oc[pos] = col
+            rows.replace(ots, okind, ovalid, tuple(oc))
         if qr.batch_callbacks:
-            payload = _LazyBatchPayload(p.out_schema.names, ots, okind,
-                                        ovalid, ocols, counts, qr)
+            payload = _LazyBatchPayload(p.out_schema.names, rows, counts)
             with _phases.phase(_st, qr.name, "sink"):
                 for bcb in qr.batch_callbacks:
                     bcb(now, payload)
@@ -1355,21 +1440,23 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
                 # emission is counted by the junction's publish) — nothing
                 # is fetched, sorted or unpacked for the statistics' sake
                 _st.stream_in(p.output_target, _routed_rows(
-                    p, rows_out, counts, okind, ovalid))
+                    p, rows_out, counts, rows))
             return
-        if len(out) == 6:
-            # pattern outputs are compacted [R,K] rank-major on device;
-            # fetch them now and restore timestamp order for event delivery
-            # with a host-side stable sort of just the valid rows
-            # (O(matches), runs on the drainer thread)
-            ts_np, okind, ovalid_np, ocols = _phases.fetch(
-                _st, qr.name, "rows", (ots, okind, ovalid, ocols))
-            idxv = np.nonzero(ovalid_np)[0]
-            order = idxv[np.argsort(ts_np[idxv], kind="stable")]
-            ots = ts_np[order]
-            okind = np.asarray(okind)[order]
-            ocols = tuple(np.asarray(c)[order] for c in ocols)
+        if headed:
+            # headed outputs are compacted rank-major on the device (a
+            # banded one: the used bands, tier after tier); fetch them
+            # now and restore timestamp order for event delivery with a
+            # host-side stable sort of just the valid rows (O(matches),
+            # runs on the drainer thread)
+            ots, okind, ovalid, ocols = rows.all()
+            idxv = np.nonzero(ovalid)[0]
+            order = idxv[np.argsort(ots[idxv], kind="stable")]
+            ots = ots[order]
+            okind = okind[order]
+            ocols = tuple(c[order] for c in ocols)
             ovalid = np.ones(order.shape[0], np.bool_)
+        else:
+            ots, okind, ovalid, ocols = rows.flat
         batch = ev.EventBatch(ots, okind, ovalid, ocols)
         pairs = ev.unpack(p.out_schema, batch,
                           want_kinds=(ev.CURRENT, ev.EXPIRED))
@@ -1393,7 +1480,7 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             raise overflow_exc
 
 
-def _routed_rows(p, rows_out: int, counts, okind, ovalid) -> int:
+def _routed_rows(p, rows_out: int, counts, rows) -> int:
     """How many of an emission's rows `_deliver_pairs` would route to the
     output target (`insert [current|expired|all] events into`), from
     numbers already on the host: the header's counts for compacted
@@ -1405,6 +1492,7 @@ def _routed_rows(p, rows_out: int, counts, okind, ovalid) -> int:
         return counts["n_current" if sel == "CURRENT_EVENTS"
                       else "n_expired"]
     want = ev.CURRENT if sel == "CURRENT_EVENTS" else ev.EXPIRED
+    _, okind, ovalid, _ = rows.flat     # a plain output: host arrays
     return int((np.asarray(ovalid) & (np.asarray(okind) == want)).sum())
 
 
@@ -2564,7 +2652,7 @@ class _EmissionDrainer:
         # (non-blocking): by the time the drainer's device_get runs, the
         # bytes are already on the host and the get costs ~0 instead of one
         # blocking transfer per drain cycle
-        targets = (out[0], out[1], wake) if len(out) == 6 else (out, wake)
+        targets = (_header_of(out), wake)
         for leaf in jax.tree_util.tree_leaves(targets):
             fn = getattr(leaf, "copy_to_host_async", None)
             if fn is not None:
@@ -2618,8 +2706,7 @@ class _EmissionDrainer:
                 with _phases.adopt(items[0][5]):
                     fetched = _phases.fetch(
                         st, tuple(it[0].name for it in items), "header", [
-                            ((out[0], out[1]), wake) if len(out) == 6
-                            else (out, wake)
+                            (_header_of(out), wake)
                             for _, out, _, wake, _, _ in items])
             except Exception:  # noqa: BLE001 — drainer must survive
                 traceback.print_exc()
@@ -2630,16 +2717,11 @@ class _EmissionDrainer:
                 try:
                     _phases.waited(st, qr.name, loop_t0)
                     if wake_h is not None:
-                        qr._apply_wake(int(wake_h))
+                        qr._apply_wake(_earliest(wake_h))
                     if fetch_h is None:
                         continue
                     with _phases.adopt(trace):
-                        if len(out) == 6:
-                            _emit_output_sync(qr, out, now, header=fetch_h,
-                                              ingest_ns=t_in)
-                        else:
-                            _emit_output_sync(qr, fetch_h, now,
-                                              ingest_ns=t_in)
+                        _emit_fetched(qr, out, fetch_h, now, t_in)
                 except Exception as exc:  # noqa: BLE001 — drainer survives
                     # route to the app error path (reference: the Disruptor
                     # ExceptionHandler) — MatchOverflowError and callback

@@ -45,9 +45,12 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
   h2d         every host->device upload (host wall)          h2d
   dispatch    the jitted step call (submit only); `tier`
               on it and on its uploads, where a send was
-              laid out as several tiers (tier_scope) — their
-              emissions leave as one (`pattern_merge`)       dispatch_submit
-  fetch       every device_get on a delivery path            d2h_drain
+              laid out as several tiers (tier_scope)         dispatch_submit
+  fetch       every device_get on a delivery path; a banded
+              pattern emission's `rows` fetches carry `ranks`
+              (ranks fetched: the bands below `ranks_used`)
+              and `ranks_cap` (R, summed over the send's
+              tiers) (FETCH_STATS)                           d2h_drain
   demux       header decode, ts-order restore, unpack        demux
   sink        callbacks, table op, rate limit, re-publish    sink
   compile     jit_step's body while tracing a new signature  (none)
@@ -94,6 +97,9 @@ STAGE_PARTS = ("stage", "route_keys", "shard_group", "obs_feed")
 # the [Kb, E] rectangles it was split into, their cells, the hottest
 # key's events, the sum of the rectangles' E (the scan ticks it costs)
 LAYOUT_STATS = ("tiers", "cells", "max_e", "ticks")
+# what a `rows` fetch says of the banded pattern emission it reads: the
+# ranks it fetched, the ranks the emission could hold
+FETCH_STATS = ("ranks", "ranks_cap")
 SPAN_PREFIX = "siddhi:"
 
 
@@ -123,7 +129,8 @@ class PhaseProfiler:
     def add(self, query: str, phase: str, ns: int,
             part: Optional[str] = None, meta: Optional[Dict] = None) -> None:
         """`meta`: the span's stats; `grouped` is tallied by value and the
-        LAYOUT_STATS are summed (both are `route_keys`'s)."""
+        LAYOUT_STATS are summed (both are `route_keys`'s), and so are a
+        `fetch`'s FETCH_STATS."""
         if ns <= 0:
             return
         key = (query, phase, part)
@@ -133,10 +140,12 @@ class PhaseProfiler:
             if meta and "grouped" in meta:
                 tally = self._grouped.setdefault(key, {})
                 tally[meta["grouped"]] = tally.get(meta["grouped"], 0) + 1
-            if meta and "cells" in meta:
-                sums = self._layout.setdefault(key, {})
-                for stat in LAYOUT_STATS:
-                    sums[stat] = sums.get(stat, 0) + int(meta.get(stat, 0))
+            for stats in (LAYOUT_STATS, FETCH_STATS):
+                if meta and stats[0] in meta:
+                    sums = self._layout.setdefault(key, {})
+                    for stat in stats:
+                        sums[stat] = sums.get(stat, 0) + \
+                            int(meta.get(stat, 0))
 
     def should_sample(self, query: str, every: int) -> bool:
         """Per-query dispatch modulus for the deep mode: True on every
@@ -162,7 +171,9 @@ class PhaseProfiler:
         part's where a query never sees one (timer-fired steps).  The
         `route_keys` part of a pattern query also lists `grouped`:
         {"view": n, "take": n}, its spans counted by that stat, and
-        `layout`: the sums of its spans' LAYOUT_STATS."""
+        `layout`: the sums of its spans' LAYOUT_STATS; `d2h_drain` of
+        one whose emission is banded lists `layout` too: the sums of
+        its fetches' FETCH_STATS."""
         with self._lock:
             ns = dict(self._ns)
             count = dict(self._count)
@@ -177,6 +188,8 @@ class PhaseProfiler:
             n = count.get((q, p, part), 0)
             if part is None:
                 ent["count"] += n
+                if (q, p, part) in layout:
+                    ent["layout"] = layout[(q, p, part)]
             else:
                 ent.setdefault("parts", {})[part] = {"ns": total,
                                                      "count": n}
@@ -422,12 +435,13 @@ def nbytes(*arrays) -> int:
     return sum(int(getattr(a, "nbytes", 0)) for a in arrays)
 
 
-def fetch(stats, query, what: str, tree, mult: int = 1):
+def fetch(stats, query, what: str, tree, mult: int = 1, **meta):
     """THE device->host fetch of every delivery path: `jax.device_get`
     inside a `fetch` span (`what` = header | rows | ring).  In blocking
     delivery the first fetch after a dispatch also holds the wait for
-    the step.  `bytes` is summed only while somebody records it."""
-    with phase(stats, query, "fetch", mult, what=what) as sp:
+    the step.  `bytes` is summed only while somebody records it; `meta`:
+    what the caller knows of the fetch beforehand (FETCH_STATS)."""
+    with phase(stats, query, "fetch", mult, what=what, **meta) as sp:
         out = jax.device_get(tree)
         if TraceAnnotation.is_enabled() or _tracing.active() is not None:
             sp.set_metadata(bytes=tree_nbytes(out))
@@ -482,6 +496,8 @@ def phase_report(rt) -> Dict:
                 "share": round(v["ns"] / base, 4) if base else 0.0}
             for p, v in phases.items()}
         for p, v in phases.items():
+            if "layout" in v:
+                entry[p]["layout"] = v["layout"]
             if "parts" in v:
                 entry[p]["parts"] = {
                     k: {"seconds": round(pv["ns"] / 1e9, 6),

@@ -145,7 +145,7 @@ class ServingDrainer:
 
     def _deliver(self, items) -> None:
         import traceback
-        from ..core.runtime import _emit_output_sync
+        from ..core.runtime import _emit_fetched, _header_of
         from ..observability import phases as _phases
         # phase accounting: each item's ring residency (append -> take,
         # stamped by ring.take) plus this cycle's batched fetch wall —
@@ -158,8 +158,7 @@ class ServingDrainer:
             with _phases.adopt(items[0][4]):
                 fetched = _phases.fetch(
                     st, tuple(it[0].name for it in items), "ring", [
-                        (out[0], out[1]) if len(out) == 6 else out
-                        for _, out, _, _, _, _ in items])
+                        _header_of(out) for _, out, _, _, _, _ in items])
         except Exception:  # noqa: BLE001 — drainer must survive
             traceback.print_exc()
             fetched = [None] * len(items)
@@ -176,12 +175,7 @@ class ServingDrainer:
                 if fetch_h is None:
                     continue
                 with _phases.adopt(trace):
-                    if len(out) == 6:
-                        _emit_output_sync(qr, out, now, header=fetch_h,
-                                          ingest_ns=t_in)
-                    else:
-                        _emit_output_sync(qr, fetch_h, now,
-                                          ingest_ns=t_in)
+                    _emit_fetched(qr, out, fetch_h, now, t_in)
                 per_q[qr] = per_q.get(qr, 0) + 1
             except Exception as exc:  # noqa: BLE001 — drainer survives
                 # same fault routing as _EmissionDrainer._run: overflow

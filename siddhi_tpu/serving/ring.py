@@ -96,7 +96,7 @@ class _Generation:
     delivery."""
 
     __slots__ = ("state", "slots", "head", "tail", "count", "key",
-                 "out_len", "placement_fallbacks", "_set", "_read")
+                 "placement_fallbacks", "_set", "_read")
 
     def __init__(self, out, slots: int, owner: str):
         from ..core.steputil import jit_step
@@ -105,7 +105,6 @@ class _Generation:
         self.tail = 0          # next read slot
         self.count = 0         # occupied slots
         self.key = _aval_key(out)
-        self.out_len = len(out)
         leaves, treedef = jax.tree.flatten(out)
         placed = [_alloc_like(x, slots) for x in leaves]
         self.state = jax.tree.unflatten(treedef, [z for z, _ in placed])

@@ -140,9 +140,9 @@ def recording(fn, log):
 def deploy(text, gathering):
     """A runtime whose pattern programs record what they return.
     `gathering`: the plan ships the staged batch and its programs gather
-    on the device — `steps` the planner's own gather wrapper around the
-    scan body (what the mesh and @fuse run), `dense_steps` the grouped
-    program behind a device gather."""
+    on the device — `steps` and `dense_steps` the grouped programs behind
+    a device gather (PR 31: the planner's own gather wrapper, `raw_steps`,
+    keeps the flat emission for @fuse, so it is no longer the twin)."""
     m = SiddhiManager()
     rt = m.create_siddhi_app_runtime(text)
     errors, got, log = [], [], []
@@ -155,9 +155,7 @@ def deploy(text, gathering):
     assert p.grouped_input and p.dense_steps is not None
     steps, dense = p.steps, p.dense_steps
     if gathering:
-        steps = {sid: pattern_planner._jit_sequential(body, "ref",
-                                                      "pattern_step")
-                 for sid, body in p.raw_steps.items()}
+        steps = {sid: gather_on_device(fn) for sid, fn in steps.items()}
         dense = {sid: gather_on_device(fn) for sid, fn in dense.items()}
     qr.planned = dataclasses.replace(
         p, grouped_input=not gathering,
@@ -455,8 +453,8 @@ def test_a_tiered_send_is_delivered_as_one_emission_in_timestamp_order(
         real = rtm._phases.fetch
         monkeypatch.setattr(
             rtm._phases, "fetch",
-            lambda st, q, what, tree, mult=1: fetches.append(what) or
-            real(st, q, what, tree, mult))
+            lambda st, q, what, tree, mult=1, **meta: fetches.append(what)
+            or real(st, q, what, tree, mult, **meta))
         try:
             drive(rt, qr, warm)
             del log[:], got[:], calls[:], batches[:], fetches[:]
@@ -496,7 +494,8 @@ def test_a_tier_that_fails_loses_no_match_of_the_tiers_before_it():
     try:
         drive(rt, qr, warm + [send])
         assert not errors and len(log) == 1 + 3
-        hot_rows = int(log[1][2][0])      # the hot tier is dispatched first
+        # the hot tier is dispatched first; its header's n_valid
+        hot_rows = int(log[1][2].headers[0][0])
     finally:
         m.shutdown()
     m, rt, qr, got, errors, log = deploy(text, gathering=False)

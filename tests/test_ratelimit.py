@@ -103,12 +103,14 @@ def test_output_snapshot_every_time_grouped():
     h.send(["a", 1])
     h.send(["b", 10])
     h.send(["a", 2])
+    # a snapshot may fall between the second and the third send (the
+    # first send compiles): wait for the one that has seen all three
+    def snaps():
+        return [{e.data[0]: e.data[1] for e in b} for b in list(batches)]
     deadline = time.time() + 3.0
-    while time.time() < deadline and not any(len(b) == 2 for b in batches):
+    while time.time() < deadline and {"a": 3, "b": 10} not in snaps():
         time.sleep(0.02)
-    full = [b for b in batches if len(b) == 2][0]
-    snap = {e.data[0]: e.data[1] for e in full}
-    assert snap == {"a": 3, "b": 10}
+    assert {"a": 3, "b": 10} in snaps()
     manager.shutdown()
 
 

@@ -695,14 +695,15 @@ def test_v5e_compile_has_no_whole_blob_x64_pass(step, guard_compiled):
 # the one-chip programs take the event columns grouped by the host (PR 29)
 # and reshape them: the dense step gathers nothing, the scan step only its
 # keys' rows of the three state planes.  The sharded step still gathers
-# the replicated [B] columns on each chip: three state planes + key (two
-# u32 halves), price, stage and the decoded i64 timestamp (two more) — its
-# count is the guard that the mesh program did not change
+# the replicated [B] columns on each chip: three state planes, and — since
+# PR 31 — ONE gather of the columns' six u32 planes stacked (key and the
+# decoded i64 timestamp as two halves each, price, stage) where there
+# were six gathers of one plane: the chip gathers by the slice
 GATHERS = {
     "dense": [],
     "gather": ["s32[50,2048]", "u32[40,2048]", "u32[40,2048]"],
-    "sharded": ["s32[50,32768]", "u32[40,32768]", "u32[40,32768]"] +
-               ["[32768,4]"] * 6,
+    "sharded": ["s32[50,32768]", "u32[40,32768]", "u32[40,32768]",
+                "u32[6,32768,4]"],
 }
 
 
@@ -712,3 +713,17 @@ def test_v5e_compile_gathers_only_what_the_step_must(step, guard_compiled):
     found = re.findall(r"= (\S+?)\{\S* gather\(", compiled.as_text())
     assert len(found) == len(GATHERS[step]), found
     assert all(want in got for want, got in zip(GATHERS[step], found)), found
+
+
+def test_v5e_sharded_event_gather_lands_in_fast_memory(guard_compiled):
+    """PR 31: XLA's memory-space assignment put five of the six one-plane
+    event gathers' results in HBM once the program's outputs changed
+    (1.87 -> 5.3-7.2 ms each on the chip).  The one stacked gather's
+    result is in the fast memory, `S(1)`, here too."""
+    _p, compiled = guard_compiled("sharded")
+    # the gather fusions' results, as the entry computation places them:
+    # three rows-of-state gathers, then the stacked columns'
+    layouts = re.findall(
+        r"^\s*%fusion\S* = u32\[\d+,6\]\{(\S*?)\} fusion\(.*gather",
+        compiled.as_text(), re.M)
+    assert len(layouts) == 1 and layouts[0].endswith("S(1)"), layouts
