@@ -568,6 +568,11 @@ class PatternQueryRuntime(_MeshResolved):
     """Host wrapper for a pattern/sequence query: groups events per key into
     the [K, E] device layout and drives the per-stream NFA steps."""
 
+    # no pattern step reads `staged.to_device`: each uploads its own
+    # columns (grouped by the host, a stack, a shard's share), so the
+    # @serve accept-edge stager has nothing to hand it (_serve_stage)
+    adopts_staged = False
+
     def __init__(self, planned, app: "SiddhiAppRuntime",
                  slot_allocator=None):
         self.planned = planned
@@ -2078,17 +2083,22 @@ class StreamJunction:
         return names
 
     def _serve_stage(self, staged) -> None:
-        """Double-buffered H2D staging (serving/staging.py): when any
-        subscriber runs the serving loop, the batch's device upload
-        starts HERE at the accept edge — batch N+1's transfer overlaps
-        batch N's compute (and, on the @async path, the queue wait).
+        """Double-buffered H2D staging (serving/staging.py): when a
+        subscriber runs the serving loop and a subscriber takes the
+        staged batch as it is (`adopts_staged`: its step reads
+        `staged.to_device`), the batch's device upload starts HERE at
+        the accept edge — batch N+1's transfer overlaps batch N's
+        compute (and, on the @async path, the queue wait).  A junction
+        whose subscribers all upload columns of their own (the pattern
+        path: grouped by the host) stages nothing: one upload a batch.
         Idempotent: a batch staged at enqueue is skipped at dispatch."""
         on = getattr(self, "_serve_staging", None)
         if on is None:
             # memoized on first dispatch: wiring is complete by then
-            on = self._serve_staging = any(
-                getattr(getattr(q, "_qr", q), "serve_emit", False)
-                for q in self.queries)
+            subs = [getattr(q, "_qr", q) for q in self.queries]
+            on = self._serve_staging = \
+                any(getattr(q, "serve_emit", False) for q in subs) and \
+                any(getattr(q, "adopts_staged", True) for q in subs)
         if on and self.app is not None and staged.dev is None:
             st = getattr(self.app, "_serve_stager", None)
             if st is not None:

@@ -45,12 +45,19 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
   h2d         every host->device upload (host wall)          h2d
   dispatch    the jitted step call (submit only); `tier`
               on it and on its uploads, where a send was
-              laid out as several tiers (tier_scope)         dispatch_submit
+              laid out as several tiers (tier_scope); under
+              @serve also the ring's two programs: `step` =
+              ring_append on the sender's thread (`occupancy`:
+              the ring's entries once it is in), ring_read on
+              the drainer's                                  dispatch_submit
   fetch       every device_get on a delivery path; a banded
               pattern emission's `rows` fetches carry `ranks`
               (ranks fetched: the bands below `ranks_used`)
               and `ranks_cap` (R, summed over the send's
-              tiers) (FETCH_STATS)                           d2h_drain
+              tiers) (FETCH_STATS); a drain cycle's one
+              `what=ring` fetch carries `items` (the sends it
+              serves) and `ring_wait_us` (their append ->
+              take residency, summed)                        d2h_drain
   demux       header decode, ts-order restore, unpack        demux
   sink        callbacks, table op, rate limit, re-publish    sink
   compile     jit_step's body while tracing a new signature  (none)
@@ -59,7 +66,9 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
               deploy (`bytes`, `shards`)                     (none)
 
 `ring_wait` (emission-ring / drainer-queue residency, append -> take) is
-a difference of two stamps on two threads, not a span: `waited()`.
+a difference of two stamps on two threads, not a span: `waited()`, with
+statistics on; the served path's `what=ring` fetch span says the same
+residency as `ring_wait_us` whether they are on or not.
 
 Counters are per-query LATENCY attribution, not wall-clock utilization:
 a batched drainer fetch serving three queries charges its full wall to

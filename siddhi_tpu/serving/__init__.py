@@ -7,7 +7,10 @@ Three pieces, one invariant — the SEND PATH NEVER FETCHES:
 - drain.py     per-app async drainer: the only thread that blocks on
                D2H, feeding the unchanged delivery machinery
 - staging.py   double-buffered H2D staging: batch N+1 uploads while
-               batch N computes
+               batch N computes — for subscribers whose step takes
+               the staged batch as it is; a junction of pattern
+               runtimes (they upload columns of their own) stages
+               nothing, so every batch goes up once
 
 Enablement: `@serve` on a query / input stream / `@app:serve`
 (core/plan_facts.serve_enabled), or app-wide via the config property

@@ -11,12 +11,20 @@ what delivery does.
 
 Cadence: the thread wakes every `serving.drain.interval.ms` (bounded
 lag for a quiet ring) and immediately on a high-water kick from any
-ring (bounded occupancy under load).  Each cycle drains every ring and
-pays ONE batched `device_get` for all taken segments — len-6
-pattern/join outs contribute only their 16-byte count header (bulk
-rows stay lazy via `_LazyBatchPayload`), len-4 outs are
-window-capacity bounded and ship whole — the same amortization as
-`_EmissionDrainer._run`.
+ring (bounded occupancy under load).  Each cycle takes every ring's
+entries (one `siddhi:dispatch step=ring_read` an entry) and pays ONE
+batched `device_get` for what `runtime._header_of` names of each — the
+same amortization as `_EmissionDrainer._run`: of a partitioned
+pattern's `BandedEmission` the per-tier headers `(n_valid, n_dropped,
+ranks_used)`, of a flat headed emission (joins, @fuse stacks, the
+block step) `(n_valid, n_dropped)`, and a plain query's window-
+capacity-bounded output whole.  That fetch is where the drainer waits
+for the device step; its span (`siddhi:fetch what=ring`) says how many
+sends it served (`items`) and how long they had sat in the ring
+(`ring_wait_us`, append -> take, summed).  A headed emission's rows
+stay on the device until the subscriber reads them
+(`runtime._EmissionRows`: of a banded one only the bands below
+`ranks_used`).
 
 `drain_all()` is the synchronous edge for flush/quiesce/shutdown: it
 runs a cycle on the CALLER'S thread under the same delivery lock the
@@ -150,15 +158,18 @@ class ServingDrainer:
         # phase accounting: each item's ring residency (append -> take,
         # stamped by ring.take) plus this cycle's batched fetch wall —
         # charged per item, exactly as each item's e2e sample counts it.
-        # ONE blocking fetch for every segment taken this cycle: len-6
-        # outs contribute the 16-byte header, len-4 outs ship whole; the
-        # span carries the batch of the first send it serves
+        # ONE blocking fetch for every entry taken this cycle: a headed
+        # emission's header, a plain one whole; the span carries the
+        # batch of the first send it serves, how many it serves and
+        # their summed residency
         st = self.app.stats
         try:
             with _phases.adopt(items[0][4]):
                 fetched = _phases.fetch(
                     st, tuple(it[0].name for it in items), "ring", [
-                        _header_of(out) for _, out, _, _, _, _ in items])
+                        _header_of(out) for _, out, _, _, _, _ in items],
+                    items=len(items),
+                    ring_wait_us=sum(it[5] for it in items) // 1000)
         except Exception:  # noqa: BLE001 — drainer must survive
             traceback.print_exc()
             fetched = [None] * len(items)

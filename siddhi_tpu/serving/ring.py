@@ -5,35 +5,42 @@ consumers host-side with its Disruptor-backed async StreamJunction
 (CORE/stream/StreamJunction.java:276) — a producer never blocks on a
 consumer; it writes into a preallocated ring and moves on.
 
-TPU design (how): at small batches the device step is short next to
-the blocking emission fetch (shares not measured on the current chip),
-and @pipeline/@fuse only *amortize* the blocking `device_get` — the
-depth-k drain still makes a periodic fetch burst structural.  This
-module does the Disruptor decoupling *across the PCIe boundary*: a
-query's emissions append into a persistent DEVICE ring buffer (one
-jitted `dynamic_update_index_in_dim` dispatch, no fetch) and stay in
-HBM until the dedicated drainer thread (serving/drain.py) pulls whole
-segments asynchronously.  The producer thread never calls
+TPU design (how): in blocking delivery the sender's thread waits for
+the device step inside the emission's header fetch, and @pipeline/@fuse
+only *amortize* that `device_get`.  This module does the Disruptor
+decoupling *across the host-device boundary*: a query's emission is
+appended to a persistent DEVICE ring buffer (one jitted
+`dynamic_update_index_in_dim` dispatch, `siddhi:dispatch
+step=ring_append`, no fetch) and stays in HBM until the drainer thread
+(serving/drain.py) takes it.  The producer thread never calls
 `jax.device_get` — tests guard this with a monkeypatched fetch.
 
-Ring layout: a stacked pytree — every leaf of the query's output block
+Ring layout: a stacked pytree — EVERY leaf of the query's emission
 gains a leading [S] slot axis, preallocated once (so the ring's bytes
-are static state: MEM001/state-bytes/audit account for them).  Appends
-and reads are slot-indexed jitted programs shared across slots (the
-index rides as a traced scalar: ONE compile per output signature, not
-one per slot).  For mesh-sharded queries the ring leaves preserve the
-output's NamedSharding with a replicated slot axis, so each shard hosts
-its own ring segment and the drain fetches per-shard buffers
-independently.
+are static state: MEM001/state-bytes/audit account for them).  A flat
+emission stacks its `(n_valid, n_dropped, ts, kind, valid, cols)`; a
+partitioned pattern's `BandedEmission` (a registered pytree) stacks,
+per tier, the header `(n_valid, n_dropped, ranks_used)` and BOTH u32
+buffers of every band of `band_edges(R)` — the bands above
+`ranks_used` too: which bands a send used is known only on the device,
+so an append writes and a read copies all of them, and only then does
+the drainer fetch the headers and `_EmissionRows` the bands below
+`ranks_used`.  Appends and reads are slot-indexed jitted programs
+shared across slots (the index rides as a traced scalar: ONE compile
+per emission signature, not one per slot).  For mesh-sharded queries
+the ring leaves preserve the output's NamedSharding with a replicated
+slot axis, so each shard hosts its own ring segment and the drain
+fetches per-shard buffers independently.
 
 Overflow follows the emission-cap grow-via-replan pattern
 (`_grow_emission_cap`): a full ring doubles in one jump, gated by
 admission's state ceilings (`admit_growth`); a denied growth degrades
 to bounded blocking backpressure on the producer — never a silent
-drop.  An output-signature change (emission-cap growth replans the
-step) seals the current ring generation and opens a fresh one; sealed
-generations drain FIFO before newer entries, so delivery order per
-query is exactly send order.
+drop.  A change of the emission's signature — emission-cap growth
+replans the step; a pattern send of another `[Kb, E]` bucket, or laid
+out as other tiers, has other band shapes — seals the current ring
+generation and opens a fresh one; sealed generations drain FIFO before
+newer entries, so delivery order per query is exactly send order.
 """
 from __future__ import annotations
 
@@ -44,6 +51,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+from ..observability import phases as _phases
 from ..observability import stateobs as _stateobs
 
 jnp = jax.numpy
@@ -95,11 +103,13 @@ class _Generation:
     drain to empty and are dropped, so a signature change never reorders
     delivery."""
 
-    __slots__ = ("state", "slots", "head", "tail", "count", "key",
+    __slots__ = ("qr", "state", "slots", "head", "tail", "count", "key",
                  "placement_fallbacks", "_set", "_read")
 
-    def __init__(self, out, slots: int, owner: str):
+    def __init__(self, out, slots: int, qr):
         from ..core.steputil import jit_step
+        owner = qr.name
+        self.qr = qr
         self.slots = slots
         self.head = 0          # next write slot
         self.tail = 0          # next read slot
@@ -128,9 +138,13 @@ class _Generation:
         self._read = jit_step(_read, owner=f"serve:{owner}:read",
                               role="ring_read")
 
-    def append(self, out) -> int:
+    def append(self, out, occupancy: int) -> int:
+        """Dispatch the slot write; `occupancy`: the ring's entries once
+        this one is in (the span says it)."""
         slot = self.head
-        self.state = self._set(self.state, out, slot)
+        with _phases.phase(self.qr.app.stats, self.qr.name, "dispatch",
+                           step="ring_append", occupancy=occupancy):
+            self.state = self._set(self.state, out, slot)
         self.head = (slot + 1) % self.slots
         self.count += 1
         return slot
@@ -139,7 +153,9 @@ class _Generation:
         """Dispatch the device read of the oldest slot (lazy arrays, no
         fetch) and free it.  Device execution order guarantees the read
         completes before any later append overwrites the slot."""
-        out = self._read(self.state, self.tail)
+        with _phases.phase(self.qr.app.stats, self.qr.name, "dispatch",
+                           step="ring_read"):
+            out = self._read(self.state, self.tail)
         self.tail = (self.tail + 1) % self.slots
         self.count -= 1
         return out
@@ -183,7 +199,7 @@ class EmissionRing:
         self.placement_fallbacks = 0
 
     def _open_generation(self, out, slots: int) -> "_Generation":
-        gen = _Generation(out, slots, self.qr.name)
+        gen = _Generation(out, slots, self.qr)
         self._gens.append(gen)
         self.generation += 1
         self.placement_fallbacks += gen.placement_fallbacks
@@ -195,16 +211,17 @@ class EmissionRing:
         with self._cond:
             gen = self._gens[-1] if self._gens else None
             if gen is None or gen.key != _aval_key(out):
-                # output signature changed (emission-cap replan): seal
-                # the old generation — it keeps draining FIFO — and
-                # open a fresh buffer at the configured capacity
+                # emission signature changed (an emission-cap replan,
+                # a pattern send of another [Kb, E] bucket or tiering):
+                # seal the old generation — it keeps draining FIFO —
+                # and open a fresh buffer at the configured capacity
                 gen = self._open_generation(out, self.capacity)
             if gen.count >= gen.slots:
                 gen = self._make_room(gen, out)
-            gen.append(out)
+            occ = len(self._meta) + 1
+            gen.append(out, occ)
             self._meta.append((gen, now, ingest_ns, trace, append_ns))
             self.appends_total += 1
-            occ = len(self._meta)
             kick = occ >= self._high_water()
         if _stateobs.obs_enabled(self.qr.app):
             # serve-ring depth high-water for the sizing ledger (host
@@ -274,8 +291,10 @@ class EmissionRing:
                 min(max_n, len(self._meta))
             for _ in range(n):
                 gen, now, ingest_ns, trace, append_ns = self._meta.pop(0)
-                out.append((self.qr, gen.read_tail(), now, ingest_ns,
-                            trace, take_ns - append_ns))
+                with _phases.adopt(trace):   # the read's span: its send's
+                    slot = gen.read_tail()
+                out.append((self.qr, slot, now, ingest_ns, trace,
+                            take_ns - append_ns))
             # drop fully-drained sealed generations (their buffers free)
             while len(self._gens) > 1 and self._gens[0].count == 0:
                 self._gens.pop(0)

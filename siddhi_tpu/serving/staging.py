@@ -12,7 +12,12 @@ a staged batch enters dispatch (sync path) or the @async ingress queue
 starts — non-blocking, so by the time `to_device` runs the transfer
 has overlapped slot resolution, lock wait, and (because dispatch is
 asynchronous) the previous batch's device compute.  `to_device` then
-adopts the prestaged arrays instead of re-transferring.
+adopts the prestaged arrays instead of re-transferring.  The junction
+stages only where a subscriber calls `to_device` on the batch it was
+given (`adopts_staged`; `StreamJunction._serve_stage`): the pattern
+runtimes upload their own columns — grouped by the host, stacked, a
+shard's share — and an upload for them would be a second one, for
+nothing.
 
 Ownership is donation-discipline: the stager's device buffers are
 handed to exactly ONE step dispatch and never touched host-side again

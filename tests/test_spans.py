@@ -188,12 +188,71 @@ def test_serve_drainer_spans_carry_the_batch_of_their_send(tmp_path):
     assert not [e for e in evs if e["name"] == "fetch" and any(
         e["thread"] == s["thread"] and s["start"] <= e["start"] <= s["end"]
         for s in sends.values())]
-    # the columns go up twice a send: the stager's whole batch at the
-    # accept edge, then the pattern path's own two uploads (it never
-    # adopts: ROADMAP A4)
-    for b, s in sends.items():
-        assert len([e for e in evs if e["name"] == "h2d"
-                    and e["batch"] == b]) == 3
+    # the columns go up once a send, as in blocking delivery: the
+    # pattern path's own two uploads — the accept-edge stager has no
+    # subscriber that would adopt its copy, and stages nothing
+    def h2d_bytes(events):
+        out = {}
+        for e in events:
+            if e["name"] == "h2d":
+                out.setdefault(e["batch"], []).append(e["bytes"])
+        return list(out.values())
+
+    blocking, _ = capture(tmp_path / "blocking", pattern_ql())
+    want = h2d_bytes(blocking)
+    assert len(want) == 3 and len(want[0]) == 2
+    assert h2d_bytes(evs) == want
+    # the ring's two programs are dispatches like the step: the append on
+    # the sender's thread, inside its send, saying the ring's occupancy
+    # once it is in; the read on whichever thread drains
+    by_step = {}
+    for e in evs:
+        if e["name"] == "dispatch":
+            by_step.setdefault(e["step"], []).append(e)
+    assert sorted(by_step) == ["pattern_dense", "ring_append", "ring_read"]
+    for step in ("ring_append", "ring_read"):
+        assert sorted(e["batch"] for e in by_step[step]) == sorted(sends)
+    for e in by_step["ring_append"]:
+        s = sends[e["batch"]]
+        assert e["thread"] == s["thread"] and \
+            s["start"] <= e["start"] and e["end"] <= s["end"]
+        assert 1 <= e["occupancy"] <= 3
+    assert not [e for e in by_step["ring_read"] if any(
+        e["thread"] == s["thread"] and s["start"] <= e["start"] <= s["end"]
+        for s in sends.values())]
+    # a drain cycle's fetch says how many sends it serves and how long
+    # they sat in the ring: every send is served by exactly one cycle
+    assert sum(e["items"] for e in ring) == 3
+    assert all(e["items"] >= 1 and e["ring_wait_us"] >= 0 for e in ring)
+
+
+def test_ring_fetch_span_and_phase_report_agree_on_ring_wait(tmp_path):
+    """`what=ring`'s `ring_wait_us` and the scrape's `ring_wait` are the
+    same append -> take stamps: the report's adds, per item, the wait
+    behind the items delivered before it in its cycle, nothing else."""
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            pattern_ql("@app:statistics('BASIC')", "@serve"))
+        rt.add_batch_callback("q", lambda ts, b: None)
+        rt.start()
+        with profiler_session(tmp_path) as events:
+            for i in range(4):
+                send_pattern(rt, i)
+            rt.flush()
+        node = rt.phase_report()["queries"]["q"]["phases"]
+    finally:
+        m.shutdown()
+    ring = [e for e in events()
+            if e["name"] == "fetch" and e["what"] == "ring"]
+    assert sum(e["items"] for e in ring) == 4
+    assert node["ring_wait"]["count"] == 4
+    span_s = sum(e["ring_wait_us"] for e in ring) / 1e6
+    behind = sum(node[p]["seconds"] for p in ("d2h_drain", "demux", "sink"))
+    assert span_s <= node["ring_wait"]["seconds"] + 1e-4
+    assert node["ring_wait"]["seconds"] - span_s <= behind + 1e-3
+    # the ring's programs are booked with the step's, under their query
+    assert node["dispatch_submit"]["count"] == 3 * 4
 
 
 # -- (c) the spans add no sync and, at OFF, feed nothing ------------------------
@@ -548,9 +607,10 @@ def test_adopted_total_counts_the_uploads_a_step_took(manager):
     assert facts["fallback_total"] == 0
 
 
-def test_pattern_path_never_adopts_its_prestaged_upload(manager):
-    """ROADMAP A4, pinned: under @serve the pattern path uploads its own
-    columns and lets the stager's copy go to waste."""
+def test_pattern_path_is_not_staged_for_under_serve(manager):
+    """One upload a batch (ROADMAP A4): the pattern path uploads columns
+    of its own and never read the stager's copy, so a junction whose
+    subscribers are pattern runtimes stages nothing under @serve."""
     rt = manager.create_siddhi_app_runtime(
         pattern_ql(query_annotations="@serve"))
     rt.add_batch_callback("q", lambda ts, b: None)
@@ -559,7 +619,29 @@ def test_pattern_path_never_adopts_its_prestaged_upload(manager):
         send_pattern(rt, i)
     rt.flush()
     facts = rt.serve_staging_facts()
-    assert facts["staged_total"] == 3 and facts["adopted_total"] == 0
+    assert facts["staged_total"] == facts["adopted_total"] == 0
+    assert facts["fallback_total"] == 0
+    assert rt.serve_rings()["q"].facts()["appends_total"] == 3
+
+
+def test_a_plain_subscriber_beside_a_pattern_still_gets_its_staged_upload(
+        manager):
+    """The stager serves whoever adopts: a served filter on the stream a
+    pattern also reads takes the accept-edge upload, once a batch."""
+    rt = manager.create_siddhi_app_runtime(
+        pattern_ql(query_annotations="@serve").replace(
+            "partition with", """@serve @info(name='f')
+        from T[price > 0.0] select key, price insert into Seen;
+        partition with"""))
+    rt.add_batch_callback("q", lambda ts, b: None)
+    rt.add_batch_callback("f", lambda ts, b: None)
+    rt.start()
+    for i in range(3):
+        send_pattern(rt, i)
+    rt.flush()
+    facts = rt.serve_staging_facts()
+    assert facts["staged_total"] == facts["adopted_total"] == 3
+    assert facts["fallback_total"] == 0
 
 
 TIMEWINDOW_QL = """
