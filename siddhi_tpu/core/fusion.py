@@ -358,16 +358,20 @@ def _dispatch_pattern(qr, items) -> None:
 
 def _dispatch_pattern_sharded(qr, items) -> None:
     """Fused dispatch of a MESH-sharded partitioned pattern: each batch
-    routes through the key-space router on the host (slot binding,
-    liveness touch, dirty marking, per-shard counters — the identical
-    bookkeeping the sequential sharded path does), the grouped layouts
-    pad to one common [n*Kb, E] shape across the stack, and the whole
-    [K, ...] block runs as ONE shard_map'd scan dispatch
-    (pattern_planner._shard_fused_step)."""
+    routes through the key-space router on the host (slot binding), the
+    grouped layouts pad to one common [n*Kb, E] shape across the stack,
+    and the whole [K, ...] block runs as ONE shard_map'd scan dispatch
+    (pattern_planner._shard_fused_step).  Then, while the chips run it
+    and before anything is delivered, each batch's bookkeeping (liveness
+    touch, dirty marking, key hotness, per-shard counters — the identical
+    `_shard_feed` the sequential sharded path runs after its dispatch)."""
     p = qr.planned
     stream_id = items[0][0]
-    preps = [qr._shard_prep(stream_id, staged, now)
-             for _, staged, now in items]
+    preps, feeds = [], []
+    for _, staged, now in items:
+        key_idx, sel, slots, counts = qr._shard_prep(stream_id, staged)
+        preps.append((key_idx, sel))
+        feeds.append((slots, counts, now))
     n = preps[0][0].shape[0]
     Kb = max(ki.shape[1] for ki, _ in preps)
     E = max(s.shape[2] for _, s in preps)
@@ -387,9 +391,13 @@ def _dispatch_pattern_sharded(qr, items) -> None:
               jnp.asarray(sel_k.reshape(k, n * Kb, E)),
               jnp.asarray(key_k.reshape(k, n * Kb)),
               _now_stack(items))
-    qr.state, outs = _phases.dispatch(
-        qr, p.shard_fused_steps[stream_id], qr.state, xs, qr._in_tabs(),
-        mult=k)
+    try:
+        qr.state, outs = _phases.dispatch(
+            qr, p.shard_fused_steps[stream_id], qr.state, xs,
+            qr._in_tabs(), mult=k)
+    finally:
+        for feed in feeds:
+            qr._shard_feed(*feed)
     _deliver_fused(qr, outs, [now for _, _, now in items])
 
 

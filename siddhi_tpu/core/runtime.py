@@ -714,19 +714,22 @@ class PatternQueryRuntime(_MeshResolved):
         # host prep, each part under its own span — key -> slot routing,
         # the ts-wire build and, for the programs that take them so
         # (p.grouped_input), the columns put in the per-key order
-        # (route_keys) — then the columns go up, BEFORE the observatory
-        # and liveness feeds (obs_feed): the upload is asynchronous, so
-        # the ~8 MB of a 524,288-event send cross the link under them.
-        # Then what else prep produced goes up (h2d again), then the step
-        # (dispatch): no span's clock holds another's work.  A send whose
-        # keys' counts are far apart is laid out as a few [Kb, E] tiers
-        # (_grouped_slots), each its own upload and dispatch of the same
-        # step, the tier of the hottest keys first: its scan is the send's
-        # longest piece of device work, and it runs under the host's prep
-        # of the others.  The tiers' emissions leave as ONE emission
-        # (BandedEmission.joined: nothing is dispatched for it): one
-        # header fetch, one payload of the bands the tiers used, and the
-        # timestamp order of delivery holds over all of the send's keys
+        # (route_keys) — then the columns and what else prep produced go
+        # up (h2d, twice), then the step (dispatch): no span's clock holds
+        # another's work.  What the step does not read is done after it
+        # is submitted: the observatory and liveness feeds (obs_feed) run
+        # once the send's last step is dispatched, while the device
+        # executes it, and before the emission is handed on — the thread
+        # would otherwise stand in the header fetch for the whole step.
+        # A send whose keys' counts are far apart is laid out as a few
+        # [Kb, E] tiers (_grouped_slots), each its own upload and dispatch
+        # of the same step, the tier of the hottest keys first: its scan
+        # is the send's longest piece of device work, and it runs under
+        # the host's prep of the others.  The tiers' emissions leave as
+        # ONE emission (BandedEmission.joined: nothing is dispatched for
+        # it): one header fetch, one payload of the bands the tiers used,
+        # and the timestamp order of delivery holds over all of the
+        # send's keys
         with _phases.phase(st, self.name, "route_keys") as sp:
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
             if p.partition_positions:
@@ -752,53 +755,63 @@ class PatternQueryRuntime(_MeshResolved):
                            for _, sel, ident in tiers]
                 sp.set_metadata(
                     grouped="view" if tiers[0][2] else "take")
-        outs, now_d, fed = [], None, not p.partition_positions
+        outs, now_d = [], None
         try:
-            for t in reversed(range(len(tiers))):
-                key_idx_np, sel_np, _ = tiers[t]
-                cols, delta = grouped[t]
-                # contiguous-slot fast path: dynamic-slice state access
-                # instead of row-serialized gather/scatter (see
-                # dense_steps).  nuniq >= 2: the Kb=1 dense specialization
-                # trips an XLA:CPU fused-dynamic-slice codegen bug
-                # (RET_CHECK llvm_module), and a 1-row gather is as fast
-                # as a 1-row slice anyway
-                n, Kb = nuniq[t], sel_np.shape[0]
-                dense = (p.dense_steps is not None and n > 1 and
-                         int(key_idx_np[0]) + Kb <= cap and
-                         int(key_idx_np[n - 1]) ==
-                         int(key_idx_np[0]) + n - 1)
-                with _phases.tier_scope(t if len(tiers) > 1 else None):
-                    with _phases.phase(st, self.name, "h2d",
-                                       bytes=_phases.nbytes(*cols)):
-                        cols_d = tuple(jax.numpy.asarray(c) for c in cols)
-                    if not fed:
-                        self._feed_observers(tiers, nuniq, now)
-                        fed = True
-                    if dense and self._dirty is not None:
-                        # the dense step also time-ticks slots beyond nuniq
-                        self._dirty[int(key_idx_np[0]):
-                                    int(key_idx_np[0]) + Kb] = True
-                    with _phases.phase(st, self.name, "h2d",
-                                       bytes=_phases.nbytes(delta, sel_np)):
-                        # the base rides the step call as the numpy scalar
-                        # it is: an upload call of its own costs as much
-                        # as the delta's
-                        ts_d = (ts_base, jax.numpy.asarray(delta))
-                        sel_d = jax.numpy.asarray(sel_np)
-                        if dense:
-                            key_d = jax.numpy.asarray(int(key_idx_np[0]),
-                                                      jax.numpy.int32)
-                        elif key_idx_np is not None:
-                            key_d = jax.numpy.asarray(key_idx_np)
-                        else:
-                            key_d = jax.numpy.asarray(
-                                np.zeros((1,), np.int32))
-                        if now_d is None:   # one upload serves every tier
-                            now_d = jax.numpy.asarray(now, jax.numpy.int64)
-                    steps = p.dense_steps if dense else p.steps
-                    outs.append(self._step(steps[stream_id], cols_d, *ts_d,
-                                           sel_d, key_d, now_d))
+            try:
+                for t in reversed(range(len(tiers))):
+                    key_idx_np, sel_np, _ = tiers[t]
+                    cols, delta = grouped[t]
+                    # contiguous-slot fast path: dynamic-slice state
+                    # access instead of row-serialized gather/scatter (see
+                    # dense_steps).  nuniq >= 2: the Kb=1 dense
+                    # specialization trips an XLA:CPU fused-dynamic-slice
+                    # codegen bug (RET_CHECK llvm_module), and a 1-row
+                    # gather is as fast as a 1-row slice anyway
+                    n, Kb = nuniq[t], sel_np.shape[0]
+                    dense = (p.dense_steps is not None and n > 1 and
+                             int(key_idx_np[0]) + Kb <= cap and
+                             int(key_idx_np[n - 1]) ==
+                             int(key_idx_np[0]) + n - 1)
+                    with _phases.tier_scope(t if len(tiers) > 1 else None):
+                        with _phases.phase(st, self.name, "h2d",
+                                           bytes=_phases.nbytes(*cols)):
+                            cols_d = tuple(jax.numpy.asarray(c)
+                                           for c in cols)
+                        if dense and self._dirty is not None:
+                            # the dense step also time-ticks slots beyond
+                            # nuniq
+                            self._dirty[int(key_idx_np[0]):
+                                        int(key_idx_np[0]) + Kb] = True
+                        with _phases.phase(
+                                st, self.name, "h2d",
+                                bytes=_phases.nbytes(delta, sel_np)):
+                            # the base rides the step call as the numpy
+                            # scalar it is: an upload call of its own costs
+                            # as much as the delta's
+                            ts_d = (ts_base, jax.numpy.asarray(delta))
+                            sel_d = jax.numpy.asarray(sel_np)
+                            if dense:
+                                key_d = jax.numpy.asarray(
+                                    int(key_idx_np[0]), jax.numpy.int32)
+                            elif key_idx_np is not None:
+                                key_d = jax.numpy.asarray(key_idx_np)
+                            else:
+                                key_d = jax.numpy.asarray(
+                                    np.zeros((1,), np.int32))
+                            if now_d is None:   # one upload serves all
+                                now_d = jax.numpy.asarray(now,
+                                                          jax.numpy.int64)
+                        steps = p.dense_steps if dense else p.steps
+                        outs.append(self._step(steps[stream_id], cols_d,
+                                               *ts_d, sel_d, key_d, now_d))
+            finally:
+                # fed whether or not every tier was dispatched: a tier that
+                # ran has advanced its keys' state, and no snapshot or
+                # purge (both take _qlock, held here) may find a key
+                # advanced and not marked; a scrape takes no _qlock and
+                # reads no state, only the books, whole or a send behind
+                if p.partition_positions:
+                    self._feed_observers(tiers, nuniq, now)
         except Exception:
             # a tier that was dispatched has advanced its keys' state: what
             # it matched is delivered before the error is, so no match is
@@ -818,8 +831,10 @@ class PatternQueryRuntime(_MeshResolved):
     def _feed_observers(self, tiers, nuniq, now: int) -> None:
         """What watches a partitioned send's keys, under one `obs_feed`
         span: the key-hotness feed, the purger's liveness touch, the
-        snapshot's dirty marks — once for all of the send's tiers."""
-        with _phases.phase(self.app.stats, self.name, "obs_feed") as sp:
+        snapshot's dirty marks — once for all of the send's tiers, after
+        the last of their dispatches (the span says so: `after`)."""
+        with _phases.phase(self.app.stats, self.name, "obs_feed",
+                           after="dispatch") as sp:
             _stateobs_feed_group(
                 self, self.slot_allocator,
                 [(key_idx, sel) for key_idx, sel, _ in tiers],
@@ -851,19 +866,13 @@ class PatternQueryRuntime(_MeshResolved):
         self.state = (pstate, sel_state)
         return out, wake
 
-    def _step_and_emit(self, step, now: int, *batch_args) -> None:
-        """One sequential step, its emission handed on."""
-        out, wake = self._step(step, *batch_args)
-        _emit_output(self, out, now, wake=self._wake_arg(wake))
-
-    def _shard_prep(self, stream_id: str, staged: ev.StagedBatch,
-                    now: int) -> Tuple[np.ndarray, np.ndarray]:
+    def _shard_prep(self, stream_id: str, staged: ev.StagedBatch):
         """Staging-time routing of one batch through the key-space router
-        (host side effects: slot binding, purger liveness touch, dirty
-        marking, per-shard routing counters).  Returns the grouped
-        (key_idx [n, Kb], sel [n, Kb, E]) device layout — shared by the
-        sequential sharded path and fused dispatch (core/fusion.py)."""
-        p = self.planned
+        (host side effect: slot binding).  Returns the grouped (key_idx
+        [n, Kb], sel [n, Kb, E]) device layout and what `_shard_feed`
+        takes once the step is dispatched: the rows' resolved slots and
+        the per-shard event counts — shared by the sequential sharded path
+        and fused dispatch (core/fusion.py)."""
         router = self.shard_router
         st = self.app.stats
         with _phases.phase(st, self.name, "route_keys"):
@@ -876,7 +885,16 @@ class PatternQueryRuntime(_MeshResolved):
                 key_idx, sel, counts = router.group(slots, staged.valid)
                 sp.set_metadata(rows=key_idx.size,
                                 keys=int((key_idx < router.block).sum()))
-        with _phases.phase(st, self.name, "obs_feed") as sp:
+        return key_idx, sel, slots, counts
+
+    def _shard_feed(self, slots, counts, now: int) -> None:
+        """`_feed_observers` of the sharded path, from what `_shard_prep`
+        resolved: key hotness, purger liveness touch, dirty marks,
+        per-shard routing counters.  Nothing here goes to the device, so
+        it runs after the step's dispatch, under it."""
+        st = self.app.stats
+        with _phases.phase(st, self.name, "obs_feed",
+                           after="dispatch") as sp:
             _stateobs_feed_slots(self, self.slot_allocator, slots, sp)
             if self._touch is not None:
                 self._touch(slots, now)
@@ -884,22 +902,22 @@ class PatternQueryRuntime(_MeshResolved):
                 live = slots[slots >= 0]
                 if live.size:
                     # global state column of slot s under the shard layout
-                    self._dirty[router.state_row(live)] = True
+                    self._dirty[self.shard_router.state_row(live)] = True
             if st.enabled:
                 st.shard_events(self.name, counts)
-        return key_idx, sel
 
     def _process_sharded(self, stream_id: str, staged: ev.StagedBatch,
                          now: int) -> None:
         """Multi-chip path: route each key to its shard (slot % n), build the
-        stacked [n*Kb, E] layout, run the shard_map step."""
+        stacked [n*Kb, E] layout, run the shard_map step, feed the
+        observers while the chips run it."""
         st = self.app.stats
         # the ts-wire build is host prep, booked where process_staged
         # books it; _shard_prep's own route_keys span is slot resolution
         # (fused dispatch shares it and ships a stacked i64 ts)
         with _phases.phase(st, self.name, "route_keys"):
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
-        key_idx, sel = self._shard_prep(stream_id, staged, now)
+        key_idx, sel, slots, counts = self._shard_prep(stream_id, staged)
         flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
         with _phases.phase(st, self.name, "h2d",
                            bytes=_phases.nbytes(ts_delta, sel, key_idx,
@@ -910,8 +928,13 @@ class PatternQueryRuntime(_MeshResolved):
             sel_d = jax.numpy.asarray(flat(sel))
             key_d = jax.numpy.asarray(flat(key_idx))
             now_d = jax.numpy.asarray(now, jax.numpy.int64)
-        self._step_and_emit(self.planned.steps[stream_id], now, raw_cols,
-                            *ts_d, sel_d, key_d, now_d)
+        try:
+            out, wake = self._step(self.planned.steps[stream_id], raw_cols,
+                                   *ts_d, sel_d, key_d, now_d)
+        finally:
+            # as in process_staged: fed whether or not the step came back
+            self._shard_feed(slots, counts, now)
+        _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def on_timer(self, now: int) -> None:
         p = self.planned

@@ -107,6 +107,26 @@ def capture(tmp_path, ql, n_sends=3, batch_cb=True, await_delivery=False):
     return events(), rows
 
 
+def _inside(evs, s):
+    """The spans of one send's thread that lie inside it, in start order."""
+    return sorted((e for e in evs if e is not s and e["thread"] == s["thread"]
+                   and s["start"] <= e["start"] and e["end"] <= s["end"]),
+                  key=lambda e: e["start"])
+
+
+def _header_fetch(inside):
+    """The one `fetch` of a blocking send that holds the wait for its step."""
+    (header,) = [e for e in inside
+                 if e["name"] == "fetch" and e["what"] == "header"]
+    return header
+
+
+def _prep_feeds(inside):
+    """The `obs_feed` spans of a send's prep (the emission side's, inside
+    demux, says no `after`)."""
+    return [e for e in inside if e["name"] == "obs_feed" and "after" in e]
+
+
 # -- (a) what a capture of the blocking path holds ----------------------------
 
 def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
@@ -120,8 +140,7 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
     taken = ("stage", "route_keys", "obs_feed", "h2d", "dispatch", "fetch",
              "demux", "sink")
     for s in sends:
-        inside = [e for e in evs if e is not s and e["thread"] == s["thread"]
-                  and s["start"] <= e["start"] and e["end"] <= s["end"]]
+        inside = _inside(evs, s)
         names = [e["name"] for e in inside]
         for name in taken:
             assert name in names, (name, names)
@@ -139,21 +158,28 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
         # columns into its [4096, 2] cells by a take (an identity `sel`
         # would read "view": tests/test_grouped_columns.py)
         assert by["route_keys"][0]["grouped"] == "take"
-        # the grouped columns go up between routing and the observatory
-        # feed (the transfer overlaps it), what else prep produced after
+        # the grouped columns go up after routing, then what else prep
+        # produced, then the step; the observatory feed runs once the step
+        # is submitted (under it, on a chip) and before the header fetch,
+        # which holds the wait for the step
         assert len(by["h2d"]) == 2
         assert by["h2d"][0]["bytes"] == 2 * 4096 * (8 + 4 + 4)
         assert by["route_keys"][0]["end"] <= by["h2d"][0]["start"]
-        assert by["h2d"][0]["end"] <= by["obs_feed"][0]["start"]
-        assert by["h2d"][1]["start"] >= by["obs_feed"][0]["end"]
+        assert by["h2d"][0]["end"] <= by["h2d"][1]["start"]
+        assert by["h2d"][1]["end"] <= by["dispatch"][0]["start"]
+        # (the span says which order it ran in; demux holds a second
+        # `obs_feed`, the emission side's, which says nothing)
+        assert [e.get("after") for e in by["obs_feed"]] == ["dispatch", None]
+        assert by["dispatch"][0]["end"] <= by["obs_feed"][0]["start"]
+        assert by["obs_feed"][0]["end"] <= _header_fetch(inside)["start"]
         kinds = sorted(e["what"] for e in by["fetch"])
         assert kinds == ["header", "rows"]        # payload: `valid` only
         assert all(e["bytes"] > 0 for e in by["fetch"])
         assert by["demux"][0]["rows"] == N_KEYS
         # pipeline order on the thread
         order = [by[n][0]["start"] for n in
-                 ("stage", "route_keys", "h2d", "obs_feed", "dispatch",
-                  "demux")]
+                 ("stage", "route_keys", "h2d", "dispatch", "obs_feed",
+                  "fetch", "demux")]
         assert order == sorted(order)
         # the emission side's observatory feed and the subscriber sit
         # inside demux
@@ -163,6 +189,102 @@ def test_off_capture_holds_every_span_nested_in_its_send(tmp_path):
     # nothing of the runtime ran outside a send
     assert all(any(s["start"] <= e["start"] and e["end"] <= s["end"]
                    for s in sends) for e in evs)
+
+
+# -- (a') the observatory feed runs under the step, on every pattern path --------
+
+def test_sharded_send_feeds_the_observatory_after_its_dispatch(tmp_path):
+    """The mesh path: `shard_group` -> `h2d` -> `dispatch` -> `obs_feed` ->
+    the header `fetch` that waits for the step."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    evs, rows = capture(tmp_path, pattern_ql("@app:mesh(shards='4')"))
+    assert rows == [N_KEYS] * 3
+    sends = [e for e in evs if e["name"] == "send"]
+    assert len(sends) == 3
+    for s in sends:
+        inside = _inside(evs, s)
+        (feed,) = _prep_feeds(inside)
+        assert feed["after"] == "dispatch" and feed["keys"] == N_KEYS
+        (group,) = [e for e in inside if e["name"] == "shard_group"]
+        (h2d,) = [e for e in inside if e["name"] == "h2d"]
+        (disp,) = [e for e in inside if e["name"] == "dispatch"]
+        assert disp["step"] == "pattern_step_sharded" and h2d["shards"] == 4
+        order = [group["end"], h2d["start"], h2d["end"], disp["start"],
+                 disp["end"], feed["start"], feed["end"],
+                 _header_fetch(inside)["start"]]
+        assert order == sorted(order)
+
+
+def test_tiered_send_feeds_once_after_the_last_of_its_dispatches(
+        tmp_path, monkeypatch):
+    """A send whose keys' counts are far apart: three tiers, three uploads
+    and dispatches of the same step, ONE feed of all the tiers' keys —
+    after the last dispatch, before the one header fetch."""
+    from siddhi_tpu.core import keyslots
+    monkeypatch.setattr(keyslots, "_TIER_MIN_CELLS", 0)
+    counts = np.concatenate([np.full(200, 2), np.full(10, 16), [100]])
+    keys = np.repeat(np.arange(counts.size, dtype=np.int64) * 7 + 3, counts)
+    n = keys.size
+
+    def send(rt, i):
+        # all openers: partials pile up per key, nothing matches
+        rt.get_input_handler("T").send_columns(
+            [keys.copy(), np.full(n, 1.0 + i, np.float32),
+             np.ones(n, np.int32)],
+            timestamps=np.full(n, 1000 + 10 * i, np.int64))
+
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(pattern_ql())
+        rt.add_batch_callback("q", lambda ts, b: None)
+        rt.start()
+        send(rt, 0)
+        with profiler_session(tmp_path) as events:
+            send(rt, 1)
+            send(rt, 2)
+            rt.flush()
+    finally:
+        m.shutdown()
+    evs = events()
+    sends = [e for e in evs if e["name"] == "send"]
+    assert len(sends) == 2
+    for s in sends:
+        inside = _inside(evs, s)
+        (route,) = [e for e in inside if e["name"] == "route_keys"]
+        assert route["tiers"] == 3 and route["keys"] == counts.size
+        disp = [e for e in inside if e["name"] == "dispatch"]
+        # (slots are bound in arrival order, so a tier's may be contiguous)
+        assert {d["step"] for d in disp} <= {"pattern_step", "pattern_dense"}
+        assert [d["tier"] for d in disp] == [2, 1, 0]     # hottest first
+        (feed,) = _prep_feeds(inside)
+        assert feed["keys"] == counts.size and "tier" not in feed
+        assert disp[-1]["end"] <= feed["start"] and \
+            feed["end"] <= _header_fetch(inside)["start"]   # the ONE header
+        assert all(e["end"] <= disp[-1]["start"] for e in inside
+                   if e["name"] == "h2d")
+
+
+def test_served_send_feeds_on_the_senders_thread_before_the_ring_append(
+        tmp_path):
+    """Under `@serve` the feed stays in the send call, on the sender's
+    thread: after the step's dispatch, before the emission is handed to
+    the ring (`ring_append`)."""
+    evs, rows = capture(tmp_path, pattern_ql(query_annotations="@serve"),
+                        await_delivery=True)
+    assert rows == [N_KEYS] * 3
+    sends = [e for e in evs if e["name"] == "send"]
+    assert len(sends) == 3
+    feeds = [e for e in evs if e["name"] == "obs_feed" and "after" in e]
+    assert sorted(e["batch"] for e in feeds) == \
+        sorted(s["batch"] for s in sends)
+    for s in sends:
+        inside = _inside(evs, s)
+        (feed,) = _prep_feeds(inside)
+        assert feed["keys"] == N_KEYS
+        by_step = {e["step"]: e for e in inside if e["name"] == "dispatch"}
+        assert by_step["pattern_dense"]["end"] <= feed["start"]
+        assert feed["end"] <= by_step["ring_append"]["start"]
 
 
 # -- (b) the served path: delivery on the drainer thread ------------------------
