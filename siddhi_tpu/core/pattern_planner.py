@@ -380,25 +380,36 @@ def plan_pattern_query(
             # events along E in arrival order.  sel_idx [Kb, E] holds each
             # cell's batch index (-1 = padding; a padding cell carries
             # row 0's values, which no valid selection reads).
+            # The body is a list of SECTIONS, each a `jax.named_scope`:
+            # op-name metadata only, the compiled program is the same
+            # without them (tests/test_spans.py).  A device trace carries
+            # an op's section in its EVENT METADATA (the `tf_op` stat of
+            # the plane's `event_metadata`, not a stat of the event), where
+            # `benchmarks/harness/step_sections.py` reads it.
             *arrays, scalars = packed      # b32, lo64, hi64: each [W, K]
-            cols = tuple(c.astype(d) for c, d in zip(cols, schema.dtypes))
-            valid = sel_idx >= 0
-            ord_ = jnp.maximum(sel_idx, 0).astype(jnp.int64)
+            with jax.named_scope("event_load"):
+                cols = tuple(c.astype(d)
+                             for c, d in zip(cols, schema.dtypes))
+                valid = sel_idx >= 0
+                ord_ = jnp.maximum(sel_idx, 0).astype(jnp.int64)
             Kb = ts.shape[0]
-            if dense:
-                # key_ref is a scalar key_lo: the batch's slots are the
-                # contiguous range [key_lo, key_lo+Kb) -> DMA-speed slices
-                key_lo = jnp.asarray(key_ref, jnp.int32)
-                z = jnp.asarray(0, jnp.int32)
-                key_idx = key_lo + jnp.arange(Kb, dtype=jnp.int32)
-                subs = [lax.dynamic_slice(a, (z, key_lo), (a.shape[0], Kb))
-                        for a in arrays]
-            else:
-                # generic path: gathers riding the minor (key) axis
-                key_idx = key_ref
-                subs = [a[:, key_idx] for a in arrays]
-            # 64-bit values exist from here on, for these Kb keys only
-            sub = packer.unpack(*subs, scalars)
+            with jax.named_scope("state_load"):
+                if dense:
+                    # key_ref is a scalar key_lo: the batch's slots are the
+                    # contiguous range [key_lo, key_lo+Kb) -> DMA-speed
+                    # slices
+                    key_lo = jnp.asarray(key_ref, jnp.int32)
+                    z = jnp.asarray(0, jnp.int32)
+                    key_idx = key_lo + jnp.arange(Kb, dtype=jnp.int32)
+                    subs = [lax.dynamic_slice(a, (z, key_lo),
+                                              (a.shape[0], Kb))
+                            for a in arrays]
+                else:
+                    # generic path: gathers riding the minor (key) axis
+                    key_idx = key_ref
+                    subs = [a[:, key_idx] for a in arrays]
+                # 64-bit values exist from here on, for these Kb keys only
+                sub = packer.unpack(*subs, scalars)
 
             def body(carry, xs):
                 st = carry
@@ -408,21 +419,22 @@ def plan_pattern_query(
                                       now_k, in_tabs)
                 return st, emit
 
-            xs = (tuple(c.T for c in cols), ts.T, valid.T)   # scan over E
-            # named scopes are op-name metadata only (a profiler trace
-            # reads the section off each device op): the compiled program
-            # is the same with and without them
+            # traced where it always was, after the rows' gather: the
+            # scopes change no equation's place in the program
+            with jax.named_scope("event_load"):
+                xs = (tuple(c.T for c in cols), ts.T, valid.T)  # scan over E
             with jax.named_scope("nfa_advance"):
                 sub, emits = lax.scan(body, sub, xs)
 
-            *news, nscal = packer.pack(sub)
-            if dense:
-                arrays = [lax.dynamic_update_slice(a, n, (z, key_lo))
-                          for a, n in zip(arrays, news)]
-            else:
-                # out-of-bounds (padding) rows are dropped by scatter
-                arrays = [a.at[:, key_idx].set(n, mode="drop")
-                          for a, n in zip(arrays, news)]
+            with jax.named_scope("state_store"):
+                *news, nscal = packer.pack(sub)
+                if dense:
+                    arrays = [lax.dynamic_update_slice(a, n, (z, key_lo))
+                              for a, n in zip(arrays, news)]
+                else:
+                    # out-of-bounds (padding) rows are dropped by scatter
+                    arrays = [a.at[:, key_idx].set(n, mode="drop")
+                              for a, n in zip(arrays, news)]
 
             sel_state, out, wake = _emit_matches(
                 pexec, sel, spec, emits, ord_, sel_state, sub, now,
@@ -547,11 +559,12 @@ def _gathering(body):
     to cause (PERF.md, PR 31)."""
     def step(packed, sel_state, raw_cols, raw_ts, sel_idx, key_ref, now,
              in_tabs=()):
-        arrays = (*raw_cols, raw_ts)
-        csel = jnp.clip(sel_idx, 0, raw_ts.shape[0] - 1)
-        planes = iter(jnp.stack(
-            [pl for a in arrays for pl in _planes(a)])[:, csel])
-        *cols, ts = (_from_planes(planes, a.dtype) for a in arrays)
+        with jax.named_scope("event_load"):
+            arrays = (*raw_cols, raw_ts)
+            csel = jnp.clip(sel_idx, 0, raw_ts.shape[0] - 1)
+            planes = iter(jnp.stack(
+                [pl for a in arrays for pl in _planes(a)])[:, csel])
+            *cols, ts = (_from_planes(planes, a.dtype) for a in arrays)
         return body(packed, sel_state, tuple(cols), ts, sel_idx, key_ref,
                     now, in_tabs)
     return step
@@ -574,12 +587,18 @@ def _jit_sequential(body, owner, role, grouped=False):
     minor dimension of E meets the TPU's 128-lane tiling at upload."""
     def step(packed, sel_state, raw_cols, ts_base, ts_delta, sel_idx,
              key_ref, now, in_tabs=()):
-        ts = ev.decode_ts(ts_base, ts_delta)
-        if grouped:
-            raw_cols = tuple(c.reshape(sel_idx.shape) for c in raw_cols)
-            ts = ts.reshape(sel_idx.shape)
-        return body(packed, sel_state, raw_cols, ts, sel_idx, key_ref, now,
-                    in_tabs)
+        # the program's outermost scope names its [Kb, E] rectangle (static:
+        # a rectangle is a signature already), so a trace tells a tiered
+        # send's executions apart; the sections of the body nest in it
+        with jax.named_scope("rect_%dx%d" % sel_idx.shape):
+            with jax.named_scope("event_load"):
+                ts = ev.decode_ts(ts_base, ts_delta)
+                if grouped:
+                    raw_cols = tuple(c.reshape(sel_idx.shape)
+                                     for c in raw_cols)
+                    ts = ts.reshape(sel_idx.shape)
+            return body(packed, sel_state, raw_cols, ts, sel_idx, key_ref,
+                        now, in_tabs)
     return jit_step(step, owner=owner, role=role, donate_argnums=(0, 1))
 
 
@@ -682,32 +701,39 @@ def _shard_local(body):
               in_tabs=()):
         *arrays, scalars = packed
         old_scalars = scalars
-        # replicated scalar counters become device-varying inside; mark them
-        scalars = tuple(lax.pcast(s, ("shard",), to="varying")
-                        for s in scalars)
-        raw_cols = tuple(lax.pcast(c, ("shard",), to="varying")
-                         for c in raw_cols)
-        raw_ts = lax.pcast(raw_ts, ("shard",), to="varying")
-        in_tabs = jax.tree.map(
-            lambda x: lax.pcast(x, ("shard",), to="varying"), in_tabs)
+        with jax.named_scope("mesh_reduce"):
+            # replicated scalar counters become device-varying inside; mark
+            # them
+            scalars = tuple(lax.pcast(s, ("shard",), to="varying")
+                            for s in scalars)
+            raw_cols = tuple(lax.pcast(c, ("shard",), to="varying")
+                             for c in raw_cols)
+            raw_ts = lax.pcast(raw_ts, ("shard",), to="varying")
+            in_tabs = jax.tree.map(
+                lambda x: lax.pcast(x, ("shard",), to="varying"), in_tabs)
         ps, ss, out, wake = body((*arrays, scalars), sel_state, raw_cols,
                                  raw_ts, sel_idx, key_idx, now, in_tabs)
-        if isinstance(out, BandedEmission):
-            # a plain pair out of the shard_map (its out_specs name the
-            # header's scalars and the bands apart); _shard_step re-wraps
-            (n_valid, n_dropped, ranks_used), bands = out.tiers[0]
-            out = ((lax.psum(n_valid, "shard"), lax.psum(n_dropped, "shard"),
-                    lax.pmax(ranks_used, "shard")), bands)
-        else:
-            out = (lax.psum(out[0], "shard"),
-                   lax.psum(out[1], "shard")) + out[2:]
-        *narrays, nscal = ps
-        # re-replicate scalar counters: old + psum(local delta)
-        nscal = tuple(
-            old + lax.psum(new - lax.pcast(old, ("shard",), to="varying"),
-                           "shard")
-            for old, new in zip(old_scalars, nscal))
-        wake = pmin_i64(wake, "shard")
+        # a chip that finishes early waits in these collectives, and the
+        # trace books the wait as busy: the section is where skew shows
+        with jax.named_scope("mesh_reduce"):
+            if isinstance(out, BandedEmission):
+                # a plain pair out of the shard_map (its out_specs name the
+                # header's scalars and the bands apart); _shard_step
+                # re-wraps
+                (n_valid, n_dropped, ranks_used), bands = out.tiers[0]
+                out = ((lax.psum(n_valid, "shard"),
+                        lax.psum(n_dropped, "shard"),
+                        lax.pmax(ranks_used, "shard")), bands)
+            else:
+                out = (lax.psum(out[0], "shard"),
+                       lax.psum(out[1], "shard")) + out[2:]
+            *narrays, nscal = ps
+            # re-replicate scalar counters: old + psum(local delta)
+            nscal = tuple(
+                old + lax.psum(
+                    new - lax.pcast(old, ("shard",), to="varying"), "shard")
+                for old, new in zip(old_scalars, nscal))
+            wake = pmin_i64(wake, "shard")
         return (*narrays, nscal), ss, out, wake
 
     return local
@@ -958,8 +984,9 @@ def compact_emission(out, EP: int, K: int, compact_rows: int,
                        cmask.reshape(R * K),
                        tuple(cmp(c).reshape(R * K) for c in ocols))
     else:
-        n_valid = jnp.sum(ovalid.astype(jnp.int64))
-        n_dropped = jnp.zeros((), jnp.int64)
+        with jax.named_scope("emission_compaction"):
+            n_valid = jnp.sum(ovalid.astype(jnp.int64))
+            n_dropped = jnp.zeros((), jnp.int64)
     if not banded:
         # leading scalars: valid-row count (drainer skips empty outputs
         # with one 16-byte read) and overflow count (rows beyond R
@@ -1000,6 +1027,22 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
     ~7 output arrays costs GBs of HBM traffic).  Valid rows beyond R
     matches per key per batch are counted in the out[1] dropped scalar
     (`compact_emission`; `banded`: as a `BandedEmission`)."""
+    with jax.named_scope("match_rows"):
+        rows, env, EP, K = _match_rows(spec, emits, ord_, now, key_idx)
+    with jax.named_scope("selector"):
+        sel_state, out = sel.process(sel_state, rows, env)
+
+    out = compact_emission(out, EP, K, compact_rows,
+                           sel.out_types if banded else None)
+
+    with jax.named_scope("match_rows"):
+        wake = _next_wake(spec, pstate)
+    return sel_state, out, wake
+
+
+def _match_rows(spec: PatternSpec, emits, ord_, now, key_idx):
+    """(rows, env, EP, K): the scan's emissions [E,P+1,K] flattened into
+    the selector's Rows over the E*(P+1)*K grid, and the capture env."""
     mask = emits["mask"]                       # [E,P+1,K]
     E, P1, K = mask.shape
     EP = E * P1
@@ -1050,14 +1093,12 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
         gslot=gslot,
         cols=(),
     )
-    with jax.named_scope("selector"):
-        sel_state, out = sel.process(sel_state, rows, env)
+    return rows, env, EP, K
 
-    out = compact_emission(out, EP, K, compact_rows,
-                           sel.out_types if banded else None)
 
-    # next wakeup: earliest absent deadline (standalone `not X for t` atoms
-    # and timed absent sides of logical pairs whose wait hasn't elapsed)
+def _next_wake(spec: PatternSpec, pstate):
+    """Next wakeup: earliest absent deadline (standalone `not X for t` atoms
+    and timed absent sides of logical pairs whose wait hasn't elapsed)."""
     wake = jnp.asarray(NO_WAKEUP, jnp.int64)
     for a in spec.atoms:
         if a.absent:
@@ -1073,4 +1114,4 @@ def _emit_matches(pexec: PatternExec, sel: SelectorExec, spec: PatternSpec,
             w = jnp.min(jnp.where(
                 at_pos, pstate.entry_ts + a.partner.waiting_time, NO_WAKEUP))
             wake = jnp.minimum(wake, w)
-    return sel_state, out, wake
+    return wake

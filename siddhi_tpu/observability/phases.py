@@ -29,9 +29,23 @@ trace's; the sampled deep mode (`profile.sample.every=N`) fences every
 Nth dispatch per query with `block_until_ready` and books the fence wall
 as `device_compute`.
 
+Inside the device step the host has no spans: the sequential pattern
+programs (core/pattern_planner.py) put every op under a `jax.named_scope`
+SECTION — `event_load`, `state_load`, `nfa_advance`, `state_store`,
+`match_rows`, `selector`, `emission_compaction`, `emission_bands`, on a
+mesh `mesh_reduce` — inside one `rect_<Kb>x<E>` scope a program.  Scopes
+are op-name metadata: in a device trace they are the `tf_op` stat of each
+op's EVENT METADATA (the plane's `event_metadata`, beside `program_id` and
+`hlo_category`), not a stat of the event itself, so
+`jax.profiler.ProfileData` does not show them; TensorBoard / xprof group
+by them, and `benchmarks/harness/step_sections.py` reads the `XSpace`
+message for them (device time by section and by rectangle).
+
 Spans (`siddhi:<name>`) and the scrape phase each feeds:
 
-  send        whole InputHandler.send / send_columns call   (no phase:
+  send        whole InputHandler.send / send_columns call;
+              `minflt` while a session records it: the minor
+              page faults the sending thread took in the call  (no phase:
               its self time is what no child span covers)
   stage       pad/adopt or pack_np into a StagedBatch        stage_host
   route_keys  key -> slot routing, grouping, ts-wire build;
@@ -87,6 +101,12 @@ from jax.profiler import TraceAnnotation
 
 from . import tracing as _tracing
 from .memory import tree_nbytes
+
+try:
+    import resource
+    _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+except ImportError:          # no `resource` module on this platform
+    resource, _RUSAGE_THREAD = None, None
 
 # canonical order — every surface (report, /metrics, /timeseries, PERF
 # tables) lists phases in pipeline order, not dict order
@@ -390,12 +410,33 @@ def phase(stats, query, name: str, mult: int = 1, **meta):
     return _Timed(ann, stats, queries, name, mult, meta)
 
 
+def _recorded() -> bool:
+    """Whether somebody records the spans now (a profiler session or a
+    DETAIL trace): what costs something to say is said only then."""
+    return TraceAnnotation.is_enabled() or _tracing.active() is not None
+
+
+def _minor_faults() -> Optional[int]:
+    """Minor page faults of the calling thread so far; None where the
+    platform has no RUSAGE_THREAD."""
+    if _RUSAGE_THREAD is None:
+        return None
+    return resource.getrusage(_RUSAGE_THREAD).ru_minflt
+
+
 class send:
     """`siddhi:send` over one InputHandler call, under `batch` — the next
     number of the junction's send sequence, which every span the send
-    causes then carries (batch_scope + phase entered as one)."""
+    causes then carries (batch_scope + phase entered as one).
 
-    __slots__ = ("scope", "stats", "stream", "events", "span")
+    While somebody records it (a profiler session or a DETAIL trace, as
+    `fetch` decides for `bytes`) the span also says `minflt`: the minor
+    page faults the sending thread took inside the call.  A send makes and
+    frees its staging arrays; fresh pages from the kernel show here (some
+    250 faults a MB without huge pages).  On the v5e hosts it reads 0 in
+    every cell and allocator mode measured so far (PERF.md, PR 35)."""
+
+    __slots__ = ("scope", "stats", "stream", "events", "span", "faults0")
 
     def __init__(self, stats, stream: str, batch: int,
                  events: Optional[int]):
@@ -407,9 +448,12 @@ class send:
         meta = {} if self.events is None else {"events": self.events}
         self.span = phase(self.stats, None, "send", stream=self.stream,
                           **meta)
+        self.faults0 = _minor_faults() if _recorded() else None
         return self.span.__enter__()
 
     def __exit__(self, *exc):
+        if self.faults0 is not None:
+            self.span.set_metadata(minflt=_minor_faults() - self.faults0)
         self.span.__exit__(*exc)
         return self.scope.__exit__(*exc)
 
@@ -452,7 +496,7 @@ def fetch(stats, query, what: str, tree, mult: int = 1, **meta):
     what the caller knows of the fetch beforehand (FETCH_STATS)."""
     with phase(stats, query, "fetch", mult, what=what, **meta) as sp:
         out = jax.device_get(tree)
-        if TraceAnnotation.is_enabled() or _tracing.active() is not None:
+        if _recorded():
             sp.set_metadata(bytes=tree_nbytes(out))
     return out
 

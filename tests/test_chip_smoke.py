@@ -104,6 +104,9 @@ def test_cache_helper_sets_only_the_checkout_dir_when_env_is_unset(
         assert jax.config.jax_compilation_cache_dir == path
         assert jax.config.jax_persistent_cache_min_compile_time_secs == \
             before[1]
+        # either way the op names are part of an entry's key: a cache a
+        # tree with other named scopes filled must not name this tree's ops
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         jax.config.update("jax_compilation_cache_dir", before[0])
 

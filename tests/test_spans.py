@@ -8,6 +8,7 @@ import contextlib
 import glob
 import os
 import time
+import types
 
 import jax
 import numpy as np
@@ -671,40 +672,108 @@ def test_every_jitted_step_lowers_to_its_role(planner, manager):
     assert "wrapped" not in modules
 
 
-def test_named_scopes_leave_the_compiled_pattern_step_as_it_was(manager,
+SECTION_SCOPES = ("event_load", "state_load", "nfa_advance", "state_store",
+                  "emission_compaction", "emission_bands")
+
+
+@pytest.mark.parametrize("role", [
+    "pattern_dense", "pattern_step", "pattern_step_sharded"])
+def test_named_scopes_leave_the_compiled_pattern_step_as_it_was(role,
                                                                 monkeypatch):
-    """jax.named_scope is op-name metadata: XLA's cost analysis of the
-    pattern step is the same with the scopes and without them."""
-    def costs(scoped):
+    """jax.named_scope is op-name metadata: the lowered program without its
+    debug info, and XLA's cost analysis of the compiled one, are the same
+    with the sections and the `rect_*` scope and with `jax.named_scope`
+    patched out — for each of the three programs the benchmark's cells
+    run."""
+    mesh = None
+    if role == "pattern_step_sharded":
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("shard",))
+
+    def facts(scoped):
         if not scoped:
             monkeypatch.setattr(jax, "named_scope",
                                 lambda name: contextlib.nullcontext())
         m = SiddhiManager()
         try:
-            rt = m.create_siddhi_app_runtime(pattern_ql())
+            rt = m.create_siddhi_app_runtime(pattern_ql(), mesh=mesh) \
+                if mesh is not None else \
+                m.create_siddhi_app_runtime(pattern_ql())
             rt.add_batch_callback("q", lambda ts, b: None)
             rt.start()
-            send_pattern(rt, 0)
+            send_pattern(rt, 0)               # contiguous keys: dense
+            h = rt.get_input_handler("T")     # gappy keys: gather / scatter
+            h.send_columns([np.array([3, 40, 700], np.int64),
+                            np.full(3, 1.0, np.float32),
+                            np.full(3, 1, np.int32)],
+                           timestamps=np.full(3, 5000, np.int64))
             rt.flush()
             out = {}
             for _role, fn, argspecs in rt.compiled_steps("q"):
                 if argspecs is not None:
-                    text = fn.lower(*argspecs).as_text()
-                    ca = fn.lower(*argspecs).compile().cost_analysis()
+                    lowered = fn.lower(*argspecs)
+                    ca = lowered.compile().cost_analysis()
+                    named = lowered.as_text(debug_info=True)
                     out[fn._siddhi_role] = (
                         ca.get("flops"), ca.get("bytes accessed"),
-                        "nfa_advance" in fn.lower(*argspecs).as_text(
-                            debug_info=True), len(text))
+                        lowered.as_text(),
+                        {n for n in SECTION_SCOPES + ("rect_", "mesh_reduce")
+                         if n in named})
         finally:
             m.shutdown()
             monkeypatch.undo()
         return out
 
-    with_scopes, without = costs(True), costs(False)
-    assert set(with_scopes) == set(without) == {"pattern_dense"}
-    flops, nbytes, named, _ = with_scopes["pattern_dense"]
-    assert named and not without["pattern_dense"][2]
-    assert (flops, nbytes) == without["pattern_dense"][:2]
+    with_scopes, without = facts(True), facts(False)
+    assert role in with_scopes and set(with_scopes) == set(without)
+    flops, nbytes, text, named = with_scopes[role]
+    want = set(SECTION_SCOPES) | {"rect_"}
+    if mesh is not None:
+        want.add("mesh_reduce")
+    assert want <= named and not without[role][3]
+    assert (flops, nbytes) == without[role][:2]
+    assert text == without[role][2]
+
+
+def test_send_span_says_the_threads_page_faults_while_recorded(
+        tmp_path, monkeypatch):
+    """`siddhi:send` carries `minflt`, the sending thread's minor page
+    faults inside the call (a `getrusage(RUSAGE_THREAD)` pair), only while
+    a session records it; where the platform has no RUSAGE_THREAD the span
+    says nothing."""
+    calls, readings = [], iter([1100, 1207, 1300, 1414])
+
+    def getrusage(who):
+        calls.append(who)
+        return types.SimpleNamespace(ru_minflt=next(readings))
+
+    monkeypatch.setattr(ph.resource, "getrusage", getrusage)
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(pattern_ql())
+        rt.add_batch_callback("q", lambda ts, b: None)
+        rt.start()
+        send_pattern(rt, 0)
+        rt.flush()
+        assert calls == []                    # nobody records: not asked
+        with profiler_session(tmp_path) as events:
+            send_pattern(rt, 1)
+            send_pattern(rt, 2)
+            rt.flush()
+        assert calls == [ph._RUSAGE_THREAD] * 4
+        faults = [e["minflt"] for e in events() if e["name"] == "send"]
+        assert faults == [107, 114]           # exit's reading - entry's
+        monkeypatch.setattr(ph, "_RUSAGE_THREAD", None)
+        del calls[:]
+        with profiler_session(tmp_path / "none") as events:
+            send_pattern(rt, 3)
+            rt.flush()
+        assert calls == []
+        (sent,) = [e for e in events() if e["name"] == "send"]
+        assert "minflt" not in sent and sent["events"] == 2 * N_KEYS
+    finally:
+        m.shutdown()
 
 
 # -- counters that count, and two that were missing -----------------------------

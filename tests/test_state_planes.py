@@ -727,3 +727,31 @@ def test_v5e_sharded_event_gather_lands_in_fast_memory(guard_compiled):
         r"^\s*%fusion\S* = u32\[\d+,6\]\{(\S*?)\} fusion\(.*gather",
         compiled.as_text(), re.M)
     assert len(layouts) == 1 and layouts[0].endswith("S(1)"), layouts
+
+
+ROLES = {"gather": "pattern_step", "dense": "pattern_dense",
+         "sharded": "pattern_step_sharded"}
+
+
+@pytest.mark.parametrize("step", sorted(GUARD))
+def test_v5e_compile_keeps_every_op_in_one_section_and_rectangle(
+        step, guard_compiled):
+    """What a device trace of the cells' programs will show: each compiled
+    instruction that runs as an op and carries an `op_name` of the program
+    names one section and the program's one rectangle
+    (tests/test_step_sections.py judges the CPU's compile the same way);
+    what the v5e's compiler adds of its own is printed by opcode."""
+    from test_step_sections import judged
+    g = GUARD[step]
+    _p, compiled = guard_compiled(step)
+    named, short, compilers = judged(compiled.as_text(), ROLES[step])
+    total = sum(named.values()) + len(short)
+    print(f"{step}: {total} instructions by (section, rectangle): "
+          f"{dict(named)}; naming none: {short}; the compiler's own: "
+          f"{dict(compilers)}")
+    assert {rect for _, rect in named} == \
+        {"rect_%dx4" % (g["Kb"] * g["chips"])}
+    assert {"state_load", "nfa_advance", "state_store",
+            "emission_compaction", "emission_bands"} <= \
+        {s for s, _ in named}
+    assert len(short) < 0.05 * total, short
