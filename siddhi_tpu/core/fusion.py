@@ -371,7 +371,7 @@ def _dispatch_pattern_sharded(qr, items) -> None:
     for _, staged, now in items:
         key_idx, sel, slots, counts = qr._shard_prep(stream_id, staged)
         preps.append((key_idx, sel))
-        feeds.append((slots, counts, now))
+        feeds.append((slots, counts, now, key_idx))
     n = preps[0][0].shape[0]
     Kb = max(ki.shape[1] for ki, _ in preps)
     E = max(s.shape[2] for _, s in preps)

@@ -21,6 +21,7 @@ from ..query_api.definition import StreamDefinition
 from ..query_api.query import Query, StateInputStream
 from . import event as ev
 from . import plan_facts
+from . import state_rows
 from .executor import CompileError
 from .pattern import PatternExec, PatternSpec, linearize, oh_take
 from .pattern_block import block_eligible, make_block_step
@@ -81,7 +82,21 @@ class StatePacker:
     array: compiled for v5e at 1,048,576 keys the stacked forms cost a
     whole-blob layout copy per step (537 MB / 1.08 GB of temporaries)
     where two planes cost none (ISSUE 27's compile table;
-    tests/test_state_planes.py repeats it).
+    tests/test_state_planes.py repeats it).  The same copy meets ANY fold
+    of the three arrays into one wider blob (one gather / scatter pass a
+    step instead of three): compiled for v5e:2x2, a gather + scatter on
+    `u32[W, 1048576]` by `s32[4096]` indices has, by W (ISSUE 36),
+
+        W                               temp_size_in_bytes
+        40, 50                          0
+        64, 80, 96, 120, 127, 128       536,935,424
+        130, 136                        1,078,114,304
+
+    — from W = 64 up layout assignment wants the blob key-major for the
+    scatter and copies the WHOLE blob there and back every step.  Do not
+    re-try the fold; what a step pays for its keys' rows is the
+    row-mover's business (`state_rows.py`: by the 128-key block on the
+    TPU, three arrays as they lie).
 
     Why [W, K] and not [K, W]: with keys leading, XLA:TPU layout assignment
     picked a key-major {0,1} layout for the [K, W] blobs, so every per-key
@@ -405,9 +420,13 @@ def plan_pattern_query(
                                               (a.shape[0], Kb))
                             for a in arrays]
                 else:
-                    # generic path: gathers riding the minor (key) axis
+                    # generic path: the keys' rows by the row-mover — by
+                    # the 128-key block on the TPU, XLA's gathers riding
+                    # the minor (key) axis elsewhere (state_rows.py)
                     key_idx = key_ref
-                    subs = [a[:, key_idx] for a in arrays]
+                    n_live = state_rows.live_count(key_idx,
+                                                   arrays[0].shape[1])
+                    subs = state_rows.load(arrays, key_idx, n_live)
                 # 64-bit values exist from here on, for these Kb keys only
                 sub = packer.unpack(*subs, scalars)
 
@@ -432,9 +451,8 @@ def plan_pattern_query(
                     arrays = [lax.dynamic_update_slice(a, n, (z, key_lo))
                               for a, n in zip(arrays, news)]
                 else:
-                    # out-of-bounds (padding) rows are dropped by scatter
-                    arrays = [a.at[:, key_idx].set(n, mode="drop")
-                              for a, n in zip(arrays, news)]
+                    # out-of-bounds (padding) rows are dropped
+                    arrays = state_rows.store(arrays, news, key_idx, n_live)
 
             sel_state, out, wake = _emit_matches(
                 pexec, sel, spec, emits, ord_, sel_state, sub, now,

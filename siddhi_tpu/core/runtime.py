@@ -33,6 +33,7 @@ from ..query_api.app import SiddhiApp
 from ..query_api.definition import StreamDefinition
 from ..query_api.query import Partition, Query, SingleInputStream
 from . import event as ev
+from . import state_rows
 from .executor import CompileError
 from .keyslots import SlotAllocator
 from .pattern_planner import (HEAD_DTYPES, BandedEmission, StatePacker,
@@ -228,6 +229,29 @@ def _stateobs_feed_group(qr, alloc, groups, pad, span) -> None:
     counts = counts[0] if len(counts) == 1 else np.concatenate(counts)
     span.set_metadata(keys=keys.size)
     qr.app.stats.stateobs.feed_keys(qr.name, alloc.capacity, keys, counts)
+
+
+def _count_state_rows(qr, key_rows, pad: int) -> None:
+    """The row-mover's counters of one send (statistics BASIC and
+    above): `<q>.state_row_keys`, the live keys whose state rows the
+    gather-path step moved, and `<q>.state_row_blocks`, the distinct
+    128-key blocks they lie in — per chip's rows on a mesh.  Keys over
+    blocks is the block mover's hit share: 1 for scattered keys, 128 for
+    a contiguous run (core/state_rows.py).  `key_rows`: one `key_idx` a
+    dispatch (a shard's local rows on a mesh), ascending, pads >= `pad`."""
+    st = qr.app.stats
+    if not st.enabled:
+        return
+    keys = blocks = 0
+    for rows in key_rows:
+        rows = rows[rows < pad]
+        if rows.size:
+            keys += rows.size
+            blocks += 1 + int(np.count_nonzero(
+                np.diff(rows // state_rows.LANES)))
+    if keys:
+        st.counter_inc(f"{qr.name}.state_row_keys", keys)
+        st.counter_inc(f"{qr.name}.state_row_blocks", blocks)
 
 
 def _wrap_stream_callback(cb) -> Callable[[List[ev.Event]], None]:
@@ -755,7 +779,7 @@ class PatternQueryRuntime(_MeshResolved):
                            for _, sel, ident in tiers]
                 sp.set_metadata(
                     grouped="view" if tiers[0][2] else "take")
-        outs, now_d = [], None
+        outs, now_d, moved = [], None, []
         try:
             try:
                 for t in reversed(range(len(tiers))):
@@ -802,6 +826,8 @@ class PatternQueryRuntime(_MeshResolved):
                                 now_d = jax.numpy.asarray(now,
                                                           jax.numpy.int64)
                         steps = p.dense_steps if dense else p.steps
+                        if not dense and key_idx_np is not None:
+                            moved.append(key_idx_np)
                         outs.append(self._step(steps[stream_id], cols_d,
                                                *ts_d, sel_d, key_d, now_d))
             finally:
@@ -811,7 +837,7 @@ class PatternQueryRuntime(_MeshResolved):
                 # advanced and not marked; a scrape takes no _qlock and
                 # reads no state, only the books, whole or a send behind
                 if p.partition_positions:
-                    self._feed_observers(tiers, nuniq, now)
+                    self._feed_observers(tiers, nuniq, now, moved)
         except Exception:
             # a tier that was dispatched has advanced its keys' state: what
             # it matched is delivered before the error is, so no match is
@@ -828,13 +854,16 @@ class PatternQueryRuntime(_MeshResolved):
             wake = tuple(w for _, w in outs)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
-    def _feed_observers(self, tiers, nuniq, now: int) -> None:
+    def _feed_observers(self, tiers, nuniq, now: int, moved=()) -> None:
         """What watches a partitioned send's keys, under one `obs_feed`
         span: the key-hotness feed, the purger's liveness touch, the
         snapshot's dirty marks — once for all of the send's tiers, after
-        the last of their dispatches (the span says so: `after`)."""
+        the last of their dispatches (the span says so: `after`).
+        `moved`: the `key_idx` of the tiers that went through the
+        row-mover (the gather-path step), for its counters."""
         with _phases.phase(self.app.stats, self.name, "obs_feed",
                            after="dispatch") as sp:
+            _count_state_rows(self, moved, self.planned.key_capacity)
             _stateobs_feed_group(
                 self, self.slot_allocator,
                 [(key_idx, sel) for key_idx, sel, _ in tiers],
@@ -887,14 +916,17 @@ class PatternQueryRuntime(_MeshResolved):
                                 keys=int((key_idx < router.block).sum()))
         return key_idx, sel, slots, counts
 
-    def _shard_feed(self, slots, counts, now: int) -> None:
+    def _shard_feed(self, slots, counts, now: int, key_idx=None) -> None:
         """`_feed_observers` of the sharded path, from what `_shard_prep`
         resolved: key hotness, purger liveness touch, dirty marks,
-        per-shard routing counters.  Nothing here goes to the device, so
-        it runs after the step's dispatch, under it."""
+        per-shard routing counters, the row-mover's counters (`key_idx`:
+        the [n, Kb] local rows the step moved).  Nothing here goes to the
+        device, so it runs after the step's dispatch, under it."""
         st = self.app.stats
         with _phases.phase(st, self.name, "obs_feed",
                            after="dispatch") as sp:
+            if key_idx is not None:
+                _count_state_rows(self, key_idx, self.shard_router.block)
             _stateobs_feed_slots(self, self.slot_allocator, slots, sp)
             if self._touch is not None:
                 self._touch(slots, now)
@@ -933,7 +965,7 @@ class PatternQueryRuntime(_MeshResolved):
                                    *ts_d, sel_d, key_d, now_d)
         finally:
             # as in process_staged: fed whether or not the step came back
-            self._shard_feed(slots, counts, now)
+            self._shard_feed(slots, counts, now, key_idx)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def on_timer(self, now: int) -> None:

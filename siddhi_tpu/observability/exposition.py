@@ -128,6 +128,13 @@ def render_prometheus(runtimes: Dict) -> str:
     e_byt = fam("siddhi_emitted_bytes_total", "counter",
                 "Output bytes delivered per query (rows x schema row "
                 "width from dtype metadata, never fetched)")
+    sr_k = fam("siddhi_state_row_keys_total", "counter",
+               "Live keys whose state rows the gather-path pattern step "
+               "moved (core/state_rows.py), per query")
+    sr_b = fam("siddhi_state_row_blocks_total", "counter",
+               "Distinct 128-key blocks those keys lay in (per chip's "
+               "rows on a mesh): keys over blocks is the block mover's "
+               "hit share, 1 scattered, 128 contiguous")
     slo_g = fam("siddhi_slo_state", "gauge",
                 "SLO rule state per app (0=ok 1=pending 2=firing), "
                 "evaluated over the in-process time series each sampler "
@@ -276,6 +283,12 @@ def render_prometheus(runtimes: Dict) -> str:
             elif name.endswith(".fused_batches"):
                 fus_b.sample(n, app=app_name,
                              query=name[:-len(".fused_batches")])
+            elif name.endswith(".state_row_keys"):
+                sr_k.sample(n, app=app_name,
+                            query=name[:-len(".state_row_keys")])
+            elif name.endswith(".state_row_blocks"):
+                sr_b.sample(n, app=app_name,
+                            query=name[:-len(".state_row_blocks")])
             elif name.endswith(".emitted_rows"):
                 e_rows.sample(n, app=app_name,
                               query=name[:-len(".emitted_rows")])

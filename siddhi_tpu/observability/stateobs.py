@@ -671,4 +671,21 @@ def state_report(rt) -> Dict:
         "hotness": snap["hotness"],
         "near_capacity": near_capacity(rt, snap) if enabled else [],
         "sizing_hints": obs.ledger(),
+        "state_rows": state_rows(rt.stats.counters()),
     }
+
+
+def state_rows(counters: Dict[str, int]) -> Dict[str, Dict]:
+    """{query: {keys, blocks, keys_per_block}} from the row-mover's
+    counters (`runtime._count_state_rows`; statistics BASIC and above):
+    live keys whose state rows the gather-path pattern step moved, the
+    distinct 128-key blocks they lay in, and their ratio — the block
+    mover's hit share, 1 for scattered keys, 128 for a contiguous run."""
+    out: Dict[str, Dict] = {}
+    for name, keys in counters.items():
+        if name.endswith(".state_row_keys"):
+            q = name[:-len(".state_row_keys")]
+            blocks = counters.get(q + ".state_row_blocks", 0)
+            out[q] = {"keys": keys, "blocks": blocks,
+                      "keys_per_block": keys / blocks if blocks else 0.0}
+    return out

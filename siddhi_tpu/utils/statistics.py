@@ -126,6 +126,11 @@ class StatisticsManager:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
 
+    def counters(self) -> Dict[str, int]:
+        """The operational counters as they stand, by name."""
+        with self._lock:
+            return dict(self._counters)
+
     def fused_dispatch(self, name: str, k: int, n: int,
                        elapsed_ns: int) -> None:
         """One @fuse dispatch covering k micro-batches (n events):

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core import event as ev
 from siddhi_tpu.core import pattern_planner
+from siddhi_tpu.core import state_rows
 from siddhi_tpu.core.pattern_planner import StatePacker
 from siddhi_tpu.core.window import NO_WAKEUP
 from siddhi_tpu.observability.explain import compiled_steps
@@ -674,7 +675,13 @@ def guard_compiled(topo, flagship_plan):
             n_rows = g["Kb"] * g["chips"]
         args = step_args(p, g["kcap"], g["B"], n_rows, 4, place, rep, batch,
                          g["dense"])
-        done[step] = (p, fn.lower(*args).compile())
+        # this process sees the CPU backend; the program the chip runs
+        # moves its state rows by the block (state_rows.block_form)
+        state_rows._FORM = "blocks"
+        try:
+            done[step] = (p, fn.lower(*args).compile())
+        finally:
+            state_rows._FORM = None
         return done[step]
     return compiled
 
@@ -693,17 +700,19 @@ def test_v5e_compile_has_no_whole_blob_x64_pass(step, guard_compiled):
 
 
 # the one-chip programs take the event columns grouped by the host (PR 29)
-# and reshape them: the dense step gathers nothing, the scan step only its
-# keys' rows of the three state planes.  The sharded step still gathers
-# the replicated [B] columns on each chip: three state planes, and — since
-# PR 31 — ONE gather of the columns' six u32 planes stacked (key and the
-# decoded i64 timestamp as two halves each, price, stage) where there
-# were six gathers of one plane: the chip gathers by the slice
+# and reshape them: the dense step gathers nothing.  Since PR 36 the scan
+# step gathers nothing either and the sharded step only the replicated [B]
+# event columns — ONE gather of the columns' six u32 planes stacked (key
+# and the decoded i64 timestamp as two halves each, price, stage; PR 31:
+# the chip gathers by the slice): their keys' rows of the three state
+# arrays move by the 128-key block, two Pallas kernels
+# (`core/state_rows.py`), where there were three gathers `s32[50, Kb]`,
+# `u32[40, Kb]`, `u32[40, Kb]` and three scatters, a serial loop over the
+# indices each
 GATHERS = {
     "dense": [],
-    "gather": ["s32[50,2048]", "u32[40,2048]", "u32[40,2048]"],
-    "sharded": ["s32[50,32768]", "u32[40,32768]", "u32[40,32768]",
-                "u32[6,32768,4]"],
+    "gather": [],
+    "sharded": ["u32[6,32768,4]"],
 }
 
 
@@ -713,6 +722,47 @@ def test_v5e_compile_gathers_only_what_the_step_must(step, guard_compiled):
     found = re.findall(r"= (\S+?)\{\S* gather\(", compiled.as_text())
     assert len(found) == len(GATHERS[step]), found
     assert all(want in got for want, got in zip(GATHERS[step], found)), found
+    assert " scatter(" not in compiled.as_text()
+
+
+# the row-mover's two kernels, by the name `pallas_call` gives their
+# custom calls.  `test_v5e_compile_has_no_whole_blob_x64_pass` holds all
+# three programs' `temp_size_in_bytes` under ONE plane: that is the test
+# that catches a whole-blob copy.  Why the three arrays are NOT folded
+# into one `u32[130, K]` blob (one pass a step instead of three): compiled
+# for v5e:2x2, a gather + scatter on `u32[W, 1048576]` by `s32[4096]`
+# indices has `temp_size_in_bytes` 0 at W = 40 and 50, 536,935,424 at
+# W = 64, 80, 96, 120, 127, 128 and 1,078,114,304 at W = 130 / 136: from
+# W = 64 up layout assignment wants the blob key-major for the scatter and
+# copies the WHOLE blob there and back every step (ISSUE 36; PR 27 met the
+# same copy with the stacked `[2, W64, K]` planes)
+MOVER = {"gather": 1, "dense": 0, "sharded": 1}
+
+
+@pytest.mark.parametrize("step", sorted(GUARD))
+def test_v5e_compile_moves_state_rows_by_the_block(step, guard_compiled):
+    _p, compiled = guard_compiled(step)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("state_rows_load", "state_rows_store"):
+        mine = [line for line in calls if "%" + name in line]
+        assert len(mine) == MOVER[step], (name, len(mine))
+    if not MOVER[step]:
+        return
+    g = GUARD[step]
+    per_chip, rows = g["kcap"] // g["chips"], g["Kb"]
+    load = next(line for line in calls if "%state_rows_load" in line)
+    store = next(line for line in calls if "%state_rows_store" in line)
+    # the load hands over the three [W, Kb] sub-arrays; the store's
+    # results ARE the three resident arrays (aliased operands: no copy)
+    for w, dt in ((50, "s32"), (40, "u32"), (40, "u32")):
+        assert "%s[%d,%d]" % (dt, w, rows) in load.split("custom-call(")[0]
+        assert "%s[%d,%d]" % (dt, w, per_chip) in \
+            store.split("custom-call(")[0]
+    assert "output_to_operand_aliasing" in store
+    # each under its section of the step (what a device trace books it to)
+    assert "state_load" in load and "state_store" in store
 
 
 def test_v5e_sharded_event_gather_lands_in_fast_memory(guard_compiled):
