@@ -485,18 +485,25 @@ def plan_single_query(
     def stage_body(wstate, ts, kind, valid, cols, gslot, now, in_tabs):
         """Pre-window chain + window advance: the half of the step a
         merge group shares (one buffer, staged once per dispatch)."""
-        env = {sid: cols, "__ts__": ts, "__now__": now, "__kind__": kind}
-        env.update(_probe_env(in_tabs))
-        keep = valid
-        is_current = kind == ev.CURRENT
-        if named_window_input:
-            # expired rows must pass the same filters so signed aggregation
-            # stays balanced (reference: filter sits after the shared window)
-            is_current = jnp.logical_or(is_current, kind == ev.EXPIRED)
-        env, cols, keep = _apply_chain(pre_chain, env, sid, cols, keep,
-                                       is_current)
-        rows = Rows(ts=ts, kind=kind, valid=keep,
-                    seq=jnp.zeros_like(ts), gslot=gslot, cols=cols)
+        # device-trace sections of the plain step (`jax.named_scope`:
+        # op-name metadata, the compiled program is the same without
+        # them): `plain_chain` here, the window's and the selector's in
+        # the code that owns the work (window.py, selector.py)
+        with jax.named_scope("plain_chain"):
+            env = {sid: cols, "__ts__": ts, "__now__": now,
+                   "__kind__": kind}
+            env.update(_probe_env(in_tabs))
+            keep = valid
+            is_current = kind == ev.CURRENT
+            if named_window_input:
+                # expired rows must pass the same filters so signed
+                # aggregation stays balanced (reference: filter sits
+                # after the shared window)
+                is_current = jnp.logical_or(is_current, kind == ev.EXPIRED)
+            env, cols, keep = _apply_chain(pre_chain, env, sid, cols, keep,
+                                           is_current)
+            rows = Rows(ts=ts, kind=kind, valid=keep,
+                        seq=jnp.zeros_like(ts), gslot=gslot, cols=cols)
         wstate, wout = wproc.process(wstate, rows, now)
         return wstate, wout.rows, wout.next_wakeup
 
@@ -510,11 +517,13 @@ def plan_single_query(
         for j in range(len(pair_allocs)):
             env2[f"__pslot__{j}"] = pslots[j]
         if post_chain:
-            data_row = jnp.logical_or(orows.kind == ev.CURRENT,
-                                      orows.kind == ev.EXPIRED)
-            env2, ocols, keep2 = _apply_chain(
-                post_chain, env2, sid, orows.cols, orows.valid, data_row)
-            orows = orows._replace(valid=keep2, cols=ocols)
+            with jax.named_scope("plain_chain"):
+                data_row = jnp.logical_or(orows.kind == ev.CURRENT,
+                                          orows.kind == ev.EXPIRED)
+                env2, ocols, keep2 = _apply_chain(
+                    post_chain, env2, sid, orows.cols, orows.valid,
+                    data_row)
+                orows = orows._replace(valid=keep2, cols=ocols)
         return sel.process(astate, orows, env2)
 
     def step(state, ts, kind, valid, cols, gslot, now, in_tabs=(),

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -321,36 +322,42 @@ class AggregatorBank:
         """Returns (new_state, per-row running values per spec)."""
         if not self.specs:
             return state, ()
+        # two device-trace sections (jax.named_scope: op-name metadata):
+        # `agg_layout` is every sort and unsort — the segment ids, the
+        # argsort by (slot, reset epoch), the permutation back — and
+        # `agg_scan` the contributions, the segmented scans and the carry
         B = rows.capacity
-        sign = jnp.where(
-            jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
-            jnp.where(jnp.logical_and(rows.valid, rows.kind == ev.EXPIRED),
-                      -1, 0))
-        gslot = jnp.where(rows.gslot >= 0, rows.gslot, 0).astype(jnp.int32)
+        with jax.named_scope("agg_layout"):
+            sign = jnp.where(
+                jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
+                jnp.where(jnp.logical_and(rows.valid,
+                                          rows.kind == ev.EXPIRED), -1, 0))
+            gslot = jnp.where(rows.gslot >= 0, rows.gslot,
+                              0).astype(jnp.int32)
 
-        is_reset = jnp.logical_and(rows.valid, rows.kind == ev.RESET)
-        reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))  # after row i
-        epoch_before = reset_epoch - is_reset.astype(jnp.int64)
-        total_resets = reset_epoch[-1]
+            is_reset = jnp.logical_and(rows.valid, rows.kind == ev.RESET)
+            reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))  # after row i
+            epoch_before = reset_epoch - is_reset.astype(jnp.int64)
+            total_resets = reset_epoch[-1]
 
-        def layout(slot_vec):
-            # segment id: (slot, epoch); rows already seq-ordered
-            seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
-            order = jnp.argsort(seg, stable=True)
-            unorder = jnp.zeros((B,), jnp.int32).at[order].set(
-                jnp.arange(B, dtype=jnp.int32))
-            seg_s = seg[order]
-            first = jnp.concatenate([
-                jnp.ones((1,), jnp.bool_), seg_s[1:] != seg_s[:-1]])
-            return (order, unorder, seg_s, first, sign[order],
-                    slot_vec[order], epoch_before[order])
+            def layout(slot_vec):
+                # segment id: (slot, epoch); rows already seq-ordered
+                seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
+                order = jnp.argsort(seg, stable=True)
+                unorder = jnp.zeros((B,), jnp.int32).at[order].set(
+                    jnp.arange(B, dtype=jnp.int32))
+                seg_s = seg[order]
+                first = jnp.concatenate([
+                    jnp.ones((1,), jnp.bool_), seg_s[1:] != seg_s[:-1]])
+                return (order, unorder, seg_s, first, sign[order],
+                        slot_vec[order], epoch_before[order])
 
-        layouts = {None: layout(gslot)}
-        for j in range(len(self.pair_sources)):
-            ps = env.get(f"__pslot__{j}")
-            if ps is not None:
-                layouts[j] = layout(
-                    jnp.where(ps >= 0, ps, 0).astype(jnp.int32))
+            layouts = {None: layout(gslot)}
+            for j in range(len(self.pair_sources)):
+                ps = env.get(f"__pslot__{j}")
+                if ps is not None:
+                    layouts[j] = layout(
+                        jnp.where(ps >= 0, ps, 0).astype(jnp.int32))
 
         env = dict(env)
         env["__scanres__"] = results = []
@@ -361,32 +368,40 @@ class AggregatorBank:
             # slot count from the STATE shape, not the plan: under
             # shard_map each device owns a K/n slice of the slot axis
             K = st.shape[0]
-            vals = spec.vals_fn(env, sign)
-            # rows that don't contribute carry the identity
-            vals = jnp.where(sign != 0, vals,
-                             jnp.asarray(spec.init, spec.dtype))
-            v_s = vals[order]
-            # inject carry state at heads of epoch-0 segments
-            carry = st[slot_s]
-            v_s = jnp.where(
-                jnp.logical_and(first, epoch_s == 0),
-                spec.op(carry, v_s), v_s)
-            scanned = _segmented_scan(v_s, seg_s, spec.op)
-            results.append(scanned[unorder])
+            with jax.named_scope("agg_scan"):
+                vals = spec.vals_fn(env, sign)
+                # rows that don't contribute carry the identity
+                vals = jnp.where(sign != 0, vals,
+                                 jnp.asarray(spec.init, spec.dtype))
+            with jax.named_scope("agg_layout"):
+                v_s = vals[order]
+            with jax.named_scope("agg_scan"):
+                # inject carry state at heads of epoch-0 segments
+                carry = st[slot_s]
+                v_s = jnp.where(
+                    jnp.logical_and(first, epoch_s == 0),
+                    spec.op(carry, v_s), v_s)
+                scanned = _segmented_scan(v_s, seg_s, spec.op)
+            with jax.named_scope("agg_layout"):
+                results.append(scanned[unorder])
 
-            # new state: per slot, value after the last row in the final epoch
-            contrib = jnp.logical_and(sign_s != 0, epoch_s == total_resets)
-            idx = jnp.arange(B)
-            # scatter-max of sorted index per slot for contributing rows
-            last_idx = jnp.full((K,), -1, jnp.int32).at[
-                jnp.where(contrib, slot_s, K).astype(jnp.int32)
-            ].max(jnp.where(contrib, idx, -1).astype(jnp.int32), mode="drop")
-            has = last_idx >= 0
-            gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
-            base = jnp.where(total_resets > 0,
-                             jnp.full((K,), spec.init, spec.dtype), st)
-            # carry survives only if no reset happened
-            ns = jnp.where(has, gathered, base)
+            with jax.named_scope("agg_scan"):
+                # new state: per slot, value after the last row in the
+                # final epoch
+                contrib = jnp.logical_and(sign_s != 0,
+                                          epoch_s == total_resets)
+                idx = jnp.arange(B)
+                # scatter-max of sorted index per slot for contributing rows
+                last_idx = jnp.full((K,), -1, jnp.int32).at[
+                    jnp.where(contrib, slot_s, K).astype(jnp.int32)
+                ].max(jnp.where(contrib, idx, -1).astype(jnp.int32),
+                      mode="drop")
+                has = last_idx >= 0
+                gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
+                base = jnp.where(total_resets > 0,
+                                 jnp.full((K,), spec.init, spec.dtype), st)
+                # carry survives only if no reset happened
+                ns = jnp.where(has, gathered, base)
             new_state.append(ns)
 
         return tuple(new_state), tuple(results)
@@ -528,18 +543,22 @@ class SelectorExec:
         env = dict(env)
         env["__aggscan__"] = scans
 
-        out_cols = tuple(c.fn(env) for c in self._compiled_proj)
-        valid = jnp.logical_and(
-            rows.valid,
-            jnp.logical_or(rows.kind == ev.CURRENT, rows.kind == ev.EXPIRED))
-        if self.having is not None:
-            valid = jnp.logical_and(valid, self.having.fn(env))
+        # device-trace section `project`: the select list over the scans'
+        # running values, having, the valid mask, order-by / limit
+        with jax.named_scope("project"):
+            out_cols = tuple(c.fn(env) for c in self._compiled_proj)
+            valid = jnp.logical_and(
+                rows.valid,
+                jnp.logical_or(rows.kind == ev.CURRENT,
+                               rows.kind == ev.EXPIRED))
+            if self.having is not None:
+                valid = jnp.logical_and(valid, self.having.fn(env))
 
-        ts, kind = rows.ts, rows.kind
-        if self._order_by or self.selector.limit is not None \
-                or self.selector.offset is not None:
-            ts, kind, valid, out_cols = self._order_limit(
-                ts, kind, valid, out_cols)
+            ts, kind = rows.ts, rows.kind
+            if self._order_by or self.selector.limit is not None \
+                    or self.selector.offset is not None:
+                ts, kind, valid, out_cols = self._order_limit(
+                    ts, kind, valid, out_cols)
         return new_state, (ts, kind, valid, out_cols)
 
     def _order_limit(self, ts, kind, valid, out_cols):

@@ -91,10 +91,13 @@ def _gather_rows(rows: Rows, idx, valid):
 
 
 def sort_rows(rows: Rows) -> Rows:
-    """Stable order by (valid desc, seq asc): invalid rows pushed to the end."""
-    key = jnp.where(rows.valid, rows.seq, BIG_SEQ)
-    idx = jnp.argsort(key, stable=True)
-    return _gather_rows(rows, idx, jnp.ones_like(rows.valid)[idx])
+    """Stable order by (valid desc, seq asc): invalid rows pushed to the
+    end.  One device-trace section, `window_order`: a caller keeps it out
+    of any section of its own."""
+    with jax.named_scope("window_order"):
+        key = jnp.where(rows.valid, rows.seq, BIG_SEQ)
+        idx = jnp.argsort(key, stable=True)
+        return _gather_rows(rows, idx, jnp.ones_like(rows.valid)[idx])
 
 
 def concat_rows(a: Rows, b: Rows) -> Rows:
@@ -445,127 +448,133 @@ class LengthBatchWindow(WindowProcessor):
                 jnp.asarray(0, jnp.int64))
 
     def process(self, state, rows: Rows, now):
-        pend, prev, seq0 = state
-        n = self.length
-        B = rows.capacity
-        is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
-        ncur = jnp.sum(is_cur.astype(jnp.int64))
-        fill0 = jnp.sum(pend.alive.astype(jnp.int64))
+        # two device-trace sections (jax.named_scope: op-name metadata):
+        # `window_fill` builds the rows a step emits, `window_state` the
+        # buffers it keeps; the ordering is `sort_rows`' own section
+        with jax.named_scope("window_fill"):
+            pend, prev, seq0 = state
+            n = self.length
+            B = rows.capacity
+            is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
+            ncur = jnp.sum(is_cur.astype(jnp.int64))
+            fill0 = jnp.sum(pend.alive.astype(jnp.int64))
 
-        # global arrival index g = fill0 + k (k = order within batch)
-        k = jnp.cumsum(is_cur.astype(jnp.int64)) - 1
-        g = fill0 + k
-        batch_idx = g // n           # which tumble this arrival belongs to
-        nflush = (fill0 + ncur) // n  # completed batches this step
+            # global arrival index g = fill0 + k (k = order within batch)
+            k = jnp.cumsum(is_cur.astype(jnp.int64)) - 1
+            g = fill0 + k
+            batch_idx = g // n           # which tumble this arrival belongs to
+            nflush = (fill0 + ncur) // n  # completed batches this step
 
-        # ---- output construction -------------------------------------------
-        # seq layout per flush f (0-based among this step's flushes):
-        #   expired rows of batch f-1+prev : seq = seq0 + f*(2n+2) + [0..n)
-        #   reset row                      : seq0 + f*(2n+2) + n
-        #   current rows of batch f        : seq0 + f*(2n+2) + n+1 + [0..n)
-        span = 2 * n + 2
+            # ---- output construction -------------------------------------------
+            # seq layout per flush f (0-based among this step's flushes):
+            #   expired rows of batch f-1+prev : seq = seq0 + f*(2n+2) + [0..n)
+            #   reset row                      : seq0 + f*(2n+2) + n
+            #   current rows of batch f        : seq0 + f*(2n+2) + n+1 + [0..n)
+            span = 2 * n + 2
 
-        # currents of flushed batches: arrival with batch_idx < nflush
-        flushed_cur = jnp.logical_and(is_cur, batch_idx < nflush)
-        pos_in_batch = g % n
-        cur_seq = seq0 + batch_idx * span + n + 1 + pos_in_batch
-        # pending entries flushed in flush 0
-        pend_flush = jnp.logical_and(pend.alive, nflush > 0)
-        pend_rank = jnp.cumsum(pend.alive.astype(jnp.int64)) - 1
-        pend_seq = seq0 + 0 * span + n + 1 + pend_rank
+            # currents of flushed batches: arrival with batch_idx < nflush
+            flushed_cur = jnp.logical_and(is_cur, batch_idx < nflush)
+            pos_in_batch = g % n
+            cur_seq = seq0 + batch_idx * span + n + 1 + pos_in_batch
+            # pending entries flushed in flush 0
+            pend_flush = jnp.logical_and(pend.alive, nflush > 0)
+            pend_rank = jnp.cumsum(pend.alive.astype(jnp.int64)) - 1
+            pend_seq = seq0 + 0 * span + n + 1 + pend_rank
 
-        cur_rows = Rows(
-            ts=jnp.concatenate([pend.ts, rows.ts]),
-            kind=jnp.full((n + B,), ev.CURRENT, jnp.int32),
-            valid=jnp.concatenate([pend_flush, flushed_cur]),
-            seq=jnp.concatenate([pend_seq, cur_seq]),
-            gslot=jnp.concatenate([pend.gslot, rows.gslot]),
-            cols=tuple(jnp.concatenate([pc, rc])
-                       for pc, rc in zip(pend.cols, rows.cols)),
-        )
+            cur_rows = Rows(
+                ts=jnp.concatenate([pend.ts, rows.ts]),
+                kind=jnp.full((n + B,), ev.CURRENT, jnp.int32),
+                valid=jnp.concatenate([pend_flush, flushed_cur]),
+                seq=jnp.concatenate([pend_seq, cur_seq]),
+                gslot=jnp.concatenate([pend.gslot, rows.gslot]),
+                cols=tuple(jnp.concatenate([pc, rc])
+                           for pc, rc in zip(pend.cols, rows.cols)),
+            )
 
-        # expired rows: prev batch replayed at flush 0; batch f-1 replayed at
-        # flush f.  prev buffer: ranks 0..n-1.
-        prev_rank = jnp.cumsum(prev.alive.astype(jnp.int64)) - 1
-        prev_valid = jnp.logical_and(prev.alive, nflush > 0)
-        prev_seq = seq0 + prev_rank
-        # arrivals replayed as expired at flush (batch_idx+1) if batch_idx+1 < nflush
-        arr_exp_valid = jnp.logical_and(is_cur, batch_idx + 1 < nflush)
-        arr_exp_seq = seq0 + (batch_idx + 1) * span + pos_in_batch
-        # pending entries (flushed at 0) replayed as expired at flush 1
-        pend_exp_valid = jnp.logical_and(pend.alive, nflush > 1)
-        pend_exp_seq = seq0 + 1 * span + pend_rank
+            # expired rows: prev batch replayed at flush 0; batch f-1 replayed at
+            # flush f.  prev buffer: ranks 0..n-1.
+            prev_rank = jnp.cumsum(prev.alive.astype(jnp.int64)) - 1
+            prev_valid = jnp.logical_and(prev.alive, nflush > 0)
+            prev_seq = seq0 + prev_rank
+            # arrivals replayed as expired at flush (batch_idx+1) if batch_idx+1 < nflush
+            arr_exp_valid = jnp.logical_and(is_cur, batch_idx + 1 < nflush)
+            arr_exp_seq = seq0 + (batch_idx + 1) * span + pos_in_batch
+            # pending entries (flushed at 0) replayed as expired at flush 1
+            pend_exp_valid = jnp.logical_and(pend.alive, nflush > 1)
+            pend_exp_seq = seq0 + 1 * span + pend_rank
 
-        exp_rows = Rows(
-            ts=jnp.concatenate([prev.ts, pend.ts, rows.ts]),
-            kind=jnp.full((2 * n + B,), ev.EXPIRED, jnp.int32),
-            valid=jnp.concatenate([prev_valid, pend_exp_valid, arr_exp_valid]),
-            seq=jnp.concatenate([prev_seq, pend_exp_seq, arr_exp_seq]),
-            gslot=jnp.concatenate([prev.gslot, pend.gslot, rows.gslot]),
-            cols=tuple(jnp.concatenate([a, b, c]) for a, b, c in
-                       zip(prev.cols, pend.cols, rows.cols)),
-        )
+            exp_rows = Rows(
+                ts=jnp.concatenate([prev.ts, pend.ts, rows.ts]),
+                kind=jnp.full((2 * n + B,), ev.EXPIRED, jnp.int32),
+                valid=jnp.concatenate([prev_valid, pend_exp_valid, arr_exp_valid]),
+                seq=jnp.concatenate([prev_seq, pend_exp_seq, arr_exp_seq]),
+                gslot=jnp.concatenate([prev.gslot, pend.gslot, rows.gslot]),
+                cols=tuple(jnp.concatenate([a, b, c]) for a, b, c in
+                           zip(prev.cols, pend.cols, rows.cols)),
+            )
 
-        # reset rows, one per flush
-        F = B // n + 1
-        f = jnp.arange(F, dtype=jnp.int64)
-        reset_rows = Rows(
-            ts=jnp.full((F,), 0, jnp.int64) + now,
-            kind=jnp.full((F,), ev.RESET, jnp.int32),
-            valid=f < nflush,
-            seq=seq0 + f * span + n,
-            gslot=jnp.full((F,), -1, jnp.int32),
-            cols=tuple(jnp.full((F,), ev.default_value(t_), d)
-                       for t_, d in zip(self.schema.types, self.schema.dtypes)),
-        )
+            # reset rows, one per flush
+            F = B // n + 1
+            f = jnp.arange(F, dtype=jnp.int64)
+            reset_rows = Rows(
+                ts=jnp.full((F,), 0, jnp.int64) + now,
+                kind=jnp.full((F,), ev.RESET, jnp.int32),
+                valid=f < nflush,
+                seq=seq0 + f * span + n,
+                gslot=jnp.full((F,), -1, jnp.int32),
+                cols=tuple(jnp.full((F,), ev.default_value(t_), d)
+                           for t_, d in zip(self.schema.types, self.schema.dtypes)),
+            )
+            emitted = concat_rows(concat_rows(exp_rows, cur_rows),
+                                  reset_rows)
+        out = sort_rows(emitted)
 
-        out = sort_rows(concat_rows(concat_rows(exp_rows, cur_rows), reset_rows))
+        with jax.named_scope("window_state"):
+            # ---- new state ------------------------------------------------------
+            # pending' = arrivals with batch_idx == nflush (+ old pending if no flush)
+            np_old_valid = jnp.logical_and(pend.alive, nflush == 0)
+            np_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush)
+            cand_valid = jnp.concatenate([np_old_valid, np_arr_valid])
+            cand_rank_src = jnp.concatenate([pend_rank, pos_in_batch])
+            cand_ts = jnp.concatenate([pend.ts, rows.ts])
+            cand_gslot = jnp.concatenate([pend.gslot, rows.gslot])
+            cand_cols = tuple(jnp.concatenate([pc, rc])
+                              for pc, rc in zip(pend.cols, rows.cols))
+            # scatter into fresh pending by rank
+            npend = empty_buffer(self.schema, n)
+            tgt = jnp.where(cand_valid, cand_rank_src, n).astype(jnp.int32)
+            def scat(dst, src):
+                return dst.at[tgt].set(src, mode="drop")
+            npend = Buffer(
+                ts=scat(npend.ts, cand_ts),
+                add_seq=npend.add_seq,
+                expire_seq=npend.expire_seq,
+                expire_ts=npend.expire_ts,
+                alive=jnp.zeros((n,), jnp.bool_).at[tgt].set(cand_valid, mode="drop"),
+                gslot=scat(npend.gslot, cand_gslot),
+                cols=tuple(scat(c0, c) for c0, c in zip(npend.cols, cand_cols)),
+            )
 
-        # ---- new state ------------------------------------------------------
-        # pending' = arrivals with batch_idx == nflush (+ old pending if no flush)
-        np_old_valid = jnp.logical_and(pend.alive, nflush == 0)
-        np_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush)
-        cand_valid = jnp.concatenate([np_old_valid, np_arr_valid])
-        cand_rank_src = jnp.concatenate([pend_rank, pos_in_batch])
-        cand_ts = jnp.concatenate([pend.ts, rows.ts])
-        cand_gslot = jnp.concatenate([pend.gslot, rows.gslot])
-        cand_cols = tuple(jnp.concatenate([pc, rc])
-                          for pc, rc in zip(pend.cols, rows.cols))
-        # scatter into fresh pending by rank
-        npend = empty_buffer(self.schema, n)
-        tgt = jnp.where(cand_valid, cand_rank_src, n).astype(jnp.int32)
-        def scat(dst, src):
-            return dst.at[tgt].set(src, mode="drop")
-        npend = Buffer(
-            ts=scat(npend.ts, cand_ts),
-            add_seq=npend.add_seq,
-            expire_seq=npend.expire_seq,
-            expire_ts=npend.expire_ts,
-            alive=jnp.zeros((n,), jnp.bool_).at[tgt].set(cand_valid, mode="drop"),
-            gslot=scat(npend.gslot, cand_gslot),
-            cols=tuple(scat(c0, c) for c0, c in zip(npend.cols, cand_cols)),
-        )
+            # prev' = last flushed batch (batch nflush-1) if any flush else prev
+            lb_old_valid = jnp.logical_and(pend.alive, nflush == 1)
+            lb_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush - 1)
+            lbc_valid = jnp.concatenate([lb_old_valid, lb_arr_valid])
+            nprev0 = empty_buffer(self.schema, n)
+            tgt2 = jnp.where(lbc_valid, cand_rank_src, n).astype(jnp.int32)
+            def scat2(dst, src):
+                return dst.at[tgt2].set(src, mode="drop")
+            flushed_prev = Buffer(
+                ts=scat2(nprev0.ts, cand_ts),
+                add_seq=nprev0.add_seq, expire_seq=nprev0.expire_seq,
+                expire_ts=nprev0.expire_ts,
+                alive=jnp.zeros((n,), jnp.bool_).at[tgt2].set(lbc_valid, mode="drop"),
+                gslot=scat2(nprev0.gslot, cand_gslot),
+                cols=tuple(scat2(c0, c) for c0, c in zip(nprev0.cols, cand_cols)),
+            )
+            nprev = jax.tree.map(
+                lambda new, old: jnp.where(nflush > 0, new, old), flushed_prev, prev)
 
-        # prev' = last flushed batch (batch nflush-1) if any flush else prev
-        lb_old_valid = jnp.logical_and(pend.alive, nflush == 1)
-        lb_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush - 1)
-        lbc_valid = jnp.concatenate([lb_old_valid, lb_arr_valid])
-        nprev0 = empty_buffer(self.schema, n)
-        tgt2 = jnp.where(lbc_valid, cand_rank_src, n).astype(jnp.int32)
-        def scat2(dst, src):
-            return dst.at[tgt2].set(src, mode="drop")
-        flushed_prev = Buffer(
-            ts=scat2(nprev0.ts, cand_ts),
-            add_seq=nprev0.add_seq, expire_seq=nprev0.expire_seq,
-            expire_ts=nprev0.expire_ts,
-            alive=jnp.zeros((n,), jnp.bool_).at[tgt2].set(lbc_valid, mode="drop"),
-            gslot=scat2(nprev0.gslot, cand_gslot),
-            cols=tuple(scat2(c0, c) for c0, c in zip(nprev0.cols, cand_cols)),
-        )
-        nprev = jax.tree.map(
-            lambda new, old: jnp.where(nflush > 0, new, old), flushed_prev, prev)
-
-        nseq = seq0 + nflush * span
+            nseq = seq0 + nflush * span
         return ((npend, nprev, nseq),
                 WindowOutput(out, None, jnp.asarray(NO_WAKEUP, jnp.int64)))
 

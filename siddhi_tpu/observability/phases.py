@@ -39,7 +39,11 @@ op's EVENT METADATA (the plane's `event_metadata`, beside `program_id` and
 `hlo_category`), not a stat of the event itself, so
 `jax.profiler.ProfileData` does not show them; TensorBoard / xprof group
 by them, and `benchmarks/harness/step_sections.py` reads the `XSpace`
-message for them (device time by section and by rectangle).
+message for them (device time by section and by rectangle).  The plain
+query step (core/planner.py `jit_plain_step`) has its own, each named where
+the work is: `plain_chain`, `window_fill`, `window_state`, `window_order`,
+`agg_layout`, `agg_scan`, `project` (`benchmarks/harness/
+plain_sections.py`).
 
 Spans (`siddhi:<name>`) and the scrape phase each feeds:
 
