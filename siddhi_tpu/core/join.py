@@ -142,6 +142,8 @@ class PlannedJoinQuery:
                 if self.slot_allocator2 is not None else None)
         if self.per_duration is not None:
             d["aggregation_per"] = self.per_duration
+        if self.selector_exec.has_aggregation:
+            d["selector_layout"] = self.selector_exec.bank.layout
         d["equi_fastpath"] = self.fastpath_facts()
         return d
 
@@ -414,10 +416,12 @@ def plan_join_query(
         Kl = Kr = 0
     gl_alloc = SlotAllocator(Kl, name=f"{name}:gl") if gl_pos else None
     gr_alloc = SlotAllocator(Kr, name=f"{name}:gr") if gr_pos else None
+    # ungrouped: Kl = Kr = 0, so every joined row's composed slot is 0
     sel = SelectorExec(query.selector, scope, left.schema,
                        max((Kl + 1) * (Kr + 1), 64),
                        (query.output_stream.target_id
-                        if query.output_stream else name), interner)
+                        if query.output_stream else name), interner,
+                       single_slot=not (gl_pos or gr_pos))
     if sel.bank.pair_sources:
         raise CompileError(
             "distinctCount/unionSet in join queries lands in a later phase")

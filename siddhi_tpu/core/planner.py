@@ -106,6 +106,8 @@ class PlannedQuery:
         if self.pair_allocs:
             d["distinct_pair_slots"] = [a.capacity
                                         for a, _ in self.pair_allocs]
+        if self.selector_exec.has_aggregation:
+            d["selector_layout"] = self.selector_exec.bank.layout
         if self.mesh is not None or self.keyed_mesh is not None:
             m = self.mesh or self.keyed_mesh
             d["sharded_over_devices"] = int(m.devices.size)
@@ -445,6 +447,10 @@ def plan_single_query(
         partition_key_fn is not None and (sel.has_aggregation or gpos))
     allocator = SlotAllocator(group_slots, name=f"{name}:groupby") \
         if needs_alloc else None
+    # no allocator (no group by, no partition key of either kind): the
+    # runtime stages slot 0 for every row, so the selector's rows are
+    # already in segment order (AggregatorBank.layout)
+    sel.bank.single_slot = allocator is None
 
     # distinctCount pair slots: (group, value) -> refcount slot
     pair_allocs: List[Tuple[SlotAllocator, int]] = []

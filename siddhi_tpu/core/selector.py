@@ -13,7 +13,9 @@ event's update" semantics — are computed with *segmented associative scans*:
 rows are stably sorted by (group slot, reset epoch), an inclusive
 associative scan runs per segment, carry-in state is injected at segment
 heads, and results are unsorted back.  O(B log B), no per-event control flow,
-exact sequential semantics.
+exact sequential semantics.  A query whose plan allocates no group slot
+holds one segment per reset epoch, already in row order: its rows are
+scanned where they stand, no sort and no permutation (`layout`).
 """
 from __future__ import annotations
 
@@ -90,8 +92,9 @@ class AggregatorBank:
     """Compiles all aggregator calls of a query into a set of scan columns
     plus per-slot carry state [K]."""
 
-    def __init__(self, group_slots: int):
+    def __init__(self, group_slots: int, single_slot: bool = False):
         self.K = group_slots
+        self.single_slot = single_slot
         self.specs: List[_AggSpec] = []
         self._index: Dict[str, int] = {}
         # distinctCount: Variables whose (group, value) pairs get host
@@ -109,6 +112,15 @@ class AggregatorBank:
         return tuple(
             jnp.full((s.K_override or self.K,), s.init, dtype=s.dtype)
             for s in self.specs)
+
+    @property
+    def layout(self) -> str:
+        """How `process` lays rows out for its scans: `in_order` — one
+        group slot, so the rows as they stand are in (slot, reset epoch)
+        order and nothing is sorted or permuted; `sorted` — the argsort
+        and the permutation back (a slot column, distinctCount's pairs)."""
+        return "in_order" if self.single_slot and not self.pair_sources \
+            else "sorted"
 
     # -- aggregator compilation ----------------------------------------------
     def compile_call(self, fn_expr: AttributeFunction, scope: Scope,
@@ -327,29 +339,39 @@ class AggregatorBank:
         # argsort by (slot, reset epoch), the permutation back — and
         # `agg_scan` the contributions, the segmented scans and the carry
         B = rows.capacity
+        in_order = self.layout == "in_order"
         with jax.named_scope("agg_layout"):
             sign = jnp.where(
                 jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
                 jnp.where(jnp.logical_and(rows.valid,
                                           rows.kind == ev.EXPIRED), -1, 0))
-            gslot = jnp.where(rows.gslot >= 0, rows.gslot,
-                              0).astype(jnp.int32)
+            gslot = None if in_order else jnp.where(
+                rows.gslot >= 0, rows.gslot, 0).astype(jnp.int32)
 
             is_reset = jnp.logical_and(rows.valid, rows.kind == ev.RESET)
             reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))  # after row i
             epoch_before = reset_epoch - is_reset.astype(jnp.int64)
             total_resets = reset_epoch[-1]
 
+            def heads(seg_s):
+                return jnp.concatenate([
+                    jnp.ones((1,), jnp.bool_), seg_s[1:] != seg_s[:-1]])
+
             def layout(slot_vec):
+                if slot_vec is None:
+                    # one slot: the segment id is epoch_before, a running
+                    # count that never decreases along the rows, so the
+                    # stable argsort below is arange and every gather by
+                    # it a copy — the rows are scanned where they stand
+                    return (None, None, epoch_before, heads(epoch_before),
+                            sign, None, epoch_before)
                 # segment id: (slot, epoch); rows already seq-ordered
                 seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
                 order = jnp.argsort(seg, stable=True)
                 unorder = jnp.zeros((B,), jnp.int32).at[order].set(
                     jnp.arange(B, dtype=jnp.int32))
                 seg_s = seg[order]
-                first = jnp.concatenate([
-                    jnp.ones((1,), jnp.bool_), seg_s[1:] != seg_s[:-1]])
-                return (order, unorder, seg_s, first, sign[order],
+                return (order, unorder, seg_s, heads(seg_s), sign[order],
                         slot_vec[order], epoch_before[order])
 
             layouts = {None: layout(gslot)}
@@ -374,16 +396,17 @@ class AggregatorBank:
                 vals = jnp.where(sign != 0, vals,
                                  jnp.asarray(spec.init, spec.dtype))
             with jax.named_scope("agg_layout"):
-                v_s = vals[order]
+                v_s = vals if order is None else vals[order]
             with jax.named_scope("agg_scan"):
                 # inject carry state at heads of epoch-0 segments
-                carry = st[slot_s]
+                carry = st[0] if slot_s is None else st[slot_s]
                 v_s = jnp.where(
                     jnp.logical_and(first, epoch_s == 0),
                     spec.op(carry, v_s), v_s)
                 scanned = _segmented_scan(v_s, seg_s, spec.op)
             with jax.named_scope("agg_layout"):
-                results.append(scanned[unorder])
+                results.append(
+                    scanned if unorder is None else scanned[unorder])
 
             with jax.named_scope("agg_scan"):
                 # new state: per slot, value after the last row in the
@@ -391,11 +414,17 @@ class AggregatorBank:
                 contrib = jnp.logical_and(sign_s != 0,
                                           epoch_s == total_resets)
                 idx = jnp.arange(B)
-                # scatter-max of sorted index per slot for contributing rows
-                last_idx = jnp.full((K,), -1, jnp.int32).at[
-                    jnp.where(contrib, slot_s, K).astype(jnp.int32)
-                ].max(jnp.where(contrib, idx, -1).astype(jnp.int32),
-                      mode="drop")
+                if slot_s is None:
+                    # slot 0's is a max-reduce; no row names another slot
+                    last = jnp.max(jnp.where(contrib, idx, -1))
+                    last_idx = jnp.where(
+                        jnp.arange(K) == 0, last, -1).astype(jnp.int32)
+                else:
+                    # scatter-max of sorted index per contributing slot
+                    last_idx = jnp.full((K,), -1, jnp.int32).at[
+                        jnp.where(contrib, slot_s, K).astype(jnp.int32)
+                    ].max(jnp.where(contrib, idx, -1).astype(jnp.int32),
+                          mode="drop")
                 has = last_idx >= 0
                 gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
                 base = jnp.where(total_resets > 0,
@@ -411,47 +440,18 @@ class AggregatorBank:
 # Selector executor
 # ---------------------------------------------------------------------------
 
-def _rewrite_aggregators(expr: Expression, found: List[AttributeFunction],
-                         prefix: str) -> Expression:
-    """Replace aggregator calls with bound pseudo-variables __agg<i>."""
-    if isinstance(expr, AttributeFunction):
-        is_agg = not expr.namespace and expr.name in AGGREGATOR_NAMES
-        if not is_agg:
-            from .extension import attribute_aggregator_registry
-            full = f"{expr.namespace}:{expr.name}" if expr.namespace \
-                else expr.name
-            is_agg = full in attribute_aggregator_registry()
-        if is_agg:
-            found.append(expr)
-            return Variable(f"{prefix}{len(found) - 1}")
-        return AttributeFunction(expr.namespace, expr.name, [
-            _rewrite_aggregators(p, found, prefix) for p in expr.parameters])
-    if isinstance(expr, (Add, Subtract, Multiply, Divide, Mod)):
-        return type(expr)(_rewrite_aggregators(expr.left, found, prefix),
-                          _rewrite_aggregators(expr.right, found, prefix))
-    if isinstance(expr, Compare):
-        return Compare(_rewrite_aggregators(expr.left, found, prefix),
-                       expr.operator,
-                       _rewrite_aggregators(expr.right, found, prefix))
-    if isinstance(expr, (And, Or)):
-        return type(expr)(_rewrite_aggregators(expr.left, found, prefix),
-                          _rewrite_aggregators(expr.right, found, prefix))
-    if isinstance(expr, Not):
-        return Not(_rewrite_aggregators(expr.expression, found, prefix))
-    if isinstance(expr, IsNull) and expr.expression is not None:
-        return IsNull(_rewrite_aggregators(expr.expression, found, prefix))
-    if isinstance(expr, In):
-        return In(_rewrite_aggregators(expr.expression, found, prefix),
-                  expr.source_id)
-    return expr
-
-
 class SelectorExec:
-    """Compiled select clause over ordered Rows."""
+    """Compiled select clause over ordered Rows.
+
+    `single_slot`: the plan allocates no group slot for this query (no
+    group by, no partition key, no range-partition key function), so every
+    row carries slot 0 and `AggregatorBank.layout` is `in_order`; a site
+    that cannot prove it keeps the default, the sorted layout."""
 
     def __init__(self, selector: Selector, scope: Scope,
                  in_schema: ev.Schema, group_slots: int,
-                 out_stream_id: str, interner: ev.StringInterner):
+                 out_stream_id: str, interner: ev.StringInterner,
+                 single_slot: bool = False):
         self.selector = selector
         self.scope = scope
         self.group_by_positions: List[int] = []
@@ -459,7 +459,7 @@ class SelectorExec:
             _, pos, _ = scope.resolve(v)
             self.group_by_positions.append(pos)
 
-        self.bank = AggregatorBank(group_slots)
+        self.bank = AggregatorBank(group_slots, single_slot)
         self._agg_calls: List[AttributeFunction] = []
 
         # select list (select-all expands to the input schema)
@@ -590,6 +590,41 @@ class SelectorExec:
                 keep = jnp.logical_and(keep, rank < lo + self.selector.limit)
             valid = jnp.logical_and(valid, keep)
         return ts, kind, valid, out_cols
+
+
+def _rewrite_aggregators(expr: Expression, found: List[AttributeFunction],
+                         prefix: str) -> Expression:
+    """Replace aggregator calls with bound pseudo-variables __agg<i>."""
+    if isinstance(expr, AttributeFunction):
+        is_agg = not expr.namespace and expr.name in AGGREGATOR_NAMES
+        if not is_agg:
+            from .extension import attribute_aggregator_registry
+            full = f"{expr.namespace}:{expr.name}" if expr.namespace \
+                else expr.name
+            is_agg = full in attribute_aggregator_registry()
+        if is_agg:
+            found.append(expr)
+            return Variable(f"{prefix}{len(found) - 1}")
+        return AttributeFunction(expr.namespace, expr.name, [
+            _rewrite_aggregators(p, found, prefix) for p in expr.parameters])
+    if isinstance(expr, (Add, Subtract, Multiply, Divide, Mod)):
+        return type(expr)(_rewrite_aggregators(expr.left, found, prefix),
+                          _rewrite_aggregators(expr.right, found, prefix))
+    if isinstance(expr, Compare):
+        return Compare(_rewrite_aggregators(expr.left, found, prefix),
+                       expr.operator,
+                       _rewrite_aggregators(expr.right, found, prefix))
+    if isinstance(expr, (And, Or)):
+        return type(expr)(_rewrite_aggregators(expr.left, found, prefix),
+                          _rewrite_aggregators(expr.right, found, prefix))
+    if isinstance(expr, Not):
+        return Not(_rewrite_aggregators(expr.expression, found, prefix))
+    if isinstance(expr, IsNull) and expr.expression is not None:
+        return IsNull(_rewrite_aggregators(expr.expression, found, prefix))
+    if isinstance(expr, In):
+        return In(_rewrite_aggregators(expr.expression, found, prefix),
+                  expr.source_id)
+    return expr
 
 
 def _substitute_aliases(e: Expression, alias_map, scope) -> Expression:

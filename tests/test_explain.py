@@ -487,3 +487,60 @@ def test_console_reporter_quantile_lines(manager):
     for token in ("p50=", "p95=", "p99=", "max=", "drops=",
                   "cap_growths="):
         assert token in qline, (token, qline)
+
+
+# -- the selector's row layout, a fact of the plan ---------------------------
+
+_LAYOUT_STREAM = "define stream S (sym string, price float, v int);\n"
+_LAYOUT_QUERY = ("@info(name='lq') from S#window.lengthBatch(4) "
+                 "select avg(price) as ap{group_by} insert into Out;")
+LAYOUT_CASES = {
+    # no slot is ever allocated: rows are scanned where they stand
+    "ungrouped": (_LAYOUT_QUERY.format(group_by=""), "in_order"),
+    "no_window": ("@info(name='lq') from S select sum(v) as t, "
+                  "count() as n insert into Out;", "in_order"),
+    "session_key_ungrouped": (
+        "@info(name='lq') from S#window.session(1 sec, sym) "
+        "select max(v) as m insert into Out;", "in_order"),
+    "join_ungrouped": (
+        "@info(name='lq') from S#window.length(4) as a join "
+        "S#window.length(4) as b on a.sym == b.sym "
+        "select sum(a.v) as t insert into Out;", "in_order"),
+    # a slot column: the sort by (slot, reset epoch) and the way back
+    "group_by": (_LAYOUT_QUERY.format(group_by=" group by sym"), "sorted"),
+    "partition_with": (
+        "partition with (sym of S) begin "
+        + _LAYOUT_QUERY.format(group_by="") + " end;", "sorted"),
+    "keyed_window_range_partition": (
+        "partition with (v < 4 as 'low' or v >= 4 as 'high' of S) begin "
+        "@info(name='lq') from S#window.length(4) "
+        "select sum(v) as t insert into Out; end;", "sorted"),
+    "distinct_count": ("@info(name='lq') from S "
+                       "select distinctCount(sym) as dc insert into Out;",
+                       "sorted"),
+    "join_group_by": (
+        "@info(name='lq') from S#window.length(4) as a join "
+        "S#window.length(4) as b on a.sym == b.sym "
+        "select a.sym as sym, sum(a.v) as t group by a.sym "
+        "insert into Out;", "sorted"),
+    # no aggregator, no layout to report
+    "no_aggregation": ("@info(name='lq') from S[v > 1] select sym, v "
+                       "insert into Out;", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_explain_says_the_selector_layout(manager, case):
+    """`selector_layout` is chosen from the plan alone and EXPLAIN says it
+    for every plain or join query with an aggregation: `in_order` exactly
+    where no group slot is ever allocated."""
+    query, want = LAYOUT_CASES[case]
+    rt = _boot(manager, _LAYOUT_STREAM + query,
+               [("S", [["ab"[i % 2], float(i), i] for i in range(8)])])
+    qr = rt.query_runtimes["lq"]
+    plan = rt.explain("lq")["plan"]
+    assert plan.get("selector_layout") == want
+    assert plan == qr.planned.describe()
+    if want == "in_order":
+        assert getattr(qr.planned, "slot_allocator", None) is None
+        assert "group_slot_capacity" not in plan
