@@ -13,7 +13,9 @@ plain reference; then one more pass over the first `--again` blocks under the
 final bindings.  It prints the sweep's wall, the per-send times, and
 `memory_stats()` of EVERY chip the cell holds (`run.py` reports the fullest
 one), and writes them to `chiprun_out/bind_sweep/<workload>.json`.  Not a
-metric of BENCHMARK.json: PERF.md section 4 quotes it.
+metric of BENCHMARK.json: PERF.md section 4 quotes it.  Its sends go through
+`runner.Deployment.issue`, to the stream each names
+(`tests/test_bench_two_streams.py` rehearses it on a two-stream app).
 """
 import argparse
 import json

@@ -73,10 +73,20 @@ def resolve(cell_name: str, rehearse: bool = False) -> Cell:
         raise SystemExit(f"benchmark: no workload {cell_name!r} in "
                          f"BENCHMARK.json ({sorted(cells)})")
     w = cells[cell_name]
-    cfg_dir = os.path.join(BENCH_DIR, "configs", w["config"])
+    return load_cell(
+        w, os.path.join(BENCH_DIR, "configs", w["config"]),
+        os.path.join(BENCH_DIR, "traffic", w["traffic"] + ".json"),
+        bench, rehearse)
+
+
+def load_cell(w: dict, cfg_dir: str, traffic_path: str, bench: dict,
+              rehearse: bool = False) -> Cell:
+    """The cell a `workloads` entry `w` describes, from the configuration's
+    directory and the traffic mix's file, with the metrics of `bench` that
+    list it (a test's fixture is loaded from its own directory)."""
+    cell_name = w["name"]
     config = _load_json(os.path.join(cfg_dir, "config.json"))
-    traffic = _load_json(
-        os.path.join(BENCH_DIR, "traffic", w["traffic"] + ".json"))
+    traffic = _load_json(traffic_path)
     sizes = dict(config["sizes"])
     if rehearse:
         sizes.update(config.get("rehearse_sizes", {}))

@@ -10,7 +10,7 @@ carries no `rect_*`, so `step_sections` books the plain step under its
 `other_modules` and no pattern reader sees it.
 
 This is `step_sections`' reduction with other names: the same slice and
-skew, the same SELF times (`step_sections.self_times`: an op minus the ops
+skew, the same SELF times (`trace_reduce.self_times`: an op minus the ops
 nested in it) and the same borrowing (`step_sections.resolve`: an op that
 names no section takes its enclosing op's, a loop the compiler rebuilt its
 body's), per device plane and averaged over the planes:
@@ -74,7 +74,7 @@ def reduce_plane(plane, lo: float, hi: float, skew: float):
                          stats.get("hlo_category", "?"))
             if section:
                 plain.add(stats["program_id"])
-    selfs = ss.self_times(((mid, s + skew, e + skew) for mid, s, e in
+    selfs = tr.self_times(((mid, s + skew, e + skew) for mid, s, e in
                            lines[tr.OPS_LINE].events()), lo, hi)
     said = [says.get(mid, (None, None, "?")) for mid, _, _ in selfs]
     booked = ss.resolve([own for own, _, _ in said],

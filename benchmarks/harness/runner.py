@@ -6,6 +6,44 @@ The system under test is reached through its public surface only
 `shutdown`) plus the `RECOMPILES` registry; statistics stay OFF, as
 deployed.  Everything that decides a number — the clock, the stamps, the
 window, the comparison — is in this directory.
+
+What a deployment brings (`configs/<name>/`), written down once
+------------------------------------------------------------------
+`config.json`: `query` (the subscribed query), `columns` (the result columns
+the subscriber reads), `sizes` (formatted into `app.siddhi`), `stream` (where
+a send that names no stream goes) and optionally `streams` — every input
+stream a send may name (default `[stream]`).  Every listed stream's handler
+is taken at deploy, never inside a send.
+
+`model.py` is plain numpy, imports nothing of the program, and gives:
+
+- `plan(seed, traffic, sizes) -> dict`: what the generator keeps between sends;
+- `make_send(rng, i, traffic, plan, clock_ms) -> send`: the i-th send of
+  `traffic`, a dict with `cols` (the columns `send_columns` takes), `ts` (the
+  timestamps), `events` (how many events it carries) and optionally `stream`
+  (which input stream it goes to), plus whatever the model's own reference
+  wants to find again;
+- `expected_rows(send) -> int`, `events_per_send(traffic) -> int`,
+  `clock_step_ms(traffic) -> int`;
+- `Attribution(plan)` with `on_issue(sid, send)` and `attribute(rows) ->
+  sids`: which send each delivered row completes;
+- `reference(sends, plan) -> [rows]` (every send since the app started, in
+  order), `canonical(rows)`, `compare(got, want) -> {number: value}` held to
+  `LIMITS`, `control_rows(want)` (the reference at the nearest lower
+  precision) and `least_bytes(traffic, sizes, config)`.
+
+Two rules hold for every model:
+
+1. ONE CALL A SEND, TO THE STREAM THE SEND NAMES.  A model that feeds several
+   streams interleaves them by the send's index; `siddhi:send`, `trace_sends`,
+   every `*_per_send` reader and the latency keep their meaning.  A send that
+   names a stream `config.json` does not list ends the run with an error:
+   there is no stream it falls back to.
+2. A SEND THAT OWES NO ROWS IS DONE WHEN ITS CALL RETURNS (`expected_rows` 0:
+   the first send of an inner join, a batch no filter passes).  Its latency
+   is `returned - due`.  Nothing is loosened by it: the check still compares
+   its rows with the reference's (none), so a row delivered for it is
+   `rows_unexpected` and the send fails.
 """
 from __future__ import annotations
 
@@ -65,7 +103,8 @@ class CompileMeters:
 class Tracker:
     """The subscriber's side: every delivered batch is read in full and
     stamped, its rows mapped to the sends they complete (the configuration's
-    `Attribution`), and a send is done when its last expected row is in."""
+    `Attribution`), and a send is done when its last expected row is in —
+    one that expects none, when its call returns."""
 
     def __init__(self, attribution, columns):
         self.attr = attribution
@@ -82,6 +121,16 @@ class Tracker:
             self.expected[sid] = n_expected
             self.got[sid] = 0
             self.attr.on_issue(sid, send)
+
+    def returned(self, sid: int, t: float) -> None:
+        """The send's call returned at `t`: a send that owes no rows is done
+        then (a row that still comes for it is the check's to find)."""
+        if self.expected[sid] != 0:      # written by this thread, in `issued`
+            return
+        with self.cv:
+            if sid not in self.done_t:
+                self.done_t[sid] = t
+                self.cv.notify_all()
 
     def on_batch(self, _ts, b) -> None:
         sel = b["valid"] & (b["kind"] == 0)
@@ -169,7 +218,10 @@ class Deployment:
         # without a listener a dropped batch reads as a faster run
         self.rt.set_exception_listener(self.errors.append)
         self.rt.start()
-        self.handler = self.rt.get_input_handler(cell.config["stream"])
+        self.default_stream = cell.config.get("stream")
+        self.handlers = {
+            name: self.rt.get_input_handler(name) for name in
+            cell.config.get("streams") or [cell.config["stream"]]}
 
     def span(self, name: str, **kw):
         if not self.annotate:
@@ -202,18 +254,24 @@ class Deployment:
 
     def issue(self, sid: int, due: float) -> None:
         send = self.sends[sid]
+        stream = send.get("stream", self.default_stream)
+        handler = self.handlers.get(stream)
+        if handler is None:
+            raise RuntimeError(
+                f"send {sid} names stream {stream!r}; config.json lists "
+                f"{sorted(self.handlers)} and a send goes nowhere else")
         self.tracker.issued(sid, send, self.model.expected_rows(send))
         st = self.stamps[sid] = {
             "due": due, "issued": now(),
             "subscriber_s": 0.0, "subscriber_end": None}
         self._in_call = (threading.get_ident(), sid)
         try:
-            with self.span("send_columns", sid=sid):
-                self.handler.send_columns(send["cols"],
-                                          timestamps=send["ts"])
+            with self.span("send_columns", sid=sid, stream=stream):
+                handler.send_columns(send["cols"], timestamps=send["ts"])
         finally:
             self._in_call = None
         st["returned"] = now()
+        self.tracker.returned(sid, st["returned"])
 
     def flush(self) -> None:
         with self.span("flush"):

@@ -80,27 +80,6 @@ def slice_of(host) -> tuple | None:
             if mid in names] for i, line in enumerate(host.lines)})
 
 
-def self_times(events, lo: float, hi: float):
-    """[(metadata_id, self ns, index of the enclosing event or -1)] of one
-    line's events (metadata_id, s, e), clipped to [lo, hi): an event's
-    interval minus the events nested in it.  Events of one line nest
-    properly or not at all."""
-    evs = sorted(((max(s, lo), min(e, hi), mid) for mid, s, e in events
-                  if e > lo and s < hi), key=lambda x: (x[0], -x[1]))
-    out, stack = [], []               # stack: indices into `out`, open events
-    ends = []
-    for s, e, mid in evs:
-        while stack and ends[stack[-1]] <= s:
-            stack.pop()
-        parent = stack[-1] if stack else -1
-        if parent >= 0:
-            out[parent][1] -= e - s
-        out.append([mid, e - s, parent])
-        ends.append(e)
-        stack.append(len(out) - 1)
-    return out
-
-
 def reduce_plane(plane, lo: float, hi: float, skew: float):
     """One device plane's slice, ns: ({(rectangle, section): the pattern
     programs' time}, {hlo_category: what of it names no section}, {module:
@@ -125,7 +104,7 @@ def reduce_plane(plane, lo: float, hi: float, skew: float):
             says[mid] = (section, pid, stats.get("hlo_category", "?"))
             if rect or section:
                 rect_of[pid] = rect or rect_of.get(pid, NO_RECT)
-    selfs = self_times(((mid, s + skew, e + skew) for mid, s, e in
+    selfs = tr.self_times(((mid, s + skew, e + skew) for mid, s, e in
                         lines[tr.OPS_LINE].events()), lo, hi)
     said = [says.get(mid, (None, None, "?")) for mid, _, _ in selfs]
     sections = resolve([own for own, _, _ in said],

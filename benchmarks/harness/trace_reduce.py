@@ -132,6 +132,30 @@ def self_intervals(spans):
     return {n: union(v) for n, v in out.items()}
 
 
+def self_times(events, lo: float, hi: float):
+    """[(metadata_id, self ns, index of the enclosing event or -1)] of one
+    line's events (metadata_id, s, e), clipped to [lo, hi): an event's
+    interval minus the events nested in it.  Events of a device line nest
+    properly or not at all, and their self times add up to the union of
+    their intervals; where two only overlap (host ops of several threads in
+    a CPU rehearsal, whose numbers are no metric) the overlap is taken from
+    the earlier one."""
+    evs = sorted(((max(s, lo), min(e, hi), mid) for mid, s, e in events
+                  if e > lo and s < hi), key=lambda x: (x[0], -x[1]))
+    out, stack = [], []               # stack: indices into `out`, open events
+    ends = []
+    for s, e, mid in evs:
+        while stack and ends[stack[-1]] <= s:
+            stack.pop()
+        parent = stack[-1] if stack else -1
+        if parent >= 0:
+            out[parent][1] -= min(e, ends[parent]) - s
+        out.append([mid, e - s, parent])
+        ends.append(e)
+        stack.append(len(out) - 1)
+    return out
+
+
 def _module_name(raw: str) -> str:
     """`jit_step_w(1234567890)` -> `jit_step_w`: the id changes per run."""
     return re.sub(r"\(\d+\)$", "", raw)
@@ -236,7 +260,8 @@ def slice_of(spans: dict):
 def reduce_trace(path: str, top: int = 10) -> dict:
     """The numbers the per-layer metrics and the result line read:
     window_s, busy_s (union of op intervals, averaged over the device
-    planes), sends_in_slice, by_module [[module, seconds]], idle_gaps
+    planes), sends_in_slice, by_module [[module, seconds of its ops' SELF
+    time]: they add up to busy_s where the list is whole], idle_gaps
     [[span, seconds]] (`split_idle`; they add up to window_s - busy_s),
     longest_gap_s."""
     devices, spans = read_planes(path)
@@ -265,15 +290,18 @@ def reduce_trace(path: str, top: int = 10) -> dict:
         mods = sorted((s, e, n) for n, s, e in rec["modules"])
         starts = [m[0] for m in mods]
         op_module = rec.get("op_module")
-        for name, s, e in ops:
+        # SELF times, as `step_sections` books them: a `while` minus the
+        # body ops it runs, so a plane's entries add up to its busy time
+        for i, ns, _parent in self_times(
+                ((i, s, e) for i, (_, s, e) in enumerate(ops)), lo, hi):
+            _, s, e = ops[i]
             if op_module is not None:
                 mod = op_module.get((s, e), "?")
             else:
-                i = bisect.bisect_right(starts, s) - 1
-                mod = (_module_name(mods[i][2])
-                       if i >= 0 and s < mods[i][1] else "no_module")
-            dur = (min(e, hi) - max(s, lo)) / n_dev
-            by_module[mod] = by_module.get(mod, 0.0) + dur
+                m = bisect.bisect_right(starts, s) - 1
+                mod = (_module_name(mods[m][2])
+                       if m >= 0 and s < mods[m][1] else "no_module")
+            by_module[mod] = by_module.get(mod, 0.0) + ns / n_dev
         gaps_by_device.append(complement(merged, lo, hi))
     gap_by = split_idle(spans, idle_before(gaps_by_device), lo, hi)
 

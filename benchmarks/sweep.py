@@ -12,6 +12,11 @@ up, had not fallen behind by the end.  The knee is the highest sustained rate
 below the first that is not.  The table goes to stdout and to
 `chiprun_out/sweep/<workload>.json`; the cell's rate (0.8 x knee) is then
 written into its traffic file by hand, with the table in PERF.md.
+
+Every send goes through `runner.Deployment.issue`, as `run.py`'s do: to the
+stream it names, and done at its call's return where it owes no rows — so a
+two-stream cell's knee is swept by this file as it stands
+(`tests/test_bench_two_streams.py` rehearses it on one).
 """
 import time
 T_START = time.perf_counter()

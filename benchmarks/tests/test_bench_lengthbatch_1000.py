@@ -353,14 +353,21 @@ def test_a_trace_with_no_plain_program_reads_none(tmp_path, name):
 
 # -- the four entries and the lists the cell joined ---------------------------------------
 
-def test_the_four_entries_and_the_lists_the_cell_joined():
-    names = [e["name"] for e in BENCH["per_layer"]]
-    assert names[78:] == [q + ".sat" for q in QUANTITIES] and len(names) == 82
+def check_the_four_entries_and_the_lists_the_cell_joined(bench):
+    """One-sided (PR 41): the four entries stay, together and in order,
+    after the pattern programs' entries; a later PR may add a cell to a
+    list behind this one, or an entry behind these."""
+    names = [e["name"] for e in bench["per_layer"]]
+    entries = {e["name"]: e for e in bench["per_layer"]}
+    at = names.index(next(iter(QUANTITIES)) + ".sat")
+    assert names[at:at + 4] == [q + ".sat" for q in QUANTITIES]
+    assert 76 <= at and len(names) <= 128
     for q in QUANTITIES:
-        e = ENTRIES[q + ".sat"]
+        e = dict(entries[q + ".sat"])
+        assert e.pop("workloads")[0] == CELL
         assert e == {"name": q + ".sat", "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "device step",
-                     "moves": "events_per_s", "workloads": [CELL]}
+                     "moves": "events_per_s"}
     got = {e["name"]: read.__module__
            for e, read in loader.resolve(CELL).per_layer}
     for q in QUANTITIES:
@@ -376,7 +383,21 @@ def test_the_four_entries_and_the_lists_the_cell_joined():
             "device_busy_ms_per_send.sat", "device_idle_pct.sat",
             "fetch_bytes_per_send.sat", "page_faults_per_send.sat"} <= \
         set(got)
-    assert len(got) == 26
-    for e in BENCH["end_to_end"] + BENCH["per_layer"]:
+    assert len(got) >= 26
+    # it joined each list behind the five pattern cells
+    cells = [w["name"] for w in bench["workloads"]]
+    older = cells[:cells.index(CELL)]
+    for e in bench["end_to_end"] + bench["per_layer"]:
         if CELL in e.get("workloads", ()):
-            assert e["workloads"][-1] == CELL, e["name"]
+            assert e["workloads"].index(CELL) == \
+                len([c for c in e["workloads"] if c in older]), e["name"]
+
+
+def test_the_four_entries_and_the_lists_the_cell_joined():
+    check_the_four_entries_and_the_lists_the_cell_joined(BENCH)
+
+
+def test_a_seventh_cell_behind_it_trips_no_pin(seventh_cell):
+    bench, name = seventh_cell
+    assert bench["workloads"][-1]["name"] == name
+    check_the_four_entries_and_the_lists_the_cell_joined(bench)

@@ -224,7 +224,7 @@ def test_least_bytes_and_config():
     # the table is test_bench_per_layer_table.py's
     bench = loader.load_benchmark()
     own = {e["name"] for e in bench["per_layer"] if e["workloads"] == [CELL]}
-    assert own == {"layout_cells_per_event.zipf", "scan_ticks_per_send.zipf",
+    assert own >= {"layout_cells_per_event.zipf", "scan_ticks_per_send.zipf",
                    "hot_key_events_per_send.zipf", "step_roofline.zipf"}
 
 

@@ -1,13 +1,20 @@
 """BENCHMARK.json's `per_layer` table: one entry a quantity and loop kind.
 
-`x.sat` moves `events_per_s` and lists the closed-loop cells, `x.paced` moves
-`latency_p50_ms` and lists the open-loop ones, what moves `setup_s` has no
+`x.sat` moves `events_per_s` and lists closed-loop cells, `x.paced` moves
+`latency_p50_ms` and lists open-loop ones, what moves `setup_s` has no
 suffix, and a quantity only one cell has keeps its single-cell name.  A new
 cell joins lists; it adds an entry only for a quantity no cell had.
 
 `data/per_layer_parent.json` holds every (cell, quantity) pair the table
 reported when it had one entry a cell (128 entries, PR 33's tree): each is
-still reported in its cell, by the same reader file, exactly once."""
+still reported in its cell, by the same reader file, exactly once.
+`data/per_layer_pr34_names.json` holds the 60 names PR 34 merged them into.
+
+Every pin is ONE-SIDED: it holds what was accepted — those pairs, those
+names, the five cells of PR 34 in the lists they were in — and lets a later
+PR add a cell to a list or an entry to the table (at most 128).  The last
+test adds a seventh cell to every `.sat` list its twin is in, in a scratch
+copy of the table, and runs every check of this file on it."""
 import json
 import os
 
@@ -15,15 +22,15 @@ import pytest
 
 from benchmarks.harness import loader
 
-BENCH = loader.load_benchmark()
-ENTRIES = {e["name"]: e for e in BENCH["per_layer"]}
-CELLS = [w["name"] for w in BENCH["workloads"]]
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+# the cells PR 34's table was merged for, by loop kind
 CLOSED = ["pattern_1m.saturated", "pattern_32m.mesh4_saturated"]
 OPEN = ["pattern_1m.paced", "pattern_16m_zipf.paced",
         "pattern_1m.served_paced"]
-with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
-                       "per_layer_parent.json")) as fh:
+with open(os.path.join(DATA, "per_layer_parent.json")) as fh:
     PARENT = json.load(fh)
+with open(os.path.join(DATA, "per_layer_pr34_names.json")) as fh:
+    PR34_NAMES = json.load(fh)
 # the one thing a cell gained: a reading its reader already served, left out
 # of PR 33 for the cap alone
 GAINED = {(cell, "obs_feed_idle_ms_per_send") for cell in OPEN}
@@ -35,26 +42,43 @@ SPAN_QUANTITIES = (
     "idle_pre_dispatch_ms_per_send", "idle_post_step_ms_per_send")
 
 
-def resolved(cell):
-    """[(quantity, entry, reader module)] as the harness resolves the cell."""
-    return [(e["name"].split(".", 1)[0], e, read.__module__)
-            for e, read in loader.resolve(cell).per_layer]
+class Table:
+    """A BENCHMARK.json and what the harness resolves from it."""
+
+    def __init__(self, bench):
+        self.bench = bench
+        self.entries = {e["name"]: e for e in bench["per_layer"]}
+        self.cells = [w["name"] for w in bench["workloads"]]
+        self._resolved, self._loops = {}, None
+
+    def resolved(self, cell):
+        """[(quantity, entry, reader module)] as the harness resolves the
+        cell."""
+        if cell not in self._resolved:
+            self._resolved[cell] = [
+                (e["name"].split(".", 1)[0], e, read.__module__)
+                for e, read in loader.resolve(cell).per_layer]
+        return self._resolved[cell]
+
+    def reports(self, cell):
+        return {e["name"] for e in self.bench["end_to_end"]
+                if "workloads" not in e or cell in e["workloads"]}
+
+    def of_kind(self, loop):
+        if self._loops is None:
+            self._loops = {c: loader.resolve(c).traffic["loop"]
+                           for c in self.cells}
+        return [c for c in self.cells if self._loops[c] == loop]
+
+    def in_order(self, cells):
+        return cells == [c for c in self.cells if c in cells]
 
 
-@pytest.fixture(scope="module")
-def by_cell():
-    return {cell: resolved(cell) for cell in CELLS}
+TABLE = Table(loader.load_benchmark())
 
 
-def reports(cell):
-    return {e["name"] for e in BENCH["end_to_end"]
-            if "workloads" not in e or cell in e["workloads"]}
-
-
-@pytest.mark.parametrize(
-    "pair", PARENT, ids=[f"{p['cell']}-{p['name']}" for p in PARENT])
-def test_every_pair_the_parent_reported_is_still_reported_once(pair, by_cell):
-    hits = [(e, mod) for q, e, mod in by_cell[pair["cell"]]
+def check_pair(t, pair):
+    hits = [(e, mod) for q, e, mod in t.resolved(pair["cell"])
             if q == pair["quantity"]]
     assert len(hits) == 1, (pair, [e["name"] for e, _ in hits])
     (entry, module), = hits
@@ -70,46 +94,48 @@ def test_every_pair_the_parent_reported_is_still_reported_once(pair, by_cell):
         assert new == old
 
 
-def test_the_table_has_room_again():
+def check_room(t):
     assert len(PARENT) == 131          # 128 entries, three of two cells
-    assert len(BENCH["per_layer"]) == len(ENTRIES) == 60 <= 64
+    assert len(PR34_NAMES) == len(set(PR34_NAMES)) == 60
+    names = [e["name"] for e in t.bench["per_layer"]]
+    assert len(names) == len(set(names)) <= 128
+    # PR 34's 60 are all there, first and in their order
+    assert names[:60] == PR34_NAMES
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_no_cell_resolves_one_quantity_twice_or_gains_one_unasked(
-        cell, by_cell):
-    quantities = [q for q, _, _ in by_cell[cell]]
+def check_cell(t, cell):
+    quantities = [q for q, _, _ in t.resolved(cell)]
     assert len(quantities) == len(set(quantities))
     had = {p["quantity"] for p in PARENT if p["cell"] == cell}
-    assert {(cell, q) for q in set(quantities) - had} == \
-        {g for g in GAINED if g[0] == cell}
     assert had <= set(quantities)
+    assert {(cell, q) for q in set(quantities) - had} >= \
+        {g for g in GAINED if g[0] == cell}
 
 
-@pytest.mark.parametrize("name", sorted(ENTRIES))
-def test_an_entry_moves_a_metric_every_cell_of_its_list_reports(name):
-    e = ENTRIES[name]
-    assert e["workloads"] == [c for c in CELLS if c in e["workloads"]]
+def check_entry(t, name):
+    e = t.entries[name]
+    assert t.in_order(e["workloads"])
     for cell in e["workloads"]:
-        assert e["moves"] in reports(cell), (name, cell)
+        assert e["moves"] in t.reports(cell), (name, cell)
+    held = name in PR34_NAMES          # the lists PR 34 wrote stay whole
     # the suffix is the loop kind, which is the metric moved
     if name.endswith(".sat"):
-        assert e["moves"] == "events_per_s" and e["workloads"] == CLOSED
+        assert e["moves"] == "events_per_s"
+        assert set(e["workloads"]) <= set(t.of_kind("closed"))
+        assert not held or set(CLOSED) <= set(e["workloads"])
     if name.endswith(".paced"):
         assert e["moves"] == "latency_p50_ms"
-        assert set(e["workloads"]) <= set(OPEN)
+        assert set(e["workloads"]) <= set(t.of_kind("open"))
     if e["moves"] == "setup_s":
-        assert "." not in name and e["workloads"] == CELLS
+        assert "." not in name
+        assert set(CLOSED + OPEN) <= set(e["workloads"])
     if len(e["workloads"]) == 1 and "." in name:
         suffix = name.rsplit(".", 1)[1]
         assert suffix in e["workloads"][0], name
 
 
-# -- what the per-file pins held, one case each ----------------------------------------
-
-@pytest.mark.parametrize("cell", CELLS)
-def test_fetch_bytes_stands_beside_fetch_ms_in_every_cell(cell, by_cell):
-    got = {q: e for q, e, _ in by_cell[cell]}
+def check_fetch_bytes(t, cell):
+    got = {q: e for q, e, _ in t.resolved(cell)}
     new, old = got["fetch_bytes_per_send"], got["fetch_ms_per_send"]
     assert new["workloads"] == old["workloads"]
     assert new["moves"] == old["moves"] and new["layer"] == old["layer"]
@@ -117,37 +143,105 @@ def test_fetch_bytes_stands_beside_fetch_ms_in_every_cell(cell, by_cell):
         ("program_span", "lower", "bytes")
 
 
-@pytest.mark.parametrize("kind,cells", [(".sat", CLOSED), (".paced", OPEN)])
-def test_obs_feed_idle_is_its_twins_entry_but_for_the_name(kind, cells):
-    e = ENTRIES["obs_feed_idle_ms_per_send" + kind]
-    twin = ENTRIES["obs_feed_ms_per_send" + kind]
+def check_obs_feed_idle(t, kind, cells):
+    e = t.entries["obs_feed_idle_ms_per_send" + kind]
+    twin = t.entries["obs_feed_ms_per_send" + kind]
     assert {k: v for k, v in e.items() if k != "name"} == \
         {k: v for k, v in twin.items() if k != "name"}
-    assert e["workloads"] == cells and e["layer"] == "host staging"
+    assert set(cells) <= set(e["workloads"])
+    assert e["layer"] == "host staging"
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_reads_every_span_quantity(cell, by_cell):
-    got = {q: e for q, e, _ in by_cell[cell]}
-    kind = ".sat" if cell in CLOSED else ".paced"
+def check_span_quantities(t, cell):
+    """A cell reads the span quantities BENCHMARK.json lists it for: a cell
+    that opens no `route_keys` / `obs_feed` span is not asked for them; the
+    five cells of PR 34 read all thirteen."""
+    got = {q: e for q, e, _ in t.resolved(cell)}
+    kind = ".sat" if cell in t.of_kind("closed") else ".paced"
     for q in SPAN_QUANTITIES:
+        if cell not in t.entries[q + kind]["workloads"]:
+            assert cell not in CLOSED + OPEN and q not in got, (cell, q)
+            continue
         e = got[q]
-        assert e["name"] == q + kind and cell in e["workloads"]
+        assert e["name"] == q + kind
         assert e["source"] == "program_span" and e["better"] == "lower"
 
 
-def test_the_served_cells_own_quantities_keep_their_single_cell_entries():
-    cell = "pattern_1m.served_paced"
-    own = {n for n, e in ENTRIES.items() if e["workloads"] == [cell]}
-    assert own == {n + ".served" for n in (
+OWN_SETS = [
+    ("pattern_1m.served_paced", ".served", (
         "send_call_ms_per_send", "delivery_lag_ms_per_send",
         "ring_wait_ms_per_send", "sends_per_drain", "h2d_bytes_per_send",
-        "ring_copy_ms_per_send", "ring_copy_roofline")}
-    assert all(ENTRIES[n]["moves"] == "latency_p50_ms" for n in own)
+        "ring_copy_ms_per_send", "ring_copy_roofline")),
+    ("pattern_32m.mesh4_saturated", ".mesh4", (
+        "shard_group_ms_per_send", "compiles_in_window"))]
 
 
-def test_the_mesh_cells_own_quantities_keep_their_single_cell_entries():
-    cell = "pattern_32m.mesh4_saturated"
-    own = {n for n, e in ENTRIES.items() if e["workloads"] == [cell]}
-    assert own == {"shard_group_ms_per_send.mesh4",
-                   "compiles_in_window.mesh4"}
+def check_own_set(t, cell, suffix, want):
+    own = {n for n, e in t.entries.items() if e["workloads"] == [cell]}
+    assert own >= {n + suffix for n in want}
+    moves = "latency_p50_ms" if cell in OPEN else "events_per_s"
+    assert all(t.entries[n + suffix]["moves"] == moves for n in want)
+
+
+@pytest.mark.parametrize(
+    "pair", PARENT, ids=[f"{p['cell']}-{p['name']}" for p in PARENT])
+def test_every_pair_the_parent_reported_is_still_reported_once(pair):
+    check_pair(TABLE, pair)
+
+
+def test_the_table_keeps_pr34s_names_and_has_room():
+    check_room(TABLE)
+
+
+@pytest.mark.parametrize("cell", TABLE.cells)
+def test_no_cell_resolves_one_quantity_twice_or_loses_one(cell):
+    check_cell(TABLE, cell)
+
+
+@pytest.mark.parametrize("name", sorted(TABLE.entries))
+def test_an_entry_moves_a_metric_every_cell_of_its_list_reports(name):
+    check_entry(TABLE, name)
+
+
+# -- what the per-file pins held, one case each ----------------------------------------
+
+@pytest.mark.parametrize("cell", TABLE.cells)
+def test_fetch_bytes_stands_beside_fetch_ms_in_every_cell(cell):
+    check_fetch_bytes(TABLE, cell)
+
+
+@pytest.mark.parametrize("kind,cells", [(".sat", CLOSED), (".paced", OPEN)])
+def test_obs_feed_idle_is_its_twins_entry_but_for_the_name(kind, cells):
+    check_obs_feed_idle(TABLE, kind, cells)
+
+
+@pytest.mark.parametrize("cell", TABLE.cells)
+def test_every_cell_reads_the_span_quantities_it_is_listed_for(cell):
+    check_span_quantities(TABLE, cell)
+
+
+@pytest.mark.parametrize("cell,suffix,want", OWN_SETS,
+                         ids=[c for c, _, _ in OWN_SETS])
+def test_a_cells_own_quantities_keep_their_single_cell_entries(
+        cell, suffix, want):
+    check_own_set(TABLE, cell, suffix, want)
+
+
+def test_a_seventh_cell_in_the_sat_lists_trips_no_pin(seventh_cell):
+    bench, name = seventh_cell
+    t = Table(bench)
+    assert t.cells[-1] == name and len(t.cells) == len(TABLE.cells) + 1
+    assert name in t.entries["stage_ms_per_send.sat"]["workloads"]
+    for pair in PARENT:
+        check_pair(t, pair)
+    check_room(t)
+    for own in OWN_SETS:
+        check_own_set(t, *own)
+    for cell in t.cells:
+        check_cell(t, cell)
+        check_fetch_bytes(t, cell)
+        check_span_quantities(t, cell)
+    for entry in t.entries:
+        check_entry(t, entry)
+    for kind, cells in ((".sat", CLOSED), (".paced", OPEN)):
+        check_obs_feed_idle(t, kind, cells)

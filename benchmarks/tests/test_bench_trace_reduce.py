@@ -1,7 +1,9 @@
 """The trace reduction: its interval arithmetic by hand, and the small TPU
 v5e trace recorded by `record_trace.py` (three sends of one elementwise op,
 20 ms of `bench:wait_due` before the second and third)."""
+import gzip
 import os
+import shutil
 
 import pytest
 
@@ -129,6 +131,54 @@ def test_recorded_program_spans_name_the_gaps_one_level_down():
     assert gaps["siddhi:sink"] == pytest.approx(0.008205, abs=1e-9)
     assert gaps["bench:send_columns"] < 1e-4 < gaps["siddhi:send"]
     assert "bench:subscriber" not in gaps
+
+
+# -- `breakdown.device_ops` adds up ---------------------------------------------------
+
+def test_a_modules_time_is_its_ops_self_time():
+    # a loop [0, 100) that runs two body ops: the whole lengths are 170,
+    # the plane was busy 100
+    events = [(0, 0.0, 100.0), (1, 10.0, 40.0), (2, 50.0, 90.0)]
+    assert sum(ns for _, ns, _ in tr.self_times(events, 0.0, 100.0)) == 100.0
+    # two ops that only overlap (host ops of two threads in a rehearsal):
+    # still the union, never the sum
+    events = [(0, 0.0, 60.0), (1, 40.0, 100.0)]
+    assert [ns for _, ns, _ in tr.self_times(events, 0.0, 100.0)] == \
+        [40.0, 60.0]
+
+
+@pytest.mark.parametrize("name", [
+    "tiny_tpu.xplane.pb", "tiny_spans.xplane.pb", "tiny_served.xplane.pb.gz",
+    "tiny_sections.xplane.pb.gz", "tiny_plain.xplane.pb.gz"])
+def test_device_ops_sum_to_no_more_than_the_planes_busy_time(name, tmp_path):
+    """Every v5e recording, the tiered (Zipf-shaped) send among them: each
+    has one device plane, so the list's sum is that plane's."""
+    path = os.path.join(os.path.dirname(RECORDED), name)
+    if name.endswith(".gz"):
+        with gzip.open(path) as src, \
+                open(tmp_path / "t.xplane.pb", "wb") as dst:
+            shutil.copyfileobj(src, dst)
+        path = str(tmp_path / "t.xplane.pb")
+    red = tr.reduce_trace(path)
+    assert red["devices"] == 1 and red["busy_s"] > 0
+    booked = sum(s for _, s in red["by_module"])
+    assert booked <= red["busy_s"] * (1 + 1e-9)
+    # ten modules or fewer ran: the list is whole and closes on busy_s
+    assert len(red["by_module"]) < 10
+    assert booked == pytest.approx(red["busy_s"], rel=1e-9)
+    # the whole lengths the list used to sum: more than the plane was busy
+    # wherever a program runs a loop
+    devices, spans = tr.read_planes(path)
+    lo, hi, _ = tr.slice_of(spans)
+    (rec,) = devices.values()
+    whole = sum(min(e + red["skew_s"] * 1e9, hi) -
+                max(s + red["skew_s"] * 1e9, lo)
+                for _, s, e in rec["ops"]
+                if e + red["skew_s"] * 1e9 > lo
+                and s + red["skew_s"] * 1e9 < hi) / 1e9
+    assert whole >= booked * (1 - 1e-9)
+    if "sections" in name:
+        assert whole > 1.2 * red["busy_s"]
 
 
 def test_a_trace_without_sends_reduces_to_nothing(tmp_path):
