@@ -1,0 +1,477 @@
+"""`join_len128`: its plain reference against a per-event Python loop, its
+generator's bookkeeping, `compare` on each fault and the bfloat16 control,
+`least_bytes` from shapes, what its configuration states, the whole of a
+run sound and with every R-side call dropped underneath it, and the join
+step's sections (`harness/join_sections.py`) — on hand-made planes, on
+`data/tiny_join.xplane.pb.gz` (`record_join.py`: four sends of this tree's
+`jit_join_left` / `jit_join_right` on the v5e: the sections add up to the
+slice's busy time, the `route_keys` spans say the lanes), on recordings with
+no join program (None from every reader) and on a CPU rehearsal's trace,
+whose host ops stand in."""
+import json
+import os
+
+import numpy as np
+import pytest
+
+import siddhi_tpu
+from benchmarks.harness import join_sections as js
+from benchmarks.harness import loader, numeric
+from benchmarks.harness import trace_reduce as tr
+from test_bench_doctored import load_run_module
+from test_bench_step_sections import reader, recorded_run
+from test_bench_two_streams import WARM, BrokenRuntime
+
+CELL = "join_len128.saturated"
+BENCH = loader.load_benchmark()
+QUANTITIES = {
+    "join_window_ms_per_send": ("join_window",),
+    "join_probe_ms_per_send": ("join_lanes", "join_probe"),
+    "join_pairs_ms_per_send": ("join_pairs", "join_select"),
+    "join_compact_ms_per_send": ("join_compact",),
+    "join_unscoped_ms_per_send": (js.UNSCOPED,),
+}
+LANES = "join_lane_fill_pct"
+ZERO = dict.fromkeys(("rows_missing", "rows_unexpected", "rows_differing"), 0)
+
+
+def sends_of(cell, seed, n, **traffic):
+    """The first `n` sends of the cell's traffic, as the harness makes
+    them, and the plan they were made with."""
+    m, t = cell.model, dict(cell.traffic, **traffic)
+    plan, clock, sends = m.plan(seed, t, cell.sizes), 1000, []
+    for i in range(n):
+        clock += m.clock_step_ms(t)
+        sends.append(m.make_send(np.random.default_rng([seed, i]), i, t,
+                                 plan, clock))
+    return sends, plan
+
+
+# -- the model alone ----------------------------------------------------------------
+
+def by_hand(sends, w):
+    """The query, an event at a time in plain Python: an arriving event is
+    paired with every held row of the other side that has its symbol, then
+    the arriving side holds it, and no more than `w` rows."""
+    held, out = {"L": [], "R": []}, []
+    for send in sends:
+        side = send["stream"]
+        other = "R" if side == "L" else "L"
+        rows = []
+        before = list(held[other])        # as it stood before the send
+        for sym, value in zip(*send["cols"]):
+            for osym, ovalue in before:
+                if osym == sym:
+                    rows.append((sym, value, ovalue) if side == "L" else
+                                (sym, ovalue, value))
+        held[side] = (held[side] + list(zip(*send["cols"])))[-w:]
+        out.append(rows)
+    return out
+
+
+@pytest.mark.parametrize("w,events,symbols", [(8, 40, 16), (128, 300, 64),
+                                              (16, 5, 4)])
+def test_the_reference_is_the_per_event_loop(w, events, symbols):
+    cell = loader.resolve(CELL)
+    cell.sizes["window_length"] = w
+    sends, plan = sends_of(cell, 5, 7, events_per_send=events,
+                           symbols=symbols)
+    refs = cell.model.reference(sends, plan)
+    for send, ref, want in zip(sends, refs, by_hand(sends, w)):
+        got = cell.model.canonical(ref)
+        assert [a.dtype for a in got.values()] == \
+            [np.int64, np.float32, np.int32]
+        assert list(zip(*(got[n].tolist() for n in "spv"))) == sorted(
+            (int(s), float(p), int(v)) for s, p, v in want)
+        assert cell.model.expected_rows(send) == len(want)
+    assert len(refs[0]["s"]) == 0 and sum(len(r["s"]) for r in refs) > 0
+
+
+def test_a_send_is_new_in_every_column_and_knows_what_it_is_owed():
+    cell = loader.resolve(CELL)
+    m, t = cell.model, cell.traffic
+    assert m.events_per_send(t) == 32768 and m.clock_step_ms(t) == 1
+    assert (t["symbols"], t["qty_hi"], t["loop"]) == (64, 8, "closed")
+    sends, plan = sends_of(cell, 9, 6)
+    refs = m.reference(sends, plan)
+    for i, (s, ref) in enumerate(zip(sends, refs)):
+        sym, value = s["cols"]
+        assert s["stream"] == "LR"[i % 2] and s["events"] == 32768
+        assert (sym.dtype, s["ts"].dtype) == (np.int64, np.int64)
+        assert value.dtype == (np.float32 if i % 2 == 0 else np.int32)
+        assert 0 <= sym.min() and sym.max() == 63
+        if i % 2 == 0:
+            assert 0 <= value.min() and value.max() < 1
+        else:
+            assert (value.min(), value.max()) == (1, 8)
+        assert np.unique(s["ts"]).tolist() == [1001 + i]
+        # what the 64-bin count says is what the reference pairs
+        assert m.expected_rows(s) == ref["s"].shape[0]
+    owed = [m.expected_rows(s) for s in sends]
+    # about two pairs an event: 128 held rows over 64 symbols
+    assert owed[0] == 0 and all(64000 < n < 67000 for n in owed[1:]), owed
+    for a, b in zip(sends, sends[2:]):            # the same side's next send
+        for ca, cb in zip(a["cols"], b["cols"]):
+            assert not np.array_equal(ca, cb)
+    # the generator keeps each side's last 128 symbols and nothing else
+    assert [plan["held"][side].shape for side in "LR"] == [(128,), (128,)]
+    assert np.array_equal(plan["held"]["R"], sends[5]["cols"][0][-128:])
+
+
+def test_compare_catches_each_fault_and_the_control_fails():
+    cell = loader.resolve(CELL)
+    m = cell.model
+    assert m.LIMITS == ZERO
+    sends, plan = sends_of(cell, 2, 3, events_per_send=2048)
+    want = m.canonical(m.reference(sends, plan)[2])
+    n = want["s"].shape[0]
+    assert n > 3000 and m.compare(want, want) == ZERO
+    # any order of delivery is the same answer
+    shuffled = np.random.default_rng(1).permutation(n)
+    assert m.compare(m.canonical({k: a[shuffled] for k, a in want.items()}),
+                     want) == ZERO
+    keep = np.arange(n) != 17
+    dropped = {k: a[keep] for k, a in want.items()}
+    assert m.compare(dropped, want) == dict(ZERO, rows_missing=1)
+    twice = m.canonical({k: np.concatenate([a, a[17:18]])
+                         for k, a in want.items()})
+    assert m.compare(twice, want) == dict(ZERO, rows_unexpected=1)
+    # one dropped and another doubled: the count is right, the rows are not
+    both = m.canonical({k: np.concatenate([a[keep], a[400:401]])
+                        for k, a in want.items()})
+    assert m.compare(both, want)["rows_differing"] >= 1
+    ulp = dict(want, p=want["p"].copy())
+    ulp["p"][5] = np.nextafter(ulp["p"][5], np.float32(2))
+    assert m.compare(ulp, want) == dict(ZERO, rows_differing=1)
+    other_qty = dict(want, v=want["v"].copy())
+    other_qty["v"][9] += 1
+    assert m.compare(other_qty, want) == dict(ZERO, rows_differing=1)
+    # the control: `p` through bfloat16 differs on nearly every pair
+    ctl = m.compare(m.canonical(m.control_rows(want)), want)
+    assert ctl["rows_missing"] == ctl["rows_unexpected"] == 0
+    assert ctl["rows_differing"] > 0.9 * n
+    assert np.array_equal(m.control_rows(want)["p"],
+                          numeric.to_bf16(want["p"]))
+    assert m.control_rows(want)["v"] is want["v"]
+
+
+def test_least_bytes_from_shapes():
+    cell = loader.resolve(CELL)
+    # 32,768 events of 20 B in, 65,536 pairs of 24 B out, both 128-row
+    # windows of 20 B rows read and one written
+    assert cell.model.least_bytes(cell.traffic, cell.sizes, cell.config) \
+        == 32768 * 20 + 65536 * 24 + 3 * 128 * 20 == 2235904
+    r = loader.resolve(CELL, rehearse=True)
+    assert r.model.least_bytes(r.traffic, r.sizes, r.config) \
+        == 512 * 20 + 64 * 24 + 3 * 8 * 20
+
+
+def test_config_states_what_the_contract_asks():
+    cell = loader.resolve(CELL)
+    cfg = cell.config
+    for key in ("source", "deployment", "assumed", "guarantees",
+                "tolerance", "reduced_why", "scale_from"):
+        assert cfg[key]
+    assert cfg["reduced"] == [] and len(cfg["source"]) <= 200
+    assert "configs`[2]" in cfg["source"] and "bench.py" in cfg["source"] \
+        and "WINDOWED_JOIN_QL" in cfg["source"]
+    assert cfg["sizes"] == {"window_length": 128, "emit_rows": 262144}
+    assert cfg["rehearse_sizes"] == {"window_length": 8, "emit_rows": 8192}
+    assert (cfg["stream"], cfg["streams"], cfg["query"], cfg["columns"]) \
+        == ("L", ["L", "R"], "q", ["s", "p", "v"])
+    # the emission cap is a size set by hand, stated as a debt
+    debt = [a for a in cfg["assumed"] if a.startswith("`emit_rows`")]
+    assert len(debt) == 1 and "EXPIRED" in debt[0] and "65,536" in debt[0]
+    assert any("64 symbols" in a for a in cfg["assumed"])
+    assert any("n_dropped" in g for g in cfg["guarantees"])
+    # the corpus text, but for the two sizes
+    from siddhi_tpu.analysis import corpus
+    want = corpus.WINDOWED_JOIN_QL.strip() \
+        .replace("rows='65536'", "rows='262144'")
+    assert cell.app_text.strip() == want
+    assert cell.chips == 1 and cell.traffic["loop"] == "closed"
+    for key in cell.traffic:
+        if key in ("events_per_send", "symbols", "warmup_sends",
+                   "prepare_sends_per_s", "trace_sends"):
+            assert cell.traffic[key.split("_")[0] + "_why"], key
+    entry = next(c for c in BENCH["configs"] if c["name"] == cfg["name"])
+    assert entry["source"] == cfg["source"] and entry["reduced"] == []
+    assert entry["file"] == "benchmarks/configs/join_len128/config.json"
+    assert {e["name"] for e in cell.end_to_end} == {
+        "events_per_s", "latency_p50_ms", "setup_s"}
+    # the model imports nothing of the program
+    with open(os.path.join(loader.BENCH_DIR, "configs", cfg["name"],
+                           "model.py")) as fh:
+        assert "siddhi_tpu" not in fh.read().split('"""', 2)[2]
+
+
+# -- the whole of a run, sound and broken underneath --------------------------------
+
+def run_with(monkeypatch, capsys, fault, trace=0):
+    real = siddhi_tpu.SiddhiManager.create_siddhi_app_runtime
+    assert loader.resolve(CELL, rehearse=True).traffic["warmup_sends"] \
+        == WARM                      # BrokenRuntime spares the warm-up
+
+    def create(self, *a, **kw):
+        rt = real(self, *a, **kw)
+        return BrokenRuntime(rt, fault) if fault else rt
+    monkeypatch.setattr(siddhi_tpu.SiddhiManager,
+                        "create_siddhi_app_runtime", create)
+    rc = load_run_module().main(["--workload", CELL, "--seed", "11",
+                                 "--seconds", "1.0", "--trace", str(trace),
+                                 "--rehearse"])
+    out = capsys.readouterr().out
+    return rc, json.loads(out.strip().splitlines()[-1]), out
+
+
+def test_the_rehearsal_of_the_cell_is_correct(monkeypatch, capsys):
+    rc, last, out = run_with(monkeypatch, capsys, None)
+    assert rc == 0 and last["correct"] is True and last["failed"] == 0, \
+        out[-1500:]
+    assert last["attempted"] >= 20
+    assert all(c == {"value": 0, "limit": 0}
+               for c in last["compared"].values())
+
+
+def test_a_runtime_that_drops_every_r_side_call_is_not_correct(
+        monkeypatch, capsys):
+    """The L sends still pair — with an R window nobody refreshed: other
+    pairs than the reference's; an R send delivers nothing and is never
+    complete."""
+    rc, last, out = run_with(monkeypatch, capsys, "drop_r_calls")
+    assert rc == 0
+    assert last["correct"] is False and last["failed"] >= 1, out[-1500:]
+    missing = last["compared"]["rows_missing"]
+    assert missing["value"] > missing["limit"] == 0 and "OVER" in out
+    assert last["compared"]["sends_undelivered"]["value"] >= 1
+
+
+def test_a_cpu_rehearsals_host_ops_stand_in_for_the_device_plane(
+        monkeypatch, capsys):
+    """No device plane: the host plane's XLA:CPU ops, named through the
+    trace's own `HloProto`s, go through the same reduction — every section
+    has time in it, they close against the slice's busy time, and the six
+    readers read (their numbers are withheld, as every rehearsal's)."""
+    rc, last, out = run_with(monkeypatch, capsys, None, trace=1)
+    assert rc == 0 and last["correct"] is True and last["metrics"] == {}
+    line = next(ln for ln in out.splitlines()
+                if ln.startswith("join step sections: "))
+    red = json.loads(line.split(": ", 1)[1])
+    assert red["devices"] == 1 and red["sends"] == 4
+    assert set(js.SECTIONS) <= set(red["sections_s"])
+    assert all(red["sections_s"][s] > 0 for s in js.SECTIONS)
+    assert "jit_join_left" not in red["other_modules_s"] and \
+        "jit_join_right" not in red["other_modules_s"]
+    assert red["closure"]["ratio"] == pytest.approx(1.0, rel=1e-6)
+    withheld = next(ln for ln in out.splitlines() if "withheld" in ln)
+    for q in list(QUANTITIES) + [LANES]:
+        assert q + ".sat" in withheld, q
+    assert "join lanes over the slice: {" in out
+
+
+# -- the join step's sections: the arithmetic, on hand-made planes --------------------
+
+def test_a_tf_op_names_its_outermost_join_section():
+    assert js.named("jit(join_left)/join_lanes/sort:") == "join_lanes"
+    # a section of the shared code, inside: the join section around it
+    assert js.named("jit(join_right)/join_window/window_order/sort:") == \
+        "join_window"
+    assert js.named("jit(join_left)/join_select/project/convert:") == \
+        "join_select"
+    # merged by the compiler: the first name decides
+    assert js.named("jit(join_left)/join_pairs/gather;jit(join_left)/"
+                    "join_compact/sort:") == "join_pairs"
+    assert js.named("state[0][0].ts:") is js.named("") is js.named(None) \
+        is None
+    # a scope's name inside another word is not the scope, nor is another
+    # program family's section one of these
+    assert js.named("jit(f)/my_join_probe/add:") is None
+    assert js.named("jit(plain_step)/window_order/sort:") is None
+    from benchmarks.harness import plain_sections as ps
+    assert not set(js.SECTIONS) & (set(js.ss.SECTIONS) | set(ps.SECTIONS))
+
+
+class FakeLine:
+    def __init__(self, name, events):
+        self.name, self._events = name, events
+
+    def events(self):
+        return iter(self._events)
+
+
+class FakePlane:
+    def __init__(self, metadata, ops, modules):
+        self.name = "/device:TPU:0"
+        self.metadata = metadata
+        self.lines = [FakeLine(tr.OPS_LINE, ops),
+                      FakeLine(tr.MODULES_LINE, modules)]
+
+
+def test_a_planes_time_goes_to_sections_unscoped_and_other_modules():
+    def op(tf_op, pid, cat="x"):
+        return {"tf_op": tf_op, "program_id": pid, "hlo_category": cat}
+    meta = {
+        1: ("sort.1", op("jit(join_left)/join_compact/sort:", 7)),
+        2: ("while.1", op("jit(join_left)/join_window/window_order/while:",
+                          7)),
+        3: ("fusion.1", op("", 7, "data formatting")),      # inside the loop
+        4: ("copy.1", op("state[0][0].ts:", 7, "data formatting")),
+        5: ("convert.1", op("jit(convert_element_type)/convert:", 9)),
+        6: ("fusion.2", op("jit(join_right)/join_probe/gather:", 8)),
+        20: ("jit_join_left(7)", {}), 21: ("jit_convert(9)", {}),
+        22: ("jit_join_right(8)", {}),
+    }
+    ops = [(1, 0.0, 40.0), (2, 50.0, 150.0), (3, 60.0, 90.0),
+           (4, 160.0, 170.0), (5, 200.0, 205.0), (6, 210.0, 260.0),
+           (1, 1000.0, 1040.0)]                             # past the slice
+    modules = [(20, 0.0, 170.0), (21, 200.0, 205.0), (22, 210.0, 260.0)]
+    sections, unscoped, others = js.reduce_plane(
+        FakePlane(meta, ops, modules), 0.0, 300.0, 0.0)
+    # the loop's own 70 and the 30 of the op inside it that names nothing;
+    # both side programs are join programs
+    assert sections == {"join_compact": 40.0, "join_window": 100.0,
+                        "join_probe": 50.0, js.UNSCOPED: 10.0}
+    assert unscoped == {"data formatting": 10.0}
+    assert others == {"jit_convert": 5.0}
+    assert sum(sections.values()) + sum(others.values()) == 205.0
+    # a plane whose programs name no join section: all other modules
+    bare = {k: (n, dict(s, tf_op="jit(plain_step)/window_order/sort:")
+                if s else s) for k, (n, s) in meta.items()}
+    sections, _, others = js.reduce_plane(
+        FakePlane(bare, ops, modules), 0.0, 300.0, 0.0)
+    assert sections == {} and others == {
+        "jit_join_left": 150.0, "jit_convert": 5.0, "jit_join_right": 50.0}
+
+
+def test_an_hlo_protos_instructions_say_their_op_names():
+    def field(number, payload):
+        assert len(payload) < 128
+        return bytes([number << 3 | 2, len(payload)]) + payload
+
+    def instruction(name, op_name=None):
+        meta = field(7, field(1, b"add") + field(2, op_name.encode())) \
+            if op_name is not None else b""
+        return field(2, field(1, name.encode()) + field(2, b"add") + meta)
+    computation = field(1, b"main") + \
+        instruction("add.1", "jit(join_left)/join_probe/add") + \
+        instruction("copy.2")
+    module = field(1, b"jit_join_left") + field(3, computation)
+    proto = field(1, module) + field(3, b"\x00")
+    assert js.hlo_op_names(proto) == {
+        "add.1": "jit(join_left)/join_probe/add", "copy.2": ""}
+    assert js.hlo_op_names(b"") == {}
+
+
+# -- this tree's scopes, as recorded on the v5e ------------------------------------------
+
+def test_the_recorded_join_steps_sections_add_up_to_the_busy_time(
+        tmp_path, capsys):
+    run = recorded_run(tmp_path, "tiny_join.xplane.pb.gz")
+    out = js.join_sections(run)
+    assert "join step sections: {" in capsys.readouterr().out
+    assert js.join_sections(run) is out              # computed once
+    assert capsys.readouterr().out == ""
+    red = run["trace_reduced"]
+    assert (out["sends"], out["devices"]) == (red["sends_in_slice"], 1) \
+        == (4, 1)
+    # both side programs ran, twice each, and neither is another module
+    modules = dict(red["by_module"])
+    assert modules["jit_join_left"] > 0 and modules["jit_join_right"] > 0
+    assert not {"jit_join_left", "jit_join_right"} & \
+        set(out["other_modules_s"])
+    for section in js.SECTIONS:
+        assert out["sections_s"][section] > 0, section
+    assert set(out["sections_s"]) <= set(js.SECTIONS) | {js.UNSCOPED}
+    assert sum(out["unscoped_by_category_s"].values()) == \
+        pytest.approx(out["sections_s"].get(js.UNSCOPED, 0.0))
+    assert out["unscoped_share"] < 0.25
+    # closure: to the last digit, both sides sum whole nanoseconds
+    assert out["join_s"] + sum(out["other_modules_s"].values()) == \
+        pytest.approx(out["total_s"])
+    assert out["total_s"] == pytest.approx(red["busy_s"], rel=1e-9)
+    assert out["closure"]["ratio"] == pytest.approx(1.0, rel=1e-9)
+    # the five readers split the join steps' time between them
+    per_send = sum(reader(q)(run) for q in QUANTITIES)
+    assert per_send == pytest.approx(out["join_s"] * 1e3 / 4)
+    for q, sections in QUANTITIES.items():
+        assert reader(q)(run) == pytest.approx(sum(
+            out["sections_s"].get(s, 0.0) for s in sections) * 1e3 / 4), q
+    # the pattern programs' reader sees them as other modules (the plain
+    # step's would not: the shared code names `window_order` and `project`
+    # inside a join program too, which is why the cell lists no `plain_*`)
+    assert js.ss.step_sections(run) is None
+
+
+def test_the_recorded_route_keys_spans_say_the_lanes(tmp_path, capsys):
+    run = recorded_run(tmp_path, "tiny_join.xplane.pb.gz")
+    lanes = js.lanes(run)
+    assert "join lanes over the slice: {" in capsys.readouterr().out
+    assert js.lanes(run) is lanes and capsys.readouterr().out == ""
+    # one span a send; windows of 8 rows over 64 symbols under lanes as
+    # wide as the planner's floor
+    assert lanes["spans"] == 4 and lanes["lane_k"] % 4 == 0
+    assert 1 <= lanes["lane_need_max"] <= lanes["lane_k"] // 4
+    assert 4 <= lanes["lane_need"] <= 4 * lanes["lane_need_max"]
+    assert reader(LANES)(run) == pytest.approx(
+        100.0 * lanes["lane_need"] / lanes["lane_k"])
+    assert 0 < reader(LANES)(run) <= 100
+
+
+@pytest.mark.parametrize("name", ["tiny_sections.xplane.pb.gz",
+                                  "tiny_served.xplane.pb.gz",
+                                  "tiny_plain.xplane.pb.gz"])
+def test_a_trace_with_no_join_program_reads_none(tmp_path, name):
+    """The other program families' recordings (and so a tree older than the
+    scopes and the span stats: the parent of the PR that brought them)."""
+    run = recorded_run(tmp_path, name)
+    assert js.join_sections(run) is None and js.lanes(run) is None
+    for q in list(QUANTITIES) + [LANES]:
+        assert reader(q)(run) is None, q
+        assert reader(q)({"trace_dir": None, "trace_reduced": None}) is None
+
+
+# -- the six entries and the lists the cell joined ----------------------------------------
+
+def test_the_six_entries_and_the_lists_the_cell_joined():
+    names = [e["name"] for e in BENCH["per_layer"]]
+    entries = {e["name"]: e for e in BENCH["per_layer"]}
+    mine = [q + ".sat" for q in QUANTITIES] + [LANES + ".sat"]
+    at = names.index(mine[0])
+    assert names[at:at + 6] == mine and 80 <= at and len(names) <= 128
+    for name in mine:
+        e = dict(entries[name])
+        assert e.pop("workloads")[0] == CELL
+        device = name != LANES + ".sat"
+        assert e == {
+            "name": name, "unit": "ms" if device else "%",
+            "better": "lower" if device else "higher",
+            "source": "device_trace" if device else "program_span",
+            "layer": "device step" if device else "host staging",
+            "moves": "events_per_s"}
+    got = {e["name"]: read.__module__
+           for e, read in loader.resolve(CELL).per_layer}
+    for name in mine:
+        assert got[name] == "bench_layer_" + name[:-4]
+    # joined: every `.sat` list the length-batch cell is in but its four
+    # `plain_*`, and key routing and the observatory's feed (a join opens
+    # both spans); left out: the pattern programs' sections, the mesh's
+    twin = {e["name"] for e in BENCH["per_layer"]
+            if "lengthbatch_1000.saturated" in e["workloads"]}
+    assert set(got) == {n for n in twin if not n.startswith("plain_")} | \
+        set(mine) | {"route_keys_ms_per_send.sat", "obs_feed_ms_per_send.sat",
+                     "obs_feed_idle_ms_per_send.sat"}
+    assert not {n for n in got if n.startswith(("step_", "plain_")) and
+                n != "step_roofline"}
+    assert not {n for n in got if n.endswith((".mesh4", ".paced"))}
+    # it joined each list behind the six cells that were there
+    cells = [w["name"] for w in BENCH["workloads"]]
+    older = cells[:cells.index(CELL)]
+    assert len(older) == 6
+    for e in BENCH["end_to_end"] + BENCH["per_layer"]:
+        if CELL in e.get("workloads", ()):
+            assert e["workloads"].index(CELL) == \
+                len([c for c in e["workloads"] if c in older]), e["name"]
+    w = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    assert (w["config"], w["traffic"], w["chips"]) == \
+        ("join_len128", "saturated_two_streams_32k", 1)
+    assert len(w["why"]) <= 200

@@ -468,25 +468,28 @@ def plan_join_query(
             this_state = wl_state if this_is_left else wr_state
             other_state = wr_state if this_is_left else wl_state
 
-            env0 = {this.key: cols, "__ts__": ts, "__now__": now}
-            keep = valid
-            is_cur = kind == ev.CURRENT
-            for f in this.pre_filters:
-                keep = jnp.logical_and(keep, jnp.logical_or(
-                    jnp.logical_not(is_cur), f.fn(env0)))
-            in_cols = cols
-            if bucket:
-                # key bucket slot rides the window buffer as a column
-                in_cols = cols + (probe,)
-            elif table_probe:
-                # original batch row index rides the (windowless) window
-                # so compacted trigger rows can find their host-computed
-                # table candidates
-                in_cols = cols + (jnp.arange(ts.shape[0],
-                                             dtype=jnp.int32),)
-            rows = Rows(ts=ts, kind=kind, valid=keep,
-                        seq=jnp.zeros_like(ts), gslot=gslot, cols=in_cols)
-            this_state, wout = this.window.process(this_state, rows, now)
+            # device-trace sections (jax.named_scope: op-name metadata
+            # only), read by benchmarks/harness/join_sections.py
+            with jax.named_scope("join_window"):
+                env0 = {this.key: cols, "__ts__": ts, "__now__": now}
+                keep = valid
+                is_cur = kind == ev.CURRENT
+                for f in this.pre_filters:
+                    keep = jnp.logical_and(keep, jnp.logical_or(
+                        jnp.logical_not(is_cur), f.fn(env0)))
+                in_cols = cols
+                if bucket:
+                    # key bucket slot rides the window buffer as a column
+                    in_cols = cols + (probe,)
+                elif table_probe:
+                    # original batch row index rides the (windowless) window
+                    # so compacted trigger rows can find their host-computed
+                    # table candidates
+                    in_cols = cols + (jnp.arange(ts.shape[0],
+                                                 dtype=jnp.int32),)
+                rows = Rows(ts=ts, kind=kind, valid=keep,
+                            seq=jnp.zeros_like(ts), gslot=gslot, cols=in_cols)
+                this_state, wout = this.window.process(this_state, rows, now)
             orows = wout.rows                       # [R]
             if bucket or table_probe:
                 trig_extra = orows.cols[-1]
@@ -509,142 +512,149 @@ def plan_join_query(
 
             R = orows.ts.shape[0]
             C = o_ts.shape[0]
-            data_row = jnp.logical_and(
-                orows.valid,
-                jnp.logical_or(orows.kind == ev.CURRENT,
-                               orows.kind == ev.EXPIRED))
             if bucket:
-                # [R, K] same-bucket candidates instead of the [R, C]
-                # grid: the lane table is re-derived from the buffer's
-                # slot column each dispatch (O(C log C), never O(R*C)),
-                # the full ON-condition re-verifies every candidate, so
-                # hash/lane collisions only cost work, never matches
-                lanes = _bucket_lanes(o_jslot, o_alive, nbl_other,
-                                      lane_k)
-                tb = trig_extra.astype(jnp.int32) % nbl_other
-                cand = lanes[tb]                       # [R, K]
-                cand_ok = cand < C
-                ri2 = jnp.minimum(cand, C - 1)
-                env = {
-                    this.key: tuple(c[:, None] for c in t_cols),
-                    other.key: tuple(c[ri2] for c in o_cols),
-                    "__ts__": orows.ts[:, None],
-                    "__now__": now,
-                }
-                m = jnp.broadcast_to(on.fn(env), ri2.shape)
-                m = jnp.logical_and(m, cand_ok)
-                m = jnp.logical_and(m, o_alive[ri2])
-            elif table_probe:
-                cand_b, ok_b = probe                   # [B, K] host probe
-                B = cand_b.shape[0]
-                bix = jnp.clip(trig_extra, 0, B - 1)
-                cand = cand_b[bix]                     # [R, K]
-                cand_ok = jnp.logical_and(ok_b[bix], cand >= 0)
-                ri2 = jnp.clip(cand, 0, C - 1)
-                env = {
-                    this.key: tuple(c[:, None] for c in t_cols),
-                    other.key: tuple(c[ri2] for c in o_cols),
-                    "__ts__": orows.ts[:, None],
-                    "__now__": now,
-                }
-                m = jnp.broadcast_to(on.fn(env), ri2.shape)
-                m = jnp.logical_and(m, cand_ok)
-                m = jnp.logical_and(m, o_alive[ri2])
-            else:
-                env = {
-                    this.key: tuple(c[:, None] for c in t_cols),
-                    other.key: tuple(c[None, :] for c in o_cols),
-                    "__ts__": orows.ts[:, None],
-                    "__now__": now,
-                }
-                if on is None:
-                    m = jnp.ones((R, C), jnp.bool_)
+                with jax.named_scope("join_lanes"):
+                    # [R, K] same-bucket candidates instead of the [R, C]
+                    # grid: the lane table is re-derived from the buffer's
+                    # slot column each dispatch (O(C log C), never O(R*C)),
+                    # the full ON-condition re-verifies every candidate, so
+                    # hash/lane collisions only cost work, never matches
+                    lanes = _bucket_lanes(o_jslot, o_alive, nbl_other,
+                                          lane_k)
+            with jax.named_scope("join_probe"):
+                data_row = jnp.logical_and(
+                    orows.valid,
+                    jnp.logical_or(orows.kind == ev.CURRENT,
+                                   orows.kind == ev.EXPIRED))
+                if bucket:
+                    tb = trig_extra.astype(jnp.int32) % nbl_other
+                    cand = lanes[tb]                       # [R, K]
+                    cand_ok = cand < C
+                    ri2 = jnp.minimum(cand, C - 1)
+                    env = {
+                        this.key: tuple(c[:, None] for c in t_cols),
+                        other.key: tuple(c[ri2] for c in o_cols),
+                        "__ts__": orows.ts[:, None],
+                        "__now__": now,
+                    }
+                    m = jnp.broadcast_to(on.fn(env), ri2.shape)
+                    m = jnp.logical_and(m, cand_ok)
+                    m = jnp.logical_and(m, o_alive[ri2])
+                elif table_probe:
+                    cand_b, ok_b = probe                   # [B, K] host probe
+                    B = cand_b.shape[0]
+                    bix = jnp.clip(trig_extra, 0, B - 1)
+                    cand = cand_b[bix]                     # [R, K]
+                    cand_ok = jnp.logical_and(ok_b[bix], cand >= 0)
+                    ri2 = jnp.clip(cand, 0, C - 1)
+                    env = {
+                        this.key: tuple(c[:, None] for c in t_cols),
+                        other.key: tuple(c[ri2] for c in o_cols),
+                        "__ts__": orows.ts[:, None],
+                        "__now__": now,
+                    }
+                    m = jnp.broadcast_to(on.fn(env), ri2.shape)
+                    m = jnp.logical_and(m, cand_ok)
+                    m = jnp.logical_and(m, o_alive[ri2])
                 else:
-                    m = jnp.broadcast_to(on.fn(env), (R, C))
-                m = jnp.logical_and(m, o_alive[None, :])
-                ri2 = jnp.broadcast_to(
-                    jnp.arange(C, dtype=jnp.int32)[None, :], (R, C))
-            m = jnp.logical_and(m, data_row[:, None])
+                    env = {
+                        this.key: tuple(c[:, None] for c in t_cols),
+                        other.key: tuple(c[None, :] for c in o_cols),
+                        "__ts__": orows.ts[:, None],
+                        "__now__": now,
+                    }
+                    if on is None:
+                        m = jnp.ones((R, C), jnp.bool_)
+                    else:
+                        m = jnp.broadcast_to(on.fn(env), (R, C))
+                    m = jnp.logical_and(m, o_alive[None, :])
+                    ri2 = jnp.broadcast_to(
+                        jnp.arange(C, dtype=jnp.int32)[None, :], (R, C))
+                m = jnp.logical_and(m, data_row[:, None])
 
-            # matched pair rows [R*Q] + unmatched rows [R] for outer
-            # joins; ri carries REAL buffer positions so seq/order match
-            # the grid path bit for bit
-            Q = m.shape[1]
-            pair_valid = m.reshape(-1)
-            left_idx = jnp.repeat(jnp.arange(R), Q)
-            right_idx = ri2.astype(jnp.int32).reshape(-1)
-            unmatched = jnp.logical_and(data_row, jnp.logical_not(
-                jnp.any(m, axis=1)))
-            if emit_unmatched_this:
-                all_valid = jnp.concatenate([pair_valid, unmatched])
-                li = jnp.concatenate([left_idx, jnp.arange(R)])
-                ri = jnp.concatenate([right_idx, jnp.zeros((R,), jnp.int32)])
-                null_tail = jnp.concatenate(
-                    [jnp.zeros((R * Q,), jnp.bool_), unmatched])
-            else:
-                all_valid = pair_valid
-                li, ri = left_idx, right_idx
-                null_tail = jnp.zeros((R * Q,), jnp.bool_)
+            with jax.named_scope("join_pairs"):
+                # matched pair rows [R*Q] + unmatched rows [R] for outer
+                # joins; ri carries REAL buffer positions so seq/order match
+                # the grid path bit for bit
+                Q = m.shape[1]
+                pair_valid = m.reshape(-1)
+                left_idx = jnp.repeat(jnp.arange(R), Q)
+                right_idx = ri2.astype(jnp.int32).reshape(-1)
+                unmatched = jnp.logical_and(data_row, jnp.logical_not(
+                    jnp.any(m, axis=1)))
+                if emit_unmatched_this:
+                    all_valid = jnp.concatenate([pair_valid, unmatched])
+                    li = jnp.concatenate([left_idx, jnp.arange(R)])
+                    ri = jnp.concatenate(
+                        [right_idx, jnp.zeros((R,), jnp.int32)])
+                    null_tail = jnp.concatenate(
+                        [jnp.zeros((R * Q,), jnp.bool_), unmatched])
+                else:
+                    all_valid = pair_valid
+                    li, ri = left_idx, right_idx
+                    null_tail = jnp.zeros((R * Q,), jnp.bool_)
 
-            N = all_valid.shape[0]
-            this_cols = tuple(c[li] for c in t_cols)
-            # unmatched outer-join rows carry REAL nulls on the other side
-            # (reference: JoinProcessor.java:107-190 emits null attributes;
-            # numerics use the reserved in-band null, core/event.py)
-            other_cols_g = tuple(
-                jnp.where(null_tail,
-                          jnp.asarray(ev.null_value(t), dtype=c.dtype),
-                          c[ri])
-                for c, t in zip(o_cols, other.schema.types))
-            sel_env = {
-                this.key: this_cols,
-                other.key: other_cols_g,
-                "__ts__": orows.ts[li],
-                "__now__": now,
-            }
-            # composed group slot: gl * (Kr + 1) + gr; unmatched outer rows
-            # take the other side's null-group id (K_other)
-            tg = orows.gslot[li]
-            og = jnp.where(null_tail, K_other,
-                           o_gslot[jnp.clip(ri, 0, C - 1)])
-            if this_is_left:
-                comp = tg * (Kr + 1) + og
-            else:
-                comp = og * (Kr + 1) + tg
-            jrows = Rows(
-                ts=orows.ts[li],
-                kind=orows.kind[li],
-                valid=all_valid,
-                seq=orows.seq[li] * (C + 1) + ri,
-                gslot=comp.astype(jnp.int32),
-                cols=(),
-            )
-            sel_state, out = sel.process(sel_state, jrows, sel_env)
-            # device-side compaction: the [N] grid (N = R*C(+R)) would cost
-            # N-row host fetches per send — megabytes of D2H for
-            # kilobytes of matches.  Stable valid-first argsort
-            # keeps delivery order; rows beyond the cap are counted as
-            # dropped and the runtime grows the cap (a planned recompile)
-            # when the cap was implicit.
-            o_ts, o_kind, o_valid, o_cols = out
-            N = o_ts.shape[0]
-            cap = min(N, emit_rows if emit_rows is not None
-                      else max(2 * R, 1024))
-            n_tot = jnp.sum(o_valid).astype(jnp.int32)
-            if cap < N:
-                order = jnp.argsort(jnp.logical_not(o_valid),
-                                    stable=True)[:cap]
-                o_ts, o_kind, o_valid = \
-                    o_ts[order], o_kind[order], o_valid[order]
-                o_cols = tuple(c[order] for c in o_cols)
-            n_del = jnp.minimum(n_tot, jnp.int32(cap))
-            # header ships [n_valid, n_current] so count-only consumers
-            # (the common bench/monitoring shape) cost ZERO bulk fetches;
-            # n_expired derives as n_valid - n_current host-side
-            n_cur = jnp.sum(jnp.logical_and(
-                o_valid, o_kind == ev.CURRENT)).astype(jnp.int32)
-            out = (jnp.stack([n_del, n_cur]), n_tot - n_del,
-                   o_ts, o_kind, o_valid, o_cols)
+                N = all_valid.shape[0]
+                this_cols = tuple(c[li] for c in t_cols)
+                # unmatched outer-join rows carry REAL nulls on the other side
+                # (reference: JoinProcessor.java:107-190 emits null attributes;
+                # numerics use the reserved in-band null, core/event.py)
+                other_cols_g = tuple(
+                    jnp.where(null_tail,
+                              jnp.asarray(ev.null_value(t), dtype=c.dtype),
+                              c[ri])
+                    for c, t in zip(o_cols, other.schema.types))
+                sel_env = {
+                    this.key: this_cols,
+                    other.key: other_cols_g,
+                    "__ts__": orows.ts[li],
+                    "__now__": now,
+                }
+                # composed group slot: gl * (Kr + 1) + gr; unmatched outer rows
+                # take the other side's null-group id (K_other)
+                tg = orows.gslot[li]
+                og = jnp.where(null_tail, K_other,
+                               o_gslot[jnp.clip(ri, 0, C - 1)])
+                if this_is_left:
+                    comp = tg * (Kr + 1) + og
+                else:
+                    comp = og * (Kr + 1) + tg
+                jrows = Rows(
+                    ts=orows.ts[li],
+                    kind=orows.kind[li],
+                    valid=all_valid,
+                    seq=orows.seq[li] * (C + 1) + ri,
+                    gslot=comp.astype(jnp.int32),
+                    cols=(),
+                )
+            with jax.named_scope("join_select"):
+                sel_state, out = sel.process(sel_state, jrows, sel_env)
+            with jax.named_scope("join_compact"):
+                # device-side compaction: the [N] grid (N = R*C(+R)) would cost
+                # N-row host fetches per send — megabytes of D2H for
+                # kilobytes of matches.  Stable valid-first argsort
+                # keeps delivery order; rows beyond the cap are counted as
+                # dropped and the runtime grows the cap (a planned recompile)
+                # when the cap was implicit.
+                o_ts, o_kind, o_valid, o_cols = out
+                N = o_ts.shape[0]
+                cap = min(N, emit_rows if emit_rows is not None
+                          else max(2 * R, 1024))
+                n_tot = jnp.sum(o_valid).astype(jnp.int32)
+                if cap < N:
+                    order = jnp.argsort(jnp.logical_not(o_valid),
+                                        stable=True)[:cap]
+                    o_ts, o_kind, o_valid = \
+                        o_ts[order], o_kind[order], o_valid[order]
+                    o_cols = tuple(c[order] for c in o_cols)
+                n_del = jnp.minimum(n_tot, jnp.int32(cap))
+                # header ships [n_valid, n_current] so count-only consumers
+                # (the common bench/monitoring shape) cost ZERO bulk fetches;
+                # n_expired derives as n_valid - n_current host-side
+                n_cur = jnp.sum(jnp.logical_and(
+                    o_valid, o_kind == ev.CURRENT)).astype(jnp.int32)
+                out = (jnp.stack([n_del, n_cur]), n_tot - n_del,
+                       o_ts, o_kind, o_valid, o_cols)
             nstate = ((this_state, other_state) if this_is_left
                       else (other_state, this_state))
             new_state = _constrain_state(
@@ -722,20 +732,21 @@ def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,
             other_table_cols, now = rest
         wl_state, wr_state, sel_state = state
         this_state = wl_state if is_left else wr_state
-        env0 = {side.key: cols, "__ts__": ts, "__now__": now}
-        keep = valid
-        is_cur = kind == ev.CURRENT
-        for f in side.pre_filters:
-            keep = jnp.logical_and(keep, jnp.logical_or(
-                jnp.logical_not(is_cur), f.fn(env0)))
-        in_cols = cols
-        if fp_mode == "bucket":
-            in_cols = cols + (probe,)
-        elif fp_mode == "table":
-            in_cols = cols + (jnp.arange(ts.shape[0], dtype=jnp.int32),)
-        rows = Rows(ts=ts, kind=kind, valid=keep, seq=jnp.zeros_like(ts),
-                    gslot=gslot, cols=in_cols)
-        this_state, wout = side.window.process(this_state, rows, now)
+        with jax.named_scope("join_window"):
+            env0 = {side.key: cols, "__ts__": ts, "__now__": now}
+            keep = valid
+            is_cur = kind == ev.CURRENT
+            for f in side.pre_filters:
+                keep = jnp.logical_and(keep, jnp.logical_or(
+                    jnp.logical_not(is_cur), f.fn(env0)))
+            in_cols = cols
+            if fp_mode == "bucket":
+                in_cols = cols + (probe,)
+            elif fp_mode == "table":
+                in_cols = cols + (jnp.arange(ts.shape[0], dtype=jnp.int32),)
+            rows = Rows(ts=ts, kind=kind, valid=keep, seq=jnp.zeros_like(ts),
+                        gslot=gslot, cols=in_cols)
+            this_state, wout = side.window.process(this_state, rows, now)
         out_empty = (
             jnp.zeros((1,), jnp.int64), jnp.zeros((1,), jnp.int32),
             jnp.zeros((1,), jnp.bool_), tuple())

@@ -1711,7 +1711,7 @@ class JoinQueryRuntime(_MeshResolved):
         self-join sees it on both sides — so fused-drain re-entries and
         deferred dispatches can never double-count the retention
         mirror.  Grows the planned lane width BEFORE the dispatch that
-        would overflow it."""
+        would overflow it; the span says both (`lane_k`, `lane_need`)."""
         cache = staged.jprobe
         if cache is None:
             cache = staged.jprobe = {}
@@ -1720,9 +1720,8 @@ class JoinQueryRuntime(_MeshResolved):
         if cached is not None:
             return cached
         from .join import _norm_key_cols
-        p = self.planned
-        st = self.app.stats
-        with _phases.phase(st, self.name, "route_keys"):
+        p, st = self.planned, self.app.stats
+        with _phases.phase(st, self.name, "route_keys") as sp:
             kvalid = staged.valid & (staged.kind == ev.CURRENT)
             pos = p.key_left if is_left else p.key_right
             slots = self._jk.track(
@@ -1731,6 +1730,7 @@ class JoinQueryRuntime(_MeshResolved):
             need = self._jk.needed_k()
             if need > p.lane_k:
                 self._grow_lane_k(need)
+            sp.set_metadata(lane_k=self.planned.lane_k, lane_need=need)
             out = np.where(kvalid, slots, -1).astype(np.int32)
         if _stateobs.obs_enabled(self.app):
             # lane demand is a running bucket-occupancy max the tracker
