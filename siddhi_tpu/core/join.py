@@ -107,6 +107,9 @@ class PlannedJoinQuery:
     table_is_left: bool = False
     table_pos: int = -1          # indexed table column
     stream_key_pos: int = -1     # stream-side key column
+    # the cap's order is taken BEFORE the pair rows are gathered and
+    # selected (make_step: `late_pairs`), so columns exist at the cap only
+    late_pairs: bool = False
 
     @staticmethod
     def _describe_side(s: "JoinSide") -> Dict:
@@ -134,6 +137,7 @@ class PlannedJoinQuery:
             "out_columns": list(self.out_schema.names),
             "emission_cap_rows": self.compact_rows,
             "emission_cap_explicit": bool(self.emit_explicit),
+            "pair_rows_materialised": "cap" if self.late_pairs else "all",
         }
         if self.slot_allocator is not None:
             d["group_slot_capacity"] = (
@@ -425,6 +429,17 @@ def plan_join_query(
     if sel.bank.pair_sources:
         raise CompileError(
             "distinctCount/unionSet in join queries lands in a later phase")
+    # late materialisation of the pair rows: the emission cap's order may
+    # move ahead of the selector only where the selector keeps nothing
+    # across rows and drops none — no aggregator (a row past the cap would
+    # still have to update a running value), no `having` (it decides which
+    # rows count against the cap), no `order by` / `limit` / `offset`
+    # (they rank over the whole chunk).  A projection is that.
+    qsel = query.selector
+    late_pairs = not (sel.has_aggregation or
+                      qsel.having_expression is not None or
+                      qsel.order_by_list or
+                      qsel.limit is not None or qsel.offset is not None)
 
     out_target = query.output_stream.target_id if query.output_stream else ""
     out_def = StreamDefinition(out_target or f"#{name}.out")
@@ -574,27 +589,48 @@ def plan_join_query(
 
             with jax.named_scope("join_pairs"):
                 # matched pair rows [R*Q] + unmatched rows [R] for outer
-                # joins; ri carries REAL buffer positions so seq/order match
-                # the grid path bit for bit
+                # joins: their flags are complete when the probe ends
                 Q = m.shape[1]
                 pair_valid = m.reshape(-1)
-                left_idx = jnp.repeat(jnp.arange(R), Q)
-                right_idx = ri2.astype(jnp.int32).reshape(-1)
                 unmatched = jnp.logical_and(data_row, jnp.logical_not(
                     jnp.any(m, axis=1)))
-                if emit_unmatched_this:
-                    all_valid = jnp.concatenate([pair_valid, unmatched])
-                    li = jnp.concatenate([left_idx, jnp.arange(R)])
-                    ri = jnp.concatenate(
-                        [right_idx, jnp.zeros((R,), jnp.int32)])
-                    null_tail = jnp.concatenate(
-                        [jnp.zeros((R * Q,), jnp.bool_), unmatched])
-                else:
-                    all_valid = pair_valid
-                    li, ri = left_idx, right_idx
-                    null_tail = jnp.zeros((R * Q,), jnp.bool_)
-
+                all_valid = jnp.concatenate([pair_valid, unmatched]) \
+                    if emit_unmatched_this else pair_valid
                 N = all_valid.shape[0]
+                cap = min(N, emit_rows if emit_rows is not None
+                          else max(2 * R, 1024))
+            order = None
+            if late_pairs and cap < N:
+                with jax.named_scope("join_compact"):
+                    # the cap's order FIRST: the selector keeps nothing
+                    # across rows and drops none, so the first `cap` valid
+                    # flat pair positions are the rows delivered — every
+                    # column below is gathered once, over `cap` rows
+                    order = jnp.argsort(jnp.logical_not(all_valid),
+                                        stable=True)[:cap]
+                    n_tot = jnp.sum(all_valid).astype(jnp.int32)
+            with jax.named_scope("join_pairs"):
+                # ri carries REAL buffer positions so seq/order match the
+                # grid path bit for bit
+                right_idx = ri2.astype(jnp.int32).reshape(-1)
+                if emit_unmatched_this:
+                    right_idx = jnp.concatenate(
+                        [right_idx, jnp.zeros((R,), jnp.int32)])
+                if order is None:
+                    # every candidate row, in flat pair position
+                    pos, ri = jnp.arange(N, dtype=jnp.int32), right_idx
+                    row_valid = all_valid
+                else:
+                    # the cap's rows alone; valid-first and stable, so the
+                    # first n_tot of them are the valid ones
+                    pos = order.astype(jnp.int32)
+                    ri = right_idx[pos]
+                    row_valid = jnp.arange(cap, dtype=jnp.int32) < n_tot
+                # the outer join's unmatched rows stand after the R*Q pairs
+                tail = pos >= R * Q
+                li = jnp.where(tail, pos - R * Q, pos // Q)
+                null_tail = jnp.logical_and(tail, row_valid)
+
                 this_cols = tuple(c[li] for c in t_cols)
                 # unmatched outer-join rows carry REAL nulls on the other side
                 # (reference: JoinProcessor.java:107-190 emits null attributes;
@@ -622,7 +658,7 @@ def plan_join_query(
                 jrows = Rows(
                     ts=orows.ts[li],
                     kind=orows.kind[li],
-                    valid=all_valid,
+                    valid=row_valid,
                     seq=orows.seq[li] * (C + 1) + ri,
                     gslot=comp.astype(jnp.int32),
                     cols=(),
@@ -630,23 +666,24 @@ def plan_join_query(
             with jax.named_scope("join_select"):
                 sel_state, out = sel.process(sel_state, jrows, sel_env)
             with jax.named_scope("join_compact"):
-                # device-side compaction: the [N] grid (N = R*C(+R)) would cost
-                # N-row host fetches per send — megabytes of D2H for
-                # kilobytes of matches.  Stable valid-first argsort
-                # keeps delivery order; rows beyond the cap are counted as
-                # dropped and the runtime grows the cap (a planned recompile)
-                # when the cap was implicit.
+                # device-side compaction: the host fetches `cap` slots, never
+                # the N = R*Q(+R) candidate pair rows.  Where the order went
+                # first (`late_pairs`) the rows already stand at the cap;
+                # elsewhere the selector ran over all N rows (its running
+                # values, `having`, `order by` / `limit` read every one) and
+                # the same stable valid-first argsort squeezes its output
+                # here.  Rows beyond the cap are counted as dropped and the
+                # runtime grows the cap (a planned recompile) when the cap
+                # was implicit.
                 o_ts, o_kind, o_valid, o_cols = out
-                N = o_ts.shape[0]
-                cap = min(N, emit_rows if emit_rows is not None
-                          else max(2 * R, 1024))
-                n_tot = jnp.sum(o_valid).astype(jnp.int32)
-                if cap < N:
-                    order = jnp.argsort(jnp.logical_not(o_valid),
-                                        stable=True)[:cap]
-                    o_ts, o_kind, o_valid = \
-                        o_ts[order], o_kind[order], o_valid[order]
-                    o_cols = tuple(c[order] for c in o_cols)
+                if order is None:
+                    n_tot = jnp.sum(o_valid).astype(jnp.int32)
+                    if cap < N:
+                        order = jnp.argsort(jnp.logical_not(o_valid),
+                                            stable=True)[:cap]
+                        o_ts, o_kind, o_valid = \
+                            o_ts[order], o_kind[order], o_valid[order]
+                        o_cols = tuple(c[order] for c in o_cols)
                 n_del = jnp.minimum(n_tot, jnp.int32(cap))
                 # header ships [n_valid, n_current] so count-only consumers
                 # (the common bench/monitoring shape) cost ZERO bulk fetches;
@@ -718,7 +755,7 @@ def plan_join_query(
         lane_k=lane_k, lane_buckets=lane_buckets, ring_caps=ring_caps,
         join_key_allocator=jk_alloc,
         table_is_left=table_is_left, table_pos=table_pos,
-        stream_key_pos=stream_key_pos)
+        stream_key_pos=stream_key_pos, late_pairs=late_pairs)
 
 
 def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,

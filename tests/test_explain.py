@@ -112,6 +112,8 @@ def test_explain_join_query(manager):
     assert rep["plan"]["left"]["window_processor"]
     assert rep["plan"]["emission_cap_rows"] is None  # per-trace default
     assert rep["plan"]["join_type"] == "JOIN"
+    # a projection: the cap's order goes first, columns exist at the cap
+    assert rep["plan"]["pair_rows_materialised"] == "cap"
 
 
 def test_explain_pattern_query(manager):
@@ -541,6 +543,9 @@ def test_explain_says_the_selector_layout(manager, case):
     plan = rt.explain("lq")["plan"]
     assert plan.get("selector_layout") == want
     assert plan == qr.planned.describe()
+    # a join with an aggregator keeps every candidate pair row
+    assert plan.get("pair_rows_materialised") == (
+        "all" if case.startswith("join_") else None)
     if want == "in_order":
         assert getattr(qr.planned, "slot_allocator", None) is None
         assert "group_slot_capacity" not in plan

@@ -464,6 +464,16 @@ def test_fastpath_facts_in_explain_and_audit(manager):
     assert node["lane_k"] >= 8 and not node["residual_predicate"]
     fp = query_fingerprint(rt, "q")
     assert fp["equi_fastpath"]["active"]
+    # `join_len128`'s text: a projection, so the pair rows exist at the
+    # emission cap only; a grouped join keeps every candidate row
+    assert rt.explain("q")["plan"]["pair_rows_materialised"] == "cap"
+    grouped = manager.create_siddhi_app_runtime(WINDOWED_JOIN_QL.replace(
+        "select L.symbol as s, L.price as p, R.qty as v",
+        "select L.symbol as s, sum(R.qty) as v group by L.symbol"))
+    grouped.start()
+    plan = grouped.explain("q")["plan"]
+    assert plan["pair_rows_materialised"] == "all"
+    assert plan["equi_fastpath"]["mode"] == "bucket"
 
 
 def test_fastpath_reason_for_named_window_side(manager):
