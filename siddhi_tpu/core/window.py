@@ -436,10 +436,10 @@ class LengthBatchWindow(WindowProcessor):
 
     @property
     def out_capacity(self):
-        # worst case: every arrival completes a batch of size 1
+        # EXPIRED slots prev + pending + arrivals, CURRENT pending + arrivals
         n = self.length
-        flushes = self.batch_capacity // n + 1
-        return 2 * self.batch_capacity + 2 * n + flushes
+        flushes = self.batch_capacity // n + 1       # one RESET slot each
+        return 2 * self.batch_capacity + 3 * n + flushes
 
     def init_state(self):
         # pending buffer (filling), previous batch buffer (for EXPIRED replay)
@@ -531,48 +531,34 @@ class LengthBatchWindow(WindowProcessor):
 
         with jax.named_scope("window_state"):
             # ---- new state ------------------------------------------------------
-            # pending' = arrivals with batch_idx == nflush (+ old pending if no flush)
-            np_old_valid = jnp.logical_and(pend.alive, nflush == 0)
-            np_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush)
-            cand_valid = jnp.concatenate([np_old_valid, np_arr_valid])
-            cand_rank_src = jnp.concatenate([pend_rank, pos_in_batch])
-            cand_ts = jnp.concatenate([pend.ts, rows.ts])
-            cand_gslot = jnp.concatenate([pend.gslot, rows.gslot])
-            cand_cols = tuple(jnp.concatenate([pc, rc])
-                              for pc, rc in zip(pend.cols, rows.cols))
-            # scatter into fresh pending by rank
-            npend = empty_buffer(self.schema, n)
-            tgt = jnp.where(cand_valid, cand_rank_src, n).astype(jnp.int32)
-            def scat(dst, src):
-                return dst.at[tgt].set(src, mode="drop")
-            npend = Buffer(
-                ts=scat(npend.ts, cand_ts),
-                add_seq=npend.add_seq,
-                expire_seq=npend.expire_seq,
-                expire_ts=npend.expire_ts,
-                alive=jnp.zeros((n,), jnp.bool_).at[tgt].set(cand_valid, mode="drop"),
-                gslot=scat(npend.gslot, cand_gslot),
-                cols=tuple(scat(c0, c) for c0, c in zip(npend.cols, cand_cols)),
-            )
+            # the rows a step keeps are one contiguous range of the global
+            # arrival index (a pending row's is its rank, the k-th CURRENT
+            # arrival's fill0 + k): prev' = batch nflush-1, pending' = what
+            # follows it.  So the two buffers are built by DESTINATION: each
+            # of their 2n slots looks its source row up in the candidates'
+            # running count, and every array is gathered once at 2n rows.
+            # The candidates are `cur_rows`' slots: concat(pending, arrivals)
+            cand_count = jnp.concatenate([pend_rank, g]) + 1
+            dest = (nflush - 1) * n + jnp.arange(2 * n, dtype=jnp.int64)
+            live = jnp.logical_and(dest >= 0, dest < fill0 + ncur)
+            src = jnp.searchsorted(cand_count, dest + 1, side="left")
+            empty = empty_buffer(self.schema, 2 * n)
 
-            # prev' = last flushed batch (batch nflush-1) if any flush else prev
-            lb_old_valid = jnp.logical_and(pend.alive, nflush == 1)
-            lb_arr_valid = jnp.logical_and(is_cur, batch_idx == nflush - 1)
-            lbc_valid = jnp.concatenate([lb_old_valid, lb_arr_valid])
-            nprev0 = empty_buffer(self.schema, n)
-            tgt2 = jnp.where(lbc_valid, cand_rank_src, n).astype(jnp.int32)
-            def scat2(dst, src):
-                return dst.at[tgt2].set(src, mode="drop")
-            flushed_prev = Buffer(
-                ts=scat2(nprev0.ts, cand_ts),
-                add_seq=nprev0.add_seq, expire_seq=nprev0.expire_seq,
-                expire_ts=nprev0.expire_ts,
-                alive=jnp.zeros((n,), jnp.bool_).at[tgt2].set(lbc_valid, mode="drop"),
-                gslot=scat2(nprev0.gslot, cand_gslot),
-                cols=tuple(scat2(c0, c) for c0, c in zip(nprev0.cols, cand_cols)),
+            def kept(cand, filler):
+                return jnp.where(live, cand[src], filler)
+            both = Buffer(
+                ts=kept(cur_rows.ts, empty.ts),
+                add_seq=empty.add_seq, expire_seq=empty.expire_seq,
+                expire_ts=empty.expire_ts,
+                alive=live,
+                gslot=kept(cur_rows.gslot, empty.gslot),
+                cols=tuple(kept(c, c0)
+                           for c, c0 in zip(cur_rows.cols, empty.cols)),
             )
+            npend = jax.tree.map(lambda x: x[n:], both)
+            # prev' = last flushed batch (batch nflush-1) if any flush else prev
             nprev = jax.tree.map(
-                lambda new, old: jnp.where(nflush > 0, new, old), flushed_prev, prev)
+                lambda new, old: jnp.where(nflush > 0, new[:n], old), both, prev)
 
             nseq = seq0 + nflush * span
         return ((npend, nprev, nseq),

@@ -269,11 +269,12 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # among them — are in the compile-cache key
 # (`jax_compilation_cache_include_metadata_in_key`): a digest that moves is
 # a one-time compile-cache miss in that configuration's cells.  A PR that
-# moves a traced line on purpose pays it, says so, and re-pins.
+# moves a traced line on purpose pays it, says so, and re-pins: PR 44 did,
+# for `lengthbatch_1000` (`LengthBatchWindow.process`' kept buffers).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "b0a59877bac491ad1740eb1026d449167bc24d6bf525ee1575579a0dde284434"},
+        "45b8f3ac6334d8d3630449e75ed1c30ff68d4b4247f3950687f08c2eb601c189"},
     "pattern_1m": {
         "dense_step[TradeStream]":
         "5e8a882d9528ba622c2f1d3d25cdcacbf698c2e14ffa4b9aaf22862cb25057a3",
