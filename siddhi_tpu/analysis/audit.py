@@ -132,16 +132,15 @@ def query_fingerprint(rt, qname: str, typeflow_summary: Optional[Dict]
     """One query's plan fingerprint from a live (never-run) runtime."""
     from ..core.plan_facts import render_cap
     from ..core import fusion as _fusion
-    from ..observability.explain import _runtime_kind, _steps_of, \
-        step_cost
+    from ..observability.explain import _steps_of, step_cost
     from ..observability.memory import query_component_bytes
     from .signatures import primary_roles, synthesize
 
     qr = rt.query_runtimes[qname]
-    kind = _runtime_kind(qr)
+    kind = qr._kind
     synth = synthesize(qr, kind)
     cache = rt.__dict__.setdefault("_explain_cost_cache", {})
-    mesh = getattr(qr, "mesh", None) or getattr(qr, "keyed_mesh", None)
+    mesh = qr.mesh or qr.keyed_mesh
     want_coll = collectives or mesh is not None
     steps: Dict[str, Dict] = {}
     for role, fn in _steps_of(qr, kind):
@@ -186,8 +185,8 @@ def query_fingerprint(rt, qname: str, typeflow_summary: Optional[Dict]
         "state": {"components": dict(comp),
                   "total_bytes": sum(comp.values())},
         "emission": {
-            "cap_rows": render_cap(getattr(p, "compact_rows", None)),
-            "cap_explicit": bool(getattr(p, "emit_explicit", False)),
+            "cap_rows": render_cap(p.compact_rows),
+            "cap_explicit": bool(p.emit_explicit),
         },
         "fusion": _fusion.eligibility(qr, kind),
         "merge": _merge_fact(qr),

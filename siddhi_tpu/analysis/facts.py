@@ -271,8 +271,8 @@ def facts_from_runtime(rt) -> List[QueryFacts]:
     static_by_name = {f.name: f for f in facts_from_app(rt.app)}
     out: List[QueryFacts] = []
     for name, qr in sorted(rt.query_runtimes.items()):
-        q = getattr(qr, "_query_ast", None)
-        kind = getattr(qr, "_kind", None) or "plain"
+        q = qr._query_ast
+        kind = qr._kind
         p = qr.planned
         try:
             desc = p.describe()
@@ -280,26 +280,25 @@ def facts_from_runtime(rt) -> List[QueryFacts]:
             desc = {}
         comp = query_component_bytes(qr)
         sf = static_by_name.get(name)
-        fb = getattr(qr, "_fuse", None)
+        fb = qr._fuse
         f = QueryFacts(
             name=name,
             query=q if q is not None else Query(),
             kind=kind, origin="planned",
             partition=sf.partition if sf is not None else None,
-            needs_timer=bool(desc.get("needs_timer",
-                                      getattr(p, "needs_timer", False))),
-            keyed_window=bool(getattr(p, "keyed_window", False)),
+            needs_timer=bool(desc.get("needs_timer", p.needs_timer)),
+            keyed_window=bool(p.keyed_window),
             fuse_requested=(fb.k if fb is not None
-                            else getattr(qr, "_fuse_requested", 0)),
+                            else qr._fuse_requested),
             fusion_exclusion=fusion_exclusion(qr),
-            emission_cap=render_cap(getattr(p, "compact_rows", None)),
-            emission_cap_explicit=bool(getattr(p, "emit_explicit",
-                                               False)),
+            emission_cap=render_cap(p.compact_rows),
+            emission_cap_explicit=bool(p.emit_explicit),
             state_bytes=sum(comp.values()) if comp else None,
             state_components=dict(comp) if comp else None,
             state_bytes_origin="measured",
-            key_capacity=int(getattr(p, "key_capacity", 0) or 1),
-            nfa_slots=int(getattr(p, "slots", _NFA_SLOTS) or _NFA_SLOTS),
+            key_capacity=int(p.key_capacity or 1),
+            nfa_slots=int(p.slots or _NFA_SLOTS) if kind == "pattern"
+            else _NFA_SLOTS,
         )
         if sf is not None and sf.join_side_rows is not None:
             f.join_side_rows = sf.join_side_rows

@@ -849,8 +849,8 @@ def _serve_blocking_sink(ctx: AnalysisContext) -> Iterator[Finding]:
         # serving? live runtime wins (serving.enabled config can turn
         # the app on wholesale); statically only annotations decide
         if rt is not None:
-            qr = getattr(rt, "query_runtimes", {}).get(f.name)
-            serving = bool(getattr(qr, "serve_emit", False))
+            qr = rt.query_runtimes.get(f.name)
+            serving = qr is not None and qr.serve_emit
         else:
             try:
                 serving = bool(serve_enabled(app, q))

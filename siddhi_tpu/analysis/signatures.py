@@ -111,8 +111,8 @@ def _pattern_specs(qr) -> Dict[str, Tuple]:
     pstate, sel_state = (_tree_specs(qr.state[0]),
                          _tree_specs(qr.state[1]))
     now = _sds((), np.int64)
-    in_tabs = _table_specs(qr.app, getattr(p.exec, "in_deps", None) or ())
-    sharded = getattr(p, "mesh", None) is not None
+    in_tabs = _table_specs(qr.app, p.exec.in_deps)
+    sharded = p.mesh is not None
     if p.partition_positions or sharded:
         G, E = _canonical_grouping(p.key_capacity, B)
     else:
@@ -147,15 +147,15 @@ def _join_side_other(qr, is_left: bool) -> Optional[Tuple]:
     p = qr.planned
     other = p.right if is_left else p.left
     app = qr.app
-    if getattr(other, "is_aggregation", False):
+    if other.is_aggregation:
         return None                 # aggregation view: duration-dependent
-    if getattr(other, "is_named_window", False):
+    if other.is_named_window:
         nw = app.named_windows[other.stream_id]
         buf = nw.wproc.current_buffer(nw.state)
         return (tuple(_sds(c.shape, c.dtype) for c in buf.cols),
                 _sds(buf.ts.shape, buf.ts.dtype),
                 _sds(buf.alive.shape, buf.alive.dtype))
-    if getattr(other, "is_table", False):
+    if other.is_table:
         t = app.tables[other.stream_id]
         return (tuple(_sds(c.shape, c.dtype) for c in t.cols),
                 _sds(t.ts.shape, t.ts.dtype),
@@ -186,9 +186,9 @@ def _join_specs(qr) -> Dict[str, Tuple]:
         # equi-join fast-path probe arg (core/join.py): bucket slots or
         # host table candidates ride between gslot and the other-side
         # snapshot
-        if getattr(p, "fastpath", None) == "bucket":
+        if p.fastpath == "bucket":
             args.append(_sds((B,), np.int32))
-        elif getattr(p, "fastpath", None) == "table":
+        elif p.fastpath == "table":
             tid = (p.left if p.table_is_left else p.right).stream_id
             t = qr.app.tables[tid]
             w = (t.indexes[p.table_pos].lanes.shape[1]

@@ -70,10 +70,10 @@ def ineligible_reason(qr, kind: str):
     if kind == "pattern":
         if p.timer_step is not None:
             return "absent pattern needs timer wakeups — wake cannot lag"
-        if getattr(p, "mesh", None) is not None:
+        if p.mesh is not None:
             # sharded partitioned patterns fuse through the shard_map'd
             # scan step (pattern_planner._shard_fused_step)
-            if getattr(p, "shard_fused_steps", None):
+            if p.shard_fused_steps:
                 return None
             return "sharded pattern step has no fusable body"
         if p.partition_positions:
@@ -100,11 +100,11 @@ def eligibility(qr, kind: str) -> Dict:
     node: Dict = {"eligible": reason is None}
     if reason is not None:
         node["exclusion_reason"] = reason
-    fb = getattr(qr, "_fuse", None)
+    fb = qr._fuse
     node["active"] = fb is not None
     if fb is not None:
         node["batches"] = fb.k
-    elif getattr(qr, "_fuse_requested", 0):
+    elif qr._fuse_requested:
         node["requested_batches"] = qr._fuse_requested
     return node
 
@@ -137,11 +137,11 @@ class FuseBuffer:
         """Accept a send into the stack.  Returns False when the caller
         must run the sequential path itself (drain re-entry, or an
         attached debugger that expects per-batch breakpoints)."""
-        if self.bypass or self.qr.app.__dict__.get("_debugger") is not None:
+        if self.bypass or self.qr.app._debugger is not None:
             return False
         # captured before a signature-change drain(), which resets the
         # runtime's stash while re-processing the OLD stack
-        t_in = self.qr.__dict__.get("_ingest_ns")
+        t_in = self.qr._ingest_ns
         sig = (tag, staged.ts.shape[0])
         if self.items and sig != self.sig:
             self.drain()
@@ -165,23 +165,23 @@ class FuseBuffer:
         self.bypass = True
         try:
             for args, t_in in zip(items, ingests):
-                qr.__dict__["_ingest_ns"] = t_in
+                qr._ingest_ns = t_in
                 qr.process_staged(*args)
                 # consume the inline-delivery flag HERE (a drain may run
                 # from flush()/quiesce with no junction dispatch around
                 # it to close e2e) — stack wait is inside the sample
-                if qr.__dict__.pop("_e2e_owed", False) and \
-                        t_in is not None and qr.app.stats.enabled:
+                owed, qr._e2e_owed = qr._e2e_owed, False
+                if owed and t_in is not None and qr.app.stats.enabled:
                     qr.app.stats.e2e_latency(
                         qr.name, time.perf_counter_ns() - t_in)
         finally:
             self.bypass = False
-            qr.__dict__["_ingest_ns"] = None
+            qr._ingest_ns = None
 
     def dispatch(self) -> None:
         """Run the full stack as ONE fused device dispatch."""
         items, self.items = self.items, []
-        self.qr.__dict__["_fused_ingests"], self.ingests = self.ingests, []
+        self.qr._fused_ingests, self.ingests = self.ingests, []
         qr = self.qr
         stats = qr.app.stats
         k = len(items)
@@ -195,7 +195,7 @@ class FuseBuffer:
 
 def pending(qr) -> int:
     """Batches held in a runtime's fuse stack (0 for unfused runtimes)."""
-    fb = getattr(qr, "_fuse", None)
+    fb = qr._fuse
     return len(fb.items) if fb is not None else 0
 
 
@@ -203,14 +203,10 @@ def drain(qr) -> None:
     """Flush a runtime's partial stack (lifecycle: flush/quiesce/
     shutdown).  Takes the query lock — the producer's offer path runs
     under it too, so a concurrent send can never double-process."""
-    fb = getattr(qr, "_fuse", None)
+    fb = qr._fuse
     if fb is None or not fb.items:
         return
-    lk = getattr(qr, "_qlock", None)
-    if lk is None:
-        fb.drain()
-        return
-    with lk:
+    with qr._qlock:
         fb.drain()
 
 
@@ -221,7 +217,7 @@ def drain(qr) -> None:
 # ---------------------------------------------------------------------------
 
 def _fused_fn(qr, kind: str, body: Callable) -> Callable:
-    cache: Dict = qr.__dict__.setdefault("_fused_cache", {})
+    cache: Dict = qr._fused_cache
     key = (kind, id(body))
     ent = cache.get(key)
     if ent is not None and ent[0] is body:
@@ -348,7 +344,7 @@ def _prepare_pattern(qr, items) -> Tuple[Callable, Tuple, Tuple]:
 
 
 def _dispatch_pattern(qr, items) -> None:
-    if getattr(qr.planned, "mesh", None) is not None:
+    if qr.planned.mesh is not None:
         return _dispatch_pattern_sharded(qr, items)
     fn, xs, const = _prepare_pattern(qr, items)
     qr.state, outs = _phases.dispatch(qr, fn, qr.state, xs, const,
@@ -482,16 +478,12 @@ def _dispatch_merged(qr, items) -> None:
         stats.counter_inc(f"merged.{qr.group}.dispatches")
         stats.counter_inc(f"merged.{qr.group}.member_batches",
                           len(qr.members) * len(items))
-    ingests = qr.__dict__.pop("_fused_ingests", None)
+    ingests, qr._fused_ingests = qr._fused_ingests, None
     if ingests is None or len(ingests) != K:
         ingests = [None] * K
     consumers = [i for i, m in enumerate(qr.members)
                  if _rt._has_consumers(m)]
-    deferred = (getattr(qr.members[0], "async_emit", False) and
-                qr.app._drainer is not None) or \
-        bool(getattr(qr.members[0], "pipeline_emit", 0) or 0) or \
-        getattr(qr.members[0], "serve_emit", False)
-    if consumers and not deferred:
+    if consumers and not qr.members[0].defers_delivery():
         # ONE fetch for every consumed member's whole [K, ...] block;
         # per-batch views below are then numpy slices
         host = _phases.fetch(stats, gname, "rows",
@@ -532,22 +524,19 @@ def _deliver_fused(qr, outs, nows: List[int]) -> None:
     error) defers until every batch has been delivered, then the first
     error propagates to the junction's fault routing."""
     from . import runtime as _rt
-    ingests = qr.__dict__.pop("_fused_ingests", None)
+    ingests, qr._fused_ingests = qr._fused_ingests, None
     if not _rt._has_consumers(qr):
         return
     K = len(nows)
     if ingests is None or len(ingests) != K:
         ingests = [None] * K
-    if getattr(qr, "serve_emit", False) \
-            or getattr(qr, "async_emit", False) and \
-            qr.app._drainer is not None \
-            or getattr(qr, "pipeline_emit", 0):
+    if qr.defers_delivery():
         for i in range(K):
             # per-batch stamp restored so _emit_output's deferred queues
             # (drainer / @pipeline deque) carry the right e2e origin
-            qr.__dict__["_ingest_ns"] = ingests[i]
+            qr._ingest_ns = ingests[i]
             _rt._emit_output(qr, _slice_out(outs, i), nows[i], wake=None)
-        qr.__dict__["_ingest_ns"] = None
+        qr._ingest_ns = None
         return
     first_exc = None
     _st = qr.app.stats
@@ -559,7 +548,7 @@ def _deliver_fused(qr, outs, nows: List[int]) -> None:
         # _emit_output_sync_impl's own test: a junction nobody reads
         # must not force a bulk fetch
         need_rows = bool(qr.callbacks) or _rt._target_live(qr) or \
-            getattr(qr.planned, "emits_uuid", False)
+            qr.planned.emits_uuid
         bulk = _phases.fetch(_st, qr.name, "rows", outs[2:]) \
             if need_rows else outs[2:]
         for i in range(K):

@@ -110,6 +110,16 @@ class PlannedJoinQuery:
     # the cap's order is taken BEFORE the pair rows are gathered and
     # selected (make_step: `late_pairs`), so columns exist at the cap only
     late_pairs: bool = False
+    # what shared code (emission, the purger, snapshots, the observatory,
+    # lint) reads off ANY plan and only a plain or a pattern plan sets: a
+    # join keeps no key axis (GSPMD row sharding under the app's mesh,
+    # no key layout), no keyed-window slab, no distinctCount pairs
+    mesh: Any = None
+    keyed_mesh: Any = None
+    keyed_window: bool = False
+    key_capacity: int = 0
+    window_key_allocator: Optional[Any] = None
+    pair_allocs: Tuple = ()
 
     @staticmethod
     def _describe_side(s: "JoinSide") -> Dict:

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -283,6 +283,21 @@ class PlannedPatternQuery:
     # mesh path's @fuse entry: one shard_map dispatch scanning K stacked
     # batches per device (fusion._dispatch_pattern_sharded); None off-mesh
     shard_fused_steps: Optional[Dict[str, Callable]] = None
+    # what shared code (emission, the purger, snapshots, the observatory,
+    # lint) reads off ANY plan and only a plain or a join plan sets: a
+    # pattern ticks through `timer_step` and its wake rides the emission
+    # (no `needs_timer` window), keeps no keyed-window slab, and its one
+    # key allocator is the runtime's (shared per partition), not the
+    # plan's
+    needs_timer: bool = False
+    keyed_window: bool = False
+    keyed_mesh: Any = None
+    mixed_kinds: bool = False
+    slot_allocator: Any = None
+    slot_allocator2: Any = None
+    join_key_allocator: Any = None
+    window_key_allocator: Any = None
+    pair_allocs: Tuple = ()
 
     # the compact_rows default means "effectively uncapped" for
     # non-partitioned patterns (a per-key cap with K=1 would cap the

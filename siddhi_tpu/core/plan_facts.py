@@ -44,19 +44,17 @@ def fusion_exclusion(qr) -> Optional[str]:
     """The concrete reason @fuse was requested but skipped for this query
     runtime, or None (fusing, eligible, or never requested).
 
-    Prefers the reason stored at wiring time (runtime._maybe_fuse) and
+    Prefers the reason stored at wiring time (runtime `_register`) and
     falls back to recomputing from the plan's static properties, so a
     runtime restored from a snapshot still reports it.  Attribute reads
     only — safe on the scrape path."""
-    why = getattr(qr, "_fuse_excluded", None)
+    why = qr._fuse_excluded
     if why is not None:
         return why
-    if getattr(qr, "_fuse_requested", 0) and \
-            getattr(qr, "_fuse", None) is None:
+    if qr._fuse_requested and qr._fuse is None:
         from . import fusion
         try:
-            return fusion.ineligible_reason(
-                qr, getattr(qr, "_kind", "plain"))
+            return fusion.ineligible_reason(qr, qr._kind)
         except Exception:  # noqa: BLE001 — diagnostics must not throw
             return "unknown (plan facts unavailable)"
     return None
@@ -557,7 +555,7 @@ def handler_fingerprints(sis) -> Tuple[Tuple[str, ...], str,
 
 def async_enabled(app, q) -> bool:
     """@async on the app, the query, or any input stream definition —
-    the ONE implementation runtime wiring (`_async_enabled`) and the
+    the ONE implementation runtime wiring (`_register`) and the
     merge planner share."""
     if app.get_annotation("async") is not None:
         return True
@@ -575,7 +573,7 @@ def async_enabled(app, q) -> bool:
 
 def pipeline_depth(app, q) -> int:
     """@pipeline(depth=k) on the query (wins) or @app:pipeline; 0 = off
-    (shared by runtime `_pipeline_enabled` and the merge planner)."""
+    (shared by runtime `_register` and the merge planner)."""
     ann = q.get_annotation("pipeline")
     if ann is None:
         ann = app.get_annotation("app:pipeline")
@@ -586,7 +584,7 @@ def pipeline_depth(app, q) -> int:
 
 def fuse_depth(app, q) -> int:
     """@fuse(batches=K) on the query, any input stream definition, or
-    @app:fuse; 0 = off (shared by runtime `_fuse_enabled`, lint's
+    @app:fuse; 0 = off (shared by runtime `_register`, lint's
     `fuse_requested`, and the merge planner)."""
     ann = q.get_annotation("fuse")
     if ann is None:
@@ -883,7 +881,7 @@ def merge_facts(qr) -> Dict:
     "group_dispatch_programs": 1}`` for a merged member;
     ``{"merged": False, "reason": ...}`` otherwise.  Attribute reads
     only — safe on diagnostic paths."""
-    mg = getattr(qr, "_merged", None)
+    mg = qr._merged
     if mg is not None:
         return {
             "merged": True,
@@ -893,7 +891,7 @@ def merge_facts(qr) -> Dict:
             "members": [m.name for m in mg.members],
             "group_dispatch_programs": 1,
         }
-    why = getattr(qr, "_merge_excluded", None)
+    why = qr._merge_excluded
     if why is not None:
         return {"merged": False, "reason": why}
     return {"merged": False}

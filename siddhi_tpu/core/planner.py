@@ -83,6 +83,15 @@ class PlannedQuery:
     # member).  raw_step == stage_body ∘ select_body by construction.
     stage_body: Optional[Callable] = None
     select_body: Optional[Callable] = None
+    # what shared code (emission, snapshots, the observatory, lint) reads
+    # off ANY plan and only a pattern or a join plan sets: no emission
+    # cap (a plain emission ships whole), no second-side / join-key
+    # allocator, CURRENT-only counts in a header
+    compact_rows: Optional[int] = None
+    emit_explicit: bool = False
+    mixed_kinds: bool = False
+    slot_allocator2: Optional[SlotAllocator] = None
+    join_key_allocator: Optional[SlotAllocator] = None
 
     def describe(self) -> Dict:
         """Compiled-plan facts for EXPLAIN (observability/explain.py):
@@ -417,7 +426,7 @@ def plan_single_query(
     keyed_window = bool(
         (partition_positions or partition_key_fn) and seen_window)
     window_key_positions = list(partition_positions or [])
-    skey_pos = getattr(window_proc, "session_key_pos", None)
+    skey_pos = window_proc.session_key_pos
     if skey_pos is not None:
         # session(gap, key): standalone keyed window — the session key
         # scopes the window slab exactly like a partition key would
@@ -608,7 +617,7 @@ def plan_single_query(
             and K % mesh.devices.size == 0 and not pair_allocs
             and not sel._order_by and query.selector.limit is None
             and query.selector.offset is None
-            and not getattr(wproc, "host_scheduled", False)
+            and not wproc.host_scheduled
             # RESET-emitting batch windows reset ALL selector slots on any
             # device that sees the flush — multiple writers per slot break
             # the replicated-state delta merge; they stay single-device

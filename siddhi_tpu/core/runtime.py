@@ -56,12 +56,6 @@ _trace_log = logging.getLogger("siddhi_tpu.trace")
 _NULL_CM = contextlib.nullcontext()
 
 
-def _sub_name(sub, default: str) -> str:
-    """Metric name of a junction subscriber (wrappers hold the runtime in
-    _qr; plain runtimes carry .name)."""
-    return getattr(getattr(sub, "_qr", sub), "name", default)
-
-
 def _staged_nbytes(staged) -> int:
     """Bytes `staged.to_device` will upload: nothing when the serving
     stager already did at the accept edge."""
@@ -89,13 +83,6 @@ class QueryCallback:
     def receive(self, timestamp: int, in_events: Optional[List[ev.Event]],
                 out_events: Optional[List[ev.Event]]) -> None:
         raise NotImplementedError
-
-
-def _sub_lock(sub):
-    """Per-query processing lock of a junction subscriber (wrappers hold
-    the runtime in _qr; aggregations lock internally -> None)."""
-    target = getattr(sub, "_qr", None) or sub
-    return getattr(target, "_qlock", None)
 
 
 @contextlib.contextmanager
@@ -178,9 +165,9 @@ def _allocator_of(qr):
     directly, planned single queries on the plan).  Explicit None checks:
     an EMPTY allocator is len()==0 and must still be returned (a fresh
     runtime restoring a snapshot hits exactly that state)."""
-    a = getattr(qr, "slot_allocator", None)
+    a = qr.slot_allocator
     if a is None:
-        a = getattr(qr.planned, "slot_allocator", None)
+        a = qr.planned.slot_allocator
     return a
 
 
@@ -286,7 +273,7 @@ class InputHandler:
     def _admitted(self, n: int) -> bool:
         if not self._admit:
             return True
-        adm = getattr(self._runtime, "admission", None)
+        adm = self._runtime.admission
         if adm is None or not adm.ingest_enabled:
             return True
         return adm.admit_ingest(self.stream_id, n)
@@ -341,47 +328,29 @@ class InputHandler:
             self._runtime._route_columns(self.stream_id, cols, timestamps)
 
 
-class _MeshResolved:
-    """Resolved mesh/router accessors shared by every query-runtime
-    wrapper: the ONE way host code asks "is this query sharded, and how".
-    sharding/router.py owns the layout; the former scattered
-    `getattr(.., "mesh"/"keyed_mesh", None)` call sites (purger resets,
-    staging grouping, snapshot layout, fusion eligibility) all route
-    through these."""
+class _QueryRuntimeBase:
+    """What "a query runtime" is to every function that is handed one —
+    the emission chain (`_emit_output` down), the junction's dispatch,
+    @fuse / @serve / merge wiring, the purger, snapshots, EXPLAIN, the
+    observatory.  `__init__` declares, with the value that means "not
+    wired", every field that code outside the class reads or writes: a
+    renamed field fails at its read, it does not come back as a default.
+    The plain, pattern and join runtimes supply `process_staged`,
+    `on_timer` and their state; a merge group (optimizer/mqo.py) and a
+    named window run no planned query of their own (`planned` is None —
+    nothing that reads a plan is handed one) and take the base for the
+    lock, the wake and the deferred-delivery / @fuse fields."""
 
-    @property
-    def mesh(self):
-        return _sharding.mesh_of(self)
+    # which rule set of fusion / EXPLAIN applies: 'plain' | 'pattern' |
+    # 'join' | 'merged'; a fact of the class, not of the wiring
+    _kind: Optional[str] = None
+    # does the step read `staged.to_device`?  Then the @serve accept-edge
+    # stager may upload the batch for it (StreamJunction._serve_stage)
+    adopts_staged = True
 
-    @property
-    def keyed_mesh(self):
-        return _sharding.keyed_mesh_of(self)
-
-    @property
-    def shard_router(self):
-        # memoized in a 1-tuple so a resolved None doesn't re-resolve
-        # per batch (replans never change mesh/capacity, so no staleness)
-        r = self.__dict__.get("_shard_router_memo")
-        if r is None:
-            r = self.__dict__["_shard_router_memo"] = \
-                (_sharding.router_for(self),)
-        return r[0]
-
-
-class QueryRuntime(_MeshResolved):
-    """Host wrapper around one planned query: staging, group slots, routing."""
-
-    def __init__(self, planned: PlannedQuery, app: "SiddhiAppRuntime"):
+    def __init__(self, planned, app: "SiddhiAppRuntime"):
         self.planned = planned
         self.app = app
-        # set by optimizer.apply_merge when this query joins a merge
-        # group: state then lives in the group's stacked pytree and the
-        # `state` property serves this member's view of it
-        self._merged = None
-        # force-copy every leaf: constant-folding can alias identical init
-        # arrays into one buffer, which breaks donated-argument execution
-        self._state = jax.tree.map(
-            lambda x: jax.numpy.array(x, copy=True), planned.init_state())
         self.callbacks: List[Callable] = []
         self.batch_callbacks: List[Callable] = []
         self.next_wakeup: int = _NO_WAKEUP_INT
@@ -389,15 +358,170 @@ class QueryRuntime(_MeshResolved):
         # QUERY, not per app (reference: per-query ReentrantLock chosen in
         # QueryParser.java:159-215 instead of one engine-wide lock)
         self._qlock = threading.RLock()
+        # -- set at wiring (SiddhiAppRuntime._register / _wire_output) --
+        # the Query this runtime was planned from (EXPLAIN, lint)
+        self._query_ast = None
+        # delivery mode (_emit_output): @async -> the app's drainer
+        # thread; @pipeline(depth) -> held on the producer's thread;
+        # @serve -> a device ring of `serve_ring_capacity` slots (0 = the
+        # config's)
+        self.async_emit = False
+        self.pipeline_emit = 0
+        self.serve_emit = False
+        self.serve_ring_capacity = 0
+        # @fuse(batches=K): the stack buffer (core/fusion.py) or None,
+        # the K asked for, and why wiring skipped it
+        self._fuse = None
+        self._fuse_requested = 0
+        self._fuse_excluded = None
+        # fn(new cap) -> the plan re-planned with a larger emission cap
+        # (adaptive overflow growth), or None
+        self._replan = None
+        # (op, table, cond, set_fns, key) of an `insert into / update /
+        # delete <table>` output; the `output ... every` limiter
+        self.table_op = None
+        self.rate_limiter = None
+        # optimizer.apply_merge: the group this query dispatches through,
+        # or why it stayed alone
+        self._merged = None
+        self._merge_excluded = None
         # set by _PartitionPurger: fn(slots, now) recording key liveness
         self._touch = None
         self._touch_group = None
-        # @fuse(batches=K): stack buffer for scan-fused dispatch, or None
-        self._fuse = None
+        # a partitioned pattern's shared key allocator and its per-key
+        # dirty mask since the last (incremental) snapshot; a bucket
+        # join's key retention mirror (core/join.py JoinKeyTracker)
+        self.slot_allocator = None
+        self._dirty = None
+        self._jk = None
+        # -- written per send / per delivery --
+        # perf_counter_ns at send acceptance, stamped by the dispatcher
+        # under the query lock; whether an inline delivery left the
+        # `<query>:e2e` sample for the dispatcher to close
+        self._ingest_ns = None
+        self._e2e_owed = False
+        # @pipeline's held emissions (a deque), @serve's EmissionRing
+        self._pending_emit = None
+        self._serve_ring = None
+        # a fused dispatch's per-batch ingest stamps; (kind, id(body)) ->
+        # (body, fused fn)
+        self._fused_ingests = None
+        self._fused_cache: Dict = {}
+        # memos: wire bytes of one output row (_row_nbytes); the resolved
+        # ShardRouter in a 1-tuple, so a resolved None is not re-resolved
+        # (replans never change mesh / capacity)
+        self._out_row_nbytes = None
+        self._shard_router_memo = None
+        # the observatory's sampled window-fill probe
+        # (observability/stateobs.py arm_fill_probe)
+        self._stateobs_tick = 0
+        self._stateobs_probe = None
+        self._stateobs_probe_caps = None
+        self._stateobs_probe_off = False
 
     @property
     def name(self):
         return self.planned.name
+
+    # the ONE way host code asks "is this query sharded, and how"
+    # (sharding/router.py owns the layout)
+    @property
+    def mesh(self):
+        return self.planned.mesh
+
+    @property
+    def keyed_mesh(self):
+        return self.planned.keyed_mesh
+
+    @property
+    def shard_router(self):
+        r = self._shard_router_memo
+        if r is None:
+            r = self._shard_router_memo = (_sharding.router_for(self),)
+        return r[0]
+
+    def defers_delivery(self) -> bool:
+        """Does `_emit_output` hand this runtime's emissions on unfetched
+        (@serve ring, @async drainer, @pipeline deque)?  A stacked
+        dispatch (fusion, merge) then gives it device slices."""
+        return bool(self.serve_emit or self.pipeline_emit or
+                    (self.async_emit and self.app._drainer is not None))
+
+    @staticmethod
+    def _timer_batch(schema: ev.Schema, now: int) -> ev.StagedBatch:
+        """The batch a timer tick sends through a step: one TIMER row."""
+        staged = ev.pack_np(schema, [], capacity=8)
+        staged.ts[0] = now
+        staged.kind[0] = ev.TIMER
+        staged.valid[0] = True
+        return staged
+
+    def _apply_wake(self, w: int) -> None:
+        self.next_wakeup = w
+        if w < _NO_WAKEUP_INT:
+            self.app._scheduler.notify_at(w, self)
+
+    def _emit(self, out, now: int, wake=None) -> None:
+        _emit_output(self, out, now, wake)
+
+    # restore hooks (SiddhiAppRuntime.restore / restore_increment): where
+    # a restored state goes, and what host mirror is rebuilt from it
+    def place_state(self, state):
+        return state
+
+    def _after_restore(self, host_state) -> None:
+        pass
+
+
+class _Subscription:
+    """A runtime as a junction (or named window) subscribes it: the
+    target and the leading arguments of its `process_staged` — a
+    pattern's stream id, a join's side, none for an aggregation.
+    `locks=False`: the target locks internally and is no query runtime
+    (an aggregation) — the dispatcher takes no query lock for it, stamps
+    nothing on it and names it after the stream."""
+
+    __slots__ = ("_qr", "_lead", "locks")
+
+    def __init__(self, qr, *lead, locks: bool = True):
+        self._qr, self._lead, self.locks = qr, lead, locks
+
+    def process_staged(self, staged: ev.StagedBatch, now: int) -> None:
+        self._qr.process_staged(*self._lead, staged, now)
+
+
+def _sub_runtime(sub):
+    """The query runtime behind a junction subscriber — itself, or the
+    one its adapter binds — or None (an aggregation's adapter)."""
+    if isinstance(sub, _Subscription):
+        return sub._qr if sub.locks else None
+    return sub
+
+
+def _sub_name(sub, default: str) -> str:
+    """Metric name of a junction subscriber: its query runtime's."""
+    qr = _sub_runtime(sub)
+    return default if qr is None else qr.name
+
+
+def _sub_lock(sub):
+    """Per-query processing lock of a junction subscriber (aggregations
+    lock internally -> None)."""
+    qr = _sub_runtime(sub)
+    return None if qr is None else qr._qlock
+
+
+class QueryRuntime(_QueryRuntimeBase):
+    """Host wrapper around one planned query: staging, group slots, routing."""
+
+    _kind = "plain"
+
+    def __init__(self, planned: PlannedQuery, app: "SiddhiAppRuntime"):
+        super().__init__(planned, app)
+        # force-copy every leaf: constant-folding can alias identical init
+        # arrays into one buffer, which breaks donated-argument execution
+        self._state = jax.tree.map(
+            lambda x: jax.numpy.array(x, copy=True), planned.init_state())
 
     @property
     def state(self):
@@ -447,7 +571,7 @@ class QueryRuntime(_MeshResolved):
 
     def process_staged(self, staged: ev.StagedBatch, now: int) -> None:
         p = self.planned
-        dbg = getattr(self.app, "_debugger", None)
+        dbg = self.app._debugger
         if dbg is not None:
             dbg.check_break_point(self.name, "IN", staged)
         if p.keyed_window:
@@ -497,7 +621,7 @@ class QueryRuntime(_MeshResolved):
         # int(wake) here would block the send path on the step per batch)
         wake_arg = None
         if p.needs_timer:
-            if getattr(p.window, "host_scheduled", False):
+            if p.window.host_scheduled:
                 self._apply_wake(p.window.host_next_wakeup(now))
             else:
                 wake_arg = wake
@@ -561,7 +685,7 @@ class QueryRuntime(_MeshResolved):
             batch.cols, gslot_d, key_d, sel_d, now_d, in_tabs)
         wake_arg = None
         if p.needs_timer:
-            if getattr(p.window, "host_scheduled", False):
+            if p.window.host_scheduled:
                 # cron-style windows schedule on the host clock
                 self._apply_wake(p.window.host_next_wakeup(now))
             else:
@@ -570,28 +694,18 @@ class QueryRuntime(_MeshResolved):
 
     def on_timer(self, now: int) -> None:
         p = self.planned
-        staged = ev.pack_np(p.in_schema, [], capacity=8)
-        staged.ts[0] = now
-        staged.kind[0] = ev.TIMER
-        staged.valid[0] = True
+        staged = self._timer_batch(p.in_schema, now)
         if p.keyed_window:
             self._process_keyed(staged, now, all_keys=True)
             return
         self.process_staged(staged, now)
 
-    def _apply_wake(self, w: int) -> None:
-        self.next_wakeup = w
-        if w < _NO_WAKEUP_INT:
-            self.app._scheduler.notify_at(w, self)
 
-    def _emit(self, out, now: int, wake=None) -> None:
-        _emit_output(self, out, now, wake)
-
-
-class PatternQueryRuntime(_MeshResolved):
+class PatternQueryRuntime(_QueryRuntimeBase):
     """Host wrapper for a pattern/sequence query: groups events per key into
     the [K, E] device layout and drives the per-stream NFA steps."""
 
+    _kind = "pattern"
     # no pattern step reads `staged.to_device`: each uploads its own
     # columns (grouped by the host, a stack, a shard's share), so the
     # @serve accept-edge stager has nothing to hand it (_serve_stage)
@@ -599,8 +713,7 @@ class PatternQueryRuntime(_MeshResolved):
 
     def __init__(self, planned, app: "SiddhiAppRuntime",
                  slot_allocator=None):
-        self.planned = planned
-        self.app = app
+        super().__init__(planned, app)
         # the plan's one jitted init writes the state where it lives
         # (each chip its own [W, K/n] share under a mesh) and returns
         # buffers no other runtime of this plan holds, so the steps may
@@ -612,28 +725,12 @@ class PatternQueryRuntime(_MeshResolved):
                 planned.init_state(planned.key_capacity))
             sp.set_metadata(bytes=_phases.tree_nbytes(self.state),
                             shards=_sharding.shard_count(planned))
-        self.callbacks: List[Callable] = []
-        self.batch_callbacks: List[Callable] = []
-        self.next_wakeup: int = _NO_WAKEUP_INT
         self.slot_allocator = slot_allocator  # shared per partition
-        self._qlock = threading.RLock()
-        # per-key dirty mask since the last (incremental) snapshot
-        self._dirty = np.zeros(planned.key_capacity, np.bool_) \
-            if planned.partition_positions else None
-        # set by _PartitionPurger: fn(slots, now) recording key liveness
-        self._touch = None
-        # set at wiring time: fn(new_cap) -> PlannedPatternQuery re-planned
-        # with a larger emission cap (adaptive overflow growth)
-        self._replan = None
+        if planned.partition_positions:
+            self._dirty = np.zeros(planned.key_capacity, np.bool_)
         # steady-state block memo for _grouped_slots: (k0, n) ->
         # (allocator version, key_idx, sel, keys copy, sel is the identity)
         self._block_cache: Dict = {}
-        # @fuse(batches=K): stack buffer for scan-fused dispatch, or None
-        self._fuse = None
-
-    @property
-    def name(self):
-        return self.planned.name
 
     _EMIT_CAP_MAX = 512
 
@@ -649,7 +746,7 @@ class PatternQueryRuntime(_MeshResolved):
         budget is exhausted, surfacing the normal overflow error."""
         if self._replan is None:
             return False
-        cap = getattr(self.planned, "compact_rows", 8)
+        cap = self.planned.compact_rows
         need = max(n_valid + n_dropped, cap * 2)
         new_cap = min(1 << (need - 1).bit_length(), self._EMIT_CAP_MAX)
         if new_cap <= cap:
@@ -657,7 +754,7 @@ class PatternQueryRuntime(_MeshResolved):
         # admission: a regrow allocates a bigger emission block AND pays
         # a recompile — past the state ceiling the growth is denied and
         # the app sheds overflow at the current cap instead of OOMing
-        adm = getattr(self.app, "admission", None)
+        adm = self.app.admission
         if adm is not None and not adm.admit_growth(
                 self.name, (new_cap - cap) * _row_nbytes(self)):
             return False
@@ -678,8 +775,7 @@ class PatternQueryRuntime(_MeshResolved):
     def _in_tabs(self):
         """Table snapshots for `x in Table` probes inside NFA filters
         (reference: InConditionExpressionExecutor in pattern conditions)."""
-        return self.app.in_probe_tables(
-            getattr(self.planned.exec, "in_deps", None) or ())
+        return self.app.in_probe_tables(self.planned.exec.in_deps)
 
     def _grouped_slots(self, key_cols, valid, p):
         """Slot resolution + [Kb, E] grouping with a steady-state block
@@ -991,11 +1087,6 @@ class PatternQueryRuntime(_MeshResolved):
         else skips the wake fetch entirely."""
         return wake if self.planned.timer_step is not None else None
 
-    def _apply_wake(self, w: int) -> None:
-        self.next_wakeup = w
-        if w < _NO_WAKEUP_INT:
-            self.app._scheduler.notify_at(w, self)
-
 
 def _target_live(qr) -> bool:
     """Does anything need this output as host rows — a table op, a rate
@@ -1004,15 +1095,13 @@ def _target_live(qr) -> bool:
     are not a reader: the output stream's throughput is counted from the
     emission header (_emit_output_sync_impl), and nothing is fetched,
     sorted or unpacked for a junction nobody reads."""
-    if getattr(qr, "table_op", None) is not None or \
-            getattr(qr, "rate_limiter", None) is not None:
+    if qr.table_op is not None or qr.rate_limiter is not None:
         return True
     tgt = qr.planned.output_target
     if not tgt:
         return False
     app = qr.app
-    if tgt in getattr(app, "named_windows", {}) or \
-            tgt in getattr(app, "tables", {}):
+    if tgt in app.named_windows or tgt in app.tables:
         return True
     j = app.junctions.get(tgt)
     return j is not None and bool(j.queries or j.stream_callbacks)
@@ -1074,9 +1163,8 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
     # junction under the query lock): rides every deferred-delivery queue
     # so the `<query>:e2e` histogram includes queue wait — None when
     # statistics are OFF or the batch arrived outside a junction dispatch
-    ingest_ns = qr.__dict__.get("_ingest_ns")
-    if getattr(qr, "serve_emit", False) and wake is None and \
-            not getattr(qr.planned, "needs_timer", False):
+    ingest_ns = qr._ingest_ns
+    if qr.serve_emit and wake is None and not qr.planned.needs_timer:
         # device-resident serving loop (siddhi_tpu/serving): the output
         # pytree appends into the query's on-device emission ring — a
         # single jitted dispatch, zero fetches — and the per-app drainer
@@ -1089,18 +1177,17 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
         # trace so the drainer's delivery spans join them
         ring_append(qr, out, now, ingest_ns, _phases.handoff())
         return
-    if getattr(qr, "async_emit", False) and qr.app._drainer is not None:
+    if qr.async_emit and qr.app._drainer is not None:
         qr.app._drainer.enqueue(qr, out, now, wake, ingest_ns,
                                 _phases.handoff())
         return
-    depth = int(getattr(qr, "pipeline_emit", 0) or 0)
-    if depth and wake is None and \
-            not getattr(qr.planned, "needs_timer", False):
+    depth = qr.pipeline_emit
+    if depth and wake is None and not qr.planned.needs_timer:
         # timer-bearing queries never pipeline: a device wake scalar would
         # stall time-driven expiry if deferred, and host-scheduled (cron)
         # windows pass wake=None yet their flush emissions must not slip a
         # period — needs_timer covers both
-        dq = getattr(qr, "_pending_emit", None)
+        dq = qr._pending_emit
         if dq is None:
             dq = qr._pending_emit = collections.deque()
         dq.append((out, now, None, ingest_ns, _phases.handoff()))
@@ -1120,7 +1207,7 @@ def _emit_output(qr, out, now: int, wake=None) -> None:
         # inline delivery: flag the dispatcher to close e2e AFTER
         # process_staged fully returns, so per batch e2e >= the step
         # latency sample by construction (same end point, earlier start)
-        qr.__dict__["_e2e_owed"] = True
+        qr._e2e_owed = True
     _deliver_output(qr, out, now, wake)
 
 
@@ -1168,11 +1255,10 @@ def _drain_pending_emit(qr) -> None:
     shutdown).  Swap + delivery run under the query lock — the producer's
     pipeline branch in _emit_output also runs under it (junction dispatch),
     so a concurrent flush can never double-deliver the same emission."""
-    if not getattr(qr, "_pending_emit", None):
+    if not qr._pending_emit:
         return
-    lk = getattr(qr, "_qlock", None) or contextlib.nullcontext()
-    with lk:
-        dq = getattr(qr, "_pending_emit", None)
+    with qr._qlock:
+        dq = qr._pending_emit
         if not dq:
             return
         items = list(dq)
@@ -1348,7 +1434,7 @@ def _row_nbytes(qr) -> int:
     kind int32 + payload column itemsizes), cached per runtime — feeds
     the `<q>.emitted_bytes` tenant-accounting counter without touching
     any buffer."""
-    nb = qr.__dict__.get("_out_row_nbytes")
+    nb = qr._out_row_nbytes
     if nb is None:
         nb = 12
         try:
@@ -1356,7 +1442,7 @@ def _row_nbytes(qr) -> int:
                 nb += int(np.dtype(ev.np_dtype(t)).itemsize)
         except Exception:  # noqa: BLE001 — metrics must not throw
             pass
-        qr.__dict__["_out_row_nbytes"] = nb
+        qr._out_row_nbytes = nb
     return nb
 
 
@@ -1418,9 +1504,9 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             if _st.enabled:
                 _st.counter_inc(f"{qr.name}.dropped", nd)
             what = ("join result rows exceeded the emission"
-                    if getattr(qr.planned, "mixed_kinds", False)
+                    if p.mixed_kinds
                     else "pattern match rows exceeded the per-key emission")
-            if not getattr(qr.planned, "emit_explicit", True):
+            if not p.emit_explicit:
                 # the cap was an implicit default: losing matches silently
                 # is a correctness hole.  First try ADAPTIVE GROWTH — the
                 # runtime rebuilds its steps with a doubled cap (state
@@ -1429,8 +1515,7 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
                 # surface as a processing error (fault stream / exception
                 # listener), raised in the finally below so the error
                 # reports partial loss, not total loss.
-                grow = getattr(qr, "_grow_emission_cap", None)
-                if grow is None or not grow(nd, nv):
+                if not qr._grow_emission_cap(nd, nv):
                     overflow_exc = MatchOverflowError(
                         f"{qr.name}: {nd} {what} capacity this batch; set "
                         f"@emit(rows='N') on the query to raise the cap or "
@@ -1452,12 +1537,12 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
         # emission-cap demand (nv + nd rows wanted out this batch) is
         # already host-side off the header fetch — the high-water mark
         # the sizing ledger persists for @emit pre-sizing
-        _cap = getattr(qr.planned, "compact_rows", None)
+        _cap = p.compact_rows
         if _cap is not None and _stateobs.obs_enabled(qr.app):
             with _phases.phase(_st, qr.name, "obs_feed"):
                 _st.stateobs.observe(
                     qr.name, "emission_cap", nv + nd, _cap,
-                    growable=not getattr(qr.planned, "emit_explicit", True),
+                    growable=not p.emit_explicit,
                     config_key="@emit(rows='N')")
     try:
         if headed:
@@ -1476,7 +1561,7 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             # already host-side (header / staged valid plane) and the byte
             # figure is schema metadata × rows — no extra fetch
             _st.emitted(qr.name, rows_out, rows_out * _row_nbytes(qr))
-        if getattr(p, "emits_uuid", False):
+        if p.emits_uuid:
             # UUID() sentinels materialize ONCE here, at the device->host
             # emission boundary, so every consumer of this emission (event
             # callbacks, batch payloads, downstream routing, table writes)
@@ -1523,14 +1608,14 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
         if not pairs:
             return
         with _phases.phase(_st, qr.name, "sink"):
-            if getattr(qr, "table_op", None) is not None:
+            if qr.table_op is not None:
                 current = [e for k, e in pairs if k == ev.CURRENT]
                 expired = [e for k, e in pairs if k == ev.EXPIRED]
                 for cb in qr.callbacks:
                     cb(now, current or None, expired or None)
                 _apply_table_op(qr, ots, okind, ovalid, ocols, now)
                 return
-            limiter = getattr(qr, "rate_limiter", None)
+            limiter = qr.rate_limiter
             if limiter is not None:
                 limiter.process(pairs, now)
                 return
@@ -1580,7 +1665,7 @@ def _deliver_pairs(qr, pairs, now: int) -> None:
     p = qr.planned
     current = [e for k, e in pairs if k == ev.CURRENT]
     expired = [e for k, e in pairs if k == ev.EXPIRED]
-    dbg = getattr(qr.app, "_debugger", None)
+    dbg = qr.app._debugger
     if dbg is not None:
         dbg.check_break_point(qr.name, "OUT", current)
     for cb in qr.callbacks:
@@ -1622,29 +1707,19 @@ def _apply_table_op(qr, ots, okind, ovalid, ocols, now) -> None:
                            staged=staged)
 
 
-class JoinQueryRuntime(_MeshResolved):
+class JoinQueryRuntime(_QueryRuntimeBase):
     """Host wrapper for join queries: routes each side's batches to the
     side-specific jitted step, passing table snapshots for table sides."""
 
+    _kind = "join"
+
     def __init__(self, planned, app: "SiddhiAppRuntime"):
-        self.planned = planned
-        self.app = app
-        self.state = jax.tree.map(
-            lambda x: jax.numpy.array(x, copy=True), planned.init_state())
-        self.state = self.place_state(self.state)
-        self.callbacks: List[Callable] = []
-        self.batch_callbacks: List[Callable] = []
-        self.next_wakeup: int = _NO_WAKEUP_INT
-        self._qlock = threading.RLock()
-        self.table_op = None
-        # set at wiring time: fn(new_rows) -> PlannedJoinQuery replanned
-        # with a larger emission compaction cap
-        self._replan = None
-        # @fuse(batches=K): stack buffer for scan-fused dispatch, or None
-        self._fuse = None
-        # equi-join bucket fast path: host retention mirror + the lane
-        # width the NEXT replan must keep (core/join.py JoinKeyTracker)
-        self._jk = None
+        super().__init__(planned, app)
+        self.state = self.place_state(jax.tree.map(
+            lambda x: jax.numpy.array(x, copy=True), planned.init_state()))
+        # equi-join bucket fast path: host retention mirror (`_jk`) + the
+        # lane width the NEXT replan must keep (core/join.py
+        # JoinKeyTracker)
         self._lane_k = 0
         if planned.fastpath == "bucket":
             from .join import JoinKeyTracker
@@ -1652,10 +1727,6 @@ class JoinQueryRuntime(_MeshResolved):
                                       planned.ring_caps,
                                       planned.lane_buckets)
             self._lane_k = planned.lane_k
-
-    @property
-    def name(self):
-        return self.planned.name
 
     _EMIT_CAP_MAX = 1 << 21   # 2M emitted rows per batch
 
@@ -1680,7 +1751,7 @@ class JoinQueryRuntime(_MeshResolved):
         # admission: deny growth past the state ceiling (see
         # PatternQueryRuntime._grow_emission_cap) — overflow keeps
         # dropping at the current cap, loudly, instead of OOMing
-        adm = getattr(self.app, "admission", None)
+        adm = self.app.admission
         if adm is not None and not adm.admit_growth(
                 self.name, (new_rows - (cur or 0)) * _row_nbytes(self)):
             return False
@@ -1833,7 +1904,7 @@ class JoinQueryRuntime(_MeshResolved):
         if other.is_aggregation:
             agg = self.app.aggregations[other.stream_id]
             return _aggregation_view(agg, p.per_duration, p.within_range)
-        if getattr(other, "is_named_window", False):
+        if other.is_named_window:
             # probe the shared window's live buffer (reference:
             # WindowWindowProcessor.find against Window.java's chain)
             nw = self.app.named_windows[other.stream_id]
@@ -1901,20 +1972,12 @@ class JoinQueryRuntime(_MeshResolved):
         _emit_output(self, out, now,
                      wake=wake if p.needs_timer else None)
 
-    def _apply_wake(self, w: int) -> None:
-        self.next_wakeup = w
-        if w < _NO_WAKEUP_INT:
-            self.app._scheduler.notify_at(w, self)
-
     def on_timer(self, now: int) -> None:
         p = self.planned
         for is_left, side in ((True, p.left), (False, p.right)):
             if side.window is not None and side.window.needs_timer:
-                staged = ev.pack_np(side.schema, [], capacity=8)
-                staged.ts[0] = now
-                staged.kind[0] = ev.TIMER
-                staged.valid[0] = True
-                self.process_staged(is_left, staged, now)
+                self.process_staged(
+                    is_left, self._timer_batch(side.schema, now), now)
 
 
 class TriggerRuntime:
@@ -1950,7 +2013,7 @@ class TriggerRuntime:
             self.app._scheduler.notify_at(self._cron.next_fire(now), self)
 
 
-class NamedWindowRuntime:
+class NamedWindowRuntime(_QueryRuntimeBase):
     """A shared window instance (reference: CORE/window/Window.java:65 —
     `define window W (...) <window>(...) output <type> events`).  Queries
     insert into it; reader queries subscribe to its CURRENT/EXPIRED output.
@@ -1962,9 +2025,9 @@ class NamedWindowRuntime:
         import jax.numpy as jnp
         from .window import Rows, create_window
 
+        super().__init__(None, app)
         self.definition = wdef
         self.schema = schema
-        self.app = app
         w = wdef.window
         if w is None:
             raise CompileError(
@@ -1972,7 +2035,7 @@ class NamedWindowRuntime:
         self.wproc = create_window(
             (w.namespace + ":" if w.namespace else "") + w.name,
             schema, w.parameters, batch_capacity=512)
-        if getattr(self.wproc, "session_key_pos", None) is not None:
+        if self.wproc.session_key_pos is not None:
             # the keyed-window slab is a query-planner construct; a shared
             # named window has no key axis — running the key-less processor
             # would silently merge every key into ONE session
@@ -1983,10 +2046,8 @@ class NamedWindowRuntime:
         self.output_event_type = wdef.output_event_type or "ALL_EVENTS"
         self.subscribers: List = []      # QueryRuntime-likes (process_staged)
         self.stream_callbacks: List[Callable] = []
-        # serializes ingest (via _route) against scheduler timers and
-        # snapshot reads of self.state
-        self._qlock = threading.RLock()
-        self.next_wakeup: int = _NO_WAKEUP_INT
+        # `_qlock` serializes ingest (via _route) against scheduler timers
+        # and snapshot reads of self.state
         wproc = self.wproc
 
         def step(state, ts, kind, valid, cols, now):
@@ -2020,17 +2081,10 @@ class NamedWindowRuntime:
             batch.valid, batch.cols, now_d)
         self._fanout(out, now)
         if self.needs_timer:
-            w = int(wake)
-            self.next_wakeup = w
-            if w < _NO_WAKEUP_INT:
-                self.app._scheduler.notify_at(w, self)
+            self._apply_wake(int(wake))
 
     def on_timer(self, now: int) -> None:
-        staged = ev.pack_np(self.schema, [], capacity=8)
-        staged.ts[0] = now
-        staged.kind[0] = ev.TIMER
-        staged.valid[0] = True
-        self.process_staged(staged, now)
+        self.process_staged(self._timer_batch(self.schema, now), now)
 
     def _fanout(self, out, now: int) -> None:
         ots, okind, ovalid, ocols = out
@@ -2087,6 +2141,9 @@ class StreamJunction:
         # carries, on whatever thread it runs (observability/phases.py)
         self._batch_seq = itertools.count(1)
         self._sub_names_memo: Optional[Tuple[str, ...]] = None
+        # does the accept-edge stager run here?  Memoized at the first
+        # dispatch (_serve_stage): wiring is complete by then
+        self._serve_staging: Optional[bool] = None
         # @async(buffer.size, workers): bounded ingress queue + worker
         # threads (the reference's Disruptor ring,
         # StreamJunction.java:276-313).  None => synchronous dispatch.
@@ -2147,19 +2204,18 @@ class StreamJunction:
         whose subscribers all upload columns of their own (the pattern
         path: grouped by the host) stages nothing: one upload a batch.
         Idempotent: a batch staged at enqueue is skipped at dispatch."""
-        on = getattr(self, "_serve_staging", None)
+        on = self._serve_staging
         if on is None:
-            # memoized on first dispatch: wiring is complete by then
-            subs = [getattr(q, "_qr", q) for q in self.queries]
+            # an aggregation (no query runtime: None) takes the staged
+            # batch as it is, and serves nothing
+            subs = [_sub_runtime(q) for q in self.queries]
             on = self._serve_staging = \
-                any(getattr(q, "serve_emit", False) for q in subs) and \
-                any(getattr(q, "adopts_staged", True) for q in subs)
+                any(q is not None and q.serve_emit for q in subs) and \
+                any(q is None or q.adopts_staged for q in subs)
         if on and self.app is not None and staged.dev is None:
-            st = getattr(self.app, "_serve_stager", None)
-            if st is not None:
-                with _phases.phase(self.app.stats, self.sub_names(), "h2d",
-                                   bytes=_staged_nbytes(staged)):
-                    st.stage(staged, self.schema)
+            with _phases.phase(self.app.stats, self.sub_names(), "h2d",
+                               bytes=_staged_nbytes(staged)):
+                self.app._serve_stager.stage(staged, self.schema)
 
     def enqueue(self, tag: str, payload, now: int) -> None:
         q = self._async_q
@@ -2272,48 +2328,41 @@ class StreamJunction:
         UNDER the query lock so the emission path — however deferred
         (@pipeline deque, @fuse stack, @async drainer) — can close the
         `<query>:e2e` histogram against the right batch.  The stamp must
-        land on the REAL runtime (wrappers hold it in _qr, same deref as
+        land on the REAL runtime (`_sub_runtime`, same deref as
         _sub_name/_sub_lock) — _emit_output reads it from the runtime the
-        emission belongs to, so stamping a _Sub/_JSub wrapper would
-        silently drop e2e for every pattern/join query."""
-        lk = _sub_lock(q)
+        emission belongs to, so stamping a `_Subscription` would silently
+        drop e2e for every pattern/join query."""
+        tgt = _sub_runtime(q)
+        locked = _query_lock(tgt._qlock, self.stream_id) \
+            if tgt is not None else _NULL_CM
         if stats is None:
-            if lk is not None:
-                with _query_lock(lk, self.stream_id):
-                    q.process_staged(staged, now)
-            else:
+            with locked:
                 q.process_staged(staged, now)
             return
-        qname = _sub_name(q, self.stream_id)
-        tgt = getattr(q, "_qr", None) or q
+        qname = tgt.name if tgt is not None else self.stream_id
         t0 = time.perf_counter_ns()
         try:
             with (_tracing.span("query", query=qname) if traced
-                  else _NULL_CM):
-                if lk is not None:
-                    with _query_lock(lk, self.stream_id):
-                        tgt.__dict__["_ingest_ns"] = ingest_ns
-                        try:
-                            q.process_staged(staged, now)
-                        finally:
-                            # cleared so a later timer-driven emission
-                            # can't close e2e against this batch's stamp
-                            tgt.__dict__["_ingest_ns"] = None
-                else:
-                    tgt.__dict__["_ingest_ns"] = ingest_ns
-                    try:
-                        q.process_staged(staged, now)
-                    finally:
-                        tgt.__dict__["_ingest_ns"] = None
+                  else _NULL_CM), locked:
+                if tgt is not None:
+                    tgt._ingest_ns = ingest_ns
+                try:
+                    q.process_staged(staged, now)
+                finally:
+                    # cleared so a later timer-driven emission can't
+                    # close e2e against this batch's stamp
+                    if tgt is not None:
+                        tgt._ingest_ns = None
         finally:
             stats.query_latency(qname, n, time.perf_counter_ns() - t0)
-            if ingest_ns is not None and \
-                    tgt.__dict__.pop("_e2e_owed", False):
+            if tgt is not None and tgt._e2e_owed:
                 # emission delivered inline during this dispatch: close
                 # `<query>:e2e` here, after the step AND delivery — the
                 # stamp predates t0, so e2e >= the step-latency sample
-                stats.e2e_latency(qname,
-                                  time.perf_counter_ns() - ingest_ns)
+                tgt._e2e_owed = False
+                if ingest_ns is not None:
+                    stats.e2e_latency(qname,
+                                      time.perf_counter_ns() - ingest_ns)
 
     def dispatch_staged(self, staged: ev.StagedBatch, now: int,
                         ingest_ns=None) -> None:
@@ -2477,12 +2526,12 @@ class _PartitionPurger:
                     jax.numpy.asarray(c)
                     for c in qr.planned.init_columns()[:3])
                 continue
-            if not hasattr(qr, "_touch"):
-                # join runtimes have no liveness hook: purging their group
+            if isinstance(qr, JoinQueryRuntime):
+                # join runtimes feed no liveness hook: purging their group
                 # allocator would judge ACTIVE slots idle and corrupt
                 # aggregates; leave them out of the GC
                 continue
-            if getattr(qr.planned, "pair_allocs", None):
+            if qr.planned.pair_allocs:
                 # distinctCount pair slots key on the group slot; recycling
                 # group slots under them would corrupt refcounts
                 import logging
@@ -2490,16 +2539,16 @@ class _PartitionPurger:
                     "@purge skips query %s: distinctCount state is not "
                     "purgeable yet", qr.name)
                 continue
-            if getattr(qr.planned, "keyed_window", False):
+            if qr.planned.keyed_window:
                 # keyed-window runtimes share the partition key allocator
                 qr._touch = self._make_touch(self._seen_shared)
             # per-query group-by allocator (keyed-window queries have BOTH:
             # the shared window-key axis and their own group slots)
-            alloc = getattr(qr.planned, "slot_allocator", None)
+            alloc = qr.planned.slot_allocator
             if alloc is not None:
                 seen = np.zeros(alloc.capacity, np.int64)
                 self._seen_q[id(qr)] = seen
-                if getattr(qr.planned, "keyed_window", False):
+                if qr.planned.keyed_window:
                     qr._touch_group = self._make_touch(seen)
                 else:
                     qr._touch = self._make_touch(seen)
@@ -2532,8 +2581,7 @@ class _PartitionPurger:
         cutoff = now - self.idle_ms
         # barrier over every runtime this purger mutates: state resets must
         # not interleave with their ingestion workers
-        locks = [qr._qlock for qr in self.runtimes
-                 if getattr(qr, "_qlock", None) is not None]
+        locks = [qr._qlock for qr in self.runtimes]
         with _acquire_all(locks):
             idle = self._idle_slots(self.shared_alloc, self._seen_shared,
                                     now, cutoff)
@@ -2542,12 +2590,12 @@ class _PartitionPurger:
                 for qr in self.runtimes:
                     if isinstance(qr, PatternQueryRuntime):
                         self._reset_pattern_keys(qr, idle)
-                    elif getattr(qr.planned, "keyed_window", False):
+                    elif qr.planned.keyed_window:
                         self._reset_keyed_window(qr, idle)
             for qr in self.runtimes:
                 if isinstance(qr, PatternQueryRuntime):
                     continue
-                alloc = getattr(qr.planned, "slot_allocator", None)
+                alloc = qr.planned.slot_allocator
                 seen = self._seen_q.get(id(qr))
                 if alloc is None or seen is None:
                     continue
@@ -2793,9 +2841,8 @@ class _EmissionDrainer:
                     # failures must reach the exception listener, not stderr
                     import logging
                     logging.getLogger("siddhi_tpu").error(
-                        "async emission error in %s: %s",
-                        getattr(qr, "name", "?"), exc)
-                    listener = getattr(qr.app, "exception_listener", None)
+                        "async emission error in %s: %s", qr.name, exc)
+                    listener = qr.app.exception_listener
                     if listener is not None:
                         try:
                             listener(exc)
@@ -2852,10 +2899,13 @@ class _Scheduler:
         their own (NOT the app lock — a timer target holding the app lock
         while taking query locks downstream could deadlock against a
         worker emitting into a named window)."""
+        # `q` is any timer target — a query runtime, or a trigger, a rate
+        # limiter, an aggregation, the purger: the probes stay
         lk = getattr(q, "_qlock", None)
         if lk is None:
             lk = q.__dict__.setdefault("_qlock", threading.RLock())
-        name = _sub_name(q, getattr(q, "stream_id", "timer"))
+        name = q.name if isinstance(q, _QueryRuntimeBase) \
+            else getattr(q, "stream_id", "timer")
         with _phases.phase(self.app.stats, name, "timer",
                            pending=len(self._heap)), lk:
             q.on_timer(ts)
@@ -2975,6 +3025,10 @@ class SiddhiAppRuntime:
             iv = parse_time_ms(st_ann.element("interval", "5 sec")) or 5000
             self._stats_reporter = ConsoleReporter(self, iv / 1000.0)
         self.exception_listener = None
+        # attached by debug(); built once the queries are planned (below)
+        self._debugger = None
+        self.admission = None
+        self.merged_groups: Dict[str, object] = {}
 
         # error store: failed events captured by @OnError(action='STORE')
         # and @sink(on.error='store'), replayable via replay_errors()/
@@ -3046,15 +3100,8 @@ class SiddhiAppRuntime:
         for aid, adef in app.aggregation_definition_map.items():
             agg = AggregationRuntime(adef, self)
             self.aggregations[aid] = agg
-
-            class _ASub:
-                def __init__(self, a):
-                    self._a = a
-
-                def process_staged(self, staged, now):
-                    self._a.process_staged(staged, now)
-
-            self.junctions[agg.input_stream_id].subscribe_query(_ASub(agg))
+            self.junctions[agg.input_stream_id].subscribe_query(
+                _Subscription(agg, locks=False))
             if agg.purge_enabled or agg._store_tables:
                 # periodic retention purge + store write-through
                 # (reference: IncrementalDataPurger scheduled executor)
@@ -3106,7 +3153,6 @@ class SiddhiAppRuntime:
         # Runs AFTER per-query planning (it stacks the planned step
         # bodies) and BEFORE admission registration (merged owners get
         # compile-gate labels too).
-        self.merged_groups: Dict[str, object] = {}
         self._merge_reasons: Dict[str, str] = {}
         from ..optimizer import apply_merge
         apply_merge(self)
@@ -3177,28 +3223,16 @@ class SiddhiAppRuntime:
                 slots=nfa_slots,
                 script_functions=self.app.function_definition_map)
             planned = plan()
-            self._validate_in_deps(
-                getattr(planned.exec, "in_deps", ()), name)
+            self._validate_in_deps(planned.exec.in_deps, name)
             runtime = PatternQueryRuntime(planned, self)
             # the SAME partial replans on emission-cap growth: initial plan
             # and regrow can never drift apart
             runtime._replan = lambda cap, _p=plan: _p(
                 compact_rows_override=cap)
-            runtime.async_emit = self._async_enabled(q)
-            runtime.pipeline_emit = self._pipeline_enabled(q)
-            self._wire_serve(runtime, q)
-            self._maybe_fuse(runtime, q, "pattern")
-            self.query_runtimes[name] = runtime
+            self._register(runtime, q, name)
             for sid in planned.spec.stream_ids:
-
-                class _Sub:
-                    def __init__(self, qr, stream):
-                        self._qr, self._sid = qr, stream
-
-                    def process_staged(self, staged, now):
-                        self._qr.process_staged(self._sid, staged, now)
-
-                self.junctions[sid].subscribe_query(_Sub(runtime, sid))
+                self.junctions[sid].subscribe_query(
+                    _Subscription(runtime, sid))
             self._wire_output(runtime, q, planned, name)
             return
         in_sid = q.input_stream.unique_stream_id
@@ -3238,11 +3272,7 @@ class SiddhiAppRuntime:
             **kw)
         self._validate_in_deps(planned.in_deps, name)
         runtime = QueryRuntime(planned, self)
-        runtime.async_emit = self._async_enabled(q)
-        runtime.pipeline_emit = self._pipeline_enabled(q)
-        self._wire_serve(runtime, q)
-        self._maybe_fuse(runtime, q, "plain")
-        self.query_runtimes[name] = runtime
+        self._register(runtime, q, name)
         if from_window:
             self.named_windows[in_sid].subscribers.append(runtime)
         else:
@@ -3253,7 +3283,6 @@ class SiddhiAppRuntime:
         """`output [all|first|last] every ... | snapshot every t` (reference:
         OutputParser.constructOutputRateLimiter, OutputParser.java:282)."""
         from .ratelimit import create_rate_limiter
-        runtime.rate_limiter = None
         if q.output_rate is None:
             return
         group_positions = None
@@ -3307,7 +3336,6 @@ class SiddhiAppRuntime:
             UpdateOrInsertStream,
             UpdateStream,
         )
-        runtime.table_op = None
         tgt = planned.output_target
         out_stream = q.output_stream
         if tgt and tgt in self.tables:
@@ -3370,25 +3398,15 @@ class SiddhiAppRuntime:
         # lane growth; the runtime's current lane width always rides
         # along so one growth can never silently reset the other
         def _join_replan(rows=None, _p=plan, _rt=runtime, **kw):
-            if getattr(_rt, "_lane_k", 0):
+            if _rt._lane_k:
                 kw.setdefault("lane_k_override", _rt._lane_k)
             return _p(emit_rows_override=rows, **kw)
         runtime._replan = _join_replan
-        runtime.async_emit = self._async_enabled(q)
-        runtime.pipeline_emit = self._pipeline_enabled(q)
-        self._wire_serve(runtime, q)
-        self._maybe_fuse(runtime, q, "join")
-        self.query_runtimes[name] = runtime
+        self._register(runtime, q, name)
         for side, is_left in ((planned.left, True), (planned.right, False)):
-            class _JSub:
-                def __init__(self, qr, left):
-                    self._qr, self._left = qr, left
-
-                def process_staged(self, staged, now):
-                    self._qr.process_staged(self._left, staged, now)
             if not side.is_table:
                 self.junctions[side.stream_id].subscribe_query(
-                    _JSub(runtime, is_left))
+                    _Subscription(runtime, is_left))
             elif side.is_named_window and (
                     planned.step_left if is_left else
                     planned.step_right) is not None:
@@ -3396,33 +3414,8 @@ class SiddhiAppRuntime:
                 # the shared window trigger the join side too (reference:
                 # Window.java:145-184 publishes to subscribing queries)
                 self.named_windows[side.stream_id].subscribers.append(
-                    _JSub(runtime, is_left))
+                    _Subscription(runtime, is_left))
         self._wire_output(runtime, q, planned, name)
-
-    def _async_enabled(self, q) -> bool:
-        """@async at app level, on the query, or on any input stream
-        definition (reference: @async is a stream-level annotation,
-        StreamJunction.startProcessing :276-313)."""
-        from .plan_facts import async_enabled
-        return async_enabled(self.app, q)
-
-    def _pipeline_enabled(self, q) -> int:
-        """@pipeline(depth='k') on the app or the query: deferred emission
-        so host staging of batch N+1 overlaps the device step of batch N
-        (no extra thread).  depth=1 (default) delivers each send's
-        predecessor; depth>1 lets emissions lag up to k sends and drains
-        them in batched device_gets, amortizing the fixed per-fetch
-        latency over ~k/2 sends.  The WHOLE delivery lags until flush():
-        callbacks, table writes, and downstream stream/window inserts — a
-        reader query in the same app observes this query's effects up to k
-        batches behind (same relaxation @async makes, minus the thread).
-        Timer-bearing (time/cron-window, absent-pattern) queries are
-        excluded in _emit_output.  Returns the depth (0 = off)."""
-        # the query's own annotation wins (it may carry a depth the
-        # app-level blanket annotation lacks); plan_facts.pipeline_depth
-        # is the one implementation, shared with the merge planner
-        from .plan_facts import pipeline_depth
-        return pipeline_depth(self.app, q)
 
     def _serve_enabled(self, q) -> bool:
         """Device-resident serving loop (siddhi_tpu/serving): emissions
@@ -3446,35 +3439,41 @@ class SiddhiAppRuntime:
         from ..serving import serving_config
         return bool(serving_config(self)["enabled"])
 
-    def _wire_serve(self, runtime, q) -> None:
-        """Stash the serving decision + ring sizing on the runtime at
-        wiring time (the emission hot path reads attributes only)."""
+    def _register(self, runtime, q: Query, name: str) -> None:
+        """The ONE wiring block every query runtime passes through with
+        its AST: the delivery mode, the @fuse stack, the app's name for
+        it.  The annotations are read by plan_facts (one implementation,
+        shared with the merge planner and lint); the emission hot path
+        reads the attributes set here.
+
+        @async: on the app, the query, or any input stream definition
+        (reference: StreamJunction.startProcessing :276-313).
+        @pipeline(depth='k'): deferred emission, so host staging of batch
+        N+1 overlaps the device step of batch N with no extra thread;
+        depth 1 delivers each send's predecessor, depth k lets emissions
+        lag up to k sends and drains them in batched device_gets.  The
+        WHOLE delivery lags until flush(): callbacks, table writes,
+        downstream inserts — a reader query in the same app sees this
+        query's effects up to k batches behind (the relaxation @async
+        makes, minus the thread).  @serve: see `_serve_enabled`.
+        @fuse(batches='K'): K staged micro-batches run as ONE lax.scan
+        dispatch (core/fusion.py); composes with the delivery modes
+        (per-batch emissions re-enter them) and @emit.  Timer-bearing
+        queries are excluded from all of them where they are used."""
+        from . import plan_facts
+        runtime._query_ast = q
+        runtime.async_emit = plan_facts.async_enabled(self.app, q)
+        runtime.pipeline_emit = plan_facts.pipeline_depth(self.app, q)
         runtime.serve_emit = self._serve_enabled(q)
         if runtime.serve_emit:
-            from .plan_facts import serve_ring_capacity
-            runtime.serve_ring_capacity = serve_ring_capacity(self.app, q)
-
-    def _fuse_enabled(self, q) -> int:
-        """@fuse(batches='K') on the query, any input stream definition,
-        or the app (@app:fuse): stack K staged micro-batches and run them
-        as ONE lax.scan device dispatch — per-send RTT and dispatch
-        overhead divide by K (core/fusion.py).  Composes with @pipeline/
-        @async (per-batch emissions re-enter their paths) and @emit.
-        Returns the stack depth K (0 = off)."""
-        from .plan_facts import fuse_depth
-        return fuse_depth(self.app, q)
-
-    def _maybe_fuse(self, runtime, q, kind: str) -> None:
-        # every query runtime passes through here with its AST and path
-        # kind — retained for EXPLAIN (observability/explain.py renders
-        # the operator tree from the AST; kind selects the fusion rules)
-        runtime._query_ast = q
-        runtime._kind = kind
-        k = self._fuse_enabled(q)
+            runtime.serve_ring_capacity = \
+                plan_facts.serve_ring_capacity(self.app, q)
+        self.query_runtimes[name] = runtime
+        k = plan_facts.fuse_depth(self.app, q)
         if k <= 0:
             return
         runtime._fuse_requested = k
-        why = _fusion.ineligible_reason(runtime, kind)
+        why = _fusion.ineligible_reason(runtime, runtime._kind)
         if why is not None:
             # kept for explain(): the concrete reason @fuse skipped this
             # query, not just a log line that scrolled away
@@ -3483,7 +3482,7 @@ class SiddhiAppRuntime:
                 "@fuse(batches=%d) ignored on query %s: %s", k,
                 runtime.name, why)
             return
-        runtime._fuse = _fusion.FuseBuffer(runtime, k, kind)
+        runtime._fuse = _fusion.FuseBuffer(runtime, k, runtime._kind)
 
     def _add_partition(self, part: Partition, qi: int) -> int:
         """Partitions: key-scoped state clones (reference:
@@ -3585,27 +3584,17 @@ class SiddhiAppRuntime:
                     partition_key_fns=pfns or None, mesh=self.mesh,
                     script_functions=self.app.function_definition_map)
                 planned = plan()
-                self._validate_in_deps(
-                    getattr(planned.exec, "in_deps", ()), qname)
+                self._validate_in_deps(planned.exec.in_deps, qname)
                 runtime = PatternQueryRuntime(planned, self,
                                               slot_allocator=shared_allocator)
                 # same partial => initial plan and regrow cannot drift
                 runtime._replan = lambda cap, _p=plan: _p(
                     compact_rows_override=cap)
-                runtime.async_emit = self._async_enabled(q)
-                runtime.pipeline_emit = self._pipeline_enabled(q)
-                self._wire_serve(runtime, q)
-                self._maybe_fuse(runtime, q, "pattern")
-                self.query_runtimes[qname] = runtime
+                self._register(runtime, q, qname)
                 part_runtimes.append(runtime)
                 for sid in planned.spec.stream_ids:
-                    class _Sub:
-                        def __init__(self, qr, stream):
-                            self._qr, self._sid = qr, stream
-
-                        def process_staged(self, staged, now):
-                            self._qr.process_staged(self._sid, staged, now)
-                    self.junctions[sid].subscribe_query(_Sub(runtime, sid))
+                    self.junctions[sid].subscribe_query(
+                        _Subscription(runtime, sid))
                 self._attach_rate_limiter(q, runtime)
                 self._define_output_for(planned, qname)
             elif isinstance(q.input_stream, JoinInputStream):
@@ -3679,11 +3668,7 @@ class SiddhiAppRuntime:
                     mesh=self.mesh)
                 self._validate_in_deps(planned.in_deps, qname)
                 runtime = QueryRuntime(planned, self)
-                runtime.async_emit = self._async_enabled(q)
-                runtime.pipeline_emit = self._pipeline_enabled(q)
-                self._wire_serve(runtime, q)
-                self._maybe_fuse(runtime, q, "plain")
-                self.query_runtimes[qname] = runtime
+                self._register(runtime, q, qname)
                 part_runtimes.append(runtime)
                 self.junctions[sid].subscribe_query(runtime)
                 self._attach_rate_limiter(q, runtime)
@@ -3814,9 +3799,8 @@ class SiddhiAppRuntime:
             self._started = False
         # release this app's compile-gate owner labels whether or not it
         # ever started (deploy-then-undeploy without traffic is common)
-        adm = getattr(self, "admission", None)
-        if adm is not None:
-            adm.unregister()
+        if self.admission is not None:
+            self.admission.unregister()
 
     def pause_sources(self) -> None:
         """reference: SiddhiAppRuntimeImpl pauses Sources around persist."""
@@ -3840,8 +3824,7 @@ class SiddhiAppRuntime:
             self._drainer.flush()
             self._serve_drainer.drain_all()   # serving rings -> empty
             if all(j.pending_async() == 0 for j in self.junctions.values()) \
-                    and not any(getattr(qr, "_pending_emit", None) or
-                                _fusion.pending(qr)
+                    and not any(qr._pending_emit or _fusion.pending(qr)
                                 for qr in self._step_runtimes()) \
                     and self._serve_drainer.pending() == 0:
                 return
@@ -3855,7 +3838,7 @@ class SiddhiAppRuntime:
         emissions: the per-query runtimes plus merged-group dispatchers
         (optimizer/mqo.py) — flush/quiesce/shutdown drain them all."""
         return list(self.query_runtimes.values()) + \
-            list(getattr(self, "merged_groups", {}).values())
+            list(self.merged_groups.values())
 
     def in_probe_tables(self, deps):
         """Snapshots for `x in Table` probes: (first column, validity) per
@@ -3920,16 +3903,13 @@ class SiddhiAppRuntime:
                 self._serve_drainer.drain_all()
                 if all(j.pending_async() == 0
                        for j in self.junctions.values()) and \
-                        not any(getattr(qr, "_pending_emit", None) or
-                                _fusion.pending(qr)
+                        not any(qr._pending_emit or _fusion.pending(qr)
                                 for qr in self._step_runtimes()) and \
                         self._serve_drainer.pending() == 0:
                     break
             locks = [self._lock]
             for qname in sorted(self.query_runtimes):
-                lk = getattr(self.query_runtimes[qname], "_qlock", None)
-                if lk is not None:
-                    locks.append(lk)
+                locks.append(self.query_runtimes[qname]._qlock)
             for wid in sorted(self.named_windows):
                 locks.append(self.named_windows[wid]._qlock)
             with _acquire_all(locks):
@@ -4161,7 +4141,7 @@ class SiddhiAppRuntime:
         serving ring (host-side attribute reads only)."""
         out: Dict[str, object] = {}
         for qname, qr in list(self.query_runtimes.items()):
-            ring = qr.__dict__.get("_serve_ring")
+            ring = qr._serve_ring
             if ring is not None:
                 out[qname] = ring
         return out
@@ -4341,8 +4321,8 @@ class SiddhiAppRuntime:
             for name, qr in self.query_runtimes.items():
                 host_state = _host_state(qr)
                 alloc = _allocator_of(qr)
-                alloc2 = getattr(qr.planned, "slot_allocator2", None)
-                jk = getattr(qr.planned, "join_key_allocator", None)
+                alloc2 = qr.planned.slot_allocator2
+                jk = qr.planned.join_key_allocator
                 states[name] = {
                     "state": host_state,
                     "slots": alloc.snapshot() if alloc is not None else None,
@@ -4351,8 +4331,8 @@ class SiddhiAppRuntime:
                     "slots_jk": jk.snapshot() if jk is not None else None,
                     "slots_pairs": [
                         a.snapshot() for a, _ in
-                        getattr(qr.planned, "pair_allocs", [])] or None,
-                    "wake": getattr(qr, "next_wakeup", None),
+                        qr.planned.pair_allocs] or None,
+                    "wake": qr.next_wakeup,
                     # key-state row order (mesh layout) this snapshot is
                     # written in: restore re-buckets through the router
                     # when the target runtime's mesh size differs
@@ -4379,7 +4359,7 @@ class SiddhiAppRuntime:
             }
             # a full snapshot resets the incremental baseline
             for qr in self.query_runtimes.values():
-                if getattr(qr, "_dirty", None) is not None:
+                if qr._dirty is not None:
                     qr._dirty[:] = False
                 alloc = _allocator_of(qr)
                 if alloc is not None:
@@ -4398,7 +4378,7 @@ class SiddhiAppRuntime:
             deltas = {}
             for name, qr in self.query_runtimes.items():
                 alloc = _allocator_of(qr)
-                dirty = getattr(qr, "_dirty", None)
+                dirty = qr._dirty
                 if dirty is not None and isinstance(qr.state, tuple) and \
                         len(qr.state) == 2 and isinstance(qr.state[0], tuple):
                     idx = np.nonzero(dirty)[0]
@@ -4417,13 +4397,13 @@ class SiddhiAppRuntime:
                             lambda x: np.asarray(x), qr.state[1]),
                         "journal": alloc.drain_journal()
                         if alloc is not None else [],
-                        "wake": getattr(qr, "next_wakeup", None),
+                        "wake": qr.next_wakeup,
                         "layout": _sharding.query_layout(qr),
                     }
                     dirty[:] = False
                 else:
-                    alloc2 = getattr(qr.planned, "slot_allocator2", None)
-                    jk = getattr(qr.planned, "join_key_allocator", None)
+                    alloc2 = qr.planned.slot_allocator2
+                    jk = qr.planned.join_key_allocator
                     deltas[name] = {
                         "kind": "full",
                         "state": _host_state(qr),
@@ -4435,8 +4415,8 @@ class SiddhiAppRuntime:
                         if jk is not None else None,
                         "slots_pairs": [
                             a.snapshot() for a, _ in
-                            getattr(qr.planned, "pair_allocs", [])] or None,
-                        "wake": getattr(qr, "next_wakeup", None),
+                            qr.planned.pair_allocs] or None,
+                        "wake": qr.next_wakeup,
                         "layout": _sharding.query_layout(qr),
                     }
             from .table import _table_state
@@ -4517,26 +4497,23 @@ class SiddhiAppRuntime:
                     host_state = _rebucket_for(qr, d.get("layout"),
                                                d["state"])
                     restored = _device_state(qr, host_state)
-                    qr.state = qr.place_state(restored) \
-                        if hasattr(qr, "place_state") else restored
+                    qr.state = qr.place_state(restored)
                     if d["slots"] is not None and alloc is not None:
                         alloc.restore(d["slots"])
-                    alloc2 = getattr(qr.planned, "slot_allocator2", None)
+                    alloc2 = qr.planned.slot_allocator2
                     if d.get("slots2") is not None and alloc2 is not None:
                         alloc2.restore(d["slots2"])
-                    jk = getattr(qr.planned, "join_key_allocator", None)
+                    jk = qr.planned.join_key_allocator
                     if d.get("slots_jk") is not None and jk is not None:
                         jk.restore(d["slots_jk"])
                     pairs = d.get("slots_pairs")
                     if pairs:
-                        for (a, _), snap in zip(
-                                getattr(qr.planned, "pair_allocs", []),
-                                pairs):
+                        for (a, _), snap in zip(qr.planned.pair_allocs,
+                                                pairs):
                             a.restore(snap)
-                    if hasattr(qr, "_after_restore"):
-                        qr._after_restore(host_state)
+                    qr._after_restore(host_state)
                 w = d.get("wake")
-                if w is not None and hasattr(qr, "_apply_wake"):
+                if w is not None:
                     qr._apply_wake(int(w))
             self._restore_shared(payload)
 
@@ -4552,29 +4529,26 @@ class SiddhiAppRuntime:
                 host_state = _rebucket_for(qr, data.get("layout"),
                                            data["state"])
                 restored = _device_state(qr, host_state)
-                qr.state = qr.place_state(restored) \
-                    if hasattr(qr, "place_state") else restored
+                qr.state = qr.place_state(restored)
                 alloc = _allocator_of(qr)
                 if data["slots"] is not None and alloc is not None:
                     alloc.restore(data["slots"])
-                alloc2 = getattr(qr.planned, "slot_allocator2", None)
+                alloc2 = qr.planned.slot_allocator2
                 if data.get("slots2") is not None and alloc2 is not None:
                     alloc2.restore(data["slots2"])
-                jk = getattr(qr.planned, "join_key_allocator", None)
+                jk = qr.planned.join_key_allocator
                 if data.get("slots_jk") is not None and jk is not None:
                     jk.restore(data["slots_jk"])
                 pairs = data.get("slots_pairs")
                 if pairs:
-                    for (a, _), snap in zip(
-                            getattr(qr.planned, "pair_allocs", []), pairs):
+                    for (a, _), snap in zip(qr.planned.pair_allocs, pairs):
                         a.restore(snap)
-                if hasattr(qr, "_after_restore"):
-                    qr._after_restore(host_state)
+                qr._after_restore(host_state)
                 # re-arm pending timers (absent deadlines, window expiry):
                 # the scheduler of this fresh runtime knows nothing of the
                 # wakeups the snapshotted state still expects
                 w = data.get("wake")
-                if w is not None and hasattr(qr, "_apply_wake"):
+                if w is not None:
                     qr._apply_wake(int(w))
             self._restore_shared(payload)
 

@@ -129,6 +129,12 @@ class WindowProcessor:
     # sharded keyed path excludes them: a RESET resets ALL selector slots
     # on whichever device sees it, violating the single-writer merge
     emits_reset = False
+    # True for cron-style windows: their flushes are scheduled on the
+    # host clock (`host_next_wakeup`), not by the step's wake scalar
+    host_scheduled = False
+    # session(gap, key): position of the key column the planner vmaps the
+    # processor over; None = no key axis
+    session_key_pos = None
 
     def __init__(self, schema: ev.Schema, params: List[Constant],
                  batch_capacity: int, capacity_hint: int = 1024):

@@ -140,9 +140,7 @@ def _selector_node(sel, planned) -> Dict:
                                 for o in sel.order_by_list]
         if sel.limit is not None:
             node["limit"] = sel.limit
-    out = getattr(planned, "out_schema", None)
-    if out is not None:
-        node["out_columns"] = list(out.names)
+    node["out_columns"] = list(planned.out_schema.names)
     return node
 
 
@@ -283,17 +281,16 @@ def _steps_of(qr, kind: str) -> List[Tuple[str, Any]]:
             steps.append(("step[right]", p.step_right))
     else:
         steps.append(("step", p.step))
-    for (fkind, _), (body, fn) in getattr(qr, "_fused_cache", {}).items():
+    for (fkind, _), (body, fn) in qr._fused_cache.items():
         steps.append((f"fused_step[{fkind}]", fn))
-    mg = getattr(qr, "_merged", None)
+    mg = qr._merged
     if mg is not None:
         # the program a merged member ACTUALLY dispatches through
         # (optimizer/mqo.py); costs appear once it has traced — the
         # audit gate pins merging via the `merge` fact instead, so this
         # traced-only entry can never make fingerprints nondeterministic
         steps.append(("merged_step", mg._step))
-        for (fkind, _), (body, fn) in \
-                getattr(mg, "_fused_cache", {}).items():
+        for (fkind, _), (body, fn) in mg._fused_cache.items():
             steps.append((f"merged_fused_step[{fkind}]", fn))
     return steps
 
@@ -305,8 +302,8 @@ def compiled_steps(qr) -> List[Tuple[str, Any, Any]]:
     reproduces what ran) or None when it has not run yet.  Served as
     `SiddhiAppRuntime.compiled_steps` — what a script outside the
     package walks instead of the planner's attributes."""
-    steps = _steps_of(qr, _runtime_kind(qr))
-    ring = qr.__dict__.get("_serve_ring")
+    steps = _steps_of(qr, qr._kind)
+    ring = qr._serve_ring
     if ring is not None:
         steps = steps + ring.programs()
     return [(role, fn,
@@ -317,18 +314,6 @@ def compiled_steps(qr) -> List[Tuple[str, Any, Any]]:
 # ---------------------------------------------------------------------------
 # the report
 # ---------------------------------------------------------------------------
-
-def _runtime_kind(qr) -> str:
-    kind = getattr(qr, "_kind", None)   # set at wiring (runtime._maybe_fuse)
-    if kind in ("plain", "pattern", "join"):
-        return kind
-    p = qr.planned
-    if isinstance(getattr(p, "steps", None), dict):
-        return "pattern"
-    if hasattr(p, "step_left"):
-        return "join"
-    return "plain"
-
 
 def _fusion_node(qr, kind: str) -> Dict:
     from ..core import fusion as _fusion
@@ -363,15 +348,13 @@ def _emission_node(qr, kind: str) -> Dict:
     from ..core.plan_facts import render_cap
     p = qr.planned
     node: Dict[str, Any] = {}
-    cap = getattr(p, "compact_rows", None)
-    if cap is not None:
-        node["cap_rows"] = render_cap(cap)
-        node["cap_explicit"] = bool(getattr(p, "emit_explicit", True))
-    bc = getattr(p, "batch_capacity", None)
-    if bc is not None:
-        node["batch_capacity"] = int(bc)
+    if p.compact_rows is not None:
+        node["cap_rows"] = render_cap(p.compact_rows)
+        node["cap_explicit"] = bool(p.emit_explicit)
     if kind == "pattern":
         node["per_key"] = True
+    else:
+        node["batch_capacity"] = int(p.batch_capacity)
     return node
 
 
@@ -380,7 +363,7 @@ def _serving_node(rt, qr) -> Dict:
     routes this query's emissions through an on-device ring, the live
     ring occupancy/overflow counters once traffic has flowed, and the
     exclusion reason when the planner keeps delivery inline."""
-    enabled = bool(getattr(qr, "serve_emit", False))
+    enabled = bool(qr.serve_emit)
     node: Dict[str, Any] = {"enabled": enabled}
     if not enabled:
         return node
@@ -390,14 +373,14 @@ def _serving_node(rt, qr) -> Dict:
             serving_config(rt)["drain_interval_ms"]
     except Exception:  # noqa: BLE001 — diagnostics must not throw
         pass
-    if getattr(qr.planned, "needs_timer", False):
+    if qr.planned.needs_timer:
         # same exclusion as @pipeline: timer-bearing queries deliver
         # inline so wake scheduling stays synchronous
         node["active"] = False
         node["excluded"] = "needs_timer"
         return node
     node["active"] = True
-    ring = qr.__dict__.get("_serve_ring")
+    ring = qr._serve_ring
     if ring is not None:
         try:
             node["ring"] = ring.facts()
@@ -454,7 +437,7 @@ def _tree_for(qr, kind: str) -> Dict:
     from ..query_api.query import (JoinInputStream, SingleInputStream,
                                    StateInputStream)
     p = qr.planned
-    ast = getattr(qr, "_query_ast", None)
+    ast = qr._query_ast
     tree: Dict[str, Any] = {"kind": kind}
     ist = getattr(ast, "input_stream", None) if ast is not None else None
     if isinstance(ist, StateInputStream):
@@ -463,8 +446,8 @@ def _tree_for(qr, kind: str) -> Dict:
             "within_ms": ist.within_time,
             "states": _state_node(ist.state_element),
         }
-        tree["key_capacity"] = getattr(p, "key_capacity", None)
-        tree["nfa_slots"] = getattr(p, "slots", None)
+        tree["key_capacity"] = p.key_capacity
+        tree["nfa_slots"] = p.slots
     elif isinstance(ist, JoinInputStream):
         sides = {}
         for label, sis in (("left", ist.left_input_stream),
@@ -482,18 +465,17 @@ def _tree_for(qr, kind: str) -> Dict:
                          "handlers": _handler_nodes(ist)}
     else:
         tree["input"] = {"stream": getattr(p, "input_stream_id", "?")}
-    w = getattr(p, "window", None)
-    if w is not None:
+    if kind == "plain":
         tree["window_processor"] = {
-            "class": type(w).__name__,
-            "needs_timer": bool(getattr(w, "needs_timer", False)),
-            "keyed": bool(getattr(p, "keyed_window", False)),
+            "class": type(p.window).__name__,
+            "needs_timer": bool(p.window.needs_timer),
+            "keyed": bool(p.keyed_window),
         }
     sel = getattr(ast, "selector", None) if ast is not None else None
     tree["select"] = _selector_node(sel, p)
     tree["output"] = {
-        "target": getattr(p, "output_target", "") or "(return)",
-        "event_type": getattr(p, "output_event_type", "ALL_EVENTS"),
+        "target": p.output_target or "(return)",
+        "event_type": p.output_event_type,
     }
     return tree
 
@@ -506,7 +488,7 @@ def explain_query(rt, query_name: str, deep: bool = True) -> Dict:
     if qr is None:
         raise KeyError(f"no query named {query_name!r} "
                        f"(queries: {sorted(rt.query_runtimes)})")
-    kind = _runtime_kind(qr)
+    kind = qr._kind
     cache = rt.__dict__.setdefault("_explain_cost_cache", {})
     # canonical no-traffic signatures (analysis/signatures.py): steps
     # that have never traced still get cost analysis, marked

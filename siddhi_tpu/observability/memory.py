@@ -58,7 +58,7 @@ def _kind_components(qr) -> Dict[str, int]:
     int64 `b64` blob a host snapshot holds), and the join's
     (left window, right window, selector...) tuple; anything that doesn't
     match falls back to positional names so the total always adds up."""
-    mg = getattr(qr, "_merged", None)
+    mg = qr._merged
     if mg is not None:
         # merged member (optimizer/mqo.py): report only this query's
         # EXCLUSIVE bytes — the shared window buffer is accounted ONCE,
@@ -68,9 +68,9 @@ def _kind_components(qr) -> Dict[str, int]:
     state = qr.state
     p = qr.planned
     names = None
-    if hasattr(p, "steps") and isinstance(getattr(p, "steps", None), dict):
+    if qr._kind == "pattern":
         names = ("pattern_slots", "selector")
-    elif hasattr(p, "step_left"):
+    elif qr._kind == "join":
         names = ("window_left", "window_right", "selector")
     elif isinstance(state, tuple) and len(state) == 2:
         names = ("window", "selector")
@@ -83,7 +83,7 @@ def _kind_components(qr) -> Dict[str, int]:
     else:
         out["state"] = tree_nbytes(state)
     # @fuse stack buffers hold K-1 staged host batches awaiting dispatch
-    fb = getattr(qr, "_fuse", None)
+    fb = qr._fuse
     if fb is not None and fb.items:
         total = 0
         for args in fb.items:
@@ -98,7 +98,7 @@ def _kind_components(qr) -> Dict[str, int]:
     # serving emission ring (serving/ring.py): device-resident output
     # slots awaiting the async drainer — metadata-only walk of the
     # ring's generation buffers
-    ring = qr.__dict__.get("_serve_ring")
+    ring = qr._serve_ring
     if ring is not None:
         try:
             total = sum(tree_nbytes(s) for s in ring.state_leaves())

@@ -478,40 +478,40 @@ def collect(rt) -> None:
 
 def _collect_query(obs: StateObservatory, qname: str, qr) -> None:
     p = qr.planned
-    wk = getattr(p, "window_key_allocator", None)
+    wk = p.window_key_allocator
     if wk is not None:
         obs.observe(qname, "window_keys", len(wk), wk.capacity,
                     growable=False, config_key="@capacity(keys='N')")
-    ga = getattr(p, "slot_allocator", None)
-    if ga is not None and getattr(qr, "slot_allocator", None) is not ga:
+    ga = p.slot_allocator
+    if ga is not None and qr.slot_allocator is not ga:
         obs.observe(qname, "group_slots", len(ga), ga.capacity,
                     growable=False, config_key="@capacity(groups='N')")
-    pairs = getattr(p, "pair_allocs", None) or ()
+    pairs = p.pair_allocs
     if pairs:
         obs.observe(qname, "pair_slots",
                     max(len(a) for a, _ in pairs),
                     max(a.capacity for a, _ in pairs),
                     growable=False, config_key="@capacity(groups='N')")
     # pattern slab allocator lives on the runtime, not the plan
-    pa = getattr(qr, "slot_allocator", None)
+    pa = qr.slot_allocator
     if pa is not None:
         obs.observe(qname, "pattern_keys", len(pa), pa.capacity,
                     growable=False, config_key="@capacity(keys='N')")
-    jk_alloc = getattr(p, "join_key_allocator", None)
+    jk_alloc = p.join_key_allocator
     if jk_alloc is not None:
         obs.observe(qname, "join_keys", len(jk_alloc), jk_alloc.capacity,
                     growable=False, config_key="@capacity(keys='N')")
-    jk = getattr(qr, "_jk", None)
+    jk = qr._jk
     if jk is not None:
         obs.observe(qname, "join_lane", jk.needed_k(),
-                    getattr(p, "lane_k", 0) or 0, growable=True,
+                    p.lane_k, growable=True,
                     config_key="auto (lane grows via replan)")
-    cap = getattr(p, "compact_rows", None)
+    cap = p.compact_rows
     if cap is not None:
         obs.observe(qname, "emission_cap", None, cap,
-                    growable=not getattr(p, "emit_explicit", True),
+                    growable=not p.emit_explicit,
                     config_key="@emit(rows='N')")
-    ring = qr.__dict__.get("_serve_ring")
+    ring = qr._serve_ring
     if ring is not None:
         obs.observe(qname, "serve_ring", ring.occupancy(), ring.capacity,
                     growable=True, config_key="serving.ring.capacity")
@@ -575,35 +575,34 @@ def arm_fill_probe(qr) -> None:
     dispatch-only).  No-op when the state holds no Buffer windows
     (keyed slabs mirror through their allocator instead)."""
     rt = qr.app
-    if qr.__dict__.get("_stateobs_probe_off"):
+    if qr._stateobs_probe_off:
         return
     if not obs_enabled(rt):
         return
     every = obs_sample_every(rt)
     if every <= 0:
         return
-    n = qr.__dict__.get("_stateobs_tick", 0) + 1
-    qr.__dict__["_stateobs_tick"] = n
+    n = qr._stateobs_tick = qr._stateobs_tick + 1
     if n % every:
         return
     leaves = _alive_leaves(qr.state)
     if not leaves:
         # no Buffer windows in this state shape — never will be; stop
         # walking the pytree on every Nth dispatch
-        qr.__dict__["_stateobs_probe_off"] = True
+        qr._stateobs_probe_off = True
         return
     try:
-        qr.__dict__["_stateobs_probe"] = _probe_fn()(leaves)
-        qr.__dict__["_stateobs_probe_caps"] = \
-            [int(np.prod(a.shape)) for a in leaves]
+        qr._stateobs_probe = _probe_fn()(leaves)
+        qr._stateobs_probe_caps = [int(np.prod(a.shape)) for a in leaves]
     except Exception:  # noqa: BLE001 — observability must not throw
-        qr.__dict__.pop("_stateobs_probe", None)
+        qr._stateobs_probe = None
 
 
 def take_fill_probe(qr):
     """Pop the pending lazy fill vector (or None) — the delivery path
     appends it to its existing fetch tuple."""
-    return qr.__dict__.pop("_stateobs_probe", None)
+    probe, qr._stateobs_probe = qr._stateobs_probe, None
+    return probe
 
 
 def record_fill(qr, fills) -> None:
@@ -612,7 +611,7 @@ def record_fill(qr, fills) -> None:
     row capacity from shape metadata)."""
     if fills is None:
         return
-    caps = qr.__dict__.get("_stateobs_probe_caps") or []
+    caps = qr._stateobs_probe_caps or []
     try:
         per_buffer = np.asarray(fills)
         if any(int(f) >= c for f, c in zip(per_buffer, caps)):

@@ -40,7 +40,6 @@ co-members at dispatch granularity, not mid-batch.
 from __future__ import annotations
 
 import logging
-import threading
 import time
 from typing import Dict, List, Tuple
 
@@ -48,6 +47,7 @@ import jax
 
 from ..core import event as ev
 from ..core import plan_facts
+from ..core.runtime import _QueryRuntimeBase
 from ..core.steputil import jit_step
 from ..core.window import NO_WAKEUP
 from ..observability import phases as _phases
@@ -70,30 +70,33 @@ def merge_enabled(rt) -> bool:
     return str(v).strip().lower() not in ("false", "0", "off", "no")
 
 
-class MergedGroupRuntime:
+class MergedGroupRuntime(_QueryRuntimeBase):
     """One merge group's host wrapper: stages each batch once, runs the
     stacked member bodies as ONE jitted step, and demuxes per-query
     emissions.  Subscribes to the junction in place of its members;
     members stay in `rt.query_runtimes` (snapshots, callbacks, metrics,
     EXPLAIN all keep addressing them by name) and read/write their state
-    through `member_state`/`set_member_state` views."""
+    through `member_state`/`set_member_state` views.  It runs no plan of
+    its own (`planned` is None) and emits nothing itself: of the base it
+    uses the lock, the @fuse stack and the dispatcher's ingest stamp."""
+
+    _kind = "merged"
 
     def __init__(self, rt, gmeta: Dict,
                  members: List[Tuple[str, object]],
                  units: List[Tuple[str, List[int]]]):
-        self.app = rt
+        super().__init__(None, rt)
         self.group = gmeta["group"]
         self.stream_id = gmeta["stream"]
-        self.name = f"merged:{self.group}"
         self.members = [qr for _, qr in members]
         self.units = units
         self._junction = rt.junctions[self.stream_id]
         self.in_schema = self.members[0].planned.in_schema
-        # ONE lock for the group: demux re-enters member emission paths
-        # (pipeline deques, table writes), and quiesce/flush take member
-        # locks — sharing the RLock keeps every such path serialized
-        # exactly as the per-query lock did unmerged
-        self._qlock = threading.RLock()
+        # ONE lock for the group (the base's `_qlock`, handed to every
+        # member below): demux re-enters member emission paths (pipeline
+        # deques, table writes), and quiesce/flush take member locks —
+        # sharing the RLock keeps every such path serialized exactly as
+        # the per-query lock did unmerged
         # member position map: id(member) -> (unit idx, pos in unit, mode)
         self._slots: Dict[int, Tuple[int, int, str]] = {}
         state: List = []
@@ -127,17 +130,20 @@ class MergedGroupRuntime:
                               role="merged_step", donate_argnums=(0,))
         # @fuse(batches=K) on every member: the MERGED dispatch owns the
         # stack (kind 'merged' in core/fusion.py); members drop theirs
-        self._fuse = None
         k = int(gmeta.get("decorations", {}).get("fuse", 0) or 0)
         if k > 0:
             from ..core import fusion as _fusion
             for m in self.members:
-                if getattr(m, "_fuse", None) is not None:
+                if m._fuse is not None:
                     m._fuse = None
                     m._fuse_excluded = (
                         f"query dispatch is merged — {self.name} owns "
                         f"the @fuse stack")
-            self._fuse = _fusion.FuseBuffer(self, k, "merged")
+            self._fuse = _fusion.FuseBuffer(self, k, self._kind)
+
+    @property
+    def name(self):
+        return f"merged:{self.group}"
 
     # -- state views (snapshots/restore address members by name) ---------------
     def member_state(self, qr):
@@ -252,7 +258,7 @@ class MergedGroupRuntime:
                      for m in self.members)
 
     def process_staged(self, staged: ev.StagedBatch, now: int) -> None:
-        dbg = getattr(self.app, "_debugger", None)
+        dbg = self.app._debugger
         if dbg is not None:
             for m in self.members:
                 dbg.check_break_point(m.name, "IN", staged)
@@ -281,8 +287,7 @@ class MergedGroupRuntime:
             stats.counter_inc(f"merged.{self.group}.dispatches")
             stats.counter_inc(f"merged.{self.group}.member_batches",
                               len(self.members))
-        stamp = self.__dict__.get("_ingest_ns")
-        self._demux([(outs, staged, now, stamp)], t0)
+        self._demux([(outs, staged, now, self._ingest_ns)], t0)
 
     # -- demux: one combined fetch, per-query delivery -------------------------
     def _demux(self, batches: List[Tuple], t0: int) -> None:
@@ -302,10 +307,7 @@ class MergedGroupRuntime:
         from ..core import runtime as _rt
         stats = self.app.stats
         members = self.members
-        deferred = (getattr(members[0], "async_emit", False) and
-                    self.app._drainer is not None) or \
-            bool(getattr(members[0], "pipeline_emit", 0) or 0) or \
-            getattr(members[0], "serve_emit", False)
+        deferred = members[0].defers_delivery()
         consumers = [i for i, m in enumerate(members)
                      if _rt._has_consumers(m)]
         hosted: Dict[int, List] = {}
@@ -325,12 +327,12 @@ class MergedGroupRuntime:
                 td = time.perf_counter_ns() if stats.enabled else 0
                 try:
                     if i in hosted:
-                        m.__dict__["_ingest_ns"] = stamp
+                        m._ingest_ns = stamp
                         try:
                             _rt._emit_output(m, hosted[i][k], now,
                                              wake=None)
                         finally:
-                            m.__dict__["_ingest_ns"] = None
+                            m._ingest_ns = None
                 except Exception as exc:  # noqa: BLE001 — per-query fault
                     self._junction._handle_error_staged(staged, exc, now)
                 finally:
@@ -338,8 +340,8 @@ class MergedGroupRuntime:
                         stats.query_latency(
                             m.name, staged.n,
                             share + time.perf_counter_ns() - td)
-                        if m.__dict__.pop("_e2e_owed", False) and \
-                                stamp is not None:
+                        owed, m._e2e_owed = m._e2e_owed, False
+                        if owed and stamp is not None:
                             stats.e2e_latency(
                                 m.name,
                                 time.perf_counter_ns() - stamp)
@@ -371,13 +373,13 @@ def apply_merge(rt) -> None:
         members: List[Tuple[str, object]] = []
         for name in g["members"]:
             qr = rt.query_runtimes.get(name)
-            p = getattr(qr, "planned", None)
-            ok = (isinstance(qr, _rt.QueryRuntime) and p is not None
-                  and getattr(p, "raw_step", None) is not None
-                  and getattr(p, "stage_body", None) is not None
-                  and not getattr(p, "needs_timer", False)
-                  and not getattr(p, "keyed_window", False)
-                  and getattr(p, "partition_key_fn", None) is None
+            p = qr.planned if isinstance(qr, _rt.QueryRuntime) else None
+            ok = (p is not None
+                  and p.raw_step is not None
+                  and p.stage_body is not None
+                  and not p.needs_timer
+                  and not p.keyed_window
+                  and p.partition_key_fn is None
                   and junction is not None and qr in junction.queries)
             if ok:
                 members.append((name, qr))

@@ -66,17 +66,16 @@ def serving_config(rt) -> dict:
 def ensure_ring(qr) -> EmissionRing:
     """The query's emission ring, created on first serving emission and
     registered with the app drainer (which lazy-starts its thread)."""
-    ring = qr.__dict__.get("_serve_ring")
+    ring = qr._serve_ring
     if ring is None:
         app = qr.app
         cfg = serving_config(app)
         # @serve(ring.capacity=) stashed at wiring time (runtime.py sets
         # `serve_ring_capacity` next to `serve_emit`); 0 = use config
-        cap = int(getattr(qr, "serve_ring_capacity", 0) or 0)
         drainer = app._serve_drainer
-        ring = EmissionRing(qr, capacity=cap or cfg["ring_capacity"],
-                            on_highwater=drainer.kick)
-        qr.__dict__["_serve_ring"] = ring
+        ring = qr._serve_ring = EmissionRing(
+            qr, capacity=qr.serve_ring_capacity or cfg["ring_capacity"],
+            on_highwater=drainer.kick)
         drainer.register(ring)
     return ring
 

@@ -192,8 +192,8 @@ class ServingDrainer:
                 # same fault routing as _EmissionDrainer._run: overflow
                 # and callback failures reach the exception listener
                 log.error("serving drain error in %s: %s",
-                          getattr(qr, "name", "?"), exc)
-                listener = getattr(qr.app, "exception_listener", None)
+                          qr.name, exc)
+                listener = qr.app.exception_listener
                 if listener is not None:
                     try:
                         listener(exc)

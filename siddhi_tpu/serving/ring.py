@@ -243,7 +243,7 @@ class EmissionRing:
         grow-via-replan pattern) or block as bounded backpressure until
         the drainer frees a slot.  Called with the cond lock held."""
         new_cap = min(self.capacity * 2, RING_CAP_MAX)
-        adm = getattr(self.qr.app, "admission", None)
+        adm = self.qr.app.admission
         grown = False
         if new_cap > self.capacity and (
                 adm is None or adm.admit_growth(

@@ -60,7 +60,7 @@ class DoubleBufferedStager:
         correctness dependency — but the lost overlap is counted
         (`facts()["fallback_total"]`) and logged, so a measurement can
         refuse to run on the downgraded path."""
-        if getattr(staged, "dev", None) is not None:
+        if staged.dev is not None:
             return
         try:
             from ..core.event import EventBatch

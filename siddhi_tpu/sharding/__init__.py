@@ -16,7 +16,7 @@ ingest path (sync, @async, @pipeline, @fuse) across the mesh with output
 byte-identical to the unsharded runtime.
 """
 from .router import (ShardRouter, group_router_for,  # noqa: F401
-                     keyed_mesh_of, mesh_of, router_for, shard_count)
+                     router_for, shard_count)
 from .snapshot import (needs_rebucket, query_layout,  # noqa: F401
                        rebucket_rows, rebucket_selector, rebucket_state)
 from .metrics import (explain_node, shard_report,  # noqa: F401

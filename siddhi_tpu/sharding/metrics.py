@@ -174,11 +174,11 @@ def explain_node(qr, kind: str, deep: bool = False) -> Optional[Dict]:
     collectives its compiled step carries."""
     from .snapshot import query_layout
     p = qr.planned
-    mesh = getattr(p, "mesh", None) or getattr(p, "keyed_mesh", None)
+    mesh = p.mesh or p.keyed_mesh
     n = shard_count(mesh) if mesh is not None else 1
     if n < 2:
         # GSPMD-placed joins have no key router but ARE sharded
-        if kind != "join" or shard_count(getattr(qr.app, "mesh", None)) < 2:
+        if kind != "join" or shard_count(qr.app.mesh) < 2:
             return None
         n = shard_count(qr.app.mesh)
     node: Dict[str, Any] = {

@@ -120,21 +120,10 @@ class ShardRouter:
 
 # ---------------------------------------------------------------------------
 # resolved accessors: the ONE place that maps a query runtime onto its
-# mesh / key layout (consolidates the former getattr(.., "mesh"/
-# "keyed_mesh", None) call sites across runtime/purger/aggregation)
+# key layout.  The meshes are the plan's own fields (`mesh`: plain /
+# pattern shard mesh, `keyed_mesh`: keyed-window slab), the ones the step
+# functions were built from; every plan declares both.
 # ---------------------------------------------------------------------------
-
-def mesh_of(qr):
-    """The plain/pattern shard mesh a query runtime executes under, or
-    None (reads the compiled plan — the same field the step functions
-    were built from)."""
-    return getattr(getattr(qr, "planned", qr), "mesh", None)
-
-
-def keyed_mesh_of(qr):
-    """The keyed-window shard mesh, or None."""
-    return getattr(getattr(qr, "planned", qr), "keyed_mesh", None)
-
 
 def shard_count(obj) -> int:
     """Devices in an app runtime's / mesh's shard axis (1 = unsharded)."""
@@ -150,19 +139,15 @@ def router_for(qr) -> Optional[ShardRouter]:
     when the query's state carries no sharded key axis (single-device
     plans, joins — whose buffers ride GSPMD row sharding with no key
     layout)."""
-    p = getattr(qr, "planned", None)
-    if p is None:
-        return None
-    mesh = mesh_of(qr)
-    if isinstance(getattr(p, "steps", None), dict):     # pattern plan
-        if not getattr(p, "partition_positions", None) or mesh is None:
+    p = qr.planned
+    if qr._kind == "pattern":
+        if not p.partition_positions or p.mesh is None:
             return None
-        return ShardRouter(shard_count(mesh), int(p.key_capacity))
-    kmesh = keyed_mesh_of(qr)
-    if kmesh is not None and getattr(p, "keyed_window", False):
-        return ShardRouter(shard_count(kmesh), int(p.key_capacity))
-    if mesh is not None and getattr(p, "slot_allocator", None) is not None:
-        return ShardRouter(shard_count(mesh),
+        return ShardRouter(shard_count(p.mesh), int(p.key_capacity))
+    if p.keyed_mesh is not None and p.keyed_window:
+        return ShardRouter(shard_count(p.keyed_mesh), int(p.key_capacity))
+    if p.mesh is not None and p.slot_allocator is not None:
+        return ShardRouter(shard_count(p.mesh),
                            int(p.slot_allocator.capacity))
     return None
 
@@ -173,13 +158,10 @@ def group_router_for(qr) -> Optional[ShardRouter]:
     are replicated — distinct from router_for, which resolves the KEY
     space (a keyed-window query has both: a sharded key slab and
     replicated selector state)."""
-    p = getattr(qr, "planned", None)
-    mesh = mesh_of(qr)
-    if p is None or mesh is None or \
-            isinstance(getattr(p, "steps", None), dict) or \
-            getattr(p, "slot_allocator", None) is None:
+    p = qr.planned
+    if p.mesh is None or qr._kind == "pattern" or p.slot_allocator is None:
         return None
-    return ShardRouter(shard_count(mesh), int(p.slot_allocator.capacity))
+    return ShardRouter(shard_count(p.mesh), int(p.slot_allocator.capacity))
 
 
 def split_columns(cols: Sequence[np.ndarray], shard: np.ndarray,

@@ -42,7 +42,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from .router import ShardRouter, keyed_mesh_of, mesh_of, shard_count
+from .router import ShardRouter, shard_count
 
 
 def query_layout(qr) -> Optional[Dict[str, Any]]:
@@ -50,24 +50,22 @@ def query_layout(qr) -> Optional[Dict[str, Any]]:
     {'kind': 'pattern'|'plain'|'keyed', 'n': shards, 'capacity': rows},
     or None when the state has no key-ordered axis (single-key patterns,
     joins, unkeyed plain queries)."""
-    p = getattr(qr, "planned", None)
-    if p is None:
-        return None
-    if isinstance(getattr(p, "steps", None), dict):     # pattern plan
-        if not getattr(p, "partition_positions", None):
+    p = qr.planned
+    if qr._kind == "pattern":
+        if not p.partition_positions:
             return None
-        return {"kind": "pattern", "n": shard_count(mesh_of(qr)),
+        return {"kind": "pattern", "n": shard_count(p.mesh),
                 "capacity": int(p.key_capacity)}
-    if hasattr(p, "step_left"):                          # join plan
+    if qr._kind == "join":
         return None
-    if getattr(p, "keyed_window", False):
-        return {"kind": "keyed", "n": shard_count(keyed_mesh_of(qr)),
+    if p.keyed_window:
+        return {"kind": "keyed", "n": shard_count(p.keyed_mesh),
                 "capacity": int(p.key_capacity)}
-    if getattr(p, "slot_allocator", None) is not None:
+    if p.slot_allocator is not None:
         # n=1 for unsharded group-bys: the identity layout — recorded so
         # a snapshot from a SHARDED runtime re-buckets when restoring
         # onto an unsharded one (and vice versa)
-        return {"kind": "plain", "n": shard_count(mesh_of(qr)),
+        return {"kind": "plain", "n": shard_count(p.mesh),
                 "capacity": int(p.slot_allocator.capacity)}
     return None
 
@@ -98,7 +96,7 @@ def _take(arr, src: np.ndarray, axis: int):
 
 
 def _sel_specs(planned):
-    sel = getattr(planned, "selector_exec", None)
+    sel = planned.selector_exec
     bank = getattr(sel, "bank", None)
     return getattr(bank, "specs", None)
 

@@ -178,14 +178,14 @@ class StatisticsManager:
         # of the base step
         owners += [f"fused:{q}" for q, qr in
                    getattr(app, "query_runtimes", {}).items()
-                   if getattr(qr, "_fuse", None) is not None]
+                   if qr._fuse is not None]
         # merged-group dispatchers (optimizer/mqo.py) compile their own
         # program: `merged:<group>` (+ `fused:merged:<group>` when the
         # group rides a @fuse stack) so recompile blame and the compile
         # gate attribute a merged trace to the group, not to nobody
         for gid, mg in getattr(app, "merged_groups", {}).items():
             owners.append(f"merged:{gid}")
-            if getattr(mg, "_fuse", None) is not None:
+            if mg._fuse is not None:
                 owners.append(f"fused:merged:{gid}")
         owners += [f"table:{t}" for t in getattr(app, "tables", ())]
         owners += [f"window:{w}" for w in getattr(app, "named_windows", ())]

@@ -270,16 +270,20 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # (`jax_compilation_cache_include_metadata_in_key`): a digest that moves is
 # a one-time compile-cache miss in that configuration's cells.  A PR that
 # moves a traced line on purpose pays it, says so, and re-pins: PR 44 did,
-# for `lengthbatch_1000` (`LengthBatchWindow.process`' kept buffers).
+# for `lengthbatch_1000` (`LengthBatchWindow.process`' kept buffers); PR 45
+# for all three (the runtimes' one base: `runtime.py`'s frames moved, one
+# is now `_Subscription.process_staged`, the plans' and the window's new
+# declarations shifted `pattern_planner.py` + 15 and `window.py` + 6 lines;
+# the texts WITHOUT debug info are the parent's byte for byte).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "45b8f3ac6334d8d3630449e75ed1c30ff68d4b4247f3950687f08c2eb601c189"},
+        "dba54f0447cce5591cf7f13c3e433a3aa601fab1fc4bac1c993c854a3836b906"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "5e8a882d9528ba622c2f1d3d25cdcacbf698c2e14ffa4b9aaf22862cb25057a3",
+        "bfee1be76467cf04f951867f72e937bcca06a9b78dbb316d0a1677f87027202d",
         "step[TradeStream]":
-        "c95f1d958fbf7cf1551aa9afaaf41d309d425d9b42ed6d2e043f9a28771a5013"},
+        "57f953eb3de5ce56e7bd68eb8f71a9eeaf7695e678de3166d009c4acc04981bc"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)
