@@ -6,9 +6,10 @@ time; batching depth is a TPU-native concern.
 
 TPU design (how): at small batches the engine is bound by per-send
 fixed costs, not by device work — each send pays host dispatch, an H2D
-submit and a blocking emission fetch whatever its size (shares not
-measured on the current chip).  Fused stepping stacks K staged
-micro-batches into [K, B]
+submit and a blocking emission fetch whatever its size (the un-fused
+shares on the v5e: PERF.md sections 5-7, `sequence_within.paced` — the
+denominator a fused cell is to be judged against; none runs `@fuse` yet).
+Fused stepping stacks K staged micro-batches into [K, B]
 host arrays, ships them in ONE transfer, and runs the compiled query
 step as a `lax.scan` over the leading axis in ONE dispatch:
 partition/window/NFA state threads through the scan carry exactly as it

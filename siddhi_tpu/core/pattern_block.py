@@ -6,7 +6,8 @@ e2=B[...]` query is a single NFA consuming the stream sequentially.
 
 TPU-native design (how): the scan path (pattern.py tick) is semantically
 complete but sequential: K=1 batches degrade to E tiny [P,1] ticks per send
-(round-4 bench: 776 ev/s on `sequence_within`).  For the COMMON simple-chain
+(what this step costs on the v5e, by section: PERF.md sections 5-7, the
+cells `sequence_within.paced` / `.saturated`).  For the COMMON simple-chain
 shape — every atom min=max=1, no logical pairs, no absent — the per-key
 advance over a block of E events is computable in S-1 *parallel stages*
 instead of E sequential ticks:
@@ -42,6 +43,7 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -63,6 +65,22 @@ def block_eligible(spec: PatternSpec) -> bool:
         if a.capture_depth != 1:
             return False
     return spec.state_type in ("PATTERN", "SEQUENCE")
+
+
+def _chunking(E: int):
+    """(W, C): a block of E events is scanned as C chunks of W."""
+    W = min(CHUNK, E)
+    return W, (E + W - 1) // W
+
+
+def block_layout(B: int, P: int) -> Dict[str, int]:
+    """What a send of B events costs the block step, as the
+    `siddhi:route_keys` span says a send's layout (`tiers`, `cells`,
+    `ticks`, `max_e`): `ticks` the chunks its `lax.scan` walks, `cells` the
+    `[T, W] = [P + W, W]` thread x event grid a stage evaluates, over all
+    of them."""
+    W, C = _chunking(B)
+    return {"tiers": 1, "cells": C * (P + W) * W, "ticks": C, "max_e": B}
 
 
 def make_block_step(spec: PatternSpec, pexec: PatternExec, sel: SelectorExec,
@@ -286,96 +304,112 @@ def make_block_step(spec: PatternSpec, pexec: PatternExec, sel: SelectorExec,
             return ncarry, (comp_valid, comp_idx, comp_ts, caps_t)
 
         # ---- unpack state, chunk the block, scan ---------------------------
+        # Every op stands under a `jax.named_scope` SECTION, the names the
+        # other pattern programs use (pattern_planner.make_step): op-name
+        # metadata a device trace books time by, no equation moves for it
         b32, lo64, hi64, scalars = packed
         B = raw_ts.shape[0]
-        csel = jnp.clip(sel_idx[0], 0, B - 1)                     # [E]
-        cols = tuple(c[csel].astype(d)
-                     for c, d in zip(raw_cols, schema.dtypes))
-        ts = raw_ts[csel]
-        valid = sel_idx[0] >= 0
-        st = packer.unpack(b32, lo64, hi64, scalars)
+        with jax.named_scope("event_load"):
+            csel = jnp.clip(sel_idx[0], 0, B - 1)                 # [E]
+            cols = tuple(c[csel].astype(d)
+                         for c, d in zip(raw_cols, schema.dtypes))
+            ts = raw_ts[csel]
+            valid = sel_idx[0] >= 0
+        with jax.named_scope("state_load"):
+            st = packer.unpack(b32, lo64, hi64, scalars)
         E = ts.shape[0]
-        W = min(CHUNK, E)
-        C = (E + W - 1) // W
+        W, C = _chunking(E)
         pad = C * W - E
         if pad:
-            cols = tuple(jnp.pad(c, (0, pad)) for c in cols)
-            ts = jnp.pad(ts, (0, pad))
-            valid = jnp.pad(valid, (0, pad))
+            with jax.named_scope("event_load"):
+                cols = tuple(jnp.pad(c, (0, pad)) for c in cols)
+                ts = jnp.pad(ts, (0, pad))
+                valid = jnp.pad(valid, (0, pad))
         T = P + W
 
         sq = lambda x: x[..., 0]                 # drop the K=1 axis
-        carry = (
-            sq(st.active), sq(st.pos), sq(st.start_ts), sq(st.entry_ts),
-            {a.ref: tuple(sq(c[:, 0]) for c in st.caps[a.ckey][1])
-             for a in atoms},
-            sq(st.seed_on), sq(st.done), st.dropped)
-        xs = (tuple(c.reshape(C, W) for c in cols), ts.reshape(C, W),
-              valid.reshape(C, W),
-              jnp.arange(C, dtype=jnp.int64) * W)
-        carry, comps = lax.scan(chunk_advance, carry, xs)
-        (factive, fpos, fstart, fentry, fcaps, fseed_on, fdone,
-         fdropped) = carry
-        if spec.within is not None:
-            factive = jnp.logical_and(factive, now - fstart <= spec.within)
+        with jax.named_scope("state_load"):
+            carry = (
+                sq(st.active), sq(st.pos), sq(st.start_ts), sq(st.entry_ts),
+                {a.ref: tuple(sq(c[:, 0]) for c in st.caps[a.ckey][1])
+                 for a in atoms},
+                sq(st.seed_on), sq(st.done), st.dropped)
+        with jax.named_scope("event_load"):
+            xs = (tuple(c.reshape(C, W) for c in cols), ts.reshape(C, W),
+                  valid.reshape(C, W),
+                  jnp.arange(C, dtype=jnp.int64) * W)
+        with jax.named_scope("nfa_advance"):
+            carry, comps = lax.scan(chunk_advance, carry, xs)
+            (factive, fpos, fstart, fentry, fcaps, fseed_on, fdone,
+             fdropped) = carry
+            if spec.within is not None:
+                factive = jnp.logical_and(factive,
+                                          now - fstart <= spec.within)
 
         # ---- write the slab back in packed form ----------------------------
         uq = lambda x: x[..., None]
-        ncapd = {}
-        for a in atoms:
-            old_ts, _old_cols = st.caps[a.ckey]
-            ncapd[a.ckey] = (old_ts, tuple(
-                uq(uq(c)) for c in fcaps[a.ref]))
-        nst = st._replace(
-            active=uq(factive), pos=uq(fpos),
-            count=jnp.zeros_like(st.count), lmask=jnp.zeros_like(st.lmask),
-            start_ts=uq(fstart), entry_ts=uq(fentry),
-            seed_on=uq(fseed_on), done=uq(fdone), dropped=fdropped,
-            caps=ncapd)
-        nb32, nlo, nhi, nscal = packer.pack(nst)
+        with jax.named_scope("state_store"):
+            ncapd = {}
+            for a in atoms:
+                old_ts, _old_cols = st.caps[a.ckey]
+                ncapd[a.ckey] = (old_ts, tuple(
+                    uq(uq(c)) for c in fcaps[a.ref]))
+            nst = st._replace(
+                active=uq(factive), pos=uq(fpos),
+                count=jnp.zeros_like(st.count),
+                lmask=jnp.zeros_like(st.lmask),
+                start_ts=uq(fstart), entry_ts=uq(fentry),
+                seed_on=uq(fseed_on), done=uq(fdone), dropped=fdropped,
+                caps=ncapd)
+            nb32, nlo, nhi, nscal = packer.pack(nst)
 
         # ---- emission: order completions by arrival, run the selector ------
         comp_valid, comp_idx, comp_ts, caps_stack = comps    # [C,T] / nested
         CT = C * T
-        thread_rank = jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int64)[None, :], (C, T))
-        key = jnp.where(comp_valid,
-                        comp_idx * (T + 1) + thread_rank,
-                        jnp.asarray(BIG, jnp.int64)).reshape(CT)
-        order = jnp.argsort(key)
-        o_valid = comp_valid.reshape(CT)[order]
-        o_ts = comp_ts.reshape(CT)[order]
+        with jax.named_scope("match_rows"):
+            thread_rank = jnp.broadcast_to(
+                jnp.arange(T, dtype=jnp.int64)[None, :], (C, T))
+            key = jnp.where(comp_valid,
+                            comp_idx * (T + 1) + thread_rank,
+                            jnp.asarray(BIG, jnp.int64)).reshape(CT)
+            order = jnp.argsort(key)
+            o_valid = comp_valid.reshape(CT)[order]
+            o_ts = comp_ts.reshape(CT)[order]
 
-        env: Dict[str, Any] = {"__ts__": o_ts, "__now__": now}
-        for a in atoms:
-            if emit_refs is not None and a.ref not in emit_refs:
-                continue
-            ocols = tuple(c.reshape(CT)[order]
-                          for c in caps_stack[a.ref])
-            bind(env, a.ref, ocols)
-        rows = Rows(
-            ts=o_ts,
-            kind=jnp.full((CT,), ev.CURRENT, jnp.int32),
-            valid=o_valid,
-            seq=jnp.arange(CT, dtype=jnp.int64),
-            gslot=jnp.zeros((CT,), jnp.int32),
-            cols=(),
-        )
-        sel_state, out = sel.process(sel_state, rows, env)
+            env: Dict[str, Any] = {"__ts__": o_ts, "__now__": now}
+            for a in atoms:
+                if emit_refs is not None and a.ref not in emit_refs:
+                    continue
+                ocols = tuple(c.reshape(CT)[order]
+                              for c in caps_stack[a.ref])
+                bind(env, a.ref, ocols)
+            rows = Rows(
+                ts=o_ts,
+                kind=jnp.full((CT,), ev.CURRENT, jnp.int32),
+                valid=o_valid,
+                seq=jnp.arange(CT, dtype=jnp.int64),
+                gslot=jnp.zeros((CT,), jnp.int32),
+                cols=(),
+            )
+        with jax.named_scope("selector"):
+            sel_state, out = sel.process(sel_state, rows, env)
         ots, okind, ovalid, ocols2 = out
         R = min(compact_rows, CT)
-        if R < CT:
-            # rows are arrival-ordered; valid rows beyond the @emit cap drop
-            rankv = jnp.cumsum(ovalid.astype(jnp.int32)) - 1
-            keep = jnp.logical_and(ovalid, rankv < R)
-            n_valid = jnp.sum(keep.astype(jnp.int64))
-            n_dropped = jnp.sum(ovalid.astype(jnp.int64)) - n_valid
-            out = (ots, okind, keep, ocols2)
-        else:
-            n_valid = jnp.sum(ovalid.astype(jnp.int64))
-            n_dropped = jnp.zeros((), jnp.int64)
-        out = (n_valid, n_dropped) + out
-        wake = jnp.asarray(NO_WAKEUP, jnp.int64)
+        with jax.named_scope("emission_compaction"):
+            if R < CT:
+                # rows are arrival-ordered; valid rows beyond the @emit cap
+                # drop
+                rankv = jnp.cumsum(ovalid.astype(jnp.int32)) - 1
+                keep = jnp.logical_and(ovalid, rankv < R)
+                n_valid = jnp.sum(keep.astype(jnp.int64))
+                n_dropped = jnp.sum(ovalid.astype(jnp.int64)) - n_valid
+                out = (ots, okind, keep, ocols2)
+            else:
+                n_valid = jnp.sum(ovalid.astype(jnp.int64))
+                n_dropped = jnp.zeros((), jnp.int64)
+            out = (n_valid, n_dropped) + out
+        with jax.named_scope("match_rows"):
+            wake = jnp.asarray(NO_WAKEUP, jnp.int64)
         return (nb32, nlo, nhi, nscal), sel_state, out, wake
 
     return step

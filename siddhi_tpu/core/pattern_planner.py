@@ -24,7 +24,7 @@ from . import plan_facts
 from . import state_rows
 from .executor import CompileError
 from .pattern import PatternExec, PatternSpec, linearize, oh_take
-from .pattern_block import block_eligible, make_block_step
+from .pattern_block import block_eligible, block_layout, make_block_step
 from .selector import SelectorExec
 from .window import NO_WAKEUP, Rows
 from .steputil import jit_step, pmin_i64
@@ -263,6 +263,10 @@ class PlannedPatternQuery:
     # False when the per-key emission cap is an implicit default: overflow
     # then raises instead of dropping rows (@emit(rows=N) opts into capping)
     emit_explicit: bool = True
+    # (B) -> {tiers, cells, ticks, max_e}: what a send of B events costs a
+    # step whose layout no key grouping decides (the block step), for the
+    # `route_keys` span; None where the host lays the send out per send
+    send_layout: Optional[Callable] = None
     # range partitions: stream_id -> host fn(staged) -> (key_cols, valid)
     # overriding positional key extraction (reference:
     # RangePartitionExecutor.java:45)
@@ -488,14 +492,16 @@ def plan_pattern_query(
     step_bodies = None
     shard_fused_steps = None
     grouped_input = False
+    send_layout = None
     if mesh is None and partition_positions is None and \
             block_eligible(spec) and not _FORCE_SCAN:
-        # single-key simple chain: the sequential E-tick scan degrades to
-        # interpreter speed (round-4: 776 ev/s); the block path advances a
-        # whole chunk in S-1 vectorized stages — see pattern_block.py
+        # single-key simple chain: the sequential scan would walk E tiny
+        # [P, 1] ticks a send; the block path advances a whole chunk in
+        # S-1 vectorized stages — see pattern_block.py
         step_bodies = {sid: make_block_step(
             spec, pexec, sel, schemas, packer, sid, compact_rows)
             for sid in spec.stream_ids}
+        send_layout = functools.partial(block_layout, P=pexec.P)
         steps = {sid: _jit_sequential(b, name, "pattern_block")
                  for sid, b in step_bodies.items()}
     elif mesh is None:
@@ -574,7 +580,7 @@ def plan_pattern_query(
         emit_explicit=emit_explicit, selector_exec=sel,
         emits_uuid=pexec.scope.uses_uuid,
         compact_rows=compact_rows, step_bodies=step_bodies,
-        shard_fused_steps=shard_fused_steps)
+        shard_fused_steps=shard_fused_steps, send_layout=send_layout)
 
 
 def _gathering(body):

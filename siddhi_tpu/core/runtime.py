@@ -875,6 +875,8 @@ class PatternQueryRuntime(_QueryRuntimeBase):
                            for _, sel, ident in tiers]
                 sp.set_metadata(
                     grouped="view" if tiers[0][2] else "take")
+            if p.send_layout is not None:
+                sp.set_metadata(**p.send_layout(B))
         outs, now_d, moved = [], None, []
         try:
             try:

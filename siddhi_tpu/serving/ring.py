@@ -285,8 +285,8 @@ class EmissionRing:
         batched blocking fetch for everything it took).  Each item is
         (qr, out, now, ingest_ns, trace_token, ring_wait_ns)."""
         out: List[Tuple] = []
-        take_ns = time.perf_counter_ns()
         with self._cond:
+            take_ns = time.perf_counter_ns()  # under the lock: after appends
             n = len(self._meta) if max_n is None else \
                 min(max_n, len(self._meta))
             for _ in range(n):

@@ -274,16 +274,20 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # for all three (the runtimes' one base: `runtime.py`'s frames moved, one
 # is now `_Subscription.process_staged`, the plans' and the window's new
 # declarations shifted `pattern_planner.py` + 15 and `window.py` + 6 lines;
-# the texts WITHOUT debug info are the parent's byte for byte).
+# the texts WITHOUT debug info are the parent's byte for byte); PR 46 for
+# all three (the plan's `send_layout` and its two lines in
+# `PatternQueryRuntime.process_staged`, for the block step's `route_keys`
+# span: `runtime.py` + 2 from line 878, `pattern_planner.py` + 4 / + 6; the
+# texts without debug info again the parent's byte for byte).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "dba54f0447cce5591cf7f13c3e433a3aa601fab1fc4bac1c993c854a3836b906"},
+        "d7239cd88ec1e159cd3e4175a273463084b6f196ab3d7cde57dfcba7448126b1"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "bfee1be76467cf04f951867f72e937bcca06a9b78dbb316d0a1677f87027202d",
+        "0a303feb0056670bc4c1db61c6eb2a919e1e5bb52721eb96df24db81898b11c7",
         "step[TradeStream]":
-        "57f953eb3de5ce56e7bd68eb8f71a9eeaf7695e678de3166d009c4acc04981bc"},
+        "445e42d677ca567d2591fd80e8614a57365e6cb8beecb8b7d8fe1fc6fe0cff36"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)
