@@ -45,6 +45,7 @@ import numpy as np
 
 from ..observability import phases as _phases
 from . import event as ev
+from .keyslots import valid_first_sel
 from .steputil import fuse_step
 
 jnp = jax.numpy
@@ -321,17 +322,10 @@ def _prepare_pattern(qr, items) -> Tuple[Callable, Tuple, Tuple]:
     p = qr.planned
     st, k = qr.app.stats, len(items)
     stream_id = items[0][0]
-    B = items[0][1].ts.shape[0]
     with _phases.phase(st, qr.name, "stage", k):
-        sels = []
-        for _, staged, _ in items:
-            if staged.valid.all():
-                sels.append(_rt._identity_sel(B))
-            else:
-                sels.append(np.where(staged.valid,
-                                     np.arange(B, dtype=np.int32),
-                                     -1)[None, :])
-        sel_np = np.stack(sels)
+        sel_np = np.stack([
+            _rt._identity_sel(staged.valid.shape[0]) if staged.valid.all()
+            else valid_first_sel(staged.valid) for _, staged, _ in items])
         stack = ev.StackedBatch([staged for _, staged, _ in items])
     with _phases.phase(st, qr.name, "h2d", k,
                        bytes=_stack_nbytes(stack, sel_np)):

@@ -708,3 +708,16 @@ _E_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096,
 # 4 is the E of a key that brings one visit of the 4-stage flagship
 _TIER_CUTS = (4, 32)
 _TIER_MIN_CELLS = 1 << 16
+
+
+def valid_first_sel(valid: np.ndarray) -> np.ndarray:
+    """[1, B] selection of an un-partitioned send whose bucket is not full
+    (one key, so no slot to resolve): the valid rows' indices in order,
+    FIRST, then -1 — never a hole between two valid rows.  The block
+    step's linear form reads the slot after a valid event as the next
+    valid event (pattern_block.py); the scan path sees the same events in
+    the same order wherever the padding lies."""
+    sel = np.full((1, valid.shape[0]), -1, np.int32)
+    rows = np.flatnonzero(valid)
+    sel[0, :rows.shape[0]] = rows
+    return sel

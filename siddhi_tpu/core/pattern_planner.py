@@ -501,7 +501,7 @@ def plan_pattern_query(
         step_bodies = {sid: make_block_step(
             spec, pexec, sel, schemas, packer, sid, compact_rows)
             for sid in spec.stream_ids}
-        send_layout = functools.partial(block_layout, P=pexec.P)
+        send_layout = functools.partial(block_layout, P=pexec.P, spec=spec)
         steps = {sid: _jit_sequential(b, name, "pattern_block")
                  for sid, b in step_bodies.items()}
     elif mesh is None:

@@ -35,7 +35,7 @@ from ..query_api.query import Partition, Query, SingleInputStream
 from . import event as ev
 from . import state_rows
 from .executor import CompileError
-from .keyslots import SlotAllocator
+from .keyslots import SlotAllocator, valid_first_sel
 from .pattern_planner import (HEAD_DTYPES, BandedEmission, StatePacker,
                               unpack_planes)
 from .planner import PlannedQuery, plan_single_query
@@ -866,9 +866,9 @@ class PatternQueryRuntime(_QueryRuntimeBase):
                 # capacity — cached read-only so repeat sends dedupe
                 tiers, nuniq = [(None, _identity_sel(B), True)], [0]
             else:
-                tiers, nuniq = [(None, np.where(
-                    staged.valid, np.arange(B, dtype=np.int32),
-                    -1)[None, :], False)], [0]
+                # the valid rows first, no hole between two of them
+                tiers, nuniq = [(None, valid_first_sel(staged.valid),
+                                 False)], [0]
             grouped = [(staged.cols, ts_delta)] * len(tiers)
             if p.grouped_input:
                 grouped = [_group_columns(sel, ident, staged.cols, ts_delta)

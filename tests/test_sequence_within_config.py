@@ -15,6 +15,7 @@ import contextlib
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import numpy as np
@@ -58,9 +59,14 @@ MODEL = _load(os.path.join(CFG_DIR, "model.py"),
               "bench_model_sequence_within_t1")
 
 
-def app_text(sizes, statistics=False):
+def app_text(sizes, statistics=False, chain="sequence"):
+    """The configuration's app; `chain="pattern"`: the same app with `->`
+    for the `,` — a PATTERN chain, which keeps the block step's grid."""
     with open(os.path.join(CFG_DIR, "app.siddhi")) as fh:
         text = fh.read().format(**sizes)
+    if chain == "pattern":
+        assert text.count("[volume == 1], e2=") == 1
+        text = text.replace("[volume == 1], e2=", "[volume == 1] -> e2=")
     return ("@app:statistics('BASIC')\n" if statistics else "") + text
 
 
@@ -88,10 +94,10 @@ class Driven:
     """The app deployed and subscribed; `send` returns what the call
     delivered (blocking delivery: the rows are here when it returns)."""
 
-    def __init__(self, sizes, statistics=False):
+    def __init__(self, sizes, statistics=False, chain="sequence"):
         self.manager = SiddhiManager()
         self.rt = self.manager.create_siddhi_app_runtime(
-            app_text(sizes, statistics))
+            app_text(sizes, statistics, chain))
         self.errors, self.batches = [], []
         self.rt.set_exception_listener(self.errors.append)
         self.rt.add_batch_callback(CONFIG["query"], self._on_batch)
@@ -120,10 +126,11 @@ class Driven:
         self.manager.shutdown()
 
 
-def drive(shape, seed, n_sends=N_SENDS, statistics=False, keep=None):
+def drive(shape, seed, n_sends=N_SENDS, statistics=False, keep=None,
+          chain="sequence"):
     sizes, events = SHAPES[shape]
     traffic = dict(TRAFFIC, events_per_send=events)
-    d = Driven(sizes, statistics)
+    d = Driven(sizes, statistics, chain)
     try:
         plan = MODEL.plan(seed, traffic, sizes)
         out = {"sends": [], "rows": [], "dropped": [], "compiles": []}
@@ -325,14 +332,44 @@ def with_scopes():
     return run["kept"]
 
 
-def test_the_block_step_names_the_seven_sections(with_scopes):
-    assert list(with_scopes) == ["step[S]"]
-    _text, named = with_scopes["step[S]"]
+@pytest.fixture(scope="module")
+def grid_scopes():
+    """The same app as a PATTERN chain (`->`): the grid form."""
+    return drive("rehearse", 3, n_sends=3, keep=step_texts,
+                 chain="pattern")["kept"]
+
+
+def ops_under(named, op):
+    """The scope paths of the `stablehlo.<op>` lines of a lowered text
+    with debug info."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', named, re.M))
+    return [locs.get(ref, ref) for ref in re.findall(
+        r'stablehlo\.%s\b.*?loc\((#loc\d+)\)\s*$' % op, named, re.M)]
+
+
+@pytest.mark.parametrize("chain", ["sequence", "pattern"])
+def test_the_block_step_names_the_seven_sections(chain, request):
+    texts = request.getfixturevalue(
+        {"sequence": "with_scopes", "pattern": "grid_scopes"}[chain])
+    assert list(texts) == ["step[S]"]
+    text, named = texts["step[S]"]
     assert "jit(pattern_block)" in named
     for section in SECTIONS:
         assert f"/{section}/" in named, section
-    # the chunk scan, whole, is the advance's
-    assert "nfa_advance/while" in named
+    if chain == "pattern":
+        # the chunk scan, whole, is the advance's; its completions are
+        # sorted back into arrival order
+        assert "nfa_advance/while" in named
+        assert "match_rows/jit(argsort)" in named
+        assert "stablehlo.sort" in text
+        return
+    # the linear form: one pass — no loop, no sort, and no gather but
+    # `event_load`'s four (three columns and the timestamp by `csel`)
+    assert "stablehlo.while" not in text and "/while" not in named
+    assert "stablehlo.sort" not in text and "argsort" not in named
+    gathers = ops_under(named, "gather")
+    assert len(gathers) == 4 == text.count('"stablehlo.gather"')
+    assert all("/event_load/" in g for g in gathers), gathers
 
 
 def test_named_scopes_leave_the_lowered_block_step_as_it_was(with_scopes,
@@ -350,26 +387,39 @@ def test_named_scopes_leave_the_lowered_block_step_as_it_was(with_scopes,
     assert text == with_scopes["step[S]"][0]
 
 
+@pytest.mark.parametrize("chain", ["sequence", "pattern"])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_the_route_keys_span_says_the_chunks_the_step_scans(shape):
-    """`phase_report()` lists the layout under stage_host's parts: a tier a
-    send, `ticks` the chunks of the send's bucket, `cells` the [P + W, W]
-    grids over them, `max_e` the bucket."""
+def test_the_route_keys_span_says_the_chunks_the_step_scans(shape, chain):
+    """`phase_report()` lists the layout under stage_host's parts, and the
+    layout says which form ran: a tier a send, `max_e` the bucket; a
+    SEQUENCE one tick and the (S - 1) x (P + bucket) slots its stages read,
+    a PATTERN `ticks` the chunks of the send's bucket and `cells` the
+    [P + W, W] grids over them."""
     n = 3
-    run = drive(shape, 5, n_sends=n, statistics=True,
-                keep=lambda rt: ph.phase_report(rt)["queries"][
-                    CONFIG["query"]])
-    lay = run["kept"]["phases"]["stage_host"]["parts"]["route_keys"][
-        "layout"]
+    run = drive(shape, 5, n_sends=n, statistics=True, chain=chain,
+                keep=lambda rt: (
+                    ph.phase_report(rt)["queries"][CONFIG["query"]],
+                    rt.query_runtimes[CONFIG["query"]].planned.spec))
+    report, spec = run["kept"]
+    lay = report["phases"]["stage_host"]["parts"]["route_keys"]["layout"]
     events = SHAPES[shape][1]
     bucket = next(b for b in (512, 2048) if events <= b)
+    assert lay["tiers"] == n and lay["max_e"] == n * bucket
+    assert spec.state_type == chain.upper() and spec.n_states == 2
+    if chain == "sequence":
+        assert lay["ticks"] == n
+        assert lay["cells"] == n * (SLOTS + bucket)
+        assert pattern_block.block_layout(8192, SLOTS, spec) == {
+            "tiers": 1, "cells": 8200, "ticks": 1, "max_e": 8192}
+        assert pattern_block.block_layout(131072, SLOTS, spec) == {
+            "tiers": 1, "cells": 131080, "ticks": 1, "max_e": 131072}
+        return
     chunks = bucket // pattern_block.CHUNK
-    assert lay["tiers"] == n and lay["ticks"] == n * chunks
-    assert lay["max_e"] == n * bucket
+    assert lay["ticks"] == n * chunks
     assert lay["cells"] == n * chunks * (SLOTS + 128) * 128
-    assert pattern_block.block_layout(8192, SLOTS)["ticks"] == 64
-    assert pattern_block.block_layout(131072, SLOTS) == {
+    assert pattern_block.block_layout(8192, SLOTS, spec)["ticks"] == 64
+    assert pattern_block.block_layout(131072, SLOTS, spec) == {
         "tiers": 1, "cells": 1024 * 136 * 128, "ticks": 1024,
         "max_e": 131072}
-    assert pattern_block.block_layout(100, SLOTS) == {
+    assert pattern_block.block_layout(100, SLOTS, spec) == {
         "tiers": 1, "cells": 108 * 100, "ticks": 1, "max_e": 100}
