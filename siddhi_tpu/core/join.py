@@ -2,8 +2,9 @@
 
 Reference behavior (what): CORE/query/input/stream/join/JoinProcessor.java:45
 — each CURRENT/EXPIRED event on one side probes the other side's window via
-find(); left/right/full outer emit unmatched rows with nulls; unidirectional
-restricts the triggering side.
+find() (an EXPIRED one only where the output expects expired events:
+`expired_joined` below); left/right/full outer emit unmatched rows with
+nulls; unidirectional restricts the triggering side.
 
 TPU-native design (how): each side's window is the columnar Buffer; a batch
 of trigger-side rows joins against the other side's buffer as one masked
@@ -20,7 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..query_api.definition import StreamDefinition
-from ..query_api.query import JoinInputStream, Query, SingleInputStream
+from ..query_api.query import (
+    InsertIntoStream,
+    JoinInputStream,
+    Query,
+    SingleInputStream,
+)
 from . import event as ev
 from .executor import CompileError, CompiledExpr, Scope, compile_expression
 from .keyslots import SlotAllocator
@@ -110,6 +116,10 @@ class PlannedJoinQuery:
     # the cap's order is taken BEFORE the pair rows are gathered and
     # selected (make_step: `late_pairs`), so columns exist at the cap only
     late_pairs: bool = False
+    # the trigger window's EXPIRED output rows join too.  False where
+    # nothing could read what they make (`plan_join_query`): the step then
+    # takes CURRENT trigger rows alone
+    expired_joined: bool = True
     # what shared code (emission, the purger, snapshots, the observatory,
     # lint) reads off ANY plan and only a plain or a pattern plan sets: a
     # join keeps no key axis (GSPMD row sharding under the app's mesh,
@@ -148,6 +158,7 @@ class PlannedJoinQuery:
             "emission_cap_rows": self.compact_rows,
             "emission_cap_explicit": bool(self.emit_explicit),
             "pair_rows_materialised": "cap" if self.late_pairs else "all",
+            "expired_rows_joined": bool(self.expired_joined),
         }
         if self.slot_allocator is not None:
             d["group_slot_capacity"] = (
@@ -452,6 +463,21 @@ def plan_join_query(
                       qsel.limit is not None or qsel.offset is not None)
 
     out_target = query.output_stream.target_id if query.output_stream else ""
+    out_event_type = (query.output_stream.output_event_type
+                      if query.output_stream else None) or "CURRENT_EVENTS"
+    # which kinds of the trigger window's output rows are join triggers: an
+    # EXPIRED row's joined rows are EXPIRED rows, and where the query says
+    # `insert into` a stream (CURRENT events, routed as they come: no table
+    # op, no `output ... every` counting them) and the selector is the
+    # projection above, nothing reads them and they change no other row —
+    # so they are not made (reference: JoinProcessor skips an EXPIRED event
+    # unless `outputExpectsExpiredEvents`).  Anything else keeps them: an
+    # aggregator needs the retraction, `having` / `order by` / `limit` see
+    # every row of the chunk.
+    expired_joined = not (
+        late_pairs and isinstance(query.output_stream, InsertIntoStream)
+        and out_event_type == "CURRENT_EVENTS"
+        and out_target not in tables and query.output_rate is None)
     out_def = StreamDefinition(out_target or f"#{name}.out")
     for n, t in zip(sel.out_names, sel.out_types):
         out_def.attribute(n, t)
@@ -483,6 +509,11 @@ def plan_join_query(
         table_probe = fp_mode == "table" and not this.is_table
         nbl_other = (lane_buckets[1] if this_is_left else
                      lane_buckets[0]) if bucket else 0
+        # CURRENT triggers alone, of a window whose CURRENT rows are its
+        # arrivals: the trigger rows are the step's input rows (R = B, not
+        # the window's out_capacity) and the window only updates its state
+        feed = this.window.admit if not expired_joined and \
+            this.window.current_is_arrivals else this.window.process
 
         def step(state, ts, kind, valid, cols, gslot, *rest):
             if bucket or table_probe:
@@ -514,7 +545,7 @@ def plan_join_query(
                                                  dtype=jnp.int32),)
                 rows = Rows(ts=ts, kind=kind, valid=keep,
                             seq=jnp.zeros_like(ts), gslot=gslot, cols=in_cols)
-                this_state, wout = this.window.process(this_state, rows, now)
+                this_state, wout = feed(this_state, rows, now)
             orows = wout.rows                       # [R]
             if bucket or table_probe:
                 trig_extra = orows.cols[-1]
@@ -547,10 +578,11 @@ def plan_join_query(
                     lanes = _bucket_lanes(o_jslot, o_alive, nbl_other,
                                           lane_k)
             with jax.named_scope("join_probe"):
-                data_row = jnp.logical_and(
-                    orows.valid,
-                    jnp.logical_or(orows.kind == ev.CURRENT,
-                                   orows.kind == ev.EXPIRED))
+                is_trigger = orows.kind == ev.CURRENT
+                if expired_joined:
+                    is_trigger = jnp.logical_or(is_trigger,
+                                                orows.kind == ev.EXPIRED)
+                data_row = jnp.logical_and(orows.valid, is_trigger)
                 if bucket:
                     tb = trig_extra.astype(jnp.int32) % nbl_other
                     cand = lanes[tb]                       # [R, K]
@@ -745,10 +777,7 @@ def plan_join_query(
         within_range=within_range, per_duration=per_duration,
         out_schema=out_schema,
         output_target=out_target,
-        output_event_type=(query.output_stream.output_event_type
-                           if query.output_stream and
-                           query.output_stream.output_event_type
-                           else "CURRENT_EVENTS"),
+        output_event_type=out_event_type,
         selector_exec=sel,
         step_left=step_left, step_right=step_right,
         init_state=init_state, batch_capacity=batch_capacity,
@@ -765,7 +794,8 @@ def plan_join_query(
         lane_k=lane_k, lane_buckets=lane_buckets, ring_caps=ring_caps,
         join_key_allocator=jk_alloc,
         table_is_left=table_is_left, table_pos=table_pos,
-        stream_key_pos=stream_key_pos, late_pairs=late_pairs)
+        stream_key_pos=stream_key_pos, late_pairs=late_pairs,
+        expired_joined=expired_joined)
 
 
 def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,

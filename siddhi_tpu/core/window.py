@@ -135,6 +135,11 @@ class WindowProcessor:
     # session(gap, key): position of the key column the planner vmaps the
     # processor over; None = no key axis
     session_key_pos = None
+    # True where the CURRENT rows a step emits ARE its CURRENT arrivals, in
+    # arrival order, in the step they arrive in: such a window answers
+    # `admit`, for a reader that wants no other kind of row (a CURRENT-only
+    # projection join's trigger side, core/join.py `expired_joined`)
+    current_is_arrivals = False
 
     def __init__(self, schema: ev.Schema, params: List[Constant],
                  batch_capacity: int, capacity_hint: int = 1024):
@@ -151,6 +156,14 @@ class WindowProcessor:
         raise NotImplementedError
 
     def process(self, state, rows: Rows, now) -> Tuple[Any, WindowOutput]:
+        raise NotImplementedError
+
+    def admit(self, state, rows: Rows, now) -> Tuple[Any, WindowOutput]:
+        """`process` for a reader of CURRENT rows alone (`current_is_arrivals`
+        windows only): the state after is `process`' bit for bit, and the
+        output rows are the arrivals WHERE THEY STAND — `valid` narrowed to
+        the CURRENT ones, each with the `seq` `process` gives it — so no
+        EXPIRED row is built and nothing is sorted."""
         raise NotImplementedError
 
     def current_buffer(self, state) -> Optional[Buffer]:
@@ -185,6 +198,8 @@ class NoWindow(WindowProcessor):
 
     name = "(none)"
     compact = True
+    # it emits nothing else; `process` only moves the invalid rows last
+    current_is_arrivals = True
 
     @property
     def out_capacity(self):
@@ -194,15 +209,20 @@ class NoWindow(WindowProcessor):
         return jnp.asarray(0, jnp.int64)  # seq counter
 
     def process(self, state, rows: Rows, now):
+        nseq, wout = self.admit(state, rows, now)
+        if self.compact:
+            wout = wout._replace(rows=sort_rows(wout.rows))
+        return nseq, wout
+
+    def admit(self, state, rows: Rows, now):
         seq0 = state
-        n = rows.capacity
         is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
         ord_ = jnp.cumsum(is_cur.astype(jnp.int64)) - 1
         seq = jnp.where(is_cur, seq0 + ord_, BIG_SEQ)
         out = Rows(rows.ts, rows.kind, is_cur, seq, rows.gslot, rows.cols)
         nseq = seq0 + jnp.sum(is_cur.astype(jnp.int64))
-        return nseq, WindowOutput(sort_rows(out) if self.compact else out,
-                                  None, jnp.asarray(NO_WAKEUP, jnp.int64))
+        return nseq, WindowOutput(out, None,
+                                  jnp.asarray(NO_WAKEUP, jnp.int64))
 
 
 class PassAllWindow(WindowProcessor):
@@ -242,6 +262,9 @@ class LengthWindow(WindowProcessor):
     """
 
     name = "length"
+    # the k-th arrival is the step's k-th CURRENT row (seq0 + 2k + 1); what
+    # it evicts is the EXPIRED row just before it, from the buffer
+    current_is_arrivals = True
 
     def __init__(self, schema, params, batch_capacity, capacity_hint=1024):
         super().__init__(schema, params, batch_capacity)
@@ -256,6 +279,12 @@ class LengthWindow(WindowProcessor):
                 jnp.asarray(0, jnp.int64))
 
     def process(self, state, rows: Rows, now):
+        return self._slide(state, rows, expired=True)
+
+    def admit(self, state, rows: Rows, now):
+        return self._slide(state, rows, expired=False)
+
+    def _slide(self, state, rows: Rows, expired: bool):
         buf, seq0 = state
         C = self.length
         B = rows.capacity
@@ -285,25 +314,29 @@ class LengthWindow(WindowProcessor):
         def phys(v):
             return jnp.where(v < count0, v, C + v - count0)
 
-        # the k-th arrival evicts virtual entry (count0 + k - length) (if >= 0)
-        evict_pos = (count0 + k - C)
-        has_evict = jnp.logical_and(is_cur, evict_pos >= 0)
-        safe_pos = jnp.clip(phys(evict_pos), 0, C + B - 1).astype(jnp.int32)
+        if expired:
+            # the k-th arrival evicts virtual entry (count0 + k - length)
+            # (if >= 0)
+            evict_pos = (count0 + k - C)
+            has_evict = jnp.logical_and(is_cur, evict_pos >= 0)
+            safe_pos = jnp.clip(phys(evict_pos), 0,
+                                C + B - 1).astype(jnp.int32)
 
-        exp_rows = Rows(
-            ts=comb_ts[safe_pos],
-            kind=jnp.full((B,), ev.EXPIRED, jnp.int32),
-            valid=has_evict,
-            seq=seq0 + 2 * k,           # expired emitted just before current k
-            gslot=comb_gslot[safe_pos],
-            cols=tuple(c[safe_pos] for c in comb_cols),
-        )
+            exp_rows = Rows(
+                ts=comb_ts[safe_pos],
+                kind=jnp.full((B,), ev.EXPIRED, jnp.int32),
+                valid=has_evict,
+                seq=seq0 + 2 * k,       # expired emitted just before current k
+                gslot=comb_gslot[safe_pos],
+                cols=tuple(c[safe_pos] for c in comb_cols),
+            )
         cur_rows = Rows(
             ts=rows.ts, kind=jnp.full((B,), ev.CURRENT, jnp.int32),
             valid=is_cur, seq=seq0 + 2 * k + 1, gslot=rows.gslot,
             cols=rows.cols,
         )
-        out = sort_rows(concat_rows(exp_rows, cur_rows))
+        out = sort_rows(concat_rows(exp_rows, cur_rows)) if expired \
+            else cur_rows
 
         # new buffer = last `length` of combined valid entries
         total = count0 + ncur

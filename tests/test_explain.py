@@ -114,6 +114,8 @@ def test_explain_join_query(manager):
     assert rep["plan"]["join_type"] == "JOIN"
     # a projection: the cap's order goes first, columns exist at the cap
     assert rep["plan"]["pair_rows_materialised"] == "cap"
+    # ... and `insert into` a stream: CURRENT trigger rows alone
+    assert rep["plan"]["expired_rows_joined"] is False
 
 
 def test_explain_pattern_query(manager):
@@ -546,6 +548,9 @@ def test_explain_says_the_selector_layout(manager, case):
     # a join with an aggregator keeps every candidate pair row
     assert plan.get("pair_rows_materialised") == (
         "all" if case.startswith("join_") else None)
+    # ... and joins the window's EXPIRED rows: the aggregator retracts them
+    assert plan.get("expired_rows_joined") == (
+        True if case.startswith("join_") else None)
     if want == "in_order":
         assert getattr(qr.planned, "slot_allocator", None) is None
         assert "group_slot_capacity" not in plan

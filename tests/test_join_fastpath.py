@@ -467,12 +467,14 @@ def test_fastpath_facts_in_explain_and_audit(manager):
     # `join_len128`'s text: a projection, so the pair rows exist at the
     # emission cap only; a grouped join keeps every candidate row
     assert rt.explain("q")["plan"]["pair_rows_materialised"] == "cap"
+    assert rt.explain("q")["plan"]["expired_rows_joined"] is False
     grouped = manager.create_siddhi_app_runtime(WINDOWED_JOIN_QL.replace(
         "select L.symbol as s, L.price as p, R.qty as v",
         "select L.symbol as s, sum(R.qty) as v group by L.symbol"))
     grouped.start()
     plan = grouped.explain("q")["plan"]
     assert plan["pair_rows_materialised"] == "all"
+    assert plan["expired_rows_joined"] is True
     assert plan["equi_fastpath"]["mode"] == "bucket"
 
 

@@ -9,10 +9,18 @@ cut at an explicit `@emit(rows=...)` and its `n_dropped`, an implicit cap that
 grows; and three joins that must NOT take the path (an aggregator, a `having`,
 an `order by ... limit`), whose answers read every candidate row whatever the
 cap.  `describe()` says which path a plan got, and the lowered `join_len128`
-programs carry no gather whose result has N rows."""
+programs carry no gather whose result has N rows.
+
+The same file holds the rule of which window output rows are join TRIGGERS
+(`expired_rows_joined`): a join that says `insert into` a stream and selects
+a projection takes CURRENT trigger rows alone — no EXPIRED joined row is
+made, counted against the cap or handed to a callback, both windows' state
+is what the `insert all events` twin's is — and every other join keeps its
+EXPIRED rows, held to the same nested loop."""
 import logging
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,6 +28,7 @@ from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core import join as joinmod
 
 import test_join_len128_config as cfg
+from test_lengthbatch_state import _LOC, _REF    # an op's `loc` and its name
 
 HEAD = """
 @app:playback
@@ -81,6 +90,20 @@ class NestedLoop:
     def send(self, side, rows):
         other = "R" if side == "L" else "L"
         pairs, lone = [], []
+        # what the send pushes out of its own side's window — rows of this
+        # send among them, where it is wider than the window — joined, in
+        # eviction order, as the arriving rows are (inner joins)
+        own, self.expired = list(self.held[side]), []
+        if side in self.triggers and side in self.keeps \
+                and self.window is not None:
+            for e in rows:
+                if len(own) == self.window:
+                    gone = own.pop(0)
+                    self.expired += [
+                        lr for h in self.held[other]
+                        if self.on(*(lr := (gone, h) if side == "L"
+                                     else (h, gone)))]
+                own.append(e)
         if side in self.triggers:
             for e in rows:
                 hits = 0
@@ -119,18 +142,26 @@ def drive(ql, sends, fastpath=True, monkeypatch=None):
     m = SiddhiManager()
     try:
         rt = m.create_siddhi_app_runtime(ql)
-        errors, rows, dropped = [], [], []
+        errors, rows, dropped, removed, counts = [], [], [], [], []
         rt.set_exception_listener(errors.append)
-        rt.add_callback("q", lambda _ts, cur, _exp: rows.extend(
-            tuple(e.data) for e in (cur or [])))
+
+        def on_events(_ts, cur, exp):
+            rows.extend(tuple(e.data) for e in (cur or []))
+            removed.extend(tuple(e.data) for e in (exp or []))
+        rt.add_callback("q", on_events)
         slots = set()
 
         def on_batch(_ts, b):
             dropped.append(int(b["n_dropped"]))
             slots.add(int(np.asarray(b["valid"]).shape[0]))
+            counts.append({k: int(b[k]) for k in (
+                "n_valid", "n_current", "n_expired")})
         rt.add_batch_callback("q", on_batch)
         rt.start()
-        out = {"rows": [], "dropped": [], "slots": slots}
+        # removed: the EXPIRED rows handed to the query callback, over the
+        # whole run; counts: every emission's header
+        out = {"rows": [], "dropped": [], "slots": slots,
+               "removed": removed, "counts": counts}
         for i, (side, data) in enumerate(sends):
             r0, d0 = len(rows), len(dropped)
             cols = [np.asarray(c, dt) for c, dt in zip(
@@ -145,6 +176,9 @@ def drive(ql, sends, fastpath=True, monkeypatch=None):
         qr = rt.query_runtimes["q"]
         out["plan"] = qr.planned.describe()
         out["fastpath"] = qr.planned.fastpath
+        # both sides' window state after the last send, leaf by leaf
+        out["windows"] = [np.asarray(x) for x in
+                          jax.tree.leaves((qr.state[0], qr.state[1]))]
         assert rt.explain("q")["plan"]["pair_rows_materialised"] == \
             out["plan"]["pair_rows_materialised"]
         assert not errors, errors[:1]
@@ -228,29 +262,24 @@ def test_a_fused_projection_join_delivers_the_same_rows(monkeypatch):
 @pytest.mark.parametrize("fastpath", [True, False], ids=["bucket", "grid"])
 def test_an_explicit_cap_delivers_the_first_rows_and_counts_the_rest(
         case, join, outer, fastpath, monkeypatch):
-    """`@emit(rows='40')` under sends that make more: the step counts the
-    window's EXPIRED joined rows against the cap as well as the CURRENT ones
-    (a debt PERF.md states), so what a send delivers of its CURRENT rows is
-    a PREFIX of the nested loop's, the valid rows past the cap are
-    `n_dropped`, and delivered + dropped is at least what the send owed."""
+    """`@emit(rows='40')` under sends that make more: only CURRENT rows
+    join (`expired_rows_joined` false), so the cut is exact — a send
+    delivers the first `cap` of the nested loop's rows and `n_dropped` is
+    the rest, whatever its windows expired meanwhile."""
     cap = 40
     sends = traffic(seed=45)
     run = drive(app(join, ann=f"@emit(rows='{cap}')"), sends, fastpath,
                 monkeypatch)
     assert run["plan"]["pair_rows_materialised"] == "cap"
+    assert run["plan"]["expired_rows_joined"] is False
     assert run["plan"]["emission_cap_rows"] == cap
     ref = NestedLoop(equi, outer=outer)
     cut = 0
     for i, (side, data) in enumerate(sends):
         want = project(ref.send(side, data))
-        got = run["rows"][i]
-        assert got == want[:len(got)], (case, i)
-        assert len(got) <= cap
-        if len(want) > cap:
-            cut += 1
-            assert run["dropped"][i] >= len(want) - len(got) > 0
-        else:
-            assert got == want or run["dropped"][i] > 0
+        assert run["rows"][i] == want[:cap], (case, i)
+        assert run["dropped"][i] == max(0, len(want) - cap), (case, i)
+        cut += len(want) > cap
     assert cut >= 3
 
 
@@ -277,9 +306,11 @@ def test_an_explicit_cap_before_any_row_expires_is_an_exact_cut():
 
 
 def test_an_implicit_cap_grows_and_then_delivers_every_row(caplog):
-    """No `@emit`: the cap is max(2 R, 1024) of N = R x 16 candidate rows;
-    one symbol makes 256 x 16 pairs a send, the overflow grows the cap once
-    and the next send of the same shape is delivered whole."""
+    """No `@emit`: the cap is max(2 R, 1024) of N = R x 16 candidate rows
+    (R = the send's 256 rows: CURRENT triggers alone); one symbol makes
+    256 x 16 pairs a send, the overflow grows the cap once — to the rows
+    the send made, no EXPIRED ones among them — and the next send of the
+    same shape is delivered whole."""
     events, window = 256, 16
     ql = app().replace(f"length({WINDOW})", f"length({window})")
     sends = [("R", [(0, i % 8 + 1) for i in range(events)])] + [
@@ -294,8 +325,8 @@ def test_an_implicit_cap_grows_and_then_delivers_every_row(caplog):
     # the send that overflowed delivered a prefix and said what it dropped
     first = run["rows"][1]
     assert 0 < len(first) < len(wants[1]) and first == wants[1][:len(first)]
-    assert run["dropped"][1] >= len(wants[1]) - len(first)
-    assert run["plan"]["emission_cap_rows"] >= 2 * events * window
+    assert run["dropped"][1] == len(wants[1]) - len(first)
+    assert run["plan"]["emission_cap_rows"] == events * window
     assert run["rows"][3] == wants[3] and run["dropped"][3] == 0
 
 
@@ -378,6 +409,146 @@ def test_describe_says_which_path_a_plan_got(sel, want):
     assert run["plan"]["pair_rows_materialised"] == want
 
 
+# -- which window output rows are join triggers ---------------------------
+
+def twin(ql, kinds="all"):
+    """The same app with `insert <kinds> events into Out`."""
+    assert ql.count("insert into Out;") == 1
+    return ql.replace("insert into Out;", f"insert {kinds} events into Out;")
+
+
+CURRENT_ONLY = dict(LATE_CASES, fused=(
+    app(ann=f"@fuse(batches='2') @emit(rows='{CAP}')"), CAP,
+    lambda: NestedLoop(equi), True, "bucket"))
+
+
+@pytest.mark.parametrize("case", sorted(CURRENT_ONLY))
+def test_a_current_only_projection_join_joins_its_current_rows_alone(
+        case, monkeypatch):
+    """`insert into` + a projection: the nested loop's rows in delivery
+    order, no EXPIRED row in any emission's header or at the callback, and
+    both windows left as the `insert all events` twin leaves them — which
+    does make EXPIRED rows out of the same sends."""
+    ql, _cap, make_ref, fastpath, mode = CURRENT_ONLY[case]
+    sends = traffic(seed=46)
+    run = drive(ql, sends, fastpath, monkeypatch)
+    assert run["fastpath"] == mode
+    assert run["plan"]["expired_rows_joined"] is False
+    ref = make_ref()
+    want = [project(ref.send(side, data)) for side, data in sends]
+    if case == "fused":
+        assert sum(run["rows"], []) + run["flushed"] == sum(want, [])
+    else:
+        assert run["rows"] == want and run["flushed"] == []
+    assert sum(map(len, want)) > 100 and sum(run["dropped"]) == 0
+    assert run["counts"] and run["removed"] == []
+    for header in run["counts"]:
+        assert header["n_expired"] == 0, header
+        assert header["n_valid"] == header["n_current"], header
+    both = drive(twin(ql), sends, fastpath, monkeypatch)
+    assert both["plan"]["expired_rows_joined"] is True
+    if mode != "table":          # a windowless stream side expires nothing
+        assert sum(h["n_expired"] for h in both["counts"]) > 50
+        assert both["removed"]
+    assert len(run["windows"]) == len(both["windows"]) >= 1
+    for i, (mine, theirs) in enumerate(zip(run["windows"],
+                                           both["windows"])):
+        np.testing.assert_array_equal(mine, theirs, err_msg=f"leaf {i}")
+
+
+TABLE_OUT = app().replace(
+    "define stream R", "define table Kept (s long, p float, v int);\n"
+    "define stream R").replace("insert into Out;", "insert into Kept;")
+# every join that keeps its EXPIRED trigger rows: (app text, whether the
+# callback's EXPIRED rows are the nested loop's, row for row)
+KEEPS_EXPIRED = {
+    "all_events_bucket": (twin(app()), True, True),
+    "all_events_grid": (twin(app()), False, True),
+    "expired_events": (twin(app(), "expired"), True, True),
+    "sum": (app(sel="select L.symbol as s, sum(R.qty) as v"), True, False),
+    "having": (app(sel=PROJ + " having p > 0.35"), True, False),
+    "order_by_limit": (app(sel=PROJ + " order by p desc limit 5"), True,
+                       False),
+    "output_rate": (app(sel=PROJ + "\noutput last every 3 events"), True,
+                    False),
+    "into_a_table": (TABLE_OUT, True, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEEPS_EXPIRED))
+def test_every_other_join_keeps_its_expired_rows(case, monkeypatch):
+    """`insert all events`, `insert expired events`, an aggregator (it needs
+    the retraction), a `having`, an `order by ... limit`, an `output ...
+    every` (it counts them) and a table as the target: the plan says the
+    EXPIRED rows join, and they do — for the projections, the nested
+    loop's, row for row, at the query callback."""
+    ql, fastpath, exact = KEEPS_EXPIRED[case]
+    sends = traffic(seed=47)
+    run = drive(ql, sends, fastpath, monkeypatch)
+    assert run["plan"]["expired_rows_joined"] is True
+    assert sum(h["n_expired"] for h in run["counts"]) > 0
+    if exact:
+        ref = NestedLoop(equi)
+        cur, gone = [], []
+        for side, data in sends:
+            cur += project(ref.send(side, data))
+            gone += project(ref.expired)
+        assert sum(run["rows"], []) == cur and len(gone) > 100
+        assert run["removed"] == gone
+        assert sum(h["n_expired"] for h in run["counts"]) == len(gone)
+        assert sum(h["n_current"] for h in run["counts"]) == len(cur)
+        assert sum(run["dropped"]) == 0
+
+
+NAMED_BATCH = HEAD + """
+define window W (symbol long, qty int) lengthBatch(4);
+@info(name='fill') from R select symbol, qty insert into W;
+@info(name='q')
+from W unidirectional join L#window.length(8) on L.symbol == W.symbol
+select L.symbol as s, L.price as p, W.qty as v
+insert into Out;"""
+TIME_APP = app(frm=f"L#window.time(1 sec) join R#window.time(1 sec)\n"
+               f"  on {ON}")
+# trigger windows whose CURRENT rows are NOT their arrivals: (app, the
+# reference, the trigger side's processor, whether the twin's sends
+# expire rows)
+MASKED_CASES = {
+    # a named `lengthBatch` window: its rows reach the join a batch late,
+    # the batch before them as EXPIRED rows among them
+    "named_length_batch": (NAMED_BATCH, lambda: NestedLoop(
+        equi, triggers=("R",), keeps=("L",)), "PassAllWindow", True),
+    # a time window orders its CURRENT rows by timestamp; nothing is a
+    # second old here, so it holds every row
+    "time_window": (TIME_APP, lambda: NestedLoop(equi, window=None),
+                    "TimeWindow", False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MASKED_CASES))
+def test_a_window_that_is_not_its_arrivals_joins_its_current_rows_masked(
+        case):
+    """The step keeps the window's output rows as its trigger rows and
+    masks the EXPIRED ones: the nested loop's rows, no EXPIRED row made."""
+    ql, make_ref, processor, expires = MASKED_CASES[case]
+    sends = traffic(seed=48, n_sends=10)
+    run = drive(ql, sends)
+    assert run["plan"]["expired_rows_joined"] is False
+    trigger = run["plan"]["left"]
+    assert trigger["window_processor"] == processor
+    ref = make_ref()
+    want = [project(ref.send(side, data)) for side, data in sends]
+    assert run["rows"] == want and sum(map(len, want)) > 100
+    assert run["removed"] == [] and sum(run["dropped"]) == 0
+    assert all(h["n_expired"] == 0 and h["n_valid"] == h["n_current"]
+               for h in run["counts"])
+    both = drive(twin(ql), sends)
+    assert both["plan"]["expired_rows_joined"] is True
+    assert both["rows"] == want
+    assert (sum(h["n_expired"] for h in both["counts"]) > 50) == expires
+    for mine, theirs in zip(run["windows"], both["windows"]):
+        np.testing.assert_array_equal(mine, theirs)
+
+
 # -- the deployed programs: no column is expanded over N rows -------------
 
 _GATHER = re.compile(
@@ -397,16 +568,20 @@ def candidate_rows(text):
     return max(int(n) for n in _FLAGS.findall(text))
 
 
-def deploy_w128(select=None, seed=5):
+def deploy_w128(select=None, seed=5, kinds=None, debug_info=False):
     """`join_len128` at the source's window under sends eight windows wide
     (`w128_e1024`: a cap of 8,192 — at `rehearse_sizes` the cap IS the
     candidate rows and nothing is compacted), optionally with another select
-    list: ({role: lowered text} of the two side programs, describe())."""
+    list or `insert <kinds> events into`: ({role: lowered text} of the two
+    side programs — with their debug info, which names each op's section,
+    where asked —, describe())."""
     sizes, events = cfg.SHAPES["w128_e1024"]
     ql = cfg.app_text("join_len128", sizes)
     if select is not None:
         assert PROJ in ql
         ql = ql.replace(PROJ, select)
+    if kinds is not None:
+        ql = twin(ql, kinds)
     m = SiddhiManager()
     try:
         rt = m.create_siddhi_app_runtime(ql)
@@ -421,7 +596,7 @@ def deploy_w128(select=None, seed=5):
                  rng.integers(1, 9, events).astype(dtype)],
                 timestamps=np.full(events, 1000, np.int64))
         assert not errors, errors[:1]
-        texts = {role: text for role, (text, _named)
+        texts = {role: both[debug_info] for role, both
                  in cfg.side_step_texts(rt).items()}
         assert sorted(texts) == ["step[left]", "step[right]"]
         return texts, rt.query_runtimes[cfg.CONFIG["query"]].planned.describe()
@@ -455,3 +630,54 @@ def test_the_guard_sees_a_join_that_expands_its_columns():
         n = candidate_rows(text)
         assert n >= 4 * plan["emission_cap_rows"]
         assert gather_rows(text).count(n) >= 4, role
+
+
+_RESULT = re.compile(r"->\s*\(?tensor<(\d+)[x>]")
+_SORT = re.compile(r"stablehlo\.sort|call @argsort")
+_BATCH = re.compile(r"%arg\d+: tensor<(\d+)xi64> [^%]*?loc\(\"ts\"\)")
+
+
+def trigger_rows(named):
+    """Of a side program's lowered text with debug info: (B, the rows the
+    send stages; the sorts under `window_order`; the leading dimension
+    of every result made under `join_probe`)."""
+    (batch,) = set(_BATCH.findall(named))
+    names = {m.group(1): m.group(2) for line in named.splitlines()
+             if (m := _LOC.match(line))}
+    sorts, probe_rows = 0, set()
+    for line in named.splitlines():
+        ref = _REF.search(line)
+        scopes = names.get(ref.group(1), "").split("/") if ref else ()
+        # `jnp.argsort` is a private function: the scope names its call
+        if "window_order" in scopes and _SORT.search(line):
+            sorts += 1
+        if "join_probe" in scopes and (m := _RESULT.search(line)):
+            probe_rows.add(int(m.group(1)))
+    return int(batch), sorts, probe_rows
+
+
+def test_the_deployed_join_takes_its_arrivals_as_trigger_rows():
+    """The cell's app: the trigger rows are the B staged rows — no
+    `window_order` sort, nothing with 2 B rows under `join_probe`, B x 16
+    candidate flags."""
+    texts, plan = deploy_w128(debug_info=True)
+    assert plan["expired_rows_joined"] is False
+    for role, named in texts.items():
+        batch, sorts, probe_rows = trigger_rows(named)
+        assert sorts == 0, role
+        assert batch in probe_rows and max(probe_rows) == batch, (
+            role, batch, sorted(probe_rows))
+        assert candidate_rows(named) == batch * 16, role
+
+
+def test_the_guard_sees_a_join_whose_expired_rows_are_triggers():
+    """The same deployment with `insert all events into`: the window's 2 B
+    output rows, sorted, are the trigger rows — the guard above can fail."""
+    texts, plan = deploy_w128(kinds="all", debug_info=True)
+    assert plan["expired_rows_joined"] is True
+    assert plan["pair_rows_materialised"] == "cap"
+    for role, named in texts.items():
+        batch, sorts, probe_rows = trigger_rows(named)
+        assert sorts == 1, role
+        assert max(probe_rows) == 2 * batch, (role, sorted(probe_rows))
+        assert candidate_rows(named) == 2 * batch * 16, role
