@@ -6,18 +6,34 @@ A join query's two side programs (siddhi_tpu/core/join.py `make_step`:
 sections — SECTIONS below, named in `make_step.step` around the calls and
 blocks that are there:
 
-- `join_window`   the arriving side's window (`this.window.process`: a
-                  `length` window hands over 2 B rows a send, B CURRENT and
-                  B EXPIRED) with the side's pre-filters;
+- `join_window`   the arriving side's window with the side's pre-filters:
+                  `this.window.process`, which for a `length` window hands
+                  over R = 2 B rows a send, B CURRENT and B EXPIRED — or,
+                  since PR 48, where no EXPIRED row joins (a CURRENT-only
+                  projection join over a window whose CURRENT rows are its
+                  arrivals), `window.admit`, the state update alone, the B
+                  staged rows being the R = B trigger rows;
 - `join_lanes`    `_bucket_lanes`: the `[buckets, K]` lane table re-derived
                   from the OTHER window's slot column, every dispatch;
 - `join_probe`    the `[R, K]` candidate gather, the ON re-check, the masks
                   (the grid and the table-probe forms take the same name);
-- `join_pairs`    the expansion to N = R x K pair rows: pair indices, both
-                  sides' gathers over them, the joined `Rows`;
-- `join_select`   the selector over the N pair rows (`sel.process`);
-- `join_compact`  the emission cap's stable valid-first argsort over N, a
-                  gather a column to the cap, the header.
+- `join_pairs`    the N = R x K candidate pairs.  A join that keeps every
+                  pair row (an aggregator, a `having`, an `order by`): the
+                  expansion to N pair rows — pair indices, both sides'
+                  gathers over them, the joined `Rows`.  A projection-only
+                  join (since PR 43, where cap < N): the N candidate FLAGS,
+                  then — after `join_compact` has taken the cap's order
+                  from them — the pair indices of the `cap` rows alone and
+                  one gather a column over `cap` rows;
+- `join_select`   the selector (`sel.process`): over the N pair rows, or the
+                  projection over the `cap` rows of a projection-only join;
+- `join_compact`  the emission cap's stable valid-first argsort over the N
+                  flags — taken FIRST in a projection-only join, which then
+                  gathers nothing of N rows — and the header; where every
+                  pair row is kept, also a gather a column to the cap.
+
+The names and what the readers add up are the same on both sides of those
+choices; only which ops stand under a name differs.
 
 None of them is one of `step_sections.SECTIONS` or `plain_sections.
 SECTIONS`, so those readers book a join program under `other_modules`.

@@ -8,18 +8,28 @@ def served_path_ms(run: dict, part: str):
     the harness's own clock, over the window's sends:
     `subscriber` (inside the batch callback, payload reads included), `post`
     (callback's end to the call's return) or `pre` (the rest: staging,
-    upload, dispatch, device step, fetch, demux).  None when results are
-    delivered on another thread than the sender's."""
+    upload, dispatch, device step, fetch, demux).
+
+    None when results are delivered on another thread than the sender's:
+    some timed send OWED rows (the stamp's `owed`, what `Deployment.issue`
+    told the tracker) and its subscriber did not run inside its call.  A
+    send that owed none and whose subscriber never ran (rule 2 of
+    `runner.py`) is no sign of that: it counts with `subscriber` 0 and
+    `post` 0, `pre` the whole call — so the three parts still add up to the
+    mean of `returned - issued` over every timed send."""
     parts = []
     for st in run["stamps"]:
         if "returned" not in st:
             continue
-        if st["subscriber_end"] is None:
-            return None
         whole = st["returned"] - st["issued"]
-        post = st["returned"] - st["subscriber_end"]
-        split = {"subscriber": st["subscriber_s"], "post": post,
-                 "pre": whole - st["subscriber_s"] - post}
+        if st["subscriber_end"] is None:
+            if st["owed"]:
+                return None
+            split = {"subscriber": 0.0, "post": 0.0, "pre": whole}
+        else:
+            post = st["returned"] - st["subscriber_end"]
+            split = {"subscriber": st["subscriber_s"], "post": post,
+                     "pre": whole - st["subscriber_s"] - post}
         parts.append(split[part] * 1e3)
     if not parts:
         return None

@@ -30,7 +30,14 @@ is taken at deploy, never inside a send.
 - `reference(sends, plan) -> [rows]` (every send since the app started, in
   order), `canonical(rows)`, `compare(got, want) -> {number: value}` held to
   `LIMITS`, `control_rows(want)` (the reference at the nearest lower
-  precision) and `least_bytes(traffic, sizes, config)`.
+  precision) and `least_bytes(traffic, sizes, config)`.  `LIMITS` names the
+  numbers compared — `rows_missing`, `rows_unexpected`, `rows_differing` at
+  the least, and whatever else the model counts — each with its limit; the
+  result line's `compared` holds exactly these and the harness's own three
+  (`stray_rows`, `listener_errors`, `sends_undelivered`).  Every number is a
+  COUNT held to 0: a tolerance the configuration states is applied inside
+  `compare`, which counts the rows over it (`lengthbatch_1000`'s `AP_RTOL`),
+  never as a limit above 0.
 
 Two rules hold for every model:
 
@@ -260,9 +267,10 @@ class Deployment:
             raise RuntimeError(
                 f"send {sid} names stream {stream!r}; config.json lists "
                 f"{sorted(self.handlers)} and a send goes nowhere else")
-        self.tracker.issued(sid, send, self.model.expected_rows(send))
+        owed = self.model.expected_rows(send)
+        self.tracker.issued(sid, send, owed)
         st = self.stamps[sid] = {
-            "due": due, "issued": now(),
+            "due": due, "owed": owed, "issued": now(),
             "subscriber_s": 0.0, "subscriber_end": None}
         self._in_call = (threading.get_ident(), sid)
         try:

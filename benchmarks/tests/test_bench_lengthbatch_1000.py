@@ -14,6 +14,7 @@ import pytest
 import siddhi_tpu
 from benchmarks.harness import loader, numeric, plain_sections as ps
 from benchmarks.harness import trace_reduce as tr
+from adding_pr import NEW_CELLS, NEW_CLOSED
 from test_bench_doctored import load_run_module
 from test_bench_step_sections import reader, recorded_run
 
@@ -156,10 +157,7 @@ def test_config_states_what_the_contract_asks():
         ("StockStream", "q", ["ap"])
     assert "lengthBatch(1000)" in cell.app_text
     assert cell.chips == 1 and cell.traffic["loop"] == "closed"
-    entry = next(c for c in BENCH["configs"] if c["name"] == cfg["name"])
-    assert entry["source"] == cfg["source"] and entry["reduced"] == []
-    assert {e["name"] for e in cell.end_to_end} == {
-        "events_per_s", "latency_p50_ms", "setup_s"}
+    check_the_tables_configuration_and_what_its_cell_reports(BENCH)
     # the model imports nothing of the program
     with open(os.path.join(loader.BENCH_DIR, "configs", cfg["name"],
                            "model.py")) as fh:
@@ -353,15 +351,27 @@ def test_a_trace_with_no_plain_program_reads_none(tmp_path, name):
 
 # -- the four entries and the lists the cell joined ---------------------------------------
 
+def check_the_tables_configuration_and_what_its_cell_reports(bench):
+    cell = loader.resolve(CELL)
+    cfg = cell.config
+    entry = next(c for c in bench["configs"] if c["name"] == cfg["name"])
+    assert entry["source"] == cfg["source"] and entry["reduced"] == []
+    assert {e["name"] for e in cell.end_to_end} == {
+        "events_per_s", "latency_p50_ms", "setup_s"}
+
+
 def check_the_four_entries_and_the_lists_the_cell_joined(bench):
     """One-sided (PR 41): the four entries stay, together and in order,
     after the pattern programs' entries; a later PR may add a cell to a
-    list behind this one, or an entry behind these."""
+    list behind this one, or an entry behind these — whatever cells it
+    lists (PR 50: what the cell resolves is held among the entries that
+    stood with it)."""
     names = [e["name"] for e in bench["per_layer"]]
     entries = {e["name"]: e for e in bench["per_layer"]}
     at = names.index(next(iter(QUANTITIES)) + ".sat")
     assert names[at:at + 4] == [q + ".sat" for q in QUANTITIES]
     assert 76 <= at and len(names) <= 128
+    stood = set(names[:at + 4])
     for q in QUANTITIES:
         e = dict(entries[q + ".sat"])
         assert e.pop("workloads")[0] == CELL
@@ -369,7 +379,8 @@ def check_the_four_entries_and_the_lists_the_cell_joined(bench):
                      "source": "device_trace", "layer": "device step",
                      "moves": "events_per_s"}
     got = {e["name"]: read.__module__
-           for e, read in loader.resolve(CELL).per_layer}
+           for e, read in loader.resolve(CELL).per_layer
+           if e["name"] in stood}
     for q in QUANTITIES:
         assert got[q + ".sat"] == "bench_layer_" + q
     # joined: every `.sat` span / clock / idle quantity, the un-suffixed
@@ -397,7 +408,11 @@ def test_the_four_entries_and_the_lists_the_cell_joined():
     check_the_four_entries_and_the_lists_the_cell_joined(BENCH)
 
 
-def test_a_seventh_cell_behind_it_trips_no_pin(seventh_cell):
-    bench, name = seventh_cell
-    assert bench["workloads"][-1]["name"] == name
-    check_the_four_entries_and_the_lists_the_cell_joined(bench)
+def test_a_seventh_cell_behind_it_trips_no_pin(adding_pr):
+    assert [w["name"] for w in adding_pr["workloads"]][-4:] == \
+        list(NEW_CELLS)
+    events = next(e for e in adding_pr["end_to_end"]
+                  if e["name"] == "events_per_s")
+    assert NEW_CLOSED in events["workloads"][-2:]
+    check_the_four_entries_and_the_lists_the_cell_joined(adding_pr)
+    check_the_tables_configuration_and_what_its_cell_reports(adding_pr)

@@ -410,7 +410,10 @@ def test_the_parent_of_this_pr_reads_bytes_and_copies_but_no_residency(
 # -- BENCHMARK.json ----------------------------------------------------------------------
 
 def test_the_cell_and_its_configuration_are_what_the_contract_asks():
-    bench = loader.load_benchmark()
+    check_the_cell_and_its_configuration(loader.load_benchmark())
+
+
+def check_the_cell_and_its_configuration(bench):
     (w,) = [w for w in bench["workloads"] if w["name"] == CELL]
     assert (w["config"], w["traffic"], w["chips"]) == \
         ("pattern_1m_served", "paced_scattered", 1)

@@ -8,19 +8,22 @@ cell joins lists; it adds an entry only for a quantity no cell had.
 `data/per_layer_parent.json` holds every (cell, quantity) pair the table
 reported when it had one entry a cell (128 entries, PR 33's tree): each is
 still reported in its cell, by the same reader file, exactly once.
-`data/per_layer_pr34_names.json` holds the 60 names PR 34 merged them into.
+`data/per_layer_pr48_names.json` holds the 89 names of PR 48's tree, which
+stand first; its first 60 are the names PR 34 merged those pairs into.
 
 Every pin is ONE-SIDED: it holds what was accepted — those pairs, those
 names, the five cells of PR 34 in the lists they were in — and lets a later
-PR add a cell to a list or an entry to the table (at most 128).  The last
-test adds a seventh cell to every `.sat` list its twin is in, in a scratch
-copy of the table, and runs every check of this file on it."""
+PR add a cell to a list or an entry to the table (at most 128).  The checks
+of a whole table are the `check_*(bench)` functions, which
+`test_bench_adding_pr.py` runs with every other file's on conftest.py's
+scratch adding PR; the last test here runs this file's on it."""
 import json
 import os
 
 import pytest
 
 from benchmarks.harness import loader
+from adding_pr import NEW_CELLS, NEW_CLOSED, NEW_ENTRIES, NEW_OPEN
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 # the cells PR 34's table was merged for, by loop kind
@@ -29,8 +32,9 @@ OPEN = ["pattern_1m.paced", "pattern_16m_zipf.paced",
         "pattern_1m.served_paced"]
 with open(os.path.join(DATA, "per_layer_parent.json")) as fh:
     PARENT = json.load(fh)
-with open(os.path.join(DATA, "per_layer_pr34_names.json")) as fh:
-    PR34_NAMES = json.load(fh)
+with open(os.path.join(DATA, "per_layer_pr48_names.json")) as fh:
+    PR48_NAMES = json.load(fh)     # the 89 an adding PR appends behind
+PR34_NAMES = PR48_NAMES[:60]       # the lists PR 34 wrote stay whole
 # the one thing a cell gained: a reading its reader already served, left out
 # of PR 33 for the cap alone
 GAINED = {(cell, "obs_feed_idle_ms_per_send") for cell in OPEN}
@@ -96,11 +100,13 @@ def check_pair(t, pair):
 
 def check_room(t):
     assert len(PARENT) == 131          # 128 entries, three of two cells
-    assert len(PR34_NAMES) == len(set(PR34_NAMES)) == 60
+    assert (PR34_NAMES[0], PR34_NAMES[-1]) == (
+        "gen_late_ms_p99", "obs_feed_idle_ms_per_send.paced")
     names = [e["name"] for e in t.bench["per_layer"]]
     assert len(names) == len(set(names)) <= 128
-    # PR 34's 60 are all there, first and in their order
-    assert names[:60] == PR34_NAMES
+    # PR 34's 60 are all there, first and in their order; so are the 89 of
+    # PR 48's tree (PR 50): an adding PR appends, it inserts nothing
+    assert names[:89] == PR48_NAMES and len(set(PR48_NAMES)) == 89
 
 
 def check_cell(t, cell):
@@ -177,10 +183,45 @@ OWN_SETS = [
 
 
 def check_own_set(t, cell, suffix, want):
-    own = {n for n, e in t.entries.items() if e["workloads"] == [cell]}
+    # the cell's own: it stands first in their lists (a later cell may join)
+    own = {n for n, e in t.entries.items() if e["workloads"][:1] == [cell]}
     assert own >= {n + suffix for n in want}
     moves = "latency_p50_ms" if cell in OPEN else "events_per_s"
     assert all(t.entries[n + suffix]["moves"] == moves for n in want)
+
+
+# -- the same checks as functions of a whole table -------------------------------------
+
+def check_every_pair_the_parent_reported(bench):
+    t = Table(bench)
+    for pair in PARENT:
+        check_pair(t, pair)
+
+
+def check_the_tables_names_and_room(bench):
+    check_room(Table(bench))
+
+
+def check_every_cell_of_the_table(bench):
+    t = Table(bench)
+    for cell in t.cells:
+        check_cell(t, cell)
+        check_fetch_bytes(t, cell)
+        check_span_quantities(t, cell)
+
+
+def check_every_entry_of_the_table(bench):
+    t = Table(bench)
+    for entry in t.entries:
+        check_entry(t, entry)
+    for kind, cells in ((".sat", CLOSED), (".paced", OPEN)):
+        check_obs_feed_idle(t, kind, cells)
+
+
+def check_the_own_sets(bench):
+    t = Table(bench)
+    for own in OWN_SETS:
+        check_own_set(t, *own)
 
 
 @pytest.mark.parametrize(
@@ -227,21 +268,15 @@ def test_a_cells_own_quantities_keep_their_single_cell_entries(
     check_own_set(TABLE, cell, suffix, want)
 
 
-def test_a_seventh_cell_in_the_sat_lists_trips_no_pin(seventh_cell):
-    bench, name = seventh_cell
-    t = Table(bench)
-    assert t.cells[-1] == name and len(t.cells) == len(TABLE.cells) + 1
-    assert name in t.entries["stage_ms_per_send.sat"]["workloads"]
-    for pair in PARENT:
-        check_pair(t, pair)
-    check_room(t)
-    for own in OWN_SETS:
-        check_own_set(t, *own)
-    for cell in t.cells:
-        check_cell(t, cell)
-        check_fetch_bytes(t, cell)
-        check_span_quantities(t, cell)
-    for entry in t.entries:
-        check_entry(t, entry)
-    for kind, cells in ((".sat", CLOSED), (".paced", OPEN)):
-        check_obs_feed_idle(t, kind, cells)
+def test_a_seventh_cell_in_the_sat_lists_trips_no_pin(adding_pr):
+    t = Table(adding_pr)
+    assert t.cells[-4:] == list(NEW_CELLS)
+    assert len(t.cells) == len(TABLE.cells) + 4
+    assert NEW_CLOSED in t.entries["stage_ms_per_send.sat"]["workloads"]
+    assert NEW_OPEN in t.entries["stage_ms_per_send.paced"]["workloads"]
+    assert list(t.entries)[-3:] == [e[0] for e in NEW_ENTRIES]
+    check_every_pair_the_parent_reported(adding_pr)
+    check_the_tables_names_and_room(adding_pr)
+    check_the_own_sets(adding_pr)
+    check_every_cell_of_the_table(adding_pr)
+    check_every_entry_of_the_table(adding_pr)

@@ -21,6 +21,7 @@ import shutil
 
 import pytest
 
+from adding_pr import NEW_CLOSED, NEW_OPEN
 from benchmarks.harness import loader, send_stats, step_sections as ss, xspace
 from benchmarks.harness import trace_reduce as tr
 
@@ -369,11 +370,12 @@ def test_the_entries_pr35_added_obey_the_tables_naming_rule():
     check_the_entries_pr35_added(BENCH)
 
 
-def test_a_seventh_cell_in_their_lists_trips_no_pin(seventh_cell):
-    bench, name = seventh_cell
-    assert name in {c for e in bench["per_layer"] for c in e["workloads"]
-                    if e["name"] == FAULTS + ".sat"}
-    check_the_entries_pr35_added(bench)
+def test_a_seventh_cell_in_their_lists_trips_no_pin(adding_pr):
+    lists = {e["name"]: e["workloads"] for e in adding_pr["per_layer"]}
+    assert NEW_CLOSED in lists[FAULTS + ".sat"][-2:]
+    assert NEW_OPEN in lists[FAULTS + ".paced"][-2:]
+    assert NEW_OPEN in lists["step_scan_ms_per_send.paced"][-2:]
+    check_the_entries_pr35_added(adding_pr)
 
 
 @pytest.mark.parametrize("cell", CLOSED + OPEN)
