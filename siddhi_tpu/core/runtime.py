@@ -354,6 +354,17 @@ class _QueryRuntimeBase:
         self.callbacks: List[Callable] = []
         self.batch_callbacks: List[Callable] = []
         self.next_wakeup: int = _NO_WAKEUP_INT
+        # A runtime holds ONE pending wake-up (`_Scheduler.arm`): the
+        # earliest time its state next changes by the clock alone.  Where
+        # a step's wake speaks for the WHOLE state (a plain query's one
+        # window, a named window) it REPLACES the one pending — what that
+        # one stood for has expired in the step, or is the new one; where
+        # it speaks for a part (the keys a partitioned step touched, one
+        # side of a join) the earlier of the pending and the new stands.
+        self._wake_replaces = False
+        # may the timer steps due up to the clock be taken as ONE step at
+        # the clock?  (the window's `timer_coalesces`)
+        self._timers_coalesce = False
         # per-query processing lock: parallel ingestion serializes PER
         # QUERY, not per app (reference: per-query ReentrantLock chosen in
         # QueryParser.java:159-215 instead of one engine-wide lock)
@@ -458,8 +469,7 @@ class _QueryRuntimeBase:
 
     def _apply_wake(self, w: int) -> None:
         self.next_wakeup = w
-        if w < _NO_WAKEUP_INT:
-            self.app._scheduler.notify_at(w, self)
+        self.app._scheduler.arm(w, self, self._wake_replaces)
 
     def _emit(self, out, now: int, wake=None) -> None:
         _emit_output(self, out, now, wake)
@@ -518,6 +528,8 @@ class QueryRuntime(_QueryRuntimeBase):
 
     def __init__(self, planned: PlannedQuery, app: "SiddhiAppRuntime"):
         super().__init__(planned, app)
+        self._wake_replaces = not planned.keyed_window
+        self._timers_coalesce = planned.window.timer_coalesces
         # force-copy every leaf: constant-folding can alias identical init
         # arrays into one buffer, which breaks donated-argument execution
         self._state = jax.tree.map(
@@ -553,7 +565,7 @@ class QueryRuntime(_QueryRuntimeBase):
         if not grouped and not p.pair_allocs:
             gslot, pslots = _zero_slots(staged.ts.shape[0]), ()
         else:
-            with _phases.phase(st, self.name, "route_keys"):
+            with _phases.phase(st, self.name, "route_keys") as sp:
                 gslot = p.slot_allocator.slots_for(
                     [staged.cols[i] for i in p.group_by_positions],
                     valid) if grouped else _zero_slots(staged.ts.shape[0])
@@ -561,6 +573,8 @@ class QueryRuntime(_QueryRuntimeBase):
                 pslots = tuple(
                     alloc.slots_for([gslot, staged.cols[pos]], valid)
                     for alloc, pos in p.pair_allocs)
+                if grouped:
+                    sp.set_metadata(bound=len(p.slot_allocator))
         if grouped or self._touch is not None:
             with _phases.phase(st, self.name, "obs_feed") as sp:
                 if grouped:
@@ -1113,6 +1127,15 @@ def _has_consumers(qr) -> bool:
     """Anything downstream that would read this output?  Checked BEFORE any
     device->host transfer so unconsumed outputs cost zero D2H traffic."""
     return bool(qr.callbacks or qr.batch_callbacks) or _target_live(qr)
+
+
+def _ts_range(events) -> Tuple[Optional[int], Optional[int]]:
+    """(earliest, latest) timestamp of an event list; (None, None) of an
+    empty one."""
+    if not events:
+        return None, None
+    stamps = [e.timestamp for e in events]
+    return min(stamps), max(stamps)
 
 
 def _earliest(wake) -> int:
@@ -2045,6 +2068,8 @@ class NamedWindowRuntime(_QueryRuntimeBase):
                 "session(gap, key) is not supported on a `define window` "
                 "shared instance; use it on a query's input stream")
         self.needs_timer = self.wproc.needs_timer
+        self._wake_replaces = True
+        self._timers_coalesce = self.wproc.timer_coalesces
         self.output_event_type = wdef.output_event_type or "ALL_EVENTS"
         self.subscribers: List = []      # QueryRuntime-likes (process_staged)
         self.stream_callbacks: List[Callable] = []
@@ -2858,16 +2883,28 @@ class _EmissionDrainer:
 
 class _Scheduler:
     """Host timer thread injecting TIMER batches
-    (reference: CORE/util/Scheduler.java:48)."""
+    (reference: CORE/util/Scheduler.java:48).
+
+    Two kinds of entry stand on the heap.  `notify_at` pushes a timer its
+    target asked for and will ask for again when it fires (a trigger, a
+    rate limiter, a purge): each fires at its own time.  `arm` keeps a
+    query runtime's ONE wake-up — the earliest time its state next
+    changes by the clock alone: re-arming replaces it, so a step that
+    leaves live rows does not pile a timer on the last step's."""
 
     def __init__(self, app: "SiddhiAppRuntime"):
         self.app = app
-        self._heap: List[Tuple[int, int, QueryRuntime]] = []
+        # (time, push order, target, is it the target's armed wake-up)
+        self._heap: List[Tuple[int, int, Any, bool]] = []
+        self._armed: Dict[Any, int] = {}
         self._cv = threading.Condition()
         self._counter = 0
         self._running = False
         self._draining = False
         self._thread: Optional[threading.Thread] = None
+        # since the app started: timer steps fired, wake-ups armed
+        self.timer_steps = 0
+        self.wakeups_armed = 0
 
     def start(self):
         if self.app.playback:
@@ -2879,21 +2916,39 @@ class _Scheduler:
         self._thread.start()
 
     def drain_playback(self, now: int) -> None:
-        if self._draining:
+        """Fire what is due at the event clock `now`, inside one
+        `timer_drain` span (`fired`: the timer steps it ran).  A wake-up
+        whose target's timer steps coalesce fires ONCE, at the clock —
+        not once for every distinct time something of its state came
+        due."""
+        if self._draining or not self._heap or self._heap[0][0] > now:
             return
         self._draining = True
         try:
-            while self._heap and self._heap[0][0] <= now:
-                ts, _, q = heapq.heappop(self._heap)
-                self._fire(q, ts)
+            with _phases.phase(self.app.stats, None, "timer_drain",
+                               clock=now) as span:
+                fired = 0
+                while self._heap and self._heap[0][0] <= now:
+                    ts, q, armed = self._pop()
+                    self._fire(q, now if armed and q._timers_coalesce
+                               else ts)
+                    fired += 1
+                span.set_metadata(fired=fired)
         finally:
             self._draining = False
 
     def pending(self) -> int:
         """Timers on the heap right now (the `siddhi_timers_pending`
-        gauge).  `notify_at` pushes without looking for an entry the
-        target already has, so this is where a pile-up shows."""
+        gauge): at most one a query runtime, plus the periodic ones."""
         return len(self._heap)
+
+    def _pop(self):
+        """(time, target, was it the target's armed wake-up) of the
+        earliest entry, taken off the heap."""
+        ts, _, q, armed = heapq.heappop(self._heap)
+        if armed:
+            del self._armed[q]
+        return ts, q, armed
 
     def _fire(self, q, ts: int) -> None:
         """One timer step under the target's query lock, inside a `timer`
@@ -2908,6 +2963,7 @@ class _Scheduler:
             lk = q.__dict__.setdefault("_qlock", threading.RLock())
         name = q.name if isinstance(q, _QueryRuntimeBase) \
             else getattr(q, "stream_id", "timer")
+        self.timer_steps += 1
         with _phases.phase(self.app.stats, name, "timer",
                            pending=len(self._heap)), lk:
             q.on_timer(ts)
@@ -2919,10 +2975,30 @@ class _Scheduler:
         if self._thread:
             self._thread.join(timeout=2.0)
 
-    def notify_at(self, ts: int, q: QueryRuntime) -> None:
+    def notify_at(self, ts: int, q) -> None:
+        self._push(ts, q, False)
+
+    def arm(self, ts: int, q, replace: bool) -> None:
+        """`q`'s one wake-up is at `ts` (`_NO_WAKEUP_INT` or later: none).
+        `replace` False keeps the earlier of the pending and the new."""
+        with self._cv:
+            old = self._armed.get(q)
+            if old is not None:
+                if old == ts or (not replace and old <= ts):
+                    return
+                del self._armed[q]
+                self._heap = [e for e in self._heap
+                              if not (e[3] and e[2] is q)]
+                heapq.heapify(self._heap)
+            if ts < _NO_WAKEUP_INT:
+                self._armed[q] = ts
+                self.wakeups_armed += 1
+                self._push(ts, q, True)
+
+    def _push(self, ts: int, q, armed: bool) -> None:
         with self._cv:
             self._counter += 1
-            heapq.heappush(self._heap, (ts, self._counter, q))
+            heapq.heappush(self._heap, (ts, self._counter, q, armed))
             self._cv.notify_all()
 
     def _run(self):
@@ -2933,12 +3009,12 @@ class _Scheduler:
                 if not self._heap:
                     self._cv.wait(timeout=0.2)
                     continue
-                ts, _, q = self._heap[0]
+                ts = self._heap[0][0]
                 now = self.app.timestamp_millis()
                 if ts > now:
                     self._cv.wait(timeout=min((ts - now) / 1000.0, 0.2))
                     continue
-                heapq.heappop(self._heap)
+                ts, q, _ = self._pop()
             try:
                 # serialized against the target's ingestion workers
                 self._fire(q, max(ts, self.app.timestamp_millis()))
@@ -3990,20 +4066,48 @@ class SiddhiAppRuntime:
         with _phases.phase(self.stats, junction.sub_names(), "stage"):
             staged = self._stage_columns(junction.schema, cols, timestamps)
         n = staged.n
-        ts = staged.ts
-        if self.playback and n:
-            with self._lock:   # vs the idle-advance thread's bump
-                self._playback_time = max(self._playback_time,
-                                          int(ts[:n].max()))
-                self._playback_last_wall = current_millis()
-        now = self.timestamp_millis()
         if self.playback:
-            with self._lock:
-                self._scheduler.drain_playback(now)
-        elif junction._async_q is not None:
+            ts = staged.ts[:n]
+            now = self._playback_advance(
+                int(ts.min()) if n and self._scheduler.pending() else None,
+                int(ts.max()) if n else None)
+            junction.dispatch_staged(staged, now)
+            self._playback_behind(now)
+            return
+        now = self.timestamp_millis()
+        if junction._async_q is not None:
             junction.enqueue("staged", staged, now)
             return
         junction.dispatch_staged(staged, now)
+
+    def _playback_advance(self, first: Optional[int],
+                          last: Optional[int]) -> int:
+        """@app:playback: move the event clock with the rows about to be
+        dispatched — `first` and `last` their earliest and latest
+        timestamp (None: no rows; `first` None: nothing is pending, so
+        nothing can be due before them) — and return the `now` they are
+        dispatched with.  Timers due at or before the FIRST row fire
+        here, on that row's clock, before any row is processed; the clock
+        then stands at the LAST row.  What comes due among the rows is
+        the step's own to expire in its place (a sliding time window
+        merges its expiries with the arrivals by time) and fires behind
+        them (`_playback_behind`): no timer runs ahead of the rows of its
+        own send."""
+        with self._lock:   # vs the idle-advance thread's bump
+            if last is not None:
+                self._playback_last_wall = current_millis()
+            if first is not None and first > self._playback_time:
+                self._playback_time = first
+            self._scheduler.drain_playback(self._playback_time)
+            if last is not None and last > self._playback_time:
+                self._playback_time = last
+            return self._playback_time
+
+    def _playback_behind(self, now: int) -> None:
+        """Timers that came due among the rows just dispatched, and any
+        the rows armed in the past."""
+        with self._lock:
+            self._scheduler.drain_playback(now)
 
     def _stage_columns(self, schema, cols, timestamps) -> ev.StagedBatch:
         """Columnar pad/adopt staging of one send_columns call."""
@@ -4046,34 +4150,25 @@ class SiddhiAppRuntime:
     def _route(self, stream_id: str, events: List[ev.Event]) -> None:
         if stream_id in self.named_windows:
             nw = self.named_windows[stream_id]
-            if self.playback and events:
-                with self._lock:
-                    self._playback_time = max(
-                        self._playback_time,
-                        max(e.timestamp for e in events))
-                    self._playback_last_wall = current_millis()
-            now = self.timestamp_millis()
-            if self.playback:
-                with self._lock:
-                    self._scheduler.drain_playback(now)
+            now = self._playback_advance(*_ts_range(events)) \
+                if self.playback else self.timestamp_millis()
             with nw._qlock:
                 nw.process_staged(ev.pack_np(nw.schema, events), now)
+            if self.playback:
+                self._playback_behind(now)
             return
         junction = self.junctions.get(stream_id)
         if junction is None:
             raise DefinitionNotExistError(f"undefined stream {stream_id!r}")
-        if self.playback and events:
-            with self._lock:
-                self._playback_time = max(self._playback_time,
-                                          max(e.timestamp for e in events))
-                self._playback_last_wall = current_millis()
-        now = self.timestamp_millis()
         if self.playback:
-            # in playback, fire timers the event clock has passed first
-            # (they are earlier in event time than the new events)
-            with self._lock:
-                self._scheduler.drain_playback(now)
-        elif junction._async_q is not None:
+            # timers the event clock has passed by the first event fire
+            # first (they are earlier in event time than the new events)
+            now = self._playback_advance(*_ts_range(events))
+            junction.publish(events, now)
+            self._playback_behind(now)
+            return
+        now = self.timestamp_millis()
+        if junction._async_q is not None:
             junction.enqueue("pub", events, now)
             return
         junction.publish(events, now)
@@ -4137,6 +4232,13 @@ class SiddhiAppRuntime:
     def timers_pending(self) -> int:
         """Timers on the scheduler's heap (siddhi_timers_pending)."""
         return self._scheduler.pending()
+
+    def timer_facts(self) -> Dict[str, int]:
+        """The scheduler's books: timers `pending` now; `timer_steps`
+        fired and `wakeups_armed` since the app started."""
+        s = self._scheduler
+        return {"pending": s.pending(), "timer_steps": s.timer_steps,
+                "wakeups_armed": s.wakeups_armed}
 
     def serve_rings(self) -> Dict[str, "object"]:
         """{query: EmissionRing} for every runtime that has opened a

@@ -140,6 +140,11 @@ class WindowProcessor:
     # `admit`, for a reader that wants no other kind of row (a CURRENT-only
     # projection join's trigger side, core/join.py `expired_joined`)
     current_is_arrivals = False
+    # True where ONE timer step at a later clock does what a step at each
+    # time something came due would do in turn — the same rows, with the
+    # same stamps, in the same order: the scheduler then runs one step for
+    # a clock that jumped, not one for every distinct time passed
+    timer_coalesces = False
 
     def __init__(self, schema: ev.Schema, params: List[Constant],
                  batch_capacity: int, capacity_hint: int = 1024):
@@ -371,6 +376,9 @@ class TimeWindow(WindowProcessor):
 
     name = "time"
     needs_timer = True
+    # a TIMER step expires everything due by its `now`, each EXPIRED row
+    # stamped with its own expiry time, in expiry order
+    timer_coalesces = True
 
     def __init__(self, schema, params, batch_capacity, capacity_hint=2048):
         super().__init__(schema, params, batch_capacity)
@@ -391,70 +399,77 @@ class TimeWindow(WindowProcessor):
         B = rows.capacity
         t = self.time_ms
 
-        is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
-        ncur = jnp.sum(is_cur.astype(jnp.int64))
+        # two device-trace sections (jax.named_scope: op-name metadata), as
+        # the length batch's: `window_fill` builds the rows the step emits —
+        # the entries due by `now` as EXPIRED, the arrivals as CURRENT,
+        # merged by time — and `window_state` the buffer it keeps; the
+        # emission's sort is `window_order` (`sort_rows`)
+        with jax.named_scope("window_fill"):
+            is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
 
-        # ordering: merge (existing entries' expiries <= now) and arrivals by
-        # time; seq = 2*rank within this batch via sorting a combined key.
-        # Assign arrivals local order first.
-        k = jnp.cumsum(is_cur.astype(jnp.int64)) - 1
+            # Candidate expiries from the old buffer
+            exp_due = jnp.logical_and(buf.alive, buf.expire_ts <= now)
 
-        # Candidate expiries from the old buffer
-        exp_due = jnp.logical_and(buf.alive, buf.expire_ts <= now)
+            # ordering: merge (existing entries' expiries <= now) and
+            # arrivals by time — expired entries (key=expire_ts, pri 0) +
+            # current arrivals (key=ts, pri 1); seq = rank in that order
+            em_ts = jnp.concatenate([buf.expire_ts, rows.ts])
+            em_pri = jnp.concatenate([jnp.zeros((C,), jnp.int64),
+                                      jnp.ones((B,), jnp.int64)])
+            em_valid = jnp.concatenate([exp_due, is_cur])
+            em_key = jnp.where(em_valid, em_ts * 2 + em_pri, BIG_SEQ)
+            order = jnp.argsort(em_key, stable=True)      # [C+B]
+            rank = jnp.zeros((C + B,), jnp.int64).at[order].set(
+                jnp.arange(C + B, dtype=jnp.int64))
+            seqs = seq0 + rank
 
-        # Build combined "emission" list: expired entries (key=expire_ts, pri 0)
-        # + current arrivals (key=ts, pri 1)
-        em_ts = jnp.concatenate([buf.expire_ts, rows.ts])
-        em_pri = jnp.concatenate([jnp.zeros((C,), jnp.int64),
-                                  jnp.ones((B,), jnp.int64)])
-        em_valid = jnp.concatenate([exp_due, is_cur])
-        em_key = jnp.where(em_valid, em_ts * 2 + em_pri, BIG_SEQ)
-        order = jnp.argsort(em_key, stable=True)      # [C+B]
-        rank = jnp.zeros((C + B,), jnp.int64).at[order].set(
-            jnp.arange(C + B, dtype=jnp.int64))
-        seqs = seq0 + rank
+            exp_rows = Rows(
+                ts=buf.expire_ts,           # reference stamps expiry time
+                kind=jnp.full((C,), ev.EXPIRED, jnp.int32),
+                valid=exp_due,
+                seq=seqs[:C],
+                gslot=buf.gslot,
+                cols=buf.cols,
+            )
+            cur_rows = Rows(
+                ts=rows.ts, kind=jnp.full((B,), ev.CURRENT, jnp.int32),
+                valid=is_cur, seq=seqs[C:], gslot=rows.gslot,
+                cols=rows.cols,
+            )
+            both = concat_rows(exp_rows, cur_rows)
+        out = sort_rows(both)
 
-        exp_rows = Rows(
-            ts=buf.expire_ts,               # reference stamps expiry time
-            kind=jnp.full((C,), ev.EXPIRED, jnp.int32),
-            valid=exp_due,
-            seq=seqs[:C],
-            gslot=buf.gslot,
-            cols=buf.cols,
-        )
-        cur_rows = Rows(
-            ts=rows.ts, kind=jnp.full((B,), ev.CURRENT, jnp.int32),
-            valid=is_cur, seq=seqs[C:], gslot=rows.gslot, cols=rows.cols,
-        )
-        out = sort_rows(concat_rows(exp_rows, cur_rows))
-
-        # new buffer = (old alive minus expired) + arrivals; compact by age
-        keep_old = jnp.logical_and(buf.alive, jnp.logical_not(exp_due))
-        cand_ts = jnp.concatenate([buf.ts, rows.ts])
-        cand_add = jnp.concatenate([buf.add_seq, seqs[C:]])
-        cand_expts = jnp.concatenate([buf.expire_ts, rows.ts + t])
-        cand_gslot = jnp.concatenate([buf.gslot, rows.gslot])
-        cand_cols = tuple(jnp.concatenate([bc, rc])
-                          for bc, rc in zip(buf.cols, rows.cols))
-        cand_valid = jnp.concatenate([keep_old, is_cur])
-        cand_key = jnp.where(cand_valid, cand_add, BIG_SEQ)
-        corder = jnp.argsort(cand_key)                # oldest first
-        total = jnp.sum(cand_valid.astype(jnp.int64))
-        # overflow: drop OLDEST if total > C (keep most recent C)
-        drop = jnp.maximum(total - C, 0)
-        sel = jnp.clip(jnp.arange(C, dtype=jnp.int64) + drop, 0, C + B - 1)
-        pos = corder[sel.astype(jnp.int32)]
-        svalid = (jnp.arange(C, dtype=jnp.int64) + drop) < total
-        nbuf = Buffer(
-            ts=cand_ts[pos], add_seq=jnp.where(svalid, cand_add[pos], BIG_SEQ),
-            expire_seq=jnp.full((C,), BIG_SEQ, jnp.int64),
-            expire_ts=jnp.where(svalid, cand_expts[pos], BIG_SEQ),
-            alive=svalid, gslot=cand_gslot[pos],
-            cols=tuple(c[pos] for c in cand_cols),
-        )
-        nseq = seq0 + rank.max() + 1
-        nseq = jnp.where(jnp.any(em_valid), nseq, seq0)
-        wake = jnp.min(jnp.where(nbuf.alive, nbuf.expire_ts, NO_WAKEUP))
+        with jax.named_scope("window_state"):
+            # new buffer = (old alive minus expired) + arrivals; compact by
+            # age
+            keep_old = jnp.logical_and(buf.alive, jnp.logical_not(exp_due))
+            cand_ts = jnp.concatenate([buf.ts, rows.ts])
+            cand_add = jnp.concatenate([buf.add_seq, seqs[C:]])
+            cand_expts = jnp.concatenate([buf.expire_ts, rows.ts + t])
+            cand_gslot = jnp.concatenate([buf.gslot, rows.gslot])
+            cand_cols = tuple(jnp.concatenate([bc, rc])
+                              for bc, rc in zip(buf.cols, rows.cols))
+            cand_valid = jnp.concatenate([keep_old, is_cur])
+            cand_key = jnp.where(cand_valid, cand_add, BIG_SEQ)
+            corder = jnp.argsort(cand_key)                # oldest first
+            total = jnp.sum(cand_valid.astype(jnp.int64))
+            # overflow: drop OLDEST if total > C (keep most recent C)
+            drop = jnp.maximum(total - C, 0)
+            sel = jnp.clip(jnp.arange(C, dtype=jnp.int64) + drop, 0,
+                           C + B - 1)
+            pos = corder[sel.astype(jnp.int32)]
+            svalid = (jnp.arange(C, dtype=jnp.int64) + drop) < total
+            nbuf = Buffer(
+                ts=cand_ts[pos],
+                add_seq=jnp.where(svalid, cand_add[pos], BIG_SEQ),
+                expire_seq=jnp.full((C,), BIG_SEQ, jnp.int64),
+                expire_ts=jnp.where(svalid, cand_expts[pos], BIG_SEQ),
+                alive=svalid, gslot=cand_gslot[pos],
+                cols=tuple(c[pos] for c in cand_cols),
+            )
+            nseq = seq0 + rank.max() + 1
+            nseq = jnp.where(jnp.any(em_valid), nseq, seq0)
+            wake = jnp.min(jnp.where(nbuf.alive, nbuf.expire_ts, NO_WAKEUP))
         return ((nbuf, nseq), WindowOutput(out, nbuf, wake))
 
 

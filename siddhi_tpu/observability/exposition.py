@@ -115,9 +115,14 @@ def render_prometheus(runtimes: Dict) -> str:
                 "Device outputs sitting in the async emission drainer "
                 "queue right now")
     t_pend = fam("siddhi_timers_pending", "gauge",
-                 "Timers on the app scheduler's heap right now (every "
-                 "wake-up is pushed without looking for one the query "
-                 "already has, so a pile-up shows here)")
+                 "Timers on the app scheduler's heap right now: at most "
+                 "one wake-up a query runtime, plus the periodic ones (a "
+                 "pile-up would show here)")
+    t_steps = fam("siddhi_timer_steps_total", "counter",
+                  "Timer steps the app scheduler has fired")
+    t_armed = fam("siddhi_wakeups_armed_total", "counter",
+                  "Wake-ups query runtimes have armed (a re-arm at the "
+                  "time already pending is not one)")
     w_full = fam("siddhi_window_slab_full_total", "counter",
                  "Sampled fill probes that found a window slab full, per "
                  "query: past this point a time window drops its OLDEST "
@@ -352,8 +357,11 @@ def render_prometheus(runtimes: Dict) -> str:
                 q_dep.sample(n, app=app_name, stream=sid)
         if hasattr(rt, "drainer_depth"):
             d_dep.sample(rt.drainer_depth(), app=app_name)
-        if hasattr(rt, "timers_pending"):
-            t_pend.sample(rt.timers_pending(), app=app_name)
+        if hasattr(rt, "timer_facts"):
+            facts = rt.timer_facts()
+            t_pend.sample(facts["pending"], app=app_name)
+            t_steps.sample(facts["timer_steps"], app=app_name)
+            t_armed.sample(facts["wakeups_armed"], app=app_name)
         # serving-loop gauges: ring occupancy per query + drainer
         # backlog (host-side deque length reads — never a fetch)
         if hasattr(rt, "ring_occupancies"):

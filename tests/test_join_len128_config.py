@@ -283,16 +283,22 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # texts without debug info again the parent's byte for byte); PR 48 for
 # `lengthbatch_1000` alone (`window.py` + 33 lines above `LengthBatchWindow`:
 # the windows' `current_is_arrivals` / `admit`; the text without debug info
-# the parent's byte for byte, `pattern_1m`'s two digests unmoved).
+# the parent's byte for byte, `pattern_1m`'s two digests unmoved); PR 51 for
+# all three (the scheduler's one wake-up a runtime and the playback clock:
+# `runtime.py`'s lines move from `_QueryRuntimeBase.__init__` down, its
+# junction and handler frames with them; `window.py` + 15 lines above
+# `LengthBatchWindow`: `timer_coalesces`, `TimeWindow.process`' sections; the
+# texts without debug info of all 60 programs of the nine accepted cells the
+# parent's byte for byte).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "e748d92ab90d86ef8452b1809b03b18e1c761e07bf14df6a9eb50305e6a19a1d"},
+        "76093c659148d19fe1e8a59c3f02bf265c261ee0302a389cadd25b73bf45938a"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "0a303feb0056670bc4c1db61c6eb2a919e1e5bb52721eb96df24db81898b11c7",
+        "05e2ac2b000cd6ec7a31f51f744f6809f4b7b9bda0415e6042916680f13ff95b",
         "step[TradeStream]":
-        "445e42d677ca567d2591fd80e8614a57365e6cb8beecb8b7d8fe1fc6fe0cff36"},
+        "f73caec3c537043a4b6a20082723a1aad54c4666dbe1afd3764204ec498537c0"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)

@@ -30,6 +30,7 @@ LIB = os.path.join(ROOT, "siddhi_tpu")
 # CHANGES.md, PR 45), and the two facts of the class beside them
 RUNTIME_FIELDS = {
     "planned", "app", "callbacks", "batch_callbacks", "next_wakeup",
+    "_wake_replaces", "_timers_coalesce",
     "_qlock", "_query_ast", "async_emit", "pipeline_emit", "serve_emit",
     "serve_ring_capacity", "_fuse", "_fuse_requested", "_fuse_excluded",
     "_replan", "table_op", "rate_limiter", "_merged", "_merge_excluded",

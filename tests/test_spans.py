@@ -863,26 +863,29 @@ def dispatches_per_send(rt, n_sends, events=64):
     return per_send, pending
 
 
-def test_timers_pile_up_under_playback_with_a_time_window(manager):
-    """Pins today's growth (PERF.md section 7): `notify_at` pushes a
-    wake-up without looking for one the query already has, every step
-    that leaves live rows pushes one, every timer that fires pushes the
-    next — so under @app:playback each send runs one more timer step than
-    the last.  The program PR that keeps one timer per query moves these
-    numbers to 1, 2, 2, 2, ... and the gauge to 1."""
+def test_a_time_window_holds_one_wake_up_under_playback(manager):
+    """PR 51 (A12): a query runtime holds ONE pending wake-up, which a
+    step's wake replaces — until then `notify_at` pushed one for every step
+    that left live rows and every timer that fired pushed the next, so each
+    send ran one more timer step than the last (1, 1, 3, 4, 5, ... was
+    pinned here).  Sends 600 ms apart: from the third on, one timer step
+    (the batch two sends back expires) and the send's own."""
     rt = manager.create_siddhi_app_runtime(
         "@app:statistics('BASIC')\n" + TIMEWINDOW_QL.format(window=4096))
     rt.add_batch_callback("q", lambda ts, b: None)
     rt.start()
     per_send, pending = dispatches_per_send(rt, 8)
-    assert per_send[:5] == [1, 1, 3, 4, 5]
-    assert per_send == sorted(per_send) and per_send[-1] >= 8
-    assert pending == sorted(pending) and pending[-1] > pending[1] >= 1
+    assert per_send == [1, 1, 2, 2, 2, 2, 2, 2]
+    assert pending == [1] * 8
+    assert rt.timer_facts() == {"pending": 1, "timer_steps": 6,
+                                "wakeups_armed": 7}
     from siddhi_tpu.observability import render_prometheus
     from siddhi_tpu.observability.health import app_health
     text = render_prometheus(manager.runtimes)
-    assert f'siddhi_timers_pending{{app="Timers"}} {pending[-1]}' in text
-    assert app_health(rt)["timers_pending"] == pending[-1]
+    assert 'siddhi_timers_pending{app="Timers"} 1' in text
+    assert 'siddhi_timer_steps_total{app="Timers"} 6' in text
+    assert 'siddhi_wakeups_armed_total{app="Timers"} 7' in text
+    assert app_health(rt)["timers_pending"] == 1
 
 
 def test_timer_span_carries_the_heap_depth(manager, tmp_path):
@@ -896,8 +899,15 @@ def test_timer_span_carries_the_heap_depth(manager, tmp_path):
                             np.full(16, 1.0, np.float32)],
                            timestamps=np.full(16, 1000 + 600 * i, np.int64))
     timers = [e for e in events() if e["name"] == "timer"]
-    assert timers and all(e["q"] == "q" for e in timers)
-    assert max(e["pending"] for e in timers) >= 1
+    assert len(timers) == 2 and all(e["q"] == "q" for e in timers)
+    # the heap's depth while it fires: the query's one wake-up is off it
+    assert [e["pending"] for e in timers] == [0, 0]
+    # each inside the `timer_drain` of the send whose clock made it due
+    drains = [e for e in events() if e["name"] == "timer_drain"]
+    assert [(d["fired"], d["clock"]) for d in drains] == [(1, 2200),
+                                                          (1, 2800)]
+    for t, d in zip(timers, drains):
+        assert d["start"] <= t["start"] and t["end"] <= d["end"]
     # a timer step is a dispatch like any other, nested in its timer span
     for t in timers:
         assert [e for e in events() if e["name"] == "dispatch"
