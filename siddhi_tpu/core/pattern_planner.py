@@ -27,25 +27,12 @@ from .pattern import PatternExec, PatternSpec, linearize, oh_take
 from .pattern_block import block_eligible, block_layout, make_block_step
 from .selector import SelectorExec
 from .window import NO_WAKEUP, Rows
-from .steputil import jit_step, pmin_i64
+from .steputil import (from_u32_planes, jit_step, join64, pmin_i64, split64,
+                       u32_planes)
 
 # test hook: force the sequential scan path even for block-eligible specs
 # (golden cross-checks compare the two implementations on the same input)
 _FORCE_SCAN = False
-
-
-def split64(x):
-    """(low words, high words) of an int64 array, as u32.  Both halves
-    are in u32's range before the convert, so it is exact on every
-    backend (no reliance on a wrapping narrow)."""
-    return ((x & 0xFFFFFFFF).astype(jnp.uint32),
-            lax.shift_right_logical(
-                x, jnp.asarray(32, jnp.int64)).astype(jnp.uint32))
-
-
-def join64(lo, hi):
-    """The int64 array of `split64`'s two planes."""
-    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
 
 
 class StatePacker:
@@ -590,7 +577,7 @@ def _gathering(body):
     their host is the busier side — and so do the @fuse stacks.
 
     ONE gather for all the columns and the timestamp: their u32 planes
-    (`_planes`) stacked `[P, B]` and gathered along B.  The v5e gathers by
+    (`u32_planes`) stacked `[P, B]` and gathered along B.  The v5e gathers by
     the SLICE, not by the element: six planes a slice cost 0.88 ms at
     262,144 slices where six gathers of one element cost 1.87 ms EACH —
     and 5.3-7.2 ms each once XLA's memory-space assignment put their
@@ -602,8 +589,8 @@ def _gathering(body):
             arrays = (*raw_cols, raw_ts)
             csel = jnp.clip(sel_idx, 0, raw_ts.shape[0] - 1)
             planes = iter(jnp.stack(
-                [pl for a in arrays for pl in _planes(a)])[:, csel])
-            *cols, ts = (_from_planes(planes, a.dtype) for a in arrays)
+                [pl for a in arrays for pl in u32_planes(a)])[:, csel])
+            *cols, ts = (from_u32_planes(planes, a.dtype) for a in arrays)
         return body(packed, sel_state, tuple(cols), ts, sel_idx, key_ref,
                     now, in_tabs)
     return step
@@ -867,32 +854,9 @@ def band_edges(R: int) -> tuple:
     return (*edges, R)
 
 
-def _planes(x) -> list:
-    """The u32 planes of one row array (the emission wire's, and the
-    stacked column gather's, `_gathering`): a 64-bit
-    array as (low words, high words) — XLA:TPU keeps it so anyway, and a
-    `device_get` of an 8-byte dtype costs ten times a 4-byte one's (6.8
-    against 0.67 ms at 262,144 elements: PERF.md, PR 31) — a 4-byte one
-    bit for bit, a bool as 0 / 1."""
-    if x.dtype.itemsize == 8:      # int64: the device has no other
-        return list(split64(x))
-    if x.dtype.itemsize == 4:
-        return [lax.bitcast_convert_type(x, jnp.uint32)]
-    return [x.astype(jnp.uint32)]
-
-
-def _from_planes(planes, dtype):
-    """`_planes` undone on the device: the next plane(s) of the iterator
-    as one array of `dtype`."""
-    if np.dtype(dtype).itemsize == 8:
-        return join64(next(planes), next(planes))
-    if np.dtype(dtype).itemsize == 4:
-        return lax.bitcast_convert_type(next(planes), dtype)
-    return next(planes).astype(dtype)
-
-
 def unpack_planes(bufs, dtypes, shards: int = 1) -> list:
-    """Host side of `_planes`: `bufs` are the fetched u32 buffers of the
+    """Host side of `steputil.u32_planes`: `bufs` are the fetched u32
+    buffers of the
     bands delivery took (each: every shard's planes of `dtypes`' arrays
     over the shard's slots of the band, shard after shard), the result
     one array a dtype over all of their slots, the bands end to end.
@@ -935,7 +899,7 @@ class BandedEmission:
     `ranks_used` is the highest per-key row count of the tier, clipped to
     its R — a key with c rows fills ranks 0 .. c-1, so no row sits at or
     above it.  `bands[b]` = (head, cols): two u32 buffers holding the
-    planes (`_planes`) of ranks [edges[b], edges[b + 1]) x K slots,
+    planes (`u32_planes`) of ranks [edges[b], edges[b + 1]) x K slots,
     rank-major — `head` the timestamp's two planes and `kind | valid <<
     31`, `cols` the output columns' planes in schema order.  Band 0 is
     one rank, so a buffer's size says how many ranks it holds.
@@ -1034,11 +998,11 @@ def compact_emission(out, EP: int, K: int, compact_rows: int,
     with jax.named_scope("emission_bands"):
         kind_valid = cmp(okind).astype(jnp.uint32) | \
             (cmask.astype(jnp.uint32) << _VALID_BIT)
-        head = _planes(cmp(ots)) + [kind_valid]
+        head = u32_planes(cmp(ots)) + [kind_valid]
         # the columns in the out schema's own dtypes: what the host
         # decodes the planes by (runtime._EmissionRows)
         body = [pl for c, t in zip(ocols, band_types)
-                for pl in _planes(cmp(c).astype(ev.dtype_of(t)))]
+                for pl in u32_planes(cmp(c).astype(ev.dtype_of(t)))]
 
         def band(planes, lo, hi):                  # [R,K] each -> u32 wire
             return jnp.concatenate(

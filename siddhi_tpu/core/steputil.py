@@ -25,6 +25,9 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 
 def pmin_i64(x, axis: str):
@@ -151,3 +154,44 @@ def jit_step(fn, owner=None, role=None, **jit_kwargs):
     except Exception:  # noqa: BLE001 — attribute support is best-effort
         pass
     return jitted
+
+
+# ---------------------------------------------------------------------------
+# row arrays as u32 planes: how a step moves or ships rows whole
+
+def split64(x):
+    """(low words, high words) of an int64 array, as u32.  Both halves
+    are in u32's range before the convert, so it is exact on every
+    backend (no reliance on a wrapping narrow)."""
+    return ((x & 0xFFFFFFFF).astype(jnp.uint32),
+            lax.shift_right_logical(
+                x, jnp.asarray(32, jnp.int64)).astype(jnp.uint32))
+
+
+def join64(lo, hi):
+    """The int64 array of `split64`'s two planes."""
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+
+
+def u32_planes(x) -> list:
+    """The u32 planes of one row array (the emission wire's, and a stacked
+    row gather's: `pattern_planner._gathering`, `window.gather_packed`): a
+    64-bit array as (low words, high words) — XLA:TPU keeps it so anyway,
+    and a `device_get` of an 8-byte dtype costs ten times a 4-byte one's
+    (6.8 against 0.67 ms at 262,144 elements: PERF.md, PR 31) — a 4-byte
+    one bit for bit, a bool as 0 / 1."""
+    if x.dtype.itemsize == 8:      # int64: the device has no other
+        return list(split64(x))
+    if x.dtype.itemsize == 4:
+        return [lax.bitcast_convert_type(x, jnp.uint32)]
+    return [x.astype(jnp.uint32)]
+
+
+def from_u32_planes(planes, dtype):
+    """`u32_planes` undone on the device: the next plane(s) of the
+    iterator as one array of `dtype`."""
+    if np.dtype(dtype).itemsize == 8:
+        return join64(next(planes), next(planes))
+    if np.dtype(dtype).itemsize == 4:
+        return lax.bitcast_convert_type(next(planes), dtype)
+    return next(planes).astype(dtype)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from ..query_api.expression import Constant
 from . import event as ev
+from .steputil import from_u32_planes, u32_planes
 
 BIG_SEQ = jnp.iinfo(jnp.int64).max // 4  # "never expired"
 NO_WAKEUP = jnp.iinfo(jnp.int64).max // 4
@@ -397,13 +398,18 @@ class TimeWindow(WindowProcessor):
         buf, seq0 = state
         C = self.capacity
         B = rows.capacity
+        N = C + B
         t = self.time_ms
 
         # two device-trace sections (jax.named_scope: op-name metadata), as
         # the length batch's: `window_fill` builds the rows the step emits —
         # the entries due by `now` as EXPIRED, the arrivals as CURRENT,
-        # merged by time — and `window_state` the buffer it keeps; the
-        # emission's sort is `window_order` (`sort_rows`)
+        # merged by time and laid out in that order — and `window_state`
+        # the buffer it keeps.  Each is one argsort and ONE gather of whole
+        # rows (`gather_packed`): on the v5e the sorts were never the cost
+        # (0.38 ms for the 139,264 keys of the benchmark's window) — a
+        # scatter of the ranks (17.4 ms) and a gather an array (8.4 ms
+        # against 0.47 packed) were (PERF.md, PR 52)
         with jax.named_scope("window_fill"):
             is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
 
@@ -412,63 +418,62 @@ class TimeWindow(WindowProcessor):
 
             # ordering: merge (existing entries' expiries <= now) and
             # arrivals by time — expired entries (key=expire_ts, pri 0) +
-            # current arrivals (key=ts, pri 1); seq = rank in that order
+            # current arrivals (key=ts, pri 1); the rows that are neither
+            # behind them, as they stand
             em_ts = jnp.concatenate([buf.expire_ts, rows.ts])
             em_pri = jnp.concatenate([jnp.zeros((C,), jnp.int64),
                                       jnp.ones((B,), jnp.int64)])
             em_valid = jnp.concatenate([exp_due, is_cur])
             em_key = jnp.where(em_valid, em_ts * 2 + em_pri, BIG_SEQ)
-            order = jnp.argsort(em_key, stable=True)      # [C+B]
-            rank = jnp.zeros((C + B,), jnp.int64).at[order].set(
-                jnp.arange(C + B, dtype=jnp.int64))
-            seqs = seq0 + rank
-
-            exp_rows = Rows(
-                ts=buf.expire_ts,           # reference stamps expiry time
-                kind=jnp.full((C,), ev.EXPIRED, jnp.int32),
-                valid=exp_due,
-                seq=seqs[:C],
-                gslot=buf.gslot,
-                cols=buf.cols,
+            order = jnp.argsort(em_key, stable=True).astype(
+                jnp.int32)                                # [C+B]
+            # a row's seq is its place in that order, so the emission in
+            # seq order is the gather by `order` itself; an EXPIRED row
+            # carries its expiry time (the reference stamps it)
+            gslot = jnp.concatenate([buf.gslot, rows.gslot])
+            cols = tuple(jnp.concatenate([bc, rc])
+                         for bc, rc in zip(buf.cols, rows.cols))
+            o_ts, o_valid, o_gslot, *o_cols = gather_packed(
+                (em_ts, em_valid, gslot) + cols, order)
+            out = Rows(
+                ts=o_ts,
+                kind=jnp.where(order < C, ev.EXPIRED,
+                               ev.CURRENT).astype(jnp.int32),
+                valid=o_valid,
+                seq=seq0 + jnp.arange(N, dtype=jnp.int64),
+                gslot=o_gslot, cols=tuple(o_cols),
             )
-            cur_rows = Rows(
-                ts=rows.ts, kind=jnp.full((B,), ev.CURRENT, jnp.int32),
-                valid=is_cur, seq=seqs[C:], gslot=rows.gslot,
-                cols=rows.cols,
-            )
-            both = concat_rows(exp_rows, cur_rows)
-        out = sort_rows(both)
+            # the arrivals' places, which the buffer keeps as `add_seq`:
+            # `order` inverted
+            rank = jnp.argsort(order).astype(jnp.int64)
+            nseq = jnp.where(jnp.any(em_valid), seq0 + N, seq0)
 
         with jax.named_scope("window_state"):
             # new buffer = (old alive minus expired) + arrivals; compact by
             # age
             keep_old = jnp.logical_and(buf.alive, jnp.logical_not(exp_due))
             cand_ts = jnp.concatenate([buf.ts, rows.ts])
-            cand_add = jnp.concatenate([buf.add_seq, seqs[C:]])
+            cand_add = jnp.concatenate([buf.add_seq, seq0 + rank[C:]])
             cand_expts = jnp.concatenate([buf.expire_ts, rows.ts + t])
-            cand_gslot = jnp.concatenate([buf.gslot, rows.gslot])
-            cand_cols = tuple(jnp.concatenate([bc, rc])
-                              for bc, rc in zip(buf.cols, rows.cols))
             cand_valid = jnp.concatenate([keep_old, is_cur])
             cand_key = jnp.where(cand_valid, cand_add, BIG_SEQ)
-            corder = jnp.argsort(cand_key)                # oldest first
+            corder = jnp.argsort(cand_key).astype(
+                jnp.int32)                                # oldest first
             total = jnp.sum(cand_valid.astype(jnp.int64))
-            # overflow: drop OLDEST if total > C (keep most recent C)
+            # overflow: drop OLDEST if total > C (keep most recent C) —
+            # `drop` is at most B, so the C kept are a slice of the order
             drop = jnp.maximum(total - C, 0)
-            sel = jnp.clip(jnp.arange(C, dtype=jnp.int64) + drop, 0,
-                           C + B - 1)
-            pos = corder[sel.astype(jnp.int32)]
+            pos = jax.lax.dynamic_slice(corder, (drop,), (C,))
             svalid = (jnp.arange(C, dtype=jnp.int64) + drop) < total
+            n_ts, n_add, n_expts, n_gslot, *n_cols = gather_packed(
+                (cand_ts, cand_add, cand_expts, gslot) + cols, pos)
             nbuf = Buffer(
-                ts=cand_ts[pos],
-                add_seq=jnp.where(svalid, cand_add[pos], BIG_SEQ),
+                ts=n_ts,
+                add_seq=jnp.where(svalid, n_add, BIG_SEQ),
                 expire_seq=jnp.full((C,), BIG_SEQ, jnp.int64),
-                expire_ts=jnp.where(svalid, cand_expts[pos], BIG_SEQ),
-                alive=svalid, gslot=cand_gslot[pos],
-                cols=tuple(c[pos] for c in cand_cols),
+                expire_ts=jnp.where(svalid, n_expts, BIG_SEQ),
+                alive=svalid, gslot=n_gslot, cols=tuple(n_cols),
             )
-            nseq = seq0 + rank.max() + 1
-            nseq = jnp.where(jnp.any(em_valid), nseq, seq0)
             wake = jnp.min(jnp.where(nbuf.alive, nbuf.expire_ts, NO_WAKEUP))
         return ((nbuf, nseq), WindowOutput(out, nbuf, wake))
 
@@ -780,3 +785,16 @@ def create_window(name: str, schema: ev.Schema, params, batch_capacity: int,
                            f"available: {sorted(WINDOW_TYPES)}")
     return WINDOW_TYPES[name](schema, params, batch_capacity,
                               capacity_hint=capacity_hint)
+
+
+def gather_packed(arrays, idx):
+    """`tuple(a[idx] for a in arrays)` over `[n]` row arrays, as ONE gather
+    of whole rows: the arrays' u32 planes (`steputil.u32_planes`) stacked
+    `[planes, n]` and gathered along n, as `pattern_planner._gathering`
+    moves its columns.  XLA:TPU gathers by the slice, not by the element —
+    the time window's planes at 139,264 rows move in ~0.5 ms together and
+    in 8.4 ms an array at a time (PERF.md, PR 52).  `idx` must be in
+    bounds."""
+    packed = jnp.stack([p for a in arrays for p in u32_planes(a)])
+    planes = iter(packed.at[:, idx].get(mode="promise_in_bounds"))
+    return tuple(from_u32_planes(planes, a.dtype) for a in arrays)

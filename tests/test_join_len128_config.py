@@ -289,16 +289,23 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # junction and handler frames with them; `window.py` + 15 lines above
 # `LengthBatchWindow`: `timer_coalesces`, `TimeWindow.process`' sections; the
 # texts without debug info of all 60 programs of the nine accepted cells the
-# parent's byte for byte).
+# parent's byte for byte); PR 52 for all three (`steputil.py` + 3 lines
+# above `jit_step`, whose frame every program carries: the u32-plane helpers
+# and their imports moved there from `pattern_planner.py`, whose lines move
+# up by 13 below its imports and 23 more below `band_edges`; `window.py` + 1
+# line from the top — that import — and + 5 above `LengthBatchWindow`:
+# `TimeWindow.process` as one argsort and one packed gather a section; the
+# texts without debug info of the nine other cells' programs are pinned
+# since then in `tests/test_accepted_cells_text.py`).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "76093c659148d19fe1e8a59c3f02bf265c261ee0302a389cadd25b73bf45938a"},
+        "cd581766d1e34069ba2d3b7873a2537226aa31031e905e85568a789cbcf5362e"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "05e2ac2b000cd6ec7a31f51f744f6809f4b7b9bda0415e6042916680f13ff95b",
+        "c570bbc161bdf76001eeb9b7860eb09b20f6922004934404f9fe959d4a7bfa3f",
         "step[TradeStream]":
-        "f73caec3c537043a4b6a20082723a1aad54c4666dbe1afd3764204ec498537c0"},
+        "f9f56aca0c83505c1edf67569857f7c40d81f4e7ce580e2e248b3ac367a81e6e"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)

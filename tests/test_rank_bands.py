@@ -42,7 +42,7 @@ def test_planes_round_trip_every_dtype_bit_for_bit(shards):
     buf = np.concatenate([
         np.asarray(pl)[s * per:(s + 1) * per]
         for s in range(shards)
-        for a in arrays for pl in pp._planes(jnp.asarray(a))])
+        for a in arrays for pl in pp.u32_planes(jnp.asarray(a))])
     assert buf.dtype == np.uint32 and buf.size == 5 * n
     back = pp.unpack_planes([buf, buf], [a.dtype for a in arrays], shards)
     for a, b in zip(arrays, back):
