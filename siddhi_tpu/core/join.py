@@ -629,7 +629,11 @@ def plan_join_query(
                         jnp.arange(C, dtype=jnp.int32)[None, :], (R, C))
                 m = jnp.logical_and(m, data_row[:, None])
 
-            with jax.named_scope("join_pairs"):
+            # `join_pairs` has three parts (a second scope level, listed in
+            # observability/phases.py): `index` — the flags, `pos` / `li` /
+            # `ri`, the composed group slot — `take_this` (what is gathered
+            # by `li`) and `take_other` (what is gathered by `ri`)
+            with jax.named_scope("join_pairs"), jax.named_scope("index"):
                 # matched pair rows [R*Q] + unmatched rows [R] for outer
                 # joins: their flags are complete when the probe ends
                 Q = m.shape[1]
@@ -652,59 +656,71 @@ def plan_join_query(
                                         stable=True)[:cap]
                     n_tot = jnp.sum(all_valid).astype(jnp.int32)
             with jax.named_scope("join_pairs"):
-                # ri carries REAL buffer positions so seq/order match the
-                # grid path bit for bit
-                right_idx = ri2.astype(jnp.int32).reshape(-1)
-                if emit_unmatched_this:
-                    right_idx = jnp.concatenate(
-                        [right_idx, jnp.zeros((R,), jnp.int32)])
-                if order is None:
-                    # every candidate row, in flat pair position
-                    pos, ri = jnp.arange(N, dtype=jnp.int32), right_idx
-                    row_valid = all_valid
-                else:
-                    # the cap's rows alone; valid-first and stable, so the
-                    # first n_tot of them are the valid ones
-                    pos = order.astype(jnp.int32)
-                    ri = right_idx[pos]
-                    row_valid = jnp.arange(cap, dtype=jnp.int32) < n_tot
-                # the outer join's unmatched rows stand after the R*Q pairs
-                tail = pos >= R * Q
-                li = jnp.where(tail, pos - R * Q, pos // Q)
-                null_tail = jnp.logical_and(tail, row_valid)
+                with jax.named_scope("index"):
+                    # ri carries REAL buffer positions so seq/order match
+                    # the grid path bit for bit
+                    right_idx = ri2.astype(jnp.int32).reshape(-1)
+                    if emit_unmatched_this:
+                        right_idx = jnp.concatenate(
+                            [right_idx, jnp.zeros((R,), jnp.int32)])
+                    if order is None:
+                        # every candidate row, in flat pair position
+                        pos, ri = jnp.arange(N, dtype=jnp.int32), right_idx
+                        row_valid = all_valid
+                    else:
+                        # the cap's rows alone; valid-first and stable, so
+                        # the first n_tot of them are the valid ones
+                        pos = order.astype(jnp.int32)
+                        ri = right_idx[pos]
+                        row_valid = jnp.arange(cap, dtype=jnp.int32) < n_tot
+                    # the outer join's unmatched rows stand after the R*Q
+                    # pairs
+                    tail = pos >= R * Q
+                    li = jnp.where(tail, pos - R * Q, pos // Q)
+                    null_tail = jnp.logical_and(tail, row_valid)
 
-                this_cols = tuple(c[li] for c in t_cols)
+                with jax.named_scope("take_this"):
+                    this_cols = tuple(c[li] for c in t_cols)
                 # unmatched outer-join rows carry REAL nulls on the other side
                 # (reference: JoinProcessor.java:107-190 emits null attributes;
                 # numerics use the reserved in-band null, core/event.py)
-                other_cols_g = tuple(
-                    jnp.where(null_tail,
-                              jnp.asarray(ev.null_value(t), dtype=c.dtype),
-                              c[ri])
-                    for c, t in zip(o_cols, other.schema.types))
-                sel_env = {
-                    this.key: this_cols,
-                    other.key: other_cols_g,
-                    "__ts__": orows.ts[li],
-                    "__now__": now,
-                }
+                with jax.named_scope("take_other"):
+                    other_cols_g = tuple(
+                        jnp.where(null_tail,
+                                  jnp.asarray(ev.null_value(t),
+                                              dtype=c.dtype),
+                                  c[ri])
+                        for c, t in zip(o_cols, other.schema.types))
+                with jax.named_scope("take_this"):
+                    sel_env = {
+                        this.key: this_cols,
+                        other.key: other_cols_g,
+                        "__ts__": orows.ts[li],
+                        "__now__": now,
+                    }
+                    tg = orows.gslot[li]
                 # composed group slot: gl * (Kr + 1) + gr; unmatched outer rows
                 # take the other side's null-group id (K_other)
-                tg = orows.gslot[li]
-                og = jnp.where(null_tail, K_other,
-                               o_gslot[jnp.clip(ri, 0, C - 1)])
-                if this_is_left:
-                    comp = tg * (Kr + 1) + og
-                else:
-                    comp = og * (Kr + 1) + tg
-                jrows = Rows(
-                    ts=orows.ts[li],
-                    kind=orows.kind[li],
-                    valid=row_valid,
-                    seq=orows.seq[li] * (C + 1) + ri,
-                    gslot=comp.astype(jnp.int32),
-                    cols=(),
-                )
+                with jax.named_scope("take_other"):
+                    og = jnp.where(null_tail, K_other,
+                                   o_gslot[jnp.clip(ri, 0, C - 1)])
+                with jax.named_scope("index"):
+                    if this_is_left:
+                        comp = tg * (Kr + 1) + og
+                    else:
+                        comp = og * (Kr + 1) + tg
+                with jax.named_scope("take_this"):
+                    j_ts, j_kind, j_seq = \
+                        orows.ts[li], orows.kind[li], orows.seq[li]
+                with jax.named_scope("index"):
+                    jrows = Rows(
+                        ts=j_ts,
+                        kind=j_kind,
+                        valid=row_valid,
+                        seq=j_seq * (C + 1) + ri,
+                        gslot=comp.astype(jnp.int32),
+                        cols=(),
+                    )
             with jax.named_scope("join_select"):
                 sel_state, out = sel.process(sel_state, jrows, sel_env)
             with jax.named_scope("join_compact"):

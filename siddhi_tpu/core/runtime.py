@@ -908,40 +908,39 @@ class PatternQueryRuntime(_QueryRuntimeBase):
                              int(key_idx_np[0]) + Kb <= cap and
                              int(key_idx_np[n - 1]) ==
                              int(key_idx_np[0]) + n - 1)
-                    with _phases.tier_scope(t if len(tiers) > 1 else None):
-                        with _phases.phase(st, self.name, "h2d",
-                                           bytes=_phases.nbytes(*cols)):
-                            cols_d = tuple(jax.numpy.asarray(c)
-                                           for c in cols)
-                        if dense and self._dirty is not None:
-                            # the dense step also time-ticks slots beyond
-                            # nuniq
-                            self._dirty[int(key_idx_np[0]):
-                                        int(key_idx_np[0]) + Kb] = True
-                        with _phases.phase(
-                                st, self.name, "h2d",
-                                bytes=_phases.nbytes(delta, sel_np)):
-                            # the base rides the step call as the numpy
-                            # scalar it is: an upload call of its own costs
-                            # as much as the delta's
-                            ts_d = (ts_base, jax.numpy.asarray(delta))
-                            sel_d = jax.numpy.asarray(sel_np)
-                            if dense:
-                                key_d = jax.numpy.asarray(
-                                    int(key_idx_np[0]), jax.numpy.int32)
-                            elif key_idx_np is not None:
-                                key_d = jax.numpy.asarray(key_idx_np)
-                            else:
-                                key_d = jax.numpy.asarray(
-                                    np.zeros((1,), np.int32))
-                            if now_d is None:   # one upload serves all
-                                now_d = jax.numpy.asarray(now,
-                                                          jax.numpy.int64)
-                        steps = p.dense_steps if dense else p.steps
-                        if not dense and key_idx_np is not None:
-                            moved.append(key_idx_np)
-                        outs.append(self._step(steps[stream_id], cols_d,
-                                               *ts_d, sel_d, key_d, now_d))
+                    with _phases.phase(st, self.name, "h2d",
+                                       bytes=_phases.nbytes(*cols)):
+                        cols_d = tuple(jax.numpy.asarray(c)
+                                       for c in cols)
+                    if dense and self._dirty is not None:
+                        # the dense step also time-ticks slots beyond
+                        # nuniq
+                        self._dirty[int(key_idx_np[0]):
+                                    int(key_idx_np[0]) + Kb] = True
+                    with _phases.phase(
+                            st, self.name, "h2d",
+                            bytes=_phases.nbytes(delta, sel_np)):
+                        # the base rides the step call as the numpy
+                        # scalar it is: an upload call of its own costs
+                        # as much as the delta's
+                        ts_d = (ts_base, jax.numpy.asarray(delta))
+                        sel_d = jax.numpy.asarray(sel_np)
+                        if dense:
+                            key_d = jax.numpy.asarray(
+                                int(key_idx_np[0]), jax.numpy.int32)
+                        elif key_idx_np is not None:
+                            key_d = jax.numpy.asarray(key_idx_np)
+                        else:
+                            key_d = jax.numpy.asarray(
+                                np.zeros((1,), np.int32))
+                        if now_d is None:   # one upload serves all
+                            now_d = jax.numpy.asarray(now,
+                                                      jax.numpy.int64)
+                    steps = p.dense_steps if dense else p.steps
+                    if not dense and key_idx_np is not None:
+                        moved.append(key_idx_np)
+                    outs.append(self._step(steps[stream_id], cols_d,
+                                           *ts_d, sel_d, key_d, now_d))
             finally:
                 # fed whether or not every tier was dispatched: a tier that
                 # ran has advanced its keys' state, and no snapshot or

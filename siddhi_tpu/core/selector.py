@@ -337,21 +337,29 @@ class AggregatorBank:
         # two device-trace sections (jax.named_scope: op-name metadata):
         # `agg_layout` is every sort and unsort — the segment ids, the
         # argsort by (slot, reset epoch), the permutation back — and
-        # `agg_scan` the contributions, the segmented scans and the carry
+        # `agg_scan` the contributions, the segmented scans and the carry.
+        # Inside each, every op stands under a PART (a second scope level,
+        # listed in observability/phases.py): `agg_layout` / `keys`,
+        # `order`, `invert`, `to_sorted`, `from_sorted`; `agg_scan` /
+        # `scan`, `store`
         B = rows.capacity
         in_order = self.layout == "in_order"
         with jax.named_scope("agg_layout"):
-            sign = jnp.where(
-                jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
-                jnp.where(jnp.logical_and(rows.valid,
-                                          rows.kind == ev.EXPIRED), -1, 0))
-            gslot = None if in_order else jnp.where(
-                rows.gslot >= 0, rows.gslot, 0).astype(jnp.int32)
+            with jax.named_scope("keys"):
+                sign = jnp.where(
+                    jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
+                    jnp.where(jnp.logical_and(rows.valid,
+                                              rows.kind == ev.EXPIRED),
+                              -1, 0))
+                gslot = None if in_order else jnp.where(
+                    rows.gslot >= 0, rows.gslot, 0).astype(jnp.int32)
 
-            is_reset = jnp.logical_and(rows.valid, rows.kind == ev.RESET)
-            reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))  # after row i
-            epoch_before = reset_epoch - is_reset.astype(jnp.int64)
-            total_resets = reset_epoch[-1]
+                is_reset = jnp.logical_and(rows.valid,
+                                           rows.kind == ev.RESET)
+                # after row i
+                reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))
+                epoch_before = reset_epoch - is_reset.astype(jnp.int64)
+                total_resets = reset_epoch[-1]
 
             def heads(seg_s):
                 return jnp.concatenate([
@@ -363,23 +371,33 @@ class AggregatorBank:
                     # count that never decreases along the rows, so the
                     # stable argsort below is arange and every gather by
                     # it a copy — the rows are scanned where they stand
-                    return (None, None, epoch_before, heads(epoch_before),
+                    with jax.named_scope("keys"):
+                        first = heads(epoch_before)
+                    return (None, None, epoch_before, first,
                             sign, None, epoch_before)
-                # segment id: (slot, epoch); rows already seq-ordered
-                seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
-                order = jnp.argsort(seg, stable=True)
-                unorder = jnp.zeros((B,), jnp.int32).at[order].set(
-                    jnp.arange(B, dtype=jnp.int32))
-                seg_s = seg[order]
-                return (order, unorder, seg_s, heads(seg_s), sign[order],
-                        slot_vec[order], epoch_before[order])
+                with jax.named_scope("keys"):
+                    # segment id: (slot, epoch); rows already seq-ordered
+                    seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
+                with jax.named_scope("order"):
+                    order = jnp.argsort(seg, stable=True)
+                with jax.named_scope("invert"):
+                    unorder = jnp.zeros((B,), jnp.int32).at[order].set(
+                        jnp.arange(B, dtype=jnp.int32))
+                with jax.named_scope("to_sorted"):
+                    seg_s = seg[order]
+                with jax.named_scope("keys"):
+                    first = heads(seg_s)
+                with jax.named_scope("to_sorted"):
+                    return (order, unorder, seg_s, first, sign[order],
+                            slot_vec[order], epoch_before[order])
 
             layouts = {None: layout(gslot)}
             for j in range(len(self.pair_sources)):
                 ps = env.get(f"__pslot__{j}")
                 if ps is not None:
-                    layouts[j] = layout(
-                        jnp.where(ps >= 0, ps, 0).astype(jnp.int32))
+                    with jax.named_scope("keys"):
+                        pslot = jnp.where(ps >= 0, ps, 0).astype(jnp.int32)
+                    layouts[j] = layout(pslot)
 
         env = dict(env)
         env["__scanres__"] = results = []
@@ -390,25 +408,27 @@ class AggregatorBank:
             # slot count from the STATE shape, not the plan: under
             # shard_map each device owns a K/n slice of the slot axis
             K = st.shape[0]
-            with jax.named_scope("agg_scan"):
+            with jax.named_scope("agg_scan"), jax.named_scope("scan"):
                 vals = spec.vals_fn(env, sign)
                 # rows that don't contribute carry the identity
                 vals = jnp.where(sign != 0, vals,
                                  jnp.asarray(spec.init, spec.dtype))
-            with jax.named_scope("agg_layout"):
+            with jax.named_scope("agg_layout"), \
+                    jax.named_scope("to_sorted"):
                 v_s = vals if order is None else vals[order]
-            with jax.named_scope("agg_scan"):
+            with jax.named_scope("agg_scan"), jax.named_scope("scan"):
                 # inject carry state at heads of epoch-0 segments
                 carry = st[0] if slot_s is None else st[slot_s]
                 v_s = jnp.where(
                     jnp.logical_and(first, epoch_s == 0),
                     spec.op(carry, v_s), v_s)
                 scanned = _segmented_scan(v_s, seg_s, spec.op)
-            with jax.named_scope("agg_layout"):
+            with jax.named_scope("agg_layout"), \
+                    jax.named_scope("from_sorted"):
                 results.append(
                     scanned if unorder is None else scanned[unorder])
 
-            with jax.named_scope("agg_scan"):
+            with jax.named_scope("agg_scan"), jax.named_scope("store"):
                 # new state: per slot, value after the last row in the
                 # final epoch
                 contrib = jnp.logical_and(sign_s != 0,

@@ -94,11 +94,14 @@ def _gather_rows(rows: Rows, idx, valid):
 def sort_rows(rows: Rows) -> Rows:
     """Stable order by (valid desc, seq asc): invalid rows pushed to the
     end.  One device-trace section, `window_order`: a caller keeps it out
-    of any section of its own."""
+    of any section of its own.  Two parts: `order` (the key and its stable
+    argsort), `to_sorted` (one gather an array by it)."""
     with jax.named_scope("window_order"):
-        key = jnp.where(rows.valid, rows.seq, BIG_SEQ)
-        idx = jnp.argsort(key, stable=True)
-        return _gather_rows(rows, idx, jnp.ones_like(rows.valid)[idx])
+        with jax.named_scope("order"):
+            key = jnp.where(rows.valid, rows.seq, BIG_SEQ)
+            idx = jnp.argsort(key, stable=True)
+        with jax.named_scope("to_sorted"):
+            return _gather_rows(rows, idx, jnp.ones_like(rows.valid)[idx])
 
 
 def concat_rows(a: Rows, b: Rows) -> Rows:

@@ -43,7 +43,38 @@ message for them (device time by section and by rectangle).  The plain
 query step (core/planner.py `jit_plain_step`) has its own, each named where
 the work is: `plain_chain`, `window_fill`, `window_state`, `window_order`,
 `agg_layout`, `agg_scan`, `project` (`benchmarks/harness/
-plain_sections.py`).
+plain_sections.py`), and the join's two side programs (core/join.py)
+`join_window`, `join_lanes`, `join_probe`, `join_pairs`, `join_select`,
+`join_compact` (`benchmarks/harness/join_sections.py`).  A tiered send's
+dispatches are told apart on the device side, by their `rect_<Kb>x<E>`.
+
+Inside a section that owns a step a second scope level names PARTS: what a
+group of its ops is FOR, in the words of the code that owns it.  Every op
+of such a section stands under one part, so an op a later edit adds outside
+them shows as part `""`.  A device op's `tf_op` then reads
+`jit(plain_step)/agg_layout/to_sorted/gather:` — section, part, ... jax
+primitive — and `benchmarks/harness/section_ops.py` books every op of a
+traced slice under (program, section, part, primitive):
+
+  agg_layout    (selector.AggregatorBank.process)
+    keys        sign, slot, reset epochs, segment ids, segment heads
+    order       the stable argsort by (slot, reset epoch)
+    invert      the inverse permutation, `.at[order].set(arange)`
+    to_sorted   every `x[order]`: the layout's four and `vals[order]` a spec
+    from_sorted `scanned[unorder]` a spec
+  agg_scan      (same)
+    scan        contributions, the carry at segment heads, the segmented
+                scan
+    store       the last row a slot and the write of the new state
+  window_order  (window.sort_rows)
+    order       the key and its stable argsort
+    to_sorted   one gather an array by it
+  join_pairs    (join.make_step)
+    index       the flags, `pos` / `li` / `ri`, the composed group slot
+    take_this   what is gathered by `li`
+    take_other  what is gathered by `ri`
+
+(`pattern_block`'s `event_load` is four gathers by one index and has none.)
 
 Spans (`siddhi:<name>`) and the scrape phase each feeds:
 
@@ -61,9 +92,7 @@ Spans (`siddhi:<name>`) and the scrape phase each feeds:
               send, nested in route_keys                     stage_host
   obs_feed    state observatory feed, liveness, dirty marks  stage_host
   h2d         every host->device upload (host wall)          h2d
-  dispatch    the jitted step call (submit only); `tier`
-              on it and on its uploads, where a send was
-              laid out as several tiers (tier_scope); under
+  dispatch    the jitted step call (submit only); under
               @serve also the ring's two programs: `step` =
               ring_append on the sender's thread (`occupancy`:
               the ring's entries once it is in), ring_read on
@@ -289,28 +318,6 @@ class batch_scope:
         return False
 
 
-class tier_scope:
-    """The spans a thread opens inside carry `tier=<i>`: the i-th [Kb, E]
-    tier of a send the pattern path laid out as several (runtime
-    `PatternQueryRuntime.process_staged`), so its uploads and its dispatch
-    can be told apart (the send's emission is one, and carries none).
-    None — a send of one rectangle — adds nothing."""
-
-    __slots__ = ("tier", "prev")
-
-    def __init__(self, tier: Optional[int]):
-        self.tier = tier
-
-    def __enter__(self):
-        self.prev = getattr(_tls, "tier", None)
-        _tls.tier = self.tier
-        return self
-
-    def __exit__(self, *exc):
-        _tls.tier = self.prev
-        return False
-
-
 def handoff():
     """Token for a cross-thread delivery (the @async drainer queue, the
     @pipeline deque, the serving ring): the DETAIL trace armed for
@@ -403,9 +410,6 @@ def phase(stats, query, name: str, mult: int = 1, **meta):
     `jax.profiler.TraceAnnotation` — nothing else runs; stats known only
     at the end go on with `.set_metadata(rows=n)` either way."""
     queries = (query,) if isinstance(query, str) else (query or ())
-    tier = getattr(_tls, "tier", None)
-    if tier is not None:
-        meta["tier"] = tier
     ann = TraceAnnotation(
         SPAN_PREFIX + name, q=queries[0] if queries else "",
         batch=getattr(_tls, "batch", 0), **meta)

@@ -1,12 +1,15 @@
 """The programs of the nine cells that do not run a `window.time` lower to the
-text they lowered to at b993d59 (PR 51), byte for byte: sha256 of
+text they lowered to at b993d59 (PR 51), and the time window's cell to
+ca1471e's (PR 52), byte for byte: sha256 of
 `fn.lower(*specs).as_text()` — WITHOUT debug info, so a line that moves
 does not trip it; an op that changes does — of every program each cell runs
 through its prefill and warm-up at rehearsal sizes (`benchmarks/harness`'
 own `Deployment`, `--rehearse`'s sizes), keyed by query, role and a digest
 of the argument shapes.  PR 52 changed `TimeWindow.process` and moved the
 u32-plane helpers from `pattern_planner` to `steputil`; no op these cells
-trace.  A PR that changes one
+trace.  `timewindow_256sym.paced`, the tenth, is pinned from ca1471e (PR 52)
+by PR 53, whose part scopes (`tests/test_section_parts.py`) move no op of
+any cell.  A PR that changes one
 of these programs ON PURPOSE re-pins its cell from its own parent:
 `python tests/test_accepted_cells_text.py <cell>...` prints the digests."""
 import hashlib
@@ -53,6 +56,9 @@ PARENTS = {
     },
     "sequence_within.saturated": {
         "q:step[S]:f2232b4e": "cce5b8c313a3f769f8bb7e7b95b15dc3740d9389563b50ed19996899ed75cf9d"
+    },
+    "timewindow_256sym.paced": {
+        "q:step:2e2fd6ee": "5e3eafc250db938b9c29e24258a42772b08f4118557d52725da2e5d2e9108e2c"
     }
 }
 

@@ -296,16 +296,24 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # line from the top — that import — and + 5 above `LengthBatchWindow`:
 # `TimeWindow.process` as one argsort and one packed gather a section; the
 # texts without debug info of the nine other cells' programs are pinned
-# since then in `tests/test_accepted_cells_text.py`).
+# since then in `tests/test_accepted_cells_text.py`); PR 53 for all three
+# (the part scopes: `selector.py` + 20 lines inside `AggregatorBank.process`
+# and so above `SelectorExec.process`, `window.py` + 3 inside `sort_rows`;
+# `phases.tier_scope` gone and the parts listed in its docstring:
+# `phases.py` + 4 lines above `dispatch`, whose frame every program
+# carries, `runtime.py` - 1, and `PatternQueryRuntime.process_staged`'s
+# upload and dispatch block one `with` shallower, so its frames' lines and
+# columns moved; the texts without debug info of ALL TEN cells' programs
+# the parent's byte for byte: `tests/test_accepted_cells_text.py`).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "cd581766d1e34069ba2d3b7873a2537226aa31031e905e85568a789cbcf5362e"},
+        "95a49c284204cffe8426f695160ffb3685634d271fcb3352b9364eaad8673209"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "c570bbc161bdf76001eeb9b7860eb09b20f6922004934404f9fe959d4a7bfa3f",
+        "e6bc241e78894673020e0bb228117cdb0ee73c187375fb7dc2a854b315838799",
         "step[TradeStream]":
-        "f9f56aca0c83505c1edf67569857f7c40d81f4e7ce580e2e248b3ac367a81e6e"},
+        "ce14d86348c1a56a0aa7f743e5834a143e258ea5d45b0b7ea391a97e45af0f27"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)

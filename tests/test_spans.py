@@ -257,7 +257,9 @@ def test_tiered_send_feeds_once_after_the_last_of_its_dispatches(
         disp = [e for e in inside if e["name"] == "dispatch"]
         # (slots are bound in arrival order, so a tier's may be contiguous)
         assert {d["step"] for d in disp} <= {"pattern_step", "pattern_dense"}
-        assert [d["tier"] for d in disp] == [2, 1, 0]     # hottest first
+        # three tiers, three dispatches; no span says which tier is whose
+        # (the device side does: each program's `rect_<Kb>x<E>`)
+        assert len(disp) == 3 and not any("tier" in d for d in disp)
         (feed,) = _prep_feeds(inside)
         assert feed["keys"] == counts.size and "tier" not in feed
         assert disp[-1]["end"] <= feed["start"] and \
@@ -562,8 +564,9 @@ def test_self_time_is_the_span_minus_its_children_on_the_thread(monkeypatch):
 def test_route_keys_layout_stats_are_summed_and_tiers_are_named(
         monkeypatch):
     """`route_keys`' `tiers` / `cells` / `max_e` / `ticks` are summed under
-    stage_host's `route_keys` part (a span without them adds nothing), and
-    inside a `tier_scope` every span carries `tier`."""
+    stage_host's `route_keys` part (a span without them adds nothing); no
+    span carries a `tier` stat (PR 53: `tier_scope` had no reader — the
+    device side names a tier by its `rect_<Kb>x<E>`)."""
     clock = _DrivenClock()
     monkeypatch.setattr(ph, "time", clock)
     st = _stats()
@@ -583,15 +586,9 @@ def test_route_keys_layout_stats_are_summed_and_tiers_are_named(
                               "ticks": 2088}
     assert part["grouped"] == {"take": 2}
     assert set(part["layout"]) == set(ph.LAYOUT_STATS)
-    # tier_scope: the metadata of what opens inside, and nothing outside
-    with ph.tier_scope(2):
-        with ph.phase(st, "q", "dispatch", step="pattern_step") as sp:
-            assert sp.meta["tier"] == 2
-        with ph.tier_scope(None):
-            with ph.phase(st, "q", "fetch", what="header") as sp:
-                assert "tier" not in sp.meta
+    assert not hasattr(ph, "tier_scope")
     with ph.phase(st, "q", "dispatch", step="pattern_step") as sp:
-        assert "tier" not in sp.meta
+        assert sp.meta == {"step": "pattern_step"}
 
 
 # -- (f) every jitted step's XLA module is jit_<role> ---------------------------
