@@ -12,10 +12,12 @@ core/keyslots.py).  Running aggregate values — Siddhi's "value after this
 event's update" semantics — are computed with *segmented associative scans*:
 rows are stably sorted by (group slot, reset epoch), an inclusive
 associative scan runs per segment, carry-in state is injected at segment
-heads, and results are unsorted back.  O(B log B), no per-event control flow,
-exact sequential semantics.  A query whose plan allocates no group slot
-holds one segment per reset epoch, already in row order: its rows are
-scanned where they stand, no sort and no permutation (`layout`).
+heads, and results are unsorted back — every column that crosses the
+permutation in ONE packed gather each way (`window.gather_packed`).
+O(B log B), no per-event control flow, exact sequential semantics.  A query
+whose plan allocates no group slot holds one segment per reset epoch,
+already in row order: its rows are scanned where they stand, no sort and no
+permutation (`layout`).
 """
 from __future__ import annotations
 
@@ -52,7 +54,7 @@ from .executor import (
     Scope,
     compile_expression,
 )
-from .window import Rows
+from .window import Rows, gather_packed
 
 BIG = jnp.iinfo(jnp.int64).max // 4
 
@@ -80,12 +82,28 @@ class _AggSpec:
     init: Any                     # identity scalar
     dtype: Any
     # vals_fn(env, sign) -> [B] contribution per row; may read
-    # env['__scanres__'][i] (running values of earlier specs)
+    # env['__scanres__'][after], the running values of the spec it names
     vals_fn: Callable
     # segment by a pair-slot column (env['__pslot__<j>']) instead of the
     # group slot — used by distinctCount's per-(group, value) refcounts
     slot_src: Optional[int] = None
     K_override: Optional[int] = None
+    # the one spec whose running values `vals_fn` reads: `process` scans
+    # this spec a wave later (distinctCount's `dc:` reads its `ref:`)
+    after: Optional[int] = None
+
+
+@dataclasses.dataclass
+class _Layout:
+    """One (slot, reset epoch) order of a step's rows: the permutation and
+    its inverse (None: the rows stand in that order already), the slot
+    column it sorts by, and — once a gather has moved them —
+    (seg_s, first, sign_s, slot_s, epoch_s) in that order."""
+
+    order: Any
+    unorder: Any
+    slot_vec: Any
+    sorted: Optional[Tuple] = None
 
 
 class AggregatorBank:
@@ -327,7 +345,7 @@ class AggregatorBank:
                     jnp.asarray(-1, jnp.int64),
                     jnp.asarray(0, jnp.int64)))
         return self._add(_AggSpec(
-            f"dc:{expr_key}", jnp.add, 0, jnp.int64, dvals))
+            f"dc:{expr_key}", jnp.add, 0, jnp.int64, dvals, after=i_ref))
 
     # -- runtime -------------------------------------------------------------
     def process(self, state, rows: Rows, env) -> Tuple[Any, Tuple]:
@@ -336,12 +354,12 @@ class AggregatorBank:
             return state, ()
         # two device-trace sections (jax.named_scope: op-name metadata):
         # `agg_layout` is every sort and unsort — the segment ids, the
-        # argsort by (slot, reset epoch), the permutation back — and
-        # `agg_scan` the contributions, the segmented scans and the carry.
-        # Inside each, every op stands under a PART (a second scope level,
-        # listed in observability/phases.py): `agg_layout` / `keys`,
-        # `order`, `invert`, `to_sorted`, `from_sorted`; `agg_scan` /
-        # `scan`, `store`
+        # argsort by (slot, reset epoch), the rows moved into that order
+        # and back — and `agg_scan` the contributions, the segmented scans
+        # and the carry.  Inside each, every op stands under a PART (a
+        # second scope level, listed in observability/phases.py):
+        # `agg_layout` / `keys`, `order`, `invert`, `to_sorted`,
+        # `from_sorted`; `agg_scan` / `scan`, `store`
         B = rows.capacity
         in_order = self.layout == "in_order"
         with jax.named_scope("agg_layout"):
@@ -369,12 +387,13 @@ class AggregatorBank:
                 if slot_vec is None:
                     # one slot: the segment id is epoch_before, a running
                     # count that never decreases along the rows, so the
-                    # stable argsort below is arange and every gather by
-                    # it a copy — the rows are scanned where they stand
+                    # stable argsort below would be arange and every gather
+                    # by it a copy — the rows are scanned where they stand
                     with jax.named_scope("keys"):
                         first = heads(epoch_before)
-                    return (None, None, epoch_before, first,
-                            sign, None, epoch_before)
+                    return _Layout(order=None, unorder=None, slot_vec=None,
+                                   sorted=(epoch_before, first, sign, None,
+                                           epoch_before))
                 with jax.named_scope("keys"):
                     # segment id: (slot, epoch); rows already seq-ordered
                     seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
@@ -383,13 +402,7 @@ class AggregatorBank:
                 with jax.named_scope("invert"):
                     unorder = jnp.zeros((B,), jnp.int32).at[order].set(
                         jnp.arange(B, dtype=jnp.int32))
-                with jax.named_scope("to_sorted"):
-                    seg_s = seg[order]
-                with jax.named_scope("keys"):
-                    first = heads(seg_s)
-                with jax.named_scope("to_sorted"):
-                    return (order, unorder, seg_s, first, sign[order],
-                            slot_vec[order], epoch_before[order])
+                return _Layout(order, unorder, slot_vec)
 
             layouts = {None: layout(gslot)}
             for j in range(len(self.pair_sources)):
@@ -399,59 +412,98 @@ class AggregatorBank:
                         pslot = jnp.where(ps >= 0, ps, 0).astype(jnp.int32)
                     layouts[j] = layout(pslot)
 
-        env = dict(env)
-        env["__scanres__"] = results = []
-        new_state = []
-        for spec, st in zip(self.specs, state):
-            (order, unorder, seg_s, first, sign_s, slot_s,
-             epoch_s) = layouts[spec.slot_src]
-            # slot count from the STATE shape, not the plan: under
-            # shard_map each device owns a K/n slice of the slot axis
-            K = st.shape[0]
-            with jax.named_scope("agg_scan"), jax.named_scope("scan"):
-                vals = spec.vals_fn(env, sign)
-                # rows that don't contribute carry the identity
-                vals = jnp.where(sign != 0, vals,
-                                 jnp.asarray(spec.init, spec.dtype))
-            with jax.named_scope("agg_layout"), \
-                    jax.named_scope("to_sorted"):
-                v_s = vals if order is None else vals[order]
-            with jax.named_scope("agg_scan"), jax.named_scope("scan"):
-                # inject carry state at heads of epoch-0 segments
-                carry = st[0] if slot_s is None else st[slot_s]
-                v_s = jnp.where(
-                    jnp.logical_and(first, epoch_s == 0),
-                    spec.op(carry, v_s), v_s)
-                scanned = _segmented_scan(v_s, seg_s, spec.op)
-            with jax.named_scope("agg_layout"), \
-                    jax.named_scope("from_sorted"):
-                results.append(
-                    scanned if unorder is None else scanned[unorder])
+        def to_sorted(lay, vals):
+            """`vals` in `lay`'s order: ONE packed gather, which the first
+            time takes the layout's own columns along — the segment ids are
+            made again from the moved (slot, epoch), not moved."""
+            if lay.order is None:
+                return vals
+            own = () if lay.sorted else (sign, lay.slot_vec, epoch_before)
+            with jax.named_scope("agg_layout"):
+                with jax.named_scope("to_sorted"):
+                    moved = gather_packed((*own, *vals), lay.order)
+                if own:
+                    with jax.named_scope("keys"):
+                        sign_s, slot_s, epoch_s = moved[:3]
+                        seg_s = slot_s.astype(jnp.int64) * (B + 2) + epoch_s
+                        lay.sorted = (seg_s, heads(seg_s), sign_s, slot_s,
+                                      epoch_s)
+            return moved[len(own):]
 
-            with jax.named_scope("agg_scan"), jax.named_scope("store"):
-                # new state: per slot, value after the last row in the
-                # final epoch
-                contrib = jnp.logical_and(sign_s != 0,
-                                          epoch_s == total_resets)
-                idx = jnp.arange(B)
-                if slot_s is None:
-                    # slot 0's is a max-reduce; no row names another slot
-                    last = jnp.max(jnp.where(contrib, idx, -1))
-                    last_idx = jnp.where(
-                        jnp.arange(K) == 0, last, -1).astype(jnp.int32)
-                else:
-                    # scatter-max of sorted index per contributing slot
-                    last_idx = jnp.full((K,), -1, jnp.int32).at[
-                        jnp.where(contrib, slot_s, K).astype(jnp.int32)
-                    ].max(jnp.where(contrib, idx, -1).astype(jnp.int32),
-                          mode="drop")
-                has = last_idx >= 0
-                gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
-                base = jnp.where(total_resets > 0,
-                                 jnp.full((K,), spec.init, spec.dtype), st)
-                # carry survives only if no reset happened
-                ns = jnp.where(has, gathered, base)
-            new_state.append(ns)
+        # WAVES: a spec is scanned after the spec whose running values its
+        # contributions read (`after`); the specs of a wave that share a
+        # layout cross its permutation together.  No permutation
+        # (`in_order`), nothing to share: each spec by itself, in its turn
+        depth: List[int] = []
+        waves: List[Dict[Any, List[int]]] = []
+        for i, spec in enumerate(self.specs):
+            depth.append(0 if spec.after is None else depth[spec.after] + 1)
+            if depth[i] == len(waves):
+                waves.append({})
+            waves[depth[i]].setdefault(
+                i if in_order else spec.slot_src, []).append(i)
+
+        env = dict(env)
+        env["__scanres__"] = results = [None] * len(self.specs)
+        new_state = [None] * len(self.specs)
+        for members in (m for wave in waves for m in wave.values()):
+            lay = layouts[self.specs[members[0]].slot_src]
+            with jax.named_scope("agg_scan"), jax.named_scope("scan"):
+                vals = []
+                for i in members:
+                    spec = self.specs[i]
+                    v = spec.vals_fn(env, sign)
+                    # rows that don't contribute carry the identity
+                    vals.append(jnp.where(
+                        sign != 0, v, jnp.asarray(spec.init, spec.dtype)))
+            vals_s = to_sorted(lay, vals)
+            seg_s, first, sign_s, slot_s, epoch_s = lay.sorted
+            scans = []
+            for i, v_s in zip(members, vals_s):
+                spec, st = self.specs[i], state[i]
+                # slot count from the STATE shape, not the plan: under
+                # shard_map each device owns a K/n slice of the slot axis
+                K = st.shape[0]
+                with jax.named_scope("agg_scan"), jax.named_scope("scan"):
+                    # inject carry state at heads of epoch-0 segments
+                    carry = st[0] if slot_s is None else st[slot_s]
+                    v_s = jnp.where(
+                        jnp.logical_and(first, epoch_s == 0),
+                        spec.op(carry, v_s), v_s)
+                    scanned = _segmented_scan(v_s, seg_s, spec.op)
+                scans.append(scanned)
+
+                with jax.named_scope("agg_scan"), jax.named_scope("store"):
+                    # new state: per slot, value after the last row in the
+                    # final epoch
+                    contrib = jnp.logical_and(sign_s != 0,
+                                              epoch_s == total_resets)
+                    idx = jnp.arange(B)
+                    if slot_s is None:
+                        # slot 0's is a max-reduce; no row names another
+                        # slot
+                        last = jnp.max(jnp.where(contrib, idx, -1))
+                        last_idx = jnp.where(
+                            jnp.arange(K) == 0, last, -1).astype(jnp.int32)
+                    else:
+                        # scatter-max of sorted index per contributing slot
+                        last_idx = jnp.full((K,), -1, jnp.int32).at[
+                            jnp.where(contrib, slot_s, K).astype(jnp.int32)
+                        ].max(jnp.where(contrib, idx, -1).astype(jnp.int32),
+                              mode="drop")
+                    has = last_idx >= 0
+                    gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
+                    base = jnp.where(
+                        total_resets > 0,
+                        jnp.full((K,), spec.init, spec.dtype), st)
+                    # carry survives only if no reset happened
+                    new_state[i] = jnp.where(has, gathered, base)
+            if lay.unorder is not None:
+                with jax.named_scope("agg_layout"), \
+                        jax.named_scope("from_sorted"):
+                    scans = gather_packed(scans, lay.unorder)
+            for i, scanned in zip(members, scans):
+                results[i] = scanned
 
         return tuple(new_state), tuple(results)
 

@@ -60,8 +60,8 @@ traced slice under (program, section, part, primitive):
     keys        sign, slot, reset epochs, segment ids, segment heads
     order       the stable argsort by (slot, reset epoch)
     invert      the inverse permutation, `.at[order].set(arange)`
-    to_sorted   every `x[order]`: the layout's four and `vals[order]` a spec
-    from_sorted `scanned[unorder]` a spec
+    to_sorted   ONE packed gather by `order`: sign, slot, epoch, every `vals`
+    from_sorted ONE packed gather by the inverse: every spec's scan
   agg_scan      (same)
     scan        contributions, the carry at segment heads, the segmented
                 scan

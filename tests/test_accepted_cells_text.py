@@ -9,7 +9,11 @@ of the argument shapes.  PR 52 changed `TimeWindow.process` and moved the
 u32-plane helpers from `pattern_planner` to `steputil`; no op these cells
 trace.  `timewindow_256sym.paced`, the tenth, is pinned from ca1471e (PR 52)
 by PR 53, whose part scopes (`tests/test_section_parts.py`) move no op of
-any cell.  A PR that changes one
+any cell, and AGAIN by PR 54 from its own tree: the selector's `sorted`
+layout there moves its rows in one packed gather each way (eleven and seven
+gathers before; `tests/test_selector_layout.py` holds the two forms to each
+other bit for bit), and the other nine — `in_order`, a projection, or no
+selector scan at all — are still the parent's.  A PR that changes one
 of these programs ON PURPOSE re-pins its cell from its own parent:
 `python tests/test_accepted_cells_text.py <cell>...` prints the digests."""
 import hashlib
@@ -58,7 +62,7 @@ PARENTS = {
         "q:step[S]:f2232b4e": "cce5b8c313a3f769f8bb7e7b95b15dc3740d9389563b50ed19996899ed75cf9d"
     },
     "timewindow_256sym.paced": {
-        "q:step:2e2fd6ee": "5e3eafc250db938b9c29e24258a42772b08f4118557d52725da2e5d2e9108e2c"
+        "q:step:2e2fd6ee": "55776da986784ffb0119a926bda462f56f7b11206efaedf02b900977e1699dd9"
     }
 }
 

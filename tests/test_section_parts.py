@@ -49,7 +49,9 @@ PROGRAMS = {
     "join_right": ("join_len128.saturated", "step[right]", {
         "join_pairs": PARTS["join_pairs"]}),
 }
-_NAME = re.compile(r'^#loc\d+ = loc\("([^"]+)"', re.M)
+_LOC = re.compile(r'^(#loc\d+) = loc\("([^"]+)"', re.M)
+_GATHER = re.compile(r'stablehlo\.gather.*: \(tensor<([^>]*)>.* loc\((#loc\d+)\)$',
+                     re.M)
 
 
 def lowered_texts(cell_name):
@@ -90,7 +92,7 @@ def texts():
 def op_names(named_text):
     """Every op name of a lowered text with debug info, as path
     components."""
-    return [name.split("/") for name in _NAME.findall(named_text)
+    return [name.split("/") for _loc, name in _LOC.findall(named_text)
             if "/" in name]
 
 
@@ -101,6 +103,32 @@ def test_the_program_names_every_part_under_its_section(program, texts):
     for section, parts in sections.items():
         under = {c[c.index(section) + 1] for c in names if section in c[:-1]}
         assert set(parts) <= under, (program, section, sorted(under))
+
+
+def gathers(named_text):
+    """Every `stablehlo.gather` of a lowered text with debug info, as (the
+    op's name, its operand's type)."""
+    name = dict(_LOC.findall(named_text))
+    return [(name[loc], operand)
+            for operand, loc in _GATHER.findall(named_text)]
+
+
+def test_the_sorted_layout_moves_its_rows_once_each_way(texts):
+    """The time-window cell's query — seven specs (`sum:` / `cnt:` of
+    `sum(price)`, of `avg(price)` and of the `having`'s `total`, and
+    `count:`), one layout, one wave: ONE gather under `to_sorted`, of the
+    layout's own three columns and the seven contributions as u32 planes,
+    and ONE under `from_sorted`, of the seven scans (PR 54; the parent made
+    eleven and seven).  The length-batch cell's `in_order` layout gathers
+    nothing (its whole text is the parent's:
+    `tests/test_accepted_cells_text.py`)."""
+    B = 10240                       # the rehearsal's rows a step
+    mine = [(n.split("/", 1)[1], t) for n, t in gathers(
+        texts("timewindow_256sym.paced")["step"][1]) if "/agg_layout/" in n]
+    assert mine == [("agg_layout/to_sorted/gather", f"16x{B}xui32"),
+                    ("agg_layout/from_sorted/gather", f"11x{B}xui32")]
+    assert not [n for n, _t in gathers(
+        texts("lengthbatch_1000.saturated")["step"][1]) if "/agg_layout/" in n]
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))

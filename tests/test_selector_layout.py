@@ -230,3 +230,185 @@ def test_in_order_layout_is_the_sorted_layout_bit_for_bit(shape, what, both):
     assert last[1].any() and any(
         not np.array_equal(bits(s), bits(z)) for s, z in
         zip(last[2], jax.device_get(selector(True).init_state())))
+
+
+# ---------------------------------------------------------------------------
+# the `sorted` layout moves its rows packed (PR 54): ONE gather into
+# (slot, epoch) order and one back a layout a wave — against the seven and
+# three one-array gathers a step it made before, kept HERE as the reference
+
+def reference_process(bank, state, rows, env):
+    """`AggregatorBank.process` as it stood at 1bba841 (PR 53), less its
+    scopes: every column gathered by `order` alone, `seg` among them, every
+    spec's scan gathered back by `unorder` alone, the specs in list order."""
+    from siddhi_tpu.core.selector import _segmented_scan
+    B = rows.capacity
+    in_order = bank.layout == "in_order"
+    sign = jnp.where(
+        jnp.logical_and(rows.valid, rows.kind == ev.CURRENT), 1,
+        jnp.where(jnp.logical_and(rows.valid, rows.kind == ev.EXPIRED),
+                  -1, 0))
+    gslot = None if in_order else jnp.where(
+        rows.gslot >= 0, rows.gslot, 0).astype(jnp.int32)
+    is_reset = jnp.logical_and(rows.valid, rows.kind == ev.RESET)
+    reset_epoch = jnp.cumsum(is_reset.astype(jnp.int64))
+    epoch_before = reset_epoch - is_reset.astype(jnp.int64)
+    total_resets = reset_epoch[-1]
+
+    def heads(seg_s):
+        return jnp.concatenate([
+            jnp.ones((1,), jnp.bool_), seg_s[1:] != seg_s[:-1]])
+
+    def layout(slot_vec):
+        if slot_vec is None:
+            return (None, None, epoch_before, heads(epoch_before), sign,
+                    None, epoch_before)
+        seg = slot_vec.astype(jnp.int64) * (B + 2) + epoch_before
+        order = jnp.argsort(seg, stable=True)
+        unorder = jnp.zeros((B,), jnp.int32).at[order].set(
+            jnp.arange(B, dtype=jnp.int32))
+        seg_s = seg[order]
+        return (order, unorder, seg_s, heads(seg_s), sign[order],
+                slot_vec[order], epoch_before[order])
+
+    layouts = {None: layout(gslot)}
+    for j in range(len(bank.pair_sources)):
+        ps = env[f"__pslot__{j}"]
+        layouts[j] = layout(jnp.where(ps >= 0, ps, 0).astype(jnp.int32))
+
+    env = dict(env)
+    env["__scanres__"] = results = []
+    new_state = []
+    for spec, st in zip(bank.specs, state):
+        (order, unorder, seg_s, first, sign_s, slot_s,
+         epoch_s) = layouts[spec.slot_src]
+        K = st.shape[0]
+        vals = spec.vals_fn(env, sign)
+        vals = jnp.where(sign != 0, vals, jnp.asarray(spec.init, spec.dtype))
+        v_s = vals if order is None else vals[order]
+        carry = st[0] if slot_s is None else st[slot_s]
+        v_s = jnp.where(jnp.logical_and(first, epoch_s == 0),
+                        spec.op(carry, v_s), v_s)
+        scanned = _segmented_scan(v_s, seg_s, spec.op)
+        results.append(scanned if unorder is None else scanned[unorder])
+        contrib = jnp.logical_and(sign_s != 0, epoch_s == total_resets)
+        idx = jnp.arange(B)
+        if slot_s is None:
+            last = jnp.max(jnp.where(contrib, idx, -1))
+            last_idx = jnp.where(
+                jnp.arange(K) == 0, last, -1).astype(jnp.int32)
+        else:
+            last_idx = jnp.full((K,), -1, jnp.int32).at[
+                jnp.where(contrib, slot_s, K).astype(jnp.int32)
+            ].max(jnp.where(contrib, idx, -1).astype(jnp.int32), mode="drop")
+        gathered = scanned[jnp.clip(last_idx, 0, B - 1)]
+        base = jnp.where(total_resets > 0,
+                         jnp.full((K,), spec.init, spec.dtype), st)
+        new_state.append(jnp.where(last_idx >= 0, gathered, base))
+    return tuple(new_state), tuple(results)
+
+
+GROUPS, VALUES = 11, 8      # group slots in use; distinct values a group
+# case -> (select list, row kinds a step, lowest gslot, keys under vmap,
+#          the (wave, layout) pairs that cross a permutation)
+PACKED = {
+    "sum_count_avg_current_expired": (
+        "sum(price) as s, count() as c, avg(price) as a", _sliding, 0, 0, 1),
+    "reset_rows_of_a_batch_window": (
+        "sum(qty) as s, count() as c, avg(price) as a",
+        _length_batch, 0, 0, 1),
+    "min_max_and_seen": (
+        "min(price) as lo, max(qty) as hi", _holes, 0, 0, 1),
+    "and_or": ("and(flag) as a, or(flag) as o", _holes, 0, 0, 1),
+    # two layouts, two waves: `sum:` / `cnt:` by group slot and `ref:` by
+    # (group, value) pair slot, then `dc:`, which reads `ref:`, by group slot
+    "distinct_count_beside_a_sum": (
+        "sum(qty) as s, distinctCount(val) as d", _sliding, 0, 0, 3),
+    "distinct_count_alone": ("distinctCount(val) as d", _holes, 0, 0, 2),
+    "gslot_minus_one": (
+        "sum(price) as s, count() as c, avg(price) as a", _holes, -1, 0, 1),
+    "under_vmap_a_keyed_window": (
+        "sum(price) as s, count() as c, max(price) as hi", _sliding, 0, 3, 1),
+}
+
+
+def grouped_bank(select):
+    app = SiddhiCompiler.parse(f"""
+        define stream S (price float, qty long, flag bool, val int);
+        @info(name='q') from S select {select} group by val insert into Out;
+        """)
+    interner = ev.StringInterner()
+    schema = ev.Schema(app.stream_definition_map["S"], interner)
+    scope = Scope()
+    scope.interner = interner
+    scope.add_source("S", schema)
+    sel = SelectorExec(app.execution_element_list[0].selector, scope, schema,
+                       16, "Out", interner)
+    assert sel.bank.layout == "sorted"
+    return sel.bank
+
+
+def grouped_rows(kinds, step, lowest, rng):
+    """`rows_of`'s shapes with a group slot a row (`lowest` = -1: some rows
+    name none) and the pair slot distinctCount's host side would hand in."""
+    shape = {v: k for k, v in SHAPES.items()}[kinds]
+    rows = rows_of(shape, step, rng)
+    gslot = rng.integers(lowest, GROUPS, B).astype(np.int32)
+    val = rng.integers(0, VALUES, B).astype(np.int32)
+    pslot = np.where(gslot >= 0, gslot * VALUES + val, -1).astype(np.int32)
+    return rows._replace(gslot=jnp.asarray(gslot),
+                         cols=rows.cols + (jnp.asarray(val),)), \
+        jnp.asarray(pslot)
+
+
+def stacked(trees):
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_packed_layout_is_the_per_array_layout_bit_for_bit(case):
+    select, kinds, lowest, keys, crossings = PACKED[case]
+    bank = grouped_bank(select)
+
+    def step(process):
+        def one(state, rows, pslot):
+            env = {"S": rows.cols, "__ts__": rows.ts, "__kind__": rows.kind,
+                   "__now__": jnp.asarray(0, jnp.int64), "__pslot__0": pslot}
+            return process(state, rows, env)
+        return jax.jit(jax.vmap(one) if keys else one)
+
+    packed = step(bank.process)
+    reference = step(lambda *a: reference_process(bank, *a))
+    rng = np.random.default_rng([54, sorted(PACKED).index(case)])
+    state = bank.init_state()
+    if keys:
+        state = stacked([state] * keys)
+    state_p = state_r = state
+    moved = 0
+    for i in range(STEPS):
+        made = [grouped_rows(kinds, i, lowest, rng)
+                for _ in range(keys or 1)]
+        rows, pslot = stacked(made) if keys else made[0]
+        state_p, scans_p = jax.device_get(packed(state_p, rows, pslot))
+        state_r, scans_r = jax.device_get(reference(state_r, rows, pslot))
+        assert len(scans_p) == len(scans_r) == len(bank.specs)
+        for got, want in zip(scans_p + state_p, scans_r + state_r):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert np.array_equal(bits(got), bits(want))
+        moved += sum(int((a != b).any()) for a, b in zip(
+            state_p, jax.device_get(state)))
+    assert moved >= len(bank.specs)       # every accumulator was at work
+
+    # what the traced program holds: one gather into a layout's order and
+    # one back for each (wave, layout), whatever the number of specs
+    waves = {(s.after is not None, s.slot_src) for s in bank.specs}
+    assert len(waves) == crossings
+    made = grouped_rows(kinds, 0, lowest, rng)
+    args = (bank.init_state(),) + made
+    if keys:
+        args = stacked([args] * keys)
+    eqns = list(equations(packed.trace(*args).jaxpr))
+    assert len(under(eqns, "gather", "to_sorted")) == crossings
+    assert len(under(eqns, "gather", "from_sorted")) == crossings
+    assert len(under(eqns, "sort", "order")) == \
+        len(under(eqns, "scatter", "invert")) == 1 + len(bank.pair_sources)
