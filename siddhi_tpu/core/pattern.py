@@ -23,9 +23,11 @@ fork/seed spawn -> in-place advance / kill / deactivate.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..query_api.expression import Expression
@@ -228,6 +230,10 @@ class PatternState(NamedTuple):
     done: Any         # bool[K]  non-every pattern already matched
     dropped: Any      # i64 scalar: forks dropped on slab overflow
     caps: Dict[str, Tuple]   # atom.ckey -> (ts[P,D,K], cols tuple [P,D,K])
+    # i64 scalar: continuations forked off a slot (a count atom past its
+    # `min`, an epsilon skip), lost ones included.  None — no leaf, the
+    # packed state and every program as they were — where no atom forks
+    forked: Any = None
 
 
 class PatternExec:
@@ -239,6 +245,9 @@ class PatternExec:
         self.P = slots
         self.S = spec.n_states
         self.interner = interner
+        # a count atom is the one thing that forks a slot's continuation
+        # (a zero-min one is also what an epsilon skip forks past)
+        self.forks = any(a.is_count for a in spec.atoms)
         # emission pruning: only captures referenced by the query's selector
         # are materialized into per-match output rows (None = all)
         self.emit_refs = emit_refs
@@ -307,6 +316,7 @@ class PatternExec:
             done=jnp.zeros((K,), jnp.bool_),
             dropped=jnp.asarray(0, jnp.int64),
             caps=caps,
+            forked=jnp.asarray(0, jnp.int64) if self.forks else None,
         )
 
     # -- one event per key ----------------------------------------------------
@@ -670,13 +680,18 @@ class PatternExec:
                 newcaps[ck] = (ts_c, cols_c)
                 continue
             D = ts_c.shape[1]
-            idx = jnp.clip(st.count, 0, D - 1)
-            ncols = tuple(
-                _set_along(c, idx, jnp.broadcast_to(
-                    ev_cols[j][None, :], idx.shape), here)
-                for j, c in enumerate(cols_c))
-            nts = _set_along(ts_c, idx, jnp.broadcast_to(
-                ev_ts[None, :], idx.shape), here)
+            # PART `count_capture` (observability/phases.py): a count
+            # atom's write is one of its D capture rows, picked by the
+            # slot's count — D selects over [P, D, K] a column a tick
+            with jax.named_scope("count_capture") if a.is_count \
+                    else contextlib.nullcontext():
+                idx = jnp.clip(st.count, 0, D - 1)
+                ncols = tuple(
+                    _set_along(c, idx, jnp.broadcast_to(
+                        ev_cols[j][None, :], idx.shape), here)
+                    for j, c in enumerate(cols_c))
+                nts = _set_along(ts_c, idx, jnp.broadcast_to(
+                    ev_ts[None, :], idx.shape), here)
             newcaps[ck] = (nts, ncols)
         st = st._replace(caps=newcaps)
 
@@ -724,9 +739,12 @@ class PatternExec:
                       for c, sc in zip(cols_c, seed_cols)))
 
         # ---- phase 6: spawn forks + seed -----------------------------------
-        st = self._spawn(st, fork, fork_tgt, fork_cnt, seed_spawn,
-                         seed_pos, seed_count, seed_side, seed_fork_also,
-                         stream_id, ev_cols, ev_ts, a0)
+        # PART `fork_spawn`: the masked ranking of candidates against free
+        # slots and the pull of every leaf, the captures among them
+        with jax.named_scope("fork_spawn"):
+            st = self._spawn(st, fork, fork_tgt, fork_cnt, seed_spawn,
+                             seed_pos, seed_count, seed_side, seed_fork_also,
+                             stream_id, ev_cols, ev_ts, a0)
 
         # surviving zero-collect origins revert skip-written captures to
         # null AFTER emission (phase 5) and fork inheritance (phase 6)
@@ -809,6 +827,9 @@ class PatternExec:
 
         st = st._replace(dropped=st.dropped + jnp.sum(
             jnp.maximum(ncand - nfree, 0).astype(jnp.int64)))
+        if st.forked is not None:
+            st = st._replace(forked=st.forked + jnp.sum(
+                fork, dtype=jnp.int64))
 
         def pull(cand_field, old_field):
             # one-hot contraction over the tiny NC axis; a take_along_axis

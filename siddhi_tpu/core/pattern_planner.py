@@ -883,7 +883,41 @@ def unpack_planes(bufs, dtypes, shards: int = 1) -> list:
 
 # what rides the head buffer of a band: ts, then kind with valid in bit 31
 HEAD_DTYPES = (np.int64, np.uint32)
+HEAD_WORDS = 3
 _VALID_BIT = 31
+
+
+def valid_slots(head_bufs, shards: int = 1) -> list:
+    """Per fetched band, the slots (in the order `unpack_planes` lays them
+    out) whose row is valid: bit 31 of the head buffer's last plane."""
+    return [np.flatnonzero(
+        buf.reshape(shards, HEAD_WORDS, -1)[:, -1].reshape(-1) >> _VALID_BIT)
+        for buf in head_bufs]
+
+
+def unpack_planes_at(bufs, dtypes, keeps, shards: int = 1) -> list:
+    """`unpack_planes` for the slots `keeps` lists a band (`valid_slots`)
+    and no other: each word is read where it lies on the wire and written
+    once.  A rank rectangle is sized by its fullest key — at a tenth full
+    (a count pattern: 32 ranks for 3.3 rows a key) decoding every slot and
+    masking afterwards costs six times what the rows do."""
+    words = [2 if np.dtype(d).itemsize == 8 else 1 for d in dtypes]
+    outs = [np.empty(sum(k.size for k in keeps), d) for d in dtypes]
+    at = 0
+    for buf, keep in zip(bufs, keeps):
+        pl = buf.reshape(shards, sum(words), -1)
+        p = 0
+        for o, w in zip(outs, words):
+            dst = o[at:at + keep.size]
+            if dst.dtype == np.bool_:
+                np.not_equal(pl[:, p].reshape(-1)[keep], 0, out=dst)
+            else:
+                dst = dst.view(np.uint32).reshape(-1, w)
+                for i in range(w):          # little-endian: low word first
+                    dst[:, i] = pl[:, p + i].reshape(-1)[keep]
+            p += w
+        at += keep.size
+    return outs
 
 
 @jax.tree_util.register_pytree_node_class
@@ -1074,13 +1108,16 @@ def _match_rows(spec: PatternSpec, emits, ord_, now, key_idx):
         # position-local (resets when a fork advances past the count atom)
         # so the fill depth derives from the capture ts plane (unfilled
         # rows hold -1; a real event at timestamp 0 still counts)
-        nfill = jnp.sum((cap_ts >= 0).astype(jnp.int32),
-                        axis=2)                         # [E,P+1,K]
-        last_i = jnp.clip(nfill - 1, 0, D - 1)
-        last_oh = (jnp.arange(D)[None, None, :, None] ==
-                   last_i[:, :, None, :])               # [E,P+1,D,K]
-        env[f"{a.ref}@-1"] = tuple(
-            flat(oh_take(c, last_oh, 2)) for c in cap_cols)
+        # PART `last_capture`: the fill depth and a one-hot contraction
+        # over D a column, all E * (P + 1) * K rows of it
+        with jax.named_scope("last_capture"):
+            nfill = jnp.sum((cap_ts >= 0).astype(jnp.int32),
+                            axis=2)                     # [E,P+1,K]
+            last_i = jnp.clip(nfill - 1, 0, D - 1)
+            last_oh = (jnp.arange(D)[None, None, :, None] ==
+                       last_i[:, :, None, :])           # [E,P+1,D,K]
+            env[f"{a.ref}@-1"] = tuple(
+                flat(oh_take(c, last_oh, 2)) for c in cap_cols)
 
     if key_idx is not None:
         gslot = flat(jnp.broadcast_to(

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import heapq
 import itertools
 import logging
@@ -36,8 +37,9 @@ from . import event as ev
 from . import state_rows
 from .executor import CompileError
 from .keyslots import SlotAllocator, valid_first_sel
-from .pattern_planner import (HEAD_DTYPES, BandedEmission, StatePacker,
-                              unpack_planes)
+from .pattern_planner import (HEAD_DTYPES, HEAD_WORDS, BandedEmission,
+                              StatePacker, unpack_planes, unpack_planes_at,
+                              valid_slots)
 from .planner import PlannedQuery, plan_single_query
 from .window import NO_WAKEUP
 from .steputil import jit_step
@@ -156,8 +158,18 @@ def _device_state(qr, host_state):
     as device arrays in the runtime's own layout."""
     if isinstance(qr, PatternQueryRuntime):
         packed, sel_state = host_state
-        host_state = (StatePacker.from_host(packed), sel_state)
+        *arrays, scalars = StatePacker.from_host(packed)
+        host_state = ((*arrays, _all_scalars(qr, scalars)), sel_state)
     return jax.tree.map(lambda x: jax.numpy.asarray(x), host_state)
+
+
+def _all_scalars(qr, scalars) -> tuple:
+    """A snapshot's pattern-state scalars as THIS runtime's state has
+    them: one written before a scalar existed (`PatternState.forked`,
+    PR 55: a count pattern's second) gets the missing ones at zero."""
+    mine = qr.state[0][3]
+    return tuple(scalars) + tuple(
+        np.zeros((), np.asarray(s).dtype) for s in mine[len(scalars):])
 
 
 def _allocator_of(qr):
@@ -405,6 +417,10 @@ class _QueryRuntimeBase:
         self.slot_allocator = None
         self._dirty = None
         self._jk = None
+        # a pattern's NFA facts as its last drain read them
+        # (PatternQueryRuntime.note_nfa_facts): what the scrape surfaces,
+        # which never fetch, say of the slab
+        self._nfa_facts = None
         # -- written per send / per delivery --
         # perf_counter_ns at send acceptance, stamped by the dispatcher
         # under the query lock; whether an inline delivery left the
@@ -1102,6 +1118,48 @@ class PatternQueryRuntime(_QueryRuntimeBase):
         else skips the wake fetch entirely."""
         return wake if self.planned.timer_step is not None else None
 
+    def note_nfa_facts(self) -> Dict[str, int]:
+        """After a drain (`SiddhiAppRuntime.flush`): read the slab's own
+        counters ONCE, off the state as it lies — no step hands them
+        over.  `forks_dropped`: `PatternState.dropped`, candidates
+        (forks and seeds) that found no free slot of the key's
+        `@capacity(slots='N')` — matches lost for good, said in a warning
+        by the first drain that finds more of them; `forks`: continuations
+        forked off a slot, where the pattern has a count atom
+        (`PatternState.forked`); with statistics on, `live_threads`: the
+        slots in use over all keys (one reduce over the `active` rows,
+        the packed state's first P).  Kept on the runtime for the scrape
+        surfaces, which never fetch (`/metrics`' `siddhi_nfa_*`,
+        `state_report()["nfa"]`)."""
+        with self._qlock:
+            b32, _lo, _hi, scalars = self.state[0]
+            read = tuple(scalars)
+            if self.app.stats.enabled:
+                read += (_live_threads(b32, self.planned.slots),)
+            read = [int(v) for v in jax.device_get(read)]
+        facts = {"forks_dropped": read[0]}
+        if self.planned.exec.forks:
+            facts["forks"] = read[1]
+        if self.app.stats.enabled:
+            facts["live_threads"] = read[-1]
+        lost = facts["forks_dropped"] - \
+            (self._nfa_facts or {}).get("forks_dropped", 0)
+        if lost > 0:
+            logging.getLogger("siddhi_tpu").warning(
+                "%s: %d pattern fork(s) or seed(s) found no free slot and "
+                "were dropped — their matches are lost; raise "
+                "@capacity(slots='%d') on the query", self.name, lost,
+                self.planned.slots)
+        self._nfa_facts = facts
+        return facts
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _live_threads(b32, slots: int):
+    """Slots in use over all keys: `active` is the packed state's first
+    leaf, rows [0, P) of the i32 blob."""
+    return jax.numpy.sum(b32[:slots] != 0, dtype=jax.numpy.int64)
+
 
 def _target_live(qr) -> bool:
     """Does anything need this output as host rows — a table op, a rate
@@ -1301,19 +1359,29 @@ class _EmissionRows:
     each tier's `ranks_used` are fetched — u32 buffers, decoded here
     (`unpack_planes`) into arrays of `ranks fetched x K` slots, the tiers
     in order, rank-major within a tier, under the same `valid` mask
-    contract; their `fetch` spans carry `ranks` and `ranks_cap`."""
+    contract — or, where at most half of those slots are rows (`sparse`),
+    into the rows alone in that same order, `valid` all True
+    (`unpack_planes_at`); their `fetch` spans carry `ranks` and
+    `ranks_cap`."""
 
     __slots__ = ("stats", "qname", "flat", "bands", "meta", "dtypes",
-                 "shards")
+                 "shards", "sparse", "_keep", "_head_got")
 
-    def __init__(self, qr, out, ranks_used=None):
+    def __init__(self, qr, out, ranks_used=None, n_valid=None):
         self.stats, self.qname = qr.app.stats, qr.name
+        self.sparse, self._keep, self._head_got = False, None, None
         if isinstance(out, BandedEmission):
             self.flat = None
             self.bands, ranks, cap = out.used(ranks_used)
             self.meta = {"ranks": ranks, "ranks_cap": cap}
             self.dtypes = qr.planned.out_schema.dtypes
             self.shards = out.shards
+            # the fetched rank rectangle is sized by the send's fullest
+            # key: where it is at most half rows (`n_valid`, the header's)
+            # only the valid slots are decoded — the same rows in the same
+            # order with `valid` all True, not `ranks x K` slots and a mask
+            slots = sum(h.size for h, _ in self.bands) // HEAD_WORDS
+            self.sparse = n_valid is not None and 2 * n_valid <= slots
         else:
             self.flat, self.meta = tuple(out[-4:]), {}
 
@@ -1326,23 +1394,38 @@ class _EmissionRows:
                              **self.meta)
 
     def _head(self, bufs):
+        if self.sparse:
+            if self._head_got is None:
+                self._keep = valid_slots(bufs, self.shards)
+                ts, kv = unpack_planes_at(bufs, HEAD_DTYPES, self._keep,
+                                          self.shards)
+                self._head_got = (ts, (kv & 0x7FFFFFFF).astype(np.int32),
+                                  np.ones(ts.shape[0], np.bool_))
+            return self._head_got
         ts, kv = unpack_planes(bufs, HEAD_DTYPES, self.shards)
         return (ts, (kv & 0x7FFFFFFF).astype(np.int32),
                 (kv >> 31).astype(np.bool_))
 
     def _cols(self, bufs):
+        if self.sparse:
+            return tuple(unpack_planes_at(bufs, self.dtypes, self._keep,
+                                          self.shards))
         return tuple(unpack_planes(bufs, self.dtypes, self.shards))
 
     def head(self):
         """(ts, kind, valid) in one roundtrip."""
         if self.flat is not None:
             return self._fetch(self.flat[:3])
+        if self._head_got is not None:
+            return self._head_got
         return self._head(self._fetch([h for h, _ in self.bands]))
 
     def cols(self):
         """The output columns, in another."""
         if self.flat is not None:
             return self._fetch(self.flat[3])
+        if self.sparse and self._keep is None:
+            self.head()            # which slots are rows is the head's to say
         return self._cols(self._fetch([c for _, c in self.bands]))
 
     def all(self):
@@ -1578,7 +1661,7 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             if not ovalid_np.any():
                 return
             rows_out = int(ovalid_np.sum())
-        rows = _EmissionRows(qr, out, ranks_used)
+        rows = _EmissionRows(qr, out, ranks_used, nv if headed else None)
         span.set_metadata(rows=rows_out)
         if _st.enabled and rows_out:
             # per-tenant events_out/emitted_bytes accounting: row count is
@@ -3904,6 +3987,9 @@ class SiddhiAppRuntime:
                     and not any(qr._pending_emit or _fusion.pending(qr)
                                 for qr in self._step_runtimes()) \
                     and self._serve_drainer.pending() == 0:
+                for qr in self.query_runtimes.values():
+                    if isinstance(qr, PatternQueryRuntime):
+                        qr.note_nfa_facts()
                 return
         import logging
         logging.getLogger("siddhi_tpu").warning(
@@ -4589,8 +4675,8 @@ class SiddhiAppRuntime:
                         def put(arr, c):
                             return arr.at[:, idx].set(jax.numpy.asarray(c))
                     arrays = tuple(put(a, c) for a, c in zip(arrays, cols))
-                    scalars = tuple(jax.numpy.asarray(s)
-                                    for s in d["scalars"])
+                    scalars = tuple(jax.numpy.asarray(s) for s in
+                                    _all_scalars(qr, d["scalars"]))
                     sel_state = jax.tree.map(lambda x: jax.numpy.asarray(x),
                                              sel_host)
                     qr.state = ((*arrays, scalars), sel_state)

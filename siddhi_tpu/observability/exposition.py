@@ -237,6 +237,16 @@ def render_prometheus(runtimes: Dict) -> str:
                  "Dispatches fenced with block_until_ready by the "
                  "sampled deep profiling mode (profile.sample.every=N) "
                  "to split submit wall from device compute, per query")
+    nfa_live = fam("siddhi_nfa_live_threads", "gauge",
+                   "NFA slots in use over all keys of a pattern query, as "
+                   "its last drain (flush) read them off the state")
+    nfa_fork = fam("siddhi_nfa_forks_total", "counter",
+                   "Continuations a pattern query's count atoms forked off "
+                   "a slot, as its last drain read the slab's counter")
+    nfa_drop = fam("siddhi_nfa_forks_dropped_total", "counter",
+                   "Pattern forks and seeds that found no free slot of "
+                   "their key (@capacity(slots='N')) and were lost, as the "
+                   "last drain read the slab's counter")
     so_occ = fam("siddhi_state_occupancy", "gauge",
                  "Utilization (occupancy/capacity, 0-1) of each sized "
                  "device state structure, from its host mirror "
@@ -345,6 +355,15 @@ def render_prometheus(runtimes: Dict) -> str:
                               query=q, structure=s)
         for q, hot in sorted(so_snap.get("hotness", {}).items()):
             so_hot.sample(hot["hot_share_1pct"], app=app_name, query=q)
+        # a pattern's slab facts: host attributes its last drain left
+        for q, qr in sorted(getattr(rt, "query_runtimes", {}).items()):
+            facts = qr._nfa_facts
+            if facts:
+                for family_, key in ((nfa_live, "live_threads"),
+                                     (nfa_fork, "forks"),
+                                     (nfa_drop, "forks_dropped")):
+                    if key in facts:
+                        family_.sample(facts[key], app=app_name, query=q)
         for gid, mg in sorted(getattr(rt, "merged_groups", {}).items()):
             mrg_q.sample(len(getattr(mg, "members", ())), app=app_name,
                          group=gid)

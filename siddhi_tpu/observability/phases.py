@@ -74,6 +74,21 @@ traced slice under (program, section, part, primitive):
     take_this   what is gathered by `li`
     take_other  what is gathered by `ri`
 
+Two pattern sections name parts for SOME of their ops — what a count atom
+(`B<1:5>`) adds to a tick and to the match rows; the rest of the section
+stays part `""`.  Inside `nfa_advance` they stand below the scan's own
+`while/body` (`.../nfa_advance/while/body/fork_spawn/...`), so a reader
+looks for them at any depth under the section
+(`benchmarks/harness/nested_parts.py`):
+
+  nfa_advance   (pattern.PatternExec.tick)
+    count_capture  a count atom's capture write: one of its D rows, by the
+                   slot's count
+    fork_spawn     `_spawn`: candidates ranked against free slots, every
+                   leaf pulled by a one-hot contraction, the captures too
+  match_rows    (pattern_planner._match_rows)
+    last_capture   `e2[last]`: the fill depth and a contraction over D
+
 (`pattern_block`'s `event_load` is four gathers by one index and has none.)
 
 Spans (`siddhi:<name>`) and the scrape phase each feeds:

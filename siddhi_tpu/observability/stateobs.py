@@ -671,6 +671,10 @@ def state_report(rt) -> Dict:
         "near_capacity": near_capacity(rt, snap) if enabled else [],
         "sizing_hints": obs.ledger(),
         "state_rows": state_rows(rt.stats.counters()),
+        # what each pattern's last drain read off its slab
+        # (runtime.PatternQueryRuntime.note_nfa_facts)
+        "nfa": {q: dict(qr._nfa_facts) for q, qr in sorted(
+            getattr(rt, "query_runtimes", {}).items()) if qr._nfa_facts},
     }
 
 

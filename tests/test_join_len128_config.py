@@ -310,16 +310,22 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 # `AggregatorBank.process` in waves with one packed gather each way; the
 # texts without debug info of the nine cells that sort nothing in the
 # selector the parent's byte for byte, `timewindow_256sym.paced` re-pinned
-# there on purpose).
+# there on purpose); PR 55 for all three (`pattern.py` + 20 lines and three
+# PART scopes — `count_capture` names nothing where no atom counts —
+# `pattern_planner.py` + 34 above `compact_emission` (`unpack_planes_at`),
+# `phases.py` + 15 in its docstring above `dispatch`, `runtime.py` + 15
+# above `PatternQueryRuntime.process_staged` (`_all_scalars`,
+# `_nfa_facts`); the texts without debug info of ALL TEN cells' programs
+# the parent's byte for byte).
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "dfbed75c697d8c13268d4cf4b69381b5d1188bfdc7dd8b1e7baa26a3a80dbc48"},
+        "cc6f062a250402dcaeec6b66c4bd8dcdf487fa812f5c54b1f678cb5c06eebb5f"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "60389722306d3f78b7b8c9c9bc91f9543654cf17c69a9a0f47627ac509a398fd",
+        "2139de17d4f8415f4951f317f592f434e539f6a812aad5b6b5b59c312860fd7b",
         "step[TradeStream]":
-        "f7e17b11af14b1a03e1f505048c75ac9da1a62bf09d073acb91453e0680e6ab7"},
+        "7e1f05c164f42dcd51b978c3bc42e6569e7dffad64071109d3dc1b3163f0eeae"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)

@@ -208,8 +208,12 @@ def test_a_batch_payload_holds_the_fetched_bands_only(monkeypatch, reps,
     rt.shutdown()
     assert not errors
     (slots, rows, n_valid, n_dropped), = got
-    # R = 4 ranks x 8 keys = 32 slots flat; the bands below ranks_used
-    assert slots == ranks * 8 < 4 * 8 + (ranks == 4)
+    # R = 4 ranks x 8 keys = 32 slots flat; the bands below ranks_used —
+    # decoded slot for slot or, where at most half of them are rows, the
+    # rows alone, `valid` all True (PR 55: `_EmissionRows.sparse`)
+    fetched = ranks * 8
+    assert fetched < 4 * 8 + (ranks == 4)
+    assert slots == (len(want) if 2 * len(want) <= fetched else fetched)
     assert rows == sorted(want) and (n_valid, n_dropped) == (len(want), 0)
     assert [w for w, _ in log] == ["header", "rows", "rows"]
     assert all(m == {"ranks": ranks, "ranks_cap": 4} for w, m in log[1:])
@@ -330,7 +334,10 @@ def test_a_three_tier_send_is_one_delivery_of_all_tiers_bands(
     # key = 1 + 16 + 64 ranks
     assert meta == {"ranks": 1 + 16 + 64, "ranks_cap": 18 + 64 + 64}
     # over [64, 2], [8, 16] and [1, 128] rectangles of keys
-    assert slots == 1 * 64 + 16 * 8 + 64 * 1 < 18 * 64 + 64 * 8 + 64 * 1
+    fetched = 1 * 64 + 16 * 8 + 64 * 1
+    assert fetched < 18 * 64 + 64 * 8 + 64 * 1
+    # half of the fetched slots are rows: the payload is the rows alone
+    assert 2 * n_valid == fetched and slots == n_valid
 
 
 def test_a_failing_later_tier_still_delivers_the_earlier_tiers_rows(
@@ -399,4 +406,88 @@ def test_bands_over_four_shards_and_ranks_used_is_the_max_over_them(
     # bands [0,1) and [1,4); a band's buffer holds every shard's slots
     assert [m_["ranks"] for m_ in metas] == [1, 1, 1, 4, 4, 4]
     assert all(m_["ranks_cap"] == 4 for m_ in metas)
-    assert s2 == 4 * s1 and s1 % 4 == 0
+    # the second delivery's 18 rows of 4 x s1 fetched slots: the rows alone
+    assert s1 % 4 == 0 and s2 == (n2 if 2 * n2 <= 4 * s1 else 4 * s1)
+
+
+# -- a rectangle that is mostly filler is decoded by its rows (PR 55) -----------
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_valid_slots_alone_decode_to_what_the_mask_would_keep(shards):
+    """`unpack_planes_at` over `valid_slots` = `unpack_planes` then the
+    `valid` mask, every dtype the wire carries (an int64 as two words, a
+    bool as a word), bands end to end, every shard's slots in place."""
+    from siddhi_tpu.core.pattern_planner import (
+        HEAD_DTYPES, unpack_planes, unpack_planes_at, valid_slots)
+    rng = np.random.default_rng(shards)
+    dtypes = (np.int64, np.float32, np.bool_, np.int32)
+    m = 8 * shards
+    heads, cols = [], []
+    for ranks in (1, 3, 12):
+        n = ranks * m
+        valid = rng.random(n) < 0.2
+        kind = rng.integers(0, 2, n).astype(np.uint32)
+        planes = [rng.integers(0, 2 ** 32, n, dtype=np.uint64).astype(
+            np.uint32) for _ in range(2)] + [kind | (
+                valid.astype(np.uint32) << 31)]
+        body = [rng.integers(0, 2 ** 32, n, dtype=np.uint64).astype(
+            np.uint32) for _ in range(5)]
+        body[3] = rng.integers(0, 2, n).astype(np.uint32)     # the bool
+        for bufs, pl in ((heads, planes), (cols, body)):
+            bufs.append(np.stack([p.reshape(shards, -1) for p in pl], 1)
+                        .reshape(-1))
+    keeps = valid_slots(heads, shards)
+    ts, kv = unpack_planes(heads, HEAD_DTYPES, shards)
+    sel = (kv >> 31) != 0
+    assert np.array_equal(np.concatenate(keeps), np.concatenate([
+        np.flatnonzero(sel[lo:lo + r * m]) for lo, r in
+        ((0, 1), (m, 3), (4 * m, 12))]))
+    for bufs, dts in ((heads, HEAD_DTYPES), (cols, dtypes)):
+        want = [a[sel] for a in unpack_planes(bufs, dts, shards)]
+        got = unpack_planes_at(bufs, dts, keeps, shards)
+        assert [g.dtype for g in got] == [w.dtype for w in want]
+        # bit for bit (random words read as float32 hold NaNs)
+        assert all(g.tobytes() == w.tobytes() for g, w in zip(got, want))
+    assert 0 < sel.sum() < sel.size / 2
+
+
+def test_a_sparse_payload_is_the_rows_in_the_dense_payloads_order(
+        monkeypatch):
+    """One key with four rows among eight with one: 32 slots fetched, 11
+    rows — the payload is the 11, in the order the 32 slots hold them, every
+    column, whichever of head and cols is read first."""
+    from siddhi_tpu.core import runtime as rtm
+    payloads = {}
+    for sparse in (True, False):
+        if not sparse:
+            real = rtm._EmissionRows.__init__
+
+            def dense(self, qr, out, ranks_used=None, n_valid=None):
+                real(self, qr, out, ranks_used, None)
+            monkeypatch.setattr(rtm._EmissionRows, "__init__", dense)
+        for first in ("valid", "cols"):
+            rt, errors = deploy(rows=4)
+            got = []
+
+            def on_batch(_now, b, first=first):
+                b[first]
+                got.append((b["ts"][b["valid"]], b["kind"][b["valid"]],
+                            {n: np.asarray(c)[b["valid"]]
+                             for n, c in b["cols"].items()},
+                            b["valid"].size))
+            rt.add_batch_callback("q", on_batch)
+            rt.start()
+            c1, t1, _w = pairs_send(range(8), 1, 1000)
+            c2, t2, _w = pairs_send([5], 3, 1100)
+            rt.get_input_handler("S").send_columns(
+                [np.concatenate([a, b]) for a, b in zip(c1, c2)],
+                timestamps=np.concatenate([t1, t2]))
+            rt.shutdown()
+            assert not errors
+            (payloads[sparse, first],) = got
+    for first in ("valid", "cols"):
+        (ts, kind, cols, size), (ts0, kind0, cols0, size0) = \
+            payloads[True, first], payloads[False, first]
+        assert (size, size0) == (11, 32)
+        assert np.array_equal(ts, ts0) and np.array_equal(kind, kind0)
+        assert all(np.array_equal(cols[n], cols0[n]) for n in cols0)

@@ -35,6 +35,7 @@ RUNTIME_FIELDS = {
     "serve_ring_capacity", "_fuse", "_fuse_requested", "_fuse_excluded",
     "_replan", "table_op", "rate_limiter", "_merged", "_merge_excluded",
     "_touch", "_touch_group", "slot_allocator", "_dirty", "_jk",
+    "_nfa_facts",
     "_ingest_ns", "_e2e_owed", "_pending_emit", "_serve_ring",
     "_fused_ingests", "_fused_cache", "_out_row_nbytes",
     "_shard_router_memo", "_stateobs_tick", "_stateobs_probe",

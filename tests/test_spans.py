@@ -453,7 +453,9 @@ def test_off_spans_add_no_sync_and_feed_no_profiler(monkeypatch):
                                              null_spans=True)
     assert rows == rows0 == [N_KEYS] * 3
     assert (g, b) == (g0, b0)
-    assert g == 3 and b == 0          # one header fetch a send, no fence
+    # one header fetch a send, no fence — and the drain's ONE read of the
+    # slab's scalars (`note_nfa_facts`, PR 55)
+    assert g == 3 + 1 and b == 0
     assert snap == snap0 == {"queries": {}, "sampled": {}}
 
 
