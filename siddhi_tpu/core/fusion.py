@@ -360,9 +360,9 @@ def _dispatch_pattern_sharded(qr, items) -> None:
     stream_id = items[0][0]
     preps, feeds = [], []
     for _, staged, now in items:
-        key_idx, sel, slots, counts = qr._shard_prep(stream_id, staged)
+        key_idx, sel, fed = qr._shard_prep(stream_id, staged)
         preps.append((key_idx, sel))
-        feeds.append((slots, counts, now, key_idx))
+        feeds.append((*fed, now, key_idx))
     n = preps[0][0].shape[0]
     Kb = max(ki.shape[1] for ki, _ in preps)
     E = max(s.shape[2] for _, s in preps)

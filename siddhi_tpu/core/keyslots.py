@@ -510,8 +510,8 @@ def _fill_groups(slots, live, n, cnt, rank, touched, nu, maxc, pad):
 
 def _count_and_fill(slots, valid, pad, fill):
     """The standalone C count pass over already-resolved `slots`, then
-    `fill` (`_fill_groups` | `_fill_tiers`): (what `fill` returns, the
-    number of distinct slots)."""
+    `fill` (`_fill_groups` | `_fill_tiers` | a `_fill_shards`): (what
+    `fill` returns, the number of distinct slots)."""
     n = slots.shape[0]
     slots = np.ascontiguousarray(slots, np.int32)
     live = np.ascontiguousarray(valid, np.uint8)
@@ -675,6 +675,60 @@ def group_events_by_key(slots: np.ndarray, valid: np.ndarray,
     group_rank = np.repeat(np.arange(len(uniq)), counts)
     sel[group_rank, within] = idx_sorted.astype(np.int32)
     return key_idx, sel, sel >= 0
+
+
+def group_events_by_shard(slots: np.ndarray, valid: np.ndarray,
+                          n_shards: int, capacity: int):
+    """`group_events_by_key` for a key space of `capacity` slots laid
+    round-robin over `n_shards` devices (sharding/router.py: slot s is
+    local row `s // n_shards` of shard `s % n_shards`), in ONE grouping of
+    the batch: (key_idx [n, Kb] int32 local rows, sel [n, Kb, E] int32,
+    events [n] int64 routed to each shard, keys [distinct] int32 — the
+    batch's distinct slots ascending — and counts [distinct] int32, the
+    events of each).  Each shard's rectangle keeps the layout contract of
+    the one (rows ascending, a key's events along E in batch order, -1 /
+    `capacity // n_shards` padding); Kb is the fullest shard's bucket, E
+    the hottest key's.  The ascending slots with `s % n_shards == d` ARE
+    shard d's local rows ascending, so nothing is grouped a shard."""
+    if LIB is not None and capacity < 2**30:
+        return _count_and_fill(slots, valid, capacity,
+                               _fill_shards(n_shards))[0]
+    keys, by_key, kvalid = group_events_by_key(slots, valid, pad=capacity)
+    live = keys < capacity
+    keys, counts = keys[live], kvalid.sum(axis=1, dtype=np.int32)[live]
+    shard = keys % n_shards
+    mine = [np.flatnonzero(shard == d) for d in range(n_shards)]
+    Kb = _bucket(max(m.size for m in mine), _KB_BUCKETS)
+    key_idx = np.full((n_shards, Kb), capacity // n_shards, np.int32)
+    sel = np.full((n_shards, Kb, by_key.shape[1]), -1, np.int32)
+    events = np.zeros(n_shards, np.int64)
+    for d, m in enumerate(mine):
+        key_idx[d, :m.size] = keys[m] // n_shards
+        sel[d, :m.size] = by_key[m]
+        events[d] = counts[m].sum()
+    return key_idx, sel, events, keys, counts
+
+
+def _fill_shards(n_shards: int):
+    """The fill of `group_events_by_shard` for `_count_and_fill` (whose
+    `pad` is the key space's capacity here): sg_group_fill_shards."""
+    def fill(slots, live, n, cnt, rank, touched, nu, maxc, pad):
+        Kb = _bucket(int(np.bincount(touched[:nu] % n_shards,
+                                     minlength=1).max()), _KB_BUCKETS)
+        E = _bucket(maxc, _E_BUCKETS)
+        key_idx = np.empty((n_shards, Kb), np.int32)
+        sel = np.empty((n_shards, Kb, E), np.int32)
+        counts = np.empty(nu, np.int32)
+        events = np.empty(n_shards, np.int64)
+        LIB.sg_group_fill_shards(
+            ptr(slots, ctypes.c_int32), ptr(live, ctypes.c_uint8), n,
+            ptr(cnt, ctypes.c_int32), ptr(rank, ctypes.c_int32),
+            ptr(touched, ctypes.c_int32), nu, n_shards, Kb, E,
+            pad // n_shards, ptr(key_idx, ctypes.c_int32),
+            ptr(sel, ctypes.c_int32), ptr(counts, ctypes.c_int32),
+            ptr(events, ctypes.c_int64))
+        return key_idx, sel, events, touched[:nu].copy(), counts
+    return fill
 
 
 def _bucket(n: int, buckets) -> int:

@@ -1026,9 +1026,9 @@ class PatternQueryRuntime(_QueryRuntimeBase):
         """Staging-time routing of one batch through the key-space router
         (host side effect: slot binding).  Returns the grouped (key_idx
         [n, Kb], sel [n, Kb, E]) device layout and what `_shard_feed`
-        takes once the step is dispatched: the rows' resolved slots and
-        the per-shard event counts — shared by the sequential sharded path
-        and fused dispatch (core/fusion.py)."""
+        takes once the step is dispatched, as the one grouping counted it
+        (per-shard event counts, distinct slots, events a slot) — shared by
+        the sequential sharded path and fused dispatch (core/fusion.py)."""
         router = self.shard_router
         st = self.app.stats
         with _phases.phase(st, self.name, "route_keys"):
@@ -1038,30 +1038,30 @@ class PatternQueryRuntime(_QueryRuntimeBase):
             # own span: route_keys' self time is slot resolution alone
             with _phases.phase(st, self.name, "shard_group",
                                shards=router.n_shards) as sp:
-                key_idx, sel, counts = router.group(slots, staged.valid)
-                sp.set_metadata(rows=key_idx.size,
-                                keys=int((key_idx < router.block).sum()))
-        return key_idx, sel, slots, counts
+                key_idx, sel, counts, keys, per_key = router.group(
+                    slots, staged.valid)
+                sp.set_metadata(rows=key_idx.size, keys=keys.size, passes=1)
+        return key_idx, sel, (counts, keys, per_key)
 
-    def _shard_feed(self, slots, counts, now: int, key_idx=None) -> None:
+    def _shard_feed(self, counts, keys, per_key, now, key_idx=None) -> None:
         """`_feed_observers` of the sharded path, from what `_shard_prep`
-        resolved: key hotness, purger liveness touch, dirty marks,
-        per-shard routing counters, the row-mover's counters (`key_idx`:
-        the [n, Kb] local rows the step moved).  Nothing here goes to the
-        device, so it runs after the step's dispatch, under it."""
+        counted: key hotness, liveness touch, dirty marks (`keys`: the
+        send's distinct slots ascending, `per_key` their events), per-shard
+        counters, the row-mover's (`key_idx`: the [n, Kb] local rows moved).
+        Nothing goes to the device: it runs after the dispatch, under it."""
         st = self.app.stats
         with _phases.phase(st, self.name, "obs_feed",
                            after="dispatch") as sp:
             if key_idx is not None:
                 _count_state_rows(self, key_idx, self.shard_router.block)
-            _stateobs_feed_slots(self, self.slot_allocator, slots, sp)
+            if keys.size and _stateobs.obs_enabled(self.app):
+                sp.set_metadata(keys=keys.size)
+                st.stateobs.feed_keys(
+                    self.name, self.slot_allocator.capacity, keys, per_key)
             if self._touch is not None:
-                self._touch(slots, now)
-            if self._dirty is not None:
-                live = slots[slots >= 0]
-                if live.size:
-                    # global state column of slot s under the shard layout
-                    self._dirty[self.shard_router.state_row(live)] = True
+                self._touch(keys, now)
+            if self._dirty is not None:    # slot s: global state row of s
+                self._dirty[self.shard_router.state_row(keys)] = True
             if st.enabled:
                 st.shard_events(self.name, counts)
 
@@ -1076,7 +1076,7 @@ class PatternQueryRuntime(_QueryRuntimeBase):
         # (fused dispatch shares it and ships a stacked i64 ts)
         with _phases.phase(st, self.name, "route_keys"):
             ts_base, ts_delta = ev.encode_ts(staged.ts, staged.n)
-        key_idx, sel, slots, counts = self._shard_prep(stream_id, staged)
+        key_idx, sel, fed = self._shard_prep(stream_id, staged)
         flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
         with _phases.phase(st, self.name, "h2d",
                            bytes=_phases.nbytes(ts_delta, sel, key_idx,
@@ -1092,7 +1092,7 @@ class PatternQueryRuntime(_QueryRuntimeBase):
                                    *ts_d, sel_d, key_d, now_d)
         finally:
             # as in process_staged: fed whether or not the step came back
-            self._shard_feed(slots, counts, now, key_idx)
+            self._shard_feed(*fed, now, key_idx)
         _emit_output(self, out, now, wake=self._wake_arg(wake))
 
     def on_timer(self, now: int) -> None:

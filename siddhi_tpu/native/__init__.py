@@ -88,6 +88,10 @@ def _bind(lib):
     lib.sg_group_fill.argtypes = [
         i32p, u8p, c.c_int64, i32p, i32p, i32p,
         c.c_int64, c.c_int64, c.c_int64, c.c_int32, i32p, i32p]
+    lib.sg_group_fill_shards.restype = None
+    lib.sg_group_fill_shards.argtypes = [
+        i32p, u8p, c.c_int64, i32p, i32p, i32p, c.c_int64,
+        c.c_int64, c.c_int64, c.c_int64, c.c_int32, i32p, i32p, i32p, i64p]
     lib.sg_group_fill_tiers.restype = None
     lib.sg_group_fill_tiers.argtypes = [
         i32p, u8p, c.c_int64, i32p, i32p, i32p, c.c_int64,

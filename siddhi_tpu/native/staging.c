@@ -179,7 +179,9 @@ int64_t sg_group_count(const int32_t *slots, const uint8_t *valid, int64_t n,
 }
 
 static void radix_sort_u32(uint32_t *a, int64_t n, uint32_t *tmp) {
-    int64_t hist[2048];
+    int64_t hist[2048], up = 1;
+    while (up < n && a[up - 1] <= a[up]) up++;
+    if (up >= n) return;          /* ascending as it came: a sweep's keys */
     for (int shift = 0; shift < 32; shift += 11) {
         memset(hist, 0, sizeof(hist));
         const uint32_t m = (shift + 11 >= 32) ? (0xFFFFFFFFu >> shift)
@@ -224,6 +226,47 @@ int32_t sg_group_fill(const int32_t *slots, const uint8_t *valid, int64_t n,
         cnt[touched[k]] = 0;                      /* leave cnt clean */
     return (n_uniq > 0 &&
             touched[n_uniq - 1] == touched[0] + (int32_t)(n_uniq - 1)) ? 1 : 0;
+}
+
+/* Sharded fill: the touched slots split by `slot % n_shards` into n_shards
+ * rectangles [Kb, E] laid end to end, each as sg_group_fill lays the one:
+ * shard d's keys are its LOCAL rows `slot / n_shards` ascending from row
+ * d * Kb (the slots ascending with `slot % n_shards == d`), pads `pad`, a
+ * key's events along E in batch order.  key_cnt [n_uniq] takes the events
+ * of each slot, in ascending slot order (touched, sorted, is the slots);
+ * shard_events [n_shards] the events routed to each shard.  Kb must hold
+ * the fullest shard's keys.  Leaves cnt clean. */
+void sg_group_fill_shards(const int32_t *slots, const uint8_t *valid,
+                          int64_t n, int32_t *cnt, int32_t *rank,
+                          int32_t *touched, int64_t n_uniq,
+                          int64_t n_shards, int64_t Kb, int64_t E,
+                          int32_t pad, int32_t *key_idx, int32_t *sel,
+                          int32_t *key_cnt, int64_t *shard_events) {
+    uint32_t *tmp = (uint32_t *)malloc((size_t)n_uniq * 4);
+    radix_sort_u32((uint32_t *)touched, n_uniq, tmp);
+    free(tmp);
+    int64_t *fill = (int64_t *)calloc((size_t)n_shards, sizeof(int64_t));
+    for (int64_t g = 0; g < n_shards * Kb; g++) key_idx[g] = pad;
+    memset(sel, 0xFF, (size_t)(n_shards * Kb * E) * 4);
+    for (int64_t d = 0; d < n_shards; d++) shard_events[d] = 0;
+    for (int64_t k = 0; k < n_uniq; k++) {
+        int32_t s = touched[k];
+        int64_t d = s % n_shards;
+        int64_t g = d * Kb + fill[d]++;
+        key_idx[g] = (int32_t)(s / n_shards);
+        rank[s] = (int32_t)g;
+        key_cnt[k] = cnt[s];
+        shard_events[d] += cnt[s];
+        cnt[s] = 0;                               /* reuse as within-counter */
+    }
+    free(fill);
+    for (int64_t i = 0; i < n; i++) {
+        int32_t s = slots[i];
+        if (s < 0 || (valid && !valid[i])) continue;
+        sel[(int64_t)rank[s] * E + cnt[s]++] = (int32_t)i;
+    }
+    for (int64_t k = 0; k < n_uniq; k++)
+        cnt[touched[k]] = 0;                      /* leave cnt clean */
 }
 
 /* Tiered fill: the touched keys split by their count into n_tiers classes

@@ -89,33 +89,18 @@ class ShardRouter:
 
     # -- staging-time grouping ------------------------------------------------
     def group(self, slots: np.ndarray, valid: np.ndarray
-              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+              ) -> Tuple[np.ndarray, ...]:
         """Arrange a batch's resolved slots into the sharded device
         layout: (key_idx [n, Kb] int32 local rows, sel [n, Kb, E] int32
         batch indices (-1 = padding), counts [n] int64 events routed to
-        each shard).  Pad rows carry local sentinel `block` — the device
-        scatter-back drops them as out-of-bounds (keyslots layout
-        contract)."""
-        from ..core.keyslots import group_events_by_key
-        n = self.n_shards
-        slots = np.asarray(slots)
-        shard = self.shard_of(slots)
-        local = self.local_of(slots)
-        groups: List[Tuple] = []
-        counts = np.zeros(n, np.int64)
-        for d in range(n):
-            mask = (shard == d) & valid & (slots >= 0)
-            counts[d] = int(mask.sum())
-            groups.append(group_events_by_key(
-                np.where(mask, local, -1), mask, pad=self.block))
-        Kb = max(g[0].shape[0] for g in groups)
-        E = max(g[1].shape[1] for g in groups)
-        key_idx = np.full((n, Kb), self.block, np.int32)
-        sel = np.full((n, Kb, E), -1, np.int32)
-        for d, (ki, s, _kv) in enumerate(groups):
-            key_idx[d, :ki.shape[0]] = ki
-            sel[d, :s.shape[0], :s.shape[1]] = s
-        return key_idx, sel, counts
+        each shard) — and what the one grouping learned on the way, for
+        the send's observers: (keys int32, the batch's distinct slots
+        ascending; key_counts int32, the events of each).  Pad rows carry
+        local sentinel `block` — the device scatter-back drops them as
+        out-of-bounds (keyslots layout contract)."""
+        from ..core.keyslots import group_events_by_shard
+        return group_events_by_shard(np.asarray(slots), valid,
+                                     self.n_shards, self.capacity)
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from siddhi_tpu.core import keyslots as ks
 from siddhi_tpu.sharding import (ShardRouter, needs_rebucket,
                                  rebucket_rows, shard_count)
 
@@ -94,13 +95,168 @@ def test_group_routes_and_counts():
     r = ShardRouter(4, 16)
     slots = np.array([0, 1, 2, 3, 4, 5, -1, 4])
     valid = np.array([True] * 7 + [False])
-    key_idx, sel, counts = r.group(slots, valid)
+    key_idx, sel, counts, _keys, _key_counts = r.group(slots, valid)
     assert key_idx.shape[0] == 4 and sel.shape[0] == 4
     # slots 0,4 -> shard 0; 1,5 -> shard 1; 2 -> shard 2; 3 -> shard 3
     assert counts.tolist() == [2, 2, 1, 1]
     # shard 0 holds local rows 0 (slot 0) and 1 (slot 4)
     live0 = key_idx[0][key_idx[0] < r.block]
     assert sorted(live0.tolist()) == [0, 1]
+
+
+def _four_pass_group(r, slots, valid):
+    """The plain reference: `ShardRouter.group` as it stood until PR 56 —
+    one mask, one count pass and one fill pass over ALL the rows for every
+    shard, then a padded copy."""
+    n = r.n_shards
+    slots = np.asarray(slots)
+    shard, local = r.shard_of(slots), r.local_of(slots)
+    groups = []
+    counts = np.zeros(n, np.int64)
+    for d in range(n):
+        mask = (shard == d) & valid & (slots >= 0)
+        counts[d] = int(mask.sum())
+        groups.append(ks.group_events_by_key(
+            np.where(mask, local, -1), mask, pad=r.block))
+    Kb = max(g[0].shape[0] for g in groups)
+    E = max(g[1].shape[1] for g in groups)
+    key_idx = np.full((n, Kb), r.block, np.int32)
+    sel = np.full((n, Kb, E), -1, np.int32)
+    for d, (ki, s, _kv) in enumerate(groups):
+        key_idx[d, :ki.shape[0]] = ki
+        sel[d, :s.shape[0], :s.shape[1]] = s
+    return key_idx, sel, counts
+
+
+def _random_batch(n, capacity, rng):
+    """Slots with repeats, rows whose `valid` is False, rows whose slot
+    is -1 (a key the range partition dropped: `valid` stays True)."""
+    slots = rng.integers(0, capacity, 600).astype(np.int32)
+    slots[rng.integers(0, 600, 40)] = -1
+    return np.repeat(slots, rng.integers(1, 4, 600)), None
+
+
+def _an_empty_shard(n, capacity, rng):
+    """Shard n - 1 gets nothing (on one shard there is none to spare)."""
+    slots = rng.integers(0, capacity // n, 300).astype(np.int32) * n
+    if n > 1:
+        slots += rng.integers(0, n - 1, 300).astype(np.int32)
+    return slots, None
+
+
+def _every_shard_empty(n, capacity, rng):
+    slots = rng.integers(0, capacity, 64).astype(np.int32)
+    valid = np.zeros(64, bool)
+    slots[::2] = -1
+    valid[::2] = True          # a valid row has no slot, a slot no valid row
+    return slots, valid
+
+
+def _one_hot_key(n, capacity, rng):
+    """One key holds 9 events (E bucket 16), the others one or two."""
+    slots = np.concatenate([rng.permutation(capacity)[:50].astype(np.int32),
+                            np.full(9, 5, np.int32),
+                            rng.integers(0, capacity, 30).astype(np.int32)])
+    slots = rng.permutation(slots)
+    return slots, (slots == 5) | (rng.random(slots.shape[0]) < 0.9)
+
+
+def _kb_edge(n, capacity, rng):
+    """One shard's key count crosses a `_KB_BUCKETS` edge (64 -> 65: Kb
+    512), the others stay under it."""
+    slots = np.concatenate([np.arange(65, dtype=np.int32) * n,
+                            np.arange(1, n, dtype=np.int32)])
+    slots = rng.permutation(np.repeat(slots, 2))
+    return slots, np.ones(slots.shape[0], bool)
+
+
+BATCHES = {"random": _random_batch, "an_empty_shard": _an_empty_shard,
+           "every_shard_empty": _every_shard_empty,
+           "one_hot_key": _one_hot_key, "kb_edge": _kb_edge}
+
+
+@pytest.fixture(params=["native", "numpy", "numpy_pad"])
+def grouping(request, monkeypatch):
+    """The three ways `group_events_by_shard` runs: the C passes, the
+    numpy path for want of the library, the numpy path for a capacity of
+    2**30 or more."""
+    if request.param == "numpy":
+        monkeypatch.setattr(ks, "LIB", None)
+    elif ks.LIB is None:
+        pytest.skip("native staging library unavailable")
+    return request.param
+
+
+@pytest.mark.parametrize("n", [1, 2, 4, 8])
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+def test_group_equals_the_four_pass_loop(grouping, batch, n, monkeypatch):
+    """The one-pass `group` lays a batch out as the per-shard loop did,
+    byte for byte — values, shapes, buckets, pads, dtypes — and the keys
+    and counts it hands the observers are `np.unique`'s of the live
+    slots."""
+    capacity = 2**30 if grouping == "numpy_pad" else 4096
+    r = ShardRouter(n, capacity)
+    rng = np.random.default_rng([n, sorted(BATCHES).index(batch)])
+    slots, valid = BATCHES[batch](n, 4096, rng)
+    if valid is None:
+        valid = rng.random(slots.shape[0]) < 0.9
+    with monkeypatch.context() as m:
+        if grouping == "numpy_pad":
+            # the loop's pad is the BLOCK, under 2**30 from two shards
+            # on: keep its C passes off a scratch of that size
+            m.setattr(ks, "LIB", None)
+        want = _four_pass_group(r, slots, valid)
+    key_idx, sel, counts, keys, key_counts = r.group(slots, valid)
+    for got, ref in zip((key_idx, sel, counts), want):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes()
+    uniq, cnt = np.unique(slots[valid & (slots >= 0)], return_counts=True)
+    assert keys.dtype == np.int32 and key_counts.dtype == np.int32
+    assert np.array_equal(keys, uniq) and np.array_equal(key_counts, cnt)
+    # ... and through `state_row` they are the layout's own rows
+    rows = np.sort(r.state_row(keys))
+    live = key_idx < r.block
+    assert np.array_equal(
+        rows, (key_idx + np.arange(n)[:, None] * r.block)[live])
+    assert np.array_equal(np.sort(r.slot_of_row(rows)), uniq)
+    assert int((sel >= 0).sum()) == int(cnt.sum()) == int(counts.sum())
+    if batch == "one_hot_key":
+        assert sel.shape[2] == 16
+    if batch == "kb_edge":
+        assert key_idx.shape[1] == 512
+    if batch == "every_shard_empty":
+        assert key_idx.shape == (n, 1) and sel.shape == (n, 1, 1)
+    if batch == "an_empty_shard" and n > 1:
+        assert counts[n - 1] == 0 and counts[:n - 1].all()
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "numpy"])
+def test_group_is_one_grouping_of_the_batch(native, monkeypatch):
+    """One count pass and one fill pass over the rows (numpy: one
+    `group_events_by_key`), whatever the shard count."""
+    calls = []
+    if native:
+        if ks.LIB is None:
+            pytest.skip("native staging library unavailable")
+        lib = ks.LIB
+
+        class Counting:
+            def __getattr__(self, name):
+                return lambda *a: calls.append(name) or getattr(lib, name)(*a)
+
+        monkeypatch.setattr(ks, "LIB", Counting())
+        want = ["sg_group_count", "sg_group_fill_shards"]
+    else:
+        real = ks.group_events_by_key
+        monkeypatch.setattr(ks, "LIB", None)
+        monkeypatch.setattr(
+            ks, "group_events_by_key",
+            lambda *a, **k: calls.append("group_events_by_key") or
+            real(*a, **k))
+        want = ["group_events_by_key"]
+    slots = np.arange(64, dtype=np.int32)
+    ShardRouter(8, 64).group(slots, np.ones(64, bool))
+    assert calls == want
 
 
 # ---------------------------------------------------------------------------

@@ -208,6 +208,10 @@ def test_sharded_send_feeds_the_observatory_after_its_dispatch(tmp_path):
         (feed,) = _prep_feeds(inside)
         assert feed["after"] == "dispatch" and feed["keys"] == N_KEYS
         (group,) = [e for e in inside if e["name"] == "shard_group"]
+        # one grouping a send: its keys, and n x Kb rows of layout (256
+        # keys a shard: Kb 512)
+        assert (group["passes"], group["keys"], group["rows"]) == \
+            (1, N_KEYS, 4 * 512)
         (h2d,) = [e for e in inside if e["name"] == "h2d"]
         (disp,) = [e for e in inside if e["name"] == "dispatch"]
         assert disp["step"] == "pattern_step_sharded" and h2d["shards"] == 4
