@@ -51,6 +51,7 @@ from ..core.plan_facts import (  # noqa: F401  (re-exported API)
     WINDOW_HINT as _WINDOW_HINT,
     capacity_annotation,
     iter_named_queries,
+    join_window_hints,
     pattern_atoms,
     query_kind,
     query_state_components,
@@ -247,10 +248,13 @@ def facts_from_app(app: SiddhiApp) -> List[QueryFacts]:
         if kind == "join":
             defs = app.stream_definition_map
             sides = []
-            for sis in (q.input_stream.left_input_stream,
-                        q.input_stream.right_input_stream):
+            # a side's bound: @capacity(window.left / window.right /
+            # window), as the runtime's join wiring reads it
+            for sis, hint in zip((q.input_stream.left_input_stream,
+                                  q.input_stream.right_input_stream),
+                                 join_window_hints(caps, _WINDOW_HINT)):
                 w = window_handler(sis)
-                sides.append(window_capacity(w, _WINDOW_HINT)
+                sides.append(window_capacity(w, hint)
                              if w is not None else _BATCH_CAPACITY)
             f.join_side_rows = (sides[0], sides[1])
         out.append(f)
@@ -300,7 +304,10 @@ def facts_from_runtime(rt) -> List[QueryFacts]:
             nfa_slots=int(p.slots or _NFA_SLOTS) if kind == "pattern"
             else _NFA_SLOTS,
         )
-        if sf is not None and sf.join_side_rows is not None:
+        if kind == "join" and any(p.ring_caps):
+            # the planned windows' own bounds
+            f.join_side_rows = tuple(p.ring_caps)
+        elif sf is not None and sf.join_side_rows is not None:
             f.join_side_rows = sf.join_side_rows
         out.append(f)
     return out

@@ -406,7 +406,7 @@ def _dispatch_join(qr, items) -> None:
             # probes were bound (and the retention mirror fed) at offer
             # time, so the stack replays them verbatim
             host.append(np.stack(
-                [np.asarray(qr._join_key_probe(is_left, staged))
+                [np.asarray(qr._join_key_probe(is_left, staged)[0])
                  for _, staged, _ in items]))
         elif p.fastpath == "table":
             # candidates resolve against the table at DISPATCH time — the

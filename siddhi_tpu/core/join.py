@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..query_api.definition import StreamDefinition
+from ..query_api.expression import Constant
 from ..query_api.query import (
     InsertIntoStream,
     JoinInputStream,
@@ -33,7 +34,9 @@ from .keyslots import SlotAllocator
 from .plan_facts import JOIN_LANE_K_MIN, join_fastpath, table_probe_attrs_of
 from .selector import SelectorExec
 from .steputil import jit_step
-from .window import Buffer, NoWindow, Rows, WindowProcessor, create_window
+from .window import (NO_WAKEUP, Buffer, NoWindow, Rows, TimeRingWindow,
+                     TimeWindow, WindowProcessor, create_window, ring_age,
+                     ring_search, ring_write, slab_take)
 
 
 @dataclasses.dataclass
@@ -107,6 +110,29 @@ class PlannedJoinQuery:
     lane_k: int = 0              # candidate lane width (bucket mode)
     lane_buckets: Tuple[int, int] = (0, 0)   # per-side lane-table rows
     ring_caps: Tuple[int, int] = (0, 0)      # per-side retention bound
+    # per side: how its rows are found by key — "chain" (a `window.time`
+    # side kept as a ring: a head table by key slot and a link a row to the
+    # older row of its key, kept across steps) or "lanes" (the `[buckets,
+    # K]` table re-derived from the buffer each dispatch)
+    index_kind: Tuple[str, str] = ("", "")
+    # a ring side's programs beyond `step_left` / `step_right` (which walk
+    # the other side's chains 1 deep, in place): `side_step(is_left, depth,
+    # slow)` builds — once, then from `side_steps` — the side's program that
+    # walks `depth` deep (`chain_depth`: the runtime asks for what the
+    # batch's own keys hold on the other side) and, `slow`, takes a batch
+    # whose stamps are out of order through the whole-slab `process`.  Each
+    # is traced and compiled when first asked for
+    step_maker: Optional[Callable] = None
+    side_steps: Dict = dataclasses.field(default_factory=dict)
+
+    def side_step(self, is_left: bool, depth: int = 1, slow: bool = False):
+        key = (bool(is_left), int(depth), bool(slow))
+        if key == (key[0], 1, False) or self.step_maker is None:
+            return self.step_left if is_left else self.step_right
+        fn = self.side_steps.get(key)
+        if fn is None:
+            fn = self.side_steps[key] = self.step_maker(*key)
+        return fn
     # shared key->slot allocator (both sides; carried across replans)
     join_key_allocator: Optional[Any] = None
     # table mode: which side is the table and the probe columns
@@ -184,6 +210,17 @@ class PlannedJoinQuery:
             if self.fastpath == "bucket":
                 node["lane_k"] = int(self.lane_k)
                 node["lane_buckets"] = list(self.lane_buckets)
+                # each side: the rows its window may hold, how its rows are
+                # found by key, how deep a probe of it walks
+                node["window_bound_rows"] = list(self.ring_caps)
+                node["index_kind"] = list(self.index_kind)
+                # the deepest walk OF each side a program has been built for
+                node["probe_depth"] = [
+                    max([1] + [d for (left, d, _s) in self.side_steps
+                               if left != probed_is_left])
+                    if kind == "chain" else 0
+                    for probed_is_left, kind in
+                    zip((True, False), self.index_kind)]
                 node["key_capacity"] = (
                     self.join_key_allocator.capacity
                     if self.join_key_allocator is not None else None)
@@ -299,7 +336,14 @@ def plan_join_query(
     mesh=None,
     emit_rows_override: Optional[int] = None,
     lane_k_override: Optional[int] = None,
+    window_caps: Tuple[Optional[int], Optional[int]] = (None, None),
+    key_capacity: Optional[int] = None,
 ) -> PlannedJoinQuery:
+    """`window_caps`: the rows each side's window may hold
+    (`@capacity(window='N')`, or `window.left` / `window.right`; None: the
+    default `window_capacity_hint`).  `key_capacity`: the distinct join keys
+    both windows may hold together (`@capacity(keys='N')`; None: every row
+    its own key)."""
     jis = query.input_stream
     assert isinstance(jis, JoinInputStream)
 
@@ -323,11 +367,13 @@ def plan_join_query(
     scope = Scope()
     scope.interner = interner
     left = _mk_side(jis.left_input_stream, schemas, tables, batch_capacity,
-                    scope, window_capacity_hint, aggregations, named_windows,
+                    scope, window_caps[0] or window_capacity_hint,
+                    aggregations, named_windows,
                     probe_col=fp_mode == "bucket")
     right = _mk_side(jis.right_input_stream, schemas, tables, batch_capacity,
-                     scope, window_capacity_hint, aggregations,
-                     named_windows, probe_col=fp_mode == "bucket")
+                     scope, window_caps[1] or window_capacity_hint,
+                     aggregations, named_windows,
+                     probe_col=fp_mode == "bucket")
     if left.is_table and right.is_table and \
             not (left.is_named_window or right.is_named_window):
         raise CompileError("cannot join two tables in a streaming query")
@@ -358,57 +404,6 @@ def plan_join_query(
     on = None
     if jis.on_compare is not None:
         on = compile_expression(jis.on_compare, scope)
-
-    # ---- equi-join fast-path plan details ---------------------------------
-    key_attrs: List[Tuple[str, str]] = []
-    key_left: List[int] = []
-    key_right: List[int] = []
-    key_dtypes: List[Any] = []
-    lane_k = 0
-    lane_buckets = (0, 0)
-    ring_caps = (0, 0)
-    jk_alloc = None
-    table_is_left = False
-    table_pos = -1
-    stream_key_pos = -1
-    if fp_mode == "bucket":
-        for _c, lv, rv in fp_pairs:
-            lp = left.schema.position(lv.attribute_name)
-            rp = right.schema.position(rv.attribute_name)
-            key_left.append(lp)
-            key_right.append(rp)
-            key_attrs.append((lv.attribute_name, rv.attribute_name))
-            # both sides hash the PROMOTED encoding, so any two values
-            # the compiled `==` would call equal land in one bucket
-            key_dtypes.append(np.promote_types(
-                ev.np_dtype(left.schema.types[lp]),
-                ev.np_dtype(right.schema.types[rp])))
-        ring_caps = (_retention_rows(left.window),
-                     _retention_rows(right.window))
-        lane_buckets = (_lane_bucket_count(ring_caps[0]),
-                        _lane_bucket_count(ring_caps[1]))
-        # initial lane width: cover small windows outright (occupancy
-        # can never exceed the retention bound, so tiny-window joins
-        # never pay a growth recompile) and start larger shapes at the
-        # K a roughly-uniform key spread settles into
-        auto_k = 1 << (max(1, min(max(ring_caps), 16)) - 1).bit_length()
-        lane_k = max(JOIN_LANE_K_MIN, auto_k, int(lane_k_override or 0))
-        # key slots live while EITHER ring retains them plus one batch
-        # of new arrivals in flight (JoinKeyTracker evicts before it
-        # allocates, so this bound holds transiently too)
-        jk_alloc = SlotAllocator(
-            ring_caps[0] + ring_caps[1] + 2 * max(batch_capacity, 8192),
-            name=f"{name}:joinkey")
-    elif fp_mode == "table":
-        tside, sside = (left, right) if left.is_table else (right, left)
-        table_is_left = left.is_table
-        _c, lv, rv = fp_pairs[0]
-        t_var, s_var = (lv, rv) if table_is_left else (rv, lv)
-        table_pos = tside.schema.position(t_var.attribute_name)
-        stream_key_pos = sside.schema.position(s_var.attribute_name)
-        key_attrs = [(lv.attribute_name, rv.attribute_name)]
-    n_conj = _conjunct_count(jis.on_compare)
-    fp_residual = fp_mode is not None and n_conj > len(key_attrs)
 
     # group-by in joins (reference: JoinProcessor + QuerySelector
     # processGroupBy, JoinProcessor.java:107-190): group attrs resolve to
@@ -478,6 +473,75 @@ def plan_join_query(
         late_pairs and isinstance(query.output_stream, InsertIntoStream)
         and out_event_type == "CURRENT_EVENTS"
         and out_target not in tables and query.output_rate is None)
+    # ---- equi-join fast-path plan details ---------------------------------
+    key_attrs: List[Tuple[str, str]] = []
+    key_left: List[int] = []
+    key_right: List[int] = []
+    key_dtypes: List[Any] = []
+    lane_k = 0
+    lane_buckets = (0, 0)
+    ring_caps = (0, 0)
+    index_kind = ("", "")
+    jk_alloc = None
+    table_is_left = False
+    table_pos = -1
+    stream_key_pos = -1
+    if fp_mode == "bucket":
+        for _c, lv, rv in fp_pairs:
+            lp = left.schema.position(lv.attribute_name)
+            rp = right.schema.position(rv.attribute_name)
+            key_left.append(lp)
+            key_right.append(rp)
+            key_attrs.append((lv.attribute_name, rv.attribute_name))
+            # both sides hash the PROMOTED encoding, so any two values
+            # the compiled `==` would call equal land in one bucket
+            key_dtypes.append(np.promote_types(
+                ev.np_dtype(left.schema.types[lp]),
+                ev.np_dtype(right.schema.types[rp])))
+        # a `window.time` side of a join that reads CURRENT rows alone is
+        # kept as a ring with its same-key chain (TimeRingWindow): a step
+        # costs what arrives.  A plan fact; GSPMD row sharding keeps the
+        # compacting form
+        if not expired_joined and (mesh is None or mesh.devices.size < 2):
+            for side, cap in ((left, window_caps[0]), (right, window_caps[1])):
+                if type(side.window) is TimeWindow:
+                    w = side.window
+                    side.window = TimeRingWindow(
+                        w.schema, [Constant(w.time_ms, "LONG")],
+                        batch_capacity,
+                        capacity_hint=cap or w.capacity)
+        index_kind = tuple(
+            "chain" if isinstance(s_.window, TimeRingWindow) else "lanes"
+            for s_ in (left, right))
+        ring_caps = (_retention_rows(left.window),
+                     _retention_rows(right.window))
+        lane_buckets = (_lane_bucket_count(ring_caps[0]),
+                        _lane_bucket_count(ring_caps[1]))
+        # initial lane width: cover small windows outright (occupancy
+        # can never exceed the retention bound, so tiny-window joins
+        # never pay a growth recompile) and start larger shapes at the
+        # K a roughly-uniform key spread settles into
+        auto_k = 1 << (max(1, min(max(ring_caps), 16)) - 1).bit_length()
+        lane_k = max(JOIN_LANE_K_MIN, auto_k, int(lane_k_override or 0))
+        # key slots live while EITHER ring retains them plus one batch
+        # of new arrivals in flight (JoinKeyTracker evicts before it
+        # allocates, so this bound holds transiently too);
+        # `@capacity(keys='N')` states fewer where the rows share keys
+        jk_alloc = SlotAllocator(
+            min(ring_caps[0] + ring_caps[1], key_capacity or (1 << 62))
+            + 2 * max(batch_capacity, 8192),
+            name=f"{name}:joinkey")
+    elif fp_mode == "table":
+        tside, sside = (left, right) if left.is_table else (right, left)
+        table_is_left = left.is_table
+        _c, lv, rv = fp_pairs[0]
+        t_var, s_var = (lv, rv) if table_is_left else (rv, lv)
+        table_pos = tside.schema.position(t_var.attribute_name)
+        stream_key_pos = sside.schema.position(s_var.attribute_name)
+        key_attrs = [(lv.attribute_name, rv.attribute_name)]
+    n_conj = _conjunct_count(jis.on_compare)
+    fp_residual = fp_mode is not None and n_conj > len(key_attrs)
+
     out_def = StreamDefinition(out_target or f"#{name}.out")
     for n, t in zip(sel.out_names, sel.out_types):
         out_def.attribute(n, t)
@@ -497,8 +561,11 @@ def plan_join_query(
     if emit_explicit:
         emit_rows = int(emit_ann.element("rows", 0)) or None
 
-    def make_step(this: JoinSide, other: JoinSide, this_is_left: bool):
-        """Step for a batch arriving on `this` side."""
+    def make_step(this: JoinSide, other: JoinSide, this_is_left: bool,
+                  slow: bool = False, depth_other: int = 1):
+        """Step for a batch arriving on `this` side (`slow`: a ring side's
+        batch through the whole-slab `process`; `depth_other`: how deep a
+        probe walks a ring `other` side's same-key chains)."""
         emit_unmatched_this = (
             (jt == "LEFT_OUTER_JOIN" and this_is_left) or
             (jt == "RIGHT_OUTER_JOIN" and not this_is_left) or
@@ -514,6 +581,13 @@ def plan_join_query(
         # the window's out_capacity) and the window only updates its state
         feed = this.window.admit if not expired_joined and \
             this.window.current_is_arrivals else this.window.process
+        # a ring side (window.TimeRingWindow) takes `_ring_feed`; a probe OF
+        # one walks its kept chain, `depth_other` deep
+        this_ring = isinstance(this.window, TimeRingWindow)
+        other_ring = isinstance(other.window, TimeRingWindow)
+        # a time window that is full drops its oldest row: the step counts
+        # them and the header carries the count (the runtime reports it)
+        counts_drops = isinstance(this.window, TimeWindow)
 
         def step(state, ts, kind, valid, cols, gslot, *rest):
             if bucket or table_probe:
@@ -545,8 +619,23 @@ def plan_join_query(
                                                  dtype=jnp.int32),)
                 rows = Rows(ts=ts, kind=kind, valid=keep,
                             seq=jnp.zeros_like(ts), gslot=gslot, cols=in_cols)
-                this_state, wout = feed(this_state, rows, now)
-            orows = wout.rows                       # [R]
+                if counts_drops and not this_ring:
+                    b0 = this_state[0]
+                    w_dropped = jnp.maximum(
+                        jnp.sum(jnp.logical_and(
+                            b0.alive, b0.expire_ts > now).astype(jnp.int32))
+                        + jnp.sum(jnp.logical_and(
+                            keep, is_cur).astype(jnp.int32))
+                        - this.window.capacity, 0)
+                if not this_ring:
+                    this_state, wout = feed(this_state, rows, now)
+                    orows, wake = wout.rows, wout.next_wakeup   # [R]
+            if this_ring:
+                # expiry is the tail's place and `expire_ts > stamp`: no
+                # timer step has anything to do
+                this_state, orows, w_dropped = _ring_feed(
+                    this.window, this_state, rows, probe, now, slow)
+                wake = jnp.asarray(NO_WAKEUP, jnp.int64)
             if bucket or table_probe:
                 trig_extra = orows.cols[-1]
                 t_cols = orows.cols[:-1]
@@ -560,7 +649,13 @@ def plan_join_query(
                 o_gslot = jnp.zeros(o_ts.shape, jnp.int32)
             else:
                 obuf: Buffer = other_state[0]
-                o_cols, o_ts, o_alive = obuf.cols, obuf.ts, obuf.alive
+                if other_ring:
+                    # a ring's slab: columns behind `column[idx]`, no
+                    # `alive` plane (residence is the ring's `pos`)
+                    o_cols = other.window.slab_columns(obuf)
+                    o_ts, o_alive = obuf.gslot, None
+                else:
+                    o_cols, o_ts, o_alive = obuf.cols, obuf.ts, obuf.alive
                 o_gslot = obuf.gslot
                 if bucket:
                     o_jslot = o_cols[-1]
@@ -568,7 +663,7 @@ def plan_join_query(
 
             R = orows.ts.shape[0]
             C = o_ts.shape[0]
-            if bucket:
+            if bucket and not other_ring:
                 with jax.named_scope("join_lanes"):
                     # [R, K] same-bucket candidates instead of the [R, C]
                     # grid: the lane table is re-derived from the buffer's
@@ -583,7 +678,22 @@ def plan_join_query(
                     is_trigger = jnp.logical_or(is_trigger,
                                                 orows.kind == ev.EXPIRED)
                 data_row = jnp.logical_and(orows.valid, is_trigger)
-                if bucket:
+                if other_ring:
+                    # the trigger row's own key's rows alone, and of them
+                    # the ones its stamp still sees
+                    ri2, cand_ok = _chain_walk(
+                        other_state[3], other_state[4], obuf.expire_ts,
+                        other_state[2], trig_extra.astype(jnp.int32),
+                        orows.ts, depth_other)
+                    env = {
+                        this.key: tuple(c[:, None] for c in t_cols),
+                        other.key: tuple(c[ri2] for c in o_cols),
+                        "__ts__": orows.ts[:, None],
+                        "__now__": now,
+                    }
+                    m = jnp.broadcast_to(on.fn(env), ri2.shape)
+                    m = jnp.logical_and(m, cand_ok)
+                elif bucket:
                     tb = trig_extra.astype(jnp.int32) % nbl_other
                     cand = lanes[tb]                       # [R, K]
                     cand_ok = cand < C
@@ -748,13 +858,16 @@ def plan_join_query(
                 # n_expired derives as n_valid - n_current host-side
                 n_cur = jnp.sum(jnp.logical_and(
                     o_valid, o_kind == ev.CURRENT)).astype(jnp.int32)
-                out = (jnp.stack([n_del, n_cur]), n_tot - n_del,
+                head = [n_del, n_cur]
+                if counts_drops:
+                    head.append(w_dropped.astype(jnp.int32))
+                out = (jnp.stack(head), n_tot - n_del,
                        o_ts, o_kind, o_valid, o_cols)
             nstate = ((this_state, other_state) if this_is_left
                       else (other_state, this_state))
             new_state = _constrain_state(
                 (nstate[0], nstate[1], sel_state), mesh)
-            return new_state, out, wout.next_wakeup
+            return new_state, out, wake
 
         return step
 
@@ -782,11 +895,26 @@ def plan_join_query(
     if raw_right is not None:
         step_right = jit_step(raw_right, owner=name, role="join_right",
                               donate_argnums=(0,))
+    def step_maker(is_left: bool, depth: int, slow: bool):
+        """A ring side's program at another walk depth / for stamps out of
+        order (PlannedJoinQuery.side_step)."""
+        side, other = (left, right) if is_left else (right, left)
+        triggers = trigger in ("ALL_EVENTS", "LEFT" if is_left else "RIGHT")
+        raw = make_step(side, other, is_left, slow=slow,
+                        depth_other=depth) if triggers else \
+            _make_feed_only(side, is_left, mesh, fp_mode, slow=slow)
+        return jit_step(raw, owner=name,
+                        role="join_left" if is_left else "join_right",
+                        donate_argnums=(0,))
 
     def init_state():
-        wl = left.window.init_state() if left.window else ()
-        wr = right.window.init_state() if right.window else ()
-        return (wl, wr, sel.init_state())
+        def side_state(side):
+            if side.window is None:
+                return ()
+            if isinstance(side.window, TimeRingWindow):
+                return _ring_side_init(side.window, jk_alloc.capacity)
+            return side.window.init_state()
+        return (side_state(left), side_state(right), sel.init_state())
 
     return PlannedJoinQuery(
         name=name, left=left, right=right, join_type=jt, trigger=trigger,
@@ -808,6 +936,8 @@ def plan_join_query(
         key_attrs=key_attrs, key_left=key_left, key_right=key_right,
         key_dtypes=key_dtypes, residual=fp_residual,
         lane_k=lane_k, lane_buckets=lane_buckets, ring_caps=ring_caps,
+        index_kind=index_kind,
+        step_maker=step_maker if "chain" in index_kind else None,
         join_key_allocator=jk_alloc,
         table_is_left=table_is_left, table_pos=table_pos,
         stream_key_pos=stream_key_pos, late_pairs=late_pairs,
@@ -815,8 +945,9 @@ def plan_join_query(
 
 
 def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,
-                    fp_mode: Optional[str] = None):
+                    fp_mode: Optional[str] = None, slow: bool = False):
     takes_probe = fp_mode in ("bucket", "table")
+    ring = isinstance(side.window, TimeRingWindow)
 
     def step(state, ts, kind, valid, cols, gslot, *rest):
         if takes_probe:
@@ -839,14 +970,19 @@ def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,
                 in_cols = cols + (jnp.arange(ts.shape[0], dtype=jnp.int32),)
             rows = Rows(ts=ts, kind=kind, valid=keep, seq=jnp.zeros_like(ts),
                         gslot=gslot, cols=in_cols)
-            this_state, wout = side.window.process(this_state, rows, now)
+            if not ring:
+                this_state, wout = side.window.process(this_state, rows, now)
+                wake = wout.next_wakeup
+        if ring:
+            this_state, _cur, _dropped = _ring_feed(
+                side.window, this_state, rows, probe, now, slow)
+            wake = jnp.asarray(NO_WAKEUP, jnp.int64)
         out_empty = (
             jnp.zeros((1,), jnp.int64), jnp.zeros((1,), jnp.int32),
             jnp.zeros((1,), jnp.bool_), tuple())
         new_state = (this_state, wr_state, sel_state) if is_left else \
             (wl_state, this_state, sel_state)
-        return _constrain_state(new_state, mesh), out_empty, \
-            wout.next_wakeup
+        return _constrain_state(new_state, mesh), out_empty, wake
 
     return step
 
@@ -854,6 +990,151 @@ def _make_feed_only(side: JoinSide, is_left: bool, mesh=None,
 # ---------------------------------------------------------------------------
 # equi-join fast path machinery (ROADMAP item 2)
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# the kept index of a ring side: a head table by key slot, a link a row
+# ---------------------------------------------------------------------------
+#
+# A `window.time` side kept as a ring (window.TimeRingWindow) carries, beside
+# its window state `(RingSlab, seq, pos)`, `head [keys]` — the ring position of
+# the NEWEST row of each key slot, -1 none — and `prev [C]` — for each ring
+# position the position of the next OLDER row of its key, -1 none.  Arrivals
+# are linked in as they are written; nothing is ever unlinked: a walk follows
+# a link only to a STRICTLY OLDER resident row (`ring_age` falling, below
+# `count`), so a link into a row that has expired, or into a position a newer
+# row has since taken, ends the walk — and every resident row of a key stands
+# before any such link, because a ring expires oldest first.  A head that
+# points at a position another key has taken costs a candidate the ON
+# condition rejects, never a match.
+
+CHAIN_EXP_BY_SEARCH = 16    # deeper walks bound expiry by one search a row
+
+
+def chain_depth(need: int, fullest: int) -> int:
+    """The walk depth a batch is given: 1 where the probed side has never
+    held two rows of a key; else the power of four at or above the most
+    rows any of the BATCH's keys holds there (`need`), 4 at the least — a
+    few programs (1, 4, 16, 64, ...), each compiled when first needed, and a
+    side of unique keys is probed 1 deep while the other holds a 300-row
+    key."""
+    if fullest <= 1:
+        return 1
+    d = 4
+    while d < need:
+        d *= 4
+    return d
+
+
+def _ring_side_init(win: TimeRingWindow, n_keys: int):
+    return win.init_state() + (
+        jnp.full((n_keys,), -1, jnp.int32),
+        jnp.full((win.capacity,), -1, jnp.int32))
+
+
+def _key_runs(ss):
+    """Of keys in sorted order: (is the row before of the same key, is this
+    the last row of its key)."""
+    same = ss[1:] == ss[:-1]
+    return (jnp.concatenate([jnp.zeros((1,), jnp.bool_), same]),
+            jnp.concatenate([jnp.logical_not(same),
+                             jnp.ones((1,), jnp.bool_)]))
+
+
+def _chain_insert(head, slot, is_cur, pos):
+    """Link a batch's arrivals (`slot`, ring position `pos`, [B]) in: ->
+    (head', the `prev` value of each arrival).  An arrival's older row is
+    the batch's previous arrival of its slot, else the slot's old head."""
+    n_keys = head.shape[0]
+    key = jnp.where(is_cur, slot, n_keys)
+    o = jnp.argsort(key, stable=True).astype(jnp.int32)
+    ss, pp = key[o], pos[o]
+    same, last = _key_runs(ss)
+    older = jnp.where(
+        same, jnp.concatenate([pp[:1], pp[:-1]]),
+        head.at[jnp.minimum(ss, n_keys - 1)].get(mode="promise_in_bounds"))
+    head = head.at[jnp.where(last, ss, n_keys)].set(
+        pp, mode="drop", unique_indices=True)
+    return head, older[jnp.argsort(o)]
+
+
+def _chain_build(jslot, count, n_keys: int):
+    """`head`, `prev` from nothing, for a ring that starts at 0 with `count`
+    rows (after `ring_process`): one sort of the slab by (slot, age)."""
+    C = jslot.shape[0]
+    live = jnp.arange(C, dtype=jnp.int32) < count
+    key = jnp.where(live, jslot.astype(jnp.int32), n_keys)
+    o = jnp.argsort(key, stable=True).astype(jnp.int32)
+    ss = key[o]
+    same, last = _key_runs(ss)
+    older = jnp.where(same, jnp.concatenate([o[:1], o[:-1]]), -1)
+    prev = jnp.full((C,), -1, jnp.int32).at[o].set(older)
+    head = jnp.full((n_keys,), -1, jnp.int32).at[
+        jnp.where(last, ss, n_keys)].set(o, mode="drop")
+    return head, prev
+
+
+def _chain_walk(head, prev, exp, rp, slot, ts, depth: int):
+    """The resident rows of each trigger row's key that its stamp still
+    sees, oldest first: -> (cand [R, depth] ring positions, ok [R, depth])."""
+    C = prev.shape[0]
+    tail, count = rp[0], rp[1]
+    known = slot >= 0
+    cur = head.at[jnp.clip(slot, 0, head.shape[0] - 1)].get(
+        mode="promise_in_bounds")
+    age = ring_age(cur, tail, C)
+    ok = jnp.logical_and(known, jnp.logical_and(cur >= 0, age < count))
+    by_search = depth > CHAIN_EXP_BY_SEARCH
+    if by_search:
+        # a ring is in expiry order: what a stamp no longer sees is a
+        # prefix, `seen` rows long — and a key whose newest row is in it has
+        # no row left
+        seen = ring_search(exp, tail, count, ts, C)
+        ok = jnp.logical_and(ok, age >= seen)
+
+    def hop(c, _):
+        cur, age, ok = c
+        nxt = prev.at[jnp.clip(cur, 0, C - 1)].get(mode="promise_in_bounds")
+        nage = ring_age(nxt, tail, C)
+        nok = jnp.logical_and(ok, jnp.logical_and(nxt >= 0, nage < age))
+        return (nxt, nage, nok), (cur, ok)
+
+    if depth == 1:
+        cand, oks = cur[:, None], ok[:, None]
+    else:
+        _, (cands, okss) = jax.lax.scan(hop, (cur, age, ok), None,
+                                       length=depth)
+        if by_search:
+            okss = jnp.logical_and(
+                okss, ring_age(cands, tail, C) >= seen[None, :])
+        # newest first as walked; the grid's order is oldest first
+        cand, oks = cands[::-1].T, okss[::-1].T
+    cand = jnp.clip(cand, 0, C - 1)
+    if not by_search:
+        oks = jnp.logical_and(oks, slab_take(exp, cand) > ts[:, None])
+    return cand, oks
+
+
+def _ring_feed(win: TimeRingWindow, side_state, rows: Rows, slot, now,
+               slow: bool):
+    """One batch into a ring side: -> (side state, the arrivals as CURRENT
+    trigger rows where they stand, rows dropped for capacity).  The window's
+    ops stand under `join_window`, the index upkeep under `join_lanes`."""
+    wstate, head, prev = side_state[:3], side_state[3], side_state[4]
+    if slow or rows.capacity > win.capacity:
+        with jax.named_scope("join_window"):
+            wstate, cur, dropped = win.ring_process(wstate, rows, now)
+        with jax.named_scope("join_lanes"):
+            head, prev = _chain_build(wstate[0].cols[-1], wstate[2][1],
+                                      head.shape[0])
+        return wstate + (head, prev), cur, dropped
+    with jax.named_scope("join_window"):
+        wstate, cur, pos, dropped, plan = win.ring_admit(wstate, rows, now)
+    with jax.named_scope("join_lanes"):
+        head, older = _chain_insert(head, slot.astype(jnp.int32), cur.valid,
+                                    pos)
+        prev, = ring_write((prev,), (older,), *plan)
+    return wstate + (head, prev), cur, dropped
+
 
 def _retention_rows(win: Optional[WindowProcessor]) -> int:
     """Upper bound on rows a join window retains: length windows keep
@@ -916,117 +1197,183 @@ def _norm_key_cols(staged_cols, positions, dtypes) -> List[np.ndarray]:
 
 
 class _TrackSide:
-    """One side's retention ring: slot ids of the last `cap` admitted
-    arrivals, plus per-lane (slot % nbl) occupancy counts."""
+    """One side's retention ring on the host: the key slot of every row the
+    device window holds, oldest first, with — for a `window.time` side —
+    the stamp each expires at; the rows a slot holds (`cnt`), the fullest
+    key ever (`deep`), and, for a side probed through lanes, the per-lane
+    (slot % nbl) occupancy."""
 
-    __slots__ = ("cap", "nbl", "ring", "head", "n", "lane")
+    __slots__ = ("cap", "nbl", "ring", "exp", "time_ms", "head", "n", "lane",
+                 "cnt", "deep", "dropped")
 
-    def __init__(self, cap: int, nbl: int):
+    def __init__(self, cap: int, nbl: int, n_keys: int,
+                 time_ms: Optional[int] = None, lanes: bool = True):
         self.cap = max(1, int(cap))
         self.nbl = max(1, int(nbl))
-        self.ring = np.full(self.cap, -1, np.int64)
+        self.ring = np.full(self.cap, -1, np.int32)
+        self.time_ms = time_ms
+        self.exp = None if time_ms is None else np.zeros(self.cap, np.int64)
         self.head = 0
         self.n = 0
-        self.lane = np.zeros(self.nbl, np.int64)
+        self.lane = np.zeros(self.nbl, np.int64) if lanes else None
+        self.cnt = np.zeros(n_keys, np.int32)
+        self.deep = 0
+        self.dropped = 0          # rows a full `window.time` side lost
 
-    def oldest(self, k: int) -> np.ndarray:
-        idx = (self.head + np.arange(k)) % self.cap
-        return self.ring[idx]
+    def _span(self, start: int, k: int) -> np.ndarray:
+        return (self.head + start + np.arange(k)) % self.cap
 
-    def pop(self, k: int) -> None:
+    def due(self, now: int) -> int:
+        """How many of the oldest rows have expired by `now` (the ring is
+        in expiry order: two sorted runs where it wraps)."""
+        if self.exp is None or not self.n:
+            return 0
+        end = self.head + self.n
+        if end <= self.cap:
+            return int(np.searchsorted(self.exp[self.head:end], now,
+                                       side="right"))
+        first = self.exp[self.head:]
+        k = int(np.searchsorted(first, now, side="right"))
+        if k == first.size:
+            k += int(np.searchsorted(self.exp[:end - self.cap], now,
+                                     side="right"))
+        return k
+
+    def pop(self, k: int) -> np.ndarray:
+        """Forget the `k` oldest rows; -> the distinct slots they held."""
+        old = self.ring[self._span(0, k)]
         self.head = (self.head + k) % self.cap
         self.n -= k
+        u, c = np.unique(old, return_counts=True)
+        self.cnt[u] -= c.astype(np.int32)
+        if self.lane is not None:
+            self.lane -= np.bincount(old % self.nbl, minlength=self.nbl)
+        return u
 
-    def push(self, arr: np.ndarray) -> None:
-        idx = (self.head + self.n + np.arange(arr.size)) % self.cap
-        self.ring[idx] = arr
-        self.n += arr.size
+    def push(self, slots: np.ndarray, exp=None) -> None:
+        idx = self._span(self.n, slots.size)
+        self.ring[idx] = slots
+        if self.exp is not None:
+            self.exp[idx] = exp
+        self.n += slots.size
+        u, c = np.unique(slots, return_counts=True)
+        self.cnt[u] += c.astype(np.int32)
+        self.deep = max(self.deep, int(self.cnt[u].max()))
+        if self.lane is not None:
+            self.lane += np.bincount(slots % self.nbl, minlength=self.nbl)
+
+    def in_expiry_order(self) -> None:
+        """After stamps out of order: the rows oldest-expiry first from 0,
+        as the device's `ring_process` leaves them."""
+        idx = self._span(0, self.n)
+        order = np.argsort(self.exp[idx], kind="stable")
+        self.ring[:self.n] = self.ring[idx][order]
+        self.exp[:self.n] = self.exp[idx][order]
+        self.head = 0
 
 
 class JoinKeyTracker:
     """Host mirror of per-key window retention for the bucketed
-    equi-join fast path.
+    equi-join fast path.  Numpy throughout: a send costs a few passes over
+    ITS rows, whatever the windows hold.
 
-    Conservative invariant: each side's ring holds the key slots of the
-    last `cap` admitted arrivals — a SUPERSET of the rows alive in that
-    side's device buffer (length windows retain exactly the last
-    `length` arrivals; time windows drop-oldest above `cap` and time
-    expiry only shrinks the alive set further).  Two guarantees ride on
-    it: (1) the max same-lane occupancy across both rings never
-    under-counts the device buffers, so the planned lane width K always
-    covers every candidate — an under-sized K would silently diverge
-    from the grid path; (2) a key slot recycles only when NEITHER ring
-    retains it, so no alive buffer row can be left holding a slot that
-    a new key re-binds (which would hide its future matches)."""
+    Conservative invariant: each side's ring holds the key slots of a
+    SUPERSET of the rows alive in that side's device buffer — exactly the
+    rows for a length window (the last `length` arrivals) and for a
+    `window.time` side whose stamps come in order (what the clock has not
+    expired by the side's own last step, the newest `cap` of them; a row a
+    full window loses is COUNTED, `dropped`).  Two guarantees ride on it:
+    (1) the fullest lane (`needed_k`) and the fullest key (`fullest_keys`)
+    never under-count the device buffers, so the planned lane width and
+    walk depth always cover every candidate; (2) a key slot recycles only
+    when NEITHER ring retains it, so no row the device still shows can be
+    left holding a slot that a new key re-binds."""
 
-    def __init__(self, alloc: SlotAllocator, ring_caps, lane_buckets):
+    def __init__(self, alloc: SlotAllocator, ring_caps, lane_buckets,
+                 time_ms=(None, None), index_kind=("lanes", "lanes")):
         self.alloc = alloc
-        self.sides = (
-            _TrackSide(ring_caps[0], lane_buckets[0]),
-            _TrackSide(ring_caps[1], lane_buckets[1]),
-        )
-        self.refs = np.zeros(alloc.capacity, np.int64)
+        self.sides = tuple(
+            _TrackSide(ring_caps[i], lane_buckets[i], alloc.capacity,
+                       time_ms[i], lanes=index_kind[i] != "chain")
+            for i in (0, 1))
+        self.batch_need = 0
 
     def needed_k(self) -> int:
-        return max(int(s.lane.max(initial=0)) for s in self.sides)
+        return max((int(s.lane.max(initial=0)) for s in self.sides
+                    if s.lane is not None), default=0)
 
-    def _evict(self, s: _TrackSide, incoming: int, dead: set) -> None:
-        k = min(max(s.n + incoming - s.cap, 0), s.n)
-        if k <= 0:
-            return
-        old = s.oldest(k)
-        s.pop(k)
-        np.subtract.at(self.refs, old, 1)
-        np.subtract.at(s.lane, old % s.nbl, 1)
-        for sl in np.unique(old):
-            if self.refs[sl] <= 0:
-                dead.add(int(sl))
+    def fullest_keys(self) -> Tuple[int, int]:
+        return (self.sides[0].deep, self.sides[1].deep)
 
-    def track(self, is_left: bool, key_cols, valid) -> np.ndarray:
+    def rows(self) -> Tuple[int, int]:
+        return (self.sides[0].n, self.sides[1].n)
+
+    def dropped(self) -> int:
+        return self.sides[0].dropped + self.sides[1].dropped
+
+    def track(self, is_left: bool, key_cols, valid, ts=None, now=None,
+              in_order: bool = True) -> np.ndarray:
         """Allocate bucket slots for one batch and fold it into the
-        side's ring.  Evicts BEFORE allocating so the allocator's
-        capacity bound (ring_l + ring_r + one batch) holds transiently,
-        and purges any slot neither ring retains afterwards."""
+        side's ring.  Evicts BEFORE allocating — what the clock has expired
+        by `now`, then what the batch pushes out of a full ring — so the
+        allocator's capacity bound holds transiently, and purges any slot
+        neither ring retains afterwards."""
         s = self.sides[0 if is_left else 1]
         nv = int(valid.sum())
-        dead: set = set()
-        if nv:
-            self._evict(s, min(nv, s.cap), dead)
+        dead = []
+        k = s.due(now) if now is not None else 0
+        if k:
+            dead.append(s.pop(k))
+        over = min(max(s.n + min(nv, s.cap) - s.cap, 0), s.n) if nv else 0
+        if over:
+            if s.exp is not None:
+                s.dropped += over
+            dead.append(s.pop(over))
         slots = self.alloc.slots_for(key_cols, valid)
-        ins = slots[valid].astype(np.int64)
-        skipped = None
+        ins = slots[valid].astype(np.int32)
+        # the most rows any of the batch's keys holds on the OTHER side:
+        # how deep its probes have to walk
+        o = self.sides[1 if is_left else 0]
+        self.batch_need = int(o.cnt[ins].max()) if ins.size else 0
+        exp = None if s.exp is None else \
+            np.asarray(ts)[valid].astype(np.int64) + s.time_ms
         if ins.size > s.cap:
             # a batch larger than the window: only its last `cap` rows
             # survive the step's own eviction — earlier rows join
             # transiently within the step but retain nothing
-            skipped, ins = ins[:-s.cap], ins[-s.cap:]
+            dead.append(np.unique(ins[:-s.cap]))
+            if s.exp is not None:
+                s.dropped += ins.size - s.cap
+                exp = exp[-s.cap:]
+            ins = ins[-s.cap:]
         if ins.size:
-            np.add.at(self.refs, ins, 1)
-            np.add.at(s.lane, ins % s.nbl, 1)
-            s.push(ins)
-        if skipped is not None:
-            dead.update(int(x) for x in np.unique(skipped))
-        gone = [d for d in dead if self.refs[d] <= 0]
-        if gone:
-            self.alloc.purge(gone)
+            s.push(ins, exp)
+            if not in_order:
+                s.in_expiry_order()
+        if dead:
+            d = np.concatenate(dead)
+            gone = d[(self.sides[0].cnt[d] == 0) &
+                     (self.sides[1].cnt[d] == 0)]
+            if gone.size:
+                self.alloc.purge(np.unique(gone))
         return slots
 
-    def rebuild(self, per_side_slots) -> None:
+    def rebuild(self, per_side_slots, per_side_exp=(None, None)) -> None:
         """Restore path: re-seed both rings from the snapshot's buffer
         contents (alive rows in arrival order) and drop every allocator
         binding neither window retains."""
-        self.refs[:] = 0
         self.sides = tuple(
-            _TrackSide(s.cap, s.nbl) for s in self.sides)
-        for s, slots in zip(self.sides, per_side_slots):
-            arr = np.asarray(slots, np.int64)[-s.cap:]
+            _TrackSide(s.cap, s.nbl, self.alloc.capacity, s.time_ms,
+                       lanes=s.lane is not None) for s in self.sides)
+        for s, slots, exp in zip(self.sides, per_side_slots, per_side_exp):
+            arr = np.asarray(slots, np.int32)[-s.cap:]
             if arr.size:
-                np.add.at(self.refs, arr, 1)
-                np.add.at(s.lane, arr % s.nbl, 1)
-                s.push(arr)
+                s.push(arr, None if s.exp is None else
+                       np.asarray(exp, np.int64)[-s.cap:])
         live = np.zeros(self.alloc.capacity, bool)
         for key, slot in self.alloc.snapshot().items():
             live[slot] = True
-        gone = np.nonzero(live & (self.refs <= 0))[0]
+        held = (self.sides[0].cnt > 0) | (self.sides[1].cnt > 0)
+        gone = np.nonzero(live & ~held)[0]
         if gone.size:
-            self.alloc.purge([int(x) for x in gone])
+            self.alloc.purge(gone)

@@ -348,24 +348,28 @@ class SlotAllocator:
 
     # -- lifecycle ------------------------------------------------------------
     def purge(self, slots: Sequence[int]) -> None:
+        """Unbind `slots` (any sequence or array of ints; unknown, unused
+        and repeated ones are skipped): a few numpy passes over them."""
         with self._lock:
             self.version += 1
             self._pcache[:] = 0
-            for s in slots:
-                s = int(s)
-                if s < 0 or s >= self.capacity or not self._used[s]:
-                    continue
-                self._used[s] = 0
-                self._free[self._meta[1]] = s
-                self._meta[1] += 1
-                self._meta[0] -= 1
-                cell = int(self._cell_by_slot[s])
-                if cell >= 0:
-                    self._cells[cell, 0] = _TOMB
-                    self._cells[cell, 1] = _EMPTY
-                    self._cells[cell, 2] = np.uint64(0xFFFFFFFF)
-                    self._cell_by_slot[s] = -1
-                    self._meta[2] += 1
+            s = np.unique(np.asarray(slots, np.int64))
+            s = s[(s >= 0) & (s < self.capacity)]
+            s = s[self._used[s] != 0]
+            if not s.size:
+                return
+            self._used[s] = 0
+            top = int(self._meta[1])
+            self._free[top:top + s.size] = s
+            self._meta[1] += s.size
+            self._meta[0] -= s.size
+            cells = self._cell_by_slot[s]
+            cells = cells[cells >= 0]
+            self._cells[cells, 0] = _TOMB
+            self._cells[cells, 1] = _EMPTY
+            self._cells[cells, 2] = np.uint64(0xFFFFFFFF)
+            self._cell_by_slot[s] = -1
+            self._meta[2] += cells.size
 
     def snapshot(self) -> Dict[bytes, int]:
         with self._lock:

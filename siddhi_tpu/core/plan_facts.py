@@ -180,9 +180,20 @@ def window_capacity(win, hint: int) -> int:
     return hint
 
 
+def join_window_hints(caps: Dict[str, int], default: int
+                      ) -> Tuple[int, int]:
+    """The rows a join's (left, right) `window.time` may hold, as
+    `runtime._add_join_query` reads `@capacity`: a side's own key, else
+    `window`, else the default hint."""
+    both = caps.get("window", default)
+    return (caps.get("window.left", both), caps.get("window.right", both))
+
+
 def capacity_annotation(q, part) -> Dict[str, int]:
     """@capacity(keys=, slots=, window=) merged across the query and its
-    partition (runtime._add_partition scans both)."""
+    partition (runtime._add_partition scans both); a join also says
+    `window.left` / `window.right`, a side's bound of its own
+    (runtime._add_join_query)."""
     out: Dict[str, int] = {}
     anns = list(q.annotations)
     if part is not None:
@@ -191,7 +202,8 @@ def capacity_annotation(q, part) -> Dict[str, int]:
             anns += list(pq.annotations)
     for ann in anns:
         if ann.name.lower() == "capacity":
-            for k in ("keys", "slots", "window"):
+            for k in ("keys", "slots", "window", "window.left",
+                      "window.right"):
                 v = ann.element(k)
                 if v is not None:
                     out[k] = int(v)
@@ -259,12 +271,14 @@ def query_state_components(app, q, kind: str, part,
             fp_mode = None
         # bucketed sides carry one extra i32 key-slot column per row
         extra = 4 if fp_mode == "bucket" else 0
-        for side, sis in (("join.left", q.input_stream.left_input_stream),
-                          ("join.right",
-                           q.input_stream.right_input_stream)):
+        hints = join_window_hints(caps, WINDOW_HINT)
+        for side, sis, hint in (
+                ("join.left", q.input_stream.left_input_stream, hints[0]),
+                ("join.right", q.input_stream.right_input_stream,
+                 hints[1])):
             win = window_handler(sis)
             if win is not None:
-                out[side] = window_capacity(win, WINDOW_HINT) * \
+                out[side] = window_capacity(win, hint) * \
                     (row_bytes(stream_def(sis.stream_id)) + extra)
         return out
     # pattern: per-key NFA slot block — `slots` pending matches per key,

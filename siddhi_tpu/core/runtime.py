@@ -24,9 +24,9 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
-from ..exceptions import (CannotRestoreStateError, DefinitionNotExistError,
-                          MatchOverflowError, QueryNotExistError,
-                          SiddhiAppValidationError)
+from ..exceptions import (CannotRestoreStateError, CapacityExceededError,
+                          DefinitionNotExistError, MatchOverflowError,
+                          QueryNotExistError, SiddhiAppValidationError)
 from ..observability import tracing as _tracing
 from ..observability import phases as _phases
 from ..observability import stateobs as _stateobs
@@ -1601,8 +1601,23 @@ def _demux_and_deliver(qr, out, now: int, header, target_live: bool,
             h0 = np.asarray(header[0])
             nd = int(header[1])
             if h0.ndim:
-                # join header vector [n_valid, n_current] (see join.py)
+                # join header vector [n_valid, n_current] (see join.py),
+                # and behind them — from a `window.time` side's step — the
+                # rows the window LOST because it was full: a wrong answer,
+                # so an error (raised below, after the rows that did come
+                # are delivered)
                 nv, ncur = int(h0[0]), int(h0[1])
+                if h0.shape[0] > 2 and int(h0[2]):
+                    lost = int(h0[2])
+                    qr._window_dropped += lost
+                    if _st.enabled:
+                        _st.counter_inc(f"{qr.name}.window_dropped", lost)
+                    overflow_exc = CapacityExceededError(
+                        f"{qr.name}: a join window dropped {lost} live "
+                        f"row(s) this batch because it was full; raise "
+                        f"@capacity(window='N') (or window.left / "
+                        f"window.right) on the query to hold what the "
+                        f"window's time keeps")
             else:
                 nv, ncur = int(h0), None
         if nd:
@@ -1828,11 +1843,23 @@ class JoinQueryRuntime(_QueryRuntimeBase):
         # lane width the NEXT replan must keep (core/join.py
         # JoinKeyTracker)
         self._lane_k = 0
+        # the last stamp each ring side admitted (a batch behind it, or out
+        # of order in itself, takes the side's slow program), and the
+        # deepest chain walk a batch has been given
+        self._ring_last_ts = [None, None]
+        self._probe_depth = 0
+        # rows a full `window.time` side lost, as the steps' headers said
+        self._window_dropped = 0
         if planned.fastpath == "bucket":
             from .join import JoinKeyTracker
-            self._jk = JoinKeyTracker(planned.join_key_allocator,
-                                      planned.ring_caps,
-                                      planned.lane_buckets)
+            self._jk = JoinKeyTracker(
+                planned.join_key_allocator, planned.ring_caps,
+                planned.lane_buckets,
+                time_ms=tuple(
+                    side.window.time_ms if kind == "chain" else None
+                    for side, kind in zip((planned.left, planned.right),
+                                          planned.index_kind)),
+                index_kind=planned.index_kind)
             self._lane_k = planned.lane_k
 
     _EMIT_CAP_MAX = 1 << 21   # 2M emitted rows per batch
@@ -1881,15 +1908,20 @@ class JoinQueryRuntime(_QueryRuntimeBase):
         self.planned = newp
         return True
 
-    def _join_key_probe(self, is_left: bool,
-                        staged: ev.StagedBatch) -> np.ndarray:
-        """Key bucket slots for one arriving batch (bucket fast path).
+    def _join_key_probe(self, is_left: bool, staged: ev.StagedBatch,
+                        now: Optional[int] = None):
+        """Key bucket slots for one arriving batch (bucket fast path), and
+        whether a ring side may take it in place (its CURRENT stamps in
+        order and none behind the side's last), and how deep its probes
+        walk a ring other side.
         Cached on the staged batch — keyed by (runtime, side), since a
         junction hands ONE staged object to every subscriber and a
         self-join sees it on both sides — so fused-drain re-entries and
         deferred dispatches can never double-count the retention
-        mirror.  Grows the planned lane width BEFORE the dispatch that
-        would overflow it; the span says both (`lane_k`, `lane_need`)."""
+        mirror.  Grows the planned lane width / walk depth BEFORE the
+        dispatch that would overflow it; the span says what the mirror
+        holds (`lane_k`, `lane_need`, `probe_depth`, `window_rows_l` /
+        `window_rows_r`, `window_dropped`)."""
         cache = staged.jprobe
         if cache is None:
             cache = staged.jprobe = {}
@@ -1902,13 +1934,36 @@ class JoinQueryRuntime(_QueryRuntimeBase):
         with _phases.phase(st, self.name, "route_keys") as sp:
             kvalid = staged.valid & (staged.kind == ev.CURRENT)
             pos = p.key_left if is_left else p.key_right
+            i = 0 if is_left else 1
+            in_order = True
+            if p.index_kind[i] == "chain":
+                ts = staged.ts[kvalid]
+                if ts.size:
+                    last = self._ring_last_ts[i]
+                    in_order = bool(
+                        (last is None or ts[0] >= last) and
+                        (ts.size < 2 or (ts[1:] >= ts[:-1]).all()))
+                    self._ring_last_ts[i] = int(ts.max()) if last is None \
+                        else max(last, int(ts.max()))
             slots = self._jk.track(
                 is_left, _norm_key_cols(staged.cols, pos, p.key_dtypes),
-                kvalid)
+                kvalid, ts=staged.ts, now=now, in_order=in_order)
             need = self._jk.needed_k()
             if need > p.lane_k:
                 self._grow_lane_k(need)
-            sp.set_metadata(lane_k=self.planned.lane_k, lane_need=need)
+            # a ring OTHER side is walked as deep as this batch's keys
+            # reach into it (join.chain_depth)
+            depth = 0
+            if p.index_kind[1 - i] == "chain":
+                from .join import chain_depth
+                depth = chain_depth(self._jk.batch_need,
+                                    self._jk.fullest_keys()[1 - i])
+                self._probe_depth = max(self._probe_depth, depth)
+            rows = self._jk.rows()
+            sp.set_metadata(lane_k=self.planned.lane_k, lane_need=need,
+                            probe_depth=depth,
+                            window_rows_l=rows[0], window_rows_r=rows[1],
+                            window_dropped=self._jk.dropped())
             out = np.where(kvalid, slots, -1).astype(np.int32)
         if _stateobs.obs_enabled(self.app):
             # lane demand is a running bucket-occupancy max the tracker
@@ -1920,8 +1975,23 @@ class JoinQueryRuntime(_QueryRuntimeBase):
                     growable=True,
                     config_key="auto (lane grows via replan)")
                 _stateobs_feed_slots(self, p.join_key_allocator, out, sp)
-        cache[key] = out
-        return out
+        cache[key] = (out, in_order, depth)
+        return cache[key]
+
+    def join_facts(self) -> Dict:
+        """What the host knows of a bucket join's windows without a fetch
+        (`state_report()["join"]`, `/metrics` `siddhi_join_*`): the rows
+        each side's retention mirror holds, the rows full `window.time`
+        sides lost (the mirror's count, or the steps' headers' where that is
+        more), the chain walks' depth.  {} off the bucket path."""
+        if self._jk is None:
+            return {}
+        rows = self._jk.rows()
+        return {"window_rows_l": rows[0], "window_rows_r": rows[1],
+                "window_dropped": max(self._jk.dropped(),
+                                      self._window_dropped),
+                "probe_depth": self._probe_depth,
+                "fullest_key": max(self._jk.fullest_keys())}
 
     def _grow_lane_k(self, need: int) -> None:
         """Recompile the side steps with wider candidate lanes.  Called
@@ -1973,17 +2043,31 @@ class JoinQueryRuntime(_QueryRuntimeBase):
         p = self.planned
         if p.fastpath != "bucket" or self._jk is None:
             return
-        sides = []
-        for st in (host_state[0], host_state[1]):
-            slots = np.empty(0, np.int64)
+        sides, exps = [], []
+        for st, kind in zip((host_state[0], host_state[1]), p.index_kind):
+            slots, exp = np.empty(0, np.int64), None
             buf = st[0] if isinstance(st, tuple) and st else None
-            if buf is not None and hasattr(buf, "alive"):
+            if buf is not None and kind == "chain":
+                # a ring (window.RingSlab): the resident rows are the
+                # `count` positions from the tail, oldest first
+                tail, count = (int(x) for x in np.asarray(st[2]))
+                at = (tail + np.arange(count)) % buf.gslot.shape[0]
+                slots = np.asarray(buf.cols[-1])[at].astype(np.int64)
+                lo, hi = (np.asarray(x)[at].astype(np.int64)
+                          for x in buf.expire_ts)
+                exp = (hi << 32) | lo
+                # the side's newest stamp: a batch behind it is out of order
+                side = p.left if len(sides) == 0 else p.right
+                self._ring_last_ts[len(sides)] = \
+                    int(exp[-1]) - side.window.time_ms if count else None
+            elif buf is not None and hasattr(buf, "alive"):
                 alive = np.asarray(buf.alive)
                 add_seq = np.asarray(buf.add_seq)[alive]
                 slots = np.asarray(buf.cols[-1])[alive][
                     np.argsort(add_seq, kind="stable")].astype(np.int64)
             sides.append(slots)
-        self._jk.rebuild(sides)
+            exps.append(exp)
+        self._jk.rebuild(sides, exps)
         need = self._jk.needed_k()
         if need > p.lane_k:
             self._grow_lane_k(need)
@@ -2040,14 +2124,17 @@ class JoinQueryRuntime(_QueryRuntimeBase):
     def process_staged(self, is_left: bool, staged: ev.StagedBatch,
                        now: int) -> None:
         p = self.planned
-        probe = None
+        probe, in_order, depth = None, True, 1
         if p.fastpath == "bucket":
             # slot binding + retention mirror BEFORE the fuse offer: a
             # lane-width growth must replan before this batch dispatches
-            probe = self._join_key_probe(is_left, staged)
+            probe, in_order, depth = self._join_key_probe(
+                is_left, staged, now)
             p = self.planned          # _grow_lane_k may have swapped it
         side = p.left if is_left else p.right
-        step = p.step_left if is_left else p.step_right
+        # `step_left` / `step_right`, or — a ring side — its program for a
+        # deeper walk of the other side's chains / for stamps out of order
+        step = p.side_step(is_left, max(depth, 1), not in_order)
         if step is None:
             return
         fb = self._fuse
@@ -3547,10 +3634,18 @@ class SiddhiAppRuntime:
     def _add_join_query(self, q: Query, name: str):
         import functools
         from .join import plan_join_query
+        # @capacity(window='N') bounds each side's window slab; `window.left`
+        # / `window.right` a side of its own; `keys` the distinct join keys
+        # both may hold together (the key-slot allocator and the rings' head
+        # tables; default: every row its own key)
+        from .plan_facts import capacity_annotation, join_window_hints
+        caps = capacity_annotation(q, None)
         plan = functools.partial(
             plan_join_query, q, name, self.schemas, self.tables,
             self.interner, aggregations=self.aggregations,
-            named_windows=self.named_windows, mesh=self.mesh)
+            named_windows=self.named_windows, mesh=self.mesh,
+            window_caps=join_window_hints(caps, None),
+            key_capacity=caps.get("keys"))
         planned = plan()
         runtime = JoinQueryRuntime(planned, self)
 

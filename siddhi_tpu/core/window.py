@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..query_api.expression import Constant
 from . import event as ev
-from .steputil import from_u32_planes, u32_planes
+from .steputil import from_u32_planes, join64, split64, u32_planes
 
 BIG_SEQ = jnp.iinfo(jnp.int64).max // 4  # "never expired"
 NO_WAKEUP = jnp.iinfo(jnp.int64).max // 4
@@ -801,3 +801,239 @@ def gather_packed(arrays, idx):
     packed = jnp.stack([p for a in arrays for p in u32_planes(a)])
     planes = iter(packed.at[:, idx].get(mode="promise_in_bounds"))
     return tuple(from_u32_planes(planes, a.dtype) for a in arrays)
+
+
+# ---------------------------------------------------------------------------
+# the time window as a RING, for a reader of CURRENT rows alone
+# ---------------------------------------------------------------------------
+
+class RingSlab(NamedTuple):
+    """A ring's rows, `[C]` arrays by ring position.  A 64-bit array is
+    kept as its two u32 planes `(low, high)` — XLA:TPU holds an s64 array
+    so anyway, and converts a whole s64 argument on the way into and out of
+    every program that takes it, a pass over all C rows a step; the planes
+    pass through untouched (the pattern state's lesson, PR 27)."""
+
+    ts: Any          # (lo, hi): the event's stamp
+    expire_ts: Any   # (lo, hi): ts + the window's time
+    gslot: Any       # i32
+    cols: Tuple[Any, ...]
+
+
+def _planes(x):
+    """An array as the planes a ring keeps it in."""
+    return split64(x) if x.dtype.itemsize == 8 else (x,)
+
+
+def slab_take(col, idx):
+    """`col[idx]` of a ring column (an array, or a 64-bit one's planes)."""
+    if isinstance(col, tuple):
+        return join64(*(p.at[idx].get(mode="promise_in_bounds")
+                        for p in col))
+    return col.at[idx].get(mode="promise_in_bounds")
+
+
+class SlabColumn:
+    """A ring column behind `column[idx]` and `.dtype`, which is all the
+    join step asks of the other side's columns."""
+
+    def __init__(self, col, dtype):
+        self.col, self.dtype = col, dtype
+
+    def __getitem__(self, idx):
+        return slab_take(self.col, idx).astype(self.dtype)
+
+
+def ring_age(pos, tail, C: int):
+    """How far behind the tail's row a ring position stands, in arrivals:
+    0 the oldest resident row, `count - 1` the newest."""
+    return jnp.mod(pos - tail, C)
+
+
+def ring_search(exp, tail, count, x, C: int):
+    """How many of the ring's `count` rows, oldest first, carry
+    `expire_ts <= x` — the rows are in expiry order, so the expired ones are
+    a prefix and this is its length.  `exp` the stamps' planes, `x` a scalar
+    or `[R]`; ~log2(C) reads of `exp` a value, never a pass over it."""
+    x = jnp.asarray(x, jnp.int64)
+    lo = jnp.zeros(x.shape, jnp.int32)
+    hi = jnp.zeros(x.shape, jnp.int32) + count
+
+    def body(_, c):
+        lo, hi = c
+        go = lo < hi
+        mid = (lo + hi) // 2
+        le = slab_take(exp, jnp.mod(tail + mid, C)) <= x
+        return (jnp.where(jnp.logical_and(go, le), mid + 1, lo),
+                jnp.where(jnp.logical_and(go, jnp.logical_not(le)), mid, hi))
+
+    return jax.lax.fori_loop(0, max(1, int(C).bit_length()), body,
+                             (lo, hi))[0]
+
+
+def ring_write(planes, values, src1, take1, h1, src2, take2):
+    """Write the arrivals into `[C]` ring planes in place: two blocks of B
+    rows each — `[h1, h1 + B)`, which holds the head, and `[0, B)`, where a
+    write that passes the end wraps to — read, overlaid (`take*`: which block
+    rows are arrivals, `src*`: which) and written back by
+    `dynamic_update_slice`.  Nothing else of the C rows is touched.
+    `values`: the arrivals' `[B]` arrays, one a plane, of the plane's
+    dtype."""
+    B = src1.shape[0]
+    v1 = gather_packed(values, src1)
+    v2 = gather_packed(values, src2)
+    out = []
+    for p, a, b in zip(planes, v1, v2):
+        blk = jax.lax.dynamic_slice(p, (h1,), (B,))
+        p = jax.lax.dynamic_update_slice(p, jnp.where(take1, a, blk), (h1,))
+        p = jax.lax.dynamic_update_slice(
+            p, jnp.where(take2, b, p[:B]), (0,))
+        out.append(p)
+    return tuple(out)
+
+
+class TimeRingWindow(TimeWindow):
+    """`window.time` for a reader of CURRENT rows alone (a CURRENT-only
+    projection join's sides, core/join.py): the slab is a RING.  A step costs
+    what arrives, never what is resident —
+
+    - the arrivals are written at the head in place (`ring_write`);
+    - the rows due by `now` are a PREFIX of the ring (stamps that never step
+      back make expiry order arrival order), found by `ring_search`; the
+      tail moves past them and nothing is rewritten — no EXPIRED row is
+      built, there is no `alive` plane;
+    - a row is resident iff its `ring_age` is below `count`; a reader at
+      stamp `t` sees it iff also `expire_ts > t`.
+
+    State: `(RingSlab, seq, pos)`, `pos` = i32 `[tail, count]`.  `admit`
+    needs the batch's CURRENT stamps non-decreasing and none before the
+    side's last (the runtime checks them on the host and otherwise sends the
+    batch through `ring_process`, today's whole-slab `process`, which leaves
+    the ring in expiry order again).  A row that would overwrite a resident
+    one is COUNTED (`dropped`): the oldest row goes, and the runtime reports
+    it as an error — a window bound too small is a wrong answer."""
+
+    current_is_arrivals = True
+
+    def __init__(self, schema, params, batch_capacity, capacity_hint=2048):
+        super().__init__(schema, params, batch_capacity, capacity_hint)
+        # the bound as asked for: the ring's rows ARE the window's bound
+        # (no `2 x batch` floor — a trace whose batch is wider than the
+        # ring takes `ring_process`)
+        self.capacity = max(int(capacity_hint), 8)
+
+    @staticmethod
+    def _slab_of(ts, expire_ts, gslot, cols) -> RingSlab:
+        return RingSlab(ts=_planes(ts), expire_ts=_planes(expire_ts),
+                        gslot=gslot,
+                        cols=tuple(c if c.dtype.itemsize != 8 else _planes(c)
+                                   for c in cols))
+
+    def init_state(self):
+        buf = empty_buffer(self.schema, self.capacity)
+        return (self._slab_of(buf.ts, buf.expire_ts, buf.gslot, buf.cols),
+                jnp.asarray(0, jnp.int64), jnp.zeros((2,), jnp.int32))
+
+    def slab_columns(self, slab: RingSlab):
+        """The slab's columns as the join step reads them."""
+        return tuple(SlabColumn(c, d)
+                     for c, d in zip(slab.cols, self.schema.dtypes))
+
+    def _arrivals(self, rows: Rows):
+        is_cur = jnp.logical_and(rows.valid, rows.kind == ev.CURRENT)
+        k = jnp.cumsum(is_cur.astype(jnp.int32)) - 1
+        return is_cur, k, jnp.sum(is_cur, dtype=jnp.int32)
+
+    def ring_admit(self, state, rows: Rows, now):
+        """-> (state, the arrivals as CURRENT rows where they stand, `pos`
+        [B] the ring position each was written to (C where none), `dropped`,
+        `plan`): `plan` is `ring_write`'s last five arguments, for a caller
+        that keeps a plane of its own by ring position (the join's same-key
+        links) and writes its arrivals' values the same way."""
+        slab, seq0, rp = state
+        C, B, t = self.capacity, rows.capacity, self.time_ms
+        tail, count = rp[0], rp[1]
+        with jax.named_scope("window_state"):
+            is_cur, k, ncur = self._arrivals(rows)
+            n_exp = ring_search(slab.expire_ts, tail, count, now, C)
+            tail = jnp.mod(tail + n_exp, C)
+            count = count - n_exp
+            head = jnp.mod(tail + count, C)
+            dropped = jnp.maximum(count + ncur - C, 0)
+            tail = jnp.mod(tail + dropped, C)
+            count = count + ncur - dropped
+            # the arrivals, valid ones first (as they stand where the
+            # batch's invalid rows are its tail, which is how it is staged)
+            order = jnp.argsort(jnp.logical_not(is_cur),
+                                stable=True).astype(jnp.int32)
+            r = jnp.arange(B, dtype=jnp.int32)
+            h1 = jnp.minimum(head, C - B)
+            i1 = r + (h1 - head)
+            i2 = r + (C - head)
+            plan = (order[jnp.clip(i1, 0, B - 1)],
+                    jnp.logical_and(i1 >= 0, i1 < ncur), h1,
+                    order[jnp.clip(i2, 0, B - 1)], i2 < ncur)
+            flat, tree = jax.tree.flatten(slab)
+            values = jax.tree.leaves(self._slab_of(
+                rows.ts, rows.ts + t, rows.gslot, rows.cols))
+            nslab = jax.tree.unflatten(tree, ring_write(flat, values, *plan))
+            pos = jnp.where(is_cur, jnp.mod(head + k, C), C)
+            cur = Rows(rows.ts, jnp.full((B,), ev.CURRENT, jnp.int32),
+                       is_cur, seq0 + k.astype(jnp.int64), rows.gslot,
+                       rows.cols)
+            nstate = (nslab, seq0 + ncur.astype(jnp.int64),
+                      jnp.stack([tail, count]).astype(jnp.int32))
+        return nstate, cur, pos, dropped, plan
+
+    def admit(self, state, rows: Rows, now):
+        nstate, cur, _pos, _dropped, _ = self.ring_admit(state, rows, now)
+        return nstate, WindowOutput(cur, None,
+                                    jnp.asarray(NO_WAKEUP, jnp.int64))
+
+    def current_buffer(self, state):
+        """The resident rows as a `Buffer` whose `alive` says so and whose
+        `add_seq` is their age (an on-demand read; a pass over the slab)."""
+        slab, rp = state[0], state[2]   # (a join side keeps more behind)
+        C = self.capacity
+        age = ring_age(jnp.arange(C, dtype=jnp.int32), rp[0], C)
+        live = age < rp[1]
+        big = jnp.full((C,), BIG_SEQ, jnp.int64)
+        return Buffer(
+            ts=join64(*slab.ts),
+            add_seq=jnp.where(live, age.astype(jnp.int64) - (C + 1), big),
+            expire_seq=big,
+            expire_ts=jnp.where(live, join64(*slab.expire_ts), big),
+            alive=live, gslot=slab.gslot,
+            cols=tuple(join64(*c).astype(d) if isinstance(c, tuple) else c
+                       for c, d in zip(slab.cols, self.schema.dtypes)))
+
+    def ring_process(self, state, rows: Rows, now):
+        """The batch through `TimeWindow.process` — for stamps out of order,
+        a batch wider than the ring: the whole slab is sorted and rewritten,
+        as before the ring.  -> (state, the arrivals as CURRENT rows where
+        they stand, `dropped`); the ring then starts at 0, in expiry order
+        (a row that came late stands where its expiry puts it)."""
+        _slab, seq0, _rp = state
+        C = self.capacity
+        old = self.current_buffer(state)
+        is_cur, k, ncur = self._arrivals(rows)
+        kept = jnp.sum(jnp.logical_and(old.alive, old.expire_ts > now),
+                       dtype=jnp.int32)
+        dropped = jnp.maximum(kept + ncur - C, 0)
+        (nbuf, nseq), _ = TimeWindow.process(self, (old, seq0), rows, now)
+        with jax.named_scope("window_state"):
+            order = jnp.argsort(
+                jnp.where(nbuf.alive, nbuf.expire_ts, BIG_SEQ),
+                stable=True).astype(jnp.int32)
+            g = gather_packed(
+                (nbuf.ts, nbuf.expire_ts, nbuf.gslot) + tuple(nbuf.cols),
+                order)
+            nbuf = nbuf._replace(ts=g[0], expire_ts=g[1], gslot=g[2],
+                                 cols=tuple(g[3:]))
+            count = jnp.sum(nbuf.alive, dtype=jnp.int32)
+        cur = Rows(rows.ts, jnp.full(rows.ts.shape, ev.CURRENT, jnp.int32),
+                   is_cur, seq0 + k.astype(jnp.int64), rows.gslot, rows.cols)
+        nstate = (self._slab_of(nbuf.ts, nbuf.expire_ts, nbuf.gslot,
+                                nbuf.cols), nseq,
+                  jnp.stack([jnp.zeros_like(count), count]))
+        return nstate, cur, dropped

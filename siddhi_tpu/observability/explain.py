@@ -279,6 +279,13 @@ def _steps_of(qr, kind: str) -> List[Tuple[str, Any]]:
             steps.append(("step[left]", p.step_left))
         if p.step_right is not None:
             steps.append(("step[right]", p.step_right))
+        # a ring side's other programs, as they were asked for: a deeper
+        # walk (`step[left]@16`), stamps out of order (`slow_step[...]`)
+        for (is_left, depth, slow), fn in sorted(p.side_steps.items()):
+            steps.append((
+                f"{'slow_' if slow else ''}step"
+                f"[{'left' if is_left else 'right'}]"
+                f"{'' if depth == 1 else '@%d' % depth}", fn))
     else:
         steps.append(("step", p.step))
     for (fkind, _), (body, fn) in qr._fused_cache.items():

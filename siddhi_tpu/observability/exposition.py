@@ -247,6 +247,16 @@ def render_prometheus(runtimes: Dict) -> str:
                    "Pattern forks and seeds that found no free slot of "
                    "their key (@capacity(slots='N')) and were lost, as the "
                    "last drain read the slab's counter")
+    jw_rows = fam("siddhi_join_window_rows", "gauge",
+                  "Rows a side's window of an equi-join holds, from the "
+                  "host's retention mirror (no fetch)")
+    jw_drop = fam("siddhi_join_window_dropped_total", "counter",
+                  "Rows a full window.time side of a join lost for "
+                  "capacity (@capacity(window='N')): each is a missing "
+                  "match, and was reported as an error")
+    jw_depth = fam("siddhi_join_probe_depth", "gauge",
+                   "Rows of one key a probe of a join's ring side walks "
+                   "(a power of two at or above its fullest key)")
     so_occ = fam("siddhi_state_occupancy", "gauge",
                  "Utilization (occupancy/capacity, 0-1) of each sized "
                  "device state structure, from its host mirror "
@@ -364,6 +374,14 @@ def render_prometheus(runtimes: Dict) -> str:
                                      (nfa_drop, "forks_dropped")):
                     if key in facts:
                         family_.sample(facts[key], app=app_name, query=q)
+            if qr._kind == "join" and qr._jk is not None:
+                jf = qr.join_facts()
+                jw_rows.sample(jf["window_rows_l"], app=app_name, query=q,
+                               side="left")
+                jw_rows.sample(jf["window_rows_r"], app=app_name, query=q,
+                               side="right")
+                jw_drop.sample(jf["window_dropped"], app=app_name, query=q)
+                jw_depth.sample(jf["probe_depth"], app=app_name, query=q)
         for gid, mg in sorted(getattr(rt, "merged_groups", {}).items()):
             mrg_q.sample(len(getattr(mg, "members", ())), app=app_name,
                          group=gid)

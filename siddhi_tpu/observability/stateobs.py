@@ -64,7 +64,8 @@ import numpy as np
 # canonical structure order — every surface lists structures in this
 # order, not dict order (the phases.PHASES convention)
 STRUCTURES = ("window_keys", "group_slots", "pattern_keys", "pair_slots",
-              "join_keys", "join_lane", "window_fill", "emission_cap",
+              "join_keys", "join_lane", "join_window_left",
+              "join_window_right", "window_fill", "emission_cap",
               "serve_ring")
 
 # count-min sketch geometry: 4 rows x 1024 counters of int64 = 32 KiB
@@ -506,6 +507,13 @@ def _collect_query(obs: StateObservatory, qname: str, qr) -> None:
         obs.observe(qname, "join_lane", jk.needed_k(),
                     p.lane_k, growable=True,
                     config_key="auto (lane grows via replan)")
+        # a `window.time` side kept as a ring: rows held over its bound
+        for rows, cap, kind, which in zip(jk.rows(), p.ring_caps,
+                                          p.index_kind, ("left", "right")):
+            if kind == "chain":
+                obs.observe(qname, f"join_window_{which}", rows, cap,
+                            growable=False,
+                            config_key=f"@capacity(window.{which}='N')")
     cap = p.compact_rows
     if cap is not None:
         obs.observe(qname, "emission_cap", None, cap,
@@ -675,6 +683,11 @@ def state_report(rt) -> Dict:
         # (runtime.PatternQueryRuntime.note_nfa_facts)
         "nfa": {q: dict(qr._nfa_facts) for q, qr in sorted(
             getattr(rt, "query_runtimes", {}).items()) if qr._nfa_facts},
+        # what each bucket join's retention mirror holds
+        # (runtime.JoinQueryRuntime.join_facts)
+        "join": {q: qr.join_facts() for q, qr in sorted(
+            getattr(rt, "query_runtimes", {}).items())
+            if qr._kind == "join" and qr._jk is not None},
     }
 
 

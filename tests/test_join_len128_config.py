@@ -320,12 +320,12 @@ def test_named_scopes_leave_the_lowered_join_steps_as_they_were(
 ACCEPTED = {
     "lengthbatch_1000": {
         "step":
-        "cc6f062a250402dcaeec6b66c4bd8dcdf487fa812f5c54b1f678cb5c06eebb5f"},
+        "98d9c374d669b83c8e5b1366ca6f4bf64f808a511c633d8f561b0c4c96bde4c8"},
     "pattern_1m": {
         "dense_step[TradeStream]":
-        "2139de17d4f8415f4951f317f592f434e539f6a812aad5b6b5b59c312860fd7b",
+        "a9e682ffd839c858550febf0f810cb60c8d66a71bc4d401d3d0681ddb1e8d122",
         "step[TradeStream]":
-        "7e1f05c164f42dcd51b978c3bc42e6569e7dffad64071109d3dc1b3163f0eeae"},
+        "7e39ae49b07fa770c8dda6673eaf4f4e794eff9ed04bbda5096e6a968e15379a"},
 }
 # what each is sent: two sends; the flagship's second revisits every other
 # key of its first, so its slots are no contiguous run (the gather step)

@@ -46,7 +46,8 @@ CLASS_FIELDS = {"_kind", "adopts_staged"}
 OWN_FIELDS = {
     runtime.QueryRuntime: {"_state"},
     runtime.PatternQueryRuntime: {"state", "_block_cache"},
-    runtime.JoinQueryRuntime: {"state", "_lane_k"},
+    runtime.JoinQueryRuntime: {"state", "_lane_k", "_probe_depth",
+                               "_ring_last_ts", "_window_dropped"},
     runtime.NamedWindowRuntime: {
         "definition", "schema", "wproc", "needs_timer",
         "output_event_type", "subscribers", "stream_callbacks", "_step",
